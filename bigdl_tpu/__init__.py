@@ -20,10 +20,45 @@ evidence-status preamble: the reference mount was empty, paths are the
 upstream 0.x layout).
 """
 
-from bigdl_tpu.engine import Engine
-from bigdl_tpu.common import RandomGenerator
-from bigdl_tpu.config import config, configure
-from bigdl_tpu.tensor import Tensor
-from bigdl_tpu import obs  # noqa: F401 — observability layer (obs.get_tracer()…)
+import os as _os
 
-__version__ = "0.1.0"
+
+def _place_compile_cache():
+    """Give JAX's persistent compilation cache a directory, once, before
+    anything compiles (package import is the one place the trainers,
+    the serving engine and the scripts all pass through; a config
+    update starts no backend).
+
+    * ``JAX_COMPILATION_CACHE_DIR`` set: nothing is set here, JAX reads
+      the variable itself;
+    * platform pinned to CPU (the tests, ``JAX_PLATFORMS=cpu``): no
+      cache at all.  The chip tool copies the checkout as it stands,
+      and a CPU executable cached on one host may meet another host's
+      CPU;
+    * otherwise: ``<checkout>/.jax_cache``.  The path is part of the
+      cache key, so it is fixed: never a temp dir, a pid or a time.
+
+    Returns the directory set, or None when nothing was set."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    platforms = jax.config.jax_platforms or ""
+    if platforms.split(",")[0].strip() == "cpu":
+        return None
+    path = _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+_place_compile_cache()
+
+from bigdl_tpu.engine import Engine  # noqa: E402
+from bigdl_tpu.common import RandomGenerator  # noqa: E402
+from bigdl_tpu.config import config, configure  # noqa: E402
+from bigdl_tpu.tensor import Tensor  # noqa: E402
+from bigdl_tpu import obs  # noqa: E402,F401 — observability layer (obs.get_tracer()…)
+
+__version__ = "0.13.0"

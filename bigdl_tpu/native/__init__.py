@@ -6,7 +6,8 @@ TPU rebuild's chip compute is XLA, but the host feeding path stays
 native: ``native/bigdl_tpu_native.cpp`` provides the fp16 wire codec,
 one-pass minibatch gather/normalize, and the OpenCV-replacement image
 ops.  This wrapper builds the library on first use (``make`` in
-``native/``) and falls back to numpy implementations when no compiler
+``native/``, again whenever the source is newer than the binary) and
+falls back to numpy implementations, with a warning, when no compiler
 is available, so the framework never hard-requires the binary.
 """
 
@@ -28,6 +29,7 @@ _NATIVE_DIR = os.path.join(
     "native",
 )
 _SO_PATH = os.path.join(_NATIVE_DIR, "libbigdl_tpu_native.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "bigdl_tpu_native.cpp")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -56,9 +58,20 @@ def _try_build() -> bool:
             capture_output=True, timeout=120,
         )
         return os.path.exists(_SO_PATH)
-    except Exception as e:  # noqa: BLE001 - fall back to numpy
-        log.info("native build unavailable (%s); using numpy fallbacks", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        # the trainer's feed path runs on this library: say so, and
+        # never load a binary older than its source in its place
+        log.warning("native build failed (%s); using numpy fallbacks", e)
         return False
+
+
+def _stale() -> bool:
+    """No binary yet, or the source is newer than it (the .so is not
+    under version control, so a checkout can meet an old one)."""
+    try:
+        return os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)
+    except OSError:
+        return not os.path.exists(_SO_PATH)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -68,12 +81,12 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO_PATH) and not _try_build():
+        if _stale() and not _try_build():
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError as e:
-            log.info("native load failed (%s); using numpy fallbacks", e)
+            log.warning("native load failed (%s); using numpy fallbacks", e)
             return None
         lib.fp16_compress.argtypes = [_f32p, _u16p, _i64]
         lib.fp16_decompress.argtypes = [_u16p, _f32p, _i64]

@@ -141,8 +141,8 @@ class SpatialConvolutionBatchNorm(AbstractModule):
         # mean at any shift, geometrically self-healing variance), and
         # chip measurements as BatchNormalization in layers.py: every
         # guarded rescue variant (lax.cond, jnp.where-subsample)
-        # measured far slower under the relay's 2026-07 XLA
-        # (scripts/bn_ab.py).
+        # measured far slower (2026-07, on another toolchain;
+        # scripts/bn_ab.py).
         y, s1, s2 = conv_bn_stats(input, w, rm, stride=self.stride,
                                   pad=self.pad)
         n = y.shape[0] * y.shape[2] * y.shape[3]
@@ -167,15 +167,17 @@ class SpatialConvolutionBatchNorm(AbstractModule):
                 f"/{self.stride}{tail})")
 
 
-def _is_fusable_conv(m, kernels=(1, 3)):
+def _is_fusable_conv(m):
     # 1x1 and 3x3 torch-padded convs have Pallas epilogue-stats kernels
-    # (ops/conv_bn.py); the 7x7 stem stays on XLA's native conv — its
-    # C=3 tap dots would starve the MXU
+    # (ops/conv_bn.py; both compile under the installed Mosaic and
+    # match the XLA reference on the chip, chip_smoke.py kernels
+    # phase); the 7x7 stem stays on XLA's native conv — its C=3 tap
+    # dots would starve the MXU
     return (
         isinstance(m, SpatialConvolution)
         and type(m) is SpatialConvolution
         and m.kernel_w == m.kernel_h
-        and m.kernel_w in kernels
+        and m.kernel_w in (1, 3)
         and m.stride_w == m.stride_h
         and m.stride_w in (1, 2)
         and m.pad_w == m.pad_h == (m.kernel_w - 1) // 2
@@ -183,15 +185,13 @@ def _is_fusable_conv(m, kernels=(1, 3)):
     )
 
 
-def fuse_conv_bn(model, kernels=(1, 3)):
+def fuse_conv_bn(model):
     """Rewrite every ``[1x1/3x3 conv (no bias),
     SpatialBatchNormalization, (ReLU)]`` run inside ``Sequential``
     containers into one ``SpatialConvolutionBatchNorm``, recursively.
-    In-place; returns the model.  ``kernels`` restricts which conv
-    sizes fuse — ``(1,)`` keeps 3x3s on XLA (useful when a toolchain
-    rejects the kxk Pallas kernel; see scripts/mosaic_probe.py)."""
+    In-place; returns the model."""
     for child in getattr(model, "modules", []):
-        fuse_conv_bn(child, kernels)
+        fuse_conv_bn(child)
     if isinstance(model, Sequential):
         mods = model.modules
         out = []
@@ -200,7 +200,7 @@ def fuse_conv_bn(model, kernels=(1, 3)):
             m = mods[i]
             nxt = mods[i + 1] if i + 1 < len(mods) else None
             if (
-                _is_fusable_conv(m, kernels)
+                _is_fusable_conv(m)
                 and isinstance(nxt, SpatialBatchNormalization)
                 and type(nxt) is SpatialBatchNormalization
                 and nxt.affine

@@ -1340,8 +1340,9 @@ class BatchNormalization(AbstractModule):
         # where (jnp.where subsample rescue) 85.5, s0 (sample-0-mean
         # shift) 64.5, cond (lax.cond rescue) 89.8-at-b32-scale + OOM
         # at b64+, twopass 57.8.
-        # The relay's 2026-07 XLA wants BN as one straight-line
-        # dependency chain; anything else defeats fusion/scheduling.
+        # (Measured 2026-07 on another toolchain.)  That XLA wanted BN
+        # as one straight-line dependency chain; anything else
+        # defeated fusion/scheduling.
         rm = state["running_mean"]
         xc = xf - rm.reshape(bshape)
         d = jnp.mean(xc, axis=axes)

@@ -43,6 +43,8 @@ from typing import Optional
 
 import jax
 
+from bigdl_tpu.ops._pallas import resolve_interpret
+
 
 # --------------------------------------------------------------------------
 # lax reference implementation
@@ -285,7 +287,8 @@ _AUTO_BLOCKS = (0, 0, 0, 0)
                               "block_kv", "block_qs")
 )
 def flash_attention(q, k, v, *, causal: bool = False,
-                    scale: Optional[float] = None, interpret: bool = False,
+                    scale: Optional[float] = None,
+                    interpret: Optional[bool] = None,
                     seq_offset: int = 0, block_q: int = 0, block_k: int = 0,
                     block_kv: int = 0, block_qs: int = 0):
     """Pallas flash attention.  q (B, H, Tq, D) against k/v
@@ -297,10 +300,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     ``block_q``/``block_k`` override the q/k tile sizes and
     ``block_kv``/``block_qs`` the streamed superblocks (0 = let
-    :func:`_flash_plan` choose) — the auto-tuner's knobs; invalid
-    overrides fall back to the lax reference like any other infeasible
-    shape.  Compiled Mosaic kernels exist only on TPU, so any other
-    backend runs the interpreter automatically.
+    :func:`_flash_plan` choose) — the auto-tuner's knobs.  A shape or
+    override :func:`_flash_plan` cannot tile raises: the caller asked
+    for the kernel by name (``impl="auto"`` never does, its predicate
+    shares the plan).  ``interpret=None`` runs the Pallas interpreter
+    on the CPU backend only (``ops/_pallas.resolve_interpret``).
 
     Differentiable with a true blockwise backward: the forward saves
     (q, k, v, out, logsumexp) — O(T) extra — and the backward kernels
@@ -310,10 +314,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """
     if seq_offset < 0:
         raise ValueError("seq_offset must be >= 0")
-    interpret = interpret or jax.default_backend() != "tpu"
     return _flash_attention_vjp(q, k, v, causal,
                                 scale if scale is not None else q.shape[-1] ** -0.5,
-                                interpret, seq_offset,
+                                resolve_interpret(interpret), seq_offset,
                                 (block_q, block_k, block_kv, block_qs))
 
 
@@ -338,12 +341,13 @@ def _flash_forward(q, k, v, causal, scale, interpret, *,
                        block_k=blocks[1], block_kv=blocks[2],
                        block_qs=blocks[3])
     if plan is None:
-        # untileable T, or even single-tile streaming would blow the
-        # symmetric VMEM budget: lax reference (auto dispatch never
-        # lands here — its predicate shares this plan)
-        out = _reference_attention(q, k, v, causal=causal, scale=scale,
-                                   seq_offset=seq_offset)
-        return (out, None) if with_lse else out
+        # auto dispatch never lands here (its predicate shares this
+        # plan), so this is an explicit request for the kernel
+        raise ValueError(
+            f"flash attention cannot tile Tq={tq} Tk={tk} d={d} "
+            f"{k.dtype} with blocks {tuple(blocks)}: T must be a "
+            "multiple of 8 and one tile must fit the VMEM budget; use "
+            "impl='lax' or impl='auto'")
 
     block_q, block_k, block_kv, _ = plan
     kernel = functools.partial(
@@ -603,19 +607,7 @@ def _flash_fwd_rule(q, k, v, causal, scale, interpret, seq_offset, blocks):
 
 
 def _flash_bwd_rule(causal, scale, interpret, seq_offset, blocks, res, g):
-    import jax
-
     q, k, v, out, lse = res
-    if lse is None:
-        # the forward fell back to the lax reference (untileable T):
-        # recompute its vjp the same way
-        def ref(q, k, v):
-            return _reference_attention(q, k, v, causal=causal,
-                                        scale=scale,
-                                        seq_offset=seq_offset)
-
-        _, vjp = jax.vjp(ref, q, k, v)
-        return vjp(g)
     return _flash_backward(q, k, v, out, lse, g, causal, scale,
                            interpret, seq_offset, blocks=blocks)
 
@@ -724,8 +716,12 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
                 "seq_offset; traced offsets (ring attention's hops) "
                 "use impl='lax'"
             )
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               interpret=(impl == "pallas_interpret"),
-                               seq_offset=seq_offset, **blocks)
+        return flash_attention(
+            q, k, v, causal=causal, scale=scale,
+            interpret=True if impl == "pallas_interpret" else None,
+            seq_offset=seq_offset, **blocks)
+    if impl != "lax":
+        raise ValueError(
+            f"impl must be auto|lax|pallas|pallas_interpret, got {impl!r}")
     return _reference_attention(q, k, v, causal=causal, scale=scale,
                                 mask=mask, seq_offset=seq_offset)

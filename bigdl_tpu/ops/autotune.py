@@ -55,6 +55,7 @@ import threading
 import time
 from typing import Optional
 from bigdl_tpu.obs import names
+from bigdl_tpu.ops._pallas import resolve_interpret
 
 # rough per-platform (peak_flops, peak_hbm_bytes_per_s) for the
 # roofline score.  Only the RANKING matters — every candidate of one
@@ -230,7 +231,13 @@ def cache_key(site: str, shape_sig: str, dtype, plat: Optional[str] = None,
 
 
 def _score(flops: float, bytes_: float, plat: Optional[str] = None) -> float:
-    peak_f, peak_b = _PEAKS.get(plat or platform(), _PEAKS["cpu"])
+    plat = plat or platform()
+    if plat not in _PEAKS:
+        raise ValueError(
+            f"no roofline peaks for platform {plat!r} (known: "
+            f"{sorted(_PEAKS)}); add it to _PEAKS before ranking "
+            "candidates on it")
+    peak_f, peak_b = _PEAKS[plat]
     return flops / peak_f + bytes_ / peak_b
 
 
@@ -429,7 +436,7 @@ def decide_attention(q_shape, k_shape, dtype, *, causal: bool,
         analytic = {"lax": _attn_cost("lax", None, b, h, tq, tk, d,
                                       dtype, causal)}
         scale = d ** -0.5
-        interp = platform() != "tpu"
+        interp = resolve_interpret()
 
         def _lax_probe(q, k, v):
             import jax
@@ -671,7 +678,7 @@ def decide_decode_attn(q_shape, page_size: int, maxp: int, dtype, *,
             analytic["pallas"] = (flops, D.decode_hbm_bytes(
                 "pallas", b, h, d, p, maxp, item))
             probes["pallas"] = _decode_probe(
-                D, p, "pallas", 1, platform() != "tpu")
+                D, p, "pallas", 1, resolve_interpret())
         return _resolve("decode_attn", key, candidates, "dense",
                         analytic, probes, arrays, use_hlo=False)
     except Exception:  # noqa: BLE001 — the tuner must never sink a step

@@ -46,10 +46,12 @@ from __future__ import annotations
 
 import functools
 import logging
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from bigdl_tpu.obs import names
+from bigdl_tpu.ops._pallas import resolve_interpret
 
 _log = logging.getLogger(__name__)
 
@@ -494,7 +496,7 @@ _conv_bn_stats_vjp.defvjp(_fwd_rule, _bwd_rule)
 
 
 def conv_bn_stats(x, w, shift, *, stride: int = 1, pad: int = 0,
-                  interpret: bool = False, impl: str = "auto",
+                  interpret: Optional[bool] = None, impl: str = "auto",
                   block_o: int = 0):
     """Fused conv + centered BN statistics.
 
@@ -509,16 +511,13 @@ def conv_bn_stats(x, w, shift, *, stride: int = 1, pad: int = 0,
     — ``BIGDL_TUNER=1``, ops/autotune.py — the cached per-shape search
     decides instead), "pallas" (static dispatch, no tuner), or "xla"
     (reference).  ``block_o`` caps the O-tile (0 = budget-derived) —
-    the tuner's knob.
+    the tuner's knob.  ``interpret=None`` runs the Pallas interpreter
+    on the CPU backend only (``ops/_pallas.resolve_interpret``).
     """
     if w.ndim == 2:
         w = w[:, :, None, None]
     shift = shift.astype(jnp.float32)
-    # compiled Mosaic kernels exist only on TPU; everything else
-    # (CPU tests, the 8-virtual-device mesh, a hypothetical GPU box —
-    # whose parallel grid would race the s1/s2 accumulation) runs the
-    # interpreter
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if impl == "auto":
         impl = "pallas"
         from bigdl_tpu.ops import autotune
@@ -535,7 +534,7 @@ def conv_bn_stats(x, w, shift, *, stride: int = 1, pad: int = 0,
 
 
 def conv1x1_bn_stats(x, w, shift, *, stride: int = 1,
-                     interpret: bool = False):
+                     interpret: Optional[bool] = None):
     """1x1 fast path, kept as the r02 API: w (O, C)."""
     return conv_bn_stats(x, w, shift, stride=stride, pad=0,
                          interpret=interpret)

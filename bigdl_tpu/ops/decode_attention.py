@@ -26,8 +26,8 @@ ops/attention.py's flash kernel):
   prefetch** so each program's BlockSpec index map DMAs exactly the
   page the table names (trash-page contract below), ``(m, l, acc)``
   carried in VMEM scratch across the page grid dimension, output
-  written on the final page.  Compiled Mosaic exists only on TPU;
-  other backends run the interpreter (tests) or pick an XLA impl.
+  written on the final page.  The CPU backend (tests) runs it in
+  the Pallas interpreter; an accelerator compiles it or fails.
 
 Mask contract (identical across impls, pinned by tests): position
 ``pos <= length`` attends, everything else is ``-inf`` before the
@@ -209,23 +209,23 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     already resolved this program's K/V block to the page the table
     names (scalar prefetch), so the kernel only sees a (P, Dh) tile;
     (m, l, acc) carry in VMEM scratch across the page grid dimension
-    (fastest-varying, sequential on TPU)."""
+    (fastest-varying, sequential on TPU).  m and l stay (1, 1) arrays
+    end to end: Mosaic stores vectors to VMEM, not scalars."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
-    d = q_ref.shape[2]
     j = pl.program_id(2)
     ns = pl.num_programs(2)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full((1, 1), -jnp.inf, jnp.float32)
-        l_scr[...] = jnp.zeros((1, 1), jnp.float32)
-        acc_scr[...] = jnp.zeros((1, d), jnp.float32)
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    q = q_ref[0].astype(jnp.float32) * scale           # (1, Dh)
+    q = q_ref[0, 0].astype(jnp.float32) * scale        # (1, Dh)
     ks = k_ref[0, 0].astype(jnp.float32)               # (P, Dh)
     vs = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(
@@ -235,23 +235,22 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     length = len_ref[pl.program_id(0)]
     s = jnp.where(pos <= length, s, -jnp.inf)
 
-    m = m_scr[0, 0]
-    m_new = jnp.maximum(m, jnp.max(s))
+    m = m_scr[...]                                     # (1, 1)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
     shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
     p = jnp.exp(s - shift)
     alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - shift, -jnp.inf))
-    l_new = l_scr[0, 0] * alpha + jnp.sum(p)
-    acc_new = acc_scr[...] * alpha + jax.lax.dot_general(
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, vs, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)            # (1, Dh)
-    m_scr[0, 0] = m_new
-    l_scr[0, 0] = l_new
-    acc_scr[...] = acc_new
+    m_scr[...] = m_new
 
     @pl.when(j == ns - 1)
     def _finalize():
-        out = acc_scr[...] / jnp.maximum(l_scr[0, 0], 1e-30)
-        o_ref[0] = out.astype(o_ref.dtype)
+        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def _pallas(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
@@ -265,19 +264,18 @@ def _pallas(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
     maxp = tables.shape[1]
     p = int(page_size)
 
+    # q and out ride as (B, H, 1, Dh): Mosaic wants a block's last two
+    # dims (8, 128)-divisible or full, which a (1, 1, Dh) block over
+    # (B, H, Dh) is not and a (1, 1, 1, Dh) block over this shape is
+    qo_spec = pl.BlockSpec((1, 1, 1, d), lambda i, hh, j, tbl, lens:
+                           (i, hh, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, p, d), lambda i, hh, j, tbl, lens:
+                           (tbl[i, j], hh, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # tables, lengths
         grid=(b, h, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda i, hh, j, tbl, lens:
-                         (i, hh, 0)),
-            pl.BlockSpec((1, 1, p, d), lambda i, hh, j, tbl, lens:
-                         (tbl[i, j], hh, 0, 0)),
-            pl.BlockSpec((1, 1, p, d), lambda i, hh, j, tbl, lens:
-                         (tbl[i, j], hh, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda i, hh, j, tbl, lens:
-                               (i, hh, 0)),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -285,12 +283,14 @@ def _pallas(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
         ],
     )
     kernel = functools.partial(_decode_kernel, page_size=p, scale=scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, kp, vp)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q[:, :, None, :], kp, vp)
+    return out[:, :, 0, :]
 
 
 # --------------------------------------------------------------------------
@@ -309,7 +309,7 @@ def static_decode_dispatch() -> tuple:
 def paged_decode_attention(q, kp, vp, tables, lengths, *,
                            page_size: int, scale: Optional[float] = None,
                            impl: str = "auto", block_pages: int = 0,
-                           interpret: bool = False):
+                           interpret: Optional[bool] = None):
     """One decode-attention step over the paged KV cache.
 
     q: ``(B, H, Dh)`` — one query token per slot.
@@ -322,7 +322,8 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
     cached ``decode_attn`` auto-tuner site when ``BIGDL_TUNER=1``),
     "dense", "fused", "pallas", or "pallas_interpret" (testing).
     ``block_pages`` sets the fused path's page-block chunk (0 = whole
-    width, one block).
+    width, one block).  ``interpret=None`` interprets the Pallas
+    kernel on the CPU backend only.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -339,12 +340,12 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
                 impl = rec.get("impl", impl)
                 block_pages = int(rec.get("block_pages") or 0)
     if impl in ("pallas", "pallas_interpret"):
-        import jax
+        from bigdl_tpu.ops._pallas import resolve_interpret
 
-        interpret = (interpret or impl == "pallas_interpret"
-                     or jax.default_backend() != "tpu")
         return _pallas(q, kp, vp, tables, lengths, page_size=page_size,
-                       scale=scale, interpret=interpret)
+                       scale=scale, interpret=resolve_interpret(
+                           True if impl == "pallas_interpret"
+                           else interpret))
     if impl == "fused":
         return _fused(q, kp, vp, tables, lengths, page_size=page_size,
                       scale=scale, block_pages=block_pages)

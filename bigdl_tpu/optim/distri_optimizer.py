@@ -48,22 +48,13 @@ def _jnp():
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map.  Replication checking is disabled:
-    the gathered weight vector is replicated by construction
-    (all_gather), which the static vma checker cannot infer."""
+    """``jax.shard_map`` with replication checking disabled: the
+    gathered weight vector is replicated by construction (all_gather),
+    which the static vma checker cannot infer."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def int8_blockwise_reduce_scatter(g, axis, n, block):
@@ -443,8 +434,19 @@ class DistriOptimizer(LocalOptimizer):
         # (valid count) on top of this; the standard step's budget is
         # the per-step account
         self._collective_footprint = self._collective_byte_footprint()
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        replicated = NamedSharding(self.mesh, P())
 
         def dispatch(pvar, opt_state, mod_state, rng, inp, tgt):
+            # the first call's params and model state come from the
+            # model (one device), every later call's are step outputs
+            # (replicated over the mesh).  Commit the first call's to
+            # the mesh, or the step is traced and compiled twice
+            if pvar.sharding != replicated:
+                pvar, mod_state = jax.device_put((pvar, mod_state),
+                                                 replicated)
             mask = self._device_mask
             if mask is None:
                 return self._plain_step(pvar, opt_state, mod_state, rng,
@@ -882,7 +884,6 @@ class DistriOptimizer(LocalOptimizer):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        jnp = _jnp()
         sh = NamedSharding(self.mesh, P(self.axis))
         mask = getattr(self, "_host_mask", None)
         if getattr(self.dataset, "per_process", False) \
@@ -893,7 +894,10 @@ class DistriOptimizer(LocalOptimizer):
             put = lambda a: jax.make_array_from_process_local_data(
                 sh, np.asarray(a))
         else:
-            put = lambda a: jax.device_put(jnp.asarray(a), sh)
+            # from host memory straight to each device's shard: going
+            # through jnp.asarray first would land the whole global
+            # batch on device 0 and scatter from there
+            put = lambda a: jax.device_put(np.asarray(a), sh)
         self._device_mask = None if mask is None else put(mask)
         return put(inp), put(tgt)
 

@@ -31,10 +31,6 @@ set -euo pipefail
 export OMP_NUM_THREADS="${OMP_NUM_THREADS:-1}"
 export KMP_AFFINITY="${KMP_AFFINITY:-granularity=fine,compact,1,0}"
 
-# TPU runtime knobs (safe defaults; override freely)
-export JAX_PLATFORMS="${JAX_PLATFORMS:-}"
-export XLA_FLAGS="${XLA_FLAGS:-}"
-
 # pass through the multi-host contract if set
 : "${BIGDL_COORDINATOR_ADDRESS:=}"
 : "${BIGDL_NUM_PROCESSES:=}"

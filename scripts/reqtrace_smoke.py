@@ -69,7 +69,7 @@ def main() -> int:
     t0 = time.monotonic()
     RandomGenerator.RNG.set_seed(13)
     model = build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                 max_len=64, attn_impl="xla")
+                                 max_len=64, attn_impl="lax")
     params = model.params()
 
     def ref(prompt, n):

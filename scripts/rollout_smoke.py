@@ -54,7 +54,7 @@ def _build(seed: int):
 
     RandomGenerator.RNG.set_seed(seed)
     model = build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                 max_len=64, attn_impl="xla")
+                                 max_len=64, attn_impl="lax")
     return model, model.params()
 
 

@@ -92,7 +92,7 @@ def run_real_engines(args) -> dict:
 
     RandomGenerator.RNG.set_seed(13)
     model = build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                 max_len=64, attn_impl="xla")
+                                 max_len=64, attn_impl="lax")
     params = model.params()
 
     def ref(prompt, n):

@@ -158,7 +158,7 @@ def main() -> int:
     from bigdl_tpu.models.transformer import build_transformer_lm
 
     model = build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                 max_len=64, attn_impl="xla")
+                                 max_len=64, attn_impl="lax")
 
     # -- 1: continuous vs static A/B ----------------------------------
     stat = _ab_arm(model, "static")
@@ -191,7 +191,7 @@ def main() -> int:
     # trace only ever fills 4 — the PR 12 baseline gathers all 32 per
     # layer per step (the gather tax the fused path deletes)
     model2 = build_transformer_lm(64, dim=128, n_head=8, n_layer=4,
-                                  max_len=512, attn_impl="xla")
+                                  max_len=512, attn_impl="lax")
     params2 = model2.params()
     base_st, base_toks = _decode_arm(
         model2, "dense-gather baseline", decode_attn="dense",

@@ -60,6 +60,13 @@ def _decide_attn(**kw):
 
 
 # ------------------------------------------------------------ cache keys
+def test_score_refuses_a_platform_without_peaks():
+    """An unknown platform used to be ranked with the CPU's peaks."""
+    assert autotune._score(1e9, 1e6, "tpu") > 0
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        autotune._score(1e9, 1e6, "some_new_chip")
+
+
 class TestCacheStore:
     def test_golden_key_format(self):
         key = autotune.cache_key("attn", "b1h2tq128tk256d16",

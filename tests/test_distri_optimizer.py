@@ -45,6 +45,35 @@ def test_mesh_has_8_devices():
     assert Engine.mesh().shape["data"] == 8
 
 
+def test_sharded_step_compiles_once_and_batch_is_put_shardwise():
+    """The first call's params and model state come from the model (one
+    device), every later call's are step outputs (replicated over the
+    mesh): without committing them to the mesh up front the step was
+    traced and compiled twice — a second ResNet-50-sized compile on a
+    chip.  And the global batch goes from host memory straight to each
+    device's shard, never through one device."""
+    compiled = []
+
+    def on_compile(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    x, y = _toy(n=256)
+    opt = DistriOptimizer(_model(), (x, y), ClassNLLCriterion(),
+                          batch_size=64)
+    opt.set_optim_method(SGD(learningrate=0.1, momentum=0.9))
+    opt.set_end_when(Trigger.max_iteration(4))
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        opt.optimize()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert len([f for f in compiled if "sharded_step" in str(f)]) == 1
+    inp, _ = opt._put_batch(x[:64], y[:64])
+    assert {s.data.shape for s in inp.addressable_shards} == {(8, 16)}
+    assert len({s.device for s in inp.addressable_shards}) == 8
+
+
 def test_distri_optimizer_converges():
     x, y = _toy()
     model = _model()

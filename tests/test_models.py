@@ -299,7 +299,7 @@ def test_transformer_generate_matches_incremental_forward():
 
     RandomGenerator.RNG.set_seed(13)
     model = build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                 max_len=24, attn_impl="xla")
+                                 max_len=24, attn_impl="lax")
     params = model.params()
     rs = np.random.RandomState(0)
     prompt = rs.randint(0, 48, (2, 5))

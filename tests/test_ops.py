@@ -130,31 +130,21 @@ class TestInt8Matmul:
         assert err < 0.05 * np.abs(np.asarray(want)).max() + 0.05
 
 
-def test_flash_untileable_t_falls_back_with_working_grad():
-    # T=27 tiles to nothing: the vjp must carry the lse=None
-    # reference-fallback residual and still produce correct gradients
-    # (attention.py _flash_bwd_rule's fallback arm).  Distinct q/k/v +
-    # per-argument grads so a permuted (dq, dk, dv) wiring in the
-    # fallback arm cannot cancel out in a shared-input sum.
+def test_flash_untileable_t_raises_on_explicit_request():
+    # T=27 tiles to nothing.  An explicit request for the kernel must
+    # not be answered by the lax reference under the kernel's name;
+    # impl="auto" never reaches the kernel for such a shape.
     rs = np.random.RandomState(11)
     q = jnp.asarray(rs.randn(1, 2, 27, 8).astype(np.float32))
-    k = jnp.asarray(rs.randn(1, 2, 27, 8).astype(np.float32))
-    v = jnp.asarray(rs.randn(1, 2, 27, 8).astype(np.float32))
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True,
-                                       interpret=True) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(_reference_attention(q, k, v, causal=True,
-                                            scale=8 ** -0.5) ** 2)
-
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip("qkv", g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4, rtol=1e-4,
-                                   err_msg=f"d{name}")
+    with pytest.raises(ValueError, match="cannot tile"):
+        flash_attention(q, q, q, causal=True, interpret=True)
+    with pytest.raises(ValueError, match="cannot tile"):
+        dot_product_attention(q, q, q, causal=True,
+                              impl="pallas_interpret")
+    out = dot_product_attention(q, q, q, causal=True, impl="auto")
+    want = _reference_attention(q, q, q, causal=True, scale=8 ** -0.5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-6)
 
 
 def test_flash_forward_lse_matches_reference_logsumexp():

@@ -28,7 +28,7 @@ def _model(seed=13, max_len=64):
 
     RandomGenerator.RNG.set_seed(seed)
     return build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                max_len=max_len, attn_impl="xla")
+                                max_len=max_len, attn_impl="lax")
 
 
 @pytest.fixture(scope="module")

@@ -388,7 +388,7 @@ def _model():
 
     RandomGenerator.RNG.set_seed(13)
     return build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
-                                max_len=64, attn_impl="xla")
+                                max_len=64, attn_impl="lax")
 
 
 @pytest.fixture(scope="module")
