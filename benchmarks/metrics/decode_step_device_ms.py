@@ -1,0 +1,8 @@
+"""Model math: device time of the engine's jitted ``step`` program, per
+call, from the profiler's trace."""
+
+from benchmarks.lib import xplane
+
+
+def read(run):
+    return xplane.program_ms_per_call(run.trace, "step")
