@@ -18,6 +18,7 @@ import logging
 import os
 import subprocess
 import threading
+import time
 from typing import Optional
 
 import numpy as np
@@ -246,12 +247,15 @@ def normalize(img: np.ndarray, mean, std) -> np.ndarray:
 class PrefetchIterator:
     """Wraps a batch-producing iterable; a daemon thread assembles the
     next batch while the chip consumes the current one (the reference's
-    Engine.default prefetch role on the data path)."""
+    Engine.default prefetch role on the data path).  With the obs tracer
+    on, the worker records its busy time a batch as ``feed.gather``
+    (``step`` counts up from ``first_step``)."""
 
-    def __init__(self, iterable, depth: int = 2):
+    def __init__(self, iterable, depth: int = 2, first_step: int = 0):
         import queue
 
         self._iterable = iterable
+        self._first_step = int(first_step)
         self._queue: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._done = object()
         self._thread: Optional[threading.Thread] = None
@@ -274,10 +278,19 @@ class PrefetchIterator:
         stop = threading.Event()
 
         def worker():
+            from bigdl_tpu import obs
+
+            tracer = obs.get_tracer()
             try:
-                for item in self._iterable:
+                t0 = time.perf_counter()
+                for k, item in enumerate(self._iterable):
+                    # retroactive: the try that ends the epoch is no batch
+                    tracer.complete("feed.gather", t0,
+                                    time.perf_counter() - t0,
+                                    step=self._first_step + k)
                     if not self._put(item, stop):
                         return  # consumer broke out early
+                    t0 = time.perf_counter()
             except BaseException as e:  # noqa: BLE001 - forwarded to consumer
                 self._err = e
             finally:

@@ -269,15 +269,23 @@ class TransformerBlock(_Composite):
         K/V (B, H, T, Dh) for a decode cache.  Attention math is the
         identical projection + ``_inner_attention`` path apply() takes
         (dropout off — decoding is inference)."""
+        import jax
+
         attn = self._children["attn"]
         h, _ = self._children["ln1"].apply(params["ln1"], {}, x)
-        q, k, v = self._project_qkv(params["attn"], h)
+        # scopes as in serving/engine.py paged_decode_math: names in a
+        # profiler trace, no change to the math
+        with jax.named_scope("dense"):
+            q, k, v = self._project_qkv(params["attn"], h)
         qh, kh, vh = attn._split(q), attn._split(k), attn._split(v)
-        o = attn._inner_attention(qh, kh, vh)
+        with jax.named_scope("attn"):
+            o = attn._inner_attention(qh, kh, vh)
         b, nh, t, hd = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
-        x = x + self._out_proj(params["attn"], o)
-        return self._mlp(params, x), kh, vh
+        with jax.named_scope("dense"):
+            x = x + self._out_proj(params["attn"], o)
+            out = self._mlp(params, x)
+        return out, kh, vh
 
     def decode_step(self, params, x, cache_k, cache_v, t):
         """One-token decode: ``x`` is (B, 1, dim), caches are

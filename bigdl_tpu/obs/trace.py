@@ -17,6 +17,44 @@ stack nested wall-clock spans:
 Off by default: when ``BIGDL_TRACE_DIR`` is unset, callers get the
 shared :data:`NULL_TRACER` whose ``span()`` returns one reusable no-op
 context manager — no allocation, no clock reads, no device syncs.
+
+**One clock with the device.**  A recording tracer's live span also
+enters a ``jax.profiler.TraceAnnotation`` of the same name that carries
+the span's ``id`` (and ``step`` where the span has one), so a profiler
+session started by anyone holds the program's spans on the host plane
+of the same ``.xplane.pb`` as the chip's operations.  Retroactive spans
+(:meth:`Tracer.complete`) cannot be annotations; a reader puts them on
+the profiler's clock by the offset the live spans give (a span's
+``wall_time`` here against its annotation's start there, joined by
+``id``).  This module is the only place in ``bigdl_tpu`` that makes a
+``TraceAnnotation``.
+
+**The trainer's span names** (``optim/optimizer.py``, shared by
+``DistriOptimizer``; ``native.PrefetchIterator``), each with ``step=``;
+the serving engine's are listed in ``serving/spans.py``:
+
+=================  =====  ==================================================
+name               kind   covers
+=================  =====  ==================================================
+``iteration``      live   one turn of the loop, from the batch in hand to
+                          the end trigger
+``batch_prep``     live   ``_prepare_batch`` of one host batch
+``device_put``     live   the call of ``_put_batch`` (returns when the copy
+                          is enqueued)
+``step_dispatch``  live   the call of the jitted train step
+``loss_readback``  live   ``float(loss)`` / ``bool(ok)`` of a dispatched
+                          step: the loop's thread waiting for the chip
+``data_wait``      retro  the loop top blocked on the batch iterator
+``input_prefetch`` retro  fetch + prepare + put of the next batch while
+                          the step is in flight (double buffer)
+``computing``      retro  dispatch to the loss on the host
+``feed.h2d``       retro  ``_put_batch``'s start until the batch is ready
+                          on every chip (``bytes=``, ``chips=``); closed by
+                          a waiter thread, never by the loop's
+``feed.gather``    retro  the prefetch thread producing one batch (the
+                          native row gather)
+``validation``, ``checkpoint``, ``build_train_step``  live, as named
+=================  =====  ==================================================
 """
 
 from __future__ import annotations
@@ -107,6 +145,9 @@ class NullTracer:
     def span(self, name, **attrs):
         return _NULL_SPAN
 
+    def add_attrs(self, span_id, **attrs):
+        pass
+
     def event(self, name, **attrs):
         pass
 
@@ -152,9 +193,18 @@ class Tracer:
                 f"{next(Tracer._FILE_SEQ)}")
         self.trace_path = os.path.join(trace_dir, stem + ".trace.json")
         self.jsonl_path = os.path.join(trace_dir, stem + ".events.jsonl")
+        # the profiler's annotation, looked up once a tracer (the null
+        # tracer never gets here, so tracing off makes no annotation)
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self._lock = threading.Lock()
         self._ids = itertools.count(1)
         self._events: list = []
+        # structured records not yet written: serialised at flush()
+        self._unwritten: list = []
+        # attributes of the spans now open, by span id (add_attrs)
+        self._open: dict = {}
         self._tids: dict = {}
         self._closed = False
         # flight recorder: the last `ring_size` structured records stay
@@ -187,20 +237,18 @@ class Tracer:
             return tid
 
     def _record(self, chrome_ev: dict, jsonl_rec: dict = None):
-        line = None
         if jsonl_rec is not None:
             # every structured record carries its origin: the aggregator
             # groups shards and tags merged spans by (host, pid)
             jsonl_rec["host"] = self.host_id
             jsonl_rec["pid"] = self.pid
-            line = json.dumps(jsonl_rec, default=str) + "\n"
         with self._lock:
             if self._closed:
                 return
             self._events.append(chrome_ev)
-            if line is not None:
+            if jsonl_rec is not None:
                 self._recent.append(jsonl_rec)
-                self._jsonl.write(line)
+                self._unwritten.append(jsonl_rec)
 
     def recent(self) -> list:
         """The flight-recorder ring: the newest records (oldest first),
@@ -217,19 +265,29 @@ class Tracer:
     # ------------------------------------------------------------------ API
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Timed nested span; yields its deterministic span id."""
+        """Timed nested span; yields its deterministic span id.  The
+        same extent goes into a running profiler session as an
+        annotation named alike, with the span's id."""
         sid = next(self._ids)
         parent = _CURRENT.get()
         token = _CURRENT.set(sid)
         ident = _push_phase(name)
         tid = self._tid()
+        self._open[sid] = attrs
+        if "step" in attrs:
+            ann = self._annotation(name, id=sid, step=attrs["step"])
+        else:
+            ann = self._annotation(name, id=sid)
         t0 = time.perf_counter()
+        ann.__enter__()
         try:
             yield sid
         finally:
+            ann.__exit__(None, None, None)
+            dur = time.perf_counter() - t0
             _CURRENT.reset(token)
             _pop_phase(ident)
-            dur = time.perf_counter() - t0
+            del self._open[sid]
             self._record(
                 {"name": name, "ph": "X", "ts": self._ts_us(t0),
                  "dur": round(dur * 1e6, 3), "pid": self.pid, "tid": tid,
@@ -237,6 +295,11 @@ class Tracer:
                 {"kind": "span", "name": name, "id": sid, "parent": parent,
                  "tid": tid, "wall_time": self._wall(t0),
                  "dur_s": round(dur, 9), "attrs": attrs})
+
+    def add_attrs(self, span_id, **attrs):
+        """Attributes of an open span that are known only inside it
+        (how many requests an admission admitted)."""
+        self._open[span_id].update(attrs)
 
     def event(self, name: str, **attrs):
         """Instant (zero-duration) structured event."""
@@ -271,12 +334,17 @@ class Tracer:
                       "pid": self.pid, "tid": 0, "args": values})
 
     def flush(self):
-        """Write the full Chrome trace JSON (atomic replace) and flush
-        the JSONL stream.  Safe to call repeatedly; the trace file is
-        valid after every flush."""
+        """Write the full Chrome trace JSON (atomic replace) and append
+        the structured records made since the last flush to the JSONL
+        stream.  Safe to call repeatedly; the trace file is valid after
+        every flush."""
         with self._lock:
             events = list(self._events)
             if not self._jsonl.closed:
+                self._jsonl.writelines(
+                    json.dumps(rec, default=str) + "\n"
+                    for rec in self._unwritten)
+                self._unwritten.clear()
                 self._jsonl.flush()
         doc = {"traceEvents": events, "displayTimeUnit": "ms",
                "otherData": {"pid": self.pid, "host_id": self.host_id,

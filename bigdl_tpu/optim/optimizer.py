@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import logging
 import os
+import queue
+import threading
 import time
 from functools import partial
 from typing import Optional, Sequence
@@ -33,6 +35,51 @@ def _jnp():
     import jax.numpy as jnp
 
     return jnp
+
+
+class _H2DWaiter:
+    """Closes the ``feed.h2d`` spans of a traced run off the loop's
+    thread.  ``jax.device_put`` returns when the copy is enqueued; this
+    thread blocks on the arrays and records, with ``tracer.complete``,
+    the put's start until they are ready on every chip.  The loop only
+    hands the arrays over, so a traced step is pipelined as an untraced
+    one is.  It exists only while the tracer records."""
+
+    def __init__(self, tracer):
+        self._tracer = tracer
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, name="bigdl-h2d-waiter", daemon=True)
+        self._thread.start()
+
+    def watch(self, t_put, arrays, step):
+        self._queue.put((t_put, arrays, step))
+
+    def _run(self):
+        import jax
+
+        while True:
+            item = self._queue.get()
+            if item is None:
+                return
+            t_put, arrays, step = item
+            try:
+                jax.block_until_ready(arrays)
+            except Exception:  # noqa: BLE001 — tracing must not end a run
+                log.debug("feed.h2d: batch of step %s not awaited", step,
+                          exc_info=True)
+                continue
+            dur = time.perf_counter() - t_put
+            leaves = jax.tree.leaves(arrays)
+            self._tracer.complete(
+                "feed.h2d", t_put, dur, step=step,
+                bytes=int(sum(a.nbytes for a in leaves)),
+                chips=len(leaves[0].sharding.device_set) if leaves else 0)
+
+    def close(self):
+        """Record what is still in flight, then end the thread."""
+        self._queue.put(None)
+        self._thread.join(timeout=60.0)
 
 
 class _GradClipper:
@@ -652,6 +699,7 @@ class LocalOptimizer(BaseOptimizer):
         # reads a device value, so enabling obs adds zero per-step
         # host-device synchronizations either way
         tracer = self._obs_tracer = obs.get_tracer()
+        self._h2d_waiter = _H2DWaiter(tracer) if tracer.enabled else None
         self._obs_runtime = obs.get_runtime() if obs.active() else None
         # goodput ledger (obs/goodput.py): interval stamps ride the
         # span boundaries below — the shared no-op object when obs is
@@ -688,40 +736,42 @@ class LocalOptimizer(BaseOptimizer):
         from bigdl_tpu.resilience import elastic as _elastic
 
         self._elastic_session = _elastic.ElasticSession.from_config()
-
-        model = self.model
-        model.training()
-
-        pvar = self._init_params()
-        # copy model/optimizer state before the first (donating) step so
-        # the model and any pre-existing opt.state never alias deleted
-        # buffers; after that, opt.state tracks the step outputs (only an
-        # exception *during* a step can catch it transiently stale)
-        copy = lambda t: jax.tree.map(
-            lambda a: a.copy() if hasattr(a, "copy") else a, t
-        )
-        mod_state = copy(model.state())
-        opt = self.optim_method
-        opt_state = copy(self._init_opt_state(pvar))
-        opt.state = opt_state
-        # the build itself is traced; the returned step is wrapped so
-        # first-call (trace+compile) vs cached-dispatch timing feeds the
-        # runtime profile (obs/runtime.py)
-        with tracer.span("build_train_step"):
-            train_step = self._build_train_step()
-        if self._obs_runtime is not None:
-            train_step = obs.instrument_jit(
-                train_step, "train_step", stats=self._obs_runtime,
-                tracer=tracer, ledger=self._obs_ledger)
-
-        base_key = jax.random.key(1234)
-        wall_start = time.time()
-        records_total = 0
-        stop = False
         from bigdl_tpu.utils.profiler import StepProfiler
 
         profiler = StepProfiler()
+        # everything from here on is undone by the finally below, so a
+        # failure while the step is being built leaves no preemption
+        # listener (and no waiter thread) behind
         try:
+            model = self.model
+            model.training()
+
+            pvar = self._init_params()
+            # copy model/optimizer state before the first (donating) step so
+            # the model and any pre-existing opt.state never alias deleted
+            # buffers; after that, opt.state tracks the step outputs (only an
+            # exception *during* a step can catch it transiently stale)
+            copy = lambda t: jax.tree.map(
+                lambda a: a.copy() if hasattr(a, "copy") else a, t
+            )
+            mod_state = copy(model.state())
+            opt = self.optim_method
+            opt_state = copy(self._init_opt_state(pvar))
+            opt.state = opt_state
+            # the build itself is traced; the returned step is wrapped so
+            # first-call (trace+compile) vs cached-dispatch timing feeds the
+            # runtime profile (obs/runtime.py)
+            with tracer.span("build_train_step"):
+                train_step = self._build_train_step()
+            if self._obs_runtime is not None:
+                train_step = obs.instrument_jit(
+                    train_step, "train_step", stats=self._obs_runtime,
+                    tracer=tracer, ledger=self._obs_ledger)
+
+            base_key = jax.random.key(1234)
+            wall_start = time.time()
+            records_total = 0
+            stop = False
             return self._optimize_loop(
                 model, pvar, mod_state, opt, opt_state, train_step,
                 base_key, wall_start, records_total, stop, profiler,
@@ -746,6 +796,9 @@ class LocalOptimizer(BaseOptimizer):
                 # no lingering non-daemon worker thread per optimizer
                 ex.shutdown(wait=True)
                 self._ckpt_executor = None
+            if self._h2d_waiter is not None:
+                self._h2d_waiter.close()
+                self._h2d_waiter = None
             # export the observability artifacts LAST so the snapshot
             # sees the final counter values (incl. any failure recorded
             # by the flush above); off = no-op
@@ -812,7 +865,10 @@ class LocalOptimizer(BaseOptimizer):
         #                 health_dev_or_None)]
 
         def resolve(n, loss_dev, ok_dev, bs, t0, health_dev=None):
-            loss_val = float(loss_dev)
+            # the loop's thread waits for the chip here and nowhere else
+            with tracer.span("loss_readback", step=n):
+                loss_val = float(loss_dev)
+                ok_val = bool(ok_dev)
             # in pipelined steady state this spans dispatch -> observed
             # completion (~ device step time + one iteration's host work)
             dt = time.perf_counter() - t0
@@ -842,13 +898,13 @@ class LocalOptimizer(BaseOptimizer):
                 # localization IS the point of that fetch.  Runs before
                 # the skip-escalation below so a NonFiniteStepError
                 # never races the layer attribution out of the trace.
-                monitor.on_step(n, health_dev, bool(ok_dev), loss_val)
+                monitor.on_step(n, health_dev, ok_val, loss_val)
             if self.train_summary is not None:
                 self.train_summary.add_scalar("Loss", loss_val, n)
                 self.train_summary.add_scalar(
                     "Throughput",
                     bs / max(1e-9, time.perf_counter() - t0), n)
-            if not bool(ok_dev):
+            if not ok_val:
                 # non-finite grads/loss: the guarded step already passed
                 # weights/opt-state through unchanged — count the skip,
                 # escalate after max_nonfinite consecutive ones
@@ -888,6 +944,20 @@ class LocalOptimizer(BaseOptimizer):
             while pending:
                 resolve(*pending.pop(0))
 
+        h2d = self._h2d_waiter
+
+        def put_batch(inp, tgt, step_tag):
+            """``_put_batch`` under its timer and span; a traced run
+            also hands the arrays to the waiter that closes
+            ``feed.h2d`` when they are ready on the chips."""
+            t_put = time.perf_counter() if h2d is not None else 0.0
+            with self.metrics.timer("put batch time"), \
+                    tracer.span("device_put", step=step_tag):
+                put = self._put_batch(inp, tgt)
+            if h2d is not None:
+                h2d.watch(t_put, put, step_tag)
+            return put
+
         while not stop:
             epoch = self.state["epoch"]
             epoch_start = time.time()
@@ -895,7 +965,6 @@ class LocalOptimizer(BaseOptimizer):
             # the chip runs the current step (native.PrefetchIterator)
             from bigdl_tpu.native import PrefetchIterator
 
-            batches = iter(PrefetchIterator(self.dataset.data(train=True)))
             batch_exhausted = False
             # mid-epoch resume (emergency / iteration-trigger
             # checkpoint): the saved neval is this many batches into the
@@ -903,6 +972,11 @@ class LocalOptimizer(BaseOptimizer):
             # the uninterrupted run exactly (resilience/elastic.py)
             skip, self._pending_fast_forward = \
                 self._pending_fast_forward, 0
+            # first_step: the step the epoch's first batch trains, for
+            # the worker's feed.gather spans
+            batches = iter(PrefetchIterator(
+                self.dataset.data(train=True),
+                first_step=self.state["neval"] - skip))
             if skip > 0:
                 log.info("mid-epoch resume: fast-forwarding %d batches "
                          "to iter %d", skip, self.state["neval"])
@@ -933,9 +1007,7 @@ class LocalOptimizer(BaseOptimizer):
                         note_stream()
                     return None
                 p_inp, p_tgt = prepared
-                with self.metrics.timer("put batch time"), \
-                        tracer.span("device_put", step=step_tag):
-                    inp_d, tgt_d = self._put_batch(p_inp, p_tgt)
+                inp_d, tgt_d = put_batch(p_inp, p_tgt, step_tag)
                 return p_inp, p_tgt, inp_d, tgt_d
 
             def _prefetch(step_tag):
@@ -1027,9 +1099,7 @@ class LocalOptimizer(BaseOptimizer):
                             action = self._fault_injector.on_step(n)
                             if action == "nan_grad":
                                 inp = self._fault_injector.poison_batch(inp)
-                        with self.metrics.timer("put batch time"), \
-                                tracer.span("device_put", step=n):
-                            inp_d, tgt_d = self._put_batch(inp, tgt)
+                        inp_d, tgt_d = put_batch(inp, tgt, n)
                     profiler.step()
                     rng = jax.random.fold_in(base_key, n)
                     t0 = time.perf_counter()

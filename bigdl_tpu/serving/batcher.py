@@ -50,6 +50,8 @@ class ServeRequest:
     t_first: Optional[float] = None   # first generated token (TTFT)
     t_done: Optional[float] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # perf_counter() where the engine appended each of ``tokens``
+    token_times: List[float] = dataclasses.field(default_factory=list)
     result: Optional[np.ndarray] = None  # classifier output row(s)
     error: Optional[str] = None
     # request-trace context (obs.reqtrace.RequestTraceContext) when the
@@ -180,8 +182,19 @@ class RequestQueue:
         try:
             first = self._buf.get(timeout=max(1e-4, timeout))
         except TimeoutError:
-            self.depth()
-            return out
+            first = None
+            if self._source.backlog():
+                # submitted, and the producer thread has not moved it
+                # into the buffer yet: it is on its way, so wait for it
+                # rather than report an empty queue (a pump or a drain
+                # right after a submit would otherwise miss it)
+                try:
+                    first = self._buf.get(timeout=0.05)
+                except TimeoutError:
+                    pass
+            if first is None:
+                self.depth()
+                return out
         if first is not None:
             out.append(first)
         while len(out) < max_n:

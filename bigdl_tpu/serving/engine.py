@@ -94,7 +94,11 @@ def paged_decode_math(children, n_layer, page_size, params, qparams,
     the cached ``decode_attn`` tuner site dispatch the flash-decode
     fused/Pallas kernels per (shape, dtype, platform); ``tables`` may
     be the engine's used-page prefix bucket rather than the full table
-    width (same mask contract either way)."""
+    width (same mask contract either way).
+
+    The ``jax.named_scope`` blocks (``kv_write``, ``attn``, ``dense``,
+    ``sample``) are metadata only: they name the step's operations in a
+    profiler trace and in the HLO, and change no math."""
     import jax
     import jax.numpy as jnp
 
@@ -120,14 +124,15 @@ def paged_decode_math(children, n_layer, page_size, params, qparams,
         pa = p["attn"]
         qb = None if qparams is None else qparams[f"h{i}"]
         h, _ = block._children["ln1"].apply(p["ln1"], {}, x)
-        if qb is None:
-            q, k, v = block._project_qkv(pa, h)
-        else:
-            q = mm(h, pa["wq"], qb["attn"]["wq"])
-            k = mm(h, pa["wk"], qb["attn"]["wk"])
-            v = mm(h, pa["wv"], qb["attn"]["wv"])
-            if pa.get("bq") is not None:
-                q, k, v = q + pa["bq"], k + pa["bk"], v + pa["bv"]
+        with jax.named_scope("dense"):
+            if qb is None:
+                q, k, v = block._project_qkv(pa, h)
+            else:
+                q = mm(h, pa["wq"], qb["attn"]["wq"])
+                k = mm(h, pa["wk"], qb["attn"]["wk"])
+                v = mm(h, pa["wv"], qb["attn"]["wv"])
+                if pa.get("bq") is not None:
+                    q, k, v = q + pa["bq"], k + pa["bk"], v + pa["bv"]
 
         def split(t):
             return t.reshape(bsz, 1, heads, head_dim).transpose(0, 2, 1, 3)
@@ -138,41 +143,47 @@ def paged_decode_math(children, n_layer, page_size, params, qparams,
         pidx = jnp.take_along_axis(
             tables, (lengths // page_size)[:, None], axis=1)[:, 0]
         off = lengths % page_size
-        kp = kp.at[i, pidx, :, off, :].set(kh.astype(kp.dtype))
-        vp = vp.at[i, pidx, :, off, :].set(vh.astype(vp.dtype))
-        o = paged_decode_attention(
-            qh[:, :, 0, :], kp[i], vp[i], tables, lengths,
-            page_size=page_size, scale=scale, impl=attn_impl,
-            block_pages=attn_block_pages)       # (B, H, Dh)
+        with jax.named_scope("kv_write"):
+            kp = kp.at[i, pidx, :, off, :].set(kh.astype(kp.dtype))
+            vp = vp.at[i, pidx, :, off, :].set(vh.astype(vp.dtype))
+        with jax.named_scope("attn"):
+            o = paged_decode_attention(
+                qh[:, :, 0, :], kp[i], vp[i], tables, lengths,
+                page_size=page_size, scale=scale, impl=attn_impl,
+                block_pages=attn_block_pages)       # (B, H, Dh)
         o = o.reshape(bsz, 1, heads * head_dim)
-        y = mm(o, pa["wo"], None if qb is None else qb["attn"]["wo"])
-        if psum is not None:
-            y = psum(y)
-        if pa.get("bo") is not None:
-            y = y + pa["bo"]
+        with jax.named_scope("dense"):
+            y = mm(o, pa["wo"], None if qb is None else qb["attn"]["wo"])
+            if psum is not None:
+                y = psum(y)
+            if pa.get("bo") is not None:
+                y = y + pa["bo"]
         x = x + y
         # MLP (pre-LN): bias of the row-parallel fc1 is local, the
         # col-parallel fc2's bias is added once, after the reduction
         h, _ = block._children["ln2"].apply(p["ln2"], {}, x)
-        h = mm(h, p["fc1"]["weight"],
-               None if qb is None else qb["fc1"]) + p["fc1"]["bias"]
-        h = jax.nn.gelu(h)
-        h = mm(h, p["fc2"]["weight"],
-               None if qb is None else qb["fc2"])
-        if psum is not None:
-            h = psum(h)
-        if p["fc2"].get("bias") is not None:
-            h = h + p["fc2"]["bias"]
+        with jax.named_scope("dense"):
+            h = mm(h, p["fc1"]["weight"],
+                   None if qb is None else qb["fc1"]) + p["fc1"]["bias"]
+            h = jax.nn.gelu(h)
+            h = mm(h, p["fc2"]["weight"],
+                   None if qb is None else qb["fc2"])
+            if psum is not None:
+                h = psum(h)
+            if p["fc2"].get("bias") is not None:
+                h = h + p["fc2"]["bias"]
         x = x + h
     h, _ = children["ln_f"].apply(params["ln_f"], {}, x)
-    logits = mm(h, params["head"]["weight"],
-                None if qparams is None else qparams["head"])[:, 0, :]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    sampled = jax.random.categorical(
-        key, logits / jnp.maximum(temps, 1e-6)[:, None],
-        axis=-1).astype(jnp.int32)
-    nxt = jnp.where(temps > 0.0, sampled, greedy)
-    nxt = jnp.where(active, nxt, 0)
+    with jax.named_scope("dense"):
+        logits = mm(h, params["head"]["weight"],
+                    None if qparams is None else qparams["head"])[:, 0, :]
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sampled = jax.random.categorical(
+            key, logits / jnp.maximum(temps, 1e-6)[:, None],
+            axis=-1).astype(jnp.int32)
+        nxt = jnp.where(temps > 0.0, sampled, greedy)
+        nxt = jnp.where(active, nxt, 0)
     return kp, vp, nxt
 
 
@@ -289,7 +300,7 @@ class LMEngine:
                 jnp.asarray, self.params,
                 is_leaf=lambda x: x is None or hasattr(x, "shape"))
         self._prefill_fns: dict = {}
-        from bigdl_tpu import obs
+        self._tracer = obs.NULL_TRACER  # pump() looks it up each cycle
         from bigdl_tpu.obs import prof as _obs_prof
 
         # continuous profiler: starts with the engine when
@@ -468,23 +479,27 @@ class LMEngine:
             x = jnp.take(params["wte"]["weight"], prompt, axis=0)
             x = x + params["wpe"]["weight"][:bucket][None]
             for i in range(n_layer):
+                # the block's prefill names its own attn and dense parts
                 x, kh, vh = children[f"h{i}"].prefill(params[f"h{i}"], x)
-                for j in range(n_write):
-                    kp = kp.at[i, pages[j]].set(
-                        kh[0, :, j * page_size:(j + 1) * page_size,
-                           :].astype(kp.dtype))
-                    vp = vp.at[i, pages[j]].set(
-                        vh[0, :, j * page_size:(j + 1) * page_size,
-                           :].astype(vp.dtype))
+                with jax.named_scope("kv_write"):
+                    for j in range(n_write):
+                        kp = kp.at[i, pages[j]].set(
+                            kh[0, :, j * page_size:(j + 1) * page_size,
+                               :].astype(kp.dtype))
+                        vp = vp.at[i, pages[j]].set(
+                            vh[0, :, j * page_size:(j + 1) * page_size,
+                               :].astype(vp.dtype))
             h = lax.dynamic_slice(x, (0, t0 - 1, 0), (1, 1, dim))
             h, _ = children["ln_f"].apply(params["ln_f"], {}, h)
-            logits, _ = children["head"].apply(params["head"], {}, h)
+            with jax.named_scope("dense"):
+                logits, _ = children["head"].apply(params["head"], {}, h)
             logits = logits[:, 0, :]
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            sampled = jax.random.categorical(
-                key, logits / jnp.maximum(temp, 1e-6),
-                axis=-1).astype(jnp.int32)
-            first = jnp.where(temp > 0.0, sampled, greedy)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                sampled = jax.random.categorical(
+                    key, logits / jnp.maximum(temp, 1e-6),
+                    axis=-1).astype(jnp.int32)
+                first = jnp.where(temp > 0.0, sampled, greedy)
             return kp, vp, first[0]
 
         fn = jax.jit(prefill, donate_argnums=(1, 2))
@@ -560,20 +575,29 @@ class LMEngine:
         if len(incoming) < wanted:
             incoming.extend(
                 self.queue.take(wanted - len(incoming), timeout=wait_s))
+        if not incoming:
+            return 0  # an idle engine's empty polls leave no span
         admitted = 0
-        for req in incoming:
-            slot = None
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    slot = i
-                    break
-            # pages are allocated for the PROMPT, not the (pow2) compile
-            # bucket — the bucket's padded tail writes to the trash page
-            if slot is None or not self.cache.can_admit(len(req.payload)):
-                self._stash.append(req)  # head-of-line, retried first
-                continue
-            self._prefill_into(slot, req, self._bucket(len(req.payload)))
-            admitted += 1
+        tracer = self._tracer
+        with tracer.span(spans.SPAN_ADMISSION, step=self._steps,
+                         offered=len(incoming)) as span_id:
+            for req in incoming:
+                slot = None
+                for i, s in enumerate(self._slots):
+                    if s is None:
+                        slot = i
+                        break
+                # pages are allocated for the PROMPT, not the (pow2)
+                # compile bucket — the bucket's padded tail writes to
+                # the trash page
+                if slot is None or \
+                        not self.cache.can_admit(len(req.payload)):
+                    self._stash.append(req)  # head-of-line, retried first
+                    continue
+                self._prefill_into(slot, req,
+                                   self._bucket(len(req.payload)))
+                admitted += 1
+            tracer.add_attrs(span_id, admitted=admitted)
         return admitted
 
     def _prefill_into(self, slot: int, req: ServeRequest, bucket: int):
@@ -588,13 +612,16 @@ class LMEngine:
         prompt = np.zeros((1, bucket), np.int32)
         prompt[0, :t0] = req.payload
         self._key, sub = jax.random.split(self._key)
-        kp, vp, first = self._prefill_fn(bucket)(
-            self.params, self.cache.kp, self.cache.vp,
-            jnp.asarray(prompt), t0, jnp.asarray(page_arg),
-            float(req.temperature), sub)
-        self.cache.kp, self.cache.vp = kp, vp
-        self.cache.lengths[slot] = t0
-        tok = int(first)
+        tracer = self._tracer
+        with tracer.span(spans.SPAN_STEP_PREFILL, step=self._steps,
+                         bucket=bucket, prompt_len=t0, request=req.id):
+            kp, vp, first = self._prefill_fn(bucket)(
+                self.params, self.cache.kp, self.cache.vp,
+                jnp.asarray(prompt), t0, jnp.asarray(page_arg),
+                float(req.temperature), sub)
+            self.cache.kp, self.cache.vp = kp, vp
+            self.cache.lengths[slot] = t0
+            tok = int(first)
         if req.trace is not None:
             req._tr_admits.append(
                 {"t": t_admit, "dur": time.monotonic() - t_admit,
@@ -604,6 +631,7 @@ class LMEngine:
             self._lat.labels(engine="lm", kind="ttft").observe(
                 req.t_first - req.t_submit)
         req.tokens.append(tok)
+        req.token_times.append(time.perf_counter())
         self._tokens_total += 1
         self._tokens_counter.inc()
         if self._t_first_work is None:
@@ -611,11 +639,8 @@ class LMEngine:
         self._order += 1
         act = _Active(req, req.max_new_tokens - 1, tok, t0, self._order)
         self._slots[slot] = act
-        from bigdl_tpu import obs
-
-        obs.get_tracer().event(spans.EVENT_ADMIT, slot=slot,
-                               request=req.id, prompt_len=t0,
-                               bucket=bucket)
+        tracer.event(spans.EVENT_ADMIT, slot=slot, request=req.id,
+                     prompt_len=t0, bucket=bucket)
         if act.remaining <= 0 or tok == self.eos_id:
             self._complete(slot)
 
@@ -642,8 +667,6 @@ class LMEngine:
         self._preempt_counter.inc()
         if req.trace is not None:
             req._tr_preempts.append(time.monotonic())
-        from bigdl_tpu import obs
-
         obs.get_tracer().event(spans.EVENT_PREEMPT, slot=slot,
                                request=req.id, owed=act.remaining)
         return slot
@@ -676,7 +699,8 @@ class LMEngine:
             engine="lm", status="error" if error else "ok").inc()
         self.completed.append(
             {"id": req.id, "e2e_s": e2e, "ttft_s": req.ttft_s,
-             "tokens": n_tok})
+             "tokens": n_tok,
+             "itl_s": np.diff(np.asarray(req.token_times, np.float64))})
         if self.slo_s > 0:
             self._slo_window.append(1.0 if e2e <= self.slo_s else 0.0)
             self._slo_gauge.set(
@@ -731,55 +755,58 @@ class LMEngine:
         import jax
         import jax.numpy as jnp
 
-        active_slots = [i for i, s in enumerate(self._slots)
-                        if s is not None]
-        if not active_slots:
+        if not self.active_count():
             return False
-        # grow pages where the next position crosses a page boundary;
-        # exhaustion preempts the youngest request (possibly this one)
-        for slot in list(active_slots):
-            if self._slots[slot] is None:
-                continue
-            while self.cache.needs_growth(slot):
-                if self.cache.grow(slot):
-                    continue
-                victim = self._preempt_youngest()
-                if victim is None or victim == slot:
-                    break
-        active_slots = [i for i, s in enumerate(self._slots)
-                        if s is not None]
-        if not active_slots:
-            return False
-        tokens = np.zeros((self.max_batch,), np.int32)
-        temps = np.zeros((self.max_batch,), np.float32)
-        active = np.zeros((self.max_batch,), bool)
-        for i in active_slots:
-            tokens[i] = self._slots[i].last_token
-            temps[i] = self._slots[i].req.temperature
-            active[i] = True
         # used-page prefix bucket (pow2): even the dense baseline stops
         # gathering the empty pool; each bucket is one compiled variant
         from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
                                                     used_page_bucket)
 
-        if self.decode_bucket:
-            longest = max(int(self.cache.lengths[i])
-                          for i in active_slots)
-            bucket = used_page_bucket(longest, self.page_size,
-                                      self.cache.max_pages_per_slot)
-        else:
-            bucket = self.cache.max_pages_per_slot
-        self._last_bucket = bucket
-        impl = self._decode_impl_for(bucket)
-        tables, lengths = self.cache.device_tables(pages=bucket)
-        self._key, sub = jax.random.split(self._key)
+        tracer = self._tracer
+        step = self._steps
+        with tracer.span(spans.SPAN_STEP_PREP, step=step) as span_id:
+            # grow pages where the next position crosses a page
+            # boundary; exhaustion preempts the youngest request
+            # (possibly this one)
+            for slot in range(self.max_batch):
+                if self._slots[slot] is None:
+                    continue
+                while self.cache.needs_growth(slot):
+                    if self.cache.grow(slot):
+                        continue
+                    victim = self._preempt_youngest()
+                    if victim is None or victim == slot:
+                        break
+            active_slots = [i for i, s in enumerate(self._slots)
+                            if s is not None]
+            if not active_slots:
+                return False
+            tokens = np.zeros((self.max_batch,), np.int32)
+            temps = np.zeros((self.max_batch,), np.float32)
+            active = np.zeros((self.max_batch,), bool)
+            for i in active_slots:
+                tokens[i] = self._slots[i].last_token
+                temps[i] = self._slots[i].req.temperature
+                active[i] = True
+            if self.decode_bucket:
+                longest = max(int(self.cache.lengths[i])
+                              for i in active_slots)
+                bucket = used_page_bucket(longest, self.page_size,
+                                          self.cache.max_pages_per_slot)
+            else:
+                bucket = self.cache.max_pages_per_slot
+            self._last_bucket = bucket
+            impl = self._decode_impl_for(bucket)
+            tables, lengths = self.cache.device_tables(pages=bucket)
+            self._key, sub = jax.random.split(self._key)
+            tracer.add_attrs(span_id, bucket=bucket,
+                             active=len(active_slots))
         t0 = time.perf_counter()
         # a LIVE span around the batched decode dispatch+resolve (not a
         # retroactive reqtrace hop): the continuous profiler attributes
         # samples landing here to the decode phase by name
-        with obs.get_tracer().span(spans.SPAN_STEP_DECODE,
-                                   bucket=bucket,
-                                   active=len(active_slots)):
+        with tracer.span(spans.SPAN_STEP_DECODE, bucket=bucket,
+                         active=len(active_slots)):
             kp, vp, nxt = self._step_fn(
                 self.params, self.cache.kp, self.cache.vp, tables,
                 lengths, jnp.asarray(tokens), jnp.asarray(temps),
@@ -787,39 +814,45 @@ class LMEngine:
             self.cache.kp, self.cache.vp = kp, vp
             nxt = np.asarray(nxt)
         step_ms = (time.perf_counter() - t0) * 1000.0
-        self._steps += 1
-        self._decode_ms_sum += step_ms
-        self._decode_ms_gauge.set(self._decode_ms_sum / self._steps)
-        kv_item = self.cache.dtype.itemsize
-        step_bytes = self._weight_bytes + self.n_layer * decode_hbm_bytes(
-            "dense" if impl == "dense" else "fused", self.max_batch,
-            self.n_head, self.head_dim, self.page_size, bucket, kv_item)
-        self._decode_bytes_gauge.set(step_bytes / len(active_slots))
-        self._occ_sum += len(active_slots) / self.max_batch
-        self._occ_gauge.set(self._occ_sum / self._steps)
-        for i in active_slots:
-            act = self._slots[i]
-            tok = int(nxt[i])
-            self.cache.lengths[i] += 1
-            act.last_token = tok
-            act.remaining -= 1
-            act.req.tokens.append(tok)
-            self._tokens_total += 1
-            self._tokens_counter.inc()
-            if act.remaining <= 0 or tok == self.eos_id:
-                self._complete(i)
-        try:
-            from bigdl_tpu.obs import server as obs_server
+        with tracer.span(spans.SPAN_STEP_EMIT, step=step):
+            self._steps += 1
+            self._decode_ms_sum += step_ms
+            self._decode_ms_gauge.set(self._decode_ms_sum / self._steps)
+            kv_item = self.cache.dtype.itemsize
+            step_bytes = self._weight_bytes + \
+                self.n_layer * decode_hbm_bytes(
+                    "dense" if impl == "dense" else "fused",
+                    self.max_batch, self.n_head, self.head_dim,
+                    self.page_size, bucket, kv_item)
+            self._decode_bytes_gauge.set(step_bytes / len(active_slots))
+            self._occ_sum += len(active_slots) / self.max_batch
+            self._occ_gauge.set(self._occ_sum / self._steps)
+            for i in active_slots:
+                act = self._slots[i]
+                tok = int(nxt[i])
+                self.cache.lengths[i] += 1
+                act.last_token = tok
+                act.remaining -= 1
+                act.req.tokens.append(tok)
+                act.req.token_times.append(time.perf_counter())
+                self._tokens_total += 1
+                self._tokens_counter.inc()
+                if act.remaining <= 0 or tok == self.eos_id:
+                    self._complete(i)
+            try:
+                from bigdl_tpu.obs import server as obs_server
 
-            obs_server.note_step(self._steps)
-        except Exception:  # noqa: BLE001 — telemetry must not kill serving
-            pass
+                obs_server.note_step(self._steps)
+            except Exception:  # noqa: BLE001 — telemetry must not kill serving
+                pass
         return True
 
     # ---------------------------------------------------------- driving
     def pump(self, wait_s: float = 0.0) -> bool:
         """One admission + decode cycle; True while there is work."""
         with self._lock:
+            # one look at the configuration a cycle; its spans share it
+            self._tracer = obs.get_tracer()
             self._admit(wait_s=wait_s if not self.active_count() else 0.0)
             stepped = self._step()
             return stepped or bool(self._stash) \
@@ -871,12 +904,16 @@ class LMEngine:
         e2e = [c["e2e_s"] for c in self.completed]
         ttft = [c["ttft_s"] for c in self.completed
                 if c["ttft_s"] is not None]
+        # gaps between consecutive tokens of one request, as stamped
+        # where the engine appends them (ServeRequest.token_times)
+        itl = np.concatenate([c["itl_s"] for c in self.completed]
+                             or [np.zeros((0,))])
         busy = None
         if self._t_first_work is not None and self._t_last_done:
             busy = self._t_last_done - self._t_first_work
 
         def pct(vals, q):
-            return float(np.percentile(vals, q)) if vals else None
+            return float(np.percentile(vals, q)) if len(vals) else None
 
         return {
             "requests": len(self.completed),
@@ -897,6 +934,7 @@ class LMEngine:
             "preemptions": int(self._preempt_counter._solo().value),
             "e2e_p50_s": pct(e2e, 50), "e2e_p99_s": pct(e2e, 99),
             "ttft_p50_s": pct(ttft, 50), "ttft_p99_s": pct(ttft, 99),
+            "itl_p50_s": pct(itl, 50), "itl_p95_s": pct(itl, 95),
             "admission": self.admission,
             "int8": self.int8,
             "tp": self.tp,

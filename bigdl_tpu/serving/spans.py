@@ -9,13 +9,40 @@ flags any ``tracer.span(...)`` / ``.event(...)`` / ``.complete(...)``
 call in ``bigdl_tpu/serving/`` (or in a module importing this one)
 whose first argument is a string literal.
 
-Two families:
+Three families:
 
 * ``SPAN_*`` — the per-request lifecycle hops of the distributed
   request trace (``obs/reqtrace.py``).  Each kept request trace is one
   set of these spans sharing a ``trace`` attribute; ``report.py``'s
   "request traces" section groups them by the hop key (the part after
   ``req.``) for p99 attribution.
+* ``SPAN_STEP_*`` / ``SPAN_ADMISSION`` — the LIVE spans of the
+  engine's thread, one set a ``pump`` cycle, on when the obs tracer is
+  (``BIGDL_TRACE_DIR``).  A recording tracer writes each into a running
+  profiler session too (obs/trace.py), so a chip's idle gap can be
+  named by the span it falls in.  The new four carry ``step=`` (the
+  engine's step count when the cycle began), so the spans of one cycle
+  share an identifier:
+
+  ======================  ==============================================
+  ``serve.admission``     the admission loop of ``_admit`` once there is
+                          a request to place (``offered=``,
+                          ``admitted=``); not ``serve.admit``, the point
+                          event of one request entering a slot
+  ``serve.prefill``       inside it: the jitted prefill call and the
+                          read-back of its first token (``bucket=``,
+                          ``prompt_len=``, ``request=``)
+  ``serve.prep``          ``_step`` from its top to the dispatch: page
+                          growth, preemption, host arrays, page tables
+                          to the device, the key split (``bucket=``,
+                          ``active=``)
+  ``serve.decode_step``   dispatch of the jitted step to its tokens on
+                          the host (``bucket=``, ``active=``; no
+                          ``step``: older than the others, and read as
+                          it is by the benchmark)
+  ``serve.emit``          after the read-back: token bookkeeping and
+                          stamps, ``_complete``, gauges
+  ======================  ==============================================
 * ``EVENT_*`` — point events the engine/simulator stamp regardless of
   request tracing.
 """
@@ -51,6 +78,14 @@ HOP_ORDER = ("queue", "placement", "retry", "prefill", "decode",
 #: reqtrace hop) so the continuous profiler (obs/prof.py) attributes
 #: decode-time samples to it
 SPAN_STEP_DECODE = "serve.decode_step"
+#: placing queued requests into free slots (contains SPAN_STEP_PREFILL)
+SPAN_ADMISSION = "serve.admission"
+#: one request's jitted prefill and the read-back of its first token
+SPAN_STEP_PREFILL = "serve.prefill"
+#: host work of a decode step before its dispatch
+SPAN_STEP_PREP = "serve.prep"
+#: host work of a decode step after its tokens are on the host
+SPAN_STEP_EMIT = "serve.emit"
 
 # ------------------------------------------------------------ point events
 #: a request entered a decode slot (engine admission)
@@ -75,6 +110,7 @@ def hop_key(span_name: str) -> str:
 
 __all__ = ["SPAN_ROUTE", "SPAN_PLACEMENT", "SPAN_RETRY", "SPAN_HANDOFF",
            "SPAN_QUEUE", "SPAN_PREFILL", "SPAN_PREEMPT", "SPAN_DECODE",
-           "SPAN_STEP_DECODE", "HOP_ORDER", "EVENT_ADMIT",
+           "SPAN_STEP_DECODE", "SPAN_ADMISSION", "SPAN_STEP_PREFILL",
+           "SPAN_STEP_PREP", "SPAN_STEP_EMIT", "HOP_ORDER", "EVENT_ADMIT",
            "EVENT_PREEMPT", "EVENT_SCENARIO", "EVENT_WEIGHT_SWAP",
            "EVENT_ROLLOUT_REJECT", "EVENT_ROLLOUT_DECISION", "hop_key"]

@@ -8,13 +8,15 @@ traces via ``jax.profiler`` — viewable in TensorBoard or Perfetto.
 
 Usage:
 
-    from bigdl_tpu.utils.profiler import trace, annotate
+    from bigdl_tpu.utils.profiler import trace
 
     with trace("/tmp/tb"):               # device + host trace
         optimizer.optimize()
 
-    with annotate("my-phase"):           # named region inside a trace
-        ...
+A named region inside a trace is a span of the obs tracer
+(``obs.get_tracer().span("my-phase")``, on when ``BIGDL_TRACE_DIR`` is
+set): a recording tracer writes each live span into the running
+profiler session too, so there is one way to name host time.
 
 Env hook: ``BIGDL_PROFILE=/dir`` makes the optimizers trace their first
 20 iterations automatically (compile excluded).
@@ -41,58 +43,6 @@ def trace(log_dir: str, create_perfetto_trace: bool = False):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-class _AnnotatedRegion:
-    """One region, two sinks: the ``jax.profiler`` TraceAnnotation (the
-    device/XLA timeline) and an obs tracer span (the Perfetto/JSONL
-    export) open and close together, under the SAME name — one code
-    path, no duplicate timers drifting apart."""
-
-    def __init__(self, name: str, attrs: dict):
-        self.name = name
-        self.attrs = attrs
-        self._ta = None
-        self._span = None
-
-    def __enter__(self):
-        import jax
-
-        from bigdl_tpu import obs
-
-        tracer = obs.get_tracer()
-        if tracer.enabled:
-            self._span = tracer.span(self.name, **self.attrs)
-            self._span.__enter__()
-        self._ta = jax.profiler.TraceAnnotation(self.name)
-        self._ta.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        self._ta.__exit__(*exc)
-        if self._span is not None:
-            self._span.__exit__(*exc)
-        return False
-
-    def __call__(self, fn):
-        # decorator form, like TraceAnnotation
-        import functools
-
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            with _AnnotatedRegion(self.name, self.attrs):
-                return fn(*args, **kwargs)
-
-        return wrapped
-
-
-def annotate(name: str, **attrs):
-    """Named region (shows up on the trace timeline); usable as context
-    manager or decorator, free when no trace is active.  The region
-    feeds BOTH ``jax.profiler`` traces and the obs span tracer (when
-    ``BIGDL_TRACE_DIR`` is set) under one name, so Perfetto span
-    exports and device profiles line up."""
-    return _AnnotatedRegion(name, attrs)
 
 
 class StepProfiler:

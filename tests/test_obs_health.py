@@ -2,8 +2,9 @@
 computed inside the jitted step, non-finite localization naming the
 planted layer in BOTH optimizers, the fetch-cadence / zero-overhead
 contract, the numerics anomaly detector, HLO-derived FLOPs + MFU, the
-profiler-annotate/span-tracer unification, and the health fan-out into
-report / flight bundle / TensorBoard."""
+and the health fan-out into report / flight bundle / TensorBoard.  (The
+profiler annotation and the span tracer became one path in
+obs/trace.py: tests/test_trace_one_clock.py.)"""
 
 import json
 import os
@@ -482,42 +483,6 @@ class TestHloCost:
         assert g(1) == 2
         assert stats.step_flops is None
         assert stats.compile_count == 1   # still a first-signature event
-
-
-# ----------------------------------------- profiler annotate unification
-class TestAnnotateUnification:
-    def test_annotate_records_obs_span(self, tmp_path, monkeypatch):
-        from bigdl_tpu.utils.profiler import annotate
-
-        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path))
-        obs.reset()
-        with annotate("my_region", step=3):
-            pass
-        recs = [r for r in obs.get_tracer().recent()
-                if r["name"] == "my_region"]
-        assert len(recs) == 1
-        assert recs[0]["kind"] == "span"
-        assert recs[0]["attrs"]["step"] == 3
-
-    def test_annotate_without_tracer_is_noop_passthrough(self):
-        from bigdl_tpu.utils.profiler import annotate
-
-        with annotate("untraced"):
-            pass    # no tracer configured: must not raise
-
-    def test_annotate_as_decorator(self, tmp_path, monkeypatch):
-        from bigdl_tpu.utils.profiler import annotate
-
-        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path))
-        obs.reset()
-
-        @annotate("decorated_region")
-        def f(a):
-            return a * 2
-
-        assert f(21) == 42
-        assert [r for r in obs.get_tracer().recent()
-                if r["name"] == "decorated_region"]
 
 
 # ------------------------------------------------- report / flight fan-out
