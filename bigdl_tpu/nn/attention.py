@@ -264,11 +264,12 @@ class TransformerBlock(_Composite):
             y = y + pa["bo"]
         return y
 
-    def prefill(self, params, x):
-        """Full-prefix block forward that ALSO returns the per-head
-        K/V (B, H, T, Dh) for a decode cache.  Attention math is the
-        identical projection + ``_inner_attention`` path apply() takes
-        (dropout off — decoding is inference)."""
+    def prefill_rows(self, params, x):
+        """Full-prefix block forward that ALSO returns K and V as the
+        projections produce them, ``(B, T, H*Dh)`` token rows — what a
+        token-major decode cache (serving/cache.py) stores.  Attention
+        math is the identical projection + ``_inner_attention`` path
+        apply() takes (dropout off — decoding is inference)."""
         import jax
 
         attn = self._children["attn"]
@@ -277,15 +278,22 @@ class TransformerBlock(_Composite):
         # profiler trace, no change to the math
         with jax.named_scope("dense"):
             q, k, v = self._project_qkv(params["attn"], h)
-        qh, kh, vh = attn._split(q), attn._split(k), attn._split(v)
         with jax.named_scope("attn"):
-            o = attn._inner_attention(qh, kh, vh)
+            o = attn._inner_attention(attn._split(q), attn._split(k),
+                                      attn._split(v))
         b, nh, t, hd = o.shape
         o = o.transpose(0, 2, 1, 3).reshape(b, t, nh * hd)
         with jax.named_scope("dense"):
             x = x + self._out_proj(params["attn"], o)
             out = self._mlp(params, x)
-        return out, kh, vh
+        return out, k, v
+
+    def prefill(self, params, x):
+        """:meth:`prefill_rows` with K/V split per head,
+        ``(B, H, T, Dh)`` — generate()'s contiguous cache."""
+        attn = self._children["attn"]
+        out, k, v = self.prefill_rows(params, x)
+        return out, attn._split(k), attn._split(v)
 
     def decode_step(self, params, x, cache_k, cache_v, t):
         """One-token decode: ``x`` is (B, 1, dim), caches are

@@ -807,14 +807,14 @@ def prewarm_decode_attn(b, h, d, *, page_size=16, maxp=4,
     import jax.numpy as jnp
 
     from bigdl_tpu.ops.decode_attention import paged_decode_attention
+    from bigdl_tpu.serving.cache import pool_shape
 
     rs = np.random.RandomState(seed)
     pool = int(num_pages or (b * maxp + 1))
     q = jnp.asarray(rs.randn(b, h, d).astype(np.float32)).astype(dtype)
-    kp = jnp.asarray(
-        rs.randn(pool, h, page_size, d).astype(np.float32)).astype(dtype)
-    vp = jnp.asarray(
-        rs.randn(pool, h, page_size, d).astype(np.float32)).astype(dtype)
+    shape = pool_shape(pool, page_size, h, d)
+    kp = jnp.asarray(rs.randn(*shape).astype(np.float32)).astype(dtype)
+    vp = jnp.asarray(rs.randn(*shape).astype(np.float32)).astype(dtype)
     lengths = jnp.asarray(
         rs.randint(1, maxp * page_size, (b,)).astype(np.int32))
     tables = jnp.asarray(

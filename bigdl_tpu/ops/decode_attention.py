@@ -1,13 +1,15 @@
 """Flash-decode over the paged KV cache — the serving hot path's kernel.
 
 PR 12's continuous-batching decode step ran its attention the naive
-way: ``gather_pages`` materialized a dense ``(B, H, max_pages*P, Dh)``
-K/V copy **per layer per step** (page gather + transpose + reshape),
-then full-width einsum attention masked the mostly-unallocated tail
-with ``-inf`` — pure wasted HBM bandwidth in a regime that is entirely
-memory-bound (one query token against a long scattered KV).  This
-module is the flash-decoding answer (the decode-side sibling of
-ops/attention.py's flash kernel):
+way: ``gather_pages`` materialized a dense per-slot K/V copy **per
+layer per step**, then full-width einsum attention masked the
+mostly-unallocated tail with ``-inf`` — pure wasted HBM bandwidth in a
+regime that is entirely memory-bound (one query token against a long
+scattered KV).  This module is the flash-decoding answer (the
+decode-side sibling of ops/attention.py's flash kernel).  All three
+bodies read the one cache layout serving/cache.py states: a layer's
+pool is ``(num_pages, P, H*Dh)``, token-major, and a head is a range
+of ``Dh`` lanes of a row:
 
 * ``impl="dense"`` — the PR 12 math, verbatim: gather + masked softmax
   einsum.  It is the **static baseline** the auto-tuner can never lose
@@ -18,15 +20,17 @@ ops/attention.py's flash kernel):
   ``block_pages`` pages per iteration), each block's scores are
   softmax-accumulated into a carried ``(m, l, acc)`` running state,
   and one final rescale produces the output — the gathered dense copy
-  (and its transpose materialization) never exists.  Runs everywhere
+  never exists.  Runs everywhere
   XLA runs, including inside the TP ``shard_map`` body on the
   head-sharded cache;
 * ``impl="pallas"`` — the true flash-decode TPU kernel: grid
-  ``(B, H, pages)`` with the page table and lengths as **scalar
+  ``(B, pages)`` with the page table and lengths as **scalar
   prefetch** so each program's BlockSpec index map DMAs exactly the
-  page the table names (trash-page contract below), ``(m, l, acc)``
-  carried in VMEM scratch across the page grid dimension, output
-  written on the final page.  The CPU backend (tests) runs it in
+  page the table names, all heads of it at once (one contiguous
+  ``(P, H*Dh)`` block; trash-page contract below), per-head
+  ``(m, l, acc)`` carried in VMEM scratch across the page grid
+  dimension, output written on the final page.  The CPU backend
+  (tests) runs it in
   the Pallas interpreter; an accelerator compiles it or fails.
 
 Mask contract (identical across impls, pinned by tests): position
@@ -100,25 +104,58 @@ def _mask_neg_inf(scores, pos, lengths):
 # --------------------------------------------------------------------------
 
 
-def _dense(q, kp, vp, tables, lengths, *, scale: float):
-    """Gather + masked softmax einsum — exactly the op sequence the
-    PR 12 ``paged_decode_math`` inlined, so the temperature-0 bit-match
-    contract vs ``generate()`` is preserved byte for byte."""
+def _head_scores(q, rows):
+    """``q·k`` per head: q ``(B, H, Dh)``, token rows ``(B, K, H*Dh)``
+    -> ``(B, H, K)``.  A head is a lane range of a row, so the rows
+    are contracted whole, on the MXU, against q laid out block-
+    diagonally (column ``h`` holds ``q[b, h]`` in head ``h``'s lanes
+    and exact zeros elsewhere): the same products and the same f32
+    accumulation as a per-head dot, with no ``(.., H*Dh) -> (.., H,
+    Dh)`` view of the rows.  (That view is a padded relayout of every
+    gathered page on the TPU — 64 -> 128 lanes, 25 -> 32 sublanes; at
+    GPT-2 XL's widths it made the 48 layers' attention 16.5 ms a step
+    against 4.0 this way — chip run, PR 25.)"""
+    import jax.numpy as jnp
+
+    b, h, d = q.shape
+    eye = jnp.eye(h, dtype=q.dtype)
+    qmat = (q[:, :, :, None] * eye[None, :, None, :]).reshape(b, h * d, h)
+    return jnp.einsum("bkc,bch->bhk", rows, qmat)
+
+
+def _head_mix(probs, rows):
+    """``probs·v`` per head: probs ``(B, H, K)``, token rows ``(B, K,
+    H*Dh)`` -> ``(B, H, Dh)``.  Every head's weights meet the whole
+    row on the MXU; head ``h`` keeps its own ``Dh`` lanes of the
+    result (the other blocks are dropped, not summed in)."""
+    import jax.numpy as jnp
+
+    b, h, _ = probs.shape
+    d = rows.shape[2] // h
+    full = jnp.einsum("bhk,bkc->bhc", probs, rows)        # (B, H, H*Dh)
+    eye = jnp.eye(h, dtype=full.dtype)
+    return jnp.sum(full.reshape(b, h, h, d) * eye[None, :, :, None],
+                   axis=2)
+
+
+def _dense(q, kp, vp, tables, lengths, *, scale: float, layer=None):
+    """Gather + masked softmax — the op sequence of
+    ``TransformerBlock.decode_step`` (scores, ``-inf`` mask, softmax,
+    weighted sum, in the same dtypes) on the token-major cache, so the
+    temperature-0 token-match contract vs ``generate()`` holds."""
     import jax
     import jax.numpy as jnp
 
     from bigdl_tpu.serving.cache import gather_pages
 
-    qh = q[:, :, None, :]                     # (B, H, 1, Dh)
-    kall = gather_pages(kp, tables)           # (B, H, maxp*P, Dh)
-    vall = gather_pages(vp, tables)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kall) * scale
-    mask = (jnp.arange(kall.shape[2])[None, None, None, :]
-            <= lengths[:, None, None, None])
+    kall = gather_pages(kp, tables, layer)    # (B, maxp*P, H*Dh)
+    vall = gather_pages(vp, tables, layer)
+    scores = _head_scores(q, kall) * scale    # (B, H, maxp*P)
+    mask = (jnp.arange(kall.shape[1])[None, None, :]
+            <= lengths[:, None, None])
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhqk,bhkd->bhqd", probs, vall)
-    return o[:, :, 0, :]
+    return _head_mix(probs, vall)
 
 
 # --------------------------------------------------------------------------
@@ -140,14 +177,16 @@ def _chunk_pages(maxp: int, block_pages: int) -> int:
 
 
 def _fused(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
-           block_pages: int = 0):
+           block_pages: int = 0, layer=None):
     """Online-softmax paged decode: page blocks are gathered one chunk
-    at a time through the table (``(B, bp, H, P, Dh)`` — page layout,
-    never the transposed contiguous copy), each chunk's masked scores
+    at a time through the table (``bp`` whole pages a slot, as they
+    lie — never the full contiguous copy), each chunk's masked scores
     fold into the carried ``(m, l, acc)``, one final rescale.  f32
     accumulation throughout."""
     import jax.numpy as jnp
     from jax import lax
+
+    from bigdl_tpu.serving.cache import gather_pages
 
     b, maxp = tables.shape
     h, d = q.shape[1], q.shape[2]
@@ -155,26 +194,23 @@ def _fused(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
     bp = _chunk_pages(maxp, block_pages)
     n_chunks = maxp // bp
     qf = q.astype(jnp.float32) * scale        # (B, H, Dh)
-    len_b = lengths[:, None, None, None]      # (B, 1, 1, 1)
+    len_b = lengths[:, None, None]            # (B, 1, 1)
 
     def block(tbl_c, c0, m, l, acc):
         """Fold pages [c0, c0+bp) (table slice ``tbl_c``) into the
         running state.  ``c0`` may be traced (fori path)."""
-        kc = kp[tbl_c].astype(jnp.float32)    # (B, bp, H, P, Dh)
-        vc = vp[tbl_c].astype(jnp.float32)
-        s = jnp.einsum("bhd,bmhpd->bhmp", qf, kc)     # (B, H, bp, P)
-        pos = ((c0 + jnp.arange(bp)) * p)[None, None, :, None] \
-            + jnp.arange(p)[None, None, None, :]
+        kc = gather_pages(kp, tbl_c, layer).astype(jnp.float32)
+        vc = gather_pages(vp, tbl_c, layer).astype(jnp.float32)
+        s = _head_scores(qf, kc)                      # (B, H, bp*P)
+        pos = c0 * p + jnp.arange(bp * p)[None, None, :]
         s = _mask_neg_inf(s, pos, len_b)
-        s = s.reshape(b, h, bp * p)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
         shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         pr = jnp.exp(s - shift[..., None])
         alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - shift, -jnp.inf))
         l_new = l * alpha + jnp.sum(pr, axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhmp,bmhpd->bhd", pr.reshape(b, h, bp, p), vc)
+        acc_new = acc * alpha[..., None] + _head_mix(pr, vc)
         return m_new, l_new, acc_new
 
     init = (jnp.full((b, h), -jnp.inf, jnp.float32),
@@ -205,19 +241,22 @@ def _fused(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
 def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, page_size: int,
                    scale: float):
-    """One (slot, head, page) program.  The BlockSpec index maps below
-    already resolved this program's K/V block to the page the table
-    names (scalar prefetch), so the kernel only sees a (P, Dh) tile;
-    (m, l, acc) carry in VMEM scratch across the page grid dimension
-    (fastest-varying, sequential on TPU).  m and l stay (1, 1) arrays
-    end to end: Mosaic stores vectors to VMEM, not scalars."""
+    """One (slot, page) program over ALL heads.  The BlockSpec index
+    maps below already resolved this program's K/V block to the page
+    the table names (scalar prefetch), so the kernel sees one whole
+    page as it lies in the cache, ``(P, H*Dh)``; head ``h`` is the
+    static lane slice ``[:, h*Dh:(h+1)*Dh]``.  (m, l, acc) carry in
+    VMEM scratch — one row per head — across the page grid dimension
+    (fastest-varying, sequential on TPU).  m and l rows stay (1, 1)
+    arrays end to end: Mosaic stores vectors to VMEM, not scalars."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(2)
-    ns = pl.num_programs(2)
+    j = pl.program_id(1)
+    ns = pl.num_programs(1)
+    n_head, d = acc_scr.shape
 
     @pl.when(j == 0)
     def _init():
@@ -225,36 +264,39 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale        # (1, Dh)
-    ks = k_ref[0, 0].astype(jnp.float32)               # (P, Dh)
-    vs = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, ks, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (1, P)
-    pos = j * page_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    length = len_ref[pl.program_id(0)]
-    s = jnp.where(pos <= length, s, -jnp.inf)
-
-    m = m_scr[...]                                     # (1, 1)
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
-    shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - shift)
-    alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - shift, -jnp.inf))
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, vs, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (1, Dh)
-    m_scr[...] = m_new
+    q = q_ref[...].astype(jnp.float32) * scale         # (H, Dh)
+    ks = k_ref[...].astype(jnp.float32)                # (P, H*Dh)
+    vs = v_ref[...].astype(jnp.float32)
+    pos = j * page_size + lax.broadcasted_iota(
+        jnp.int32, (1, page_size), 1)
+    live = pos <= len_ref[pl.program_id(0)]
+    for h in range(n_head):
+        row, lanes = slice(h, h + 1), slice(h * d, (h + 1) * d)
+        s = jax.lax.dot_general(
+            q[row], ks[:, lanes], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (1, P)
+        s = jnp.where(live, s, -jnp.inf)
+        m = m_scr[row, :]                              # (1, 1)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
+        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - shift)
+        alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - shift, -jnp.inf))
+        l_scr[row, :] = l_scr[row, :] * alpha + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_scr[row, :] = acc_scr[row, :] * alpha + jax.lax.dot_general(
+            p, vs[:, lanes], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (1, Dh)
+        m_scr[row, :] = m_new
 
     @pl.when(j == ns - 1)
     def _finalize():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _pallas(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
-            interpret: bool = False):
+            interpret: bool = False, layer=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -264,33 +306,35 @@ def _pallas(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
     maxp = tables.shape[1]
     p = int(page_size)
 
-    # q and out ride as (B, H, 1, Dh): Mosaic wants a block's last two
-    # dims (8, 128)-divisible or full, which a (1, 1, Dh) block over
-    # (B, H, Dh) is not and a (1, 1, 1, Dh) block over this shape is
-    qo_spec = pl.BlockSpec((1, 1, 1, d), lambda i, hh, j, tbl, lens:
-                           (i, hh, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, p, d), lambda i, hh, j, tbl, lens:
-                           (tbl[i, j], hh, 0, 0))
+    # every block's last two dims are full — (H, Dh) of q and out,
+    # (P, H*Dh) of a page — which is what Mosaic asks of a block that
+    # is not (8, 128)-divisible; the leading dims are squeezed away
+    qo_spec = pl.BlockSpec((None, h, d), lambda i, j, tbl, lens: (i, 0, 0))
+    if layer is None:
+        kv_spec = pl.BlockSpec((None, p, h * d), lambda i, j, tbl, lens:
+                               (tbl[i, j], 0, 0))
+    else:   # the stacked cache: the layer rides in the index map
+        kv_spec = pl.BlockSpec((None, None, p, h * d),
+                               lambda i, j, tbl, lens:
+                               (layer, tbl[i, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # tables, lengths
-        grid=(b, h, maxp),
+        grid=(b, maxp),
         in_specs=[qo_spec, kv_spec, kv_spec],
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_decode_kernel, page_size=p, scale=scale)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q[:, :, None, :], kp, vp)
-    return out[:, :, 0, :]
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, kp, vp)
 
 
 # --------------------------------------------------------------------------
@@ -309,11 +353,16 @@ def static_decode_dispatch() -> tuple:
 def paged_decode_attention(q, kp, vp, tables, lengths, *,
                            page_size: int, scale: Optional[float] = None,
                            impl: str = "auto", block_pages: int = 0,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           layer: Optional[int] = None):
     """One decode-attention step over the paged KV cache.
 
     q: ``(B, H, Dh)`` — one query token per slot.
-    kp/vp: ``(num_pages, H, P, Dh)`` — one layer's page pool.
+    kp/vp: ``(num_pages, P, H*Dh)`` — one layer's page pool, token-
+    major (serving/cache.py ``pool_shape``); or, with ``layer``, the
+    engine's stacked ``(n_layer, num_pages, P, H*Dh)`` buffers, read
+    in place (a ``kp[layer]`` handed in instead costs a copy of the
+    layer's pool on the TPU).
     tables: ``(B, maxp)`` int32 page table (maxp may be the engine's
     used-page bucket, not the full table width); lengths: ``(B,)``
     int32 — position ``pos <= length`` attends.
@@ -332,10 +381,12 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
         from bigdl_tpu.ops import autotune
 
         if autotune.enabled():
+            # the site's probes take one layer's pool
+            pools = (kp, vp) if layer is None else (kp[layer], vp[layer])
             rec = autotune.decide_decode_attn(
                 q.shape, int(page_size), int(tables.shape[1]), q.dtype,
                 kv_dtype=kp.dtype,
-                arrays=(q, kp, vp, tables, lengths))
+                arrays=(q, *pools, tables, lengths))
             if rec is not None:
                 impl = rec.get("impl", impl)
                 block_pages = int(rec.get("block_pages") or 0)
@@ -343,16 +394,17 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
         from bigdl_tpu.ops._pallas import resolve_interpret
 
         return _pallas(q, kp, vp, tables, lengths, page_size=page_size,
-                       scale=scale, interpret=resolve_interpret(
+                       scale=scale, layer=layer,
+                       interpret=resolve_interpret(
                            True if impl == "pallas_interpret"
                            else interpret))
     if impl == "fused":
         return _fused(q, kp, vp, tables, lengths, page_size=page_size,
-                      scale=scale, block_pages=block_pages)
+                      scale=scale, block_pages=block_pages, layer=layer)
     if impl != "dense":
         raise ValueError(
             f"impl must be auto|dense|fused|pallas, got {impl!r}")
-    return _dense(q, kp, vp, tables, lengths, scale=scale)
+    return _dense(q, kp, vp, tables, lengths, scale=scale, layer=layer)
 
 
 __all__ = ["paged_decode_attention", "static_decode_dispatch",

@@ -5,17 +5,30 @@ cache per layer, sized for the *longest possible* sequence and owned by
 the whole batch for the whole decode — a request that finishes early
 keeps its columns hot until the slowest batchmate drains.  Serving
 needs the vLLM-style alternative: K/V live in fixed-size **pages**
-(``(page_size, Dh)`` per head), each request owns only the pages its
-tokens actually fill (a per-slot **page table**), pages return to a
-free list the moment a request completes, and a new request is admitted
-into the freed slot at the next step boundary.
+(``page_size`` token rows of ``n_head * Dh``), each request owns only
+the pages its tokens actually fill (a per-slot **page table**), pages
+return to a free list the moment a request completes, and a new
+request is admitted into the freed slot at the next step boundary.
 
 Layout (one array per K and V, all layers stacked so the decode step
-carries two device buffers instead of 2·L):
+carries two device buffers instead of 2·L).  It is written down once,
+here — :func:`pool_shape`, :func:`write_token_rows`,
+:func:`write_prompt_pages`, :func:`gather_pages` — and every cache in
+the repo (the engine's, the tuner's probes, the smokes') is built,
+written and read through them:
 
-* ``kp``/``vp``: ``(n_layer, num_pages, n_head, page_size, head_dim)``
+* ``kp``/``vp``: ``(n_layer, num_pages, page_size, n_head * head_dim)``
   device arrays in the cache dtype (defaults to the model dtype — bf16
-  weights get a bf16 cache, halving decode HBM traffic);
+  weights get a bf16 cache, halving decode HBM traffic).  The layout is
+  **token-major**: one row is one token's K (or V) for all heads, as
+  the projection produces it, so a page is ``page_size`` whole rows.
+  On the TPU that is the shape the chip works on as it lies — the
+  ``n_head * head_dim`` lanes are full (1600 of 1664 for GPT-2 XL), a
+  page's 16 rows fill a bf16 tile — so the buffer the engine donates
+  to the decode step and the prefill is the buffer those programs
+  update in place: no layout conversion in or out, no padded twin
+  (heads-as-sublanes, ``(.., n_head, page_size, head_dim)``, cost four
+  whole-cache copies a step and a working copy 2.56x the cache);
 * page table: ``(max_slots, max_pages_per_slot)`` int32, host-owned and
   shipped to the device per step (a few hundred bytes);
 * page 0 is a reserved **trash page**: unallocated table entries and
@@ -60,8 +73,8 @@ class PagedKVCache:
         # +1: page 0 is the reserved trash page, never allocated
         self.num_pages = max(int(num_pages), 2)
         self.dtype = jnp.dtype(dtype) if dtype is not None else jnp.float32
-        shape = (self.n_layer, self.num_pages, self.n_head,
-                 self.page_size, self.head_dim)
+        shape = pool_shape(self.num_pages, self.page_size, self.n_head,
+                           self.head_dim, n_layer=self.n_layer)
         self.kp = jnp.zeros(shape, self.dtype)
         self.vp = jnp.zeros(shape, self.dtype)
         self.page_tables = np.zeros(
@@ -161,14 +174,56 @@ class PagedKVCache:
         return self.max_pages_per_slot * self.page_size
 
 
-def gather_pages(pages, page_table):
-    """``(num_pages, H, P, Dh)`` pages + ``(B, maxp)`` table ->
-    ``(B, H, maxp*P, Dh)`` per-slot contiguous K/V view (positions past
-    a slot's length are trash and must be masked by the caller)."""
-    b, maxp = page_table.shape
-    g = pages[page_table]                      # (B, maxp, H, P, Dh)
-    g = g.transpose(0, 2, 1, 3, 4)             # (B, H, maxp, P, Dh)
-    return g.reshape(b, g.shape[1], maxp * g.shape[3], g.shape[4])
+def pool_shape(num_pages: int, page_size: int, n_head: int,
+               head_dim: int, n_layer: Optional[int] = None) -> tuple:
+    """Shape of one layer's K (or V) page pool, ``(num_pages,
+    page_size, n_head * head_dim)``; with ``n_layer`` the engine's
+    stacked ``(n_layer, ...)`` buffer.  The one statement of the
+    layout: everything that builds a cache asks here."""
+    pool = (int(num_pages), int(page_size), int(n_head) * int(head_dim))
+    return pool if n_layer is None else (int(n_layer),) + pool
 
 
-__all__ = ["PagedKVCache", "gather_pages"]
+def write_token_rows(pages, layer: int, tables, lengths, rows):
+    """Decode write: slot ``b``'s new token row ``rows[b]`` (``(B,
+    n_head * head_dim)``, the projection's output as it comes) lands
+    at position ``lengths[b]`` of its table — exactly the one row
+    ``pages[layer, page, slot_in_page, :]``; no other byte changes.
+    Inactive slots (length 0, trash table row) write the trash page."""
+    import jax.numpy as jnp
+
+    page_size = pages.shape[2]
+    page = jnp.take_along_axis(
+        tables, (lengths // page_size)[:, None], axis=1)[:, 0]
+    return pages.at[layer, page, lengths % page_size, :].set(
+        rows.astype(pages.dtype))
+
+
+def write_prompt_pages(pages, layer: int, page_ids, rows):
+    """Prefill write: ``rows`` (``(T, n_head * head_dim)``, ``T`` a
+    multiple of the page size) fills the pages ``page_ids`` (``(T //
+    page_size,)``) in order, as ONE scatter.  Never a loop of per-page
+    updates: on the TPU that makes the compiler convert the whole
+    cache to another layout and back (four whole-cache copies)."""
+    n = page_ids.shape[0]
+    return pages.at[layer, page_ids].set(
+        rows.reshape(n, pages.shape[2], pages.shape[3])
+        .astype(pages.dtype))
+
+
+def gather_pages(pages, page_table, layer: Optional[int] = None):
+    """The pages a ``(B, maxp)`` table names, as per-slot contiguous
+    token rows ``(B, maxp*P, H*Dh)``: position ``t`` of slot ``b`` is
+    row ``[b, t]``, head ``h`` its lanes ``[h*Dh, (h+1)*Dh)``
+    (positions past a slot's length are trash and must be masked by
+    the caller).  ``pages`` is one layer's pool, or with ``layer`` the
+    stacked buffer — the layer then rides in the same gather as the
+    page ids, so no per-layer slice of the cache is ever materialised
+    (``pages[layer][table]`` costs a copy of the layer's pool per call
+    on the TPU)."""
+    g = pages[page_table] if layer is None else pages[layer, page_table]
+    return g.reshape(g.shape[0], g.shape[1] * g.shape[2], g.shape[3])
+
+
+__all__ = ["PagedKVCache", "gather_pages", "pool_shape",
+           "write_prompt_pages", "write_token_rows"]

@@ -104,6 +104,7 @@ def paged_decode_math(children, n_layer, page_size, params, qparams,
 
     from bigdl_tpu.ops.decode_attention import paged_decode_attention
     from bigdl_tpu.ops.quantized_matmul import int8_matmul
+    from bigdl_tpu.serving.cache import write_token_rows
 
     attn0 = children["h0"]._children["attn"]
     heads = attn0.n_head if n_head is None else int(n_head)
@@ -134,21 +135,17 @@ def paged_decode_math(children, n_layer, page_size, params, qparams,
                 if pa.get("bq") is not None:
                     q, k, v = q + pa["bq"], k + pa["bk"], v + pa["bv"]
 
-        def split(t):
-            return t.reshape(bsz, 1, heads, head_dim).transpose(0, 2, 1, 3)
-
-        qh = split(q)
-        kh = split(k)[:, :, 0, :]            # (B, H, Dh)
-        vh = split(v)[:, :, 0, :]
-        pidx = jnp.take_along_axis(
-            tables, (lengths // page_size)[:, None], axis=1)[:, 0]
-        off = lengths % page_size
+        qh = q.reshape(bsz, heads, head_dim)
         with jax.named_scope("kv_write"):
-            kp = kp.at[i, pidx, :, off, :].set(kh.astype(kp.dtype))
-            vp = vp.at[i, pidx, :, off, :].set(vh.astype(vp.dtype))
+            # one token row per slot, the projection's output as it
+            # comes (the cache is token-major: no split into heads)
+            kp = write_token_rows(kp, i, tables, lengths, k[:, 0, :])
+            vp = write_token_rows(vp, i, tables, lengths, v[:, 0, :])
         with jax.named_scope("attn"):
+            # the stacked buffers and the layer's index, not kp[i]:
+            # the pages are read where they lie
             o = paged_decode_attention(
-                qh[:, :, 0, :], kp[i], vp[i], tables, lengths,
+                qh, kp, vp, tables, lengths, layer=i,
                 page_size=page_size, scale=scale, impl=attn_impl,
                 block_pages=attn_block_pages)       # (B, H, Dh)
         o = o.reshape(bsz, 1, heads * head_dim)
@@ -468,10 +465,11 @@ class LMEngine:
         import jax.numpy as jnp
         from jax import lax
 
+        from bigdl_tpu.serving.cache import write_prompt_pages
+
         children = self.model._children
-        n_layer, page_size = self.n_layer, self.page_size
+        n_layer = self.n_layer
         dim = self.model.dim
-        n_write = bucket // page_size
 
         def prefill(params, kp, vp, prompt, t0, pages, temp, key):
             # prompt is (1, bucket), zero-padded past t0 — causal
@@ -480,15 +478,12 @@ class LMEngine:
             x = x + params["wpe"]["weight"][:bucket][None]
             for i in range(n_layer):
                 # the block's prefill names its own attn and dense parts
-                x, kh, vh = children[f"h{i}"].prefill(params[f"h{i}"], x)
+                x, k, v = children[f"h{i}"].prefill_rows(
+                    params[f"h{i}"], x)
                 with jax.named_scope("kv_write"):
-                    for j in range(n_write):
-                        kp = kp.at[i, pages[j]].set(
-                            kh[0, :, j * page_size:(j + 1) * page_size,
-                               :].astype(kp.dtype))
-                        vp = vp.at[i, pages[j]].set(
-                            vh[0, :, j * page_size:(j + 1) * page_size,
-                               :].astype(vp.dtype))
+                    # one scatter a layer over the bucket's pages
+                    kp = write_prompt_pages(kp, i, pages, k[0])
+                    vp = write_prompt_pages(vp, i, pages, v[0])
             h = lax.dynamic_slice(x, (0, t0 - 1, 0), (1, 1, dim))
             h, _ = children["ln_f"].apply(params["ln_f"], {}, h)
             with jax.named_scope("dense"):

@@ -2,7 +2,8 @@
 
 Decode is memory-bound: one token's matmuls stream every weight byte
 per step, so splitting the weights across ``tp`` devices divides the
-per-device bytes (and the KV cache, sharded on the head axis) at the
+per-device bytes (and the KV cache, sharded by heads: a head is a
+lane range of the token-major cache's rows, serving/cache.py) at the
 price of two small cross-device reductions per block — exactly the two
 Megatron psums, run here through ``parallel.wire_psum`` so an int8/fp8
 wire compresses the only bytes serving puts on the interconnect.
@@ -102,7 +103,9 @@ def build_tp_decode_step(model, *, tp: int, wire=None, page_size: int,
     _account(model.n_layer, max_batch, dim, tp, spec)
 
     pspecs = param_specs(model.params(), mesh, rules=SERVE_TP_RULES)
-    cache_spec = P(None, None, "model", None, None)
+    # heads are contiguous in the token-major cache's last dimension
+    # (serving/cache.py), so a head shard is a lane range
+    cache_spec = P(None, None, None, "model")
     children = model._children
     n_layer = model.n_layer
 

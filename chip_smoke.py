@@ -384,6 +384,7 @@ def phase_kernels(cfg, platform, compiles) -> dict:
     from bigdl_tpu.ops.conv_bn import _reference as conv_reference
     from bigdl_tpu.ops.conv_bn import conv_bn_stats, kernel_path
     from bigdl_tpu.ops.decode_attention import paged_decode_attention
+    from bigdl_tpu.serving.cache import pool_shape
 
     rehearsal = platform == "cpu"
     out = {}
@@ -441,8 +442,8 @@ def phase_kernels(cfg, platform, compiles) -> dict:
         rs.randint(1, c["maxp"] * c["p"] - 1, c["b"]), jnp.int32)
     for dt in (jnp.float32, jnp.bfloat16):
         q = jnp.asarray(rs.randn(c["b"], c["h"], c["d"]), dt)
-        kp, vp = (jnp.asarray(rs.randn(pool, c["h"], c["p"], c["d"]), dt)
-                  for _ in range(2))
+        kp, vp = (jnp.asarray(rs.randn(*pool_shape(
+            pool, c["p"], c["h"], c["d"])), dt) for _ in range(2))
 
         def decode(q, kp, vp, impl="pallas"):
             return paged_decode_attention(q, kp, vp, tables, lengths,

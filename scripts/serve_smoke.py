@@ -185,6 +185,7 @@ def main() -> int:
     from bigdl_tpu.models.transformer import build_transformer_lm
     from bigdl_tpu.ops import autotune
     from bigdl_tpu.ops.decode_attention import paged_decode_attention
+    from bigdl_tpu.serving.cache import pool_shape
 
     RandomGenerator.RNG.set_seed(29)
     # max_len 512 / page 16 = a 32-page table per slot, of which the
@@ -235,8 +236,9 @@ def main() -> int:
     rs2 = np.random.RandomState(2)
     pool = 33
     qo = jnp.asarray(rs2.randn(8, 8, 16).astype(np.float32))
-    kpo = jnp.asarray(rs2.randn(pool, 8, 16, 16).astype(np.float32))
-    vpo = jnp.asarray(rs2.randn(pool, 8, 16, 16).astype(np.float32))
+    kv_shape = pool_shape(pool, 16, 8, 16)
+    kpo = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
+    vpo = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
     lens = jnp.asarray(rs2.randint(1, 63, (8,)).astype(np.int32))
     tbls = jnp.asarray(rs2.randint(1, pool, (8, 4)).astype(np.int32))
     od = paged_decode_attention(qo, kpo, vpo, tbls, lens, page_size=16,

@@ -70,12 +70,14 @@ np.testing.assert_allclose(np.asarray(yt), np.asarray(yr), atol=1e-4,
 # ... the serving decode_attn site (flash-decode over the paged cache):
 # the measured prewarm must agree with the static dense path
 from bigdl_tpu.ops.decode_attention import paged_decode_attention
+from bigdl_tpu.serving.cache import pool_shape
 got = autotune.prewarm_decode_attn(2, 2, 16, page_size=8, maxp=2, seed=3)
 rs2 = np.random.RandomState(3)
 pool = 2 * 2 + 1
 qd = jnp.asarray(rs2.randn(2, 2, 16).astype(np.float32))
-kpd = jnp.asarray(rs2.randn(pool, 2, 8, 16).astype(np.float32))
-vpd = jnp.asarray(rs2.randn(pool, 2, 8, 16).astype(np.float32))
+kv_shape = pool_shape(pool, 8, 2, 16)
+kpd = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
+vpd = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
 lens = jnp.asarray(rs2.randint(1, 16, (2,)).astype(np.int32))
 tbls = jnp.asarray(rs2.randint(1, pool, (2, 2)).astype(np.int32))
 refd = paged_decode_attention(qd, kpd, vpd, tbls, lens, page_size=8,

@@ -29,6 +29,7 @@ from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
                                             paged_decode_attention,
                                             static_decode_dispatch,
                                             used_page_bucket)
+from bigdl_tpu.serving.cache import pool_shape
 
 
 @pytest.fixture(autouse=True)
@@ -58,8 +59,9 @@ def _state(b=4, h=4, d=16, p=8, maxp=8, pool=24, seed=0,
     trash table row) like a released engine slot."""
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(b, h, d).astype(np.float32))
-    kp = jnp.asarray(rs.randn(pool, h, p, d).astype(np.float32))
-    vp = jnp.asarray(rs.randn(pool, h, p, d).astype(np.float32))
+    shape = pool_shape(pool, p, h, d)
+    kp = jnp.asarray(rs.randn(*shape).astype(np.float32))
+    vp = jnp.asarray(rs.randn(*shape).astype(np.float32))
     if lengths is None:
         lengths = [0, p - 1, p, min(3 * p - 1, maxp * p - 1)][:b]
         lengths += [1] * (b - len(lengths))
@@ -84,16 +86,17 @@ def _numpy_reference(q, kp, vp, tables, lengths, p):
     out = np.zeros((b, h, d))
     scale = d ** -0.5
     for i in range(b):
+        # token-major pages: (P, H*Dh) rows -> (maxp*P, H, Dh)
         k = np.concatenate([kp[tables[i, j]] for j in range(maxp)],
-                           axis=1)          # (H, maxp*P, Dh)
+                           axis=0).reshape(maxp * p, h, d)
         v = np.concatenate([vp[tables[i, j]] for j in range(maxp)],
-                           axis=1)
+                           axis=0).reshape(maxp * p, h, d)
         n = int(lengths[i]) + 1
-        s = np.einsum("hd,hkd->hk", q[i], k[:, :n]) * scale
+        s = np.einsum("hd,khd->hk", q[i], k[:n]) * scale
         s -= s.max(axis=-1, keepdims=True)
         pr = np.exp(s)
         pr /= pr.sum(axis=-1, keepdims=True)
-        out[i] = np.einsum("hk,hkd->hd", pr, v[:, :n])
+        out[i] = np.einsum("hk,khd->hd", pr, v[:n])
     return out
 
 
@@ -149,6 +152,37 @@ class TestPagedDecodeParity:
                                      impl="pallas_interpret")
         np.testing.assert_allclose(np.asarray(pal), np.asarray(dense),
                                    atol=1e-5)
+
+    @pytest.mark.parametrize("shape", ["engine", "tiny"])
+    def test_three_bodies_agree_on_the_token_major_cache(self, shape):
+        """dense, fused and the Pallas kernel read the one cache
+        layout — a layer's pool handed in, and the engine's stacked
+        buffers with the layer's index — and agree, at the engine's
+        shape (chip_smoke.py FULL) and at a tiny one."""
+        c = (dict(b=8, h=8, d=64, p=16, maxp=32) if shape == "engine"
+             else dict(b=2, h=2, d=8, p=4, maxp=2))
+        q, kp, vp, tbl, lens = _state(
+            b=c["b"], h=c["h"], d=c["d"], p=c["p"], maxp=c["maxp"],
+            pool=1 + c["b"] * c["maxp"], seed=11)
+        assert kp.shape == (1 + c["b"] * c["maxp"], c["p"],
+                            c["h"] * c["d"])
+        outs = {impl: paged_decode_attention(
+            q, kp, vp, tbl, lens, page_size=c["p"], impl=impl,
+            block_pages=4) for impl in ("dense", "fused",
+                                        "pallas_interpret")}
+        want = _numpy_reference(q, kp, vp, tbl, lens, c["p"])
+        for impl, got in outs.items():
+            np.testing.assert_allclose(np.asarray(got), want, atol=2e-5,
+                                       err_msg=impl)
+        # the stacked cache, read in place at layer 1 of 2
+        kps, vps = (jnp.stack([jnp.full_like(x, 7.0), x])
+                    for x in (kp, vp))
+        for impl, got in outs.items():
+            stacked = paged_decode_attention(
+                q, kps, vps, tbl, lens, page_size=c["p"], impl=impl,
+                block_pages=4, layer=1)
+            np.testing.assert_array_equal(np.asarray(stacked),
+                                          np.asarray(got), err_msg=impl)
 
     @pytest.mark.parametrize("impl", ["dense", "fused",
                                       "pallas_interpret"])

@@ -73,16 +73,82 @@ class TestPagedKVCache:
         import jax.numpy as jnp
 
         from bigdl_tpu.serving import gather_pages
+        from bigdl_tpu.serving.cache import pool_shape
 
-        pages = jnp.arange(3 * 2 * 4 * 5, dtype=jnp.float32).reshape(
-            3, 2, 4, 5)
+        shape = pool_shape(3, 4, 2, 5)       # 3 pages of 4 rows, H=2
+        assert shape == (3, 4, 10)
+        pages = jnp.arange(3 * 4 * 10, dtype=jnp.float32).reshape(shape)
         table = jnp.asarray([[2, 1], [0, 0]], jnp.int32)
         g = gather_pages(pages, table)
-        assert g.shape == (2, 2, 8, 5)
+        assert g.shape == (2, 8, 10)         # (B, maxp*P, H*Dh)
         np.testing.assert_array_equal(
-            np.asarray(g[0, :, :4]), np.asarray(pages[2]))
+            np.asarray(g[0, :4]), np.asarray(pages[2]))
         np.testing.assert_array_equal(
-            np.asarray(g[0, :, 4:]), np.asarray(pages[1]))
+            np.asarray(g[0, 4:]), np.asarray(pages[1]))
+        # the stacked buffer + a layer index reads the same rows
+        stacked = jnp.stack([pages + 1000.0, pages])
+        np.testing.assert_array_equal(
+            np.asarray(gather_pages(stacked, table, layer=1)),
+            np.asarray(g))
+
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_prompt_write_round_trips_through_gather(self, dtype):
+        """The prefill's one-scatter write, then ``gather_pages``,
+        against a contiguous ``(B, T, H, Dh)`` reference — through a
+        table whose pages are out of order and whose tail points at
+        the trash page."""
+        import jax.numpy as jnp
+
+        from bigdl_tpu.serving.cache import (gather_pages, pool_shape,
+                                             write_prompt_pages)
+
+        n_layer, n_head, head_dim, page = 3, 4, 8, 4
+        rs = np.random.RandomState(5)
+        # two prompts of 3 and 2 live pages in a 4-page bucket
+        ref = jnp.asarray(rs.randn(2, 16, n_head, head_dim), dtype)
+        ids = np.asarray([[7, 2, 5, 0], [1, 8, 0, 0]], np.int32)
+        pages = jnp.full(pool_shape(9, page, n_head, head_dim,
+                                    n_layer=n_layer), -3.0, dtype)
+        for b in range(2):
+            pages = write_prompt_pages(
+                pages, 1, jnp.asarray(ids[b]),
+                ref[b].reshape(16, n_head * head_dim))
+        assert pages.dtype == jnp.dtype(dtype)
+        got = gather_pages(pages, jnp.asarray(ids), layer=1)
+        got = np.asarray(got.astype(jnp.float32)).reshape(
+            2, 16, n_head, head_dim)
+        want = np.asarray(ref.astype(jnp.float32))
+        np.testing.assert_array_equal(got[0, :12], want[0, :12])
+        np.testing.assert_array_equal(got[1, :8], want[1, :8])
+        # the other layers were not touched, and nothing but the named
+        # pages (trash included) of layer 1
+        flat = np.asarray(pages.astype(jnp.float32))
+        assert (flat[0] == -3.0).all() and (flat[2] == -3.0).all()
+        assert (flat[1][[3, 4, 6]] == -3.0).all()
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_decode_write_lands_in_exactly_one_row(self, dtype):
+        """``write_token_rows`` sets ``[layer, page, slot_in_page, :]``
+        for each slot and changes no other byte of a cache filled
+        with a sentinel."""
+        import jax.numpy as jnp
+
+        from bigdl_tpu.serving.cache import pool_shape, write_token_rows
+
+        page, width = 4, 4 * 8
+        tables = jnp.asarray([[6, 3, 0], [2, 5, 1]], jnp.int32)
+        lengths = jnp.asarray([5, 8], jnp.int32)   # -> [3, 1], [1, 0]
+        rows = jnp.asarray(
+            np.random.RandomState(6).randn(2, width), dtype)
+        before = jnp.full(pool_shape(7, page, 4, 8, n_layer=2), 9.0,
+                          dtype)
+        after = np.asarray(write_token_rows(
+            before, 1, tables, lengths, rows).astype(jnp.float32))
+        want = np.full(after.shape, 9.0, np.float32)
+        want[1, 3, 1] = np.asarray(rows[0].astype(jnp.float32))
+        want[1, 1, 0] = np.asarray(rows[1].astype(jnp.float32))
+        np.testing.assert_array_equal(after, want)
 
 
 # --------------------------------------------------------------- engine
@@ -180,6 +246,29 @@ class TestContinuousBatching:
         eng.close()
         assert r.done and len(r.tokens) == 8
         assert all(0 <= t < 48 for t in r.tokens)
+
+    def test_int8_tokens_do_not_depend_on_page_placement(self, lm_model):
+        """The int8 step reads and writes the same token-major cache:
+        its greedy tokens are the same wherever the request's pages
+        lie and however wide the step's table is."""
+        from bigdl_tpu.serving import LMEngine
+
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5]
+        runs = []
+        for kw, first in ((dict(), None),
+                          (dict(decode_bucket=False, num_pages=40),
+                           [7, 7, 7])):
+            eng = LMEngine(lm_model, max_batch=2, page_size=8, int8=True,
+                           **kw)
+            if first is not None:        # takes the pages run 1 used
+                eng.submit(first, 12)
+                eng.pump()
+            r = eng.submit(prompt, 10)
+            eng.run_until_idle(60)
+            eng.close()
+            assert r.done and len(r.tokens) == 10
+            runs.append(list(r.tokens))
+        assert runs[0] == runs[1]
 
     def test_int8_excludes_tp(self, lm_model):
         from bigdl_tpu.serving import LMEngine

@@ -19,6 +19,7 @@ import pytest
 from bigdl_tpu.ops.attention import flash_attention
 from bigdl_tpu.ops.conv_bn import conv_bn_stats
 from bigdl_tpu.ops.decode_attention import paged_decode_attention
+from bigdl_tpu.serving.cache import pool_shape
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -60,7 +61,7 @@ def test_paged_decode_lowers_at_the_engine_shape(dtype):
                                       page_size=c["p"], impl="pallas",
                                       interpret=False)
 
-    kv = ((pool, c["h"], c["p"], c["d"]), dtype)
+    kv = (pool_shape(pool, c["p"], c["h"], c["d"]), dtype)
     n = _mosaic_calls(f, ((c["b"], c["h"], c["d"]), dtype), kv, kv,
                       ((c["b"], c["maxp"]), jnp.int32),
                       ((c["b"],), jnp.int32))
@@ -105,13 +106,11 @@ def test_no_interpreter_by_default_off_the_cpu(monkeypatch):
         flash_attention.clear_cache()
 
 
-@pytest.mark.slow
-def test_libtpu_mosaic_compiles_every_kernel_without_a_chip():
-    """The whole way down, still without a chip: libtpu can describe a
-    v5e topology on a host that has none, and compiling for it runs
-    Mosaic itself (vector layout inference, scoped-VMEM allocation).
-    Slow-tagged (it starts libtpu); run it before spending chip minutes
-    on a kernel change."""
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a v5e that is described, not attached.  Asked for
+    by the slow tests alone, from inside them (never at import: one
+    process at a time may load libtpu)."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -120,7 +119,17 @@ def test_libtpu_mosaic_compiles_every_kernel_without_a_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no usable libtpu here
         pytest.skip(f"no TPU topology without a chip: {e}")
-    sh = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.slow
+def test_libtpu_mosaic_compiles_every_kernel_without_a_chip(one_chip):
+    """The whole way down, still without a chip: libtpu can describe a
+    v5e topology on a host that has none, and compiling for it runs
+    Mosaic itself (vector layout inference, scoped-VMEM allocation).
+    Slow-tagged (it starts libtpu); run it before spending chip minutes
+    on a kernel change."""
+    sh = one_chip
 
     def compile_(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
@@ -140,7 +149,7 @@ def test_libtpu_mosaic_compiles_every_kernel_without_a_chip():
     c = FULL["decode"]
     pool = 1 + c["b"] * c["maxp"]
     for kv_dt in (jnp.float32, jnp.bfloat16):
-        kv = ((pool, c["h"], c["p"], c["d"]), kv_dt)
+        kv = (pool_shape(pool, c["p"], c["h"], c["d"]), kv_dt)
         compile_(
             lambda q, kp, vp, t, n: paged_decode_attention(
                 q, kp, vp, t, n, page_size=c["p"], impl="pallas",
@@ -153,3 +162,96 @@ def test_libtpu_mosaic_compiles_every_kernel_without_a_chip():
                 x, w, s, stride=stride, pad=(k - 1) // 2, impl="pallas",
                 interpret=False),
             ((n, ci, hw, hw), dt), ((o, ci, k, k), dt), ((o,), jnp.float32))
+
+
+# GPT-2 XL's widths and the benchmark's engine (benchmarks/configs/
+# gpt2_xl.json), cut to 2 layers and a 256-word vocabulary: neither
+# changes how a program treats the cache
+XL = dict(dim=1600, n_head=25, head_dim=64, n_layer=2, max_len=1024,
+          vocab=256, max_batch=12, page_size=16, num_pages=481)
+
+
+@pytest.mark.slow
+def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
+    """The engine's decode step and both prefill buckets of the serving
+    cell, weight-free, compiled for the described v5e with the caches
+    donated: the buffer handed in is the buffer worked on.  No
+    instruction but the parameters and the in-place scatters has the
+    whole cache's shape (a ``copy`` there is a layout conversion: four
+    of them were 33.5 of the step's 56 ms, ledger PR 24), and the
+    temporaries are small beside the cache (the padded working copy
+    was 2.56x of it).  Also the re-blocked Pallas kernel at these
+    widths.  Run it before spending chip minutes on the cache."""
+    import re
+
+    from bigdl_tpu.models.transformer import build_transformer_lm
+    from bigdl_tpu.serving import LMEngine
+
+    sh = one_chip
+    c = XL
+    dt = jnp.bfloat16
+    model = build_transformer_lm(
+        c["vocab"], dim=c["dim"], n_head=c["n_head"],
+        n_layer=c["n_layer"], max_len=c["max_len"])
+    params = jax.tree.map(lambda a: a.astype(dt), model.params())
+    eng = LMEngine(model, params=params, max_batch=c["max_batch"],
+                   page_size=c["page_size"], num_pages=c["num_pages"],
+                   decode_attn="dense")
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    def like(a):
+        return spec(a.shape, a.dtype)
+
+    b, page = c["max_batch"], c["page_size"]
+    weights = jax.tree.map(like, eng.params)
+    kp, vp = like(eng.cache.kp), like(eng.cache.vp)
+    key = like(jax.random.key(0))
+    programs = {"step": eng._step_fn.lower(
+        weights, kp, vp, spec((b, 32), jnp.int32), spec((b,), jnp.int32),
+        spec((b,), jnp.int32), spec((b,), jnp.float32),
+        spec((b,), jnp.bool_), key)}
+    for bucket in (128, 256):
+        programs[f"prefill{bucket}"] = eng._prefill_fn(bucket).lower(
+            weights, kp, vp, spec((1, bucket), jnp.int32),
+            spec((), jnp.int32), spec((bucket // page,), jnp.int32),
+            spec((), jnp.float32), key)
+    eng.close()
+
+    dims = ",".join(str(n) for n in eng.cache.kp.shape)
+    whole = re.compile(r"= bf16\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
+    buffer_bytes = 2 * eng.cache.kp.size
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        ops = {}
+        for line in compiled.as_text().splitlines():
+            m = whole.search(line)
+            if m is None:
+                continue
+            op = m.group(1)
+            if op == "fusion" and "kv_write/scatter" in line:
+                op = "scatter fusion"
+            ops[op] = ops.get(op, 0) + 1
+        assert set(ops) <= {"parameter", "scatter", "scatter fusion"}, \
+            (name, ops)
+        # K and V, once a layer, in place
+        assert ops["scatter fusion"] == 2 * c["n_layer"], (name, ops)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        # activations only (the gathered pages stay in fast memory):
+        # under a tenth of one cache buffer, even of this 2-layer one
+        assert temp < buffer_bytes // 10, (name, temp, buffer_bytes)
+        print(f"{name}: whole-cache instructions {ops}, temporaries "
+              f"{temp / 1e6:.1f} MB, one cache buffer "
+              f"{buffer_bytes / 1e6:.1f} MB")
+
+    for kv_dt in (jnp.float32, jnp.bfloat16):
+        kv = spec(eng.cache.kp.shape, kv_dt)
+        lowered = jax.jit(
+            lambda q, kp, vp, t, n: paged_decode_attention(
+                q, kp, vp, t, n, page_size=page, impl="pallas",
+                interpret=False, layer=1)).lower(
+            spec((b, c["n_head"], c["head_dim"]), kv_dt), kv, kv,
+            spec((b, 32), jnp.int32), spec((b,), jnp.int32))
+        assert "tpu_custom_call" in lowered.as_text()
+        lowered.compile()
