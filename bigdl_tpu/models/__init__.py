@@ -2,7 +2,10 @@
 
 Rebuild of «bigdl»/models/ (SURVEY.md §2.1 "Reference models"): lenet,
 resnet (CIFAR + ImageNet), inception, vgg, alexnet, rnn (PTB LM),
-autoencoder — each with a builder and a runnable train entry point.
+autoencoder — each with a builder and a runnable train entry point —
+and the language models served behind ``serving.LMEngine``:
+``TransformerLM`` and ``LongCatFlash`` (latent attention, the
+shortcut-connected double layer, a dropless expert layer).
 """
 
 from bigdl_tpu.models.lenet import build_lenet5
@@ -18,6 +21,7 @@ from bigdl_tpu.models.ncf import build_ncf
 from bigdl_tpu.models.autoencoder import build_autoencoder
 from bigdl_tpu.models.rnn import build_ptb_lm
 from bigdl_tpu.models.transformer import TransformerLM, build_transformer_lm
+from bigdl_tpu.models.longcat_flash import LongCatFlash, build_longcat_flash
 from bigdl_tpu.models.wide_and_deep import build_wide_and_deep, pack_batch
 
 __all__ = [
@@ -26,5 +30,5 @@ __all__ = [
     "build_alexnet", "build_alexnet_original", "build_inception_v1",
     "build_inception_v2", "build_ncf", "build_wide_and_deep", "pack_batch",
     "build_autoencoder", "build_ptb_lm", "TransformerLM",
-    "build_transformer_lm",
+    "build_transformer_lm", "LongCatFlash", "build_longcat_flash",
 ]

@@ -212,9 +212,201 @@ class TransformerLM(_Composite):
                 jnp.arange(t0, total - 1))
         return tokens
 
+    # ------------------------------------------------------------ serving
+    # What ``serving.LMEngine`` asks of a model: its cache, and one
+    # prompt or one token a slot over that cache, up to the logits.
+    def cache_spec(self, params) -> dict:
+        """K and V of every layer, a row of ``n_head * head_dim``
+        values each, in two buffers; ``heads`` / ``head_dim`` let the
+        decode-attention tuner key on the shape."""
+        n_head = int(self._config["n_head"])
+        return {"layers": self.n_layer, "row_width": self.dim,
+                "buffers": 2, "max_len": int(self._config["max_len"]),
+                "dtype": params["wte"]["weight"].dtype,
+                "heads": n_head, "head_dim": self.dim // n_head}
+
+    def paged_prefill(self, params, caches, prompt, t0, pages):
+        """One prompt, ``prompt`` (1, bucket) zero-padded past ``t0`` —
+        causal attention keeps the real prefix exact — into the pages
+        ``pages``; ``(caches, logits (1, vocab) at t0 - 1, None)``."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from bigdl_tpu.serving.cache import write_prompt_pages
+
+        kp, vp = caches
+        c = self._children
+        bucket = prompt.shape[1]
+        x = jnp.take(params["wte"]["weight"], prompt, axis=0)
+        x = x + params["wpe"]["weight"][:bucket][None]
+        for i in range(self.n_layer):
+            # the block's prefill names its own attn and dense parts
+            x, k, v = c[f"h{i}"].prefill_rows(params[f"h{i}"], x)
+            with jax.named_scope("kv_write"):
+                # one scatter a layer over the bucket's pages
+                kp = write_prompt_pages(kp, i, pages, k[0])
+                vp = write_prompt_pages(vp, i, pages, v[0])
+        h = lax.dynamic_slice(x, (0, t0 - 1, 0), (1, 1, self.dim))
+        h, _ = c["ln_f"].apply(params["ln_f"], {}, h)
+        with jax.named_scope("dense"):
+            logits, _ = c["head"].apply(params["head"], {}, h)
+        return (kp, vp), logits[:, 0, :], None
+
+    def paged_decode(self, params, caches, tables, lengths, tokens, active,
+                     *, page_size, qparams=None, attn_impl="dense"):
+        """One token a slot: ``(caches, logits (B, vocab), None)``."""
+        del active  # an inactive slot writes the trash page
+        kp, vp, logits = paged_decode_logits(
+            self._children, self.n_layer, page_size, params, qparams,
+            *caches, tables, lengths, tokens, attn_impl=attn_impl)
+        return (kp, vp), logits, None
+
+    def quantize_for_decode(self, params):
+        """The int8 twins ``paged_decode(qparams=)`` takes."""
+        return _quantize_tree(params, self.n_layer)
+
+    def tp_decode_step(self, **kw):
+        """The decode step sharded over ``tp`` devices (serving/tp.py)."""
+        from bigdl_tpu.serving.tp import build_tp_decode_step
+
+        return build_tp_decode_step(self, **kw)
+
     def __repr__(self):
         return (f"TransformerLM(vocab={self.vocab_size}, dim={self.dim}, "
                 f"layers={self.n_layer})")
+
+
+def _quantize_tree(params, n_layer):
+    """Per-output-channel int8 twins of every decode matmul weight —
+    the ``quantize_per_channel`` path ``module.quantize()`` uses."""
+    from bigdl_tpu.ops.quantized_matmul import quantize_per_channel
+
+    q = {}
+    for i in range(n_layer):
+        pa = params[f"h{i}"]["attn"]
+        blk = {"attn": {}, "fc1": None, "fc2": None}
+        for w in ("wq", "wk", "wv", "wo"):
+            blk["attn"][w] = quantize_per_channel(pa[w], axis=0)
+        blk["fc1"] = quantize_per_channel(
+            params[f"h{i}"]["fc1"]["weight"], axis=0)
+        blk["fc2"] = quantize_per_channel(
+            params[f"h{i}"]["fc2"]["weight"], axis=0)
+        q[f"h{i}"] = blk
+    q["head"] = quantize_per_channel(params["head"]["weight"], axis=0)
+    return q
+
+
+def paged_decode_logits(children, n_layer, page_size, params, qparams,
+                        kp, vp, tables, lengths, tokens, *, n_head=None,
+                        psum=None, attn_impl="dense", attn_block_pages=0):
+    """One decode step over the paged cache, up to the logits — the
+    single source of truth shared by the jitted single-host step and
+    the TP shard_map body (``n_head`` is the LOCAL head count there, ``psum`` the
+    compressed block reduction).  Mirrors
+    ``TransformerBlock.decode_step`` exactly in the float path so paged
+    decode bit-matches ``generate()`` at temperature 0.
+
+    The attention body is ``ops.decode_attention.paged_decode_attention``
+    — ``attn_impl="dense"`` is the bit-match gather path, "auto" lets
+    the cached ``decode_attn`` tuner site dispatch the flash-decode
+    fused/Pallas kernels per (shape, dtype, platform); ``tables`` may
+    be the engine's used-page prefix bucket rather than the full table
+    width (same mask contract either way).
+
+    The ``jax.named_scope`` blocks (``kv_write``, ``attn``, ``dense``;
+    the engine adds ``sample``) are metadata only: they name the step's operations in a
+    profiler trace and in the HLO, and change no math."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.ops.decode_attention import paged_decode_attention
+    from bigdl_tpu.ops.quantized_matmul import int8_matmul
+    from bigdl_tpu.serving.cache import write_token_rows
+
+    attn0 = children["h0"]._children["attn"]
+    heads = attn0.n_head if n_head is None else int(n_head)
+    head_dim = attn0.head_dim
+    bsz = tokens.shape[0]
+    scale = 1.0 / float(np.sqrt(head_dim))
+
+    def mm(x, w, qw):
+        if qparams is not None and qw is not None:
+            return int8_matmul(x, qw[0], qw[1], impl="auto")
+        return jnp.matmul(x, w.T)
+
+    x = jnp.take(params["wte"]["weight"], tokens, axis=0)[:, None, :]
+    x = x + jnp.take(params["wpe"]["weight"], lengths, axis=0)[:, None, :]
+    for i in range(n_layer):
+        block = children[f"h{i}"]
+        p = params[f"h{i}"]
+        pa = p["attn"]
+        qb = None if qparams is None else qparams[f"h{i}"]
+        h, _ = block._children["ln1"].apply(p["ln1"], {}, x)
+        with jax.named_scope("dense"):
+            if qb is None:
+                q, k, v = block._project_qkv(pa, h)
+            else:
+                q = mm(h, pa["wq"], qb["attn"]["wq"])
+                k = mm(h, pa["wk"], qb["attn"]["wk"])
+                v = mm(h, pa["wv"], qb["attn"]["wv"])
+                if pa.get("bq") is not None:
+                    q, k, v = q + pa["bq"], k + pa["bk"], v + pa["bv"]
+
+        qh = q.reshape(bsz, heads, head_dim)
+        with jax.named_scope("kv_write"):
+            # one token row per slot, the projection's output as it
+            # comes (the cache is token-major: no split into heads)
+            kp = write_token_rows(kp, i, tables, lengths, k[:, 0, :])
+            vp = write_token_rows(vp, i, tables, lengths, v[:, 0, :])
+        with jax.named_scope("attn"):
+            # the stacked buffers and the layer's index, not kp[i]:
+            # the pages are read where they lie
+            o = paged_decode_attention(
+                qh, kp, vp, tables, lengths, layer=i,
+                page_size=page_size, scale=scale, impl=attn_impl,
+                block_pages=attn_block_pages)       # (B, H, Dh)
+        o = o.reshape(bsz, 1, heads * head_dim)
+        with jax.named_scope("dense"):
+            y = mm(o, pa["wo"], None if qb is None else qb["attn"]["wo"])
+            if psum is not None:
+                y = psum(y)
+            if pa.get("bo") is not None:
+                y = y + pa["bo"]
+        x = x + y
+        # MLP (pre-LN): bias of the row-parallel fc1 is local, the
+        # col-parallel fc2's bias is added once, after the reduction
+        h, _ = block._children["ln2"].apply(p["ln2"], {}, x)
+        with jax.named_scope("dense"):
+            h = mm(h, p["fc1"]["weight"],
+                   None if qb is None else qb["fc1"]) + p["fc1"]["bias"]
+            h = jax.nn.gelu(h)
+            h = mm(h, p["fc2"]["weight"],
+                   None if qb is None else qb["fc2"])
+            if psum is not None:
+                h = psum(h)
+            if p["fc2"].get("bias") is not None:
+                h = h + p["fc2"]["bias"]
+        x = x + h
+    h, _ = children["ln_f"].apply(params["ln_f"], {}, x)
+    with jax.named_scope("dense"):
+        logits = mm(h, params["head"]["weight"],
+                    None if qparams is None else qparams["head"])[:, 0, :]
+    return kp, vp, logits
+
+
+def paged_decode_math(children, n_layer, page_size, params, qparams,
+                      kp, vp, tables, lengths, tokens, temps, active,
+                      key, **kw):
+    """:func:`paged_decode_logits` and the engine's sampling: the whole
+    step, as the TP ``shard_map`` body runs it."""
+    from bigdl_tpu.serving.engine import sample_step
+
+    kp, vp, logits = paged_decode_logits(
+        children, n_layer, page_size, params, qparams, kp, vp, tables,
+        lengths, tokens, **kw)
+    return kp, vp, sample_step(logits, temps, active, key)
+
 
 
 def build_transformer_lm(vocab_size: int, **kw) -> TransformerLM:

@@ -39,6 +39,12 @@ from bigdl_tpu.nn.sparse import (
     SparseTensor,
     SparseTensorMath,
 )
+from bigdl_tpu.nn.latent import (
+    GatedMLP,
+    LatentAttention,
+    RMSNorm,
+)
+from bigdl_tpu.nn.experts import DroplessExperts
 from bigdl_tpu.nn.attention import (
     LayerNorm,
     MultiHeadAttention,
@@ -162,6 +168,7 @@ __all__ = (
         "TimeDistributed", "Select", "MultiRNNCell", "ConvLSTMPeephole",
         "LayerNorm", "MultiHeadAttention", "TransformerBlock",
         "PositionalEmbedding",
+        "RMSNorm", "GatedMLP", "LatentAttention", "DroplessExperts",
         "SpatialConvolutionBatchNorm", "fuse_conv_bn",
     ]
     + list(_layers_all)

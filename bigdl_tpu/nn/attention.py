@@ -274,7 +274,7 @@ class TransformerBlock(_Composite):
 
         attn = self._children["attn"]
         h, _ = self._children["ln1"].apply(params["ln1"], {}, x)
-        # scopes as in serving/engine.py paged_decode_math: names in a
+        # scopes as in models/transformer.py paged_decode_logits: names in a
         # profiler trace, no change to the math
         with jax.named_scope("dense"):
             q, k, v = self._project_qkv(params["attn"], h)

@@ -1,0 +1,181 @@
+"""A **dropless** expert layer for serving, told which experts it holds.
+
+``parallel/moe.py`` is the training layer: GShard top-1 / top-2 with a
+capacity factor that drops what overflows.  Serving cannot drop a token
+and routes to many experts, most of which live on other chips.  This
+layer computes **this chip's share** of
+
+    s = softmax(W_r x)                    (router in float32, no bias)
+    chosen = the top_k largest of s + b   (b: a selection bias, a buffer)
+    w_e = scale * s_e                     (the bias is not in the weight)
+    M(x) = sum over chosen e of w_e E_e(x)
+    E_e = gated SiLU MLP for e < n_routed,  E_e(x) = x for the
+          n_zero zero-compute experts after them
+
+The router keeps all ``n_routed + n_zero`` outputs and its ``top_k``.
+The layer holds the weights of the routed experts ``[lo, hi)`` only,
+and returns the sum over a token's chosen experts that are **held** or
+**zero-compute**; what the absent ones would have added is left out
+(their chips add it in a deployment; here nothing stands in for them).
+Zero-compute experts need no weights and are computed where the token
+lives, so the sum over all shares counts them once.
+
+How it computes: the ``tokens x top_k`` assignments are sorted by
+expert (held experts first, in order; everything else behind them), the
+sorted rows go through three grouped matrix products
+(``ops/grouped_matmul.py``: a Pallas grouped product on the TPU, chosen
+over ``jax.lax.ragged_dot`` by measurement; sized by the rows, not by
+rows x experts; an expert without a row is not read) with the held
+experts' group sizes,
+and are added back to their tokens with their weights; the identity
+experts are one weighted add.  There is no capacity: the row buffer
+holds every assignment, so no token is dropped at any imbalance.
+
+It also counts what it routed (``counts``): assignments to held,
+zero-compute and absent experts, how many held experts got a token, and
+the largest load of a held expert — the work of a step varies with the
+routing, and the serving spans carry these numbers.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from bigdl_tpu.nn.module import AbstractModule
+
+#: order of :meth:`DroplessExperts.apply`'s ``counts`` vector
+COUNT_NAMES = ("held", "zero", "absent", "hit", "max_load")
+
+
+def merge_counts(a, b):
+    """Counts of two expert layers of one step: sums, and the larger
+    ``max_load``."""
+    import jax.numpy as jnp
+
+    if a is None:
+        return b
+    return jnp.concatenate([a[:4] + b[:4], jnp.maximum(a[4:], b[4:])])
+
+
+class DroplessExperts(AbstractModule):
+    """See the module docstring.  ``held=(lo, hi)`` names the routed
+    experts whose weights this layer has (default: all of them)."""
+
+    param_names = ("router", "bias", "w_gate", "w_up", "w_down")
+
+    def __init__(self, dim: int, hidden: int, n_routed: int, n_zero: int,
+                 top_k: int, scale: float = 1.0, held=None,
+                 init: bool = True):
+        super().__init__()
+        lo, hi = (0, n_routed) if held is None else (int(held[0]),
+                                                      int(held[1]))
+        if not 0 <= lo < hi <= n_routed:
+            raise ValueError(f"held experts [{lo}, {hi}) must lie in "
+                             f"[0, {n_routed})")
+        if top_k > n_routed + n_zero:
+            raise ValueError(f"top_k {top_k} over {n_routed + n_zero} "
+                             "experts")
+        self._config = dict(dim=dim, hidden=hidden, n_routed=n_routed,
+                            n_zero=n_zero, top_k=top_k, scale=scale,
+                            held=(lo, hi))
+        self.dim, self.hidden = dim, hidden
+        self.n_routed, self.n_zero = n_routed, n_zero
+        self.top_k, self.scale = top_k, float(scale)
+        self.lo, self.hi = lo, hi
+        for n in self.param_names:
+            setattr(self, n, None)
+        if init:
+            self.reset()
+
+    @property
+    def n_held(self) -> int:
+        return self.hi - self.lo
+
+    def reset(self):
+        import jax.numpy as jnp
+
+        from bigdl_tpu.nn.latent import _draw
+
+        g = self.n_held
+        self.router = _draw((self.n_routed + self.n_zero, self.dim))
+        self.bias = jnp.zeros((self.n_routed + self.n_zero,), jnp.float32)
+        # (group, in, out): the grouped product's right-hand side
+        self.w_gate = _draw((g, self.dim, self.hidden))
+        self.w_up = _draw((g, self.dim, self.hidden))
+        self.w_down = _draw((g, self.hidden, self.dim))
+        return self
+
+    # ------------------------------------------------------------ parts
+    def route(self, params, x):
+        """``x`` (N, dim) -> chosen expert ids (N, top_k) and their
+        weights ``scale * s_e`` (N, top_k), float32."""
+        import jax
+        import jax.numpy as jnp
+
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            params["router"].astype(jnp.float32).T,
+                            precision="highest")
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(s + params["bias"].astype(jnp.float32),
+                               self.top_k)
+        w = self.scale * jnp.take_along_axis(s, idx, axis=-1)
+        return idx, w
+
+    def apply(self, params, state, input, *, mask=None, training=False,
+              rng=None):
+        """``input`` (N, dim) -> ``((y (N, dim), counts (5,) int32),
+        state)``.  ``mask`` (N,) marks the rows that are real tokens;
+        padding rows are routed nowhere, get 0, and are not counted."""
+        import jax
+        import jax.numpy as jnp
+
+        from bigdl_tpu.ops.grouped_matmul import grouped_matmul
+
+        x = input
+        n, k, g = x.shape[0], self.top_k, self.n_held
+        with jax.named_scope("moe.route"):
+            idx, w = self.route(params, x)
+            real = jnp.ones((n,), bool) if mask is None else mask
+            real = real[:, None]
+            is_zero = (idx >= self.n_routed) & real
+            is_held = (idx >= self.lo) & (idx < self.hi) & real
+            # sort the N*k assignments by held expert; the rest sort
+            # behind the last group and belong to none
+            local = jnp.where(is_held, idx - self.lo, g).reshape(-1)
+            order = jnp.argsort(local, stable=True)
+            sizes = jnp.bincount(local, length=g + 1)[:g].astype(jnp.int32)
+            token = order // k
+            valid = jnp.take(local, order) < g
+            w_sorted = jnp.where(valid, jnp.take(w.reshape(-1), order), 0.0)
+            held = jnp.sum(sizes)
+            counts = jnp.stack([
+                held, jnp.sum(is_zero),
+                jnp.sum(real) * k - held - jnp.sum(is_zero),
+                jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
+        with jax.named_scope("moe.experts"):
+            xs = jnp.take(x, token, axis=0)                  # (N*k, dim)
+            h = jax.nn.silu(grouped_matmul(xs, params["w_gate"], sizes)) \
+                * grouped_matmul(xs, params["w_up"], sizes)
+            ys = grouped_matmul(h, params["w_down"], sizes,
+                                preferred_element_type=jnp.float32)
+            # rows behind the last group are no expert's: whatever the
+            # product left there is dropped, not scaled
+            ys = jnp.where(valid[:, None], ys * w_sorted[:, None], 0.0)
+            y = jnp.zeros((n, self.dim), jnp.float32).at[token].add(ys)
+        with jax.named_scope("moe.zero"):
+            w_zero = jnp.sum(jnp.where(is_zero, w, 0.0), axis=-1)
+            y = y + x.astype(jnp.float32) * w_zero[:, None]
+        return (y.astype(x.dtype), counts), state
+
+    def __repr__(self):
+        return (f"DroplessExperts({self.n_routed}+{self.n_zero} experts, "
+                f"top {self.top_k}, held [{self.lo}, {self.hi}))")
+
+
+def counts_dict(counts) -> dict:
+    """A ``counts`` vector on the host as ``{name: int}``."""
+    vals = np.asarray(counts).reshape(-1)
+    return {name: int(v) for name, v in zip(COUNT_NAMES, vals)}
+
+
+__all__ = ["COUNT_NAMES", "DroplessExperts", "counts_dict", "merge_counts"]

@@ -387,6 +387,15 @@ SERVE_DECODE_ATTN_MS = _m(
 SERVE_DECODE_HBM_BYTES_PER_TOKEN = _m(
     "bigdl_serve_decode_hbm_bytes_per_token", "gauge", policy="max",
     doc="Modeled HBM traffic per decoded token")
+SERVE_MOE_ASSIGNMENTS_TOTAL = _m(
+    "bigdl_serve_moe_assignments_total", "counter", ("kind",), 3,
+    "Token-to-expert assignments routed by a served expert model, by "
+    "kind: held (computed here), zero (zero-compute experts), absent "
+    "(experts on other chips: left out of this chip's share)")
+SERVE_MOE_LOAD_MAX_OVER_MEAN = _m(
+    "bigdl_serve_moe_load_max_over_mean", "gauge", policy="max",
+    doc="Largest load of a held expert over the mean load of the held "
+        "experts, in the last step that routed to one (1 = even)")
 SERVE_REJECTS_TOTAL = _m(
     "bigdl_serve_rejects_total", "counter",
     doc="Admissions rejected 503 + Retry-After (queue full past the "

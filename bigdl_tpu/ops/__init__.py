@@ -16,12 +16,14 @@ play for its native kernels (SURVEY.md §4.3).
 from bigdl_tpu.ops import autotune
 from bigdl_tpu.ops.attention import dot_product_attention, flash_attention
 from bigdl_tpu.ops.decode_attention import paged_decode_attention
+from bigdl_tpu.ops.grouped_matmul import grouped_matmul
 from bigdl_tpu.ops.quantized_matmul import int8_matmul, quantize_per_channel
 
 __all__ = [
     "autotune",
     "dot_product_attention",
     "flash_attention",
+    "grouped_matmul",
     "int8_matmul",
     "paged_decode_attention",
     "quantize_per_channel",

@@ -33,6 +33,11 @@ of ``Dh`` lanes of a row:
   (tests) runs it in
   the Pallas interpreter; an accelerator compiles it or fails.
 
+A fourth body, :func:`latent_decode_attention`, serves models whose
+cache holds one compressed row a token for all heads and no V buffer
+(latent attention, ``nn/latent.py``); it has one implementation and
+takes no part in the dispatch below.
+
 Mask contract (identical across impls, pinned by tests): position
 ``pos <= length`` attends, everything else is ``-inf`` before the
 softmax — so page 0 (the reserved trash page unallocated table entries
@@ -338,6 +343,80 @@ def _pallas(q, kp, vp, tables, lengths, *, page_size: int, scale: float,
 
 
 # --------------------------------------------------------------------------
+# latent — multi-query attention over one shared compressed row a token
+# --------------------------------------------------------------------------
+
+
+def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
+                            value_width: int, layer: Optional[int] = None,
+                            block_pages: int = 16):
+    """Decode attention over a **latent** paged cache (``nn/latent.py``:
+    a token's row is ``[c | rotated k_rope]``, shared by all heads, and
+    there is no V buffer).
+
+    q: ``(B, H, R)`` — a row-shaped query a head (``W_kvb`` absorbed:
+    ``[q_nope W_k | q_rope]``); pages: one layer's ``(num_pages, P, R)``
+    pool or, with ``layer``, the stacked buffer, read where it lies;
+    tables / lengths as in :func:`paged_decode_attention` (``pos <=
+    length`` attends, everything else is ``-inf``).  Returns the mix
+    over the rows' first ``value_width`` lanes, ``(B, H, value_width)``
+    in float32 — the caller applies ``W_v`` and ``W_o``.
+
+    It is multi-query attention with ``H`` query heads on one row: both
+    contractions take the gathered rows whole, on the MXU, with float32
+    accumulation, and the softmax is in float32.  The rows are read
+    ``block_pages`` pages a slot at a time and folded into a running
+    ``(m, l, acc)`` (the online softmax of :func:`_fused`): gathered
+    whole, a slot's rows are a temporary of the bucket's size an
+    attention (335 MB at 128 slots x 2048 positions x 640 lanes) that
+    the TPU compiler, short of memory beside the weights, builds again
+    for each of its uses (26 ms a step for 8 attentions; chip run,
+    PR 26)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from bigdl_tpu.serving.cache import gather_pages
+
+    b, maxp = tables.shape
+    h = q.shape[1]
+    p = pages.shape[-2]
+    vw = int(value_width)
+    bp = _chunk_pages(maxp, block_pages)
+    qs = (q.astype(jnp.float32) * scale).astype(pages.dtype)
+    len_b = lengths[:, None, None]
+
+    def block(tbl_c, c0, m, l, acc):
+        rows = gather_pages(pages, tbl_c, layer)       # (B, bp*P, R)
+        s = jnp.einsum("bhc,bkc->bhk", qs, rows,
+                       preferred_element_type=jnp.float32)
+        pos = c0 * p + jnp.arange(bp * p)[None, None, :]
+        s = _mask_neg_inf(s, pos, len_b)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
+        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        pr = jnp.exp(s - shift[..., None])
+        alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - shift, -jnp.inf))
+        mix = jnp.einsum("bhk,bkc->bhc", pr.astype(rows.dtype),
+                         rows[..., :vw],
+                         preferred_element_type=jnp.float32)
+        return (m_new, l * alpha + jnp.sum(pr, axis=-1),
+                acc * alpha[..., None] + mix)
+
+    init = (jnp.full((b, h), -jnp.inf, jnp.float32),
+            jnp.zeros((b, h), jnp.float32),
+            jnp.zeros((b, h, vw), jnp.float32))
+    if bp == maxp:
+        _, l, acc = block(tables, 0, *init)
+    else:
+        def body(c, carry):
+            tbl_c = lax.dynamic_slice_in_dim(tables, c * bp, bp, axis=1)
+            return block(tbl_c, c * bp, *carry)
+
+        _, l, acc = lax.fori_loop(0, maxp // bp, body, init)
+    return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+# --------------------------------------------------------------------------
 # public dispatcher
 # --------------------------------------------------------------------------
 
@@ -407,5 +486,6 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
     return _dense(q, kp, vp, tables, lengths, scale=scale, layer=layer)
 
 
-__all__ = ["paged_decode_attention", "static_decode_dispatch",
-           "used_page_bucket", "decode_hbm_bytes"]
+__all__ = ["paged_decode_attention", "latent_decode_attention",
+           "static_decode_dispatch", "used_page_bucket",
+           "decode_hbm_bytes"]

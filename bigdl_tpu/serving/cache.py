@@ -11,14 +11,18 @@ return to a free list the moment a request completes, and a new
 request is admitted into the freed slot at the next step boundary.
 
 Layout (one array per K and V, all layers stacked so the decode step
-carries two device buffers instead of 2·L).  It is written down once,
+carries two device buffers instead of 2·L; a model whose cache has no
+separate V — latent attention, ``nn/latent.py`` — states ``buffers=1``
+and its row's width, and gets ``kp`` alone).  It is written down once,
 here — :func:`pool_shape`, :func:`write_token_rows`,
 :func:`write_prompt_pages`, :func:`gather_pages` — and every cache in
 the repo (the engine's, the tuner's probes, the smokes') is built,
 written and read through them:
 
-* ``kp``/``vp``: ``(n_layer, num_pages, page_size, n_head * head_dim)``
-  device arrays in the cache dtype (defaults to the model dtype — bf16
+* ``kp``/``vp``: ``(n_layer, num_pages, page_size, row)`` device
+  arrays, ``row`` the width the model states (``n_head * head_dim``
+  for per-head K/V; ``kv_rank + rope`` for a latent cache; ``n_layer``
+  counts cached attentions, two a layer in ``models/longcat_flash.py``) in the cache dtype (defaults to the model dtype — bf16
   weights get a bf16 cache, halving decode HBM traffic).  The layout is
   **token-major**: one row is one token's K (or V) for all heads, as
   the projection produces it, so a page is ``page_size`` whole rows.
@@ -54,7 +58,9 @@ from bigdl_tpu.obs import names
 class PagedKVCache:
     """Host-side page allocator + device-side paged K/V buffers."""
 
-    def __init__(self, n_layer: int, n_head: int, head_dim: int, *,
+    def __init__(self, n_layer: int, n_head: Optional[int] = None,
+                 head_dim: Optional[int] = None, *,
+                 row_width: Optional[int] = None, buffers: int = 2,
                  page_size: int = 16, num_pages: int = 64,
                  max_slots: int = 8, max_len: int = 256,
                  dtype=None):
@@ -62,9 +68,16 @@ class PagedKVCache:
 
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
+        if row_width is None:
+            if n_head is None or head_dim is None:
+                raise ValueError("give row_width, or n_head and head_dim")
+            row_width = int(n_head) * int(head_dim)
+        if buffers not in (1, 2):
+            raise ValueError(f"buffers must be 1 or 2, got {buffers}")
         self.n_layer = int(n_layer)
-        self.n_head = int(n_head)
-        self.head_dim = int(head_dim)
+        self.n_head = None if n_head is None else int(n_head)
+        self.head_dim = None if head_dim is None else int(head_dim)
+        self.row_width = int(row_width)
         self.page_size = int(page_size)
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
@@ -73,10 +86,12 @@ class PagedKVCache:
         # +1: page 0 is the reserved trash page, never allocated
         self.num_pages = max(int(num_pages), 2)
         self.dtype = jnp.dtype(dtype) if dtype is not None else jnp.float32
-        shape = pool_shape(self.num_pages, self.page_size, self.n_head,
-                           self.head_dim, n_layer=self.n_layer)
+        shape = pool_shape(self.num_pages, self.page_size, 1,
+                           self.row_width, n_layer=self.n_layer)
         self.kp = jnp.zeros(shape, self.dtype)
-        self.vp = jnp.zeros(shape, self.dtype)
+        # a model without a separate V has one buffer, not a second of
+        # zeros
+        self.vp = jnp.zeros(shape, self.dtype) if buffers == 2 else None
         self.page_tables = np.zeros(
             (self.max_slots, self.max_pages_per_slot), np.int32)
         self.lengths = np.zeros((self.max_slots,), np.int32)
@@ -153,6 +168,15 @@ class PagedKVCache:
         return list(self._slot_pages[slot])
 
     # ------------------------------------------------------ device state
+    def buffers(self) -> tuple:
+        """The device buffers a step takes and returns, in order."""
+        return (self.kp,) if self.vp is None else (self.kp, self.vp)
+
+    def set_buffers(self, bufs):
+        self.kp = bufs[0]
+        if len(bufs) > 1:
+            self.vp = bufs[1]
+
     def device_tables(self, pages: Optional[int] = None):
         """(page_tables, lengths) as jnp arrays for the next step.
 

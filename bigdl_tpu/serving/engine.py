@@ -30,6 +30,18 @@ that with the production shape:
   with its generated prefix as prompt — instead of deadlocking the
   batch.
 
+**What the engine asks of a model** (``models/transformer.py`` and
+``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
+many cached layers, the width of a token's row, one buffer or two, the
+longest context; ``paged_prefill(params, caches, prompt, t0, pages)``
+and ``paged_decode(params, caches, tables, lengths, tokens, active,
+...)``, each returning ``(caches, logits, counts)`` with ``counts`` the
+step's expert-routing counts or None.  Sampling, buckets, donation, the
+page tables, spans and ``stats()`` are the engine's; the layers'
+internals are the model's.  ``int8=True`` needs the model's
+``quantize_for_decode``, ``tp > 1`` its ``tp_decode_step``; a model
+without them is refused with a ``ValueError`` that says so.
+
 Telemetry closes the serving loop: ``bigdl_request_latency_seconds
 {engine,kind=ttft|per_token|e2e}`` histograms, token/request counters,
 batch-occupancy and queue-depth gauges (the autoscaler's signals), a
@@ -58,122 +70,12 @@ LAT_META = (names.REQUEST_LATENCY_SECONDS,
             "token, per_token = mean inter-token, e2e = submit to done)")
 
 
-def _quantize_tree(params, n_layer):
-    """Per-output-channel int8 twins of every decode matmul weight —
-    the ``quantize_per_channel`` path ``module.quantize()`` uses."""
-    from bigdl_tpu.ops.quantized_matmul import quantize_per_channel
-
-    q = {}
-    for i in range(n_layer):
-        pa = params[f"h{i}"]["attn"]
-        blk = {"attn": {}, "fc1": None, "fc2": None}
-        for w in ("wq", "wk", "wv", "wo"):
-            blk["attn"][w] = quantize_per_channel(pa[w], axis=0)
-        blk["fc1"] = quantize_per_channel(
-            params[f"h{i}"]["fc1"]["weight"], axis=0)
-        blk["fc2"] = quantize_per_channel(
-            params[f"h{i}"]["fc2"]["weight"], axis=0)
-        q[f"h{i}"] = blk
-    q["head"] = quantize_per_channel(params["head"]["weight"], axis=0)
-    return q
-
-
-def paged_decode_math(children, n_layer, page_size, params, qparams,
-                      kp, vp, tables, lengths, tokens, temps, active,
-                      key, *, n_head=None, psum=None, attn_impl="dense",
-                      attn_block_pages=0):
-    """One decode step over the paged cache — the single source of
-    truth shared by the jitted single-host step and the TP shard_map
-    body (``n_head`` is the LOCAL head count there, ``psum`` the
-    compressed block reduction).  Mirrors
-    ``TransformerBlock.decode_step`` exactly in the float path so paged
-    decode bit-matches ``generate()`` at temperature 0.
-
-    The attention body is ``ops.decode_attention.paged_decode_attention``
-    — ``attn_impl="dense"`` is the bit-match gather path, "auto" lets
-    the cached ``decode_attn`` tuner site dispatch the flash-decode
-    fused/Pallas kernels per (shape, dtype, platform); ``tables`` may
-    be the engine's used-page prefix bucket rather than the full table
-    width (same mask contract either way).
-
-    The ``jax.named_scope`` blocks (``kv_write``, ``attn``, ``dense``,
-    ``sample``) are metadata only: they name the step's operations in a
-    profiler trace and in the HLO, and change no math."""
+def sample_step(logits, temps, active, key):
+    """The decode step's next tokens, ``logits`` (B, vocab): greedy at
+    temperature 0, categorical above it, 0 for an inactive slot."""
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.ops.decode_attention import paged_decode_attention
-    from bigdl_tpu.ops.quantized_matmul import int8_matmul
-    from bigdl_tpu.serving.cache import write_token_rows
-
-    attn0 = children["h0"]._children["attn"]
-    heads = attn0.n_head if n_head is None else int(n_head)
-    head_dim = attn0.head_dim
-    bsz = tokens.shape[0]
-    scale = 1.0 / float(np.sqrt(head_dim))
-
-    def mm(x, w, qw):
-        if qparams is not None and qw is not None:
-            return int8_matmul(x, qw[0], qw[1], impl="auto")
-        return jnp.matmul(x, w.T)
-
-    x = jnp.take(params["wte"]["weight"], tokens, axis=0)[:, None, :]
-    x = x + jnp.take(params["wpe"]["weight"], lengths, axis=0)[:, None, :]
-    for i in range(n_layer):
-        block = children[f"h{i}"]
-        p = params[f"h{i}"]
-        pa = p["attn"]
-        qb = None if qparams is None else qparams[f"h{i}"]
-        h, _ = block._children["ln1"].apply(p["ln1"], {}, x)
-        with jax.named_scope("dense"):
-            if qb is None:
-                q, k, v = block._project_qkv(pa, h)
-            else:
-                q = mm(h, pa["wq"], qb["attn"]["wq"])
-                k = mm(h, pa["wk"], qb["attn"]["wk"])
-                v = mm(h, pa["wv"], qb["attn"]["wv"])
-                if pa.get("bq") is not None:
-                    q, k, v = q + pa["bq"], k + pa["bk"], v + pa["bv"]
-
-        qh = q.reshape(bsz, heads, head_dim)
-        with jax.named_scope("kv_write"):
-            # one token row per slot, the projection's output as it
-            # comes (the cache is token-major: no split into heads)
-            kp = write_token_rows(kp, i, tables, lengths, k[:, 0, :])
-            vp = write_token_rows(vp, i, tables, lengths, v[:, 0, :])
-        with jax.named_scope("attn"):
-            # the stacked buffers and the layer's index, not kp[i]:
-            # the pages are read where they lie
-            o = paged_decode_attention(
-                qh, kp, vp, tables, lengths, layer=i,
-                page_size=page_size, scale=scale, impl=attn_impl,
-                block_pages=attn_block_pages)       # (B, H, Dh)
-        o = o.reshape(bsz, 1, heads * head_dim)
-        with jax.named_scope("dense"):
-            y = mm(o, pa["wo"], None if qb is None else qb["attn"]["wo"])
-            if psum is not None:
-                y = psum(y)
-            if pa.get("bo") is not None:
-                y = y + pa["bo"]
-        x = x + y
-        # MLP (pre-LN): bias of the row-parallel fc1 is local, the
-        # col-parallel fc2's bias is added once, after the reduction
-        h, _ = block._children["ln2"].apply(p["ln2"], {}, x)
-        with jax.named_scope("dense"):
-            h = mm(h, p["fc1"]["weight"],
-                   None if qb is None else qb["fc1"]) + p["fc1"]["bias"]
-            h = jax.nn.gelu(h)
-            h = mm(h, p["fc2"]["weight"],
-                   None if qb is None else qb["fc2"])
-            if psum is not None:
-                h = psum(h)
-            if p["fc2"].get("bias") is not None:
-                h = h + p["fc2"]["bias"]
-        x = x + h
-    h, _ = children["ln_f"].apply(params["ln_f"], {}, x)
-    with jax.named_scope("dense"):
-        logits = mm(h, params["head"]["weight"],
-                    None if qparams is None else qparams["head"])[:, 0, :]
     with jax.named_scope("sample"):
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         sampled = jax.random.categorical(
@@ -181,7 +83,21 @@ def paged_decode_math(children, n_layer, page_size, params, qparams,
             axis=-1).astype(jnp.int32)
         nxt = jnp.where(temps > 0.0, sampled, greedy)
         nxt = jnp.where(active, nxt, 0)
-    return kp, vp, nxt
+    return nxt
+
+
+def sample_first(logits, temp, key):
+    """A prompt's first token, ``logits`` (1, vocab)."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sampled = jax.random.categorical(
+            key, logits / jnp.maximum(temp, 1e-6),
+            axis=-1).astype(jnp.int32)
+        first = jnp.where(temp > 0.0, sampled, greedy)
+    return first[0]
 
 
 class _Active:
@@ -243,17 +159,24 @@ class LMEngine:
         if self.int8 and self.tp > 1:
             raise ValueError("int8 decode and tp-sharded decode are "
                              "currently exclusive")
-        mc = model._config
-        self.max_len = int(mc["max_len"])
-        self.n_layer = model.n_layer
-        self.n_head = int(mc["n_head"])
-        self.head_dim = model.dim // self.n_head
+        for feature, on, method in (
+                ("int8=True", self.int8, "quantize_for_decode"),
+                ("tp > 1", self.tp > 1, "tp_decode_step")):
+            if on and not hasattr(model, method):
+                raise ValueError(
+                    f"{type(model).__name__} does not offer {feature} "
+                    f"serving (it has no {method})")
+        # the model states its cache; the engine builds and owns it
+        spec = model.cache_spec(self.params)
+        self._cache_spec = spec
+        self.max_len = int(spec["max_len"])
         if cache_dtype is None:
-            cache_dtype = self.params["wte"]["weight"].dtype
+            cache_dtype = spec["dtype"]
         pages = num_pages or cfg.num_pages or (
             1 + self.max_batch * -(-self.max_len // self.page_size))
         self.cache = PagedKVCache(
-            self.n_layer, self.n_head, self.head_dim,
+            int(spec["layers"]), spec.get("heads"), spec.get("head_dim"),
+            row_width=int(spec["row_width"]), buffers=int(spec["buffers"]),
             page_size=self.page_size, num_pages=pages,
             max_slots=self.max_batch, max_len=self.max_len,
             dtype=cache_dtype)
@@ -261,7 +184,7 @@ class LMEngine:
         self._slots: List[Optional[_Active]] = [None] * self.max_batch
         self._stash: collections.deque = collections.deque()
         self._key = jax.random.key(int(seed))
-        self._qparams = (_quantize_tree(self.params, self.n_layer)
+        self._qparams = (model.quantize_for_decode(self.params)
                         if self.int8 else None)
         self._order = 0
         self._steps = 0
@@ -284,10 +207,8 @@ class LMEngine:
         self._decode_ms_sum = 0.0
         self._weight_bytes = self._decode_weight_bytes()
         if self.tp > 1:
-            from bigdl_tpu.serving.tp import build_tp_decode_step
-
-            self._step_fn = build_tp_decode_step(
-                model, tp=self.tp, wire=wire, page_size=self.page_size,
+            self._step_fn = model.tp_decode_step(
+                tp=self.tp, wire=wire, page_size=self.page_size,
                 max_batch=self.max_batch,
                 positions=self.cache.padded_positions(),
                 attn_impl=self.decode_attn)
@@ -335,10 +256,34 @@ class LMEngine:
             "Analytic HBM bytes streamed per generated token (decode "
             "weights + the KV pages the step's page-table bucket "
             "names)")
+        self._moe_counter = self._moe_gauge = None  # an expert model's
         self._swap_counter = reg.counter(
             names.SERVE_WEIGHT_SWAPS_TOTAL,
             "Live weight hot-swaps completed, by promoted version",
             labels=("version",))
+
+    def _note_routing(self, counts) -> dict:
+        """An expert model's routing counts (``nn/experts.py``
+        ``COUNT_NAMES``, summed over the step's expert layers) into the
+        registry; returns them as the span's ``moe_*`` attributes."""
+        from bigdl_tpu.nn.experts import counts_dict
+
+        c = counts_dict(counts)
+        if self._moe_counter is None:
+            reg = obs.get_registry()
+            self._moe_counter = reg.counter(
+                names.SERVE_MOE_ASSIGNMENTS_TOTAL,
+                "Token-to-expert assignments by kind (held, zero, "
+                "absent)", labels=("kind",))
+            self._moe_gauge = reg.gauge(
+                names.SERVE_MOE_LOAD_MAX_OVER_MEAN,
+                "Largest over mean load of the held experts, last step")
+        for kind in ("held", "zero", "absent"):
+            self._moe_counter.labels(kind=kind).inc(c[kind])
+        slots = self._cache_spec.get("expert_slots")
+        if c["held"] and slots:
+            self._moe_gauge.set(c["max_load"] * slots / c["held"])
+        return {f"moe_{k}": v for k, v in c.items()}
 
     def _decode_weight_bytes(self) -> float:
         """Static per-step weight-stream bytes of the decode matmuls —
@@ -393,7 +338,7 @@ class LMEngine:
             params = jax.tree.map(
                 jnp.asarray, params,
                 is_leaf=lambda x: x is None or hasattr(x, "shape"))
-        qparams = (_quantize_tree(params, self.n_layer)
+        qparams = (self.model.quantize_for_decode(params)
                    if self.int8 else None)
         with self._lock:
             self.params = params
@@ -414,19 +359,24 @@ class LMEngine:
     def _build_step(self):
         import jax
 
-        children = self.model._children
-        n_layer, page_size = self.n_layer, self.page_size
+        model, page_size = self.model, self.page_size
         qparams = self._qparams
         attn_impl = self.decode_attn
+        n = len(self.cache.buffers())
 
-        def step(params, kp, vp, tables, lengths, tokens, temps,
-                 active, key):
-            return paged_decode_math(
-                children, n_layer, page_size, params, qparams, kp, vp,
-                tables, lengths, tokens, temps, active, key,
-                attn_impl=attn_impl)
+        def step(params, *rest):
+            # rest: the cache's buffers (donated), then tables, lengths,
+            # tokens, temps, active, key
+            tables, lengths, tokens, temps, active, key = rest[n:]
+            caches, logits, counts = model.paged_decode(
+                params, rest[:n], tables, lengths, tokens, active,
+                page_size=page_size, qparams=qparams, attn_impl=attn_impl)
+            nxt = sample_step(logits, temps, active, key)
+            # the routing counts ride back with the tokens
+            return (*caches, nxt) if counts is None \
+                else (*caches, nxt, counts)
 
-        return jax.jit(step, donate_argnums=(1, 2))
+        return jax.jit(step, donate_argnums=tuple(range(1, 1 + n)))
 
     def _decode_impl_for(self, bucket: int) -> str:
         """The decode-attention impl this step's bucket resolves to —
@@ -443,12 +393,13 @@ class LMEngine:
             try:
                 from bigdl_tpu.ops import autotune
 
-                if autotune.enabled():
-                    heads = self.n_head // self.tp
-                    q_dtype = self.params["wte"]["weight"].dtype
+                spec = self._cache_spec
+                # a cache of per-head K/V rows is what the site tunes
+                if autotune.enabled() and "heads" in spec:
                     rec = autotune.decide_decode_attn(
-                        (self.max_batch, heads, self.head_dim),
-                        self.page_size, bucket, q_dtype,
+                        (self.max_batch, spec["heads"] // self.tp,
+                         spec["head_dim"]),
+                        self.page_size, bucket, spec["dtype"],
                         kv_dtype=self.cache.dtype)
                     if rec is not None:
                         impl = rec.get("impl", "dense")
@@ -462,42 +413,22 @@ class LMEngine:
         if fn is not None:
             return fn
         import jax
-        import jax.numpy as jnp
-        from jax import lax
 
-        from bigdl_tpu.serving.cache import write_prompt_pages
+        model = self.model
+        n = len(self.cache.buffers())
 
-        children = self.model._children
-        n_layer = self.n_layer
-        dim = self.model.dim
+        def prefill(params, *rest):
+            # rest: the cache's buffers (donated), then the prompt
+            # (1, bucket) zero-padded past t0, t0, the bucket's pages,
+            # the temperature, the key
+            prompt, t0, pages, temp, key = rest[n:]
+            caches, logits, counts = model.paged_prefill(
+                params, rest[:n], prompt, t0, pages)
+            first = sample_first(logits, temp, key)
+            return (*caches, first) if counts is None \
+                else (*caches, first, counts)
 
-        def prefill(params, kp, vp, prompt, t0, pages, temp, key):
-            # prompt is (1, bucket), zero-padded past t0 — causal
-            # attention keeps the real prefix exact
-            x = jnp.take(params["wte"]["weight"], prompt, axis=0)
-            x = x + params["wpe"]["weight"][:bucket][None]
-            for i in range(n_layer):
-                # the block's prefill names its own attn and dense parts
-                x, k, v = children[f"h{i}"].prefill_rows(
-                    params[f"h{i}"], x)
-                with jax.named_scope("kv_write"):
-                    # one scatter a layer over the bucket's pages
-                    kp = write_prompt_pages(kp, i, pages, k[0])
-                    vp = write_prompt_pages(vp, i, pages, v[0])
-            h = lax.dynamic_slice(x, (0, t0 - 1, 0), (1, 1, dim))
-            h, _ = children["ln_f"].apply(params["ln_f"], {}, h)
-            with jax.named_scope("dense"):
-                logits, _ = children["head"].apply(params["head"], {}, h)
-            logits = logits[:, 0, :]
-            with jax.named_scope("sample"):
-                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                sampled = jax.random.categorical(
-                    key, logits / jnp.maximum(temp, 1e-6),
-                    axis=-1).astype(jnp.int32)
-                first = jnp.where(temp > 0.0, sampled, greedy)
-            return kp, vp, first[0]
-
-        fn = jax.jit(prefill, donate_argnums=(1, 2))
+        fn = jax.jit(prefill, donate_argnums=tuple(range(1, 1 + n)))
         self._prefill_fns[bucket] = fn
         return fn
 
@@ -608,15 +539,19 @@ class LMEngine:
         prompt[0, :t0] = req.payload
         self._key, sub = jax.random.split(self._key)
         tracer = self._tracer
+        n = len(self.cache.buffers())
         with tracer.span(spans.SPAN_STEP_PREFILL, step=self._steps,
-                         bucket=bucket, prompt_len=t0, request=req.id):
-            kp, vp, first = self._prefill_fn(bucket)(
-                self.params, self.cache.kp, self.cache.vp,
+                         bucket=bucket, prompt_len=t0,
+                         request=req.id) as span_id:
+            out = self._prefill_fn(bucket)(
+                self.params, *self.cache.buffers(),
                 jnp.asarray(prompt), t0, jnp.asarray(page_arg),
                 float(req.temperature), sub)
-            self.cache.kp, self.cache.vp = kp, vp
+            self.cache.set_buffers(out[:n])
             self.cache.lengths[slot] = t0
-            tok = int(first)
+            tok = int(out[n])
+            if len(out) > n + 1:
+                tracer.add_attrs(span_id, **self._note_routing(out[n + 1]))
         if req.trace is not None:
             req._tr_admits.append(
                 {"t": t_admit, "dur": time.monotonic() - t_admit,
@@ -800,24 +735,37 @@ class LMEngine:
         # a LIVE span around the batched decode dispatch+resolve (not a
         # retroactive reqtrace hop): the continuous profiler attributes
         # samples landing here to the decode phase by name
+        n = len(self.cache.buffers())
         with tracer.span(spans.SPAN_STEP_DECODE, bucket=bucket,
-                         active=len(active_slots)):
-            kp, vp, nxt = self._step_fn(
-                self.params, self.cache.kp, self.cache.vp, tables,
+                         active=len(active_slots)) as span_id:
+            out = self._step_fn(
+                self.params, *self.cache.buffers(), tables,
                 lengths, jnp.asarray(tokens), jnp.asarray(temps),
                 jnp.asarray(active), sub)
-            self.cache.kp, self.cache.vp = kp, vp
-            nxt = np.asarray(nxt)
+            self.cache.set_buffers(out[:n])
+            nxt = np.asarray(out[n])
+            if len(out) > n + 1:
+                # an expert model's step: what it routed, and the rows
+                # of context it had to read
+                tracer.add_attrs(
+                    span_id, **self._note_routing(out[n + 1]),
+                    context_tokens=int(sum(
+                        int(self.cache.lengths[i]) + 1
+                        for i in active_slots)))
         step_ms = (time.perf_counter() - t0) * 1000.0
         with tracer.span(spans.SPAN_STEP_EMIT, step=step):
             self._steps += 1
             self._decode_ms_sum += step_ms
             self._decode_ms_gauge.set(self._decode_ms_sum / self._steps)
             kv_item = self.cache.dtype.itemsize
+            # without per-head rows a row is one head; one buffer is
+            # half of K + V
+            heads = self._cache_spec.get("heads", 1)
             step_bytes = self._weight_bytes + \
-                self.n_layer * decode_hbm_bytes(
+                self.cache.n_layer * len(self.cache.buffers()) / 2.0 \
+                * decode_hbm_bytes(
                     "dense" if impl == "dense" else "fused",
-                    self.max_batch, self.n_head, self.head_dim,
+                    self.max_batch, heads, self.cache.row_width // heads,
                     self.page_size, bucket, kv_item)
             self._decode_bytes_gauge.set(step_bytes / len(active_slots))
             self._occ_sum += len(active_slots) / self.max_batch
@@ -872,6 +820,11 @@ class LMEngine:
             while not self._stop:
                 if not self.pump(wait_s=0.02):
                     time.sleep(0.002)
+                elif self.draining:
+                    # a drain waits for the lock between two cycles; the
+                    # lock is not fair, and on an idle host this loop
+                    # would take it back at once until the work is done
+                    time.sleep(0.001)
 
         self._thread = threading.Thread(
             target=loop, name="bigdl-serve-lm", daemon=True)
@@ -945,4 +898,4 @@ class LMEngine:
         }
 
 
-__all__ = ["LMEngine", "paged_decode_math"]
+__all__ = ["LMEngine", "sample_first", "sample_step"]

@@ -43,6 +43,28 @@ Three families:
   ``serve.emit``          after the read-back: token bookkeeping and
                           stamps, ``_complete``, gauges
   ======================  ==============================================
+  An **expert model**'s ``serve.decode_step`` and ``serve.prefill``
+  also carry what the step routed, summed over its expert layers
+  (``nn/experts.py`` ``COUNT_NAMES``; the counts ride back from the
+  device with the tokens, in the same transfer), listed here once:
+
+  ====================  ================================================
+  ``moe_held``          assignments to experts this chip holds
+  ``moe_zero``          assignments to zero-compute (identity) experts
+  ``moe_absent``        assignments to experts on other chips (left out
+                        of this chip's share); the three add up to
+                        ``top_k`` x tokens x expert layers
+  ``moe_hit``           held experts that got a token (their weights are
+                        what the grouped product has to read)
+  ``moe_max_load``      the largest load of a held expert, a layer
+  ``context_tokens``    ``serve.decode_step`` only: the sum of the
+                        active slots' contexts, the step's own token
+                        included (the cache rows attention has to read)
+  ====================  ================================================
+
+  The registry has the same counts as
+  ``bigdl_serve_moe_assignments_total{kind}`` and
+  ``bigdl_serve_moe_load_max_over_mean`` (``obs/names.py``).
 * ``EVENT_*`` — point events the engine/simulator stamp regardless of
   request tracing.
 """

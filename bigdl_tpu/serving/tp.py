@@ -84,7 +84,7 @@ def build_tp_decode_step(model, *, tp: int, wire=None, page_size: int,
     from bigdl_tpu.optim.distri_optimizer import _shard_map
     from bigdl_tpu.parallel import wire as W
     from bigdl_tpu.parallel.tensor_parallel import param_specs
-    from bigdl_tpu.serving.engine import paged_decode_math
+    from bigdl_tpu.models.transformer import paged_decode_math
 
     del positions  # shapes flow through shard_map; kept for the API
     tp = int(tp)

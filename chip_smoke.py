@@ -56,6 +56,7 @@ FULL = dict(
         (1, 2, 2048, 32768, 128),
     ],
     decode=dict(b=8, h=8, d=64, p=16, maxp=32),
+    grouped=dict(m=1536, k=6144, n=2048, sizes=(3, 0, 5, 1, 2, 9, 0, 4)),
     conv=[  # (N, C, H, O, k, stride): ResNet-50 sites
         (8, 256, 56, 64, 1, 1),
         (8, 128, 28, 128, 3, 1),
@@ -69,6 +70,7 @@ TINY = dict(
     prompt_lens=(5, 20), new_tokens=8,
     flash=[(1, 2, 128, 128, 16), (1, 2, 128, 256, 16)],
     decode=dict(b=4, h=2, d=16, p=8, maxp=4),
+    grouped=dict(m=256, k=256, n=128, sizes=(37, 0, 90, 5)),
     conv=[(2, 16, 8, 16, 1, 1), (2, 8, 8, 16, 3, 1), (2, 8, 8, 16, 3, 2)],
     multichip=dict(devices=4, batch=16, steps=3),
 )
@@ -271,6 +273,58 @@ def _round_of_requests(server, prompts, new_tokens: int) -> list:
     return out
 
 
+def _serve_latent_expert_model(platform) -> dict:
+    """The other kind of block behind the same engine: a small
+    LongCat-Flash (latent attention over a one-buffer latent cache, the
+    double layer, a dropless expert layer holding 8 of 16 experts beside
+    8 zero-compute ones), its greedy tokens scored by the plain
+    reference's logit gap.  On the chip float32 products run in bfloat16
+    passes, so the limit is a bfloat16 one."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bigdl_tpu.models import longcat_flash_reference as ref
+    from bigdl_tpu.models.longcat_flash import build_longcat_flash
+    from bigdl_tpu.serving import LMEngine
+
+    config = dict(
+        vocab_size=96, hidden_size=64, num_layers=2, num_attention_heads=4,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, ffn_hidden_size=128,
+        expert_ffn_hidden_size=32, n_routed_experts=8, router_experts=16,
+        held_experts=[4, 12], zero_expert_num=8, moe_topk=4,
+        routed_scaling_factor=6.0, rms_norm_eps=1e-5, rope_theta=1e7,
+        max_len=64, initializer_range=0.1)
+    sizes = ref.sizes_of(config)
+    params = ref.init_params(7, sizes, jnp.float32)
+    engine = LMEngine(build_longcat_flash(config, params=params),
+                      params=params, max_batch=4, page_size=8)
+    rs = np.random.RandomState(5)
+    prompts = [[int(t) for t in rs.randint(0, 96, n)] for n in (5, 9, 14)]
+    reqs = [engine.submit(p, 12) for p in prompts]
+    engine.run_until_idle(timeout_s=600)
+    if not on_platform((engine.params, engine.cache.buffers()), platform):
+        raise AssertionError("serve: the latent cache or the expert "
+                             f"model's parameters are not on {platform}")
+    gaps = np.concatenate([
+        ref.served_gaps(params, sizes, p, list(r.tokens))[0]
+        for p, r in zip(prompts, reqs)])
+    if any(r.error for r in reqs) or gaps.size != 36 \
+            or float(gaps.mean()) > 0.05:
+        raise AssertionError(
+            f"serve: latent expert model: errors "
+            f"{[r.error for r in reqs]}, logit gaps {gaps.tolist()}")
+    log(f"serve: small LongCat-Flash behind the same engine: 36 greedy "
+        f"tokens, mean logit gap to the float32 reference "
+        f"{gaps.mean():.4f} (largest {gaps.max():.4f}; limit of the mean "
+        f"0.05), one cache buffer {tuple(engine.cache.kp.shape)}")
+    engine.close()
+    return {"tokens": int(gaps.size), "served_gap_mean": float(gaps.mean()),
+            "served_gap_max": float(gaps.max()),
+            "cache_buffers": len(engine.cache.buffers()),
+            "cache_shape": list(engine.cache.kp.shape)}
+
+
 def phase_serve(cfg, platform, compiles) -> dict:
     import numpy as np
 
@@ -323,6 +377,7 @@ def phase_serve(cfg, platform, compiles) -> dict:
         server.close()
         engine.close()
     info = {
+        "latent_expert_model": _serve_latent_expert_model(platform),
         "requests": len(prompts), "prompt_lens": lens, "new_tokens": new,
         "first_round_s": rounds[0], "second_round_s": rounds[1],
         "decode_steps": stats["steps"],
@@ -458,6 +513,30 @@ def phase_kernels(cfg, platform, compiles) -> dict:
             f"paged decode B={c['b']} H={c['h']} Dh={c['d']} P={c['p']} "
             f"pages={c['maxp']} {name} vs dense",
             decode, (q, kp, vp), want, TOL[name], rehearsal)
+
+    # ---- the expert layer's grouped product, against lax.ragged_dot
+    from bigdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    c = cfg["grouped"]
+    sizes = jnp.asarray(c["sizes"], jnp.int32)
+    # rows behind the last group are nobody's: masked on both sides
+    mine = (jnp.arange(c["m"]) < int(sizes.sum()))[:, None]
+    lhs = jnp.asarray(rs.randn(c["m"], c["k"]), jnp.bfloat16)
+    rhs = jnp.asarray(rs.randn(len(c["sizes"]), c["k"], c["n"])
+                      * c["k"] ** -0.5, jnp.bfloat16)
+
+    def grouped(lhs, rhs, impl="pallas"):
+        return jnp.where(mine, grouped_matmul(
+            lhs, rhs, sizes, impl=impl,
+            preferred_element_type=jnp.float32), 0.0)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(
+            lambda a, b: grouped(a, b, impl="ragged"))(*f32((lhs, rhs))))
+    out["grouped_matmul_bf16"] = _run_kernel(
+        f"grouped product M={c['m']} K={c['k']} N={c['n']} "
+        f"groups={len(c['sizes'])} bf16 vs ragged_dot",
+        grouped, (lhs, rhs), want, TOL["bfloat16"], rehearsal)
 
     # ---- conv + BN statistics, forward (the kernel) and its custom vjp
     for n, ci, hw, o, ksz, stride in cfg["conv"]:

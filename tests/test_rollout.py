@@ -352,10 +352,16 @@ class TestHandoffVersionPin:
         p = [5, 11, 2, 7, 3, 9]
         res = {}
         t = threading.Thread(target=lambda: res.update(
-            router.route(p, 24, session="pin-session")))
+            router.route(p, 56, session="pin-session")))
         t.start()
-        _time.sleep(0.3)
-        router.begin_drain("a", deadline_s=0.05)
+        # drain as soon as the request decodes on 'a', with no time to
+        # finish (a fixed sleep and a deadline of 50 ms raced the
+        # request; and on an idle host the engine's loop kept the lock
+        # from the drain until the request was done: LMEngine.start)
+        waited = _time.time() + 30.0
+        while not ea.active_count() and _time.time() < waited:
+            _time.sleep(0.001)
+        router.begin_drain("a", deadline_s=0.0)
         t.join(60)
         for eng in (ea, eb, ec):
             eng.close()
@@ -364,7 +370,7 @@ class TestHandoffVersionPin:
             f"replay landed on {res['replica']} — version pin ignored"
         assert res["handoffs"] >= 1
         assert [int(x) for x in list(p) + res["tokens"]] \
-            == _ref(lm_model, lm_params, p, 24), \
+            == _ref(lm_model, lm_params, p, 56), \
             "version-pinned replay is not bit-equal to generate()"
         assert _counter_total("bigdl_rollout_version_mismatch_total") \
             > before, "the mismatch refusal was not counted"

@@ -245,6 +245,9 @@ def _layer_cases():
         (N.MixtureTable(), (np.abs(v[:, :2]), (v, v))),
         (N.MapTable(L.Linear(6, 4)), (v, v)),
         (N.Bottle(L.Linear(6, 4), 2, 2), seq),
+        (N.RMSNorm(6), seq),
+        (N.GatedMLP(6, 10), seq),
+        (N.LatentAttention(6, 2, 4, 4, 2, 2, 2), seq),
     ]
     return cases
 
@@ -300,6 +303,9 @@ def test_every_exported_layer_is_covered_or_known():
         "RoiPooling",
         # fused conv+BN (own parity + round-trip specs in test_fused)
         "SpatialConvolutionBatchNorm",
+        # returns (output, routing counts): own round-trip spec in
+        # test_longcat_flash.py
+        "DroplessExperts",
     }
     missing = []
     for name in dir(N):

@@ -255,3 +255,130 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
             spec((b, 32), jnp.int32), spec((b,), jnp.int32))
         assert "tpu_custom_call" in lowered.as_text()
         lowered.compile()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("row_align", [128, 1])
+def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
+                                                             row_align,
+                                                             monkeypatch):
+    """LongCat-Flash's decode step and a prefill at the published
+    widths (one double layer, 16 of 512 experts held, 128 slots, the
+    default pool of 16385 pages), from shapes alone, compiled for the
+    described v5e with the latent cache donated.
+
+    A token's row is 512 + 64 = 576 values, 4.5 tiles of 128 lanes.
+    Stored as it is (``row_align=1``) the chip's compiler takes the
+    cache in another dimension order than the program works in and
+    copies the whole of it, in and out, in every program; padded to 640
+    lanes (the model's default) the buffer handed in is the buffer
+    worked on.  That is the evidence the padding was chosen by; run it
+    before spending chip minutes on the latent cache."""
+    import functools
+    import re
+    import types
+
+    from bigdl_tpu.models import longcat_flash_reference as ref
+    from bigdl_tpu.models.longcat_flash import LongCatFlash, PUBLISHED
+    from bigdl_tpu.serving.engine import LMEngine
+
+    # the program asks the backend whether to take its kernels: here it
+    # is being compiled for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = one_chip
+    dt = jnp.bfloat16
+    sizes = dict(PUBLISHED, num_layers=1, vocab_size=256)
+    slots, page, max_len = 128, 16, 2048
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    # the weights' shapes, from the reference's initialiser (nothing is
+    # drawn); the model is built around them and draws none either
+    cfg = dict(sizes, router_experts=512, n_routed_experts=16,
+               held_experts=[0, 16], max_len=max_len)
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(cfg), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    probe = LongCatFlash(max_len=max_len, held_experts=(0, 16),
+                         row_align=row_align, params=weights, **sizes)
+    cs = probe.cache_spec(weights)
+    assert cs["row_width"] == (640 if row_align == 128 else 576)
+    buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
+    # the engine's own builders, given shapes in place of an engine
+    eng = types.SimpleNamespace(
+        model=probe, page_size=page, _qparams=None, decode_attn="auto",
+        cache=types.SimpleNamespace(buffers=lambda: (buf,)),
+        _prefill_fns={})
+    key = spec((), jax.random.key(0).dtype)
+    b = slots
+    programs = {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, spec((b, 128), jnp.int32), spec((b,), jnp.int32),
+            spec((b,), jnp.int32), spec((b,), jnp.float32),
+            spec((b,), jnp.bool_), key),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, spec((1, 256), jnp.int32), spec((), jnp.int32),
+            spec((256 // page,), jnp.int32), spec((), jnp.float32), key)}
+    dims = ",".join(str(n) for n in buf.shape)
+    whole = re.compile(r"= bf16\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
+    buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
+    for name, lowered in programs.items():
+        # the expert layer's grouped products are the Pallas kernel (two
+        # distinct ones: up / gate, and down)
+        assert lowered.as_text().count("tpu_custom_call") >= 2, name
+        compiled = lowered.compile()
+        ops = {}
+        for line in compiled.as_text().splitlines():
+            m = whole.search(line)
+            if m is not None:
+                op = m.group(1)
+                if op == "fusion" and "kv_write/scatter" in line:
+                    op = "scatter fusion"
+                ops[op] = ops.get(op, 0) + 1
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        print(f"row {cs['row_width']} {name}: whole-cache instructions "
+              f"{ops}, temporaries {temp / 1e6:.1f} MB, the cache "
+              f"{buffer_bytes / 1e6:.1f} MB")
+        if row_align == 1:
+            # 576 lanes: the whole cache is copied (why the row is padded)
+            assert ops.get("copy", 0) >= 1, (name, ops)
+            assert temp > buffer_bytes // 2, (name, temp)
+        else:
+            # get-tuple-element: the attention's loop over page blocks
+            # takes the buffer as it is (a view, not a copy)
+            assert set(ops) <= {"parameter", "scatter", "scatter fusion",
+                                "get-tuple-element"}, (name, ops)
+            # a block of gathered rows and its scores beside the cache;
+            # nothing of the cache's own size
+            assert temp < buffer_bytes // 2, (name, temp, buffer_bytes)
+
+
+@pytest.mark.slow
+def test_the_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):
+    """megablox's grouped product at the tilings ``ops/grouped_matmul.py``
+    picks for LongCat-Flash's experts (16 held, 6144 x 2048 and back),
+    at the decode step's 1536 rows, a 256-token prefill's 3072 and a
+    16-token prefill's 192 (padded to whole row tiles): Mosaic compiles
+    each without a chip."""
+    from bigdl_tpu.ops.grouped_matmul import _tiling, grouped_matmul
+
+    assert _tiling(6144, 2048) == (128, 2048, 1024)
+    assert _tiling(2048, 6144) == (128, 2048, 1024)
+    assert _tiling(64, 32) is None
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for m in (1536, 3072, 192):
+        for k, n, out in ((6144, 2048, jnp.bfloat16),
+                          (2048, 6144, jnp.float32)):
+            lowered = jax.jit(
+                lambda a, b, s, out=out: grouped_matmul(
+                    a, b, s, impl="pallas", interpret=False,
+                    preferred_element_type=out)).lower(
+                spec((m, k), jnp.bfloat16), spec((16, k, n), jnp.bfloat16),
+                spec((16,), jnp.int32))
+            assert "tpu_custom_call" in lowered.as_text()
+            lowered.compile()
