@@ -56,6 +56,12 @@ class ArrayDataSet(LocalDataSet):
     ragged tail batch in train mode (keeps it for eval) so the jitted
     step never retraces on a new batch shape — the TPU analogue of the
     reference's fixed-size MiniBatch packing.
+
+    ``data()`` yields arrays that are the caller's to keep
+    (``list(ds.data())`` holds distinct batches).  ``data_into(gather)``
+    is for the trainer's loop, which has its batches gathered into a
+    ring of reused host buffers and says itself when one may be
+    overwritten (``native.StagingRing``).
     """
 
     def __init__(self, features, labels, batch_size: int = 32,
@@ -77,19 +83,38 @@ class ArrayDataSet(LocalDataSet):
         return self._n
 
     def data(self, train: bool = True):
+        # single float32 feature arrays assemble through the native
+        # row gather (bigdl_tpu/native — the BigDL-core replacement for
+        # the host data plane); every batch is an array of the
+        # caller's own
+        from bigdl_tpu import native as _native
+
+        return self._batches(train, _native.gather_rows)
+
+    def data_into(self, gather, train: bool = True):
+        """The batches of :meth:`data`, each float32 feature batch
+        produced by ``gather(features, rows)`` into memory of the
+        caller's choosing (the trainer's feed passes
+        ``native.StagingRing.gather``).  Such a batch belongs to the
+        caller: what ``gather`` returns is yielded as it is, and may be
+        overwritten as soon as the caller's own rule says so, so this is
+        for a loop that knows when it has finished with a batch.
+        Features that never go through the row gather (several arrays,
+        another dtype), all labels, and every batch of a subclass that
+        makes its own in ``data`` are arrays of their own, as from
+        :meth:`data`."""
+        if type(self).data is not ArrayDataSet.data:
+            return self.data(train)
+        return self._batches(train, gather)
+
+    def _batches(self, train, gather):
         idx = np.arange(self._n)
         if train and self.shuffle:
             idx = RandomGenerator.RNG.randperm(self._n)
         bs = self.batch_size
         n_full = self._n // bs
-        # single float32 feature arrays assemble through the native
-        # multi-threaded row gather (bigdl_tpu/native — the BigDL-core
-        # replacement for the host data plane)
-        gather = None
-        if not self._multi and self.features.dtype == np.float32:
-            from bigdl_tpu import native as _native
-
-            gather = _native.gather_rows
+        if self._multi or self.features.dtype != np.float32:
+            gather = None
         for b in range(n_full):
             sel = idx[b * bs : (b + 1) * bs]
             if self._multi:

@@ -327,6 +327,13 @@ CHECKPOINT_VERIFY_FAILURES_TOTAL = _m(
     "bigdl_checkpoint_verify_failures_total", "counter",
     doc="Checkpoint read-back verifications that failed")
 
+# --------------------------------------------------------------- feed
+FEED_STAGING_BATCHES_TOTAL = _m(
+    "bigdl_feed_staging_batches_total", "counter", labels=("staging",),
+    cardinality=2,
+    doc="Training batches gathered into a reused or a new host buffer "
+        "(staging=reused|new; all reused once the ring is full)")
+
 # --------------------------------------------------------------- streaming
 # fleet-wide buffered-records level is additive — explicit opt-in
 STREAM_BUFFER_DEPTH = _m(  # graftlint: disable=RD007

@@ -52,7 +52,10 @@ name               kind   covers
                           on every chip (``bytes=``, ``chips=``); closed by
                           a waiter thread, never by the loop's
 ``feed.gather``    retro  the prefetch thread producing one batch (the
-                          native row gather)
+                          native row gather); from a ``StagingRing`` also
+                          ``bytes=``, ``threads=`` (row ranges copied side
+                          by side) and ``staging="reused"|"new"`` (into a
+                          buffer that came back, or one made for it)
 ``validation``, ``checkpoint``, ``build_train_step``  live, as named
 =================  =====  ==================================================
 """

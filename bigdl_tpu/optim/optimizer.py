@@ -700,6 +700,11 @@ class LocalOptimizer(BaseOptimizer):
         # host-device synchronizations either way
         tracer = self._obs_tracer = obs.get_tracer()
         self._h2d_waiter = _H2DWaiter(tracer) if tracer.enabled else None
+        # the feed's reused host batches and gather threads, for as long
+        # as this call (nothing is allocated before the first batch)
+        from bigdl_tpu.native import StagingRing
+
+        self._staging = StagingRing()
         self._obs_runtime = obs.get_runtime() if obs.active() else None
         # goodput ledger (obs/goodput.py): interval stamps ride the
         # span boundaries below — the shared no-op object when obs is
@@ -799,6 +804,8 @@ class LocalOptimizer(BaseOptimizer):
             if self._h2d_waiter is not None:
                 self._h2d_waiter.close()
                 self._h2d_waiter = None
+            self._staging.close()
+            self._staging = None
             # export the observability artifacts LAST so the snapshot
             # sees the final counter values (incl. any failure recorded
             # by the flush above); off = no-op
@@ -862,13 +869,20 @@ class LocalOptimizer(BaseOptimizer):
             if t is not None
         )
         pending = []  # [(n, loss_dev, ok_dev, batch_size, t_dispatch,
-        #                 health_dev_or_None)]
+        #                 health_dev_or_None, host_batch)]
+        # host batches are lent by the ring (native.StagingRing): each
+        # goes back when its step's loss has been read, or when no step
+        # will see it; until then neither the copy to the chips nor the
+        # step has provably finished reading the host array
+        ring = self._staging
 
-        def resolve(n, loss_dev, ok_dev, bs, t0, health_dev=None):
+        def resolve(n, loss_dev, ok_dev, bs, t0, health_dev=None,
+                    host_batch=None):
             # the loop's thread waits for the chip here and nowhere else
             with tracer.span("loss_readback", step=n):
                 loss_val = float(loss_dev)
                 ok_val = bool(ok_dev)
+            ring.release(host_batch)
             # in pipelined steady state this spans dispatch -> observed
             # completion (~ device step time + one iteration's host work)
             dt = time.perf_counter() - t0
@@ -974,9 +988,11 @@ class LocalOptimizer(BaseOptimizer):
                 self._pending_fast_forward, 0
             # first_step: the step the epoch's first batch trains, for
             # the worker's feed.gather spans
+            data_into = getattr(self.dataset, "data_into", None)
             batches = iter(PrefetchIterator(
-                self.dataset.data(train=True),
-                first_step=self.state["neval"] - skip))
+                self.dataset.data(train=True) if data_into is None
+                else data_into(ring.gather, train=True),
+                first_step=self.state["neval"] - skip, staging=ring))
             if skip > 0:
                 log.info("mid-epoch resume: fast-forwarding %d batches "
                          "to iter %d", skip, self.state["neval"])
@@ -984,13 +1000,15 @@ class LocalOptimizer(BaseOptimizer):
                              neval=self.state["neval"])
                 for _ in range(skip):
                     try:
-                        next(batches)
+                        ring.release(next(batches)[0])
                     except StopIteration:
                         break
             # double-buffer slot: the prefetcher parks the next batch
             # (host arrays + device buffers) here while the current
             # step runs; a discarded staged batch (stop/preemption) is
-            # harmless — streams re-read anything yielded-but-untrained
+            # harmless — streams re-read anything yielded-but-untrained,
+            # and its host buffer, whose copy may still be under way, is
+            # not given back: it goes with the ring
             staged = None
             staged_end = False
 
@@ -1000,6 +1018,7 @@ class LocalOptimizer(BaseOptimizer):
                 with tracer.span("batch_prep", step=step_tag):
                     prepared = self._prepare_batch(raw_inp, raw_tgt)
                 if prepared is None:
+                    ring.release(raw_inp)
                     if note_stream is not None:
                         log.warning("dropped a streaming batch at "
                                     "iter %d — its records are "
@@ -1008,7 +1027,7 @@ class LocalOptimizer(BaseOptimizer):
                     return None
                 p_inp, p_tgt = prepared
                 inp_d, tgt_d = put_batch(p_inp, p_tgt, step_tag)
-                return p_inp, p_tgt, inp_d, tgt_d
+                return p_inp, p_tgt, inp_d, tgt_d, raw_inp
 
             def _prefetch(step_tag):
                 """Double-buffer: pull the NEXT batch through the full
@@ -1075,11 +1094,13 @@ class LocalOptimizer(BaseOptimizer):
                     if batch is not None:
                         # double-buffered: prepared + transferred while
                         # the previous step was in flight
-                        inp, tgt, inp_d, tgt_d = batch
+                        inp, tgt, inp_d, tgt_d, host_batch = batch
                     else:
+                        host_batch = inp
                         with tracer.span("batch_prep", step=n):
                             prepared = self._prepare_batch(inp, tgt)
                         if prepared is None:
+                            ring.release(host_batch)
                             if note_stream is not None:
                                 # a dropped batch still consumed its
                                 # stream records: advance the frontier so
@@ -1128,12 +1149,13 @@ class LocalOptimizer(BaseOptimizer):
                         # loop comes back around, the input is on device
                         staged = _prefetch(n + 1)
                     if sync_per_step:
-                        resolve(n, loss, ok, bs, t0, health_dev)
+                        resolve(n, loss, ok, bs, t0, health_dev, host_batch)
                     else:
                         # the step is dispatched; reading back the
                         # PREVIOUS loss now lets the device run two-deep
                         flush_pending()
-                        pending.append((n, loss, ok, bs, t0, health_dev))
+                        pending.append((n, loss, ok, bs, t0, health_dev,
+                                        host_batch))
                     if self.train_summary is not None:
                         # histograms stay on the synchronous path: pvar
                         # here IS step n's output and neval is still n,

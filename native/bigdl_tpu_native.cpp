@@ -16,8 +16,6 @@
 #include <cstring>
 #include <cmath>
 #include <algorithm>
-#include <thread>
-#include <vector>
 
 extern "C" {
 
@@ -87,8 +85,9 @@ void fp16_decompress(const uint16_t* src, float* dst, int64_t n) {
 
 // --------------------------------------------------------------------------
 // minibatch assembly — shuffled row gather (+ optional normalize) in one
-// memory pass; the multi-threaded variant splits rows across threads
-// with the GIL released on the Python side
+// memory pass, into the caller's buffer.  Threads are the caller's:
+// bigdl_tpu.native.GatherPool hands row ranges of one batch to its
+// standing threads, each a call of gather_rows with the GIL released
 // --------------------------------------------------------------------------
 
 void gather_rows(const float* src, const int64_t* idx, float* dst,
@@ -96,25 +95,6 @@ void gather_rows(const float* src, const int64_t* idx, float* dst,
     for (int64_t i = 0; i < n_rows; i++)
         std::memcpy(dst + i * row_len, src + idx[i] * row_len,
                     (size_t)row_len * 4);
-}
-
-void gather_rows_mt(const float* src, const int64_t* idx, float* dst,
-                    int64_t n_rows, int64_t row_len, int n_threads) {
-    if (n_threads <= 1 || n_rows < 2 * n_threads) {
-        gather_rows(src, idx, dst, n_rows, row_len);
-        return;
-    }
-    std::vector<std::thread> pool;
-    int64_t chunk = (n_rows + n_threads - 1) / n_threads;
-    for (int t = 0; t < n_threads; t++) {
-        int64_t lo = t * chunk, hi = std::min(n_rows, lo + chunk);
-        if (lo >= hi) break;
-        pool.emplace_back([=] {
-            gather_rows(src + 0, idx + lo, dst + lo * row_len,
-                        hi - lo, row_len);
-        });
-    }
-    for (auto& th : pool) th.join();
 }
 
 // gather uint8 rows and convert to normalized float in one pass:
