@@ -444,22 +444,9 @@ class ServeConfig:
     # bigdl_serve_latency_slo_ratio gauge the serve_latency_slo_burn
     # alert rule watches [BIGDL_SERVE_SLO_MS, milliseconds]
     slo_s: float = 0.0
-    # "continuous" admits at step boundaries (the point of the tier);
-    # "static" drains the whole batch first — the A/B baseline
-    # [BIGDL_SERVE_ADMISSION]
-    admission: str = "continuous"
     # HTTP front-end port for serving/server.py (0 = ephemeral);
     # unset = constructor default [BIGDL_SERVE_PORT]
     port: Optional[int] = None
-    # paged decode-attention dispatch (ops/decode_attention.py):
-    # "auto" = the static dense policy, overridden per shape by the
-    # cached decode_attn auto-tuner site when BIGDL_TUNER=1; "dense" /
-    # "fused" / "pallas" pin an impl [BIGDL_SERVE_DECODE_ATTN]
-    decode_attn: str = "auto"
-    # slice each step's page tables to the pow2 used-page prefix so
-    # even the dense baseline stops gathering the empty pool
-    # [BIGDL_SERVE_DECODE_BUCKET]
-    decode_bucket: bool = True
 
     @classmethod
     def from_env(cls) -> "ServeConfig":
@@ -470,10 +457,7 @@ class ServeConfig:
             queue_capacity=_env_int("BIGDL_SERVE_QUEUE", 64),
             int8=_env_bool("BIGDL_SERVE_INT8", False),
             slo_s=_env_float("BIGDL_SERVE_SLO_MS", 0.0) / 1000.0,
-            admission=_env_str("BIGDL_SERVE_ADMISSION", "continuous"),
             port=_env_opt_int("BIGDL_SERVE_PORT", None),
-            decode_attn=_env_str("BIGDL_SERVE_DECODE_ATTN", "auto"),
-            decode_bucket=_env_bool("BIGDL_SERVE_DECODE_BUCKET", True),
         )
 
 

@@ -260,19 +260,15 @@ class LongCatFlash(_Composite):
         return (buf,), self._logits(params, h)[:, 0, :], counts
 
     def paged_decode(self, params, caches, tables, lengths, tokens, active,
-                     *, page_size=None, qparams=None, attn_impl="auto"):
+                     *, page_size=None, qparams=None):
         """One token a slot over the paged latent cache: ``(caches,
         logits (B, vocab), counts)``.  ``page_size`` is the cache's own
-        (read from the buffer); there is one attention body."""
+        (read from the buffer)."""
         import jax.numpy as jnp
 
         del page_size
         if qparams is not None:
             raise ValueError("LongCatFlash offers no int8 decode")
-        if attn_impl not in ("auto", "dense"):
-            raise ValueError(
-                "LongCatFlash has one decode attention body (latent, "
-                f"dense); decode_attn={attn_impl!r} is not offered")
         (buf,) = caches
         c = self._children
         x = jnp.take(params["embed"]["weight"], tokens, axis=0)

@@ -217,8 +217,8 @@ class TransformerLM(_Composite):
     # prompt or one token a slot over that cache, up to the logits.
     def cache_spec(self, params) -> dict:
         """K and V of every layer, a row of ``n_head * head_dim``
-        values each, in two buffers; ``heads`` / ``head_dim`` let the
-        decode-attention tuner key on the shape."""
+        values each, in two buffers of ``heads`` x ``head_dim`` lanes
+        (the bytes-per-token gauge reads them)."""
         n_head = int(self._config["n_head"])
         return {"layers": self.n_layer, "row_width": self.dim,
                 "buffers": 2, "max_len": int(self._config["max_len"]),
@@ -254,12 +254,12 @@ class TransformerLM(_Composite):
         return (kp, vp), logits[:, 0, :], None
 
     def paged_decode(self, params, caches, tables, lengths, tokens, active,
-                     *, page_size, qparams=None, attn_impl="dense"):
+                     *, page_size, qparams=None):
         """One token a slot: ``(caches, logits (B, vocab), None)``."""
         del active  # an inactive slot writes the trash page
         kp, vp, logits = paged_decode_logits(
             self._children, self.n_layer, page_size, params, qparams,
-            *caches, tables, lengths, tokens, attn_impl=attn_impl)
+            *caches, tables, lengths, tokens)
         return (kp, vp), logits, None
 
     def quantize_for_decode(self, params):
@@ -299,7 +299,7 @@ def _quantize_tree(params, n_layer):
 
 def paged_decode_logits(children, n_layer, page_size, params, qparams,
                         kp, vp, tables, lengths, tokens, *, n_head=None,
-                        psum=None, attn_impl="dense", attn_block_pages=0):
+                        psum=None):
     """One decode step over the paged cache, up to the logits — the
     single source of truth shared by the jitted single-host step and
     the TP shard_map body (``n_head`` is the LOCAL head count there, ``psum`` the
@@ -308,11 +308,9 @@ def paged_decode_logits(children, n_layer, page_size, params, qparams,
     decode bit-matches ``generate()`` at temperature 0.
 
     The attention body is ``ops.decode_attention.paged_decode_attention``
-    — ``attn_impl="dense"`` is the bit-match gather path, "auto" lets
-    the cached ``decode_attn`` tuner site dispatch the flash-decode
-    fused/Pallas kernels per (shape, dtype, platform); ``tables`` may
-    be the engine's used-page prefix bucket rather than the full table
-    width (same mask contract either way).
+    (the body of a cache of per-head K/V rows); ``tables`` may be the
+    engine's used-page prefix bucket rather than the full table width
+    (same mask contract either way).
 
     The ``jax.named_scope`` blocks (``kv_write``, ``attn``, ``dense``;
     the engine adds ``sample``) are metadata only: they name the step's operations in a
@@ -364,8 +362,7 @@ def paged_decode_logits(children, n_layer, page_size, params, qparams,
             # the pages are read where they lie
             o = paged_decode_attention(
                 qh, kp, vp, tables, lengths, layer=i,
-                page_size=page_size, scale=scale, impl=attn_impl,
-                block_pages=attn_block_pages)       # (B, H, Dh)
+                page_size=page_size, scale=scale)   # (B, H, Dh)
         o = o.reshape(bsz, 1, heads * head_dim)
         with jax.named_scope("dense"):
             y = mm(o, pa["wo"], None if qb is None else qb["attn"]["wo"])

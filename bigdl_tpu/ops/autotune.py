@@ -300,15 +300,13 @@ def _gate_measured(tuned_label: str, tuned_s: float, static_label: str,
 
 
 def _resolve(site, key, candidates, static_label, analytic, probes,
-             arrays, use_hlo=True):
+             arrays):
     """Core search: cache -> (score | measure) -> gate -> cache.
 
     ``candidates``: {label: decision-payload}; ``analytic``:
     {label: (flops, bytes)}; ``probes``: {label: fn(*arrays)} builders
     for the fwd+bwd measurement/HLO probe (XLA labels only get HLO
-    costing; ``use_hlo=False`` keeps every candidate on the analytic
-    model — the paged-gather sites, where HloCostAnalysis bills a
-    gather at whole-operand bytes and erases the ranking)."""
+    costing)."""
     import jax
 
     cache = get_cache()
@@ -330,7 +328,7 @@ def _resolve(site, key, candidates, static_label, analytic, probes,
     hlo = {}
     for label, (flops, bytes_) in analytic.items():
         fl, by = flops, bytes_
-        if use_hlo and not label.startswith("pallas") and label in probes:
+        if not label.startswith("pallas") and label in probes:
             # XLA candidates: the compiler's own count beats the model
             # (Pallas custom calls are opaque to HloCostAnalysis — the
             # analytic kernel traffic plan stands in)
@@ -628,77 +626,6 @@ def _conv_cost(impl, n, c, h, wd, o, k, stride, pad, item):
 
 
 # --------------------------------------------------------------------------
-# site: paged decode attention (the serving hot path, ISSUE 13)
-# --------------------------------------------------------------------------
-
-
-def decide_decode_attn(q_shape, page_size: int, maxp: int, dtype, *,
-                       kv_dtype=None, arrays=None) -> Optional[dict]:
-    """Dispatch decision for the ``decode_attn`` site
-    (``ops.decode_attention.paged_decode_attention(impl="auto")``).
-    Returns ``{"impl": "dense"|"fused"|"pallas", "block_pages": int}``
-    (plus provenance) or None for "use the static dense policy".
-
-    Costing note: the XLA candidates here are scored by the documented
-    analytic paged-traffic model (``decode_hbm_bytes``), NOT the HLO
-    ``cost_analysis`` path — measured on CPU, HloCostAnalysis bills
-    the page gather at whole-operand bytes (3.3 MB billed for a 0.5 MB
-    indexed access on a 129-page pool), which makes dense and fused
-    indistinguishable and erases exactly the gather tax this site
-    exists to price.  Wall-clock measurement (``prewarm_decode_attn``
-    with BIGDL_TUNER_MEASURE=1) still overrides the model."""
-    try:
-        import jax.numpy as jnp
-
-        from bigdl_tpu.ops import decode_attention as D
-
-        b, h, d = (int(s) for s in q_shape)
-        p, maxp = int(page_size), int(maxp)
-        kv_dtype = dtype if kv_dtype is None else kv_dtype
-        item = jnp.dtype(kv_dtype).itemsize
-        key = cache_key("decode_attn", f"b{b}h{h}d{d}p{p}m{maxp}", dtype)
-
-        flops = 4.0 * b * h * maxp * p * d
-        candidates = {"dense": {"impl": "dense", "block_pages": 0}}
-        analytic = {"dense": (flops, D.decode_hbm_bytes(
-            "dense", b, h, d, p, maxp, item))}
-        probes = {"dense": _decode_probe(D, p, "dense", 0, False)}
-        fused_bytes = D.decode_hbm_bytes("fused", b, h, d, p, maxp, item)
-        for bp in sorted({maxp, 1, min(4, maxp)}, reverse=True):
-            if maxp % bp:
-                continue
-            label = f"fused_p{bp}"
-            candidates[label] = {"impl": "fused", "block_pages": bp}
-            analytic[label] = (flops, fused_bytes)
-            probes[label] = _decode_probe(D, p, "fused", bp, False)
-        # the Pallas kernel only where it would run COMPILED (TPU) or
-        # where a wall-clock probe can arbitrate (interpret mode)
-        if platform() == "tpu" or (_cfg().measure and _concrete(arrays)):
-            candidates["pallas"] = {"impl": "pallas", "block_pages": 1}
-            analytic["pallas"] = (flops, D.decode_hbm_bytes(
-                "pallas", b, h, d, p, maxp, item))
-            probes["pallas"] = _decode_probe(
-                D, p, "pallas", 1, resolve_interpret())
-        return _resolve("decode_attn", key, candidates, "dense",
-                        analytic, probes, arrays, use_hlo=False)
-    except Exception:  # noqa: BLE001 — the tuner must never sink a step
-        return None
-
-
-def _decode_probe(D, page_size, impl, block_pages, interp):
-    def probe(q, kp, vp, tables, lengths):
-        import jax.numpy as jnp
-
-        out = D.paged_decode_attention(
-            q, kp, vp, tables, lengths, page_size=page_size,
-            impl=("pallas_interpret" if impl == "pallas" and interp
-                  else impl), block_pages=block_pages)
-        return jnp.sum(out.astype(jnp.float32) ** 2)
-
-    return probe
-
-
-# --------------------------------------------------------------------------
 # site: quantized matmul (int8 decode weights, ROADMAP "widen" item)
 # --------------------------------------------------------------------------
 
@@ -794,33 +721,6 @@ def prewarm_conv_bn(n, c, h, w, o, k, *, stride=1, pad=0,
         (rs.randn(o, c, k, k) * 0.1).astype(np.float32)).astype(dtype)
     shift = jnp.asarray(rs.randn(o).astype(np.float32))
     return conv_bn_stats(x, wt, shift, stride=stride, pad=pad)
-
-
-def prewarm_decode_attn(b, h, d, *, page_size=16, maxp=4,
-                        num_pages=None, dtype="float32", seed=0):
-    """Offline cache warmer for the serving ``decode_attn`` site:
-    synthetic paged K/V state with ragged lengths, one ``impl="auto"``
-    dispatch on CONCRETE inputs (measured when BIGDL_TUNER_MEASURE=1).
-    Returns the op output so callers can assert numerics."""
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    from bigdl_tpu.ops.decode_attention import paged_decode_attention
-    from bigdl_tpu.serving.cache import pool_shape
-
-    rs = np.random.RandomState(seed)
-    pool = int(num_pages or (b * maxp + 1))
-    q = jnp.asarray(rs.randn(b, h, d).astype(np.float32)).astype(dtype)
-    shape = pool_shape(pool, page_size, h, d)
-    kp = jnp.asarray(rs.randn(*shape).astype(np.float32)).astype(dtype)
-    vp = jnp.asarray(rs.randn(*shape).astype(np.float32)).astype(dtype)
-    lengths = jnp.asarray(
-        rs.randint(1, maxp * page_size, (b,)).astype(np.int32))
-    tables = jnp.asarray(
-        rs.randint(1, pool, (b, maxp)).astype(np.int32))
-    return paged_decode_attention(q, kp, vp, tables, lengths,
-                                  page_size=page_size, impl="auto")
 
 
 def prewarm_int8_mm(m, k, n, *, dtype="float32", seed=0):

@@ -16,7 +16,7 @@ separate V — latent attention, ``nn/latent.py`` — states ``buffers=1``
 and its row's width, and gets ``kp`` alone).  It is written down once,
 here — :func:`pool_shape`, :func:`write_token_rows`,
 :func:`write_prompt_pages`, :func:`gather_pages` — and every cache in
-the repo (the engine's, the tuner's probes, the smokes') is built,
+the repo (the engine's, the tests', the smokes') is built,
 written and read through them:
 
 * ``kp``/``vp``: ``(n_layer, num_pages, page_size, row)`` device

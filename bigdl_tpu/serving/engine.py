@@ -10,8 +10,7 @@ that with the production shape:
 * **continuous admission**: at every step boundary, free slots are
   refilled from the request queue (serving/batcher.py) — a finished
   request's slot and pages are reused immediately, not when the batch
-  drains (``admission="static"`` keeps the drain-first behavior as the
-  A/B baseline the serve smoke measures against);
+  drains;
 * **prefill/decode split**: a new request's prompt runs one batched
   forward (``TransformerBlock.prefill`` — the identical attention path
   training uses) padded to a page-aligned bucket, writing its K/V pages
@@ -124,10 +123,7 @@ class LMEngine:
                  queue_capacity: Optional[int] = None,
                  int8: Optional[bool] = None, tp: int = 1, wire=None,
                  cache_dtype=None, eos_id: Optional[int] = None,
-                 slo_s: Optional[float] = None,
-                 admission: Optional[str] = None,
-                 decode_attn: Optional[str] = None,
-                 decode_bucket: Optional[bool] = None, seed: int = 0,
+                 slo_s: Optional[float] = None, seed: int = 0,
                  weight_version: str = "v0"):
         import jax
         import jax.numpy as jnp
@@ -141,21 +137,8 @@ class LMEngine:
         self.page_size = int(page_size or cfg.page_size)
         self.int8 = cfg.int8 if int8 is None else bool(int8)
         self.tp = int(tp or 1)
-        self.decode_attn = decode_attn or cfg.decode_attn
-        if self.decode_attn not in ("auto", "dense", "fused", "pallas",
-                                    "pallas_interpret"):
-            raise ValueError(
-                f"decode_attn must be auto|dense|fused|pallas, got "
-                f"{self.decode_attn!r}")
-        self.decode_bucket = (cfg.decode_bucket if decode_bucket is None
-                              else bool(decode_bucket))
         self.eos_id = eos_id
         self.slo_s = cfg.slo_s if slo_s is None else float(slo_s)
-        self.admission = admission or cfg.admission
-        if self.admission not in ("continuous", "static"):
-            raise ValueError(
-                f"admission must be continuous|static, got "
-                f"{self.admission!r}")
         if self.int8 and self.tp > 1:
             raise ValueError("int8 decode and tp-sharded decode are "
                              "currently exclusive")
@@ -203,15 +186,13 @@ class LMEngine:
         self._lock = threading.RLock()
 
         self._last_bucket = self.cache.max_pages_per_slot
-        self._impl_by_bucket: dict = {}
         self._decode_ms_sum = 0.0
         self._weight_bytes = self._decode_weight_bytes()
         if self.tp > 1:
             self._step_fn = model.tp_decode_step(
                 tp=self.tp, wire=wire, page_size=self.page_size,
                 max_batch=self.max_batch,
-                positions=self.cache.padded_positions(),
-                attn_impl=self.decode_attn)
+                positions=self.cache.padded_positions())
         else:
             self._step_fn = self._build_step()
             self.params = jax.tree.map(
@@ -361,7 +342,6 @@ class LMEngine:
 
         model, page_size = self.model, self.page_size
         qparams = self._qparams
-        attn_impl = self.decode_attn
         n = len(self.cache.buffers())
 
         def step(params, *rest):
@@ -370,43 +350,13 @@ class LMEngine:
             tables, lengths, tokens, temps, active, key = rest[n:]
             caches, logits, counts = model.paged_decode(
                 params, rest[:n], tables, lengths, tokens, active,
-                page_size=page_size, qparams=qparams, attn_impl=attn_impl)
+                page_size=page_size, qparams=qparams)
             nxt = sample_step(logits, temps, active, key)
             # the routing counts ride back with the tokens
             return (*caches, nxt) if counts is None \
                 else (*caches, nxt, counts)
 
         return jax.jit(step, donate_argnums=tuple(range(1, 1 + n)))
-
-    def _decode_impl_for(self, bucket: int) -> str:
-        """The decode-attention impl this step's bucket resolves to —
-        host-side mirror of the in-trace dispatch, cached per bucket
-        (drives the bytes-per-token gauge and ``stats()``; with the
-        tuner enabled this is also what pre-populates the
-        ``decode_attn`` cache entry the traced step then hits)."""
-        impl = self._impl_by_bucket.get(bucket)
-        if impl is not None:
-            return impl
-        impl = self.decode_attn
-        if impl == "auto":
-            impl = "dense"
-            try:
-                from bigdl_tpu.ops import autotune
-
-                spec = self._cache_spec
-                # a cache of per-head K/V rows is what the site tunes
-                if autotune.enabled() and "heads" in spec:
-                    rec = autotune.decide_decode_attn(
-                        (self.max_batch, spec["heads"] // self.tp,
-                         spec["head_dim"]),
-                        self.page_size, bucket, spec["dtype"],
-                        kv_dtype=self.cache.dtype)
-                    if rec is not None:
-                        impl = rec.get("impl", "dense")
-            except Exception:  # noqa: BLE001 — a hint, never a sink
-                pass
-        self._impl_by_bucket[bucket] = impl
-        return impl
 
     def _prefill_fn(self, bucket: int):
         fn = self._prefill_fns.get(bucket)
@@ -493,8 +443,6 @@ class LMEngine:
         free = self._free_slots()
         if not free:
             return 0
-        if self.admission == "static" and self.active_count():
-            return 0  # static batching: drain fully before refilling
         wanted = len(free)
         incoming = list(self._stash)
         self._stash.clear()
@@ -687,8 +635,8 @@ class LMEngine:
 
         if not self.active_count():
             return False
-        # used-page prefix bucket (pow2): even the dense baseline stops
-        # gathering the empty pool; each bucket is one compiled variant
+        # used-page prefix bucket (pow2): the step stops gathering the
+        # empty pool; each bucket is one compiled variant
         from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
                                                     used_page_bucket)
 
@@ -718,15 +666,11 @@ class LMEngine:
                 tokens[i] = self._slots[i].last_token
                 temps[i] = self._slots[i].req.temperature
                 active[i] = True
-            if self.decode_bucket:
-                longest = max(int(self.cache.lengths[i])
-                              for i in active_slots)
-                bucket = used_page_bucket(longest, self.page_size,
-                                          self.cache.max_pages_per_slot)
-            else:
-                bucket = self.cache.max_pages_per_slot
+            longest = max(int(self.cache.lengths[i])
+                          for i in active_slots)
+            bucket = used_page_bucket(longest, self.page_size,
+                                      self.cache.max_pages_per_slot)
             self._last_bucket = bucket
-            impl = self._decode_impl_for(bucket)
             tables, lengths = self.cache.device_tables(pages=bucket)
             self._key, sub = jax.random.split(self._key)
             tracer.add_attrs(span_id, bucket=bucket,
@@ -764,7 +708,6 @@ class LMEngine:
             step_bytes = self._weight_bytes + \
                 self.cache.n_layer * len(self.cache.buffers()) / 2.0 \
                 * decode_hbm_bytes(
-                    "dense" if impl == "dense" else "fused",
                     self.max_batch, heads, self.cache.row_width // heads,
                     self.page_size, bucket, kv_item)
             self._decode_bytes_gauge.set(step_bytes / len(active_slots))
@@ -883,12 +826,8 @@ class LMEngine:
             "e2e_p50_s": pct(e2e, 50), "e2e_p99_s": pct(e2e, 99),
             "ttft_p50_s": pct(ttft, 50), "ttft_p99_s": pct(ttft, 99),
             "itl_p50_s": pct(itl, 50), "itl_p95_s": pct(itl, 95),
-            "admission": self.admission,
             "int8": self.int8,
             "tp": self.tp,
-            "decode_attn": self.decode_attn,
-            "decode_bucket": self.decode_bucket,
-            "decode_impl_by_bucket": dict(self._impl_by_bucket),
             "last_bucket_pages": self._last_bucket,
             "decode_ms_mean": (self._decode_ms_sum / self._steps
                                if self._steps else None),

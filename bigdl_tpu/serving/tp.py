@@ -68,16 +68,13 @@ def _account(n_layer: int, batch: int, dim: int, tp: int, spec):
 
 
 def build_tp_decode_step(model, *, tp: int, wire=None, page_size: int,
-                         max_batch: int, positions: int,
-                         attn_impl: str = "auto"):
+                         max_batch: int, positions: int):
     """The engine's decode step, sharded ``tp`` ways on the first
     ``tp`` local devices.  Same signature as the single-host step:
     ``step(params, kp, vp, tables, lengths, tokens, temps, active,
     key) -> (kp, vp, next_tokens)`` with replicated params/cache
-    accepted (GSPMD reshards on first call).  ``attn_impl`` is the
-    paged decode-attention dispatch (ops/decode_attention.py) — the
-    body sees the LOCAL head shard, so the tuner's ``decode_attn``
-    site keys on the per-device shape."""
+    accepted (GSPMD reshards on first call).  The attention body sees
+    the LOCAL head shard."""
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -120,7 +117,7 @@ def build_tp_decode_step(model, *, tp: int, wire=None, page_size: int,
         return paged_decode_math(
             children, n_layer, page_size, params, None, kp, vp,
             tables, lengths, tokens, temps, active, key,
-            n_head=n_head // tp, psum=psum_fn, attn_impl=attn_impl)
+            n_head=n_head // tp, psum=psum_fn)
 
     mapped = _shard_map(
         body, mesh,

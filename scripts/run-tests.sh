@@ -29,9 +29,8 @@
 #                                                # ratio with nonzero rework
 #                                                # badput (no pytest)
 #   scripts/run-tests.sh --tune                  # auto-tuner smoke: tunes one
-#                                                # attention, one conv+BN, one
-#                                                # serving decode_attn and one
-#                                                # int8_mm shape on CPU
+#                                                # attention, one conv+BN and
+#                                                # one int8_mm shape on CPU
 #                                                # (interpret mode, measured
 #                                                # candidates), asserts a
 #                                                # persisted JSON cache,
@@ -93,17 +92,13 @@
 #                                                # (no pytest)
 #   scripts/run-tests.sh --serve                 # serving-tier smoke: the
 #                                                # continuous-batching LM
-#                                                # engine A/B'd against
-#                                                # static batching on one
-#                                                # bursty request trace
-#                                                # (must win tokens/sec at
-#                                                # equal-or-better p99), the
-#                                                # flash-decode kernel A/B
-#                                                # (tuner-dispatched fused
-#                                                # path must beat the dense
-#                                                # full-width gather >=1.15x
-#                                                # at equal p99, token-
-#                                                # identical),
+#                                                # engine on one bursty
+#                                                # request trace (slots
+#                                                # refilled at step
+#                                                # boundaries), long decodes
+#                                                # on 32-page tables (the
+#                                                # used-page bucket, tokens
+#                                                # equal generate()),
 #                                                # concurrent HTTP clients
 #                                                # against an int8 ResNet +
 #                                                # the LM decoder, a queue-

@@ -4,21 +4,15 @@
 Driven by ``scripts/run-tests.sh --serve``.  Six stages, each a hard
 assert:
 
-1. **continuous vs static A/B** — the same bursty request trace (mixed
-   prompt lengths, short and long decodes interleaved so static
-   batching head-of-line blocks) is decoded by two engines sharing one
-   model: ``admission="static"`` (drain the whole batch before
-   refilling — the ``generate()`` baseline behavior) vs
-   ``admission="continuous"`` (refill freed slots at step boundaries).
-   Continuous must win on tokens/sec at equal-or-better p99.
-2. **decode-kernel A/B (ISSUE 13)** — the same long-decode trace on a
-   serving-sized model, decoded by the PR 12 dense-gather baseline
-   (``decode_attn="dense"``, full-width tables) vs the tuner-
-   dispatched flash-decode path (``BIGDL_TUNER=1``, used-page prefix
-   buckets).  The fused path must win >= 1.15x tokens/sec at
-   equal-or-better p99, with ``decode_attn`` tuner decisions visible,
-   byte-identical greedy tokens across arms (and vs ``generate()``),
-   and fused-vs-dense op output within 1e-5.
+1. **continuous batching** — a bursty request trace (mixed prompt
+   lengths, short and long decodes interleaved, so a batch that
+   drained before refilling would sit half empty) on one engine:
+   every request completes and freed slots are refilled at step
+   boundaries (mean occupancy >= 0.8; a drain-first scheduler reads
+   0.48 on this trace).
+2. **long decodes on a serving-sized model** — a long-decode trace on
+   32-page tables of which it fills 4: every step ships the used-page
+   bucket, not the table, and the greedy tokens equal ``generate()``.
 3. **concurrent clients over HTTP** — a ResNet classifier (int8 via the
    existing ``quantize()``/folded-BN path) and the LM decoder behind
    one stdlib front-end, hammered by concurrent client threads mixing
@@ -32,8 +26,7 @@ assert:
    incl. the decode ms/step + HBM bytes/token line) in text and carry
    the request-latency histograms + the autoscale decision in
    ``--json``.
-6. **bank** — ``SERVE_SMOKE.json`` (incl. ``decode_kernel``) for BENCH
-   ``extras.serve``.
+6. **bank** — ``SERVE_SMOKE.json`` for BENCH ``extras.serve``.
 
 NOTE: the parent pins JAX_PLATFORMS=cpu for itself — importing
 bigdl_tpu pulls jax, which otherwise probes this container's TPU
@@ -60,8 +53,8 @@ TMP = None  # set in main
 
 
 def _trace(prompts_seed: int = 7, n: int = 24):
-    """The shared A/B request trace: short/long decodes interleaved so
-    a drained-batch scheduler head-of-line blocks."""
+    """The bursty request trace: short/long decodes interleaved so
+    a drained-batch scheduler would head-of-line block."""
     import numpy as np
 
     rs = np.random.RandomState(prompts_seed)
@@ -80,10 +73,10 @@ def _reset_measures(eng):
     eng._t_first_work = eng._t_last_done = None
 
 
-def _ab_arm(model, admission: str):
+def _bursty_run(model):
     from bigdl_tpu.serving import LMEngine
 
-    eng = LMEngine(model, max_batch=4, page_size=8, admission=admission,
+    eng = LMEngine(model, max_batch=4, page_size=8,
                    queue_capacity=64, slo_s=30.0, seed=3)
     # warm every compile OUTSIDE the measured window: one request per
     # prefill bucket, plus one long decode that walks the step through
@@ -103,9 +96,9 @@ def _ab_arm(model, admission: str):
     return st
 
 
-# -------------------------------------------------- decode-kernel A/B
+# ------------------------------------------------------ long decodes
 def _decode_trace(n: int = 16):
-    """Long-decode trace for the kernel A/B: short prompts, 40-56
+    """Long-decode trace: short prompts, 40-56
     generated tokens each, so the step count is decode-dominated and
     slot lengths stay under 64 (= the 4-page bucket at page 16)."""
     import numpy as np
@@ -116,11 +109,11 @@ def _decode_trace(n: int = 16):
             for i in range(n)]
 
 
-def _decode_arm(model, label, **engine_kw):
+def _decode_run(model):
     from bigdl_tpu.serving import LMEngine
 
     eng = LMEngine(model, max_batch=8, page_size=16, num_pages=64,
-                   queue_capacity=64, slo_s=30.0, seed=7, **engine_kw)
+                   queue_capacity=64, slo_s=30.0, seed=7)
     # warmup drives one slot through every decode bucket the trace
     # touches (lengths 4 -> 60: 1-, 2- and 4-page tables) plus the
     # prefill bucket, so the measured window has zero compiles
@@ -131,7 +124,7 @@ def _decode_arm(model, label, **engine_kw):
     eng.run_until_idle(600)
     assert all(r.done and len(r.tokens) == m
                for r, (_, m) in zip(reqs, _decode_trace())), \
-        f"incomplete requests in {label} arm"
+        "incomplete requests"
     st = eng.stats()
     eng.close()
     return st, [list(r.tokens) for r in reqs]
@@ -160,96 +153,37 @@ def main() -> int:
     model = build_transformer_lm(48, dim=32, n_head=4, n_layer=2,
                                  max_len=64, attn_impl="lax")
 
-    # -- 1: continuous vs static A/B ----------------------------------
-    stat = _ab_arm(model, "static")
-    cont = _ab_arm(model, "continuous")
-    speedup = cont["tokens_per_s"] / stat["tokens_per_s"]
-    print(f"[serve-smoke] static:     {stat['tokens_per_s']:.1f} tok/s, "
-          f"p99 {stat['e2e_p99_s'] * 1000:.0f}ms, occupancy "
-          f"{stat['occupancy_mean'] * 100:.0f}%")
+    # -- 1: continuous batching on a bursty trace ---------------------
+    cont = _bursty_run(model)
     print(f"[serve-smoke] continuous: {cont['tokens_per_s']:.1f} tok/s, "
           f"p99 {cont['e2e_p99_s'] * 1000:.0f}ms, occupancy "
           f"{cont['occupancy_mean'] * 100:.0f}%")
-    assert cont["tokens_per_s"] > stat["tokens_per_s"], \
-        f"continuous {cont['tokens_per_s']:.1f} tok/s did not beat " \
-        f"static {stat['tokens_per_s']:.1f}"
-    assert cont["e2e_p99_s"] <= stat["e2e_p99_s"], \
-        f"continuous p99 {cont['e2e_p99_s']:.3f}s worse than static " \
-        f"{stat['e2e_p99_s']:.3f}s"
-    print(f"[serve-smoke] continuous batching: {speedup:.2f}x tokens/s "
-          "at equal-or-better p99 — PASS")
+    assert cont["occupancy_mean"] >= 0.8, \
+        f"freed slots were not refilled: occupancy " \
+        f"{cont['occupancy_mean']:.2f}"
+    print("[serve-smoke] continuous batching: every request done, "
+          "slots refilled at step boundaries — PASS")
 
-    # -- 2: decode-kernel A/B (flash-decode vs the dense gather) ------
-    import jax.numpy as jnp
-
-    from bigdl_tpu.models.transformer import build_transformer_lm
-    from bigdl_tpu.ops import autotune
-    from bigdl_tpu.ops.decode_attention import paged_decode_attention
-    from bigdl_tpu.serving.cache import pool_shape
-
+    # -- 2: long decodes on a serving-sized model ---------------------
     RandomGenerator.RNG.set_seed(29)
     # max_len 512 / page 16 = a 32-page table per slot, of which the
-    # trace only ever fills 4 — the PR 12 baseline gathers all 32 per
-    # layer per step (the gather tax the fused path deletes)
+    # trace only ever fills 4
     model2 = build_transformer_lm(64, dim=128, n_head=8, n_layer=4,
                                   max_len=512, attn_impl="lax")
     params2 = model2.params()
-    base_st, base_toks = _decode_arm(
-        model2, "dense-gather baseline", decode_attn="dense",
-        decode_bucket=False)
-    os.environ["BIGDL_TUNER"] = "1"
-    os.environ["BIGDL_TUNER_CACHE"] = os.path.join(TMP, "tuner.json")
-    autotune.reset()
-    fused_st, fused_toks = _decode_arm(model2, "tuner-dispatched")
-    dspeed = fused_st["tokens_per_s"] / base_st["tokens_per_s"]
-    impls = fused_st["decode_impl_by_bucket"]
-    decisions = [d for d in autotune.summary()["decisions"]
-                 if d["site"] == "decode_attn"]
-    print(f"[serve-smoke] decode dense-full:  "
-          f"{base_st['tokens_per_s']:.0f} tok/s, p99 "
-          f"{base_st['e2e_p99_s'] * 1000:.0f}ms, "
-          f"{base_st['decode_ms_mean']:.2f}ms/step, "
-          f"{base_st['decode_hbm_bytes_per_token'] / 1e6:.2f} MB/token")
-    print(f"[serve-smoke] decode tuned:       "
-          f"{fused_st['tokens_per_s']:.0f} tok/s, p99 "
-          f"{fused_st['e2e_p99_s'] * 1000:.0f}ms, "
-          f"{fused_st['decode_ms_mean']:.2f}ms/step, "
-          f"{fused_st['decode_hbm_bytes_per_token'] / 1e6:.2f} MB/token")
-    print(f"[serve-smoke] decode_attn tuner decisions: "
-          + ", ".join(f"{d['key'].split('|')[1]}->{d['label']}"
-                      f"({d['source']})" for d in decisions))
-    assert decisions, "no decode_attn tuner decisions recorded"
-    assert impls and all(v == "fused" for v in impls.values()), impls
-    assert fused_toks == base_toks, \
-        "tuned arm diverged from the dense baseline's greedy tokens"
+    dec, dec_toks = _decode_run(model2)
+    print(f"[serve-smoke] long decodes: {dec['tokens_per_s']:.0f} tok/s, "
+          f"p99 {dec['e2e_p99_s'] * 1000:.0f}ms, "
+          f"{dec['decode_ms_mean']:.2f}ms/step, "
+          f"{dec['decode_hbm_bytes_per_token'] / 1e6:.2f} MB/token")
+    assert dec["last_bucket_pages"] <= 4, dec["last_bucket_pages"]
     p0, m0 = _decode_trace()[0]
     ref0 = list(np.asarray(model2.generate(
         params2, np.asarray(p0)[None, :], m0))[0])
-    assert [int(t) for t in p0 + base_toks[0]] == ref0, \
-        "dense baseline lost temperature-0 parity vs generate()"
-    assert dspeed >= 1.15, \
-        f"flash-decode speedup {dspeed:.2f}x < 1.15x"
-    assert fused_st["e2e_p99_s"] <= base_st["e2e_p99_s"] * 1.02, \
-        f"tuned p99 {fused_st['e2e_p99_s']:.3f}s worse than dense " \
-        f"{base_st['e2e_p99_s']:.3f}s"
-    # op-level fused-vs-dense parity at the serving shape
-    rs2 = np.random.RandomState(2)
-    pool = 33
-    qo = jnp.asarray(rs2.randn(8, 8, 16).astype(np.float32))
-    kv_shape = pool_shape(pool, 16, 8, 16)
-    kpo = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
-    vpo = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
-    lens = jnp.asarray(rs2.randint(1, 63, (8,)).astype(np.int32))
-    tbls = jnp.asarray(rs2.randint(1, pool, (8, 4)).astype(np.int32))
-    od = paged_decode_attention(qo, kpo, vpo, tbls, lens, page_size=16,
-                                impl="dense")
-    of = paged_decode_attention(qo, kpo, vpo, tbls, lens, page_size=16,
-                                impl="fused")
-    op_diff = float(jnp.max(jnp.abs(od - of)))
-    assert op_diff < 1e-5, f"fused-vs-dense op diff {op_diff:g}"
-    print(f"[serve-smoke] flash-decode: {dspeed:.2f}x tokens/s at "
-          f"equal-or-better p99, token-identical, op diff "
-          f"{op_diff:.1e} — PASS")
+    assert [int(t) for t in p0 + dec_toks[0]] == ref0, \
+        "paged decode lost temperature-0 parity vs generate()"
+    print("[serve-smoke] long decodes: used-page bucket <= 4 of 32 "
+          "pages, tokens equal generate() — PASS")
 
     # -- 3: concurrent clients vs ResNet + LM over HTTP ---------------
     from bigdl_tpu.models.resnet import build_resnet_cifar
@@ -373,40 +307,20 @@ def main() -> int:
     assert sv["decode_hbm_bytes_per_token"] > 0, sv
     decs = rep["autoscale"]["decisions_total"]
     assert decs.get("up:queue_high", 0) >= 1, decs
-    tn = rep.get("tuner")
-    assert tn and any(s.startswith("decode_attn")
-                      for s in tn["decisions_total"]), tn
     print("[serve-smoke] report: serving section + latency histograms "
           "+ the queue-driven decision all present (text + --json) — "
           "PASS")
 
     # -- 6: bank for BENCH extras.serve -------------------------------
     bank = {
-        "static": {k: stat[k] for k in
-                   ("tokens_per_s", "e2e_p99_s", "e2e_p50_s",
-                    "occupancy_mean", "requests", "tokens", "steps")},
         "continuous": {k: cont[k] for k in
                        ("tokens_per_s", "e2e_p99_s", "e2e_p50_s",
                         "occupancy_mean", "requests", "tokens",
                         "steps")},
-        "tokens_per_s_speedup": speedup,
-        "p99_ratio": cont["e2e_p99_s"] / stat["e2e_p99_s"],
-        "decode_kernel": {
-            "dense_full": {k: base_st[k] for k in
-                           ("tokens_per_s", "e2e_p99_s", "e2e_p50_s",
-                            "decode_ms_mean",
-                            "decode_hbm_bytes_per_token", "steps",
-                            "tokens")},
-            "tuned": {k: fused_st[k] for k in
-                      ("tokens_per_s", "e2e_p99_s", "e2e_p50_s",
-                       "decode_ms_mean", "decode_hbm_bytes_per_token",
-                       "steps", "tokens")},
-            "tokens_per_s_speedup": dspeed,
-            "p99_ratio": fused_st["e2e_p99_s"] / base_st["e2e_p99_s"],
-            "impl_by_bucket": impls,
-            "fused_vs_dense_max_abs_diff": op_diff,
-            "tuner_decisions": decisions,
-        },
+        "long_decodes": {k: dec[k] for k in
+                         ("tokens_per_s", "e2e_p99_s", "e2e_p50_s",
+                          "decode_ms_mean", "decode_hbm_bytes_per_token",
+                          "last_bucket_pages", "steps", "tokens")},
         "classifier": {"requests": stats["classifier"]["requests"],
                        "int8": True},
         "autoscale_decision": {"direction": decision.direction,

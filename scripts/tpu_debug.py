@@ -89,12 +89,10 @@ undersized — the autoscaler's queue band (BIGDL_AUTOSCALE_QUEUE_*) and
 latency band (BIGDL_AUTOSCALE_P99_*) scale on exactly these signals.
 SLOW DECODE specifically starts at the serving section's "decode:
 X ms/step, Y MB/token" line (gauges bigdl_serve_decode_attn_ms /
-bigdl_serve_decode_hbm_bytes_per_token): a high MB/token with
-BIGDL_SERVE_DECODE_BUCKET off or decode_attn pinned to "dense" means
-you are paying the full-pool gather tax — enable BIGDL_TUNER=1 so the
-cached decode_attn site dispatches the fused/Pallas flash-decode path
-(pre-warm with autotune.prewarm_decode_attn; MIGRATION.md "Decode
-kernels").  A P99 REGRESSION you cannot place from aggregates alone
+bigdl_serve_decode_hbm_bytes_per_token): every step reads the pow2
+bucket of pages its longest slot uses, so MB/token follows the longest
+context in the batch (MIGRATION.md "Decode attention").  A P99
+REGRESSION you cannot place from aggregates alone
 reads the report's "request traces" section next (run with
 BIGDL_REQTRACE_SAMPLE > 0): the slowest decile's per-hop breakdown
 (queue / prefill / preempt / decode / placement / retry / handoff)

@@ -5,9 +5,8 @@ Driven by ``scripts/run-tests.sh --tune``.  Four stages, each a hard
 assert:
 
 1. a FRESH process (``BIGDL_TUNER=1``, ``BIGDL_TUNER_MEASURE=1``, CPU
-   interpret mode) tunes one attention shape, one conv+BN shape, one
-   serving ``decode_attn`` shape (flash-decode over the paged KV
-   cache) and one ``int8_mm`` shape through the real ``impl="auto"``
+   interpret mode) tunes one attention shape, one conv+BN shape and
+   one ``int8_mm`` shape through the real ``impl="auto"``
    dispatchers, measures candidates (wall clock), and must persist a
    well-formed JSON cache under ``BIGDL_TUNER_CACHE`` with one
    decision per site;
@@ -67,23 +66,6 @@ yr, s1r, s2r = _reference(x, w, sh, 2, 1)
 np.testing.assert_allclose(np.asarray(yt), np.asarray(yr), atol=1e-4,
                            rtol=1e-4)
 
-# ... the serving decode_attn site (flash-decode over the paged cache):
-# the measured prewarm must agree with the static dense path
-from bigdl_tpu.ops.decode_attention import paged_decode_attention
-from bigdl_tpu.serving.cache import pool_shape
-got = autotune.prewarm_decode_attn(2, 2, 16, page_size=8, maxp=2, seed=3)
-rs2 = np.random.RandomState(3)
-pool = 2 * 2 + 1
-qd = jnp.asarray(rs2.randn(2, 2, 16).astype(np.float32))
-kv_shape = pool_shape(pool, 8, 2, 16)
-kpd = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
-vpd = jnp.asarray(rs2.randn(*kv_shape).astype(np.float32))
-lens = jnp.asarray(rs2.randint(1, 16, (2,)).astype(np.int32))
-tbls = jnp.asarray(rs2.randint(1, pool, (2, 2)).astype(np.int32))
-refd = paged_decode_attention(qd, kpd, vpd, tbls, lens, page_size=8,
-                              impl="dense")
-np.testing.assert_allclose(np.asarray(got), np.asarray(refd), atol=1e-5)
-
 # ... and the int8_mm site the int8 decode matmuls ride
 autotune.prewarm_int8_mm(4, 32, 64)
 
@@ -129,17 +111,11 @@ def main() -> int:
         doc = json.load(open(cache, encoding="utf-8"))
         assert doc["version"] == 1
         sites = {r["site"] for r in doc["decisions"].values()}
-        assert sites == {"attn", "conv_bn_kxk", "decode_attn",
-                         "int8_mm"}, sites
-        assert s1["cache"]["misses"] >= 4
+        assert sites == {"attn", "conv_bn_kxk", "int8_mm"}, sites
+        assert s1["cache"]["misses"] >= 3
         for rec in doc["decisions"].values():
             assert rec["source"] == "measured", rec
             assert rec["measured_s"], rec
-        da = [r for r in doc["decisions"].values()
-              if r["site"] == "decode_attn"]
-        assert da and "dense" in da[0]["measured_s"], da
-        assert any(lbl.startswith("fused") for lbl in
-                   da[0]["measured_s"]), da
         print(f"[tune_smoke] cold run: {len(doc['decisions'])} "
               f"measured decision(s) persisted -> {cache}")
 
@@ -148,7 +124,7 @@ def main() -> int:
         assert p2.returncode == 0, (p2.stdout[-2000:], p2.stderr[-2000:])
         s2 = _summary(p2)
         assert s2["cache"]["misses"] == 0, s2["cache"]
-        assert s2["cache"]["hits"] >= 4, s2["cache"]
+        assert s2["cache"]["hits"] >= 3, s2["cache"]
         doc2 = json.load(open(cache, encoding="utf-8"))
         assert doc2["decisions"] == doc["decisions"], \
             "warm run mutated the cache"
@@ -165,8 +141,7 @@ def main() -> int:
         assert "-- kernel auto-tuner --" in rep.stdout, rep.stdout
         assert "attn:" in rep.stdout and "conv_bn_kxk:" in rep.stdout, \
             rep.stdout
-        assert "decode_attn:" in rep.stdout and "int8_mm:" in \
-            rep.stdout, rep.stdout
+        assert "int8_mm:" in rep.stdout, rep.stdout
         assert "wall-clock probe(s)" in rep.stdout
         rep_j = subprocess.run(
             [sys.executable, "-m", "bigdl_tpu.obs.report", trace,

@@ -1,19 +1,22 @@
-"""ops/decode_attention.py — flash-decode over the paged KV cache
-(ISSUE 13).
+"""ops/decode_attention.py — decode attention over the paged cache:
+two bodies, one a cache kind.
 
 The load-bearing contracts:
 
-* the dense path is the PR 12 math verbatim (the engine's bit-match
-  tests in test_serving.py pin that end to end);
-* fused (every page-block chunking) and the Pallas kernel (interpret
-  mode here) agree with dense within f32 tolerance across ragged
-  lengths, page boundaries and arbitrary page-table permutations;
+* ``paged_decode_attention`` (per-head K/V rows) and
+  ``latent_decode_attention`` (one shared compressed row a token) each
+  agree with an independent float64 numpy oracle across ragged
+  lengths, lengths around every page edge, a live slot of length 0,
+  full slots and arbitrary page-table permutations — on a layer's own
+  pool and on the engine's stacked buffer with ``layer=``;
+* the latent body's page-block loop gives the oracle's answer at every
+  block size;
 * the trash page is never READ into an output: arbitrary finite
-  garbage in page 0 changes no live slot's result, on every impl;
-* the ``decode_attn`` / ``int8_mm`` auto-tuner sites: golden keys,
-  model dispatch flips dense -> fused (the analytic gather-tax model),
-  the measured prewarm cycle persists and then serves from cache, and
-  tuner-off ``impl="auto"`` is exactly the static dense policy.
+  garbage in page 0 changes no live slot's result;
+* a table sliced to the used-page bucket gives the full table's
+  output to the last bit (what lets the engine slice every step);
+* the ``int8_mm`` auto-tuner site: golden key, never-lose, the
+  measured prewarm cycle persists.
 """
 
 import json
@@ -26,8 +29,8 @@ import jax.numpy as jnp
 from bigdl_tpu.ops import autotune
 from bigdl_tpu.ops import decode_attention as D
 from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
+                                            latent_decode_attention,
                                             paged_decode_attention,
-                                            static_decode_dispatch,
                                             used_page_bucket)
 from bigdl_tpu.serving.cache import pool_shape
 
@@ -52,28 +55,65 @@ def tuner(tmp_path, monkeypatch):
     autotune.reset()
 
 
-def _state(b=4, h=4, d=16, p=8, maxp=8, pool=24, seed=0,
-           lengths=None):
-    """Random paged K/V state with ragged lengths (incl. a page
-    boundary) and a permuted page table; slot 0 is inactive (length 0,
-    trash table row) like a released engine slot."""
+P, MAXP = 8, 8
+# lengths a slot (``pos <= length`` attends); None is a released slot:
+# length 0 and a table row of trash pages
+LENGTHS = {
+    "ragged": [None, P - 1, P, 3 * P - 1],
+    "page_edges": [k * P + e for k in (1, 2, 5) for e in (-1, 0, 1)],
+    "length_zero": [0, None, 5, 0],
+    "all_full": [MAXP * P - 1] * 4,
+}
+
+
+def _tables(lengths, p, maxp, pool, rs):
+    """A permuted page table: a live slot owns the pages its length
+    reaches, every other entry points at the trash page."""
+    tbl = np.zeros((len(lengths), maxp), np.int32)
+    free = list(range(1, pool))
+    rs.shuffle(free)
+    for i, ln in enumerate(lengths):
+        for j in range(0 if ln is None else ln // p + 1):
+            tbl[i, j] = free.pop()
+    lens = np.asarray([ln or 0 for ln in lengths], np.int32)
+    return jnp.asarray(tbl), jnp.asarray(lens)
+
+
+def _state(lengths=LENGTHS["ragged"], h=4, d=16, p=P, maxp=MAXP, seed=0):
+    """Random paged K/V state under ``lengths``."""
     rs = np.random.RandomState(seed)
+    b = len(lengths)
+    pool = 1 + b * maxp
     q = jnp.asarray(rs.randn(b, h, d).astype(np.float32))
     shape = pool_shape(pool, p, h, d)
     kp = jnp.asarray(rs.randn(*shape).astype(np.float32))
     vp = jnp.asarray(rs.randn(*shape).astype(np.float32))
-    if lengths is None:
-        lengths = [0, p - 1, p, min(3 * p - 1, maxp * p - 1)][:b]
-        lengths += [1] * (b - len(lengths))
-    tbl = np.zeros((b, maxp), np.int32)
-    free = list(range(1, pool))
-    rs.shuffle(free)
-    for i, ln in enumerate(lengths):
-        need = ln // p + 1 if ln else 0
-        for j in range(min(need, maxp)):
-            tbl[i, j] = free.pop()
-    return (q, kp, vp, jnp.asarray(tbl),
-            jnp.asarray(np.asarray(lengths, np.int32)))
+    return (q, kp, vp, *_tables(lengths, p, maxp, pool, rs))
+
+
+def _latent_state(lengths=LENGTHS["ragged"], h=4, r=24, p=P, maxp=MAXP,
+                  seed=0):
+    """Random latent rows (one buffer, ``r`` lanes a token) under
+    ``lengths``."""
+    rs = np.random.RandomState(seed)
+    b = len(lengths)
+    pool = 1 + b * maxp
+    q = jnp.asarray(rs.randn(b, h, r).astype(np.float32))
+    pages = jnp.asarray(rs.randn(pool, p, r).astype(np.float32))
+    return (q, pages, *_tables(lengths, p, maxp, pool, rs))
+
+
+def _stacked(pool, layer, n_layer=3):
+    """The engine's stacked buffer with ``pool`` at ``layer`` and other
+    values in every other layer."""
+    return jnp.stack([pool if i == layer else jnp.full_like(pool, 7.0 + i)
+                      for i in range(n_layer)])
+
+
+def _softmax_rows(s):
+    s = s - s.max(axis=-1, keepdims=True)
+    pr = np.exp(s)
+    return pr / pr.sum(axis=-1, keepdims=True)
 
 
 def _numpy_reference(q, kp, vp, tables, lengths, p):
@@ -92,121 +132,117 @@ def _numpy_reference(q, kp, vp, tables, lengths, p):
         v = np.concatenate([vp[tables[i, j]] for j in range(maxp)],
                            axis=0).reshape(maxp * p, h, d)
         n = int(lengths[i]) + 1
-        s = np.einsum("hd,khd->hk", q[i], k[:n]) * scale
-        s -= s.max(axis=-1, keepdims=True)
-        pr = np.exp(s)
-        pr /= pr.sum(axis=-1, keepdims=True)
+        pr = _softmax_rows(np.einsum("hd,khd->hk", q[i], k[:n]) * scale)
         out[i] = np.einsum("hk,khd->hd", pr, v[:n])
     return out
 
 
+def _latent_reference(q, pages, tables, lengths, scale, vw):
+    """The same oracle for a latent cache: every head scores the whole
+    shared row and mixes its first ``vw`` lanes."""
+    q, pages = np.asarray(q, np.float64), np.asarray(pages, np.float64)
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    b, h, _ = q.shape
+    out = np.zeros((b, h, vw))
+    for i in range(b):
+        rows = np.concatenate([pages[j] for j in tables[i]], axis=0)
+        n = int(lengths[i]) + 1
+        pr = _softmax_rows(q[i] @ rows[:n].T * scale)
+        out[i] = pr @ rows[:n, :vw]
+    return out
+
+
 class TestPagedDecodeParity:
-    def test_dense_matches_numpy_oracle(self):
-        q, kp, vp, tbl, lens = _state()
-        got = paged_decode_attention(q, kp, vp, tbl, lens, page_size=8,
-                                     impl="dense")
-        want = _numpy_reference(q, kp, vp, tbl, lens, 8)
+    @pytest.mark.parametrize("layout", ["pool", "stacked"])
+    @pytest.mark.parametrize("case", sorted(LENGTHS))
+    def test_dense_matches_numpy_oracle(self, case, layout):
+        q, kp, vp, tbl, lens = _state(LENGTHS[case])
+        want = _numpy_reference(q, kp, vp, tbl, lens, P)
+        if layout == "stacked":  # read in place at layer 1 of 3
+            got = paged_decode_attention(
+                q, _stacked(kp, 1), _stacked(vp, 1), tbl, lens,
+                page_size=P, layer=1)
+        else:
+            got = paged_decode_attention(q, kp, vp, tbl, lens,
+                                         page_size=P)
         np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
 
-    @pytest.mark.parametrize("bp", [0, 1, 2, 4])
-    def test_fused_matches_dense_ragged(self, bp):
-        q, kp, vp, tbl, lens = _state()
-        dense = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl="dense")
-        fused = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl="fused",
-                                       block_pages=bp)
-        np.testing.assert_allclose(np.asarray(fused), np.asarray(dense),
-                                   atol=1e-5)
-
-    def test_fused_matches_dense_across_page_boundaries(self):
-        # every length around each page boundary of a 3-page window
-        for ln in (1, 7, 8, 9, 15, 16, 17, 23):
-            q, kp, vp, tbl, lens = _state(b=2, maxp=3, seed=ln,
-                                          lengths=[ln, 1])
-            dense = paged_decode_attention(q, kp, vp, tbl, lens,
-                                           page_size=8, impl="dense")
-            fused = paged_decode_attention(q, kp, vp, tbl, lens,
-                                           page_size=8, impl="fused",
-                                           block_pages=1)
-            np.testing.assert_allclose(np.asarray(fused),
-                                       np.asarray(dense), atol=1e-5)
-
-    def test_fused_fori_path_matches(self):
-        # > 4 chunks takes the lax.fori_loop branch
-        q, kp, vp, tbl, lens = _state(maxp=8)
-        dense = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl="dense")
-        fused = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl="fused",
-                                       block_pages=1)
-        np.testing.assert_allclose(np.asarray(fused), np.asarray(dense),
-                                   atol=1e-5)
-
-    def test_pallas_interpret_matches_dense(self):
-        q, kp, vp, tbl, lens = _state(b=3, h=2, d=8, p=4, maxp=4,
-                                      pool=16)
-        dense = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=4, impl="dense")
-        pal = paged_decode_attention(q, kp, vp, tbl, lens, page_size=4,
-                                     impl="pallas_interpret")
-        np.testing.assert_allclose(np.asarray(pal), np.asarray(dense),
-                                   atol=1e-5)
-
-    @pytest.mark.parametrize("shape", ["engine", "tiny"])
-    def test_three_bodies_agree_on_the_token_major_cache(self, shape):
-        """dense, fused and the Pallas kernel read the one cache
-        layout — a layer's pool handed in, and the engine's stacked
-        buffers with the layer's index — and agree, at the engine's
-        shape (chip_smoke.py FULL) and at a tiny one."""
-        c = (dict(b=8, h=8, d=64, p=16, maxp=32) if shape == "engine"
-             else dict(b=2, h=2, d=8, p=4, maxp=2))
-        q, kp, vp, tbl, lens = _state(
-            b=c["b"], h=c["h"], d=c["d"], p=c["p"], maxp=c["maxp"],
-            pool=1 + c["b"] * c["maxp"], seed=11)
-        assert kp.shape == (1 + c["b"] * c["maxp"], c["p"],
-                            c["h"] * c["d"])
-        outs = {impl: paged_decode_attention(
-            q, kp, vp, tbl, lens, page_size=c["p"], impl=impl,
-            block_pages=4) for impl in ("dense", "fused",
-                                        "pallas_interpret")}
-        want = _numpy_reference(q, kp, vp, tbl, lens, c["p"])
-        for impl, got in outs.items():
-            np.testing.assert_allclose(np.asarray(got), want, atol=2e-5,
-                                       err_msg=impl)
-        # the stacked cache, read in place at layer 1 of 2
-        kps, vps = (jnp.stack([jnp.full_like(x, 7.0), x])
-                    for x in (kp, vp))
-        for impl, got in outs.items():
-            stacked = paged_decode_attention(
-                q, kps, vps, tbl, lens, page_size=c["p"], impl=impl,
-                block_pages=4, layer=1)
-            np.testing.assert_array_equal(np.asarray(stacked),
-                                          np.asarray(got), err_msg=impl)
-
-    @pytest.mark.parametrize("impl", ["dense", "fused",
-                                      "pallas_interpret"])
-    def test_trash_page_never_read(self, impl):
+    @pytest.mark.parametrize("layout", ["pool", "stacked"])
+    def test_trash_page_never_read(self, layout):
         """Finite garbage in page 0 (the reserved trash page) must not
         change any live slot's output — the `pos <= length` mask
-        contract every impl shares."""
+        contract."""
         q, kp, vp, tbl, lens = _state()
+        kw = {}
+        if layout == "stacked":  # what the engine hands the body
+            kp, vp, kw = _stacked(kp, 1), _stacked(vp, 1), {"layer": 1}
         clean = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl=impl)
-        kp2 = kp.at[0].set(1e30)
-        vp2 = vp.at[0].set(1e30)
-        dirty = paged_decode_attention(q, kp2, vp2, tbl, lens,
-                                       page_size=8, impl=impl)
+                                       page_size=P, **kw)
+        trash = (slice(None), 0) if layout == "stacked" else 0
+        dirty = paged_decode_attention(
+            q, kp.at[trash].set(1e30), vp.at[trash].set(1e30), tbl,
+            lens, page_size=P, **kw)
         live = np.asarray(lens) > 0
         np.testing.assert_array_equal(np.asarray(dirty)[live],
                                       np.asarray(clean)[live])
         assert np.isfinite(np.asarray(dirty)[live]).all()
 
-    def test_invalid_impl_raises(self):
-        q, kp, vp, tbl, lens = _state(b=1, maxp=1)
-        with pytest.raises(ValueError, match="impl"):
-            paged_decode_attention(q, kp, vp, tbl, lens, page_size=8,
-                                   impl="nope")
+
+class TestLatentDecodeParity:
+    SCALE, VW = 0.3, 16
+
+    def _run(self, q, pages, tbl, lens, **kw):
+        return np.asarray(latent_decode_attention(
+            q, pages, tbl, lens, scale=self.SCALE, value_width=self.VW,
+            **kw))
+
+    @pytest.mark.parametrize("block_pages", [MAXP, 1, 2, 4])
+    def test_block_loop_matches_numpy_oracle(self, block_pages):
+        """One block (the whole width) and the ``fori_loop`` at three
+        block sizes, 8 pages a slot."""
+        q, pages, tbl, lens = _latent_state()
+        assert D._chunk_pages(MAXP, block_pages) == block_pages
+        want = _latent_reference(q, pages, tbl, lens, self.SCALE, self.VW)
+        got = self._run(q, pages, tbl, lens, block_pages=block_pages)
+        assert got.shape == (4, 4, self.VW) and got.dtype == np.float32
+        np.testing.assert_allclose(got, want, atol=1e-5)
+
+    def test_page_edges_and_length_zero_in_one_batch(self):
+        lengths = LENGTHS["page_edges"] + [0, None]
+        q, pages, tbl, lens = _latent_state(lengths, seed=3)
+        want = _latent_reference(q, pages, tbl, lens, self.SCALE, self.VW)
+        for bp in (MAXP, 2):
+            np.testing.assert_allclose(
+                self._run(q, pages, tbl, lens, block_pages=bp), want,
+                atol=1e-5, err_msg=f"block_pages={bp}")
+
+    def test_stacked_buffer_is_read_at_its_layer(self):
+        q, pages, tbl, lens = _latent_state(seed=5)
+        own = self._run(q, pages, tbl, lens, block_pages=2)
+        stacked = self._run(q, _stacked(pages, 2), tbl, lens,
+                            block_pages=2, layer=2)
+        np.testing.assert_array_equal(stacked, own)
+
+
+@pytest.mark.parametrize("body", ["paged", "latent"])
+def test_bucket_slice_equals_the_full_table_to_the_last_bit(body):
+    """The engine hands a step the table's first ``used_page_bucket``
+    columns: every position past the longest length is masked, so the
+    narrower read changes no bit of the output."""
+    lengths = [None, P - 1, P, 2 * P - 1]
+    bucket = used_page_bucket(max(ln or 0 for ln in lengths), P, MAXP)
+    assert bucket == 2 < MAXP
+    if body == "paged":
+        q, kp, vp, tbl, lens = _state(lengths)
+        full, cut = (paged_decode_attention(q, kp, vp, t, lens,
+                                            page_size=P)
+                     for t in (tbl, tbl[:, :bucket]))
+    else:   # a block a page: 8 trips of the loop against 2
+        q, pages, tbl, lens = _latent_state(lengths)
+        full, cut = (latent_decode_attention(
+            q, pages, t, lens, scale=0.3, value_width=16, block_pages=1)
+            for t in (tbl, tbl[:, :bucket]))
+    np.testing.assert_array_equal(np.asarray(cut), np.asarray(full))
 
 
 class TestBucketHelpers:
@@ -227,67 +263,13 @@ class TestBucketHelpers:
         assert D._chunk_pages(8, 4) == 4
         assert D._chunk_pages(1, 1) == 1
 
-    def test_decode_hbm_bytes_dense_carries_gather_tax(self):
-        d = decode_hbm_bytes("dense", 8, 8, 16, 16, 4)
-        f = decode_hbm_bytes("fused", 8, 8, 16, 16, 4)
-        p = decode_hbm_bytes("pallas", 8, 8, 16, 16, 4)
-        assert d > 2 * f        # the materialized copy + score plane
-        assert f == p
-
-    def test_static_dispatch_is_dense(self):
-        assert static_decode_dispatch() == ("dense", 0)
-
-
-class TestDecodeAttnTunerSite:
-    def test_golden_key_and_model_flips_to_fused(self, tuner):
-        rec = autotune.decide_decode_attn((4, 4, 16), 8, 4, jnp.float32)
-        assert rec is not None
-        assert rec["key"] == "decode_attn|b4h4d16p8m4|float32|cpu"
-        assert rec["impl"] == "fused"        # analytic gather-tax model
-        assert rec["source"] == "model"
-        assert rec["static"] == "dense"
-        assert rec["block_pages"] >= 1
-
-    def test_auto_dispatch_consults_and_caches(self, tuner):
-        q, kp, vp, tbl, lens = _state()
-        out = paged_decode_attention(q, kp, vp, tbl, lens, page_size=8,
-                                     impl="auto")
-        dense = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl="dense")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
-                                   atol=1e-5)
-        doc = json.loads(tuner.read_text())
-        sites = {r["site"] for r in doc["decisions"].values()}
-        assert "decode_attn" in sites
-
-    def test_measured_prewarm_cold_then_warm(self, tuner, monkeypatch):
-        monkeypatch.setenv("BIGDL_TUNER_MEASURE", "1")
-        monkeypatch.setenv("BIGDL_TUNER_MEASURE_ITERS", "1")
-        autotune.reset()
-        autotune.prewarm_decode_attn(2, 2, 8, page_size=4, maxp=2)
-        doc = json.loads(tuner.read_text())
-        recs = [r for r in doc["decisions"].values()
-                if r["site"] == "decode_attn"]
-        assert recs and recs[0]["source"] == "measured"
-        assert recs[0]["measured_s"]
-        # pallas is measurable (interpret) so it must have been probed
-        assert any(lbl.startswith("pallas")
-                   for lbl in recs[0]["measured_s"])
-        autotune.reset()    # fresh process: everything from the cache
-        autotune.prewarm_decode_attn(2, 2, 8, page_size=4, maxp=2)
-        st = autotune.get_cache().stats()
-        assert st["misses"] == 0 and st["hits"] >= 1
-
-    def test_tuner_off_auto_is_static_dense(self):
-        # with the tuner off, impl="auto" must never consult the site:
-        # no cache, no decisions, numerics == dense
-        q, kp, vp, tbl, lens = _state(b=2, maxp=2)
-        out = paged_decode_attention(q, kp, vp, tbl, lens, page_size=8,
-                                     impl="auto")
-        dense = paged_decode_attention(q, kp, vp, tbl, lens,
-                                       page_size=8, impl="dense")
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(dense))
-        assert autotune.get_cache().decisions == {}
+    def test_decode_hbm_bytes_carries_gather_tax(self):
+        b, h, d, p, maxp, item = 8, 8, 16, 16, 4, 4
+        pages = 2 * b * maxp * p * h * d * item       # K + V, once
+        # read, written as the gathered copy, read again; the f32 score
+        # plane out and back; q in and the output out
+        assert decode_hbm_bytes(b, h, d, p, maxp, item) == (
+            3 * pages + 2 * b * h * maxp * p * 4 + 2 * b * h * d * 4)
 
 
 class TestInt8MMSite:

@@ -552,15 +552,6 @@ def test_int8_and_tp_are_refused_with_a_reason(kw, what):
         LMEngine(model, params=params, max_batch=2, page_size=4, **kw)
 
 
-def test_other_decode_attention_bodies_are_refused():
-    model, params, _ = make()
-    eng = LMEngine(model, params=params, max_batch=2, page_size=4,
-                   decode_attn="fused")
-    eng.submit([1, 2, 3], 3)
-    with pytest.raises(ValueError, match="one decode attention body"):
-        eng.run_until_idle(timeout_s=60)
-
-
 # --------------------------------------------- the cache, as it is stated
 def test_a_cache_states_its_row_and_its_buffers():
     one = PagedKVCache(3, row_width=20, buffers=1, page_size=4,

@@ -217,24 +217,6 @@ class TestContinuousBatching:
             eng.submit([1, 2, 3], 100)
         eng.close()
 
-    def test_static_admission_drains_first(self, lm_model):
-        from bigdl_tpu.serving import LMEngine
-
-        eng = LMEngine(lm_model, max_batch=2, page_size=8,
-                       admission="static")
-        r1 = eng.submit([1, 2, 3], 6)
-        r2 = eng.submit([4, 5, 6], 2)
-        for _ in range(3):
-            eng.pump()
-        assert r2.done and not r1.done
-        r3 = eng.submit([7, 8, 9], 2)
-        eng.pump()
-        # the freed slot stays empty until the whole batch drains
-        assert eng.active_count() == 1 and not r3.done
-        eng.run_until_idle(60)
-        assert r3.done
-        eng.close()
-
     def test_int8_decode(self, lm_model):
         from bigdl_tpu.serving import LMEngine
 
@@ -250,14 +232,13 @@ class TestContinuousBatching:
     def test_int8_tokens_do_not_depend_on_page_placement(self, lm_model):
         """The int8 step reads and writes the same token-major cache:
         its greedy tokens are the same wherever the request's pages
-        lie and however wide the step's table is."""
+        lie and whatever the pool's size."""
         from bigdl_tpu.serving import LMEngine
 
         prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5]
         runs = []
         for kw, first in ((dict(), None),
-                          (dict(decode_bucket=False, num_pages=40),
-                           [7, 7, 7])):
+                          (dict(num_pages=40), [7, 7, 7])):
             eng = LMEngine(lm_model, max_batch=2, page_size=8, int8=True,
                            **kw)
             if first is not None:        # takes the pages run 1 used
@@ -315,80 +296,13 @@ class TestTPDecode:
             LMEngine(lm_model, tp=3)
 
 
-# ------------------------------------------- decode kernels (ISSUE 13)
-class TestDecodeKernelDispatch:
-    """paged_decode_math's attention body is now
-    ops.decode_attention.paged_decode_attention — the fused flash-
-    decode path must reproduce the dense bit-match semantics through
-    every engine scenario (ragged admission, preemption refold, TP
-    head sharding, int8), and the used-page bucket must be observable.
-    """
-
-    def test_fused_engine_matches_generate_mid_batch(self, lm_model,
-                                                     lm_params):
-        from bigdl_tpu.serving import LMEngine
-
-        rs = np.random.RandomState(21)
-        p1, p2, p3 = (rs.randint(0, 48, (n,)) for n in (5, 9, 4))
-        eng = LMEngine(lm_model, max_batch=2, page_size=8,
-                       decode_attn="fused")
-        r1 = eng.submit(p1, 10)
-        r2 = eng.submit(p2, 3)
-        for _ in range(3):
-            eng.pump()
-        assert r2.done and not r1.done
-        r3 = eng.submit(p3, 7)     # admitted mid-flight
-        eng.run_until_idle(60)
-        eng.close()
-        assert _out(p1, r1) == _ref(lm_model, lm_params, p1, 10)
-        assert _out(p2, r2) == _ref(lm_model, lm_params, p2, 3)
-        assert _out(p3, r3) == _ref(lm_model, lm_params, p3, 7)
-
-    def test_fused_engine_survives_preemption_refold(self, lm_model,
-                                                     lm_params):
-        from bigdl_tpu.serving import LMEngine
-
-        rs = np.random.RandomState(22)
-        p1, p2 = rs.randint(0, 48, (5,)), rs.randint(0, 48, (9,))
-        eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=8,
-                       decode_attn="fused")
-        a, b = eng.submit(p1, 12), eng.submit(p2, 12)
-        eng.run_until_idle(120)
-        assert eng.stats()["preemptions"] >= 1
-        eng.close()
-        assert _out(p1, a) == _ref(lm_model, lm_params, p1, 12)
-        assert _out(p2, b) == _ref(lm_model, lm_params, p2, 12)
-
-    def test_tp_fused_agrees(self, lm_model, lm_params):
-        from bigdl_tpu.serving import LMEngine
-
-        rs = np.random.RandomState(23)
-        p1, p2 = rs.randint(0, 48, (5,)), rs.randint(0, 48, (9,))
-        eng = LMEngine(lm_model, max_batch=2, page_size=8, tp=4,
-                       decode_attn="fused")
-        r1, r2 = eng.submit(p1, 6), eng.submit(p2, 3)
-        eng.run_until_idle(120)
-        eng.close()
-        assert _out(p1, r1) == _ref(lm_model, lm_params, p1, 6)
-        assert _out(p2, r2) == _ref(lm_model, lm_params, p2, 3)
-
-    def test_int8_fused_passthrough(self, lm_model):
-        from bigdl_tpu.serving import LMEngine
-
-        eng = LMEngine(lm_model, max_batch=2, page_size=8, int8=True,
-                       decode_attn="fused")
-        r = eng.submit([3, 1, 4, 1, 5], 8)
-        eng.run_until_idle(60)
-        eng.close()
-        assert r.done and len(r.tokens) == 8
-        assert all(0 <= t < 48 for t in r.tokens)
-
+# ----------------------------------------------- the used-page bucket
+class TestDecodeBucket:
     def test_bucket_slices_tables_and_gauges_publish(self, lm_model):
         from bigdl_tpu import obs
         from bigdl_tpu.serving import LMEngine
 
         eng = LMEngine(lm_model, max_batch=2, page_size=8)
-        assert eng.decode_bucket       # default ON
         r = eng.submit([1, 2, 3], 4)   # short: 1 page in use
         eng.run_until_idle(60)
         st = eng.stats()
@@ -403,52 +317,26 @@ class TestDecodeKernelDispatch:
         assert reg.gauge(
             "bigdl_serve_decode_hbm_bytes_per_token")._solo().value > 0
 
-    def test_bucket_off_ships_full_tables(self, lm_model):
+    def test_nothing_chooses_a_decode_path(self):
+        """The bucket is always on, admission continuous and the
+        attention body the cache's kind's: no constructor argument, no
+        config field and no environment name selects another."""
+        import dataclasses
+        import inspect
+
+        from bigdl_tpu.config import ServeConfig, refresh_from_env
         from bigdl_tpu.serving import LMEngine
 
-        eng = LMEngine(lm_model, max_batch=2, page_size=8,
-                       decode_bucket=False)
-        r = eng.submit([1, 2, 3], 3)
-        eng.run_until_idle(60)
-        st = eng.stats()
-        eng.close()
-        assert r.done
-        assert st["last_bucket_pages"] == eng.cache.max_pages_per_slot
-
-    def test_invalid_decode_attn_rejected(self, lm_model):
-        from bigdl_tpu.serving import LMEngine
-
-        with pytest.raises(ValueError, match="decode_attn"):
-            LMEngine(lm_model, decode_attn="nope")
-
-    def test_tuner_dispatches_fused_in_engine(self, lm_model, lm_params,
-                                              tmp_path, monkeypatch):
-        from bigdl_tpu.ops import autotune
-        from bigdl_tpu.serving import LMEngine
-
-        monkeypatch.setenv("BIGDL_TUNER", "1")
-        monkeypatch.setenv("BIGDL_TUNER_CACHE",
-                           str(tmp_path / "tuner.json"))
-        autotune.reset()
-        try:
-            rs = np.random.RandomState(24)
-            p1 = rs.randint(0, 48, (5,))
-            eng = LMEngine(lm_model, max_batch=2, page_size=8)
-            assert eng.decode_attn == "auto"
-            r1 = eng.submit(p1, 8)
-            eng.run_until_idle(60)
-            st = eng.stats()
-            eng.close()
-            # the analytic gather-tax model flips every bucket to the
-            # fused flash-decode path — and tokens still match the
-            # contiguous-cache generate()
-            assert st["decode_impl_by_bucket"]
-            assert set(st["decode_impl_by_bucket"].values()) == {"fused"}
-            assert _out(p1, r1) == _ref(lm_model, lm_params, p1, 8)
-            sites = {d["site"] for d in autotune.summary()["decisions"]}
-            assert "decode_attn" in sites
-        finally:
-            autotune.reset()
+        gone = ("decode_attn", "decode_bucket", "admission")
+        offered = set(inspect.signature(LMEngine.__init__).parameters)
+        offered |= {f.name for f in dataclasses.fields(ServeConfig)}
+        assert not offered & set(gone)
+        text = refresh_from_env().describe() + inspect.getsource(
+            inspect.getmodule(ServeConfig))
+        for name in gone + ("BIGDL_SERVE_DECODE_ATTN",
+                            "BIGDL_SERVE_DECODE_BUCKET",
+                            "BIGDL_SERVE_ADMISSION"):
+            assert name not in text, name
 
 
 # ----------------------------------------------------- queue / batcher

@@ -3,8 +3,7 @@
 ``jit(f).trace(...).lower(lowering_platforms=("tpu",))`` runs the JAX
 side of the Mosaic lowering without a chip.  It catches what the CPU
 interpreter never sees: a BlockSpec whose last two block dims are neither
-(8, 128)-divisible nor full (the refusal that kept the flash-decode
-kernel from ever compiling), a scalar store to VMEM, an unsupported
+(8, 128)-divisible nor full, a scalar store to VMEM, an unsupported
 primitive.  Whether libtpu's Mosaic then accepts the module is
 ``chip_smoke.py``'s kernel phase, on the chip.  Shapes are the smoke's.
 """
@@ -51,21 +50,29 @@ def test_flash_forward_and_both_backwards_lower(b, h, tq, tk, d):
     assert n == 3  # forward, dq, dkv
 
 
+# GPT-2 XL's widths and the benchmark's engine (benchmarks/configs/
+# gpt2_xl.json), cut to 2 layers and a 256-word vocabulary: neither
+# changes how a program treats the cache
+XL = dict(dim=1600, n_head=25, head_dim=64, n_layer=2, max_len=1024,
+          vocab=256, max_batch=12, page_size=16, num_pages=481)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_lowers_at_the_engine_shape(dtype):
-    c = FULL["decode"]
-    pool = 1 + c["b"] * c["maxp"]
+    """The gather body at the serving cell's widths, on the stacked
+    buffer, lowers for the TPU as plain XLA: no kernel of ours in it."""
+    c = XL
+    b, h, d, page = c["max_batch"], c["n_head"], c["head_dim"], \
+        c["page_size"]
 
     def f(q, kp, vp, tables, lengths):
         return paged_decode_attention(q, kp, vp, tables, lengths,
-                                      page_size=c["p"], impl="pallas",
-                                      interpret=False)
+                                      page_size=page, layer=1)
 
-    kv = (pool_shape(pool, c["p"], c["h"], c["d"]), dtype)
-    n = _mosaic_calls(f, ((c["b"], c["h"], c["d"]), dtype), kv, kv,
-                      ((c["b"], c["maxp"]), jnp.int32),
-                      ((c["b"],), jnp.int32))
-    assert n == 1
+    kv = (pool_shape(c["num_pages"], page, h, d, c["n_layer"]), dtype)
+    n = _mosaic_calls(f, ((b, h, d), dtype), kv, kv,
+                      ((b, 32), jnp.int32), ((b,), jnp.int32))
+    assert n == 0
 
 
 @pytest.mark.parametrize("n,c,hw,o,k,stride", FULL["conv"])
@@ -146,29 +153,12 @@ def test_libtpu_mosaic_compiles_every_kernel_without_a_chip(one_chip):
                     seq_offset=off).astype(jnp.float32)),
                 argnums=(0, 1, 2))(q, k, v),
             ((b, h, tq, d), dt), ((b, h, tk, d), dt), ((b, h, tk, d), dt))
-    c = FULL["decode"]
-    pool = 1 + c["b"] * c["maxp"]
-    for kv_dt in (jnp.float32, jnp.bfloat16):
-        kv = (pool_shape(pool, c["p"], c["h"], c["d"]), kv_dt)
-        compile_(
-            lambda q, kp, vp, t, n: paged_decode_attention(
-                q, kp, vp, t, n, page_size=c["p"], impl="pallas",
-                interpret=False),
-            ((c["b"], c["h"], c["d"]), kv_dt), kv, kv,
-            ((c["b"], c["maxp"]), jnp.int32), ((c["b"],), jnp.int32))
     for n, ci, hw, o, k, stride in FULL["conv"]:
         compile_(
             lambda x, w, s, k=k, stride=stride: conv_bn_stats(
                 x, w, s, stride=stride, pad=(k - 1) // 2, impl="pallas",
                 interpret=False),
             ((n, ci, hw, hw), dt), ((o, ci, k, k), dt), ((o,), jnp.float32))
-
-
-# GPT-2 XL's widths and the benchmark's engine (benchmarks/configs/
-# gpt2_xl.json), cut to 2 layers and a 256-word vocabulary: neither
-# changes how a program treats the cache
-XL = dict(dim=1600, n_head=25, head_dim=64, n_layer=2, max_len=1024,
-          vocab=256, max_batch=12, page_size=16, num_pages=481)
 
 
 @pytest.mark.slow
@@ -180,8 +170,8 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
     whole cache's shape (a ``copy`` there is a layout conversion: four
     of them were 33.5 of the step's 56 ms, ledger PR 24), and the
     temporaries are small beside the cache (the padded working copy
-    was 2.56x of it).  Also the re-blocked Pallas kernel at these
-    widths.  Run it before spending chip minutes on the cache."""
+    was 2.56x of it).  Run it before spending chip minutes on the
+    cache."""
     import re
 
     from bigdl_tpu.models.transformer import build_transformer_lm
@@ -195,8 +185,7 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
         n_layer=c["n_layer"], max_len=c["max_len"])
     params = jax.tree.map(lambda a: a.astype(dt), model.params())
     eng = LMEngine(model, params=params, max_batch=c["max_batch"],
-                   page_size=c["page_size"], num_pages=c["num_pages"],
-                   decode_attn="dense")
+                   page_size=c["page_size"], num_pages=c["num_pages"])
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
@@ -244,17 +233,6 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
         print(f"{name}: whole-cache instructions {ops}, temporaries "
               f"{temp / 1e6:.1f} MB, one cache buffer "
               f"{buffer_bytes / 1e6:.1f} MB")
-
-    for kv_dt in (jnp.float32, jnp.bfloat16):
-        kv = spec(eng.cache.kp.shape, kv_dt)
-        lowered = jax.jit(
-            lambda q, kp, vp, t, n: paged_decode_attention(
-                q, kp, vp, t, n, page_size=page, impl="pallas",
-                interpret=False, layer=1)).lower(
-            spec((b, c["n_head"], c["head_dim"]), kv_dt), kv, kv,
-            spec((b, 32), jnp.int32), spec((b,), jnp.int32))
-        assert "tpu_custom_call" in lowered.as_text()
-        lowered.compile()
 
 
 @pytest.mark.slow
@@ -308,7 +286,7 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
     # the engine's own builders, given shapes in place of an engine
     eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None, decode_attn="auto",
+        model=probe, page_size=page, _qparams=None,
         cache=types.SimpleNamespace(buffers=lambda: (buf,)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)

@@ -335,8 +335,7 @@ def lm_model():
 def _serve(lm_model, prompts, new):
     from bigdl_tpu.serving import LMEngine
 
-    eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33,
-                   decode_attn="dense")
+    eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33)
     reqs = [eng.submit(p, new) for p in prompts]
     eng.run_until_idle()
     return eng, reqs
@@ -448,8 +447,7 @@ class TestEngineSpans:
 
         from bigdl_tpu.serving import LMEngine
 
-        eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33,
-                       decode_attn="dense")
+        eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33)
         tables, lengths = eng.cache.device_tables()
         z = jnp.zeros((2,), jnp.int32)
         step_txt = eng._step_fn.lower(
