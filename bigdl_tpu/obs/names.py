@@ -390,7 +390,16 @@ SERVE_LATENCY_SLO_RATIO = _m(
     doc="Share of recent requests inside the e2e latency SLO")
 SERVE_DECODE_ATTN_MS = _m(
     "bigdl_serve_decode_attn_ms", "gauge", policy="max",
-    doc="Mean decode-attention kernel milliseconds per step")
+    doc="Mean milliseconds the engine's thread spends in a decode step: "
+        "dispatching step k and waiting for step k-1's tokens")
+SERVE_STEPS_AHEAD_TOTAL = _m(
+    "bigdl_serve_steps_ahead_total", "counter",
+    doc="Decode steps dispatched while the previous step's tokens were "
+        "still unread (the pipelined loop engaging)")
+SERVE_SETTLES_TOTAL = _m(
+    "bigdl_serve_settles_total", "counter", ("reason",), 4,
+    "Steps in flight read outside the pipelined loop, by reason "
+    "(preempt, swap, idle, close)")
 SERVE_DECODE_HBM_BYTES_PER_TOKEN = _m(
     "bigdl_serve_decode_hbm_bytes_per_token", "gauge", policy="max",
     doc="Modeled HBM traffic per decoded token")

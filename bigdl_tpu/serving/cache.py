@@ -185,13 +185,20 @@ class PagedKVCache:
         ``used_page_bucket``), so a mostly-empty pool ships a few
         dozen bytes and the decode step never gathers the unallocated
         tail.  Entries past a slot's pages are 0 (trash) either way —
-        the mask contract is unchanged."""
+        the mask contract is unchanged.
+
+        Both are COPIES of the host's arrays: the engine advances
+        lengths and grows tables while the step that was given these is
+        still in flight, and a host array put on the device may be read
+        later than the call (or, on the CPU backend, be the device
+        array)."""
         import jax.numpy as jnp
 
         tables = self.page_tables
         if pages is not None and pages < self.max_pages_per_slot:
             tables = tables[:, :int(pages)]
-        return (jnp.asarray(tables), jnp.asarray(self.lengths))
+        return (jnp.asarray(np.array(tables)),
+                jnp.asarray(np.array(self.lengths)))
 
     def padded_positions(self) -> int:
         """Columns of the gathered per-slot attention window."""

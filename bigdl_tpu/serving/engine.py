@@ -27,7 +27,17 @@ that with the production shape:
 * **preemption**: if the page pool is exhausted mid-decode, the
   youngest request is preempted — pages freed, the request re-queued
   with its generated prefix as prompt — instead of deadlocking the
-  batch.
+  batch;
+* **one step in flight**: step k+1 is dispatched before step k's
+  tokens are read, so the host's emit, admission and prep run while the
+  chip decodes.  A step's tokens are an argument of the next step and
+  never visit the host on the way (a slot admitted since is overridden
+  from the host inside the program); lengths, pages and the owed count
+  advance at dispatch, tokens are emitted one step behind, and
+  whatever needs host and chip to agree (preemption, a weight swap,
+  ``close``, an engine with nothing left to run) settles the step in
+  flight first.  An EOS is learnt one step late: the slot's row in the
+  step already dispatched is wasted, never emitted.
 
 **What the engine asks of a model** (``models/transformer.py`` and
 ``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
@@ -67,6 +77,8 @@ from bigdl_tpu.obs import names
 LAT_META = (names.REQUEST_LATENCY_SECONDS,
             "Request latency by engine and kind (ttft = time to first "
             "token, per_token = mean inter-token, e2e = submit to done)")
+#: why a step in flight was read outside the pipelined loop
+SETTLE_REASONS = ("preempt", "swap", "idle", "close")
 
 
 def sample_step(logits, temps, active, key):
@@ -100,18 +112,34 @@ def sample_first(logits, temp, key):
 
 
 class _Active:
-    """Host bookkeeping for one occupied slot."""
+    """Host bookkeeping for one occupied slot.  ``remaining`` counts
+    the tokens not yet DISPATCHED (emission lags one step);
+    ``first_token`` is the prefill's token until the slot's first
+    decode step has taken it from the host, then None: the slot's
+    input is the previous step's output, on the device."""
 
-    __slots__ = ("req", "remaining", "last_token", "prompt_len",
+    __slots__ = ("req", "remaining", "first_token", "prompt_len",
                  "t_admit", "order")
 
-    def __init__(self, req, remaining, last_token, prompt_len, order):
+    def __init__(self, req, remaining, first_token, prompt_len, order):
         self.req = req
         self.remaining = remaining
-        self.last_token = last_token
+        self.first_token = first_token
         self.prompt_len = prompt_len
         self.t_admit = time.monotonic()
         self.order = order
+
+
+class _InFlight:
+    """A dispatched decode step whose tokens the host has not read."""
+
+    __slots__ = ("nxt", "counts", "entries", "context_tokens")
+
+    def __init__(self, nxt, counts, entries, context_tokens):
+        self.nxt = nxt              # (B,) device array, the step's tokens
+        self.counts = counts        # an expert model's routing counts
+        self.entries = entries      # [(slot, _Active, last)] it ran for
+        self.context_tokens = context_tokens
 
 
 class LMEngine:
@@ -187,6 +215,12 @@ class LMEngine:
 
         self._last_bucket = self.cache.max_pages_per_slot
         self._decode_ms_sum = 0.0
+        # the step in flight (dispatched, its tokens unread) and the
+        # last dispatched step's tokens, the next step's input
+        self._inflight: Optional[_InFlight] = None
+        self._prev_nxt = jnp.zeros((self.max_batch,), jnp.int32)
+        self._steps_ahead = 0
+        self._settles = dict.fromkeys(SETTLE_REASONS, 0)
         self._weight_bytes = self._decode_weight_bytes()
         if self.tp > 1:
             self._step_fn = model.tp_decode_step(
@@ -230,8 +264,18 @@ class LMEngine:
             "on KV-page exhaustion")
         self._decode_ms_gauge = reg.gauge(
             names.SERVE_DECODE_ATTN_MS,
-            "Mean wall-clock of the jitted paged-decode step "
-            "(attention-dominated, memory-bound) in milliseconds")
+            "Mean wall-clock of a decode step on the engine's thread, "
+            "in milliseconds: the dispatch of step k and the wait for "
+            "step k-1's tokens (one step is in flight, so about the "
+            "step period less the host's own work)")
+        self._ahead_counter = reg.counter(
+            names.SERVE_STEPS_AHEAD_TOTAL,
+            "Decode steps dispatched while the previous step's tokens "
+            "were still unread")
+        self._settle_counter = reg.counter(
+            names.SERVE_SETTLES_TOTAL,
+            "Steps in flight read outside the pipelined loop, by "
+            "reason", labels=("reason",))
         self._decode_bytes_gauge = reg.gauge(
             names.SERVE_DECODE_HBM_BYTES_PER_TOKEN,
             "Analytic HBM bytes streamed per generated token (decode "
@@ -300,7 +344,14 @@ class LMEngine:
         tree and (int8) requantizing the per-channel twins — happens on
         the CALLER's thread, outside the engine lock; the swap itself
         is a pointer flip the decode loop observes at its next
-        ``pump`` cycle.  Page tables, slots and in-flight decodes
+        ``pump`` cycle.  Under the lock, before the flip, the step in
+        flight is settled (its tokens read and emitted): it ran on the
+        old weights, so every token emitted before this call returns
+        follows them, and the first step on the new weights is
+        dispatched with nothing pending.  A step's wall time on the
+        engine's thread (``decode_ms_mean``) spans the dispatch of step
+        k and the wait for step k-1's tokens; the settle here is
+        outside any step.  Page tables, slots and in-flight decodes
         survive untouched: the step and prefill functions take the
         params tree as an argument, so nothing recompiles on the float
         path.  The int8 step closes over the quantized twins, so that
@@ -322,6 +373,7 @@ class LMEngine:
         qparams = (self.model.quantize_for_decode(params)
                    if self.int8 else None)
         with self._lock:
+            self._settle("swap")
             self.params = params
             self._qparams = qparams
             if self.int8:
@@ -339,6 +391,7 @@ class LMEngine:
     # -------------------------------------------------------- jit builders
     def _build_step(self):
         import jax
+        import jax.numpy as jnp
 
         model, page_size = self.model, self.page_size
         qparams = self._qparams
@@ -346,8 +399,12 @@ class LMEngine:
 
         def step(params, *rest):
             # rest: the cache's buffers (donated), then tables, lengths,
-            # tokens, temps, active, key
-            tables, lengths, tokens, temps, active, key = rest[n:]
+            # prev (the last step's tokens, still on the device), the
+            # host's tokens and fresh (the slots that take theirs from
+            # the host: admitted since that step), temps, active, key
+            tables, lengths, prev, tokens, fresh, temps, active, key = \
+                rest[n:]
+            tokens = jnp.where(fresh, tokens, prev)
             caches, logits, counts = model.paged_decode(
                 params, rest[:n], tables, lengths, tokens, active,
                 page_size=page_size, qparams=qparams)
@@ -524,7 +581,10 @@ class LMEngine:
 
     def _preempt_youngest(self) -> Optional[int]:
         """Free the youngest active slot's pages; its request re-queues
-        with the generated prefix folded into the prompt."""
+        with the generated prefix folded into the prompt.  The step in
+        flight is settled first: the fold needs every dispatched token
+        in ``req.tokens`` (and a slot it completes is no victim)."""
+        self._settle("preempt")
         victims = [(s.order, i) for i, s in enumerate(self._slots)
                    if s is not None]
         if not victims:
@@ -629,12 +689,21 @@ class LMEngine:
             handoff=(error == HANDOFF_ERROR), e2e_s=e2e)
         return kept
 
+    def _runs(self, slot: int) -> bool:
+        """Whether ``slot`` owes a token beyond those dispatched."""
+        act = self._slots[slot]
+        return act is not None and act.remaining > 0
+
     def _step(self):
+        """Dispatch the next decode step, THEN read and emit the one
+        before it: while the host does that, admits and prepares again,
+        the chip runs the step just dispatched."""
         import jax
         import jax.numpy as jnp
 
-        if not self.active_count():
-            return False
+        if not any(self._runs(i) for i in range(self.max_batch)):
+            # nothing to dispatch: what is in flight is all there is
+            return self._settle("idle")
         # used-page prefix bucket (pow2): the step stops gathering the
         # empty pool; each bucket is one compiled variant
         from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
@@ -644,58 +713,76 @@ class LMEngine:
         step = self._steps
         with tracer.span(spans.SPAN_STEP_PREP, step=step) as span_id:
             # grow pages where the next position crosses a page
-            # boundary; exhaustion preempts the youngest request
-            # (possibly this one)
+            # boundary.  Exhaustion first settles the step in flight
+            # (a request it completes frees pages), then preempts the
+            # youngest request (possibly this one)
             for slot in range(self.max_batch):
-                if self._slots[slot] is None:
-                    continue
-                while self.cache.needs_growth(slot):
-                    if self.cache.grow(slot):
+                while self._runs(slot) and self.cache.needs_growth(slot):
+                    if self.cache.grow(slot) or self._settle("preempt"):
                         continue
                     victim = self._preempt_youngest()
                     if victim is None or victim == slot:
                         break
-            active_slots = [i for i, s in enumerate(self._slots)
-                            if s is not None]
-            if not active_slots:
+            running = [i for i in range(self.max_batch) if self._runs(i)]
+            if not running:
                 return False
             tokens = np.zeros((self.max_batch,), np.int32)
+            fresh = np.zeros((self.max_batch,), bool)
             temps = np.zeros((self.max_batch,), np.float32)
             active = np.zeros((self.max_batch,), bool)
-            for i in active_slots:
-                tokens[i] = self._slots[i].last_token
-                temps[i] = self._slots[i].req.temperature
+            for i in running:
+                act = self._slots[i]
+                if act.first_token is not None:
+                    # admitted since the last step: its input is the
+                    # prefill's token, from the host, this once
+                    tokens[i], fresh[i] = act.first_token, True
+                    act.first_token = None
+                temps[i] = act.req.temperature
                 active[i] = True
-            longest = max(int(self.cache.lengths[i])
-                          for i in active_slots)
+            longest = max(int(self.cache.lengths[i]) for i in running)
             bucket = used_page_bucket(longest, self.page_size,
                                       self.cache.max_pages_per_slot)
             self._last_bucket = bucket
             tables, lengths = self.cache.device_tables(pages=bucket)
             self._key, sub = jax.random.split(self._key)
-            tracer.add_attrs(span_id, bucket=bucket,
-                             active=len(active_slots))
+            tracer.add_attrs(span_id, bucket=bucket, active=len(running))
         t0 = time.perf_counter()
-        # a LIVE span around the batched decode dispatch+resolve (not a
-        # retroactive reqtrace hop): the continuous profiler attributes
-        # samples landing here to the decode phase by name
+        # a LIVE span (not a retroactive reqtrace hop): the continuous
+        # profiler attributes samples landing here to the decode phase
+        # by name.  It covers this step's dispatch and the wait for the
+        # PREVIOUS step's tokens
         n = len(self.cache.buffers())
+        prev = self._inflight
         with tracer.span(spans.SPAN_STEP_DECODE, bucket=bucket,
-                         active=len(active_slots)) as span_id:
+                         active=len(running),
+                         ahead=int(prev is not None)) as span_id:
             out = self._step_fn(
-                self.params, *self.cache.buffers(), tables,
-                lengths, jnp.asarray(tokens), jnp.asarray(temps),
-                jnp.asarray(active), sub)
+                self.params, *self.cache.buffers(), tables, lengths,
+                self._prev_nxt, jnp.asarray(tokens), jnp.asarray(fresh),
+                jnp.asarray(temps), jnp.asarray(active), sub)
             self.cache.set_buffers(out[:n])
-            nxt = np.asarray(out[n])
-            if len(out) > n + 1:
-                # an expert model's step: what it routed, and the rows
-                # of context it had to read
-                tracer.add_attrs(
-                    span_id, **self._note_routing(out[n + 1]),
-                    context_tokens=int(sum(
-                        int(self.cache.lengths[i]) + 1
-                        for i in active_slots)))
+            self._prev_nxt = out[n]
+            for arr in out[n:]:
+                # on their way to the host as soon as they exist, not
+                # when the next pump asks for them
+                arr.copy_to_host_async()
+            # the host's state advances at dispatch: the next prep
+            # (growth, bucket, who runs) needs no token
+            entries, context = [], 0
+            for i in running:
+                act = self._slots[i]
+                self.cache.lengths[i] += 1
+                context += int(self.cache.lengths[i])
+                act.remaining -= 1
+                entries.append((i, act, act.remaining <= 0))
+            self._inflight = _InFlight(
+                out[n], out[n + 1] if len(out) > n + 1 else None,
+                entries, context)
+            if prev is not None:
+                toks, routed = self._read(prev)
+                # an expert model's step: what the step just read
+                # routed, and the rows of context it had to read
+                tracer.add_attrs(span_id, **routed)
         step_ms = (time.perf_counter() - t0) * 1000.0
         with tracer.span(spans.SPAN_STEP_EMIT, step=step):
             self._steps += 1
@@ -710,21 +797,13 @@ class LMEngine:
                 * decode_hbm_bytes(
                     self.max_batch, heads, self.cache.row_width // heads,
                     self.page_size, bucket, kv_item)
-            self._decode_bytes_gauge.set(step_bytes / len(active_slots))
-            self._occ_sum += len(active_slots) / self.max_batch
+            self._decode_bytes_gauge.set(step_bytes / len(running))
+            self._occ_sum += len(running) / self.max_batch
             self._occ_gauge.set(self._occ_sum / self._steps)
-            for i in active_slots:
-                act = self._slots[i]
-                tok = int(nxt[i])
-                self.cache.lengths[i] += 1
-                act.last_token = tok
-                act.remaining -= 1
-                act.req.tokens.append(tok)
-                act.req.token_times.append(time.perf_counter())
-                self._tokens_total += 1
-                self._tokens_counter.inc()
-                if act.remaining <= 0 or tok == self.eos_id:
-                    self._complete(i)
+            if prev is not None:
+                self._steps_ahead += 1
+                self._ahead_counter.inc()
+                self._emit(prev, toks)
             try:
                 from bigdl_tpu.obs import server as obs_server
 
@@ -733,16 +812,64 @@ class LMEngine:
                 pass
         return True
 
+    def _read(self, rec: _InFlight):
+        """Wait for a dispatched step's tokens.  With them come an
+        expert model's routing counts: returned as span attributes,
+        beside the rows of context that step had to read."""
+        toks = np.asarray(rec.nxt)
+        if rec.counts is None:
+            return toks, {}
+        return toks, dict(self._note_routing(rec.counts),
+                          context_tokens=rec.context_tokens)
+
+    def _emit(self, rec: _InFlight, toks):
+        """Hand a read step's tokens to their requests."""
+        for slot, act, last in rec.entries:
+            if self._slots[slot] is not act:
+                # completed on an EOS after this step was dispatched:
+                # the row was wasted, its token is no one's
+                continue
+            tok = int(toks[slot])
+            act.req.tokens.append(tok)
+            act.req.token_times.append(time.perf_counter())
+            self._tokens_total += 1
+            self._tokens_counter.inc()
+            if last or tok == self.eos_id:
+                self._complete(slot)
+
+    def _settle(self, reason: str) -> bool:
+        """Read and emit the step in flight, outside the pipelined loop:
+        before anything that needs the host and the chip to agree
+        (``reason`` one of ``SETTLE_REASONS``).  An event, not a
+        ``serve.decode_step`` span: that step has its span already.
+        False where nothing was in flight."""
+        rec = self._inflight
+        if rec is None:
+            return False
+        self._inflight = None
+        tracer = obs.get_tracer()  # not always inside a pump
+        toks, routed = self._read(rec)
+        tracer.event(spans.EVENT_SETTLE, reason=reason, **routed)
+        with tracer.span(spans.SPAN_STEP_EMIT, step=self._steps):
+            self._emit(rec, toks)
+        self._settles[reason] += 1
+        self._settle_counter.labels(reason=reason).inc()
+        return True
+
     # ---------------------------------------------------------- driving
     def pump(self, wait_s: float = 0.0) -> bool:
-        """One admission + decode cycle; True while there is work."""
+        """One cycle: admit, dispatch the next decode step, read and
+        emit the one before it.  True while there is work, a step in
+        flight included: a request's last token is emitted by the cycle
+        AFTER the one that dispatched it (drive with
+        :meth:`run_until_idle`, not with a counted number of pumps)."""
         with self._lock:
             # one look at the configuration a cycle; its spans share it
             self._tracer = obs.get_tracer()
             self._admit(wait_s=wait_s if not self.active_count() else 0.0)
             stepped = self._step()
-            return stepped or bool(self._stash) \
-                or self.queue.depth() > 0
+            return stepped or self._inflight is not None \
+                or bool(self._stash) or self.queue.depth() > 0
 
     def run_until_idle(self, timeout_s: float = 60.0):
         """Drive synchronously until queue + slots drain (tests/smokes)."""
@@ -788,6 +915,8 @@ class LMEngine:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        with self._lock:
+            self._settle("close")
         self.queue.close()
 
     # ------------------------------------------------------------- stats
@@ -810,6 +939,8 @@ class LMEngine:
             "requests": len(self.completed),
             "tokens": self._tokens_total,
             "steps": self._steps,
+            "steps_ahead": self._steps_ahead,
+            "settles": dict(self._settles),
             "busy_s": busy,
             "tokens_per_s": (self._tokens_total / busy
                              if busy else None),

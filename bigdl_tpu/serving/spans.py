@@ -36,17 +36,27 @@ Three families:
                           growth, preemption, host arrays, page tables
                           to the device, the key split (``bucket=``,
                           ``active=``)
-  ``serve.decode_step``   dispatch of the jitted step to its tokens on
-                          the host (``bucket=``, ``active=``; no
-                          ``step``: older than the others, and read as
-                          it is by the benchmark)
+  ``serve.decode_step``   one a ``jit_step`` execution: the dispatch of
+                          step k, then the wait for step k-1's tokens
+                          (one step is in flight: engine.py), so its
+                          length is about the step period less the
+                          host's own work (``bucket=``, ``active=`` of
+                          step k; ``ahead=`` 1 where step k-1 was still
+                          unread at the dispatch, 0 for the first step
+                          after an idle engine or a settle; no ``step``:
+                          older than the others, and read as it is by
+                          the benchmark)
   ``serve.emit``          after the read-back: token bookkeeping and
-                          stamps, ``_complete``, gauges
+                          stamps, ``_complete``, gauges; one more, with
+                          no ``serve.decode_step`` before it, where a
+                          step in flight is settled (``serve.settle``)
   ======================  ==============================================
   An **expert model**'s ``serve.decode_step`` and ``serve.prefill``
-  also carry what the step routed, summed over its expert layers
+  also carry what a step routed, summed over its expert layers
   (``nn/experts.py`` ``COUNT_NAMES``; the counts ride back from the
-  device with the tokens, in the same transfer), listed here once:
+  device with the tokens, in the same transfer, so a
+  ``serve.decode_step`` carries those of the step it READ, k-1, and a
+  settled step's go on its ``serve.settle`` event), listed here once:
 
   ====================  ================================================
   ``moe_held``          assignments to experts this chip holds
@@ -59,7 +69,8 @@ Three families:
   ``moe_max_load``      the largest load of a held expert, a layer
   ``context_tokens``    ``serve.decode_step`` only: the sum of the
                         active slots' contexts, the step's own token
-                        included (the cache rows attention has to read)
+                        included (the cache rows attention has to
+                        read), of the same step as the counts
   ====================  ================================================
 
   The registry has the same counts as
@@ -95,10 +106,10 @@ HOP_ORDER = ("queue", "placement", "retry", "prefill", "decode",
              "preempt", "handoff", "route")
 
 # ----------------------------------------------------------- live phases
-#: one live batched decode step (dispatch -> resolved next tokens) —
-#: stamped by Engine._step as a REAL tracer span (not a retroactive
-#: reqtrace hop) so the continuous profiler (obs/prof.py) attributes
-#: decode-time samples to it
+#: one live batched decode step (its dispatch -> the previous step's
+#: tokens on the host) — stamped by Engine._step as a REAL tracer span
+#: (not a retroactive reqtrace hop) so the continuous profiler
+#: (obs/prof.py) attributes decode-time samples to it
 SPAN_STEP_DECODE = "serve.decode_step"
 #: placing queued requests into free slots (contains SPAN_STEP_PREFILL)
 SPAN_ADMISSION = "serve.admission"
@@ -116,6 +127,9 @@ EVENT_ADMIT = "serve.admit"
 EVENT_PREEMPT = "serve.preempt"
 #: one chaos-scenario verdict (sim/serve.py)
 EVENT_SCENARIO = "serve.scenario"
+#: a step in flight was read and emitted outside the pipelined loop
+#: (``reason=`` preempt | swap | idle | close)
+EVENT_SETTLE = "serve.settle"
 #: a live weight hot-swap completed (pointer flip between decode steps)
 EVENT_WEIGHT_SWAP = "serve.weight_swap"
 #: the rollout watcher refused a published checkpoint (verify failed)
@@ -134,5 +148,6 @@ __all__ = ["SPAN_ROUTE", "SPAN_PLACEMENT", "SPAN_RETRY", "SPAN_HANDOFF",
            "SPAN_QUEUE", "SPAN_PREFILL", "SPAN_PREEMPT", "SPAN_DECODE",
            "SPAN_STEP_DECODE", "SPAN_ADMISSION", "SPAN_STEP_PREFILL",
            "SPAN_STEP_PREP", "SPAN_STEP_EMIT", "HOP_ORDER", "EVENT_ADMIT",
-           "EVENT_PREEMPT", "EVENT_SCENARIO", "EVENT_WEIGHT_SWAP",
+           "EVENT_PREEMPT", "EVENT_SCENARIO", "EVENT_SETTLE",
+           "EVENT_WEIGHT_SWAP",
            "EVENT_ROLLOUT_REJECT", "EVENT_ROLLOUT_DECISION", "hop_key"]

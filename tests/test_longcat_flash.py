@@ -490,27 +490,36 @@ def test_spans_carry_the_routing_counts_and_the_registry_counts_them(
         with open(tracer.jsonl_path, encoding="utf-8") as fh:
             recs = [json.loads(line) for line in fh]
         spans = [r for r in recs if r["kind"] == "span"]
-        steps = [s for s in spans if s["name"] == S.SPAN_STEP_DECODE]
+        steps = sorted((s for s in spans
+                        if s["name"] == S.SPAN_STEP_DECODE),
+                       key=lambda s: s["wall_time"])
         prefills = [s for s in spans if s["name"] == S.SPAN_STEP_PREFILL]
+        settles = [r for r in recs if r["kind"] == "event"
+                   and r["name"] == S.EVENT_SETTLE]
         assert len(steps) == eng.stats()["steps"] and len(prefills) == 2
+        # one step is in flight: a step's counts come back with its
+        # tokens, so they ride on the NEXT step's span (the one that
+        # read them) and the last step's on the settle's event
+        assert "moe_held" not in steps[0]["attrs"]
+        assert [e["attrs"]["reason"] for e in settles] == ["idle"]
+        routed = [(s["attrs"], t["attrs"]["active"])
+                  for s, t in zip(steps[1:] + settles, steps)]
+        routed += [(s["attrs"], s["attrs"]["prompt_len"])
+                   for s in prefills]
         total = {k: 0 for k in ("held", "zero", "absent")}
-        for s in steps + prefills:
-            a = s["attrs"]
+        for a, tokens in routed:
             assert {"moe_held", "moe_zero", "moe_absent", "moe_hit",
                     "moe_max_load"} <= set(a)
-            tokens = a["active"] if s["name"] == S.SPAN_STEP_DECODE \
-                else a["prompt_len"]
             assert a["moe_held"] + a["moe_zero"] + a["moe_absent"] == \
                 4 * 2 * tokens
             assert a["moe_hit"] <= 2 * 8
             assert a["moe_max_load"] <= tokens
             for k in total:
                 total[k] += a[f"moe_{k}"]
-        for s in steps:
-            # the rows of context the step had to read, its own included
-            assert s["attrs"]["context_tokens"] >= s["attrs"]["active"]
-        first = min(steps, key=lambda s: s["wall_time"])
-        assert first["attrs"]["context_tokens"] == (5 + 1) + (6 + 1)
+        for (a, tokens) in routed[:len(steps)]:
+            # the rows of context that step had to read, its own included
+            assert a["context_tokens"] >= tokens
+        assert steps[1]["attrs"]["context_tokens"] == (5 + 1) + (6 + 1)
         reg = obs.get_registry()
         fam = reg.counter(names.SERVE_MOE_ASSIGNMENTS_TOTAL, "",
                           labels=("kind",))
@@ -528,9 +537,10 @@ def test_step_programs_carry_the_new_scopes():
                    num_pages=20)
     tables, lengths = eng.cache.device_tables(pages=2)
     z = jnp.zeros((2,), jnp.int32)
+    no = jnp.zeros((2,), bool)
     step = eng._step_fn.lower(
-        eng.params, eng.cache.kp, tables, lengths, z,
-        jnp.zeros((2,), jnp.float32), jnp.zeros((2,), bool),
+        eng.params, eng.cache.kp, tables, lengths, z, z, no,
+        jnp.zeros((2,), jnp.float32), no,
         jax.random.key(0)).as_text(debug_info=True)
     pre = eng._prefill_fn(8).lower(
         eng.params, eng.cache.kp, jnp.zeros((1, 8), jnp.int32), 5,

@@ -296,6 +296,275 @@ class TestTPDecode:
             LMEngine(lm_model, tp=3)
 
 
+# ------------------------------------------------ one step in flight
+def _until_eos(prompt, ref, eos):
+    """``generate()``'s output cut after the first ``eos`` it emits."""
+    gen = ref[len(prompt):]
+    if eos in gen:
+        gen = gen[:gen.index(eos) + 1]
+    return [int(t) for t in list(prompt) + gen]
+
+
+def _drive_step_by_step(eng):
+    """The engine with NO step in flight: every step's tokens are read
+    before the next is dispatched (what the loop was before)."""
+    while eng.pump():
+        eng._settle("idle")
+    assert eng.stats()["steps_ahead"] == 0
+
+
+class TestOneStepInFlight:
+    # (prompt length, new tokens); 1 and 2 new tokens end at the
+    # prefill and at the first step
+    MIX = ((5, 10), (9, 3), (4, 7), (7, 1), (6, 2), (3, 12), (8, 5))
+
+    def _mixed(self, eng):
+        """MIX through ``eng``, most of it submitted while a step is in
+        flight; returns [(prompt, new, request)]."""
+        rs = np.random.RandomState(11)
+        todo = [(rs.randint(0, 48, (n,)), new) for n, new in self.MIX]
+        sent = [(p, new, eng.submit(p, new)) for p, new in todo[:2]]
+        eng.pump()
+        for p, new in todo[2:]:
+            eng.pump()
+            assert eng._inflight is not None  # admitted behind a step
+            sent.append((p, new, eng.submit(p, new)))
+        eng.run_until_idle(120)
+        assert eng._inflight is None and eng.active_count() == 0
+        assert eng.cache.pages_in_use() == 0
+        return sent
+
+    @pytest.mark.parametrize("kind", ["float", "tp2", "int8"])
+    def test_pipelined_greedy_is_token_for_token_the_reference(
+            self, lm_model, lm_params, kind):
+        """Tokens never visit the host on their way into the next step
+        and are emitted one step late; none is lost, reordered or
+        changed.  The reference is generate() (float, tp), and for int8
+        the same engine driven with no step in flight."""
+        from bigdl_tpu.serving import LMEngine
+
+        kw = {"float": {}, "tp2": {"tp": 2}, "int8": {"int8": True}}[kind]
+        eng = LMEngine(lm_model, max_batch=3, page_size=4, **kw)
+        sent = self._mixed(eng)
+        st = eng.stats()
+        eng.close()
+        # the loop engaged: all but the first step of a busy stretch
+        assert st["steps_ahead"] >= st["steps"] - 2 > 0
+        assert st["tokens"] == sum(new for _, new in self.MIX)
+        if kind == "int8":
+            ref = LMEngine(lm_model, max_batch=3, page_size=4, int8=True)
+            want = [ref.submit(p, new) for p, new, _ in sent]
+            _drive_step_by_step(ref)
+            ref.close()
+            for (p, new, req), w in zip(sent, want):
+                assert w.done and list(req.tokens) == list(w.tokens)
+                assert len(req.tokens) == new
+            return
+        for p, new, req in sent:
+            assert req.error is None
+            assert _out(p, req) == _ref(lm_model, lm_params, p, new)
+
+    def test_eos_mid_stream_wastes_a_row_never_a_token(self, lm_model,
+                                                       lm_params):
+        """An EOS is learnt one step late: the slot is in the step
+        already dispatched, whose token for it is dropped.  The slot
+        and its pages are reused at once and decode bit-equal."""
+        from bigdl_tpu.serving import LMEngine
+
+        rs = np.random.RandomState(11)
+        pa, pb, pc = (rs.randint(0, 48, (n,)) for n in (5, 9, 4))
+        ref_b = _ref(lm_model, lm_params, pb, 14)
+        eos = int(ref_b[len(pb) + 3])        # b's fourth token
+        want = {k: _until_eos(p, _ref(lm_model, lm_params, p, 14), eos)
+                for k, p in (("a", pa), ("b", pb), ("c", pc))}
+        assert len(want["b"]) == len(pb) + 4
+        assert len(want["a"]) == len(pa) + 14    # a never emits it
+        eng = LMEngine(lm_model, max_batch=2, page_size=4, eos_id=eos)
+        a, b = eng.submit(pa, 14), eng.submit(pb, 14)
+        pages_b = None
+        while not b.done:
+            eng.pump()
+            pages_b = eng.cache.slot_pages(1) or pages_b
+        assert not a.done and eng._inflight is not None
+        # the step in flight still carries b's slot: its row is wasted
+        assert [slot for slot, _, _ in eng._inflight.entries] == [0, 1]
+        c = eng.submit(pc, 14)
+        eng.pump()
+        assert eng._slots[1] is not None and eng._slots[1].req is c
+        assert set(eng.cache.slot_pages(1)) & set(pages_b)
+        eng.run_until_idle(60)
+        assert eng.cache.pages_in_use() == 0
+        eng.close()
+        for k, p, req in (("a", pa, a), ("b", pb, b), ("c", pc, c)):
+            assert _out(p, req) == want[k], k   # nothing after the EOS
+        assert b.tokens[-1] == eos and eos not in b.tokens[:-1]
+
+    def test_preemption_settles_the_step_in_flight_first(self, lm_model,
+                                                         lm_params):
+        """The fold of a preempted request's tokens into its prompt
+        needs every dispatched token on the host."""
+        from bigdl_tpu.serving import LMEngine
+
+        rs = np.random.RandomState(2)
+        p1, p2 = rs.randint(0, 48, (5,)), rs.randint(0, 48, (9,))
+        eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=8)
+        prompts = {}
+        folds = []
+        preempt = eng._preempt_youngest
+
+        def watched():
+            slot = preempt()
+            assert eng._inflight is None
+            if slot is not None:
+                req = eng._stash[0]
+                folds.append((list(req.payload),
+                              prompts[req.id] + list(req.tokens),
+                              req.max_new_tokens + len(req.tokens)))
+            return slot
+
+        eng._preempt_youngest = watched
+        counted = eng.stats()["preemptions"]   # the registry's, so far
+        a, b = eng.submit(p1, 12), eng.submit(p2, 12)
+        prompts.update({a.id: list(p1), b.id: list(p2)})
+        eng.run_until_idle(120)
+        st = eng.stats()
+        eng.close()
+        assert folds and st["preemptions"] - counted == len(folds)
+        assert st["settles"]["preempt"] >= 1
+        for payload, prompt_and_tokens, total in folds:
+            assert payload == prompt_and_tokens and total == 12
+        assert _out(p1, a) == _ref(lm_model, lm_params, p1, 12)
+        assert _out(p2, b) == _ref(lm_model, lm_params, p2, 12)
+
+    def test_swap_weights_settles_first(self, lm_model, lm_params):
+        """The step in flight ran on the old weights: its tokens are
+        emitted before the flip, and the first step on the new weights
+        is dispatched with nothing pending."""
+        import jax
+
+        from bigdl_tpu.serving import LMEngine
+
+        new_params = jax.tree.map(lambda a: a * 1.5, lm_params)
+        prompt = [3, 7, 11, 2, 9]
+        eng = LMEngine(lm_model, max_batch=2, page_size=4)
+        req = eng.submit(prompt, 12)
+        for _ in range(4):
+            eng.pump()
+        before = len(req.tokens)
+        assert eng._inflight is not None
+        eng.swap_weights(new_params, version="v1")
+        assert eng._inflight is None
+        assert eng.stats()["settles"]["swap"] == 1
+        old = _ref(lm_model, lm_params, prompt, 12)
+        emitted = len(req.tokens)
+        assert emitted == before + 1
+        assert _out(prompt, req) == old[:len(prompt) + emitted]
+        late = eng.submit(prompt, 6)
+        eng.run_until_idle(60)
+        eng.close()
+        assert len(req.tokens) == 12 and req.error is None
+        assert _out(prompt, late) == _ref(lm_model, new_params, prompt, 6)
+
+    def test_sampling_with_a_fixed_seed_repeats(self, lm_model):
+        """Temperature > 0: the sampled token feeds the next step on the
+        device, and the key is split in dispatch order, so a seed fixes
+        the run."""
+        from bigdl_tpu.serving import LMEngine
+
+        runs = []
+        for _ in range(2):
+            eng = LMEngine(lm_model, max_batch=2, page_size=4, seed=7)
+            reqs = [eng.submit(p, 9, temperature=0.9)
+                    for p in ([3, 7, 11], [5, 1, 4, 8, 2], [9, 9])]
+            eng.run_until_idle(60)
+            eng.close()
+            runs.append([list(r.tokens) for r in reqs])
+            assert all(len(t) == 9 for t in runs[-1])
+        assert runs[0] == runs[1]
+        greedy = LMEngine(lm_model, max_batch=2, page_size=4, seed=7)
+        g = greedy.submit([3, 7, 11], 9)
+        greedy.run_until_idle(60)
+        greedy.close()
+        assert runs[0][0] != list(g.tokens)   # it did sample
+
+    def test_step_k_plus_1_is_dispatched_before_step_k_is_read(
+            self, lm_model, tmp_path, monkeypatch):
+        """The order, without a clock: a stub step whose tokens record
+        when the host first reads them."""
+        import json
+
+        from bigdl_tpu import obs
+        from bigdl_tpu.obs import names
+        from bigdl_tpu.serving import LMEngine, spans as S
+
+        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path / "trace"))
+        obs.reset()
+        try:
+            log = []
+
+            class Tokens:
+                def __init__(self, k):
+                    self.k = k
+
+                def copy_to_host_async(self):
+                    pass
+
+                def __array__(self, dtype=None, copy=None):
+                    log.append(("read", self.k))
+                    return np.full((2,), 1 + self.k % 40, np.int32)
+
+            def stub(params, kp, vp, tables, lengths, prev, *rest):
+                k = sum(1 for what, _ in log if what == "dispatch")
+                if k:   # the last step's tokens, not a host copy
+                    assert isinstance(prev, Tokens) and prev.k == k - 1
+                log.append(("dispatch", k))
+                return kp, vp, Tokens(k)
+
+            eng = LMEngine(lm_model, max_batch=2, page_size=4)
+            eng._step_fn = stub
+            reqs = [eng.submit([1, 2, 3], 7), eng.submit([4, 5], 7)]
+            eng.run_until_idle(60)
+            st = eng.stats()
+            eng.close()
+            assert st["steps"] == 6
+            at = {ev: i for i, ev in enumerate(log)}
+            for k in range(5):
+                assert at[("dispatch", k + 1)] < at[("read", k)]
+            assert sum(1 for what, _ in log if what == "read") == 6
+            for r in reqs:      # step k's token, in order, none lost
+                assert r.tokens[1:] == [1 + k for k in range(6)]
+            assert st["steps_ahead"] == 5
+            assert st["settles"] == {"preempt": 0, "swap": 0, "idle": 1,
+                                     "close": 0}
+            reg = obs.get_registry()
+            assert reg.counter(
+                names.SERVE_STEPS_AHEAD_TOTAL)._solo().value == 5
+            assert reg.counter(names.SERVE_SETTLES_TOTAL, "", labels=(
+                "reason",)).labels(reason="idle").value == 1
+            tracer = obs.get_tracer()
+            tracer.flush()
+            with open(tracer.jsonl_path, encoding="utf-8") as fh:
+                recs = [json.loads(line) for line in fh]
+            steps = sorted((r for r in recs if r["kind"] == "span"
+                            and r["name"] == S.SPAN_STEP_DECODE),
+                           key=lambda r: r["wall_time"])
+            assert [s["attrs"]["ahead"] for s in steps] == [0] + [1] * 5
+        finally:
+            obs.reset()
+
+    def test_close_settles_what_a_lone_pump_left_in_flight(self, lm_model):
+        from bigdl_tpu.serving import LMEngine
+
+        eng = LMEngine(lm_model, max_batch=2, page_size=4)
+        req = eng.submit([1, 2, 3], 5)
+        assert eng.pump()              # prefill, and step 0 dispatched
+        assert len(req.tokens) == 1 and eng._inflight is not None
+        assert eng.stats()["steps"] == 1
+        eng.close()
+        assert len(req.tokens) == 2 and eng._inflight is None
+        assert eng.stats()["settles"]["close"] == 1
+
+
 # ----------------------------------------------- the used-page bucket
 class TestDecodeBucket:
     def test_bucket_slices_tables_and_gauges_publish(self, lm_model):

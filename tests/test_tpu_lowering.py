@@ -199,7 +199,8 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
     key = like(jax.random.key(0))
     programs = {"step": eng._step_fn.lower(
         weights, kp, vp, spec((b, 32), jnp.int32), spec((b,), jnp.int32),
-        spec((b,), jnp.int32), spec((b,), jnp.float32),
+        spec((b,), jnp.int32), spec((b,), jnp.int32),
+        spec((b,), jnp.bool_), spec((b,), jnp.float32),
         spec((b,), jnp.bool_), key)}
     for bucket in (128, 256):
         programs[f"prefill{bucket}"] = eng._prefill_fn(bucket).lower(
@@ -294,7 +295,8 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     programs = {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, spec((b, 128), jnp.int32), spec((b,), jnp.int32),
-            spec((b,), jnp.int32), spec((b,), jnp.float32),
+            spec((b,), jnp.int32), spec((b,), jnp.int32),
+            spec((b,), jnp.bool_), spec((b,), jnp.float32),
             spec((b,), jnp.bool_), key),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, spec((1, 256), jnp.int32), spec((), jnp.int32),

@@ -49,11 +49,14 @@ def traced(tmp_path, monkeypatch):
     obs.reset()
 
 
-def _spans(tracer):
+def _records(tracer):
     tracer.flush()
     with open(tracer.jsonl_path, encoding="utf-8") as fh:
-        recs = [json.loads(line) for line in fh]
-    return [r for r in recs if r["kind"] == "span"]
+        return [json.loads(line) for line in fh]
+
+
+def _spans(tracer):
+    return [r for r in _records(tracer) if r["kind"] == "span"]
 
 
 def _named(spans, name):
@@ -357,9 +360,16 @@ class TestEngineSpans:
         preps = _named(live, S.SPAN_STEP_PREP)
         assert [s["attrs"]["step"] for s in preps] == \
             list(range(eng.stats()["steps"]))
-        # prep -> decode_step -> emit, one after the other, one step
+        # prep -> decode_step -> emit, one after the other, one step;
+        # an emit with no decode_step before it is a settled step's
         order = [s for s in live if s["name"] in (
             S.SPAN_STEP_PREP, S.SPAN_STEP_DECODE, S.SPAN_STEP_EMIT)]
+        settled = [k for k, s in enumerate(order)
+                   if s["name"] == S.SPAN_STEP_EMIT
+                   and order[k - 1]["name"] != S.SPAN_STEP_DECODE]
+        assert len(settled) == sum(eng.stats()["settles"].values()) >= 1
+        order = [s for k, s in enumerate(order) if k not in settled]
+        assert len(order) == 3 * eng.stats()["steps"]
         for k in range(0, len(order), 3):
             prep, dec, emit = order[k:k + 3]
             assert (prep["name"], dec["name"], emit["name"]) == (
@@ -392,12 +402,51 @@ class TestEngineSpans:
             assert a["wall_time"] + a["dur_s"] <= \
                 prep_start[a["attrs"]["step"]] + 1e-6
         # the point event of one request entering a slot is still there
-        tr = obs.get_tracer()
-        tr.flush()
-        with open(tr.jsonl_path, encoding="utf-8") as fh:
-            admits = [json.loads(ln) for ln in fh]
+        admits = _records(traced)
         assert len([r for r in admits if r["kind"] == "event"
                     and r["name"] == S.EVENT_ADMIT]) == len(PROMPTS)
+
+    def test_one_decode_step_span_per_executed_step(self, traced,
+                                                    lm_model):
+        """The benchmark's readers divide by and average over the
+        ``serve.decode_step`` spans: one per execution of the step
+        program, with that step's bucket, however the step's tokens
+        came to be read (by the next step, or by a settle)."""
+        from bigdl_tpu.serving import LMEngine, spans as S
+
+        eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=8)
+        executed = []
+        fn = eng._step_fn
+
+        def counted(params, kp, vp, tables, *rest):
+            executed.append(int(tables.shape[1]))
+            return fn(params, kp, vp, tables, *rest)
+
+        eng._step_fn = counted
+        # two busy stretches (an idle settle between them) and a pool
+        # small enough to preempt
+        reqs = [eng.submit(p, 9) for p in PROMPTS[:2]]
+        eng.run_until_idle()
+        reqs += [eng.submit(p, n) for p, n in zip(PROMPTS, (14, 2, 14))]
+        eng.run_until_idle()
+        st = eng.stats()
+        eng.close()
+        assert all(r.done and r.error is None for r in reqs)
+        assert st["preemptions"] >= 1 and st["settles"]["idle"] >= 2
+        recs = _records(traced)
+        steps = sorted((r for r in recs if r["kind"] == "span"
+                        and r["name"] == S.SPAN_STEP_DECODE),
+                       key=lambda r: r["wall_time"])
+        assert len(steps) == len(executed) == st["steps"]
+        assert [s["attrs"]["bucket"] for s in steps] == executed
+        assert all(1 <= s["attrs"]["active"] <= 2 for s in steps)
+        # ahead is 0 exactly on the first step after a settle
+        settles = [r for r in recs if r["kind"] == "event"
+                   and r["name"] == S.EVENT_SETTLE]
+        assert len(settles) == sum(st["settles"].values())
+        assert sum(s["attrs"]["ahead"] for s in steps) == \
+            st["steps_ahead"] == len(steps) - len(settles)
+        assert steps[0]["attrs"]["ahead"] == 0
 
     def test_tokens_are_stamped_and_stats_report_itl(self, lm_model):
         eng, reqs = _serve(lm_model, PROMPTS, 6)
@@ -450,9 +499,10 @@ class TestEngineSpans:
         eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33)
         tables, lengths = eng.cache.device_tables()
         z = jnp.zeros((2,), jnp.int32)
+        no = jnp.zeros((2,), bool)
         step_txt = eng._step_fn.lower(
             eng.params, eng.cache.kp, eng.cache.vp, tables, lengths, z,
-            jnp.zeros((2,), jnp.float32), jnp.zeros((2,), bool),
+            z, no, jnp.zeros((2,), jnp.float32), no,
             jax.random.key(0)).as_text(debug_info=True)
         pre_txt = eng._prefill_fn(8).lower(
             eng.params, eng.cache.kp, eng.cache.vp,
