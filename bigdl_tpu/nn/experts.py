@@ -31,6 +31,19 @@ and are added back to their tokens with their weights; the identity
 experts are one weighted add.  There is no capacity: the row buffer
 holds every assignment, so no token is dropped at any imbalance.
 
+**Another router, and a shared expert** (``models/joyai_flash.py``;
+the arguments' defaults are the layer above, bit for bit):
+``score="sigmoid"`` takes ``s = sigmoid(W_r x)`` in place of the
+softmax; ``renormalise=True`` divides a token's ``top_k`` weights by
+their sum (+ 1e-20) before ``scale``, over ALL of them, held or absent,
+so the shares still add up to the uncut layer; ``shared_hidden=n`` adds
+``S(x)``, a gated SiLU MLP of that width with weights ``s_gate`` /
+``s_up`` / ``s_down`` that every token passes through.  Like a
+zero-compute expert it is computed where the token lives, whole, so the
+sum over all shares counts it once (a deployment adds the shares'
+routed parts to one ``S(x)``).  It is a dense MLP and runs under the
+scope ``ffn``, not under ``moe.*``.
+
 It also counts what it routed (``counts``): assignments to held,
 zero-compute and absent experts, how many held experts got a token, and
 the largest load of a held expert — the work of a step varies with the
@@ -65,8 +78,11 @@ class DroplessExperts(AbstractModule):
 
     def __init__(self, dim: int, hidden: int, n_routed: int, n_zero: int,
                  top_k: int, scale: float = 1.0, held=None,
-                 init: bool = True):
+                 score: str = "softmax", renormalise: bool = False,
+                 shared_hidden: int = 0, init: bool = True):
         super().__init__()
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {score!r}: softmax or sigmoid")
         lo, hi = (0, n_routed) if held is None else (int(held[0]),
                                                       int(held[1]))
         if not 0 <= lo < hi <= n_routed:
@@ -77,11 +93,18 @@ class DroplessExperts(AbstractModule):
                              "experts")
         self._config = dict(dim=dim, hidden=hidden, n_routed=n_routed,
                             n_zero=n_zero, top_k=top_k, scale=scale,
-                            held=(lo, hi))
+                            held=(lo, hi), score=score,
+                            renormalise=renormalise,
+                            shared_hidden=shared_hidden)
         self.dim, self.hidden = dim, hidden
         self.n_routed, self.n_zero = n_routed, n_zero
         self.top_k, self.scale = top_k, float(scale)
         self.lo, self.hi = lo, hi
+        self.score, self.renormalise = score, bool(renormalise)
+        self.shared_hidden = int(shared_hidden)
+        if self.shared_hidden:
+            self.param_names = type(self).param_names + (
+                "s_gate", "s_up", "s_down")
         for n in self.param_names:
             setattr(self, n, None)
         if init:
@@ -103,23 +126,32 @@ class DroplessExperts(AbstractModule):
         self.w_gate = _draw((g, self.dim, self.hidden))
         self.w_up = _draw((g, self.dim, self.hidden))
         self.w_down = _draw((g, self.hidden, self.dim))
+        if self.shared_hidden:
+            # (out, in), like a dense gated MLP
+            self.s_gate = _draw((self.shared_hidden, self.dim))
+            self.s_up = _draw((self.shared_hidden, self.dim))
+            self.s_down = _draw((self.dim, self.shared_hidden))
         return self
 
     # ------------------------------------------------------------ parts
     def route(self, params, x):
         """``x`` (N, dim) -> chosen expert ids (N, top_k) and their
-        weights ``scale * s_e`` (N, top_k), float32."""
+        weights ``scale * s_e`` (N, top_k), float32 (``s_e`` over the
+        sum of the chosen where the layer renormalises)."""
         import jax
         import jax.numpy as jnp
 
         logits = jnp.matmul(x.astype(jnp.float32),
                             params["router"].astype(jnp.float32).T,
                             precision="highest")
-        s = jax.nn.softmax(logits, axis=-1)
+        s = jax.nn.softmax(logits, axis=-1) if self.score == "softmax" \
+            else jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(s + params["bias"].astype(jnp.float32),
                                self.top_k)
-        w = self.scale * jnp.take_along_axis(s, idx, axis=-1)
-        return idx, w
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if self.renormalise:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx, self.scale * w
 
     def apply(self, params, state, input, *, mask=None, training=False,
               rng=None):
@@ -162,9 +194,16 @@ class DroplessExperts(AbstractModule):
             # product left there is dropped, not scaled
             ys = jnp.where(valid[:, None], ys * w_sorted[:, None], 0.0)
             y = jnp.zeros((n, self.dim), jnp.float32).at[token].add(ys)
-        with jax.named_scope("moe.zero"):
-            w_zero = jnp.sum(jnp.where(is_zero, w, 0.0), axis=-1)
-            y = y + x.astype(jnp.float32) * w_zero[:, None]
+        if self.n_zero:
+            with jax.named_scope("moe.zero"):
+                w_zero = jnp.sum(jnp.where(is_zero, w, 0.0), axis=-1)
+                y = y + x.astype(jnp.float32) * w_zero[:, None]
+        if self.shared_hidden:
+            from bigdl_tpu.nn.latent import gated_mlp
+
+            with jax.named_scope("ffn"):
+                y = y + gated_mlp(x, params["s_gate"], params["s_up"],
+                                  params["s_down"]).astype(jnp.float32)
         return (y.astype(x.dtype), counts), state
 
     def __repr__(self):

@@ -19,7 +19,10 @@ whole model's equations):
     scores q_h . k_h / sqrt(nope + rope), causal softmax in float32
     y = W_o concat_h(sum_s p_hs v_hs)
 
-with ``s_q = sqrt(dim / q_rank)`` and ``s_kv = sqrt(dim / kv_rank)``.
+with ``s_q = sqrt(dim / q_rank)`` and ``s_kv = sqrt(dim / kv_rank)``
+(LongCat-Flash's ``mla_scale_*``) unless the constructor is given
+``q_scale`` / ``kv_scale``: a model without such factors
+(``models/joyai_flash.py``) gives 1, and nothing is multiplied.
 **The cached row of a token is ``[c | rotated k_rope]``** — ``kv_rank +
 rope`` values for all heads together, and zeros up to a multiple of
 ``row_align`` lanes (a TPU works on a buffer of 640-lane rows as it
@@ -28,7 +31,11 @@ lies; 576-lane rows it copies whole, in and out of every program:
 rebuilds K and V per head from the rows; :meth:`LatentAttention.decode`
 absorbs ``W_kvb`` into the query (``q_nope_h W_k,h`` scores against
 ``c``) and into the output, so a decode step reads the rows where they
-lie in the paged cache and never builds a key or a value.
+lie in the paged cache and never builds a key or a value.  Handed
+``(B, Q, dim)`` it advances ``Q`` consecutive positions a slot in one
+pass (a step that verifies a draft, ``serving/engine.py``): ``Q`` rows
+written, and the ``Q`` queries of a slot ride the head axis, each with
+its own length, over ONE read of the slot's rows.
 """
 
 from __future__ import annotations
@@ -154,12 +161,13 @@ class LatentAttention(AbstractModule):
     def __init__(self, dim: int, n_head: int, q_rank: int, kv_rank: int,
                  nope_dim: int, rope_dim: int, v_dim: int,
                  eps: float = 1e-5, theta: float = 1e4, row_align: int = 1,
-                 init: bool = True):
+                 q_scale=None, kv_scale=None, init: bool = True):
         super().__init__()
         self._config = dict(dim=dim, n_head=n_head, q_rank=q_rank,
                             kv_rank=kv_rank, nope_dim=nope_dim,
                             rope_dim=rope_dim, v_dim=v_dim, eps=eps,
-                            theta=theta, row_align=row_align)
+                            theta=theta, row_align=row_align,
+                            q_scale=q_scale, kv_scale=kv_scale)
         self.dim, self.n_head = dim, n_head
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
@@ -167,8 +175,11 @@ class LatentAttention(AbstractModule):
         #: width of a token's cached row, ``[c | rotated k_rope]`` and
         #: zeros up to a multiple of ``row_align`` lanes
         self.row_width = -(-(kv_rank + rope_dim) // row_align) * row_align
-        self.q_scale = math.sqrt(dim / q_rank)
-        self.kv_scale = math.sqrt(dim / kv_rank)
+        # None: LongCat-Flash's sqrt(dim / rank); 1 multiplies nothing
+        self.q_scale = math.sqrt(dim / q_rank) if q_scale is None \
+            else float(q_scale)
+        self.kv_scale = math.sqrt(dim / kv_rank) if kv_scale is None \
+            else float(kv_scale)
         self.score_scale = 1.0 / math.sqrt(nope_dim + rope_dim)
         for n in self.param_names:
             setattr(self, n, None)
@@ -197,13 +208,15 @@ class LatentAttention(AbstractModule):
         h = self.n_head
         c_q = rms_norm(jnp.matmul(x, params["wq_a"].T), params["q_norm"],
                        self.eps)
-        q = jnp.matmul(c_q, params["wq_b"].T) * jnp.asarray(
-            self.q_scale, x.dtype)
+        q = jnp.matmul(c_q, params["wq_b"].T)
+        if self.q_scale != 1.0:
+            q = q * jnp.asarray(self.q_scale, x.dtype)
         q = q.reshape(*x.shape[:-1], h, self.nope_dim + self.rope_dim)
         q_nope, q_rope = q[..., :self.nope_dim], q[..., self.nope_dim:]
         kv = jnp.matmul(x, params["wkv_a"].T)
         c = rms_norm(kv[..., :self.kv_rank], params["kv_norm"], self.eps)
-        c = c * jnp.asarray(self.kv_scale, x.dtype)
+        if self.kv_scale != 1.0:
+            c = c * jnp.asarray(self.kv_scale, x.dtype)
         q_rope = rotary_interleaved(q_rope, jnp.asarray(positions)[..., None],
                                     self.theta)
         k_rope = rotary_interleaved(kv[..., self.kv_rank:], positions,
@@ -271,31 +284,46 @@ class LatentAttention(AbstractModule):
         ``(cached layers, pages, P, row)`` buffer, at ``layer``) and
         attends over positions ``<= length`` with ``W_kvb`` absorbed:
         the query becomes a row-shaped vector, the mix a ``kv_rank``
-        vector a head.  Returns ``(y (B, dim), pages)``."""
+        vector a head.  Returns ``(y (B, dim), pages)``.
+
+        ``x`` (B, Q, dim) is ``Q`` consecutive tokens a slot, at
+        positions ``lengths + 0 .. Q-1``: ``Q`` rows written, query
+        ``j`` attends positions ``<= length + j`` (its own row and the
+        rows written before it in this call included), ``y`` (B, Q,
+        dim)."""
         import jax
 
         from bigdl_tpu.ops.decode_attention import latent_decode_attention
         from bigdl_tpu.serving.cache import write_token_rows
 
         jnp = _jnp()
-        b = x.shape[0]
+        lead = x.shape[:-1]                   # (B,) or (B, Q)
+        positions = lengths if len(lead) == 1 else \
+            lengths[:, None] + jnp.arange(lead[1], dtype=lengths.dtype)
         with jax.named_scope("mla.proj"):
-            q_nope, q_rope, row = self.project(params, x, lengths)
+            q_nope, q_rope, row = self.project(params, x, positions)
         with jax.named_scope("kv_write"):
             pages = write_token_rows(pages, layer, tables, lengths, row)
         with jax.named_scope("mla.attn"):
             # W_kvb absorbed, on both sides of the rows
             wkv = self._kv_heads(params)
-            q_abs = jnp.einsum("bhd,hdc->bhc", q_nope,
+            q_abs = jnp.einsum("...hd,hdc->...hc", q_nope,
                                wkv[:, :self.nope_dim, :])
+            q_rows = self._row(q_abs, q_rope)
+            q_len = lengths
+            if len(lead) == 2:
+                # the Q queries of a slot beside each other on the head
+                # axis, a length a query: one read of the slot's rows
+                q_rows = q_rows.reshape(lead[0], -1, self.row_width)
+                q_len = jnp.repeat(positions, self.n_head, axis=1)
             o_lat = latent_decode_attention(
-                self._row(q_abs, q_rope), pages, tables, lengths,
-                layer=layer,
+                q_rows, pages, tables, q_len, layer=layer,
                 scale=self.score_scale, value_width=self.kv_rank)
-            o = jnp.einsum("bhc,hdc->bhd", o_lat.astype(x.dtype),
+            o_lat = o_lat.reshape(*lead, self.n_head, self.kv_rank)
+            o = jnp.einsum("...hc,hdc->...hd", o_lat.astype(x.dtype),
                            wkv[:, self.nope_dim:, :])
         with jax.named_scope("mla.proj"):
-            y = jnp.matmul(o.reshape(b, self.n_head * self.v_dim),
+            y = jnp.matmul(o.reshape(*lead, self.n_head * self.v_dim),
                            params["wo"].T)
         return y, pages
 

@@ -412,6 +412,11 @@ SERVE_MOE_LOAD_MAX_OVER_MEAN = _m(
     "bigdl_serve_moe_load_max_over_mean", "gauge", policy="max",
     doc="Largest load of a held expert over the mean load of the held "
         "experts, in the last step that routed to one (1 = even)")
+SERVE_DRAFT_TOKENS_TOTAL = _m(
+    "bigdl_serve_draft_tokens_total", "counter", ("outcome",), 2,
+    "Drafts verified by the decode steps of a model that drafts its own "
+    "next-but-one token, by outcome: accepted (the step yielded two "
+    "tokens) or rejected")
 SERVE_REJECTS_TOTAL = _m(
     "bigdl_serve_rejects_total", "counter",
     doc="Admissions rejected 503 + Retry-After (queue full past the "

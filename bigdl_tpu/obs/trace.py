@@ -31,7 +31,9 @@ the profiler's clock by the offset the live spans give (a span's
 
 **The trainer's span names** (``optim/optimizer.py``, shared by
 ``DistriOptimizer``; ``native.PrefetchIterator``), each with ``step=``;
-the serving engine's are listed in ``serving/spans.py``:
+the serving engine's are listed in ``serving/spans.py`` (with an expert
+model's ``moe_*`` and a drafting model's ``draft_verified`` /
+``draft_accepted`` / ``tokens_emitted`` attributes):
 
 =================  =====  ==================================================
 name               kind   covers

@@ -1,5 +1,6 @@
-"""Decode attention over the paged cache: one query token a slot
-against the rows its page table names — the serving hot path.
+"""Decode attention over the paged cache: one query token a slot (in
+the latent body: or the few consecutive ones of a step that verifies a
+draft) against the rows its page table names — the serving hot path.
 
 Two bodies, one a cache kind (``serving/cache.py`` states the layouts);
 the model calls the body of the cache it states, and nothing chooses
@@ -179,7 +180,11 @@ def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
     ``[q_nope W_k | q_rope]``); pages: one layer's ``(num_pages, P, R)``
     pool or, with ``layer``, the stacked buffer, read where it lies;
     tables / lengths as in :func:`paged_decode_attention` (``pos <=
-    length`` attends, everything else is ``-inf``).  Returns the mix
+    length`` attends, everything else is ``-inf``); ``lengths`` (B, H)
+    gives every query a length of its own: the ``Q`` queries a slot of
+    a step that verifies a draft ride the head axis (``H = Q x heads``)
+    and share the one read of the slot's rows, each attending up to its
+    own position (``nn/latent.py``).  Returns the mix
     over the rows' first ``value_width`` lanes, ``(B, H, value_width)``
     in float32 — the caller applies ``W_v`` and ``W_o``.
 
@@ -203,7 +208,8 @@ def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
     vw = int(value_width)
     bp = _chunk_pages(maxp, block_pages)
     qs = (q.astype(jnp.float32) * scale).astype(pages.dtype)
-    len_b = lengths[:, None, None]
+    len_b = lengths[:, None, None] if lengths.ndim == 1 \
+        else lengths[:, :, None]
 
     def block(tbl_c, c0, m, l, acc):
         rows = gather_pages(pages, tbl_c, layer)       # (B, bp*P, R)

@@ -52,6 +52,10 @@ class ServeRequest:
     tokens: List[int] = dataclasses.field(default_factory=list)
     # perf_counter() where the engine appended each of ``tokens``
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # under a model that drafts its own next-but-one token: for each
+    # draft a step verified, (index in ``tokens`` of the token it was
+    # checked against, the draft)
+    drafts: List[tuple] = dataclasses.field(default_factory=list)
     result: Optional[np.ndarray] = None  # classifier output row(s)
     error: Optional[str] = None
     # request-trace context (obs.reqtrace.RequestTraceContext) when the

@@ -149,10 +149,11 @@ class PagedKVCache:
         self._pages_gauge.set(float(self.pages_in_use()))
         return True
 
-    def needs_growth(self, slot: int) -> bool:
-        """True when the next token's position lands past the slot's
-        allocated pages."""
-        return (int(self.lengths[slot]) // self.page_size
+    def needs_growth(self, slot: int, ahead: int = 0) -> bool:
+        """True when the next token's position (or the one ``ahead``
+        of it: a step that writes more than one row) lands past the
+        slot's allocated pages."""
+        return ((int(self.lengths[slot]) + int(ahead)) // self.page_size
                 >= len(self._slot_pages[slot]))
 
     def release(self, slot: int):
@@ -220,13 +221,24 @@ def write_token_rows(pages, layer: int, tables, lengths, rows):
     n_head * head_dim)``, the projection's output as it comes) lands
     at position ``lengths[b]`` of its table — exactly the one row
     ``pages[layer, page, slot_in_page, :]``; no other byte changes.
-    Inactive slots (length 0, trash table row) write the trash page."""
+    Inactive slots (length 0, trash table row) write the trash page.
+
+    ``rows`` ``(B, Q, row)`` are ``Q`` consecutive tokens a slot, at
+    positions ``lengths[b] + 0 .. Q-1`` (a step that verifies a draft
+    writes two), still one scatter; the engine names the page of the
+    last of them before it dispatches the step."""
     import jax.numpy as jnp
 
     page_size = pages.shape[2]
-    page = jnp.take_along_axis(
-        tables, (lengths // page_size)[:, None], axis=1)[:, 0]
-    return pages.at[layer, page, lengths % page_size, :].set(
+    if rows.ndim == 2:
+        page = jnp.take_along_axis(
+            tables, (lengths // page_size)[:, None], axis=1)[:, 0]
+        pos = lengths
+    else:
+        pos = lengths[:, None] + jnp.arange(rows.shape[1],
+                                            dtype=lengths.dtype)
+        page = jnp.take_along_axis(tables, pos // page_size, axis=1)
+    return pages.at[layer, page, pos % page_size, :].set(
         rows.astype(pages.dtype))
 
 

@@ -37,7 +37,22 @@ that with the production shape:
   whatever needs host and chip to agree (preemption, a weight swap,
   ``close``, an engine with nothing left to run) settles the step in
   flight first.  An EOS is learnt one step late: the slot's row in the
-  step already dispatched is wasted, never emitted.
+  step already dispatched is wasted, never emitted;
+* **a step that yields one or two tokens a slot**: a model that drafts
+  its own next-but-one token (``draft_spec``; ``models/joyai_flash.py``)
+  has each step verify two positions a slot, the certain token and the
+  draft, and emit the second token where the draft was right.  How many
+  tokens a step yielded is known on the device one step after the host
+  has dispatched the next, so the slot's next input token, next draft,
+  length and owed count are device arrays carried from step to step
+  (a freshly admitted slot's come from the host, selected inside the
+  program), the host keeps bounds (a length's lower bound advances by
+  one a dispatch, pages and the bucket are named for the upper bound,
+  a slot runs while it MAY still owe a token) and reconciles when it
+  reads the step's tokens and emitted counts one step late.  A slot
+  that owed nothing on the device in a step already dispatched is a
+  wasted row, as after an EOS.  Same loop, same settles; greedy only
+  (a temperature on such a model is refused at ``submit``).
 
 **What the engine asks of a model** (``models/transformer.py`` and
 ``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
@@ -47,7 +62,16 @@ and ``paged_decode(params, caches, tables, lengths, tokens, active,
 ...)``, each returning ``(caches, logits, counts)`` with ``counts`` the
 step's expert-routing counts or None.  Sampling, buckets, donation, the
 page tables, spans and ``stats()`` are the engine's; the layers'
-internals are the model's.  ``int8=True`` needs the model's
+internals are the model's.  **A model that drafts**
+(``models/joyai_flash.py``) also answers ``draft_spec(params)``
+(``tokens_per_step``: 2) and must choose tokens in the middle of its
+step, so it is handed the engine's ``pick`` (logits ``(N, vocab)`` ->
+tokens ``(N,)``) and returns tokens, not logits:
+``paged_prefill(..., pick=)`` -> ``(caches, first, draft, counts)`` and
+``paged_decode(params, caches, tables, lengths, tokens, drafts, owed,
+active, pick=)`` -> ``(caches, picked (B, 2), accepted (B,), next_draft
+(B,), counts)``.  A model that declares no draft runs the programs it
+always ran.  ``int8=True`` needs the model's
 ``quantize_for_decode``, ``tp > 1`` its ``tp_decode_step``; a model
 without them is refused with a ``ValueError`` that says so.
 
@@ -97,6 +121,17 @@ def sample_step(logits, temps, active, key):
     return nxt
 
 
+def pick_greedy(logits):
+    """What a drafting model's step picks with, ``logits`` (N, vocab)
+    -> (N,): the largest logit (a draft is verified by exact match
+    against it)."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def sample_first(logits, temp, key):
     """A prompt's first token, ``logits`` (1, vocab)."""
     import jax
@@ -113,19 +148,29 @@ def sample_first(logits, temp, key):
 
 class _Active:
     """Host bookkeeping for one occupied slot.  ``remaining`` counts
-    the tokens not yet DISPATCHED (emission lags one step);
-    ``first_token`` is the prefill's token until the slot's first
+    the tokens not yet DISPATCHED (emission lags one step) and ``left``
+    those not yet EMITTED; a dispatched step is taken to yield one
+    token until it is read, so under a drafting model ``remaining`` is
+    an upper bound between a dispatch and its read, and the two agree
+    whenever nothing is in flight.  ``first_token`` (and a drafting
+    model's ``first_draft``) is the prefill's until the slot's first
     decode step has taken it from the host, then None: the slot's
-    input is the previous step's output, on the device."""
+    input is the previous step's output, on the device.  ``unread`` is
+    1 while a step the slot ran in has not been read; ``last_pos`` is
+    the last position the request can ever write a row at."""
 
-    __slots__ = ("req", "remaining", "first_token", "prompt_len",
-                 "t_admit", "order")
+    __slots__ = ("req", "remaining", "left", "first_token", "first_draft",
+                 "prompt_len", "last_pos", "unread", "t_admit", "order")
 
-    def __init__(self, req, remaining, first_token, prompt_len, order):
+    def __init__(self, req, remaining, first_token, prompt_len, order,
+                 first_draft=None):
         self.req = req
-        self.remaining = remaining
+        self.remaining = self.left = remaining
         self.first_token = first_token
+        self.first_draft = first_draft
         self.prompt_len = prompt_len
+        self.last_pos = prompt_len + remaining
+        self.unread = 0
         self.t_admit = time.monotonic()
         self.order = order
 
@@ -133,13 +178,33 @@ class _Active:
 class _InFlight:
     """A dispatched decode step whose tokens the host has not read."""
 
-    __slots__ = ("nxt", "counts", "entries", "context_tokens")
+    __slots__ = ("result", "counts", "entries", "context_tokens")
 
-    def __init__(self, nxt, counts, entries, context_tokens):
-        self.nxt = nxt              # (B,) device array, the step's tokens
+    def __init__(self, result, counts, entries, context_tokens):
+        # (B,) device array, the step's tokens; a drafting model's
+        # (B, len(DRAFT_RESULT)) rows
+        self.result = result
         self.counts = counts        # an expert model's routing counts
-        self.entries = entries      # [(slot, _Active, last)] it ran for
+        self.entries = entries      # [(slot, _Active)] it ran for
         self.context_tokens = context_tokens
+
+
+#: columns of a drafting step's result, a slot: the two tokens picked,
+#: how many of them the step yields (0 where the slot owed nothing), the
+#: draft it verified and the slot's length before the step
+DRAFT_RESULT = ("first", "second", "emitted", "draft", "length")
+
+
+class _StepRead:
+    """A read step on the host: ``tokens`` (B, k), ``emitted`` (B,)
+    tokens each slot yields (None: one each), a drafting model's
+    verified ``drafts`` by slot, and the span's attributes."""
+
+    __slots__ = ("tokens", "emitted", "drafts", "attrs")
+
+    def __init__(self, tokens, emitted, drafts, attrs):
+        self.tokens, self.emitted = tokens, emitted
+        self.drafts, self.attrs = drafts, attrs
 
 
 class LMEngine:
@@ -180,6 +245,13 @@ class LMEngine:
         # the model states its cache; the engine builds and owns it
         spec = model.cache_spec(self.params)
         self._cache_spec = spec
+        # ... and whether it drafts: tokens a step verifies a slot
+        self._tokens_per_step = int(
+            model.draft_spec(self.params)["tokens_per_step"]
+            if hasattr(model, "draft_spec") else 1)
+        if self._tokens_per_step not in (1, 2):
+            raise ValueError("a step verifies one draft a slot at most")
+        self._drafts = self._tokens_per_step > 1
         self.max_len = int(spec["max_len"])
         if cache_dtype is None:
             cache_dtype = spec["dtype"]
@@ -218,7 +290,13 @@ class LMEngine:
         # the step in flight (dispatched, its tokens unread) and the
         # last dispatched step's tokens, the next step's input
         self._inflight: Optional[_InFlight] = None
-        self._prev_nxt = jnp.zeros((self.max_batch,), jnp.int32)
+        # what a step hands the next on the device: its tokens; under a
+        # drafting model each slot's next token, next draft, length and
+        # owed count
+        zeros = jnp.zeros((self.max_batch,), jnp.int32)
+        self._carry = (zeros,) * (4 if self._drafts else 1)
+        self._draft_verified = self._draft_accepted = 0
+        self._slot_steps = self._step_tokens = 0
         self._steps_ahead = 0
         self._settles = dict.fromkeys(SETTLE_REASONS, 0)
         self._weight_bytes = self._decode_weight_bytes()
@@ -282,6 +360,10 @@ class LMEngine:
             "weights + the KV pages the step's page-table bucket "
             "names)")
         self._moe_counter = self._moe_gauge = None  # an expert model's
+        self._draft_counter = reg.counter(
+            names.SERVE_DRAFT_TOKENS_TOTAL,
+            "Drafts a self-drafting model's steps verified, by outcome",
+            labels=("outcome",)) if self._drafts else None
         self._swap_counter = reg.counter(
             names.SERVE_WEIGHT_SWAPS_TOTAL,
             "Live weight hot-swaps completed, by promoted version",
@@ -397,21 +479,53 @@ class LMEngine:
         qparams = self._qparams
         n = len(self.cache.buffers())
 
-        def step(params, *rest):
-            # rest: the cache's buffers (donated), then tables, lengths,
-            # prev (the last step's tokens, still on the device), the
-            # host's tokens and fresh (the slots that take theirs from
-            # the host: admitted since that step), temps, active, key
-            tables, lengths, prev, tokens, fresh, temps, active, key = \
-                rest[n:]
-            tokens = jnp.where(fresh, tokens, prev)
-            caches, logits, counts = model.paged_decode(
-                params, rest[:n], tables, lengths, tokens, active,
-                page_size=page_size, qparams=qparams)
-            nxt = sample_step(logits, temps, active, key)
-            # the routing counts ride back with the tokens
-            return (*caches, nxt) if counts is None \
-                else (*caches, nxt, counts)
+        # one name for both programs: the profiler and the readers of its
+        # trace know the decode step as ``jit_step``
+        if self._drafts:
+            def step(params, *rest):
+                # rest: the cache's buffers (donated), then tables, the
+                # host's lengths, the four arrays the last step carried
+                # (token, draft, length, owed), the host's values of the
+                # four for the slots in fresh (admitted since that step),
+                # active
+                (tables, h_len, c_tok, c_draft, c_len, c_owed,
+                 h_tok, h_draft, h_owed, fresh, active) = rest[n:]
+                tok = jnp.where(fresh, h_tok, c_tok)
+                draft = jnp.where(fresh, h_draft, c_draft)
+                length = jnp.where(fresh, h_len, c_len)
+                owed = jnp.where(fresh, h_owed, c_owed)
+                # the host runs a slot while it MAY owe a token; the count
+                # here is exact, and a slot that owes nothing computes a
+                # wasted row at position 0 of its own pages
+                run = active & (owed > 0)
+                caches, picked, accepted, next_draft, counts = \
+                    model.paged_decode(
+                        params, rest[:n], tables, jnp.where(run, length, 0),
+                        tok, draft, owed, run, pick=pick_greedy,
+                        page_size=page_size, qparams=qparams)
+                emitted = jnp.where(run, 1 + accepted.astype(jnp.int32), 0)
+                nxt = jnp.where(accepted, picked[:, 1], picked[:, 0])
+                result = jnp.stack([picked[:, 0], picked[:, 1], emitted,
+                                    draft, length], axis=1)
+                out = (*caches, nxt, next_draft, length + emitted,
+                       owed - emitted, result)
+                return out if counts is None else (*out, counts)
+        else:
+            def step(params, *rest):
+                # rest: the cache's buffers (donated), then tables, lengths,
+                # prev (the last step's tokens, still on the device), the
+                # host's tokens and fresh (the slots that take theirs from
+                # the host: admitted since that step), temps, active, key
+                tables, lengths, prev, tokens, fresh, temps, active, key = \
+                    rest[n:]
+                tokens = jnp.where(fresh, tokens, prev)
+                caches, logits, counts = model.paged_decode(
+                    params, rest[:n], tables, lengths, tokens, active,
+                    page_size=page_size, qparams=qparams)
+                nxt = sample_step(logits, temps, active, key)
+                # the routing counts ride back with the tokens
+                return (*caches, nxt) if counts is None \
+                    else (*caches, nxt, counts)
 
         return jax.jit(step, donate_argnums=tuple(range(1, 1 + n)))
 
@@ -424,16 +538,27 @@ class LMEngine:
         model = self.model
         n = len(self.cache.buffers())
 
-        def prefill(params, *rest):
-            # rest: the cache's buffers (donated), then the prompt
-            # (1, bucket) zero-padded past t0, t0, the bucket's pages,
-            # the temperature, the key
-            prompt, t0, pages, temp, key = rest[n:]
-            caches, logits, counts = model.paged_prefill(
-                params, rest[:n], prompt, t0, pages)
-            first = sample_first(logits, temp, key)
-            return (*caches, first) if counts is None \
-                else (*caches, first, counts)
+        if self._drafts:
+            def prefill(params, *rest):
+                # as below; greedy, so the temperature and the key are not
+                # read, and the first token comes with the first draft
+                prompt, t0, pages = rest[n:n + 3]
+                caches, first, draft, counts = model.paged_prefill(
+                    params, rest[:n], prompt, t0, pages, pick=pick_greedy)
+                pair = jax.numpy.stack([first, draft])
+                return (*caches, pair) if counts is None \
+                    else (*caches, pair, counts)
+        else:
+            def prefill(params, *rest):
+                # rest: the cache's buffers (donated), then the prompt
+                # (1, bucket) zero-padded past t0, t0, the bucket's pages,
+                # the temperature, the key
+                prompt, t0, pages, temp, key = rest[n:]
+                caches, logits, counts = model.paged_prefill(
+                    params, rest[:n], prompt, t0, pages)
+                first = sample_first(logits, temp, key)
+                return (*caches, first) if counts is None \
+                    else (*caches, first, counts)
 
         fn = jax.jit(prefill, donate_argnums=tuple(range(1, 1 + n)))
         self._prefill_fns[bucket] = fn
@@ -461,6 +586,11 @@ class LMEngine:
                 f"exceeds max_len {self.max_len}")
         if int(max_new_tokens) < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        if self._drafts and float(temperature) > 0.0:
+            raise ValueError(
+                f"{type(self.model).__name__} verifies its own drafts by "
+                "exact match against the greedy token: temperature "
+                f"{temperature:g} is not served (give 0)")
         # feasibility: a request that can NEVER fit the page pool even
         # alone would preempt-loop forever — reject it at the door
         worst = self.cache.pages_for(len(prompt) + int(max_new_tokens))
@@ -554,7 +684,9 @@ class LMEngine:
                 float(req.temperature), sub)
             self.cache.set_buffers(out[:n])
             self.cache.lengths[slot] = t0
-            tok = int(out[n])
+            # a drafting model's first token comes with its first draft
+            tok, draft = (int(t) for t in np.asarray(out[n])) \
+                if self._drafts else (int(out[n]), None)
             if len(out) > n + 1:
                 tracer.add_attrs(span_id, **self._note_routing(out[n + 1]))
         if req.trace is not None:
@@ -572,7 +704,8 @@ class LMEngine:
         if self._t_first_work is None:
             self._t_first_work = time.monotonic()
         self._order += 1
-        act = _Active(req, req.max_new_tokens - 1, tok, t0, self._order)
+        act = _Active(req, req.max_new_tokens - 1, tok, t0, self._order,
+                      first_draft=draft)
         self._slots[slot] = act
         tracer.event(spans.EVENT_ADMIT, slot=slot, request=req.id,
                      prompt_len=t0, bucket=bucket)
@@ -712,12 +845,13 @@ class LMEngine:
         tracer = self._tracer
         step = self._steps
         with tracer.span(spans.SPAN_STEP_PREP, step=step) as span_id:
-            # grow pages where the next position crosses a page
+            # grow pages where the step's last row crosses a page
             # boundary.  Exhaustion first settles the step in flight
             # (a request it completes frees pages), then preempts the
             # youngest request (possibly this one)
             for slot in range(self.max_batch):
-                while self._runs(slot) and self.cache.needs_growth(slot):
+                while self._runs(slot) and self.cache.needs_growth(
+                        slot, self._ahead(slot)):
                     if self.cache.grow(slot) or self._settle("preempt"):
                         continue
                     victim = self._preempt_youngest()
@@ -730,16 +864,21 @@ class LMEngine:
             fresh = np.zeros((self.max_batch,), bool)
             temps = np.zeros((self.max_batch,), np.float32)
             active = np.zeros((self.max_batch,), bool)
+            drafts = np.zeros((self.max_batch,), np.int32)
+            owed = np.zeros((self.max_batch,), np.int32)
             for i in running:
                 act = self._slots[i]
                 if act.first_token is not None:
                     # admitted since the last step: its input is the
                     # prefill's token, from the host, this once
                     tokens[i], fresh[i] = act.first_token, True
-                    act.first_token = None
+                    if self._drafts:
+                        drafts[i], owed[i] = act.first_draft, act.left
+                    act.first_token = act.first_draft = None
                 temps[i] = act.req.temperature
                 active[i] = True
-            longest = max(int(self.cache.lengths[i]) for i in running)
+            longest = max(int(self.cache.lengths[i]) + self._ahead(i)
+                          for i in running)
             bucket = used_page_bucket(longest, self.page_size,
                                       self.cache.max_pages_per_slot)
             self._last_bucket = bucket
@@ -756,33 +895,45 @@ class LMEngine:
         with tracer.span(spans.SPAN_STEP_DECODE, bucket=bucket,
                          active=len(running),
                          ahead=int(prev is not None)) as span_id:
+            if self._drafts:
+                host = (jnp.asarray(tokens), jnp.asarray(drafts),
+                        jnp.asarray(owed), jnp.asarray(fresh),
+                        jnp.asarray(active))
+            else:
+                host = (jnp.asarray(tokens), jnp.asarray(fresh),
+                        jnp.asarray(temps), jnp.asarray(active), sub)
             out = self._step_fn(
                 self.params, *self.cache.buffers(), tables, lengths,
-                self._prev_nxt, jnp.asarray(tokens), jnp.asarray(fresh),
-                jnp.asarray(temps), jnp.asarray(active), sub)
+                *self._carry, *host)
             self.cache.set_buffers(out[:n])
-            self._prev_nxt = out[n]
-            for arr in out[n:]:
+            k = len(self._carry)
+            self._carry = out[n:n + k]
+            # what the host reads: the tokens (a one-token model's are
+            # the carry itself), and an expert model's counts
+            result = out[n + k:] if self._drafts else out[n:]
+            for arr in result:
                 # on their way to the host as soon as they exist, not
                 # when the next pump asks for them
                 arr.copy_to_host_async()
-            # the host's state advances at dispatch: the next prep
-            # (growth, bucket, who runs) needs no token
+            # the host's state advances at dispatch, by the one token a
+            # step yields at least: the next prep (growth, bucket, who
+            # runs) needs no token
             entries, context = [], 0
             for i in running:
                 act = self._slots[i]
                 self.cache.lengths[i] += 1
                 context += int(self.cache.lengths[i])
                 act.remaining -= 1
-                entries.append((i, act, act.remaining <= 0))
+                act.unread += 1
+                entries.append((i, act))
             self._inflight = _InFlight(
-                out[n], out[n + 1] if len(out) > n + 1 else None,
+                result[0], result[1] if len(result) > 1 else None,
                 entries, context)
             if prev is not None:
-                toks, routed = self._read(prev)
-                # an expert model's step: what the step just read
-                # routed, and the rows of context it had to read
-                tracer.add_attrs(span_id, **routed)
+                read = self._read(prev)
+                # what the step just read routed and yielded, and the
+                # rows of context it had to read
+                tracer.add_attrs(span_id, **read.attrs)
         step_ms = (time.perf_counter() - t0) * 1000.0
         with tracer.span(spans.SPAN_STEP_EMIT, step=step):
             self._steps += 1
@@ -803,7 +954,7 @@ class LMEngine:
             if prev is not None:
                 self._steps_ahead += 1
                 self._ahead_counter.inc()
-                self._emit(prev, toks)
+                self._emit(prev, read)
             try:
                 from bigdl_tpu.obs import server as obs_server
 
@@ -812,30 +963,87 @@ class LMEngine:
                 pass
         return True
 
-    def _read(self, rec: _InFlight):
-        """Wait for a dispatched step's tokens.  With them come an
-        expert model's routing counts: returned as span attributes,
-        beside the rows of context that step had to read."""
-        toks = np.asarray(rec.nxt)
-        if rec.counts is None:
-            return toks, {}
-        return toks, dict(self._note_routing(rec.counts),
-                          context_tokens=rec.context_tokens)
+    def _ahead(self, slot: int) -> int:
+        """How far past its (lower-bound) length the slot's next step
+        may write: 0 for a one-token model; a drafting model's step
+        writes a second row, and a step not yet read may have taken its
+        draft.  Never past the request's last position."""
+        if not self._drafts:
+            return 0
+        act = self._slots[slot]
+        return max(0, min(1 + act.unread,
+                          act.last_pos - int(self.cache.lengths[slot])))
 
-    def _emit(self, rec: _InFlight, toks):
-        """Hand a read step's tokens to their requests."""
-        for slot, act, last in rec.entries:
+    def _read(self, rec: _InFlight) -> _StepRead:
+        """Wait for a dispatched step's tokens.  With them come an
+        expert model's routing counts and a drafting model's emitted
+        counts: returned as span attributes, beside the rows of context
+        that step had to read."""
+        res = np.asarray(rec.result)
+        attrs, drafts = {}, {}
+        if self._drafts:
+            first, second, emitted, draft, length = res.T  # DRAFT_RESULT
+            toks = np.stack([first, second], axis=1)
+            accepted = tokens = context = 0
+            for slot, act in rec.entries:
+                if self._slots[slot] is not act or not emitted[slot]:
+                    continue    # completed since, or owed nothing there
+                # a draft counts as verified where the slot owed the
+                # token it drafts (the device's owed count is the
+                # host's ``left`` once every earlier step is emitted,
+                # as here)
+                if act.left >= 2:
+                    drafts[slot] = int(draft[slot])
+                accepted += int(emitted[slot] == 2)
+                tokens += int(emitted[slot])
+                # the rows the step had to read, once a slot: up to
+                # its second query's position
+                context += int(length[slot]) + 2
+            attrs.update(draft_verified=len(drafts),
+                         draft_accepted=accepted, tokens_emitted=tokens)
+            self._draft_verified += len(drafts)
+            self._draft_accepted += accepted
+            self._draft_counter.labels(outcome="accepted").inc(accepted)
+            self._draft_counter.labels(outcome="rejected").inc(
+                len(drafts) - accepted)
+        else:
+            toks, emitted, context = res[:, None], None, rec.context_tokens
+        if rec.counts is not None:
+            attrs.update(self._note_routing(rec.counts),
+                         context_tokens=context)
+        return _StepRead(toks, emitted, drafts, attrs)
+
+    def _emit(self, rec: _InFlight, read: _StepRead):
+        """Hand a read step's tokens to their requests, and bring the
+        host's bounds up to what the step turned out to yield."""
+        for slot, act in rec.entries:
+            act.unread -= 1
             if self._slots[slot] is not act:
                 # completed on an EOS after this step was dispatched:
                 # the row was wasted, its token is no one's
                 continue
-            tok = int(toks[slot])
-            act.req.tokens.append(tok)
-            act.req.token_times.append(time.perf_counter())
-            self._tokens_total += 1
-            self._tokens_counter.inc()
-            if last or tok == self.eos_id:
-                self._complete(slot)
+            n = 1 if read.emitted is None else int(read.emitted[slot])
+            if not n:
+                continue    # owed nothing on the device: a wasted row
+            req = act.req
+            if slot in read.drafts:
+                req.drafts.append((len(req.tokens), read.drafts[slot]))
+            # the dispatch counted one token; the step may have yielded
+            # another
+            self.cache.lengths[slot] += n - 1
+            act.remaining -= n - 1
+            self._slot_steps += 1
+            for j in range(n):
+                tok = int(read.tokens[slot, j])
+                req.tokens.append(tok)
+                req.token_times.append(time.perf_counter())
+                self._tokens_total += 1
+                self._step_tokens += 1
+                self._tokens_counter.inc()
+                act.left -= 1
+                if act.left <= 0 or tok == self.eos_id:
+                    self._complete(slot)
+                    break
 
     def _settle(self, reason: str) -> bool:
         """Read and emit the step in flight, outside the pipelined loop:
@@ -848,10 +1056,10 @@ class LMEngine:
             return False
         self._inflight = None
         tracer = obs.get_tracer()  # not always inside a pump
-        toks, routed = self._read(rec)
-        tracer.event(spans.EVENT_SETTLE, reason=reason, **routed)
+        read = self._read(rec)
+        tracer.event(spans.EVENT_SETTLE, reason=reason, **read.attrs)
         with tracer.span(spans.SPAN_STEP_EMIT, step=self._steps):
-            self._emit(rec, toks)
+            self._emit(rec, read)
         self._settles[reason] += 1
         self._settle_counter.labels(reason=reason).inc()
         return True
@@ -940,6 +1148,15 @@ class LMEngine:
             "tokens": self._tokens_total,
             "steps": self._steps,
             "steps_ahead": self._steps_ahead,
+            # tokens a slot and step that yielded any (1 unless the
+            # model drafts), and the share of verified drafts accepted
+            "tokens_per_step": (self._step_tokens / self._slot_steps
+                                if self._slot_steps else None),
+            "drafts_verified": self._draft_verified,
+            "drafts_accepted": self._draft_accepted,
+            "draft_accept_share": (
+                self._draft_accepted / self._draft_verified
+                if self._draft_verified else None),
             "settles": dict(self._settles),
             "busy_s": busy,
             "tokens_per_s": (self._tokens_total / busy
@@ -968,4 +1185,4 @@ class LMEngine:
         }
 
 
-__all__ = ["LMEngine", "sample_first", "sample_step"]
+__all__ = ["LMEngine", "pick_greedy", "sample_first", "sample_step"]

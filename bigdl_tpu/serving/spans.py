@@ -76,6 +76,31 @@ Three families:
   The registry has the same counts as
   ``bigdl_serve_moe_assignments_total{kind}`` and
   ``bigdl_serve_moe_load_max_over_mean`` (``obs/names.py``).
+
+  A **model that drafts** (``draft_spec``; ``models/joyai_flash.py``)
+  verifies two positions a slot in a step, so its ``moe_*`` count both
+  positions (and the prediction layer's expert layer), its
+  ``context_tokens`` is still the rows the step had to read ONCE a slot
+  (up to the second query's position: the two queries share one read),
+  and its ``serve.decode_step`` (of the step it READ, like the counts;
+  a settled step's on ``serve.settle``) also carries:
+
+  ====================  ================================================
+  ``draft_verified``    slots whose draft the step checked while they
+                        owed the token it drafts (two or more left)
+  ``draft_accepted``    of those, the drafts that were right: the step
+                        yielded two tokens for the slot
+  ``tokens_emitted``    tokens the step yielded over its live slots:
+                        between their count and twice it
+  ====================  ================================================
+
+  Registry: ``bigdl_serve_draft_tokens_total{outcome="accepted"|
+  "rejected"}``; ``stats()["draft_accept_share"]``,
+  ``["tokens_per_step"]``.  ``ServeRequest.drafts`` keeps, for each
+  verified draft, the index of the token it was checked against and the
+  draft.  With two tokens a step ``serve.decode_step``'s length is still
+  one step period less the host's work, not a gap between tokens, and
+  ``active=`` still counts slots, not tokens.
 * ``EVENT_*`` — point events the engine/simulator stamp regardless of
   request tracing.
 """

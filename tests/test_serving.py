@@ -387,7 +387,7 @@ class TestOneStepInFlight:
             pages_b = eng.cache.slot_pages(1) or pages_b
         assert not a.done and eng._inflight is not None
         # the step in flight still carries b's slot: its row is wasted
-        assert [slot for slot, _, _ in eng._inflight.entries] == [0, 1]
+        assert [slot for slot, _ in eng._inflight.entries] == [0, 1]
         c = eng.submit(pc, 14)
         eng.pump()
         assert eng._slots[1] is not None and eng._slots[1].req is c

@@ -236,6 +236,24 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
               f"{buffer_bytes / 1e6:.1f} MB")
 
 
+def _whole_cache_ops(compiled, buf) -> dict:
+    """Instructions of a compiled program whose result has the whole
+    cache's shape, by kind (the cache write is a ``scatter fusion``)."""
+    import re
+
+    dims = ",".join(str(n) for n in buf.shape)
+    whole = re.compile(r"= bf16\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
+    ops = {}
+    for line in compiled.as_text().splitlines():
+        m = whole.search(line)
+        if m is not None:
+            op = m.group(1)
+            if op == "fusion" and "kv_write/scatter" in line:
+                op = "scatter fusion"
+            ops[op] = ops.get(op, 0) + 1
+    return ops
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("row_align", [128, 1])
 def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
@@ -254,7 +272,6 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     worked on.  That is the evidence the padding was chosen by; run it
     before spending chip minutes on the latent cache."""
     import functools
-    import re
     import types
 
     from bigdl_tpu.models import longcat_flash_reference as ref
@@ -287,7 +304,7 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
     # the engine's own builders, given shapes in place of an engine
     eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None,
+        model=probe, page_size=page, _qparams=None, _drafts=False,
         cache=types.SimpleNamespace(buffers=lambda: (buf,)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)
@@ -301,22 +318,13 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, spec((1, 256), jnp.int32), spec((), jnp.int32),
             spec((256 // page,), jnp.int32), spec((), jnp.float32), key)}
-    dims = ",".join(str(n) for n in buf.shape)
-    whole = re.compile(r"= bf16\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
     buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
     for name, lowered in programs.items():
         # the expert layer's grouped products are the Pallas kernel (two
         # distinct ones: up / gate, and down)
         assert lowered.as_text().count("tpu_custom_call") >= 2, name
         compiled = lowered.compile()
-        ops = {}
-        for line in compiled.as_text().splitlines():
-            m = whole.search(line)
-            if m is not None:
-                op = m.group(1)
-                if op == "fusion" and "kv_write/scatter" in line:
-                    op = "scatter fusion"
-                ops[op] = ops.get(op, 0) + 1
+        ops = _whole_cache_ops(compiled, buf)
         temp = compiled.memory_analysis().temp_size_in_bytes
         print(f"row {cs['row_width']} {name}: whole-cache instructions "
               f"{ops}, temporaries {temp / 1e6:.1f} MB, the cache "
@@ -362,3 +370,68 @@ def test_the_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):
                 spec((16,), jnp.int32))
             assert "tpu_custom_call" in lowered.as_text()
             lowered.compile()
+
+
+@pytest.mark.slow
+def test_draft_engine_programs_work_on_the_cache_as_it_lies(one_chip,
+                                                            monkeypatch):
+    """JoyAI-LLM-Flash's verify-and-draft step and a prefill at the
+    published widths and the cell's engine size (the dense layer, one
+    expert layer and the prediction layer; 32 of 256 experts held, 256
+    slots, the default pool of 32769 pages), from shapes alone,
+    compiled for the described v5e with the latent cache donated: two
+    rows written a slot and two queries a slot on the head axis, and
+    still no instruction of the whole cache's size but the scatters."""
+    import functools
+    import types
+
+    from bigdl_tpu.models import joyai_flash_reference as ref
+    from bigdl_tpu.models.joyai_flash import JoyAIFlash, PUBLISHED
+    from bigdl_tpu.serving.engine import LMEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = one_chip
+    dt = jnp.bfloat16
+    sizes = dict(PUBLISHED, num_hidden_layers=2, vocab_size=1024)
+    slots, page, max_len = 256, 16, 2048
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = dict(sizes, router_experts=256, n_routed_experts=32,
+               held_experts=[0, 32], max_len=max_len)
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(cfg), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    probe = JoyAIFlash(max_len=max_len, held_experts=(0, 32),
+                       params=weights, **sizes)
+    cs = probe.cache_spec(weights)
+    assert (cs["row_width"], cs["layers"]) == (640, 3)
+    buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
+    eng = types.SimpleNamespace(
+        model=probe, page_size=page, _qparams=None, _drafts=True,
+        cache=types.SimpleNamespace(buffers=lambda: (buf,)),
+        _prefill_fns={})
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((slots,), jnp.int32)
+    flags = spec((slots,), jnp.bool_)
+    programs = {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, spec((slots, 128), jnp.int32), ints,
+            ints, ints, ints, ints, ints, ints, ints, flags, flags),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, spec((1, 256), jnp.int32), spec((), jnp.int32),
+            spec((256 // page,), jnp.int32), spec((), jnp.float32), key)}
+    buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
+    for name, lowered in programs.items():
+        assert lowered.as_text().count("tpu_custom_call") >= 2, name
+        compiled = lowered.compile()
+        ops = _whole_cache_ops(compiled, buf)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        print(f"draft {name}: whole-cache instructions {ops}, "
+              f"temporaries {temp / 1e6:.1f} MB, the cache "
+              f"{buffer_bytes / 1e6:.1f} MB")
+        assert set(ops) <= {"parameter", "scatter", "scatter fusion",
+                            "get-tuple-element"}, (name, ops)
+        assert temp < buffer_bytes // 2, (name, temp, buffer_bytes)
