@@ -26,12 +26,15 @@ with ``s_q = sqrt(dim / q_rank)`` and ``s_kv = sqrt(dim / kv_rank)``
 **The cached row of a token is ``[c | rotated k_rope]``** — ``kv_rank +
 rope`` values for all heads together, and zeros up to a multiple of
 ``row_align`` lanes (a TPU works on a buffer of 640-lane rows as it
-lies; 576-lane rows it copies whole, in and out of every program:
+lies; 576-lane rows the prefill copies whole, in and out, and the
+decode kernel cannot copy a page of them at all:
 ``tests/test_tpu_lowering.py``).  :meth:`LatentAttention.prefill`
 rebuilds K and V per head from the rows; :meth:`LatentAttention.decode`
 absorbs ``W_kvb`` into the query (``q_nope_h W_k,h`` scores against
-``c``) and into the output, so a decode step reads the rows where they
-lie in the paged cache and never builds a key or a value.  Handed
+``c``) and into the output, so a decode step never builds a key or a
+value: ``ops/decode_attention.latent_decode_attention``, a kernel,
+copies each slot's pages from where they lie in the paged cache into
+fast memory, up to the slot's own length, and reads each row once.  Handed
 ``(B, Q, dim)`` it advances ``Q`` consecutive positions a slot in one
 pass (a step that verifies a draft, ``serving/engine.py``): ``Q`` rows
 written, and the ``Q`` queries of a slot ride the head axis, each with
@@ -284,7 +287,9 @@ class LatentAttention(AbstractModule):
         ``(cached layers, pages, P, row)`` buffer, at ``layer``) and
         attends over positions ``<= length`` with ``W_kvb`` absorbed:
         the query becomes a row-shaped vector, the mix a ``kv_rank``
-        vector a head.  Returns ``(y (B, dim), pages)``.
+        vector a head; between the two absorptions (plain einsums) the
+        latent kernel reads the slot's pages, at ``[layer, page]``, up
+        to its length.  Returns ``(y (B, dim), pages)``.
 
         ``x`` (B, Q, dim) is ``Q`` consecutive tokens a slot, at
         positions ``lengths + 0 .. Q-1``: ``Q`` rows written, query

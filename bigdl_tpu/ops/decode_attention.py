@@ -13,8 +13,11 @@ between them:
   ``TransformerBlock.decode_step``, so paged decode matches
   ``generate()`` token for token at temperature 0;
 * :func:`latent_decode_attention` — a latent cache (``nn/latent.py``):
-  one compressed row a token for all heads and no V buffer; the rows
-  are read a block of pages at a time into an online softmax.
+  one compressed row a token for all heads and no V buffer.  A Pallas
+  kernel: it walks each slot's page list up to the slot's own length,
+  copies the pages from where they lie in the pool into fast memory a
+  block at a time, and folds each block into an online softmax there;
+  the contexts' rows are read once, and nothing is gathered in HBM.
 
 Mask contract (both bodies, pinned by tests): position ``pos <=
 length`` attends, everything else is ``-inf`` before the softmax — so
@@ -24,7 +27,9 @@ output.
 
 The engine slices each step's page tables to the used-page bucket
 (:func:`used_page_bucket`): the pow2 count of pages covering
-``max(lengths)//P + 1``, so neither body pays for the empty pool.
+``max(lengths)//P + 1``, so the gather body does not pay for the empty
+pool (the latent kernel stops at each slot's length whatever the
+width; the bucket only bounds the table it is handed).
 
 A faster body REPLACES one of these two, in a ``perf_opt`` PR that
 shows its gain in a cell of the benchmark; it is not added beside one
@@ -33,6 +38,7 @@ behind a switch.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 
@@ -63,14 +69,6 @@ def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
     pages = 2.0 * b * maxp * page_size * h * d * kv_itemsize  # K + V
     qio = 2.0 * b * h * d * 4                                 # q + out
     return pages * 3 + 2.0 * b * h * k * 4 + qio
-
-
-def _mask_neg_inf(scores, pos, lengths):
-    """``pos <= length`` attends; everything else -inf (the trash-page
-    contract)."""
-    import jax.numpy as jnp
-
-    return jnp.where(pos <= lengths, scores, -jnp.inf)
 
 
 # --------------------------------------------------------------------------
@@ -156,22 +154,136 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
 # --------------------------------------------------------------------------
 
 
-def _chunk_pages(maxp: int, block_pages: int) -> int:
-    """Largest valid page-block size <= the request that divides the
-    table width (0 / oversize requests collapse to the full width —
-    one block, no loop)."""
-    maxp = int(maxp)
-    bp = int(block_pages)
-    if bp <= 0 or bp >= maxp:
-        return maxp
-    while bp > 1 and maxp % bp:
-        bp -= 1
-    return bp
+# A block of pages is what one buffer of the kernel's ring holds, about
+# this many bytes; the copies of the ring's other blocks are in flight
+# while one is contracted.  Chosen on the chip (PERF.md section 6,
+# PR 31): blocks of 16 pages are a tenth slower than of 32, and a third
+# or fourth buffer buys nothing.
+_BLOCK_BYTES = 640 * 1024
+_BUFFERS = 2
+# copies started a trip of the kernel's issue loop: a branch a page
+# would cost the scalar core as much as the copy's descriptor
+_COPIES_A_TRIP = 8
+
+
+def _block_pages(page_size: int, row_width: int, itemsize: int,
+                 head_rows: int) -> int:
+    """Pages a block of the latent kernel, from the shapes alone (never
+    from the table's width: a narrower bucket must change no bit): as
+    many whole pages as ``_BLOCK_BYTES`` hold, and no more positions
+    than 1024 or than keep a block's float32 scores (``head_rows`` x
+    positions) within 256 KB."""
+    by_bytes = _BLOCK_BYTES // (page_size * row_width * itemsize)
+    positions = min(1024, (64 * 1024) // max(1, head_rows))
+    bp = max(1, min(by_bytes, positions // page_size))
+    return bp if bp < _COPIES_A_TRIP else bp - bp % _COPIES_A_TRIP
+
+
+def _latent_kernel(bp: int, page: int, maxp: int, vw: int):
+    """The kernel body of :func:`latent_decode_attention` for blocks of
+    ``bp`` pages of ``page`` rows, a table ``maxp`` wide and a mix over
+    the rows' first ``vw`` lanes.  One grid step a slot; the blocks of
+    all slots, in order, are one stream through the ring of buffers it
+    is given: all but one block's copies are in flight while one is
+    contracted, across the slots' edges."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows_blk = bp * page
+    unroll = min(_COPIES_A_TRIP, bp)
+    assert bp % unroll == 0, bp
+
+    def kernel(tables, need, layer, q_ref, len_ref, pool, o_ref,
+               buf, sems, ring):
+        # ring: [0] slot and [1] block the next copies are for, [2]
+        # blocks issued, [3] blocks contracted
+        b = pl.program_id(0)
+        nslots = pl.num_programs(0)
+        nbuf = buf.shape[0]
+        lyr = layer[0]
+
+        def blocks_of(slot):
+            return (need[slot] + bp - 1) // bp
+
+        def issue():
+            slot, blk = ring[0], ring[1]
+
+            @pl.when(slot < nslots)
+            def _():
+                half = ring[2] % nbuf
+
+                def group(g, c):
+                    for j in range(unroll):
+                        j += g * unroll
+                        # past the slot's last page: what the table names
+                        # there (page 0, finite by the cache's contract;
+                        # past the table's width, its last entry again)
+                        pg = tables[slot * maxp
+                                    + jnp.minimum(blk * bp + j, maxp - 1)]
+                        pltpu.make_async_copy(pool.at[lyr, pg],
+                                              buf.at[half, j],
+                                              sems.at[half]).start()
+                    return c
+
+                lax.fori_loop(0, bp // unroll, group, 0)
+                last = blk + 1 >= blocks_of(slot)
+                ring[0] = jnp.where(last, slot + 1, slot)
+                ring[1] = jnp.where(last, 0, blk + 1)
+                ring[2] = ring[2] + 1
+
+        @pl.when(b == 0)
+        def _():
+            for k in range(4):
+                ring[k] = 0
+
+        nblk = blocks_of(b)
+        qs = q_ref[0]                                  # (H, R)
+        lens = len_ref[0]                              # (H, 1)
+        h = qs.shape[0]
+
+        def block(i, carry):
+            m, l, acc = carry
+            # one more block's copies under way (before the very first
+            # block: the whole ring's)
+            def more(_, c):
+                issue()
+                return c
+
+            lax.fori_loop(0, jnp.where(ring[2] == 0, nbuf, 1), more, 0)
+            half = ring[3] % nbuf
+            ring[3] = ring[3] + 1
+            # one wait for the block's bytes, whichever copy ends last
+            pltpu.make_async_copy(pool.at[lyr, pl.ds(0, bp)], buf.at[half],
+                                  sems.at[half]).wait()
+            rows = buf[half].reshape(rows_blk, buf.shape[-1])
+            s = lax.dot_general(qs, rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            pos = i * rows_blk + lax.broadcasted_iota(
+                jnp.int32, (h, rows_blk), 1)
+            s = jnp.where(pos <= lens, s, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
+            shift = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            pr = jnp.exp(s - shift)
+            alpha = jnp.exp(m - shift)
+            mix = jnp.dot(pr.astype(rows.dtype), rows[:, :vw],
+                          preferred_element_type=jnp.float32)
+            return (m_new, l * alpha + jnp.sum(pr, axis=-1, keepdims=True),
+                    acc * alpha + mix)
+
+        init = (jnp.full((h, 1), -jnp.inf, jnp.float32),
+                jnp.zeros((h, 1), jnp.float32),
+                jnp.zeros((h, vw), jnp.float32))
+        _, l, acc = lax.fori_loop(0, nblk, block, init)
+        o_ref[0] = acc / jnp.maximum(l, 1e-30)
+
+    return kernel
 
 
 def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
-                            value_width: int, layer: Optional[int] = None,
-                            block_pages: int = 16):
+                            value_width: int, layer: Optional[int] = None):
     """Decode attention over a **latent** paged cache (``nn/latent.py``:
     a token's row is ``[c | rotated k_rope]``, shared by all heads, and
     there is no V buffer).
@@ -180,66 +292,92 @@ def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
     ``[q_nope W_k | q_rope]``); pages: one layer's ``(num_pages, P, R)``
     pool or, with ``layer``, the stacked buffer, read where it lies;
     tables / lengths as in :func:`paged_decode_attention` (``pos <=
-    length`` attends, everything else is ``-inf``); ``lengths`` (B, H)
-    gives every query a length of its own: the ``Q`` queries a slot of
-    a step that verifies a draft ride the head axis (``H = Q x heads``)
-    and share the one read of the slot's rows, each attending up to its
-    own position (``nn/latent.py``).  Returns the mix
-    over the rows' first ``value_width`` lanes, ``(B, H, value_width)``
-    in float32 — the caller applies ``W_v`` and ``W_o``.
+    length`` attends, everything else contributes nothing); ``lengths``
+    (B, H) gives every query a length of its own: the ``Q`` queries a
+    slot of a step that verifies a draft ride the head axis (``H = Q x
+    heads``) and share the one read of the slot's rows, each attending
+    up to its own position (``nn/latent.py``).  Returns the mix over
+    the rows' first ``value_width`` lanes, ``(B, H, value_width)`` in
+    float32 — the caller applies ``W_v`` and ``W_o``.
 
-    It is multi-query attention with ``H`` query heads on one row: both
-    contractions take the gathered rows whole, on the MXU, with float32
-    accumulation, and the softmax is in float32.  The rows are read
-    ``block_pages`` pages a slot at a time and folded into a running
-    ``(m, l, acc)`` (an online softmax): gathered whole, a slot's rows
-    are a temporary of the bucket's size an attention (335 MB at 128
-    slots x 2048 positions x 640 lanes) that the TPU compiler, short of
-    memory beside the weights, builds again for each of its uses (26 ms
-    a step for 8 attentions; chip run, PR 26)."""
+    A Pallas kernel, one grid step a slot.  Tables, lengths and
+    ``layer`` are scalar prefetch; the pool stays in HBM and a slot's
+    pages are copied from ``[layer, page]`` into fast memory a block at
+    a time, up to the slot's OWN length (the blocks that cover the
+    ``length // P + 1`` pages of its longest query; the last block is
+    copied whole, from what the table names there: page 0, finite by
+    the cache's contract, and masked) — not the table's width, and
+    with no gathered copy in HBM — the next blocks' copies (at a slot's
+    end: the next slot's first) in flight while this one is
+    contracted.  It is multi-query attention with ``H`` query heads on
+    one row: a block is one ``(H, R) x (R, rows)`` and one ``(H, rows)
+    x (rows, value_width)`` product on the MXU with float32
+    accumulation, operands in the pool's dtype (``q`` is scaled in
+    float32 first), folded into a running float32 ``(m, l, acc)`` (an
+    online softmax).
+    The pages a block are taken from the shapes (:func:`_block_pages`),
+    so a table cut to the used-page bucket changes no bit.  Off the CPU
+    it is the Mosaic kernel or an error; on the CPU backend the Pallas
+    interpreter (``ops/_pallas.resolve_interpret``)."""
     import jax.numpy as jnp
-    from jax import lax
 
-    from bigdl_tpu.serving.cache import gather_pages
+    from bigdl_tpu.ops._pallas import resolve_interpret
 
-    b, maxp = tables.shape
-    h = q.shape[1]
-    p = pages.shape[-2]
-    vw = int(value_width)
-    bp = _chunk_pages(maxp, block_pages)
-    qs = (q.astype(jnp.float32) * scale).astype(pages.dtype)
-    len_b = lengths[:, None, None] if lengths.ndim == 1 \
-        else lengths[:, :, None]
+    return _latent_program(float(scale), int(value_width),
+                           resolve_interpret(None))(
+        q, pages if layer is not None else pages[None], tables, lengths,
+        jnp.full((1,), layer or 0, jnp.int32))
 
-    def block(tbl_c, c0, m, l, acc):
-        rows = gather_pages(pages, tbl_c, layer)       # (B, bp*P, R)
-        s = jnp.einsum("bhc,bkc->bhk", qs, rows,
-                       preferred_element_type=jnp.float32)
-        pos = c0 * p + jnp.arange(bp * p)[None, None, :]
-        s = _mask_neg_inf(s, pos, len_b)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
-        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        pr = jnp.exp(s - shift[..., None])
-        alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - shift, -jnp.inf))
-        mix = jnp.einsum("bhk,bkc->bhc", pr.astype(rows.dtype),
-                         rows[..., :vw],
-                         preferred_element_type=jnp.float32)
-        return (m_new, l * alpha + jnp.sum(pr, axis=-1),
-                acc * alpha[..., None] + mix)
 
-    init = (jnp.full((b, h), -jnp.inf, jnp.float32),
-            jnp.zeros((b, h), jnp.float32),
-            jnp.zeros((b, h, vw), jnp.float32))
-    if bp == maxp:
-        _, l, acc = block(tables, 0, *init)
-    else:
-        def body(c, carry):
-            tbl_c = lax.dynamic_slice_in_dim(tables, c * bp, bp, axis=1)
-            return block(tbl_c, c * bp, *carry)
+@functools.lru_cache(maxsize=None)
+def _latent_program(scale: float, vw: int, interpret: bool):
+    """The jitted call of the latent kernel for one ``(scale,
+    value_width)``, the layer an argument: built once, so a model's
+    attentions share one traced program a shape, and a caller outside
+    a ``jit`` (the tests' teacher-forced loops) compiles it once a
+    shape and not once a call."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-        _, l, acc = lax.fori_loop(0, maxp // bp, body, init)
-    return acc / jnp.maximum(l, 1e-30)[..., None]
+    def call(q, pool, tables, lengths, layer):
+        b, maxp = tables.shape
+        h, r = q.shape[1], q.shape[2]
+        p = pool.shape[-2]
+        bp = _block_pages(p, r, pool.dtype.itemsize, h)
+        qs = (q.astype(jnp.float32) * scale).astype(pool.dtype)
+        lens = jnp.broadcast_to(
+            lengths[:, None] if lengths.ndim == 1 else lengths,
+            (b, h)).astype(jnp.int32)
+        # pages a slot must read: up to its longest query's position
+        need = jnp.clip(jnp.max(lens, axis=1) // p + 1, 1, maxp)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, r), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, h, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, vw), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((_BUFFERS, bp, p, r), pool.dtype),
+                pltpu.SemaphoreType.DMA((_BUFFERS,)),
+                pltpu.SMEM((4,), jnp.int32),
+            ])
+        return pl.pallas_call(
+            _latent_kernel(bp, p, maxp, vw),
+            out_shape=jax.ShapeDtypeStruct((b, h, vw), jnp.float32),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="latent_decode_attention",
+        )(tables.reshape(-1).astype(jnp.int32), need, layer, qs,
+          lens[:, :, None], pool)
+
+    return jax.jit(call)
 
 
 __all__ = ["paged_decode_attention", "latent_decode_attention",

@@ -56,6 +56,8 @@ FULL = dict(
         (1, 2, 2048, 32768, 128),
     ],
     grouped=dict(m=1536, k=6144, n=2048, sizes=(3, 0, 5, 1, 2, 9, 0, 4)),
+    # the latent cells' row, page and head rows; contexts from 0 to full
+    latent=dict(slots=16, heads=64, row=640, page=16, maxp=128, value=512),
     conv=[  # (N, C, H, O, k, stride): ResNet-50 sites
         (8, 256, 56, 64, 1, 1),
         (8, 128, 28, 128, 3, 1),
@@ -69,6 +71,7 @@ TINY = dict(
     prompt_lens=(5, 20), new_tokens=8,
     flash=[(1, 2, 128, 128, 16), (1, 2, 128, 256, 16)],
     grouped=dict(m=256, k=256, n=128, sizes=(37, 0, 90, 5)),
+    latent=dict(slots=4, heads=4, row=128, page=8, maxp=20, value=16),
     conv=[(2, 16, 8, 16, 1, 1), (2, 8, 8, 16, 3, 1), (2, 8, 8, 16, 3, 2)],
     multichip=dict(devices=4, batch=16, steps=3),
 )
@@ -506,6 +509,44 @@ def phase_kernels(cfg, platform, compiles) -> dict:
         f"grouped product M={c['m']} K={c['k']} N={c['n']} "
         f"groups={len(c['sizes'])} bf16 vs ragged_dot",
         grouped, (lhs, rhs), want, TOL["bfloat16"], rehearsal)
+
+    # ---- latent decode attention over a paged pool, against a gather
+    from bigdl_tpu.ops.decode_attention import latent_decode_attention
+    from bigdl_tpu.serving.cache import gather_pages
+
+    c = cfg["latent"]
+    span = c["maxp"] * c["page"]
+    lens = np.linspace(0, span - 1, c["slots"]).astype(np.int32)
+    pool = 1 + c["slots"] * c["maxp"]
+    tables = np.zeros((c["slots"], c["maxp"]), np.int32)
+    free = rs.permutation(np.arange(1, pool))
+    for i, n in enumerate(lens // c["page"] + 1):
+        tables[i, :n], free = free[:n], free[n:]
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    q = jnp.asarray(rs.randn(c["slots"], c["heads"], c["row"]),
+                    jnp.bfloat16)
+    rows = jnp.asarray(rs.randn(2, pool, c["page"], c["row"]), jnp.bfloat16)
+    scale = c["row"] ** -0.5
+
+    def latent(q, rows):
+        return latent_decode_attention(q, rows, tables, lens, scale=scale,
+                                       value_width=c["value"], layer=1)
+
+    def latent_truth(q, rows):
+        ctx = gather_pages(rows, tables, 1)        # (slots, span, row)
+        s = jnp.einsum("bhc,bkc->bhk", q, ctx) * scale
+        s = jnp.where(jnp.arange(span)[None, None] <= lens[:, None, None],
+                      s, -jnp.inf)
+        return jnp.einsum("bhk,bkc->bhc", jax.nn.softmax(s, axis=-1),
+                          ctx[..., :c["value"]])
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(
+            jax.jit(latent_truth)(*f32((q, rows))))
+    out["latent_decode_bf16"] = _run_kernel(
+        f"latent decode attention slots={c['slots']} heads={c['heads']} "
+        f"row={c['row']} pages of {c['page']} x {c['maxp']} bf16 vs gather",
+        latent, (q, rows), want, TOL["bfloat16"], rehearsal)
 
     # ---- conv + BN statistics, forward (the kernel) and its custom vjp
     for n, ci, hw, o, ksz, stride in cfg["conv"]:

@@ -9,10 +9,13 @@ The load-bearing contracts:
   lengths, lengths around every page edge, a live slot of length 0,
   full slots and arbitrary page-table permutations — on a layer's own
   pool and on the engine's stacked buffer with ``layer=``;
-* the latent body's page-block loop gives the oracle's answer at every
-  block size;
+* the latent kernel gives the oracle's answer over several blocks of
+  pages a slot, with one and two queries a slot (a length each), in
+  float32 and bfloat16, at rows 640 lanes wide;
 * the trash page is never READ into an output: arbitrary finite
-  garbage in page 0 changes no live slot's result;
+  garbage in page 0 (and, in the latent kernel, in the rows past a
+  slot's length) changes no live slot's result, and NaNs in pages no
+  table names change no bit;
 * a table sliced to the used-page bucket gives the full table's
   output to the last bit (what lets the engine slice every step);
 * the ``int8_mm`` auto-tuner site: golden key, never-lose, the
@@ -196,32 +199,98 @@ class TestLatentDecodeParity:
             q, pages, tbl, lens, scale=self.SCALE, value_width=self.VW,
             **kw))
 
-    @pytest.mark.parametrize("block_pages", [MAXP, 1, 2, 4])
-    def test_block_loop_matches_numpy_oracle(self, block_pages):
-        """One block (the whole width) and the ``fori_loop`` at three
-        block sizes, 8 pages a slot."""
-        q, pages, tbl, lens = _latent_state()
-        assert D._chunk_pages(MAXP, block_pages) == block_pages
-        want = _latent_reference(q, pages, tbl, lens, self.SCALE, self.VW)
-        got = self._run(q, pages, tbl, lens, block_pages=block_pages)
-        assert got.shape == (4, 4, self.VW) and got.dtype == np.float32
-        np.testing.assert_allclose(got, want, atol=1e-5)
+    # rows as wide as the served models' (640 lanes), 80 pages a slot:
+    # the kernel takes its block from the shapes (32 pages in float32,
+    # 64 in bfloat16), so a full slot is three or two blocks, the last
+    # of them partly past the slot's pages
+    WIDE = dict(h=4, r=640, p=P, maxp=80)
+    WIDE_LENGTHS = [0, 80 * P - 1, 33 * P + 3, None, 32 * P - 1, 64 * P]
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layout", ["pool", "stacked"])
+    @pytest.mark.parametrize("queries", [1, 2])
+    def test_kernel_matches_numpy_oracle(self, queries, layout, dtype):
+        """What the kernel does see: one or two queries a slot on the
+        head axis with a length each, a layer's pool or the stacked
+        buffer, float32 or bfloat16 rows; a slot of length 0 beside one
+        that fills its last page, several blocks a slot."""
+        vw = 512
+        q, pages, tbl, lens = _latent_state(self.WIDE_LENGTHS, seed=11,
+                                            **self.WIDE)
+        assert D._block_pages(P, 640, jnp.dtype(dtype).itemsize, 4) < 80
+        q, pages = q.astype(dtype), pages.astype(dtype)
+        if queries == 2:   # heads 0-1 at the length, 2-3 one past it
+            lens = jnp.minimum(lens[:, None] + jnp.asarray([0, 0, 1, 1]),
+                               80 * P - 1)
+        # the contract's operands: q scaled in float32, then cast to
+        # the rows' dtype; everything after it in float64
+        qs = (q.astype(jnp.float32) * self.SCALE).astype(dtype)
+        want = np.stack([
+            _latent_reference(qs[:, i:i + 1], pages, tbl,
+                              lens if lens.ndim == 1 else lens[:, i],
+                              1.0, vw)[:, 0]
+            for i in range(4)], axis=1)
+        kw = {}
+        if layout == "stacked":
+            pages, kw = _stacked(pages, 1), {"layer": 1}
+        got = np.asarray(latent_decode_attention(
+            q, pages, tbl, lens, scale=self.SCALE, value_width=vw, **kw))
+        assert got.shape == (6, 4, vw) and got.dtype == np.float32
+        np.testing.assert_allclose(
+            got, want, atol=2e-5 if dtype == "float32" else 2e-2)
 
     def test_page_edges_and_length_zero_in_one_batch(self):
         lengths = LENGTHS["page_edges"] + [0, None]
         q, pages, tbl, lens = _latent_state(lengths, seed=3)
         want = _latent_reference(q, pages, tbl, lens, self.SCALE, self.VW)
-        for bp in (MAXP, 2):
-            np.testing.assert_allclose(
-                self._run(q, pages, tbl, lens, block_pages=bp), want,
-                atol=1e-5, err_msg=f"block_pages={bp}")
+        got = self._run(q, pages, tbl, lens)
+        assert got.shape == (11, 4, self.VW) and got.dtype == np.float32
+        np.testing.assert_allclose(got, want, atol=1e-5)
 
     def test_stacked_buffer_is_read_at_its_layer(self):
         q, pages, tbl, lens = _latent_state(seed=5)
-        own = self._run(q, pages, tbl, lens, block_pages=2)
-        stacked = self._run(q, _stacked(pages, 2), tbl, lens,
-                            block_pages=2, layer=2)
+        own = self._run(q, pages, tbl, lens)
+        stacked = self._run(q, _stacked(pages, 2), tbl, lens, layer=2)
         np.testing.assert_array_equal(stacked, own)
+
+    def test_trash_page_and_unwritten_tail_never_reach_an_output(self):
+        """Huge finite values in page 0 (what unallocated table entries
+        name) and in the rows past each slot's length change no bit of
+        a live slot's output: their scores are masked before the
+        softmax, and the pages of a block past the slot's last are not
+        read at all."""
+        lengths = [0, 80 * P - 1, 33 * P + 3, None, 5]
+        q, pages, tbl, lens = _latent_state(lengths, seed=7, **self.WIDE)
+        clean = np.asarray(latent_decode_attention(
+            q, pages, tbl, lens, scale=self.SCALE, value_width=512))
+        dirty = np.array(pages)
+        dirty[0] = 1e30
+        for i, ln in enumerate(lengths):
+            if ln is not None:
+                last = int(tbl[i, ln // P])
+                dirty[last, ln % P + 1:] = -1e30
+        got = np.asarray(latent_decode_attention(
+            q, jnp.asarray(dirty), tbl, lens, scale=self.SCALE,
+            value_width=512))
+        live = np.asarray([ln is not None for ln in lengths])
+        np.testing.assert_array_equal(got[live], clean[live])
+        assert np.isfinite(got[live]).all()
+
+    def test_pages_no_table_names_are_never_read(self):
+        """NaNs in every page no table entry names change no bit: the
+        kernel copies the pages a slot's table names up to its length
+        and nothing else."""
+        lengths = [0, 70 * P, 33 * P + 3, 5]
+        q, pages, tbl, lens = _latent_state(lengths, seed=9, **self.WIDE)
+        clean = np.asarray(latent_decode_attention(
+            q, pages, tbl, lens, scale=self.SCALE, value_width=512))
+        named = np.zeros(pages.shape[0], bool)
+        named[np.asarray(tbl).ravel()] = True
+        assert (~named).sum() > 100
+        poisoned = jnp.where(named[:, None, None], pages, jnp.nan)
+        got = np.asarray(latent_decode_attention(
+            q, poisoned, tbl, lens, scale=self.SCALE, value_width=512))
+        np.testing.assert_array_equal(got, clean)
 
 
 @pytest.mark.parametrize("body", ["paged", "latent"])
@@ -237,10 +306,10 @@ def test_bucket_slice_equals_the_full_table_to_the_last_bit(body):
         full, cut = (paged_decode_attention(q, kp, vp, t, lens,
                                             page_size=P)
                      for t in (tbl, tbl[:, :bucket]))
-    else:   # a block a page: 8 trips of the loop against 2
+    else:   # the kernel's block is taken from the shapes, not the width
         q, pages, tbl, lens = _latent_state(lengths)
         full, cut = (latent_decode_attention(
-            q, pages, t, lens, scale=0.3, value_width=16, block_pages=1)
+            q, pages, t, lens, scale=0.3, value_width=16)
             for t in (tbl, tbl[:, :bucket]))
     np.testing.assert_array_equal(np.asarray(cut), np.asarray(full))
 
@@ -256,12 +325,16 @@ class TestBucketHelpers:
         assert used_page_bucket(63, 8, 8) == 8
         assert used_page_bucket(1000, 8, 8) == 8  # clamped
 
-    def test_chunk_pages(self):
-        assert D._chunk_pages(8, 0) == 8
-        assert D._chunk_pages(8, 16) == 8
-        assert D._chunk_pages(8, 3) == 2   # largest divisor <= request
-        assert D._chunk_pages(8, 4) == 4
-        assert D._chunk_pages(1, 1) == 1
+    def test_block_pages_come_from_the_shapes(self):
+        """Pages a block of the latent kernel: from the page, the row,
+        the itemsize and the head rows (never the table's width, which
+        is not an argument)."""
+        # the served models: pages of 16 rows of 640 bfloat16 lanes, 64
+        # head rows -> 32 pages (655 KB, 512 positions)
+        assert D._block_pages(16, 640, 2, 64) == 32
+        assert D._block_pages(16, 640, 4, 64) == 16     # float32 rows
+        assert D._block_pages(16, 640, 2, 512) == 8     # scores bound it
+        assert D._block_pages(4096, 640, 2, 64) == 1    # never under 1
 
     def test_decode_hbm_bytes_carries_gather_tax(self):
         b, h, d, p, maxp, item = 8, 8, 16, 16, 4, 4

@@ -232,7 +232,7 @@ def test_a_query_never_reads_past_its_own_length():
     q = jnp.asarray(rng.normal(size=(2, 6, 12)), jnp.float32)   # 2 x 3 heads
     lens = jnp.asarray([[2, 2, 2, 3, 3, 3], [5, 5, 5, 6, 6, 6]], jnp.int32)
     out = latent_decode_attention(q, pages, tables, lens, scale=0.3,
-                                  value_width=8, layer=1, block_pages=1)
+                                  value_width=8, layer=1)
     for j, n in ((0, [2, 5]), (1, [3, 6])):
         alone = latent_decode_attention(
             q[:, 3 * j:3 * j + 3], pages, tables, jnp.asarray(n, jnp.int32),
