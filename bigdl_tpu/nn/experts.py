@@ -32,7 +32,11 @@ experts are one weighted add.  There is no capacity: the row buffer
 holds every assignment, so no token is dropped at any imbalance.
 
 **Another router, and a shared expert** (``models/joyai_flash.py``;
-the arguments' defaults are the layer above, bit for bit):
+the arguments' defaults are the layer above, bit for bit; a softmax
+WITH renormalised weights, no zero-compute and no shared expert, scale
+1 and every expert held is ``models/sdar_moe.py``'s layer, run and
+guarded by ``tests/test_sdar_moe.py`` and the benchmark's
+``sdar_moe_block_gen``):
 ``score="sigmoid"`` takes ``s = sigmoid(W_r x)`` in place of the
 softmax; ``renormalise=True`` divides a token's ``top_k`` weights by
 their sum (+ 1e-20) before ``scale``, over ALL of them, held or absent,

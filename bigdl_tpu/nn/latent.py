@@ -92,6 +92,25 @@ def rotary_interleaved(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def rotary_halves(x, positions, theta: float):
+    """Rotate the pairs ``(x[i], x[i + d/2])`` of the last axis by the
+    angle ``position * theta ** (-2i / d)``: the half-split rotary of
+    the decoders with per-head keys (``models/sdar_moe.py``), where
+    :func:`rotary_interleaved` pairs neighbours.  ``positions``
+    broadcasts against ``x.shape[:-1]``.  Float32 inside, ``x``'s dtype
+    out."""
+    jnp = _jnp()
+    d = x.shape[-1]
+    inv = jnp.asarray(theta, jnp.float32) ** (
+        -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.asarray(positions)[..., None].astype(jnp.float32) * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def gated_mlp(x, gate, up, down):
     """``down (silu(gate x) * up x)`` with ``(out, in)`` matrices."""
     import jax

@@ -417,6 +417,11 @@ SERVE_DRAFT_TOKENS_TOTAL = _m(
     "Drafts verified by the decode steps of a model that drafts its own "
     "next-but-one token, by outcome: accepted (the step yielded two "
     "tokens) or rejected")
+SERVE_BLOCK_POSITIONS_TOTAL = _m(
+    "bigdl_serve_block_positions_total", "counter", ("outcome",), 2,
+    "Masked positions met by the refining passes of a model that "
+    "generates by blocks, by outcome: unmasked (the pass made the "
+    "position final) or left_masked (a later pass will)")
 SERVE_REJECTS_TOTAL = _m(
     "bigdl_serve_rejects_total", "counter",
     doc="Admissions rejected 503 + Retry-After (queue full past the "

@@ -1,17 +1,23 @@
-"""Decode attention over the paged cache: one query token a slot (in
-the latent body: or the few consecutive ones of a step that verifies a
-draft) against the rows its page table names — the serving hot path.
+"""Decode attention over the paged cache: one query token a slot (or
+the few consecutive ones of a step that verifies a draft or refines a
+block) against the rows its page table names — the serving hot path.
 
 Two bodies, one a cache kind (``serving/cache.py`` states the layouts);
 the model calls the body of the cache it states, and nothing chooses
 between them:
 
 * :func:`paged_decode_attention` — a cache of per-head K/V rows: a
-  layer's pool is ``(num_pages, P, H*Dh)``, token-major, a head a
-  range of ``Dh`` lanes of a row.  Gather the pages the table names,
-  masked softmax, weighted sum: the op sequence of
+  layer's pool is ``(num_pages, P, H_kv*Dh)``, token-major, a key/value
+  head a range of ``Dh`` lanes of a row.  Gather the pages the table
+  names, masked softmax, weighted sum: with one query a slot and a key
+  head a query head the op sequence of
   ``TransformerBlock.decode_step``, so paged decode matches
-  ``generate()`` token for token at temperature 0;
+  ``generate()`` token for token at temperature 0.  **Grouped heads
+  and several positions a slot** (``models/sdar_moe.py``: 32 query
+  heads over 4 key heads, a block of 4 positions a step) go through
+  the same body: the ``H / H_kv`` query heads and the ``S`` positions
+  that share a key head's rows are that head's query rows, all under
+  the slot's one mask;
 * :func:`latent_decode_attention` — a latent cache (``nn/latent.py``):
   one compressed row a token for all heads and no V buffer.  A Pallas
   kernel: it walks each slot's page list up to the slot's own length,
@@ -59,16 +65,21 @@ def used_page_bucket(max_length: int, page_size: int,
 
 
 def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
-                     maxp: int, kv_itemsize: int = 4) -> float:
+                     maxp: int, kv_itemsize: int = 4,
+                     kv_heads: Optional[int] = None,
+                     positions: int = 1) -> float:
     """Analytic HBM traffic of ONE layer's decode attention (the
     engine's bytes-per-token gauge): the ``2 * B * maxp`` K/V pages the
     tables name are read, and the gathered contiguous copy is written
     and read again (the gather tax), plus the f32 score plane's round
-    trip."""
+    trip.  A cached row holds ``kv_heads`` heads (default: one a query
+    head); ``q``, the output and the score plane have ``h`` heads at
+    each of a slot's ``positions``."""
     k = maxp * page_size
-    pages = 2.0 * b * maxp * page_size * h * d * kv_itemsize  # K + V
-    qio = 2.0 * b * h * d * 4                                 # q + out
-    return pages * 3 + 2.0 * b * h * k * 4 + qio
+    rows = h if kv_heads is None else kv_heads
+    pages = 2.0 * b * maxp * page_size * rows * d * kv_itemsize  # K + V
+    qio = 2.0 * b * positions * h * d * 4                     # q + out
+    return pages * 3 + 2.0 * b * positions * h * k * 4 + qio
 
 
 # --------------------------------------------------------------------------
@@ -76,60 +87,89 @@ def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
 # --------------------------------------------------------------------------
 
 
-def _head_scores(q, rows):
-    """``q·k`` per head: q ``(B, H, Dh)``, token rows ``(B, K, H*Dh)``
-    -> ``(B, H, K)``.  A head is a lane range of a row, so the rows
-    are contracted whole, on the MXU, against q laid out block-
-    diagonally (column ``h`` holds ``q[b, h]`` in head ``h``'s lanes
-    and exact zeros elsewhere): the same products and the same f32
-    accumulation as a per-head dot, with no ``(.., H*Dh) -> (.., H,
+def _key_head_of(n: int, kv_heads: int, dtype):
+    """``(kv_heads, n)`` selector: 1 where query row ``n`` reads key
+    head ``j`` (the rows are laid out key head by key head, ``n //
+    kv_heads`` of them each)."""
+    import jax.numpy as jnp
+
+    return jnp.repeat(jnp.eye(kv_heads, dtype=dtype), n // kv_heads, axis=1)
+
+
+def _head_scores(q, rows, dtype=None):
+    """``q·k`` per head: q ``(B, N, Dh)``, token rows ``(B, K,
+    H_kv*Dh)`` -> ``(B, N, K)``.  A key head is a lane range of a row,
+    so the rows are contracted whole, on the MXU, against q laid out
+    block-diagonally (column ``n`` holds ``q[b, n]`` in the lanes of its
+    key head and exact zeros elsewhere): the same products and the same
+    f32 accumulation as a per-head dot, with no ``(.., H*Dh) -> (.., H,
     Dh)`` view of the rows.  (That view is a padded relayout of every
     gathered page on the TPU — 64 -> 128 lanes, 25 -> 32 sublanes; at
     GPT-2 XL's widths it made the 48 layers' attention 16.5 ms a step
-    against 4.0 this way — chip run, PR 25.)"""
+    against 4.0 this way — chip run, PR 25.)  With a key head a query
+    row (``N == H_kv``) this is, op for op, what it was before rows
+    could share a key head; ``N > H_kv`` rows come key head by key
+    head, ``N / H_kv`` to each."""
     import jax.numpy as jnp
 
-    b, h, d = q.shape
-    eye = jnp.eye(h, dtype=q.dtype)
-    qmat = (q[:, :, :, None] * eye[None, :, None, :]).reshape(b, h * d, h)
-    return jnp.einsum("bkc,bch->bhk", rows, qmat)
+    b, n, d = q.shape
+    hkv = rows.shape[2] // d
+    if n == hkv:
+        eye = jnp.eye(n, dtype=q.dtype)
+        qmat = (q[:, :, :, None] * eye[None, :, None, :]).reshape(
+            b, n * d, n)
+        return jnp.einsum("bkc,bch->bhk", rows, qmat)
+    sel = _key_head_of(n, hkv, q.dtype)                     # (H_kv, N)
+    qmat = (q.transpose(0, 2, 1)[:, None, :, :]
+            * sel[None, :, None, :]).reshape(b, hkv * d, n)
+    return jnp.einsum("bkc,bcn->bnk", rows, qmat,
+                      preferred_element_type=dtype)
 
 
-def _head_mix(probs, rows):
-    """``probs·v`` per head: probs ``(B, H, K)``, token rows ``(B, K,
-    H*Dh)`` -> ``(B, H, Dh)``.  Every head's weights meet the whole
-    row on the MXU; head ``h`` keeps its own ``Dh`` lanes of the
-    result (the other blocks are dropped, not summed in)."""
+def _head_mix(probs, rows, d: int):
+    """``probs·v`` per head: probs ``(B, N, K)``, token rows ``(B, K,
+    H_kv*Dh)`` -> ``(B, N, Dh)``.  Every query row's weights meet the
+    whole row on the MXU; row ``n`` keeps its key head's ``Dh`` lanes of
+    the result (the other blocks are dropped, not summed in)."""
     import jax.numpy as jnp
 
-    b, h, _ = probs.shape
-    d = rows.shape[2] // h
-    full = jnp.einsum("bhk,bkc->bhc", probs, rows)        # (B, H, H*Dh)
-    eye = jnp.eye(h, dtype=full.dtype)
-    return jnp.sum(full.reshape(b, h, h, d) * eye[None, :, :, None],
+    b, n, _ = probs.shape
+    hkv = rows.shape[2] // d
+    full = jnp.einsum("bhk,bkc->bhc", probs, rows)        # (B, N, H_kv*Dh)
+    keep = jnp.eye(n, dtype=full.dtype) if n == hkv \
+        else _key_head_of(n, hkv, full.dtype).T           # (N, H_kv)
+    return jnp.sum(full.reshape(b, n, hkv, d) * keep[None, :, :, None],
                    axis=2)
 
 
 def paged_decode_attention(q, kp, vp, tables, lengths, *,
                            page_size: int, scale: Optional[float] = None,
-                           layer: Optional[int] = None):
+                           layer: Optional[int] = None, score_dtype=None):
     """One decode-attention step over a paged cache of per-head K/V
     rows.
 
-    q: ``(B, H, Dh)`` — one query token per slot.
-    kp/vp: ``(num_pages, P, H*Dh)`` — one layer's page pool, token-
-    major (serving/cache.py ``pool_shape``); or, with ``layer``, the
-    engine's stacked ``(n_layer, num_pages, P, H*Dh)`` buffers, read
-    in place (a ``kp[layer]`` handed in instead costs a copy of the
-    layer's pool on the TPU).
+    q: ``(B, H, Dh)`` — one query token per slot; or ``(B, S, H, Dh)``
+    — ``S`` positions a slot (a block being refined), which all attend
+    the same rows.
+    kp/vp: ``(num_pages, P, H_kv*Dh)`` — one layer's page pool, token-
+    major (serving/cache.py ``pool_shape``), ``H_kv`` dividing ``H``
+    (query head ``h`` reads key head ``h // (H / H_kv)``); or, with
+    ``layer``, the engine's stacked ``(n_layer, num_pages, P,
+    H_kv*Dh)`` buffers, read in place (a ``kp[layer]`` handed in
+    instead costs a copy of the layer's pool on the TPU).
     tables: ``(B, maxp)`` int32 page table (maxp may be the engine's
     used-page bucket, not the full table width); lengths: ``(B,)``
-    int32 — position ``pos <= length`` attends.
+    int32 — position ``pos <= length`` attends, for every query of the
+    slot (a block's step gives its last position: the block's rows are
+    written before it attends).
+    ``score_dtype``: the scores' and the softmax's dtype where rows
+    share a key head (None: the operands').
 
-    Gather + masked softmax — the op sequence of
-    ``TransformerBlock.decode_step`` (scores, ``-inf`` mask, softmax,
-    weighted sum, in the same dtypes) on the token-major cache, so the
-    temperature-0 token-match contract vs ``generate()`` holds.
+    Gather + masked softmax — for ``(B, H, Dh)`` and ``H_kv == H`` the
+    op sequence of ``TransformerBlock.decode_step`` (scores, ``-inf``
+    mask, softmax, weighted sum, in the same dtypes) on the token-major
+    cache, so the temperature-0 token-match contract vs ``generate()``
+    holds.  Returns ``q``'s shape.
     """
     import jax
     import jax.numpy as jnp
@@ -137,16 +177,31 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
     from bigdl_tpu.serving.cache import gather_pages
 
     del page_size  # the pool's own (its rows are gathered whole)
+    d = q.shape[-1]
     if scale is None:
-        scale = q.shape[-1] ** -0.5
-    kall = gather_pages(kp, tables, layer)    # (B, maxp*P, H*Dh)
+        scale = d ** -0.5
+    shape = q.shape
+    if q.ndim == 4:
+        # key head by key head: its H / H_kv query heads at each of the
+        # S positions are its query rows
+        b, s, h, _ = shape
+        hkv = kp.shape[-1] // d
+        q = q.reshape(b, s, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(b, s * h, d)
+    kall = gather_pages(kp, tables, layer)    # (B, maxp*P, H_kv*Dh)
     vall = gather_pages(vp, tables, layer)
-    scores = _head_scores(q, kall) * scale    # (B, H, maxp*P)
+    scores = _head_scores(q, kall, score_dtype) * scale   # (B, N, maxp*P)
     mask = (jnp.arange(kall.shape[1])[None, None, :]
             <= lengths[:, None, None])
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    return _head_mix(probs, vall)
+    if score_dtype is not None:
+        probs = probs.astype(vall.dtype)
+    out = _head_mix(probs, vall, d)
+    if len(shape) == 4:
+        out = out.reshape(b, hkv, s, h // hkv, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(shape)
+    return out
 
 
 # --------------------------------------------------------------------------

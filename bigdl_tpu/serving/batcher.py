@@ -56,6 +56,13 @@ class ServeRequest:
     # draft a step verified, (index in ``tokens`` of the token it was
     # checked against, the draft)
     drafts: List[tuple] = dataclasses.field(default_factory=list)
+    # under a model that generates by blocks: for every generated
+    # position, in order (those of the last block that the answer cuts
+    # off included), (its token, the pass of its block that unmasked
+    # it); the pass is -1 where the position was still masked when the
+    # request ended and -2 where a preemption made it part of the
+    # prompt (serving/engine.py NEVER_UNMASKED, GIVEN)
+    unmasked: List[tuple] = dataclasses.field(default_factory=list)
     result: Optional[np.ndarray] = None  # classifier output row(s)
     error: Optional[str] = None
     # request-trace context (obs.reqtrace.RequestTraceContext) when the

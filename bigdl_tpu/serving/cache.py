@@ -5,7 +5,7 @@ cache per layer, sized for the *longest possible* sequence and owned by
 the whole batch for the whole decode — a request that finishes early
 keeps its columns hot until the slowest batchmate drains.  Serving
 needs the vLLM-style alternative: K/V live in fixed-size **pages**
-(``page_size`` token rows of ``n_head * Dh``), each request owns only
+(``page_size`` token rows of ``kv_heads * Dh``), each request owns only
 the pages its tokens actually fill (a per-slot **page table**), pages
 return to a free list the moment a request completes, and a new
 request is admitted into the freed slot at the next step boundary.
@@ -20,14 +20,17 @@ the repo (the engine's, the tests', the smokes') is built,
 written and read through them:
 
 * ``kp``/``vp``: ``(n_layer, num_pages, page_size, row)`` device
-  arrays, ``row`` the width the model states (``n_head * head_dim``
-  for per-head K/V; ``kv_rank + rope`` for a latent cache; ``n_layer``
-  counts cached attentions, two a layer in ``models/longcat_flash.py``) in the cache dtype (defaults to the model dtype — bf16
+  arrays, ``row`` the width the model states (``kv_heads * head_dim``
+  for per-head K/V, ``kv_heads`` the key/value heads: as many as query
+  heads in ``models/transformer.py``, 4 under 32 query heads in
+  ``models/sdar_moe.py``; ``kv_rank + rope`` for a latent cache;
+  ``n_layer`` counts cached attentions, two a layer in
+  ``models/longcat_flash.py``) in the cache dtype (defaults to the model dtype — bf16
   weights get a bf16 cache, halving decode HBM traffic).  The layout is
   **token-major**: one row is one token's K (or V) for all heads, as
   the projection produces it, so a page is ``page_size`` whole rows.
   On the TPU that is the shape the chip works on as it lies — the
-  ``n_head * head_dim`` lanes are full (1600 of 1664 for GPT-2 XL), a
+  ``kv_heads * head_dim`` lanes are full (1600 of 1664 for GPT-2 XL), a
   page's 16 rows fill a bf16 tile — so the buffer the engine donates
   to the decode step and the prefill is the buffer those programs
   update in place: no layout conversion in or out, no padded twin
@@ -58,7 +61,7 @@ from bigdl_tpu.obs import names
 class PagedKVCache:
     """Host-side page allocator + device-side paged K/V buffers."""
 
-    def __init__(self, n_layer: int, n_head: Optional[int] = None,
+    def __init__(self, n_layer: int, kv_heads: Optional[int] = None,
                  head_dim: Optional[int] = None, *,
                  row_width: Optional[int] = None, buffers: int = 2,
                  page_size: int = 16, num_pages: int = 64,
@@ -69,13 +72,15 @@ class PagedKVCache:
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if row_width is None:
-            if n_head is None or head_dim is None:
-                raise ValueError("give row_width, or n_head and head_dim")
-            row_width = int(n_head) * int(head_dim)
+            if kv_heads is None or head_dim is None:
+                raise ValueError("give row_width, or kv_heads and head_dim")
+            row_width = int(kv_heads) * int(head_dim)
         if buffers not in (1, 2):
             raise ValueError(f"buffers must be 1 or 2, got {buffers}")
         self.n_layer = int(n_layer)
-        self.n_head = None if n_head is None else int(n_head)
+        #: key/value heads a cached row holds (the query heads may be a
+        #: multiple of them)
+        self.kv_heads = None if kv_heads is None else int(kv_heads)
         self.head_dim = None if head_dim is None else int(head_dim)
         self.row_width = int(row_width)
         self.page_size = int(page_size)
@@ -206,27 +211,30 @@ class PagedKVCache:
         return self.max_pages_per_slot * self.page_size
 
 
-def pool_shape(num_pages: int, page_size: int, n_head: int,
+def pool_shape(num_pages: int, page_size: int, kv_heads: int,
                head_dim: int, n_layer: Optional[int] = None) -> tuple:
     """Shape of one layer's K (or V) page pool, ``(num_pages,
-    page_size, n_head * head_dim)``; with ``n_layer`` the engine's
-    stacked ``(n_layer, ...)`` buffer.  The one statement of the
-    layout: everything that builds a cache asks here."""
-    pool = (int(num_pages), int(page_size), int(n_head) * int(head_dim))
+    page_size, kv_heads * head_dim)``, ``kv_heads`` the KEY/VALUE heads
+    (a model with grouped heads caches fewer than it has query heads);
+    with ``n_layer`` the engine's stacked ``(n_layer, ...)`` buffer.
+    The one statement of the layout: everything that builds a cache
+    asks here."""
+    pool = (int(num_pages), int(page_size), int(kv_heads) * int(head_dim))
     return pool if n_layer is None else (int(n_layer),) + pool
 
 
 def write_token_rows(pages, layer: int, tables, lengths, rows):
     """Decode write: slot ``b``'s new token row ``rows[b]`` (``(B,
-    n_head * head_dim)``, the projection's output as it comes) lands
+    kv_heads * head_dim)``, the projection's output as it comes) lands
     at position ``lengths[b]`` of its table — exactly the one row
     ``pages[layer, page, slot_in_page, :]``; no other byte changes.
     Inactive slots (length 0, trash table row) write the trash page.
 
     ``rows`` ``(B, Q, row)`` are ``Q`` consecutive tokens a slot, at
     positions ``lengths[b] + 0 .. Q-1`` (a step that verifies a draft
-    writes two), still one scatter; the engine names the page of the
-    last of them before it dispatches the step."""
+    writes two, a step that refines a block its block's), still one
+    scatter; the engine names the page of the last of them before it
+    dispatches the step."""
     import jax.numpy as jnp
 
     page_size = pages.shape[2]
@@ -243,7 +251,7 @@ def write_token_rows(pages, layer: int, tables, lengths, rows):
 
 
 def write_prompt_pages(pages, layer: int, page_ids, rows):
-    """Prefill write: ``rows`` (``(T, n_head * head_dim)``, ``T`` a
+    """Prefill write: ``rows`` (``(T, kv_heads * head_dim)``, ``T`` a
     multiple of the page size) fills the pages ``page_ids`` (``(T //
     page_size,)``) in order, as ONE scatter.  Never a loop of per-page
     updates: on the TPU that makes the compiler convert the whole

@@ -52,7 +52,25 @@ that with the production shape:
   reads the step's tokens and emitted counts one step late.  A slot
   that owed nothing on the device in a step already dispatched is a
   wasted row, as after an EOS.  Same loop, same settles; greedy only
-  (a temperature on such a model is refused at ``submit``).
+  (a temperature on such a model is refused at ``submit``);
+* **a step that refines a block a slot**: a model that generates by
+  blocks (``block_spec``; ``models/sdar_moe.py``) has each step forward
+  ``B`` positions a slot.  A pass unmasks between 1 and ``B`` of them
+  by confidence; a block takes 1 to ``T`` passes and then one that
+  commits its rows, and only then does the slot's length advance, by
+  ``B``.  How many positions a pass unmasked is known on the device
+  one step before the host reads it, so the block's tokens, its mask
+  flags, its pass count and the slot's length are device arrays
+  carried from step to step, as a draft is (a freshly admitted slot's
+  come from the host, selected inside the program); the host keeps
+  bounds (pages and the bucket are named for the end of the block
+  after the one it last saw, a slot runs until its request is done)
+  and reconciles when it reads.  **Emission is by prefix**: a token is
+  handed to its request by the step after which it and every position
+  before it are final, so a step yields 0 to ``B`` tokens a slot and a
+  commit none; ``ServeRequest.unmasked`` keeps every generated
+  position's token and the pass that unmasked it.  Same loop, same
+  settles; greedy only.
 
 **What the engine asks of a model** (``models/transformer.py`` and
 ``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
@@ -71,7 +89,20 @@ tokens ``(N,)``) and returns tokens, not logits:
 ``paged_decode(params, caches, tables, lengths, tokens, drafts, owed,
 active, pick=)`` -> ``(caches, picked (B, 2), accepted (B,), next_draft
 (B,), counts)``.  A model that declares no draft runs the programs it
-always ran.  ``int8=True`` needs the model's
+always ran.  **A model that generates by blocks**
+(``models/sdar_moe.py``) answers ``block_spec(params)``
+(``block_length``, ``passes``, ``threshold``) instead and owns the
+rule by which a pass unmasks: ``paged_prefill(..., pick=)`` ->
+``(caches, (tokens (B,), masked (B,)), counts)``, the first block's
+state and no token (what the prompt's whole blocks leave over sits,
+fixed, at that block's head), and ``paged_decode(params, caches,
+tables, lengths, tokens (S, B), masked (S, B), passes (S,), active,
+pick=)`` -> ``(caches, (tokens, masked, passes, lengths) after the
+step, kind (S,), counts)``: it forwards every slot's block, writes its
+rows at ``lengths + 0 .. B-1``, unmasks where a position is masked and
+commits (``lengths + B``, a new block all masked) where none is; the
+engine carries that state to the next step untouched.  ``int8=True``
+needs the model's
 ``quantize_for_decode``, ``tp > 1`` its ``tp_decode_step``; a model
 without them is refused with a ``ValueError`` that says so.
 
@@ -155,12 +186,17 @@ class _Active:
     whenever nothing is in flight.  ``first_token`` (and a drafting
     model's ``first_draft``) is the prefill's until the slot's first
     decode step has taken it from the host, then None: the slot's
-    input is the previous step's output, on the device.  ``unread`` is
+    input is the previous step's output, on the device.  Under a model
+    that generates by blocks nothing is counted at dispatch
+    (``remaining`` is ``left``), ``block`` is the host's view of the
+    slot's current block as of the last step read, and ``first_token``
+    only marks the slot as fresh.  ``unread`` is
     1 while a step the slot ran in has not been read; ``last_pos`` is
     the last position the request can ever write a row at."""
 
     __slots__ = ("req", "remaining", "left", "first_token", "first_draft",
-                 "prompt_len", "last_pos", "unread", "t_admit", "order")
+                 "prompt_len", "last_pos", "unread", "t_admit", "order",
+                 "block")
 
     def __init__(self, req, remaining, first_token, prompt_len, order,
                  first_draft=None):
@@ -168,11 +204,36 @@ class _Active:
         self.remaining = self.left = remaining
         self.first_token = first_token
         self.first_draft = first_draft
+        self.block: Optional[_Block] = None
         self.prompt_len = prompt_len
         self.last_pos = prompt_len + remaining
         self.unread = 0
         self.t_admit = time.monotonic()
         self.order = order
+
+
+class _Block:
+    """The host's view of a slot's current block, as of the last step
+    read: its tokens, which positions are still masked, the pass that
+    unmasked each (-1: none yet), and ``shown``, how many of its
+    leading positions are with the request already (emitted, or the
+    prompt's: ``origin`` is the position the request's first generated
+    token has)."""
+
+    __slots__ = ("tokens", "masked", "passes", "shown", "origin")
+
+    def __init__(self, tokens, masked, origin: int):
+        self.tokens = np.array(tokens, np.int32)
+        self.masked = np.array(masked, bool)
+        self.passes = np.full(self.tokens.shape, -1, np.int32)
+        self.shown = int(np.sum(~self.masked))
+        self.origin = origin
+
+    def renew(self):
+        self.tokens[:] = 0
+        self.masked[:] = True
+        self.passes[:] = -1
+        self.shown = 0
 
 
 class _InFlight:
@@ -193,18 +254,31 @@ class _InFlight:
 #: how many of them the step yields (0 where the slot owed nothing), the
 #: draft it verified and the slot's length before the step
 DRAFT_RESULT = ("first", "second", "emitted", "draft", "length")
+#: what follows the block's tokens and its mask flags in a row of a
+#: block step's result: the slot's length before the step, what the
+#: step did (the model's ``kind``: 0 nothing, then the two below) and
+#: the pass index it ran
+BLOCK_RESULT = ("length", "kind", "pass")
+BLOCK_REFINED, BLOCK_COMMITTED = 1, 2
+#: ``ServeRequest.unmasked``'s pass for a position that was still masked
+#: when its request ended, and for one that was with the request when a
+#: preemption folded it into the prompt (the state that chose it is gone)
+NEVER_UNMASKED, GIVEN = -1, -2
 
 
 class _StepRead:
     """A read step on the host: ``tokens`` (B, k), ``emitted`` (B,)
     tokens each slot yields (None: one each), a drafting model's
-    verified ``drafts`` by slot, and the span's attributes."""
+    verified ``drafts`` by slot, a block model's ``blocks`` by slot
+    (mask flags after the step, what the step did, its pass index), and
+    the span's attributes."""
 
-    __slots__ = ("tokens", "emitted", "drafts", "attrs")
+    __slots__ = ("tokens", "emitted", "drafts", "blocks", "attrs")
 
-    def __init__(self, tokens, emitted, drafts, attrs):
+    def __init__(self, tokens, emitted, drafts, attrs, blocks=None):
         self.tokens, self.emitted = tokens, emitted
         self.drafts, self.attrs = drafts, attrs
+        self.blocks = blocks or {}
 
 
 class LMEngine:
@@ -252,13 +326,25 @@ class LMEngine:
         if self._tokens_per_step not in (1, 2):
             raise ValueError("a step verifies one draft a slot at most")
         self._drafts = self._tokens_per_step > 1
+        # ... or generates by blocks: positions a step forwards a slot
+        self._block = int(model.block_spec(self.params)["block_length"]
+                          if hasattr(model, "block_spec") else 0)
+        if self._block and (self._drafts or self.int8 or self.tp > 1):
+            raise ValueError("a model that generates by blocks neither "
+                             "drafts nor offers int8 or tp decode")
         self.max_len = int(spec["max_len"])
+        if self._block and (self.page_size % self._block
+                            or self.max_len % self._block):
+            raise ValueError(
+                f"blocks of {self._block} do not divide the page size "
+                f"{self.page_size} and the longest context {self.max_len}")
         if cache_dtype is None:
             cache_dtype = spec["dtype"]
         pages = num_pages or cfg.num_pages or (
             1 + self.max_batch * -(-self.max_len // self.page_size))
         self.cache = PagedKVCache(
-            int(spec["layers"]), spec.get("heads"), spec.get("head_dim"),
+            int(spec["layers"]), spec.get("kv_heads", spec.get("heads")),
+            spec.get("head_dim"),
             row_width=int(spec["row_width"]), buffers=int(spec["buffers"]),
             page_size=self.page_size, num_pages=pages,
             max_slots=self.max_batch, max_len=self.max_len,
@@ -295,7 +381,13 @@ class LMEngine:
         # owed count
         zeros = jnp.zeros((self.max_batch,), jnp.int32)
         self._carry = (zeros,) * (4 if self._drafts else 1)
+        if self._block:
+            # a block model's: tokens, mask flags, pass count, length
+            wide = jnp.zeros((self.max_batch, self._block), jnp.int32)
+            self._carry = (wide, wide.astype(bool), zeros, zeros)
         self._draft_verified = self._draft_accepted = 0
+        self._block_passes = self._block_commits = 0
+        self._positions_unmasked = 0
         self._slot_steps = self._step_tokens = 0
         self._steps_ahead = 0
         self._settles = dict.fromkeys(SETTLE_REASONS, 0)
@@ -364,6 +456,10 @@ class LMEngine:
             names.SERVE_DRAFT_TOKENS_TOTAL,
             "Drafts a self-drafting model's steps verified, by outcome",
             labels=("outcome",)) if self._drafts else None
+        self._block_counter = reg.counter(
+            names.SERVE_BLOCK_POSITIONS_TOTAL,
+            "Masked positions a block model's refining passes met, by "
+            "outcome", labels=("outcome",)) if self._block else None
         self._swap_counter = reg.counter(
             names.SERVE_WEIGHT_SWAPS_TOTAL,
             "Live weight hot-swaps completed, by promoted version",
@@ -510,6 +606,36 @@ class LMEngine:
                 out = (*caches, nxt, next_draft, length + emitted,
                        owed - emitted, result)
                 return out if counts is None else (*out, counts)
+        elif self._block:
+            def step(params, *rest):
+                # rest: the cache's buffers (donated), then tables, the
+                # host's lengths, the four arrays the last step carried
+                # (block tokens, mask flags, pass count, length), the
+                # host's block for the slots in fresh (admitted since
+                # that step; their pass count is 0), active
+                (tables, h_len, c_tok, c_mask, c_pass, c_len,
+                 h_tok, h_mask, fresh, active) = rest[n:]
+                tok = jnp.where(fresh[:, None], h_tok, c_tok)
+                mask = jnp.where(fresh[:, None], h_mask, c_mask)
+                done = jnp.where(fresh, 0, c_pass)
+                length = jnp.where(fresh, h_len, c_len)
+                # an inactive slot computes a wasted block at position 0
+                # of the trash page
+                caches, state, kind, counts = model.paged_decode(
+                    params, rest[:n], tables, jnp.where(active, length, 0),
+                    tok, mask, done, active, pick=pick_greedy,
+                    page_size=page_size, qparams=qparams)
+                new_tok, new_mask, new_pass, new_len = state
+                # where the step committed, the host wants the block it
+                # committed, not the fresh one behind it
+                kept = kind[:, None] == BLOCK_COMMITTED
+                result = jnp.concatenate(
+                    [jnp.where(kept, tok, new_tok),
+                     jnp.where(kept, mask, new_mask).astype(jnp.int32),
+                     jnp.stack([length, kind, done], axis=1)], axis=1)
+                out = (*caches, new_tok, new_mask, new_pass,
+                       jnp.where(active, new_len, length), result)
+                return out if counts is None else (*out, counts)
         else:
             def step(params, *rest):
                 # rest: the cache's buffers (donated), then tables, lengths,
@@ -548,6 +674,17 @@ class LMEngine:
                 pair = jax.numpy.stack([first, draft])
                 return (*caches, pair) if counts is None \
                     else (*caches, pair, counts)
+        elif self._block:
+            def prefill(params, *rest):
+                # as below; no token is picked: the prompt's whole blocks
+                # are cached, and the first block's state comes back
+                prompt, t0, pages = rest[n:n + 3]
+                caches, (tokens, masked), counts = model.paged_prefill(
+                    params, rest[:n], prompt, t0, pages, pick=pick_greedy)
+                first = jax.numpy.stack(
+                    [tokens, masked.astype(tokens.dtype)])
+                return (*caches, first) if counts is None \
+                    else (*caches, first, counts)
         else:
             def prefill(params, *rest):
                 # rest: the cache's buffers (donated), then the prompt
@@ -590,6 +727,11 @@ class LMEngine:
             raise ValueError(
                 f"{type(self.model).__name__} verifies its own drafts by "
                 "exact match against the greedy token: temperature "
+                f"{temperature:g} is not served (give 0)")
+        if self._block and float(temperature) > 0.0:
+            raise ValueError(
+                f"{type(self.model).__name__} unmasks a block's positions "
+                "by the confidence of the greedy token: temperature "
                 f"{temperature:g} is not served (give 0)")
         # feasibility: a request that can NEVER fit the page pool even
         # alone would preempt-loop forever — reject it at the door
@@ -684,33 +826,51 @@ class LMEngine:
                 float(req.temperature), sub)
             self.cache.set_buffers(out[:n])
             self.cache.lengths[slot] = t0
-            # a drafting model's first token comes with its first draft
-            tok, draft = (int(t) for t in np.asarray(out[n])) \
-                if self._drafts else (int(out[n]), None)
+            # a drafting model's first token comes with its first draft;
+            # a block model's prefill yields its first block and no token
+            first = np.asarray(out[n])
+            tok, draft = (int(t) for t in first) if self._drafts \
+                else (None, None) if self._block else (int(first), None)
             if len(out) > n + 1:
                 tracer.add_attrs(span_id, **self._note_routing(out[n + 1]))
         if req.trace is not None:
             req._tr_admits.append(
                 {"t": t_admit, "dur": time.monotonic() - t_admit,
                  "bucket": bucket, "prompt_len": t0, "slot": slot})
+        if self._t_first_work is None:
+            self._t_first_work = time.monotonic()
+        self._order += 1
+        if self._block:
+            # the slot's length is its block's first position; every
+            # generated position so far is on record (a preemption
+            # folded them into the prompt)
+            b = self._block
+            self.cache.lengths[slot] = t0 - t0 % b
+            act = _Active(req, req.max_new_tokens, -1, t0, self._order)
+            act.last_pos = -(-(t0 + req.max_new_tokens) // b) * b - 1
+            act.block = _Block(first[0], first[1],
+                               origin=t0 - len(req.unmasked))
+        else:
+            self._first_token(req)
+            req.tokens.append(tok)
+            req.token_times.append(time.perf_counter())
+            self._tokens_total += 1
+            self._tokens_counter.inc()
+            act = _Active(req, req.max_new_tokens - 1, tok, t0, self._order,
+                          first_draft=draft)
+        self._slots[slot] = act
+        tracer.event(spans.EVENT_ADMIT, slot=slot, request=req.id,
+                     prompt_len=t0, bucket=bucket)
+        if tok is not None and (act.remaining <= 0 or tok == self.eos_id):
+            self._complete(slot)
+
+    def _first_token(self, req: ServeRequest):
+        """Stamp a request's first token (once: a preempted request's
+        second admission has it)."""
         if req.t_first is None:
             req.t_first = time.monotonic()
             self._lat.labels(engine="lm", kind="ttft").observe(
                 req.t_first - req.t_submit)
-        req.tokens.append(tok)
-        req.token_times.append(time.perf_counter())
-        self._tokens_total += 1
-        self._tokens_counter.inc()
-        if self._t_first_work is None:
-            self._t_first_work = time.monotonic()
-        self._order += 1
-        act = _Active(req, req.max_new_tokens - 1, tok, t0, self._order,
-                      first_draft=draft)
-        self._slots[slot] = act
-        tracer.event(spans.EVENT_ADMIT, slot=slot, request=req.id,
-                     prompt_len=t0, bucket=bucket)
-        if act.remaining <= 0 or tok == self.eos_id:
-            self._complete(slot)
 
     def _preempt_youngest(self) -> Optional[int]:
         """Free the youngest active slot's pages; its request re-queues
@@ -729,9 +889,13 @@ class LMEngine:
         # still-owed budget becomes the new max_new_tokens (req.tokens
         # keeps everything, so the client sees one contiguous output)
         gen = req.max_new_tokens - act.remaining
-        req.payload = list(req.payload) + [int(t) for t in
-                                           req.tokens[-gen:]]
+        req.payload = list(req.payload) + [
+            int(t) for t in req.tokens[len(req.tokens) - gen:]]
         req.max_new_tokens = act.remaining
+        if act.block is not None:
+            # what the block has shown is the request's; the rest of it
+            # is generated again
+            self._record_block(act, slot, GIVEN)
         self.cache.release(slot)
         self._slots[slot] = None
         self._stash.appendleft(req)
@@ -745,6 +909,8 @@ class LMEngine:
     # ---------------------------------------------------------------- step
     def _complete(self, slot: int, error: Optional[str] = None):
         act = self._slots[slot]
+        if act.block is not None:
+            self._record_block(act, slot)
         self.cache.release(slot)
         self._slots[slot] = None
         req = act.req
@@ -866,12 +1032,21 @@ class LMEngine:
             active = np.zeros((self.max_batch,), bool)
             drafts = np.zeros((self.max_batch,), np.int32)
             owed = np.zeros((self.max_batch,), np.int32)
+            if self._block:
+                tokens = np.zeros((self.max_batch, self._block), np.int32)
+                masked = np.zeros((self.max_batch, self._block), bool)
             for i in running:
                 act = self._slots[i]
                 if act.first_token is not None:
                     # admitted since the last step: its input is the
-                    # prefill's token, from the host, this once
-                    tokens[i], fresh[i] = act.first_token, True
+                    # prefill's token (a block model's: its first
+                    # block), from the host, this once
+                    fresh[i] = True
+                    if self._block:
+                        tokens[i], masked[i] = (act.block.tokens,
+                                                act.block.masked)
+                    else:
+                        tokens[i] = act.first_token
                     if self._drafts:
                         drafts[i], owed[i] = act.first_draft, act.left
                     act.first_token = act.first_draft = None
@@ -899,6 +1074,9 @@ class LMEngine:
                 host = (jnp.asarray(tokens), jnp.asarray(drafts),
                         jnp.asarray(owed), jnp.asarray(fresh),
                         jnp.asarray(active))
+            elif self._block:
+                host = (jnp.asarray(tokens), jnp.asarray(masked),
+                        jnp.asarray(fresh), jnp.asarray(active))
             else:
                 host = (jnp.asarray(tokens), jnp.asarray(fresh),
                         jnp.asarray(temps), jnp.asarray(active), sub)
@@ -910,20 +1088,23 @@ class LMEngine:
             self._carry = out[n:n + k]
             # what the host reads: the tokens (a one-token model's are
             # the carry itself), and an expert model's counts
-            result = out[n + k:] if self._drafts else out[n:]
+            result = out[n + k:] if self._drafts or self._block \
+                else out[n:]
             for arr in result:
                 # on their way to the host as soon as they exist, not
                 # when the next pump asks for them
                 arr.copy_to_host_async()
             # the host's state advances at dispatch, by the one token a
             # step yields at least: the next prep (growth, bucket, who
-            # runs) needs no token
+            # runs) needs no token.  (A block's step may yield none:
+            # that host's state advances when the step is read.)
             entries, context = [], 0
+            sure = 0 if self._block else 1
             for i in running:
                 act = self._slots[i]
-                self.cache.lengths[i] += 1
+                self.cache.lengths[i] += sure
                 context += int(self.cache.lengths[i])
-                act.remaining -= 1
+                act.remaining -= sure
                 act.unread += 1
                 entries.append((i, act))
             self._inflight = _InFlight(
@@ -941,13 +1122,17 @@ class LMEngine:
             self._decode_ms_gauge.set(self._decode_ms_sum / self._steps)
             kv_item = self.cache.dtype.itemsize
             # without per-head rows a row is one head; one buffer is
-            # half of K + V
+            # half of K + V; a row holds the key/value heads, the
+            # queries and the output the query heads at each position
             heads = self._cache_spec.get("heads", 1)
+            kv_heads = self._cache_spec.get("kv_heads", heads)
             step_bytes = self._weight_bytes + \
                 self.cache.n_layer * len(self.cache.buffers()) / 2.0 \
                 * decode_hbm_bytes(
-                    self.max_batch, heads, self.cache.row_width // heads,
-                    self.page_size, bucket, kv_item)
+                    self.max_batch, heads,
+                    self.cache.row_width // kv_heads, self.page_size,
+                    bucket, kv_item, kv_heads=kv_heads,
+                    positions=self._block or 1)
             self._decode_bytes_gauge.set(step_bytes / len(running))
             self._occ_sum += len(running) / self.max_batch
             self._occ_gauge.set(self._occ_sum / self._steps)
@@ -967,11 +1152,15 @@ class LMEngine:
         """How far past its (lower-bound) length the slot's next step
         may write: 0 for a one-token model; a drafting model's step
         writes a second row, and a step not yet read may have taken its
-        draft.  Never past the request's last position."""
-        if not self._drafts:
+        draft; a block's step writes its block's rows, and every step
+        not yet read may have committed a block.  Never past the
+        request's last position."""
+        if not (self._drafts or self._block):
             return 0
         act = self._slots[slot]
-        return max(0, min(1 + act.unread,
+        reach = self._block * (1 + act.unread) - 1 if self._block \
+            else 1 + act.unread
+        return max(0, min(reach,
                           act.last_pos - int(self.cache.lengths[slot])))
 
     def _read(self, rec: _InFlight) -> _StepRead:
@@ -981,6 +1170,8 @@ class LMEngine:
         that step had to read."""
         res = np.asarray(rec.result)
         attrs, drafts = {}, {}
+        if self._block:
+            return self._read_block(rec, res)
         if self._drafts:
             first, second, emitted, draft, length = res.T  # DRAFT_RESULT
             toks = np.stack([first, second], axis=1)
@@ -1013,6 +1204,87 @@ class LMEngine:
                          context_tokens=context)
         return _StepRead(toks, emitted, drafts, attrs)
 
+    def _read_block(self, rec: _InFlight, res) -> _StepRead:
+        """A block model's read: what each live slot's step did, how
+        many positions it unmasked and how many tokens that shows (the
+        unmasked prefix beyond what is shown already, up to the
+        request's last token or an EOS)."""
+        b = self._block
+        toks, after = res[:, :b], res[:, b:2 * b].astype(bool)
+        length, kind, done = res[:, 2 * b:].T          # BLOCK_RESULT
+        emitted = np.zeros((self.max_batch,), np.int32)
+        blocks = {}
+        passes = commits = unmasked = left = context = 0
+        for slot, act in rec.entries:
+            if self._slots[slot] is not act or not kind[slot]:
+                continue    # completed since: a wasted block
+            blocks[slot] = (after[slot], int(kind[slot]), int(done[slot]))
+            context += int(length[slot]) + b
+            if kind[slot] == BLOCK_COMMITTED:
+                commits += 1
+                continue
+            passes += 1
+            unmasked += int(np.sum(act.block.masked & ~after[slot]))
+            left += int(np.sum(after[slot]))
+            shown = act.block.shown
+            prefix = b if not after[slot].any() \
+                else int(np.argmax(after[slot]))
+            n = max(0, min(prefix - shown, act.left))
+            for j in range(n):
+                if int(toks[slot, shown + j]) == self.eos_id:
+                    n = j + 1
+                    break
+            emitted[slot] = n
+        self._block_passes += passes
+        self._block_commits += commits
+        self._positions_unmasked += unmasked
+        self._block_counter.labels(outcome="unmasked").inc(unmasked)
+        self._block_counter.labels(outcome="left_masked").inc(left)
+        attrs = dict(block_passes=passes, block_commits=commits,
+                     positions_unmasked=unmasked,
+                     tokens_emitted=int(emitted.sum()))
+        if rec.counts is not None:
+            attrs.update(self._note_routing(rec.counts),
+                         context_tokens=context)
+        return _StepRead(toks, emitted, {}, attrs, blocks)
+
+    def _advance_block(self, slot: int, act: _Active, read: _StepRead):
+        """Bring the host's view of ``slot``'s block up to the step
+        read; returns the block position its shown tokens start at."""
+        after, kind, done = read.blocks[slot]
+        blk = act.block
+        if kind == BLOCK_COMMITTED:
+            # committed: on record, the length advances, a new block
+            self._record_block(act, slot)
+            self.cache.lengths[slot] += self._block
+            blk.renew()
+            return 0
+        newly = blk.masked & ~after
+        blk.tokens[newly] = read.tokens[slot][newly]
+        blk.passes[newly] = done
+        blk.masked = after.copy()
+        start = blk.shown
+        blk.shown += int(read.emitted[slot])
+        return start
+
+    def _record_block(self, act: _Active, slot: int, given=None):
+        """Put the block's generated positions that are not on record
+        yet into ``ServeRequest.unmasked``: all of them, or with
+        ``given`` (a preemption) only those already shown, marked
+        so."""
+        blk, req = act.block, act.req
+        at = int(self.cache.lengths[slot]) - blk.origin
+        for i in range(blk.shown if given is not None else self._block):
+            if at + i < len(req.unmasked):
+                continue    # the prompt's, or recorded before
+            if given is not None:
+                req.unmasked.append((int(blk.tokens[i]), given))
+            elif blk.masked[i]:
+                req.unmasked.append((0, NEVER_UNMASKED))
+            else:
+                req.unmasked.append((int(blk.tokens[i]),
+                                     int(blk.passes[i])))
+
     def _emit(self, rec: _InFlight, read: _StepRead):
         """Hand a read step's tokens to their requests, and bring the
         host's bounds up to what the step turned out to yield."""
@@ -1023,18 +1295,26 @@ class LMEngine:
                 # the row was wasted, its token is no one's
                 continue
             n = 1 if read.emitted is None else int(read.emitted[slot])
+            start = 0
+            if slot in read.blocks:
+                start = self._advance_block(slot, act, read)
             if not n:
                 continue    # owed nothing on the device: a wasted row
             req = act.req
             if slot in read.drafts:
                 req.drafts.append((len(req.tokens), read.drafts[slot]))
             # the dispatch counted one token; the step may have yielded
-            # another
-            self.cache.lengths[slot] += n - 1
-            act.remaining -= n - 1
+            # another (a block's step was counted for none, and its
+            # length advances where it commits)
+            if self._block:
+                act.remaining -= n
+            else:
+                self.cache.lengths[slot] += n - 1
+                act.remaining -= n - 1
             self._slot_steps += 1
+            self._first_token(req)
             for j in range(n):
-                tok = int(read.tokens[slot, j])
+                tok = int(read.tokens[slot, start + j])
                 req.tokens.append(tok)
                 req.token_times.append(time.perf_counter())
                 self._tokens_total += 1
@@ -1129,6 +1409,7 @@ class LMEngine:
 
     # ------------------------------------------------------------- stats
     def stats(self) -> dict:
+        forwards = self._block_passes + self._block_commits
         e2e = [c["e2e_s"] for c in self.completed]
         ttft = [c["ttft_s"] for c in self.completed
                 if c["ttft_s"] is not None]
@@ -1157,6 +1438,15 @@ class LMEngine:
             "draft_accept_share": (
                 self._draft_accepted / self._draft_verified
                 if self._draft_verified else None),
+            # a block model's: refining passes and commits its slots
+            # ran, tokens a forward of a block, the commits' share
+            "block_passes": self._block_passes,
+            "block_commits": self._block_commits,
+            "positions_unmasked": self._positions_unmasked,
+            "tokens_per_forward": (self._step_tokens / forwards
+                                   if forwards else None),
+            "commit_share": (self._block_commits / forwards
+                             if forwards else None),
             "settles": dict(self._settles),
             "busy_s": busy,
             "tokens_per_s": (self._tokens_total / busy

@@ -101,6 +101,37 @@ Three families:
   draft.  With two tokens a step ``serve.decode_step``'s length is still
   one step period less the host's work, not a gap between tokens, and
   ``active=`` still counts slots, not tokens.
+
+  A **model that generates by blocks** (``block_spec``;
+  ``models/sdar_moe.py``) forwards a block of ``B`` positions a slot in
+  a step, so its ``moe_*`` count every position of every live slot's
+  block, its ``context_tokens`` is the rows the step had to read ONCE a
+  slot, up to its block's end (the block's positions and a key head's
+  query heads share one read), and its ``serve.decode_step`` (of the
+  step it READ; a settled step's on ``serve.settle``) also carries:
+
+  ======================  ==============================================
+  ``block_passes``        live slots that ran a refining pass (a wasted
+                          block of a slot that had completed is none)
+  ``block_commits``       live slots whose block was final and was
+                          committed: its rows written, its length
+                          advanced by ``B``; no token comes of it
+  ``positions_unmasked``  positions the refining passes made final:
+                          between ``block_passes`` and ``B`` times it
+  ``tokens_emitted``      tokens the step handed to requests: a
+                          position's token goes out with the step after
+                          which it and every position before it are
+                          final, so 0 to ``B`` a refining slot
+  ======================  ==============================================
+
+  Registry: ``bigdl_serve_block_positions_total{outcome="unmasked"|
+  "left_masked"}``; ``stats()["tokens_per_forward"]`` (tokens over
+  passes + commits of a slot), ``["commit_share"]``,
+  ``["block_passes"]``, ``["block_commits"]``,
+  ``["positions_unmasked"]``.  ``ServeRequest.unmasked`` keeps, for
+  every generated position, its token and the pass of its block that
+  unmasked it.  ``serve.decode_step``'s length is still one step period
+  less the host's work; a reader sees a gap of one to five periods.
 * ``EVENT_*`` — point events the engine/simulator stamp regardless of
   request tracing.
 """

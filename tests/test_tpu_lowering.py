@@ -468,3 +468,70 @@ def test_draft_engine_programs_work_on_the_cache_as_it_lies(one_chip,
         assert set(ops) <= {"parameter", "scatter",
                             "scatter fusion"}, (name, ops)
         assert temp < buffer_bytes // 4, (name, temp, buffer_bytes)
+
+
+@pytest.mark.slow
+def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
+                                                            monkeypatch):
+    """SDAR-30B-A3B-Chat's block step and a prefill at the published
+    widths and the cell's engine size (two layers, all 128 experts, the
+    whole vocabulary; 128 slots, the default pool of 16385 pages), from
+    shapes alone, compiled for the described v5e with both cache
+    buffers donated: four rows written a slot and buffer, 32 query rows
+    a key head over the gathered context, and no instruction of a whole
+    buffer's size but the scatters."""
+    import functools
+    import types
+
+    from bigdl_tpu.models import sdar_moe_reference as ref
+    from bigdl_tpu.models.sdar_moe import PUBLISHED, SDARMoE
+    from bigdl_tpu.serving.engine import LMEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = one_chip
+    dt = jnp.bfloat16
+    sizes = dict(PUBLISHED, num_hidden_layers=2)
+    slots, page, max_len, block = 128, 16, 2048, 4
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = dict(sizes, max_len=max_len)
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(cfg), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    probe = SDARMoE(max_len=max_len, params=weights, **sizes)
+    cs = probe.cache_spec(weights)
+    assert (cs["row_width"], cs["kv_heads"], cs["heads"], cs["buffers"]) \
+        == (512, 4, 32, 2)
+    buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
+    eng = types.SimpleNamespace(
+        model=probe, page_size=page, _qparams=None, _drafts=False,
+        _block=block, cache=types.SimpleNamespace(buffers=lambda: (buf, buf)),
+        _prefill_fns={})
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((slots,), jnp.int32)
+    flags = spec((slots,), jnp.bool_)
+    wide = spec((slots, block), jnp.int32)
+    wide_flags = spec((slots, block), jnp.bool_)
+    programs = {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, buf, spec((slots, 128), jnp.int32), ints,
+            wide, wide_flags, ints, ints, wide, wide_flags, flags, flags),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, buf, spec((1, 256), jnp.int32),
+            spec((), jnp.int32), spec((256 // page,), jnp.int32),
+            spec((), jnp.float32), key)}
+    buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
+    for name, lowered in programs.items():
+        assert lowered.as_text().count("tpu_custom_call") >= 2, name
+        compiled = lowered.compile()
+        ops = _whole_cache_ops(compiled, buf)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        print(f"block {name}: whole-cache instructions {ops}, "
+              f"temporaries {temp / 1e6:.1f} MB, one cache buffer "
+              f"{buffer_bytes / 1e6:.1f} MB")
+        assert set(ops) <= {"parameter", "scatter",
+                            "scatter fusion"}, (name, ops)
+        assert temp < 3 * buffer_bytes, (name, temp, buffer_bytes)
