@@ -58,6 +58,10 @@ step (``serving/engine.py`` "What the engine asks of a model"): one
 phase.  The block's provisional rows are WRITTEN before it attends (the
 rows at ``length .. length + B - 1``; the attention's mask is ``pos <
 length + B``), and the commit overwrites them with the final ones.
+The attention is ``ops/decode_attention.paged_decode_attention``'s
+KERNEL path (a key head's ``B x H / H_kv`` query rows share its rows):
+each slot's pages of K and of V are read once, where they lie, up to
+the block's end, and nothing is gathered.
 
 **A chip's share**, weights brought by the caller, no weights drawn:
 as ``models/longcat_flash.py``.
@@ -177,7 +181,10 @@ class GroupedQueryAttention(AbstractModule):
         """A block a slot, ``x`` (S, B, dim) at positions ``lengths +
         0 .. B-1``: its K and V rows are written first, then every
         position attends every row up to the block's last (module
-        docstring).  Returns ``(y, kp, vp)``."""
+        docstring): one call of the page-walking kernel over the
+        stacked buffers at ``layer``, the ``B x H`` queries of a slot
+        under the one length ``lengths + B - 1``, float32 softmax
+        whatever the rows' dtype.  Returns ``(y, kp, vp)``."""
         import jax
         import jax.numpy as jnp
 
@@ -194,7 +201,7 @@ class GroupedQueryAttention(AbstractModule):
         with jax.named_scope("gqa.attn"):
             o = paged_decode_attention(
                 q, kp, vp, tables, lengths + (b - 1), layer=layer,
-                page_size=kp.shape[2], score_dtype=jnp.float32)
+                page_size=kp.shape[2])
         with jax.named_scope("dense"):
             y = jnp.matmul(o.reshape(s, b, self.n_head * self.head_dim),
                            params["wo"].T)

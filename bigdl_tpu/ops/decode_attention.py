@@ -8,24 +8,35 @@ between them:
 
 * :func:`paged_decode_attention` — a cache of per-head K/V rows: a
   layer's pool is ``(num_pages, P, H_kv*Dh)``, token-major, a key/value
-  head a range of ``Dh`` lanes of a row.  Gather the pages the table
-  names, masked softmax, weighted sum: with one query a slot and a key
-  head a query head the op sequence of
-  ``TransformerBlock.decode_step``, so paged decode matches
-  ``generate()`` token for token at temperature 0.  **Grouped heads
-  and several positions a slot** (``models/sdar_moe.py``: 32 query
-  heads over 4 key heads, a block of 4 positions a step) go through
-  the same body: the ``H / H_kv`` query heads and the ``S`` positions
-  that share a key head's rows are that head's query rows, all under
-  the slot's one mask;
+  head a range of ``Dh`` lanes of a row.  **Which shapes take which
+  path** (the function looks at ``q`` and the pool, at nothing else):
+
+  - *one query row a key head* (``S x H == H_kv``: ``models/
+    transformer.py``, 25 heads of 64 lanes, one token a slot): gather
+    the pages the table names, masked softmax, weighted sum — the op
+    sequence of ``TransformerBlock.decode_step``, so paged decode
+    matches ``generate()`` token for token at temperature 0.  A
+    per-head kernel measured 6x slower than this at those widths (25
+    tiny dots a page; PERF.md section 6, PRs 25 and 28);
+  - *query rows that share a key head* (``S x H > H_kv``: grouped
+    heads and/or several positions a slot; ``models/sdar_moe.py``, 32
+    query heads over 4 key heads of 128 lanes, a block of 4 positions a
+    step): a Pallas kernel that walks each slot's page list up to the
+    slot's own length over the K pool and the V pool, copies the pages
+    from where they lie into fast memory a block at a time and folds
+    each block into a float32 online softmax, key head by key head:
+    a key head's ``S x H / H_kv`` query rows share the one read of its
+    lanes.  Nothing is gathered in HBM and no score plane is written.
+
+  The two paths share no logic: their needs conflict (PERF.md section
+  6, PR 33), so they are separated, not adapted;
 * :func:`latent_decode_attention` — a latent cache (``nn/latent.py``):
   one compressed row a token for all heads and no V buffer.  A Pallas
-  kernel: it walks each slot's page list up to the slot's own length,
-  copies the pages from where they lie in the pool into fast memory a
-  block at a time, and folds each block into an online softmax there;
-  the contexts' rows are read once, and nothing is gathered in HBM.
+  kernel of the same build (the two kernels share the ring of buffers
+  their pages stream through, :func:`_page_stream`): the contexts' rows
+  are read once, and nothing is gathered in HBM.
 
-Mask contract (both bodies, pinned by tests): position ``pos <=
+Mask contract (every path, pinned by tests): position ``pos <=
 length`` attends, everything else is ``-inf`` before the softmax — so
 page 0 (the reserved trash page unallocated table entries point at)
 can hold arbitrary finite garbage and never contributes a bit to any
@@ -33,18 +44,19 @@ output.
 
 The engine slices each step's page tables to the used-page bucket
 (:func:`used_page_bucket`): the pow2 count of pages covering
-``max(lengths)//P + 1``, so the gather body does not pay for the empty
-pool (the latent kernel stops at each slot's length whatever the
-width; the bucket only bounds the table it is handed).
+``max(lengths)//P + 1``, so the gather path does not pay for the empty
+pool (the kernels stop at each slot's length whatever the width; the
+bucket only bounds the table they are handed).
 
-A faster body REPLACES one of these two, in a ``perf_opt`` PR that
-shows its gain in a cell of the benchmark; it is not added beside one
-behind a switch.
+A faster body REPLACES one of these, in a ``perf_opt`` PR that shows
+its gain in a cell of the benchmark; it is not added beside one behind
+a switch.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 
@@ -68,83 +80,71 @@ def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
                      maxp: int, kv_itemsize: int = 4,
                      kv_heads: Optional[int] = None,
                      positions: int = 1) -> float:
-    """Analytic HBM traffic of ONE layer's decode attention (the
-    engine's bytes-per-token gauge): the ``2 * B * maxp`` K/V pages the
-    tables name are read, and the gathered contiguous copy is written
-    and read again (the gather tax), plus the f32 score plane's round
-    trip.  A cached row holds ``kv_heads`` heads (default: one a query
-    head); ``q``, the output and the score plane have ``h`` heads at
-    each of a slot's ``positions``."""
+    """Analytic HBM traffic of ONE layer's decode attention over
+    per-head K/V rows (the engine's bytes-per-token gauge), by the
+    path :func:`paged_decode_attention` takes at these shapes.  A
+    cached row holds ``kv_heads`` heads (default: one a query head);
+    ``q`` and the output have ``h`` heads at each of a slot's
+    ``positions``.
+
+    One query row a key head (the gather): the ``2 * B * maxp`` K/V
+    pages the tables name are read, and the gathered contiguous copy is
+    written and read again (the gather tax), plus the f32 score plane's
+    round trip.  Rows that share a key head (the kernel): the bucket's
+    K and V pages ONCE (an upper bound: the kernel stops at each slot's
+    length, which the gauge does not know), the queries and the
+    outputs; nothing is gathered and no score plane leaves fast
+    memory."""
     k = maxp * page_size
     rows = h if kv_heads is None else kv_heads
     pages = 2.0 * b * maxp * page_size * rows * d * kv_itemsize  # K + V
     qio = 2.0 * b * positions * h * d * 4                     # q + out
+    if positions * h > rows:
+        return pages + qio
     return pages * 3 + 2.0 * b * positions * h * k * 4 + qio
 
 
 # --------------------------------------------------------------------------
-# per-head K/V rows — gather, masked softmax, weighted sum
+# per-head K/V rows, one query row a key head — gather, masked softmax,
+# weighted sum
 # --------------------------------------------------------------------------
 
 
-def _key_head_of(n: int, kv_heads: int, dtype):
-    """``(kv_heads, n)`` selector: 1 where query row ``n`` reads key
-    head ``j`` (the rows are laid out key head by key head, ``n //
-    kv_heads`` of them each)."""
+def _head_scores(q, rows):
+    """``q·k`` per head: q ``(B, H, Dh)``, token rows ``(B, K, H*Dh)``
+    -> ``(B, H, K)``.  A head is a lane range of a row, so the rows are
+    contracted whole, on the MXU, against q laid out block-diagonally
+    (column ``h`` holds ``q[b, h]`` in the lanes of its head and exact
+    zeros elsewhere): the same products and the same f32 accumulation
+    as a per-head dot, with no ``(.., H*Dh) -> (.., H, Dh)`` view of
+    the rows.  (That view is a padded relayout of every gathered page
+    on the TPU — 64 -> 128 lanes, 25 -> 32 sublanes; at GPT-2 XL's
+    widths it made the 48 layers' attention 16.5 ms a step against 4.0
+    this way — chip run, PR 25.)"""
     import jax.numpy as jnp
 
-    return jnp.repeat(jnp.eye(kv_heads, dtype=dtype), n // kv_heads, axis=1)
-
-
-def _head_scores(q, rows, dtype=None):
-    """``q·k`` per head: q ``(B, N, Dh)``, token rows ``(B, K,
-    H_kv*Dh)`` -> ``(B, N, K)``.  A key head is a lane range of a row,
-    so the rows are contracted whole, on the MXU, against q laid out
-    block-diagonally (column ``n`` holds ``q[b, n]`` in the lanes of its
-    key head and exact zeros elsewhere): the same products and the same
-    f32 accumulation as a per-head dot, with no ``(.., H*Dh) -> (.., H,
-    Dh)`` view of the rows.  (That view is a padded relayout of every
-    gathered page on the TPU — 64 -> 128 lanes, 25 -> 32 sublanes; at
-    GPT-2 XL's widths it made the 48 layers' attention 16.5 ms a step
-    against 4.0 this way — chip run, PR 25.)  With a key head a query
-    row (``N == H_kv``) this is, op for op, what it was before rows
-    could share a key head; ``N > H_kv`` rows come key head by key
-    head, ``N / H_kv`` to each."""
-    import jax.numpy as jnp
-
-    b, n, d = q.shape
-    hkv = rows.shape[2] // d
-    if n == hkv:
-        eye = jnp.eye(n, dtype=q.dtype)
-        qmat = (q[:, :, :, None] * eye[None, :, None, :]).reshape(
-            b, n * d, n)
-        return jnp.einsum("bkc,bch->bhk", rows, qmat)
-    sel = _key_head_of(n, hkv, q.dtype)                     # (H_kv, N)
-    qmat = (q.transpose(0, 2, 1)[:, None, :, :]
-            * sel[None, :, None, :]).reshape(b, hkv * d, n)
-    return jnp.einsum("bkc,bcn->bnk", rows, qmat,
-                      preferred_element_type=dtype)
+    b, h, d = q.shape
+    eye = jnp.eye(h, dtype=q.dtype)
+    qmat = (q[:, :, :, None] * eye[None, :, None, :]).reshape(b, h * d, h)
+    return jnp.einsum("bkc,bch->bhk", rows, qmat)
 
 
 def _head_mix(probs, rows, d: int):
-    """``probs·v`` per head: probs ``(B, N, K)``, token rows ``(B, K,
-    H_kv*Dh)`` -> ``(B, N, Dh)``.  Every query row's weights meet the
-    whole row on the MXU; row ``n`` keeps its key head's ``Dh`` lanes of
-    the result (the other blocks are dropped, not summed in)."""
+    """``probs·v`` per head: probs ``(B, H, K)``, token rows ``(B, K,
+    H*Dh)`` -> ``(B, H, Dh)``.  Every head's weights meet the whole row
+    on the MXU; head ``h`` keeps its own ``Dh`` lanes of the result
+    (the other blocks are dropped, not summed in)."""
     import jax.numpy as jnp
 
-    b, n, _ = probs.shape
-    hkv = rows.shape[2] // d
-    full = jnp.einsum("bhk,bkc->bhc", probs, rows)        # (B, N, H_kv*Dh)
-    keep = jnp.eye(n, dtype=full.dtype) if n == hkv \
-        else _key_head_of(n, hkv, full.dtype).T           # (N, H_kv)
-    return jnp.sum(full.reshape(b, n, hkv, d) * keep[None, :, :, None],
-                   axis=2)
+    b, h, _ = probs.shape
+    full = jnp.einsum("bhk,bkc->bhc", probs, rows)        # (B, H, H*Dh)
+    eye = jnp.eye(h, dtype=full.dtype)
+    return jnp.sum(full.reshape(b, h, h, d) * eye[None, :, :, None], axis=2)
 
 
 def paged_decode_attention(q, kp, vp, tables, lengths, *,
                            page_size: int, scale: Optional[float] = None,
-                           layer: Optional[int] = None, score_dtype=None):
+                           layer: Optional[int] = None):
     """One decode-attention step over a paged cache of per-head K/V
     rows.
 
@@ -162,71 +162,83 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
     int32 — position ``pos <= length`` attends, for every query of the
     slot (a block's step gives its last position: the block's rows are
     written before it attends).
-    ``score_dtype``: the scores' and the softmax's dtype where rows
-    share a key head (None: the operands').
 
-    Gather + masked softmax — for ``(B, H, Dh)`` and ``H_kv == H`` the
-    op sequence of ``TransformerBlock.decode_step`` (scores, ``-inf``
-    mask, softmax, weighted sum, in the same dtypes) on the token-major
-    cache, so the temperature-0 token-match contract vs ``generate()``
-    holds.  Returns ``q``'s shape.
+    Two paths that share no logic, chosen by the shapes of ``q`` and
+    the pool alone (module docstring):
+
+    * ``S x H == H_kv`` (one query row a key head, whether ``q`` comes
+      as ``(B, H, Dh)`` or ``(B, 1, H, Dh)``): gather + masked softmax,
+      the op sequence of ``TransformerBlock.decode_step`` (scores,
+      ``-inf`` mask, softmax, weighted sum, in the same dtypes) on the
+      token-major cache, so the temperature-0 token-match contract vs
+      ``generate()`` holds;
+    * ``S x H > H_kv`` (rows that share a key head): the Pallas kernel
+      of :func:`_grouped_program` — each slot's pages of K and of V are
+      copied from ``[layer, page]`` where they lie, a block at a time,
+      up to the slot's own length, and contracted in fast memory:
+      operands in the pools' dtype (``q`` is scaled in float32 first),
+      float32 scores, online softmax and accumulation.  Off the CPU it
+      is the Mosaic kernel or an error; on the CPU backend the Pallas
+      interpreter.
+
+    Returns ``q``'s shape and dtype.
     """
     import jax
     import jax.numpy as jnp
 
-    from bigdl_tpu.serving.cache import gather_pages
-
-    del page_size  # the pool's own (its rows are gathered whole)
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
     shape = q.shape
+    if math.prod(shape[1:-1]) > kp.shape[-1] // d:   # query rows a slot
+        from bigdl_tpu.ops._pallas import resolve_interpret
+
+        stacked = layer is not None
+        return _grouped_program(float(scale), resolve_interpret(None))(
+            q.reshape(shape[0], -1, *shape[-2:]),
+            kp if stacked else kp[None], vp if stacked else vp[None],
+            tables, lengths, jnp.full((1,), layer or 0, jnp.int32)
+        ).reshape(shape)
+
+    from bigdl_tpu.serving.cache import gather_pages
+
+    del page_size  # the pool's own (its rows are gathered whole)
     if q.ndim == 4:
-        # key head by key head: its H / H_kv query heads at each of the
-        # S positions are its query rows
-        b, s, h, _ = shape
-        hkv = kp.shape[-1] // d
-        q = q.reshape(b, s, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4) \
-            .reshape(b, s * h, d)
-    kall = gather_pages(kp, tables, layer)    # (B, maxp*P, H_kv*Dh)
+        q = q[:, 0]
+    kall = gather_pages(kp, tables, layer)    # (B, maxp*P, H*Dh)
     vall = gather_pages(vp, tables, layer)
-    scores = _head_scores(q, kall, score_dtype) * scale   # (B, N, maxp*P)
+    scores = _head_scores(q, kall) * scale    # (B, H, maxp*P)
     mask = (jnp.arange(kall.shape[1])[None, None, :]
             <= lengths[:, None, None])
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    if score_dtype is not None:
-        probs = probs.astype(vall.dtype)
     out = _head_mix(probs, vall, d)
-    if len(shape) == 4:
-        out = out.reshape(b, hkv, s, h // hkv, d).transpose(0, 2, 1, 3, 4) \
-            .reshape(shape)
-    return out
+    return out if len(shape) == 3 else out[:, None]
 
 
 # --------------------------------------------------------------------------
-# latent — multi-query attention over one shared compressed row a token
+# the kernels — a slot's pages streamed through fast memory where they lie
 # --------------------------------------------------------------------------
 
 
-# A block of pages is what one buffer of the kernel's ring holds, about
+# A block of pages is what one buffer of a kernel's ring holds, about
 # this many bytes; the copies of the ring's other blocks are in flight
 # while one is contracted.  Chosen on the chip (PERF.md section 6,
 # PR 31): blocks of 16 pages are a tenth slower than of 32, and a third
 # or fourth buffer buys nothing.
 _BLOCK_BYTES = 640 * 1024
 _BUFFERS = 2
-# copies started a trip of the kernel's issue loop: a branch a page
+# copies started a trip of the kernels' issue loop: a branch a page
 # would cost the scalar core as much as the copy's descriptor
 _COPIES_A_TRIP = 8
 
 
 def _block_pages(page_size: int, row_width: int, itemsize: int,
                  head_rows: int) -> int:
-    """Pages a block of the latent kernel, from the shapes alone (never
-    from the table's width: a narrower bucket must change no bit): as
-    many whole pages as ``_BLOCK_BYTES`` hold, and no more positions
-    than 1024 or than keep a block's float32 scores (``head_rows`` x
+    """Pages a block of a kernel, from the shapes alone (never from
+    the table's width: a narrower bucket must change no bit): as many
+    whole pages as ``_BLOCK_BYTES`` hold, and no more positions than
+    1024 or than keep a block's float32 scores (``head_rows`` x
     positions) within 256 KB."""
     by_bytes = _BLOCK_BYTES // (page_size * row_width * itemsize)
     positions = min(1024, (64 * 1024) // max(1, head_rows))
@@ -234,104 +246,258 @@ def _block_pages(page_size: int, row_width: int, itemsize: int,
     return bp if bp < _COPIES_A_TRIP else bp - bp % _COPIES_A_TRIP
 
 
-def _latent_kernel(bp: int, page: int, maxp: int, vw: int):
-    """The kernel body of :func:`latent_decode_attention` for blocks of
-    ``bp`` pages of ``page`` rows, a table ``maxp`` wide and a mix over
-    the rows' first ``vw`` lanes.  One grid step a slot; the blocks of
-    all slots, in order, are one stream through the ring of buffers it
-    is given: all but one block's copies are in flight while one is
-    contracted, across the slots' edges."""
+def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
+    """What both kernels do about their pages, inside the kernel body
+    (one grid step a slot): the blocks of ``bp`` pages of all slots, in
+    order, are one stream through a ring of buffers: all but one
+    block's copies are in flight while one is contracted, across the
+    slots' edges.  ``tables`` (flattened, ``maxp`` a slot), ``need``
+    (the pages a slot must read) and ``layer`` are scalar prefetch;
+    ``streams`` is one ``(pool, buffers, semaphores)`` a pool read: the
+    pool in HBM, read at ``[layer, page]``, a ring of buffers ``(n,
+    bp, P, row)`` and a DMA semaphore a buffer; ``ring`` four SMEM
+    ints: [0] slot and [1] block the next copies are for, [2] blocks
+    issued, [3] blocks contracted.
+
+    Returns ``(blocks, next_block)``: the blocks of this grid step's
+    slot, and a function that puts one more block's copies under way
+    (before the very first block: the whole ring's), waits for the
+    oldest block's bytes (one wait a pool, whichever copy ends last)
+    and returns the index of the buffer that holds it."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rows_blk = bp * page
     unroll = min(_COPIES_A_TRIP, bp)
     assert bp % unroll == 0, bp
+    b = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    nbuf = streams[0][1].shape[0]
+    lyr = layer[0]
 
-    def kernel(tables, need, layer, q_ref, len_ref, pool, o_ref,
-               buf, sems, ring):
-        # ring: [0] slot and [1] block the next copies are for, [2]
-        # blocks issued, [3] blocks contracted
-        b = pl.program_id(0)
-        nslots = pl.num_programs(0)
-        nbuf = buf.shape[0]
-        lyr = layer[0]
+    def blocks_of(slot):
+        return (need[slot] + bp - 1) // bp
 
-        def blocks_of(slot):
-            return (need[slot] + bp - 1) // bp
+    def issue():
+        slot, blk = ring[0], ring[1]
 
-        def issue():
-            slot, blk = ring[0], ring[1]
+        @pl.when(slot < nslots)
+        def _():
+            half = ring[2] % nbuf
 
-            @pl.when(slot < nslots)
-            def _():
-                half = ring[2] % nbuf
-
-                def group(g, c):
-                    for j in range(unroll):
-                        j += g * unroll
-                        # past the slot's last page: what the table names
-                        # there (page 0, finite by the cache's contract;
-                        # past the table's width, its last entry again)
-                        pg = tables[slot * maxp
-                                    + jnp.minimum(blk * bp + j, maxp - 1)]
+            def group(g, c):
+                for j in range(unroll):
+                    j += g * unroll
+                    # past the slot's last page: what the table names
+                    # there (page 0, finite by the cache's contract;
+                    # past the table's width, its last entry again)
+                    pg = tables[slot * maxp
+                                + jnp.minimum(blk * bp + j, maxp - 1)]
+                    for pool, buf, sems in streams:
                         pltpu.make_async_copy(pool.at[lyr, pg],
                                               buf.at[half, j],
                                               sems.at[half]).start()
-                    return c
+                return c
 
-                lax.fori_loop(0, bp // unroll, group, 0)
-                last = blk + 1 >= blocks_of(slot)
-                ring[0] = jnp.where(last, slot + 1, slot)
-                ring[1] = jnp.where(last, 0, blk + 1)
-                ring[2] = ring[2] + 1
+            lax.fori_loop(0, bp // unroll, group, 0)
+            last = blk + 1 >= blocks_of(slot)
+            ring[0] = jnp.where(last, slot + 1, slot)
+            ring[1] = jnp.where(last, 0, blk + 1)
+            ring[2] = ring[2] + 1
 
-        @pl.when(b == 0)
-        def _():
-            for k in range(4):
-                ring[k] = 0
+    @pl.when(b == 0)
+    def _():
+        for k in range(4):
+            ring[k] = 0
 
-        nblk = blocks_of(b)
+    def next_block():
+        def more(_, c):
+            issue()
+            return c
+
+        lax.fori_loop(0, jnp.where(ring[2] == 0, nbuf, 1), more, 0)
+        half = ring[3] % nbuf
+        ring[3] = ring[3] + 1
+        for pool, buf, sems in streams:
+            pltpu.make_async_copy(pool.at[lyr, pl.ds(0, bp)], buf.at[half],
+                                  sems.at[half]).wait()
+        return half
+
+    return blocks_of(b), next_block
+
+
+def _fold(carry, s, rows, lanes):
+    """One block into a running float32 online softmax ``(m, l, acc)``:
+    ``s`` the block's masked float32 scores (query rows x positions),
+    the mix over lanes ``lanes`` of the block's ``rows``."""
+    import jax.numpy as jnp
+
+    m, l, acc = carry
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
+    shift = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+    pr = jnp.exp(s - shift)
+    alpha = jnp.exp(m - shift)
+    mix = jnp.dot(pr.astype(rows.dtype), rows[:, lanes],
+                  preferred_element_type=jnp.float32)
+    return (m_new, l * alpha + jnp.sum(pr, axis=-1, keepdims=True),
+            acc * alpha + mix)
+
+
+def _fold_start(rows: int, width: int):
+    """:func:`_fold`'s ``(m, l, acc)`` before the first block."""
+    import jax.numpy as jnp
+
+    return (jnp.full((rows, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, width), jnp.float32))
+
+
+# --------------------------------------------------------------------------
+# per-head K/V rows, query rows that share a key head — the kernel
+# --------------------------------------------------------------------------
+
+
+def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int):
+    """The kernel body of :func:`_grouped_program` for blocks of ``bp``
+    pages of ``page`` rows of ``hkv`` heads of ``d`` lanes and a table
+    ``maxp`` wide.  A block of K pages and the same pages of V arrive
+    together (:func:`_page_stream`); per key head, its query rows meet
+    its lanes of the K rows, then of the V rows."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    rows_blk = bp * page
+
+    def kernel(tables, need, lens, layer, q_ref, kpool, vpool, o_ref,
+               kbuf, vbuf, ksems, vsems, ring):
+        nblk, next_block = _page_stream(
+            tables, need, layer, ring,
+            ((kpool, kbuf, ksems), (vpool, vbuf, vsems)), bp, maxp)
+        length = lens[pl.program_id(0)]
+        qs = [q_ref[0, j] for j in range(hkv)]         # (R, Dh) each
+        r = qs[0].shape[0]
+
+        def block(i, carry):
+            half = next_block()
+            krows = kbuf[half].reshape(rows_blk, hkv * d)
+            vrows = vbuf[half].reshape(rows_blk, hkv * d)
+            live = i * rows_blk + lax.broadcasted_iota(
+                jnp.int32, (r, rows_blk), 1) <= length
+            out = []
+            for j in range(hkv):
+                lanes = slice(j * d, (j + 1) * d)
+                s = lax.dot_general(qs[j], krows[:, lanes],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+                out.append(_fold(carry[j], jnp.where(live, s, -jnp.inf),
+                                 vrows, lanes))
+            return tuple(out)
+
+        heads = lax.fori_loop(0, nblk, block,
+                              tuple(_fold_start(r, d) for _ in range(hkv)))
+        for j, (_, l, acc) in enumerate(heads):
+            o_ref[0, j] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    return kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_program(scale: float, interpret: bool):
+    """The jitted call of the grouped kernel for one ``scale``, the
+    layer an argument (as :func:`_latent_program`): ``q`` ``(B, S, H,
+    Dh)``, both pools stacked, ``lengths`` ``(B,)`` -> ``(B, S, H,
+    Dh)`` in ``q``'s dtype.
+
+    One grid step a slot.  Tables, the pages each slot needs
+    (``length // P + 1``, clipped to the table's width), lengths and
+    ``layer`` are scalar prefetch; both pools stay in HBM.  Key head
+    ``j``'s query rows are its ``H / H_kv`` query heads at each of the
+    ``S`` positions, ``(B, H_kv, S x H / H_kv, Dh)``.  The pages a
+    block are taken from the shapes (:func:`_block_pages`), so a table
+    cut to the used-page bucket changes no bit."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def call(q, kpool, vpool, tables, lengths, layer):
+        b, maxp = tables.shape
+        _, s, h, d = q.shape
+        p, row = kpool.shape[-2:]
+        hkv = row // d
+        r = s * h // hkv
+        bp = _block_pages(p, row, kpool.dtype.itemsize, hkv * r)
+        qs = (q.astype(jnp.float32) * scale).astype(kpool.dtype)
+        qs = qs.reshape(b, s, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(b, hkv, r, d)
+        lens = lengths.astype(jnp.int32)
+        need = jnp.clip(lens // p + 1, 1, maxp)
+        buffers = pltpu.VMEM((_BUFFERS, bp, p, row), kpool.dtype)
+        sems = pltpu.SemaphoreType.DMA((_BUFFERS,))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, hkv, r, d), lambda i, *_: (i, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, hkv, r, d),
+                                   lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[buffers, buffers, sems, sems,
+                            pltpu.SMEM((4,), jnp.int32)])
+        out = pl.pallas_call(
+            _grouped_kernel(bp, p, maxp, hkv, d),
+            out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="grouped_decode_attention",
+        )(tables.reshape(-1).astype(jnp.int32), need, lens, layer, qs,
+          kpool, vpool)
+        return out.reshape(b, hkv, s, h // hkv, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(q.shape)
+
+    return jax.jit(call)
+
+
+# --------------------------------------------------------------------------
+# latent — multi-query attention over one shared compressed row a token
+# --------------------------------------------------------------------------
+
+
+def _latent_kernel(bp: int, page: int, maxp: int, vw: int):
+    """The kernel body of :func:`latent_decode_attention` for blocks of
+    ``bp`` pages of ``page`` rows, a table ``maxp`` wide and a mix over
+    the rows' first ``vw`` lanes.  One grid step a slot; the slot's
+    blocks arrive through :func:`_page_stream`."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    rows_blk = bp * page
+
+    def kernel(tables, need, layer, q_ref, len_ref, pool, o_ref,
+               buf, sems, ring):
+        nblk, next_block = _page_stream(tables, need, layer, ring,
+                                        ((pool, buf, sems),), bp, maxp)
         qs = q_ref[0]                                  # (H, R)
         lens = len_ref[0]                              # (H, 1)
         h = qs.shape[0]
 
         def block(i, carry):
-            m, l, acc = carry
-            # one more block's copies under way (before the very first
-            # block: the whole ring's)
-            def more(_, c):
-                issue()
-                return c
-
-            lax.fori_loop(0, jnp.where(ring[2] == 0, nbuf, 1), more, 0)
-            half = ring[3] % nbuf
-            ring[3] = ring[3] + 1
-            # one wait for the block's bytes, whichever copy ends last
-            pltpu.make_async_copy(pool.at[lyr, pl.ds(0, bp)], buf.at[half],
-                                  sems.at[half]).wait()
-            rows = buf[half].reshape(rows_blk, buf.shape[-1])
+            rows = buf[next_block()].reshape(rows_blk, buf.shape[-1])
             s = lax.dot_general(qs, rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             pos = i * rows_blk + lax.broadcasted_iota(
                 jnp.int32, (h, rows_blk), 1)
-            s = jnp.where(pos <= lens, s, -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            # fully-masked-so-far rows keep m=-inf; shift 0 avoids NaN
-            shift = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-            pr = jnp.exp(s - shift)
-            alpha = jnp.exp(m - shift)
-            mix = jnp.dot(pr.astype(rows.dtype), rows[:, :vw],
-                          preferred_element_type=jnp.float32)
-            return (m_new, l * alpha + jnp.sum(pr, axis=-1, keepdims=True),
-                    acc * alpha + mix)
+            return _fold(carry, jnp.where(pos <= lens, s, -jnp.inf), rows,
+                         slice(0, vw))
 
-        init = (jnp.full((h, 1), -jnp.inf, jnp.float32),
-                jnp.zeros((h, 1), jnp.float32),
-                jnp.zeros((h, vw), jnp.float32))
-        _, l, acc = lax.fori_loop(0, nblk, block, init)
+        _, l, acc = lax.fori_loop(0, nblk, block, _fold_start(h, vw))
         o_ref[0] = acc / jnp.maximum(l, 1e-30)
 
     return kernel

@@ -58,6 +58,10 @@ FULL = dict(
     grouped=dict(m=1536, k=6144, n=2048, sizes=(3, 0, 5, 1, 2, 9, 0, 4)),
     # the latent cells' row, page and head rows; contexts from 0 to full
     latent=dict(slots=16, heads=64, row=640, page=16, maxp=128, value=512),
+    # the block cell's rows: 4 positions x 32 query heads over 4 key
+    # heads of 128 lanes; contexts from 0 to full
+    shared=dict(slots=16, positions=4, heads=32, kv_heads=4, head_dim=128,
+                page=16, maxp=128),
     conv=[  # (N, C, H, O, k, stride): ResNet-50 sites
         (8, 256, 56, 64, 1, 1),
         (8, 128, 28, 128, 3, 1),
@@ -72,6 +76,8 @@ TINY = dict(
     flash=[(1, 2, 128, 128, 16), (1, 2, 128, 256, 16)],
     grouped=dict(m=256, k=256, n=128, sizes=(37, 0, 90, 5)),
     latent=dict(slots=4, heads=4, row=128, page=8, maxp=20, value=16),
+    shared=dict(slots=4, positions=2, heads=4, kv_heads=2, head_dim=128,
+                page=8, maxp=20),
     conv=[(2, 16, 8, 16, 1, 1), (2, 8, 8, 16, 3, 1), (2, 8, 8, 16, 3, 2)],
     multichip=dict(devices=4, batch=16, steps=3),
 )
@@ -514,15 +520,20 @@ def phase_kernels(cfg, platform, compiles) -> dict:
     from bigdl_tpu.ops.decode_attention import latent_decode_attention
     from bigdl_tpu.serving.cache import gather_pages
 
+    def contexts(c):
+        """Slots whose contexts run from 0 to full, their pages drawn
+        from a permuted pool: ``(span, pool pages, tables, lengths)``."""
+        span = c["maxp"] * c["page"]
+        lens = np.linspace(0, span - 1, c["slots"]).astype(np.int32)
+        pool = 1 + c["slots"] * c["maxp"]
+        tables = np.zeros((c["slots"], c["maxp"]), np.int32)
+        free = rs.permutation(np.arange(1, pool))
+        for i, n in enumerate(lens // c["page"] + 1):
+            tables[i, :n], free = free[:n], free[n:]
+        return span, pool, jnp.asarray(tables), jnp.asarray(lens)
+
     c = cfg["latent"]
-    span = c["maxp"] * c["page"]
-    lens = np.linspace(0, span - 1, c["slots"]).astype(np.int32)
-    pool = 1 + c["slots"] * c["maxp"]
-    tables = np.zeros((c["slots"], c["maxp"]), np.int32)
-    free = rs.permutation(np.arange(1, pool))
-    for i, n in enumerate(lens // c["page"] + 1):
-        tables[i, :n], free = free[:n], free[n:]
-    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    span, pool, tables, lens = contexts(c)
     q = jnp.asarray(rs.randn(c["slots"], c["heads"], c["row"]),
                     jnp.bfloat16)
     rows = jnp.asarray(rs.randn(2, pool, c["page"], c["row"]), jnp.bfloat16)
@@ -547,6 +558,41 @@ def phase_kernels(cfg, platform, compiles) -> dict:
         f"latent decode attention slots={c['slots']} heads={c['heads']} "
         f"row={c['row']} pages of {c['page']} x {c['maxp']} bf16 vs gather",
         latent, (q, rows), want, TOL["bfloat16"], rehearsal)
+
+    # ---- per-head K/V decode attention where query rows share a key
+    # head (the page-walking kernel), against a gather
+    from bigdl_tpu.ops.decode_attention import paged_decode_attention
+
+    c = cfg["shared"]
+    span, pool, tables, lens = contexts(c)
+    hkv, d, group = c["kv_heads"], c["head_dim"], c["heads"] // c["kv_heads"]
+    q = jnp.asarray(rs.randn(c["slots"], c["positions"], c["heads"], d),
+                    jnp.bfloat16)
+    kp, vp = (jnp.asarray(rs.randn(2, pool, c["page"], hkv * d),
+                          jnp.bfloat16) for _ in range(2))
+
+    def shared(q, kp, vp):
+        return paged_decode_attention(q, kp, vp, tables, lens,
+                                      page_size=c["page"], layer=1)
+
+    def shared_truth(q, kp, vp):
+        k = gather_pages(kp, tables, 1).reshape(c["slots"], span, hkv, d)
+        v = gather_pages(vp, tables, 1).reshape(c["slots"], span, hkv, d)
+        qg = q.reshape(c["slots"], c["positions"], hkv, group, d)
+        s = jnp.einsum("bsjgd,bkjd->bsjgk", qg, k) * d ** -0.5
+        s = jnp.where(jnp.arange(span) <= lens[:, None, None, None, None],
+                      s, -jnp.inf)
+        return jnp.einsum("bsjgk,bkjd->bsjgd", jax.nn.softmax(s, axis=-1),
+                          v).reshape(q.shape)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(
+            jax.jit(shared_truth)(*f32((q, kp, vp))))
+    out["grouped_decode_bf16"] = _run_kernel(
+        f"per-head decode attention slots={c['slots']} "
+        f"positions={c['positions']} heads={c['heads']} over {hkv} of {d} "
+        f"lanes, pages of {c['page']} x {c['maxp']} bf16 vs gather",
+        shared, (q, kp, vp), want, TOL["bfloat16"], rehearsal)
 
     # ---- conv + BN statistics, forward (the kernel) and its custom vjp
     for n, ci, hw, o, ksz, stride in cfg["conv"]:

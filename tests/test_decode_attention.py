@@ -9,6 +9,14 @@ The load-bearing contracts:
   lengths, lengths around every page edge, a live slot of length 0,
   full slots and arbitrary page-table permutations — on a layer's own
   pool and on the engine's stacked buffer with ``layer=``;
+* ``paged_decode_attention`` takes its path by the shapes of ``q`` and
+  the pool alone: one query row a key head is the gather (no kernel in
+  a ``TransformerLM`` step, its products counted), rows that share a
+  key head are the page-walking kernel (one a layer of a grouped
+  model's block step, no gather of a pool), which gives the oracle's
+  answer for 1 and 4 positions a slot, 2 and 8 query heads a key head,
+  float32 and bfloat16 pools, over several blocks of pages a slot and
+  at their edges;
 * the latent kernel gives the oracle's answer over several blocks of
   pages a slot, with one and two queries a slot (a length each), in
   float32 and bfloat16, at rows 640 lanes wide;
@@ -191,6 +199,187 @@ class TestPagedDecodeParity:
         assert np.isfinite(np.asarray(dirty)[live]).all()
 
 
+def _grouped_state(lengths, s, g, hkv=2, d=128, p=32, maxp=40, seed=0,
+                   dtype="float32"):
+    """Random paged K/V state for ``s`` positions a slot and ``g`` query
+    heads a key head: ``q`` (B, s, hkv*g, d) over pools of ``hkv`` heads
+    of ``d`` lanes."""
+    rs = np.random.RandomState(seed)
+    b = len(lengths)
+    pool = 1 + b * maxp
+    q = jnp.asarray(rs.randn(b, s, hkv * g, d), dtype)
+    shape = pool_shape(pool, p, hkv, d)
+    kp = jnp.asarray(rs.randn(*shape), dtype)
+    vp = jnp.asarray(rs.randn(*shape), dtype)
+    return (q, kp, vp, *_tables(lengths, p, maxp, pool, rs))
+
+
+def _grouped_reference(q, kp, vp, tables, lengths, hkv):
+    """Float64 oracle for query rows that share a key head: query head
+    ``h`` of every position reads key head ``h // (H / hkv)``, all
+    under the slot's one length.  The contract's operands: ``q`` scaled
+    in float32 and cast to the pools' dtype, the probabilities cast to
+    the pools' dtype before the mix."""
+    dtype = kp.dtype
+    b, s, h, d = q.shape
+    qs = np.asarray((q.astype(jnp.float32) * d ** -0.5).astype(dtype),
+                    np.float64)
+    kp, vp = np.asarray(kp, np.float64), np.asarray(vp, np.float64)
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    out = np.zeros((b, s, h, d))
+    for i in range(b):
+        n = int(lengths[i]) + 1
+        k = np.concatenate(kp[tables[i]], axis=0)[:n].reshape(n, hkv, d)
+        v = np.concatenate(vp[tables[i]], axis=0)[:n].reshape(n, hkv, d)
+        for head in range(h):
+            j = head // (h // hkv)
+            pr = _softmax_rows(qs[i, :, head] @ k[:, j].T)
+            out[i, :, head] = pr @ v[:, j]
+    return out
+
+
+class TestGroupedDecodeParity:
+    """The kernel path of ``paged_decode_attention``: query rows that
+    share a key head.  Rows of 256 lanes in pages of 32, 40 pages a
+    slot: the kernel takes its block from the shapes (16 pages in
+    float32, 32 in bfloat16), so a full slot is three or two blocks,
+    the last of them partly past the slot's pages."""
+    P, MAXP, HKV = 32, 40, 2
+
+    def _lengths(self, s, g, dtype):
+        """0, a full slot, a block's last row, the row one past it, a
+        page's last row, inside a page, a released slot."""
+        bp = D._block_pages(self.P, self.HKV * 128,
+                            jnp.dtype(dtype).itemsize, self.HKV * s * g)
+        assert 8 <= bp < self.MAXP
+        edge = bp * self.P
+        return [0, self.MAXP * self.P - 1, edge - 1, edge, 3 * self.P - 1,
+                edge + self.P + 3, None]
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layout", ["pool", "stacked"])
+    @pytest.mark.parametrize("g", [2, 8])
+    @pytest.mark.parametrize("s", [1, 4])
+    def test_kernel_matches_float64_oracle(self, s, g, layout, dtype):
+        lengths = self._lengths(s, g, dtype)
+        q, kp, vp, tbl, lens = _grouped_state(lengths, s, g, dtype=dtype,
+                                              seed=s + g)
+        want = _grouped_reference(q, kp, vp, tbl, lens, self.HKV)
+        kw = {}
+        if layout == "stacked":     # read in place at layer 1 of 3
+            kp, vp, kw = _stacked(kp, 1), _stacked(vp, 1), {"layer": 1}
+        got = paged_decode_attention(q if s > 1 else q[:, 0], kp, vp, tbl,
+                                     lens, page_size=self.P, **kw)
+        assert got.dtype == q.dtype
+        assert got.shape == (q.shape if s > 1 else q[:, 0].shape)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64).reshape(want.shape), want,
+            atol=2e-5 if dtype == "float32" else 2e-2)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_trash_page_and_unwritten_tail_never_reach_an_output(
+            self, dtype):
+        """Huge finite values in page 0 (what unallocated table entries
+        name, and what the last block of a slot is filled up with) and
+        in the rows past each slot's length change no bit of a live
+        slot's output, in K or in V."""
+        lengths = self._lengths(4, 8, dtype)
+        q, kp, vp, tbl, lens = _grouped_state(lengths, 4, 8, dtype=dtype,
+                                              seed=7)
+        clean = np.asarray(paged_decode_attention(
+            q, kp, vp, tbl, lens, page_size=self.P), np.float32)
+        dk, dv = np.array(kp, np.float32), np.array(vp, np.float32)
+        dk[0], dv[0] = 1e30, -1e30
+        for i, ln in enumerate(lengths):
+            if ln is not None:
+                last = int(tbl[i, ln // self.P])
+                dk[last, ln % self.P + 1:] = -1e30
+                dv[last, ln % self.P + 1:] = 1e30
+        got = np.asarray(paged_decode_attention(
+            q, jnp.asarray(dk, dtype), jnp.asarray(dv, dtype), tbl, lens,
+            page_size=self.P), np.float32)
+        live = np.asarray([ln is not None for ln in lengths])
+        np.testing.assert_array_equal(got[live], clean[live])
+        assert np.isfinite(got[live]).all()
+
+    def test_pages_no_table_names_are_never_read(self):
+        """NaNs in every page no table entry names change no bit: the
+        kernel copies the pages a slot's table names and nothing
+        else."""
+        lengths = [0, 35 * self.P, 17 * self.P + 3, 5]
+        q, kp, vp, tbl, lens = _grouped_state(lengths, 4, 2, seed=9)
+        clean = np.asarray(paged_decode_attention(
+            q, kp, vp, tbl, lens, page_size=self.P))
+        named = np.zeros(kp.shape[0], bool)
+        named[np.asarray(tbl).ravel()] = True
+        assert (~named).sum() > 50
+        poison = lambda pool: jnp.where(named[:, None, None], pool, jnp.nan)
+        got = np.asarray(paged_decode_attention(
+            q, poison(kp), poison(vp), tbl, lens, page_size=self.P))
+        np.testing.assert_array_equal(got, clean)
+
+    def test_a_block_of_positions_is_its_positions_one_at_a_time(self):
+        """The slot's one mask holds for every position of a block: the
+        4-position result is four 1-position calls under the same
+        ``lengths`` (what the model hands: the block's last row)."""
+        lengths = self._lengths(4, 8, "float32")
+        q, kp, vp, tbl, lens = _grouped_state(lengths, 4, 8, seed=5)
+        whole = np.asarray(paged_decode_attention(
+            q, kp, vp, tbl, lens, page_size=self.P))
+        for i in range(4):
+            one = np.asarray(paged_decode_attention(
+                q[:, i:i + 1], kp, vp, tbl, lens, page_size=self.P))
+            np.testing.assert_allclose(one[:, 0], whole[:, i], atol=1e-6)
+
+    def test_the_scale_is_the_callers(self):
+        lengths = [5, 20 * self.P]
+        q, kp, vp, tbl, lens = _grouped_state(lengths, 1, 2, seed=2)
+        d = q.shape[-1]
+        default = paged_decode_attention(q, kp, vp, tbl, lens,
+                                         page_size=self.P)
+        given = paged_decode_attention(q * 2.0, kp, vp, tbl, lens,
+                                       page_size=self.P,
+                                       scale=0.5 * d ** -0.5)
+        np.testing.assert_allclose(np.asarray(given), np.asarray(default),
+                                   atol=1e-6)
+
+
+class TestThePathIsChosenByTheShapes:
+    """One query row a key head is the gather, op for op; rows that
+    share a key head are the kernel: nothing else decides."""
+
+    @staticmethod
+    def _lowered(q, monkeypatch, hkv=4, d=16):
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+        b = q[0]
+        S = jax.ShapeDtypeStruct
+        pool = S(pool_shape(1 + b * MAXP, P, hkv, d), jnp.float32)
+        return jax.jit(
+            lambda q, kp, vp, t, l: paged_decode_attention(
+                q, kp, vp, t, l, page_size=P)).trace(
+            S(q, jnp.float32), pool, pool, S((b, MAXP), jnp.int32),
+            S((b,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+
+    @pytest.mark.parametrize("q", [(3, 4, 16), (3, 1, 4, 16)],
+                             ids=["B_H_Dh", "B_1_H_Dh"])
+    def test_one_query_row_a_key_head_is_the_gather(self, q, monkeypatch):
+        text = self._lowered(q, monkeypatch)
+        assert "tpu_custom_call" not in text
+        assert text.count("#stablehlo.gather<") == 2        # K and V
+        assert text.count("stablehlo.dot_general") == 2     # scores, mix
+
+    @pytest.mark.parametrize("q", [(3, 8, 16), (3, 2, 4, 16), (3, 4, 32, 16)],
+                             ids=["grouped_heads", "two_positions", "both"])
+    def test_rows_that_share_a_key_head_are_the_kernel(self, q,
+                                                       monkeypatch):
+        text = self._lowered(q, monkeypatch)
+        assert text.count("tpu_custom_call") == 1
+        assert 'kernel_name = "grouped_decode_attention"' in text
+        assert "stablehlo.gather" not in text
+
+
 class TestLatentDecodeParity:
     SCALE, VW = 0.3, 16
 
@@ -293,7 +482,7 @@ class TestLatentDecodeParity:
         np.testing.assert_array_equal(got, clean)
 
 
-@pytest.mark.parametrize("body", ["paged", "latent"])
+@pytest.mark.parametrize("body", ["paged", "grouped", "latent"])
 def test_bucket_slice_equals_the_full_table_to_the_last_bit(body):
     """The engine hands a step the table's first ``used_page_bucket``
     columns: every position past the longest length is masked, so the
@@ -306,7 +495,12 @@ def test_bucket_slice_equals_the_full_table_to_the_last_bit(body):
         full, cut = (paged_decode_attention(q, kp, vp, t, lens,
                                             page_size=P)
                      for t in (tbl, tbl[:, :bucket]))
-    else:   # the kernel's block is taken from the shapes, not the width
+    elif body == "grouped":   # a block of 2 positions, 2 heads a key head
+        q, kp, vp, tbl, lens = _grouped_state(lengths, 2, 2, p=P, maxp=MAXP)
+        full, cut = (paged_decode_attention(q, kp, vp, t, lens,
+                                            page_size=P)
+                     for t in (tbl, tbl[:, :bucket]))
+    else:   # the kernels' block is taken from the shapes, not the width
         q, pages, tbl, lens = _latent_state(lengths)
         full, cut = (latent_decode_attention(
             q, pages, t, lens, scale=0.3, value_width=16)
@@ -326,9 +520,12 @@ class TestBucketHelpers:
         assert used_page_bucket(1000, 8, 8) == 8  # clamped
 
     def test_block_pages_come_from_the_shapes(self):
-        """Pages a block of the latent kernel: from the page, the row,
-        the itemsize and the head rows (never the table's width, which
-        is not an argument)."""
+        """Pages a block of a kernel: from the page, the row, the
+        itemsize and the head rows (never the table's width, which is
+        not an argument)."""
+        # the block cell: pages of 16 rows of 4 x 128 bfloat16 lanes,
+        # 128 query rows a slot -> 32 pages (512 positions: the scores)
+        assert D._block_pages(16, 512, 2, 128) == 32
         # the served models: pages of 16 rows of 640 bfloat16 lanes, 64
         # head rows -> 32 pages (655 KB, 512 positions)
         assert D._block_pages(16, 640, 2, 64) == 32

@@ -302,7 +302,7 @@ def test_a_block_of_four_positions_eight_query_heads_a_key_head(layer):
         kp = jnp.stack([jnp.zeros_like(kp), kp])
         vp = jnp.stack([jnp.zeros_like(vp), vp])
     got = paged_decode_attention(q, kp, vp, tables, lengths, layer=layer,
-                                 page_size=page, score_dtype=jnp.float32)
+                                 page_size=page)
     assert got.shape == q.shape
     k1, v1 = (kp, vp) if layer is None else (kp[layer], vp[layer])
     for i, end in enumerate(ends):
@@ -318,18 +318,26 @@ def test_a_block_of_four_positions_eight_query_heads_a_key_head(layer):
 
 
 def test_decode_hbm_bytes_by_key_value_heads():
-    """32 query heads over 4 key/value heads at 4 positions: the pages
-    count the key/value heads, ``q``, the output and the scores the
-    query heads at every position; without them it reads what it
-    read."""
+    """The gauge counts what the path taken at the shapes moves.  32
+    query heads over 4 key/value heads at 4 positions share a key head:
+    the kernel reads the bucket's K and V pages ONCE (by key/value
+    heads) beside ``q`` and the output (by query heads at every
+    position): no gather tax, no score plane.  So do grouped heads at
+    one position.  One query row a key head reads what it read."""
     b, h, hkv, d, p, maxp, item, s = 128, 32, 4, 128, 16, 128, 2, 4
     k = maxp * p
     got = decode_hbm_bytes(b, h, d, p, maxp, item, kv_heads=hkv,
                            positions=s)
-    assert got == (3 * 2.0 * b * k * hkv * d * item
-                   + 2.0 * b * s * h * k * 4 + 2.0 * b * s * h * d * 4)
+    assert got == 2.0 * b * k * hkv * d * item + 2.0 * b * s * h * d * 4
+    assert decode_hbm_bytes(b, h, d, p, maxp, item, kv_heads=hkv) == (
+        2.0 * b * k * hkv * d * item + 2.0 * b * h * d * 4)
+    # several positions over a key head a query head share it too
+    assert decode_hbm_bytes(b, hkv, d, p, maxp, item, positions=s) == (
+        2.0 * b * k * hkv * d * item + 2.0 * b * s * hkv * d * 4)
     assert decode_hbm_bytes(12, 25, 64, 16, 32, 2) == decode_hbm_bytes(
-        12, 25, 64, 16, 32, 2, kv_heads=25, positions=1)
+        12, 25, 64, 16, 32, 2, kv_heads=25, positions=1) == (
+        3 * 2.0 * 12 * 512 * 25 * 64 * 2 + 2.0 * 12 * 25 * 512 * 4
+        + 2.0 * 12 * 25 * 64 * 4)
     model, params, _ = make(3)
     eng = LMEngine(model, params=params, max_batch=2, page_size=4)
     try:
@@ -950,3 +958,91 @@ def test_step_programs_carry_the_scopes():
         assert "jit_step" in text or "jit(step)" in text
     finally:
         eng.close()
+
+
+# ------------------- (h) which programs hold the kernel, and which do not
+def _lowered_for_a_chip(fn, args, monkeypatch):
+    """The StableHLO ``fn`` lowers to for the TPU platform, the backend
+    under another name than ``cpu`` (so a kernel is the Mosaic call, not
+    the interpreter's operations)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+    return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def _like(a):
+    return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_one_token_model_s_step_holds_no_kernel(dtype, monkeypatch):
+    """The guard PR 31's and PR 32's builders ran by hand:
+    ``TransformerLM``'s paged decode step (one query row a key head)
+    lowers with no custom call at all, and with the gather body's
+    products: a layer is the q, k and v projections, the body's scores
+    and mix (two, each ONE block-diagonal product over all heads), the
+    output projection and the two MLP matrices; then the head."""
+    import re
+
+    from bigdl_tpu.models.transformer import build_transformer_lm
+
+    n_layer, b = 2, 3
+    model = build_transformer_lm(64, dim=32, n_head=4, n_layer=n_layer,
+                                 max_len=64)
+    params = jax.tree.map(lambda a: a.astype(dtype), model.params())
+    eng = LMEngine(model, params=params, max_batch=b, page_size=4)
+    try:
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32)
+        flags = jax.ShapeDtypeStruct((b,), jnp.bool_)
+        text = _lowered_for_a_chip(eng._step_fn, (
+            jax.tree.map(_like, eng.params), _like(eng.cache.kp),
+            _like(eng.cache.vp), jax.ShapeDtypeStruct((b, 4), jnp.int32),
+            ints, ints, ints, flags,
+            jax.ShapeDtypeStruct((b,), jnp.float32), flags,
+            _like(jax.random.key(0))), monkeypatch)
+    finally:
+        eng.close()
+    assert "custom_call" not in text
+    assert len(re.findall(r"stablehlo\.dot_general", text)) \
+        == (3 + 2 + 1 + 2) * n_layer + 1
+    # K and V of each layer are gathered from the stacked buffers
+    pool = "x".join(str(n) for n in eng.cache.kp.shape)
+    assert len(re.findall(
+        r'"stablehlo\.gather"\([^)]*\)[^\n]*: \(tensor<%sx' % pool,
+        text)) == 2 * n_layer
+
+
+def test_the_block_step_holds_one_kernel_a_layer_and_gathers_no_pool(
+        monkeypatch):
+    """SDAR's block step (32 query rows a key head at the tests' size:
+    4 positions x 4 heads): one call of the page-walking kernel a
+    layer, both buffers handed to it whole, and no gather of a pool."""
+    import re
+
+    model, params, _ = make(3)
+    b = 2
+    eng = LMEngine(model, params=params, max_batch=b, page_size=4)
+    try:
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32)
+        flags = jax.ShapeDtypeStruct((b,), jnp.bool_)
+        wide = jax.ShapeDtypeStruct((b, B), jnp.int32)
+        wide_flags = jax.ShapeDtypeStruct((b, B), jnp.bool_)
+        bufs = [_like(x) for x in eng.cache.buffers()]
+        text = _lowered_for_a_chip(eng._step_fn, (
+            jax.tree.map(_like, eng.params), *bufs,
+            jax.ShapeDtypeStruct((b, 4), jnp.int32), ints, wide,
+            wide_flags, ints, ints, wide, wide_flags, flags, flags),
+            monkeypatch)
+    finally:
+        eng.close()
+    # a model's attentions share one traced program: one private
+    # function of the module holds the kernel, called once a layer
+    assert text.count('kernel_name = "grouped_decode_attention"') == 1
+    holder = re.findall(
+        r"func\.func private @([\w.]+)\(",
+        text[:text.index('kernel_name = "grouped_decode_attention"')])[-1]
+    assert len(re.findall(r"call @%s\(" % re.escape(holder), text)) \
+        == SMALL["num_hidden_layers"]
+    pool = "x".join(str(n) for n in bufs[0].shape)
+    assert f"tensor<{pool}x" in text
+    assert not re.findall(
+        r'"stablehlo\.gather"\([^)]*\)[^\n]*: \(tensor<%sx' % pool, text)

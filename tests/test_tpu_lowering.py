@@ -101,6 +101,44 @@ def test_latent_decode_is_one_kernel_at_the_engine_shape(queries,
     assert calls() == 1
 
 
+# SDAR-30B-A3B-Chat's attention at the cell's engine size
+# (benchmarks/configs/sdar_30b_a3b_chat.json): 128 slots, a block of 4
+# positions, 32 query heads over 4 key heads of 128 lanes, pages of 16,
+# 128 pages a slot, the stacked bfloat16 pools of six layers
+GROUPED = dict(slots=128, block=4, heads=32, kv_heads=4, head_dim=128,
+               page=16, maxp=128, layers=6)
+
+
+def _grouped_shapes(c=GROUPED, dt=jnp.bfloat16):
+    b, maxp = c["slots"], c["maxp"]
+    pool = (pool_shape(1 + b * maxp, c["page"], c["kv_heads"],
+                       c["head_dim"], c["layers"]), dt)
+    return (((b, c["block"], c["heads"], c["head_dim"]), dt), pool, pool,
+            ((b, maxp), jnp.int32), ((b,), jnp.int32))
+
+
+def _grouped(q, kp, vp, tables, lengths):
+    return paged_decode_attention(q, kp, vp, tables, lengths,
+                                  page_size=GROUPED["page"], layer=3)
+
+
+def test_grouped_decode_is_one_kernel_at_the_engine_shape(monkeypatch):
+    """Query rows that share a key head at the block cell's widths, on
+    the stacked buffers: ONE Mosaic call and no gather, and off the CPU
+    no interpreter."""
+    def text():   # a new function a call: nothing traced is reused
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in _grouped_shapes()]
+        return jax.jit(lambda *a: _grouped(*a)).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    assert "tpu_custom_call" not in text()       # CPU: interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+    lowered = text()
+    assert lowered.count("tpu_custom_call") == 1
+    assert 'kernel_name = "grouped_decode_attention"' in lowered
+    assert "stablehlo.gather" not in lowered
+
+
 @pytest.mark.parametrize("n,c,hw,o,k,stride", FULL["conv"])
 def test_conv_bn_lowers_at_resnet50_sites(n, c, hw, o, k, stride):
     def f(x, w, shift):
@@ -188,6 +226,28 @@ def test_libtpu_mosaic_compiles_every_kernel_without_a_chip(one_chip):
 
 
 @pytest.mark.slow
+def test_libtpu_mosaic_compiles_the_grouped_kernel_where_the_pools_lie(
+        one_chip, monkeypatch):
+    """The per-head K/V kernel at the block cell's shapes, compiled by
+    libtpu's Mosaic for the described v5e: both stacked pools go into
+    the kernel as they are handed in (no instruction of a pool's shape
+    but the two parameters: no ``kp[layer]``, no gathered copy), and
+    the program's temporaries are the scaled, regrouped queries."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in _grouped_shapes()]
+    lowered = jax.jit(_grouped).lower(*args)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    ops = _whole_cache_ops(compiled, args[1])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"grouped kernel: whole-pool instructions {ops}, temporaries "
+          f"{temp / 1e6:.1f} MB")
+    assert set(ops) <= {"parameter"} and ops.get("parameter", 0) == 2, ops
+    assert temp < 64e6, temp
+
+
+@pytest.mark.slow
 def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
     """The engine's decode step and both prefill buckets of the serving
     cell, weight-free, compiled for the described v5e with the caches
@@ -262,6 +322,18 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
               f"{buffer_bytes / 1e6:.1f} MB")
 
 
+def _kernel_calls(text: str, kernel: str) -> int:
+    """How often a lowered program runs the Mosaic kernel ``kernel``:
+    the kernel's jitted program is one private function of the module
+    (a model's attentions share one traced program), called once an
+    attention."""
+    import re
+
+    body = text.index(f'kernel_name = "{kernel}"')
+    inside = re.findall(r"func\.func private @([\w.]+)\(", text[:body])[-1]
+    return len(re.findall(r"call @%s\(" % re.escape(inside), text))
+
+
 def _whole_cache_ops(compiled, buf) -> dict:
     """Instructions of a compiled program whose result has the whole
     cache's shape, by kind (the cache write is a ``scatter fusion``)."""
@@ -332,7 +404,7 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     # the engine's own builders, given shapes in place of an engine
     eng = types.SimpleNamespace(
         model=probe, page_size=page, _qparams=None, _drafts=False,
-        cache=types.SimpleNamespace(buffers=lambda: (buf,)),
+        _block=0, cache=types.SimpleNamespace(buffers=lambda: (buf,)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)
     b = slots
@@ -477,9 +549,12 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     widths and the cell's engine size (two layers, all 128 experts, the
     whole vocabulary; 128 slots, the default pool of 16385 pages), from
     shapes alone, compiled for the described v5e with both cache
-    buffers donated: four rows written a slot and buffer, 32 query rows
-    a key head over the gathered context, and no instruction of a whole
-    buffer's size but the scatters."""
+    buffers donated: four rows written a slot and buffer, then ONE
+    ``grouped_decode_attention`` kernel a layer that reads both buffers
+    where they lie (32 query rows a key head; no gather of a pool), and
+    no instruction of a whole buffer's size but the scatters.  The
+    step's temporaries are the head's float32 logits and little else
+    (the gather body's gathered pages and score plane are gone)."""
     import functools
     import types
 
@@ -525,7 +600,15 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
             spec((), jnp.float32), key)}
     buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
     for name, lowered in programs.items():
-        assert lowered.as_text().count("tpu_custom_call") >= 2, name
+        text = lowered.as_text()
+        assert text.count("tpu_custom_call") >= 2, name
+        # the attention kernel is the step's alone (the prefill attends
+        # its own prompt densely); once a layer, and no pool is gathered
+        if name == "step":
+            assert _kernel_calls(text, "grouped_decode_attention") \
+                == sizes["num_hidden_layers"]
+        else:
+            assert "grouped_decode_attention" not in text
         compiled = lowered.compile()
         ops = _whole_cache_ops(compiled, buf)
         temp = compiled.memory_analysis().temp_size_in_bytes
@@ -535,3 +618,7 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
         assert set(ops) <= {"parameter", "scatter",
                             "scatter fusion"}, (name, ops)
         assert temp < 3 * buffer_bytes, (name, temp, buffer_bytes)
+        if name == "step":
+            # the float32 logits of 512 positions (311 MB) and little
+            # else: the gather body's step held 475 MB
+            assert temp < 350e6, temp
