@@ -391,7 +391,9 @@ SERVE_LATENCY_SLO_RATIO = _m(
 SERVE_DECODE_ATTN_MS = _m(
     "bigdl_serve_decode_attn_ms", "gauge", policy="max",
     doc="Mean milliseconds the engine's thread spends in a decode step: "
-        "dispatching step k and waiting for step k-1's tokens")
+        "dispatching step k, waiting for step k-1's tokens and reading "
+        "them (host work and slack in one sum: the spans serve.dispatch, "
+        "serve.wait and serve.read give the parts)")
 SERVE_STEPS_AHEAD_TOTAL = _m(
     "bigdl_serve_steps_ahead_total", "counter",
     doc="Decode steps dispatched while the previous step's tokens were "

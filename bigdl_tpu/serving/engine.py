@@ -435,9 +435,10 @@ class LMEngine:
         self._decode_ms_gauge = reg.gauge(
             names.SERVE_DECODE_ATTN_MS,
             "Mean wall-clock of a decode step on the engine's thread, "
-            "in milliseconds: the dispatch of step k and the wait for "
-            "step k-1's tokens (one step is in flight, so about the "
-            "step period less the host's own work)")
+            "in milliseconds: the dispatch of step k, the wait for step "
+            "k-1's tokens and their read, work and slack in one sum "
+            "(the spans serve.dispatch, serve.wait and serve.read give "
+            "the parts)")
         self._ahead_counter = reg.counter(
             names.SERVE_STEPS_AHEAD_TOTAL,
             "Decode steps dispatched while the previous step's tokens "
@@ -817,22 +818,35 @@ class LMEngine:
         self._key, sub = jax.random.split(self._key)
         tracer = self._tracer
         n = len(self.cache.buffers())
-        with tracer.span(spans.SPAN_STEP_PREFILL, step=self._steps,
+        step = self._steps
+        with tracer.span(spans.SPAN_STEP_PREFILL, step=step,
                          bucket=bucket, prompt_len=t0,
                          request=req.id) as span_id:
-            out = self._prefill_fn(bucket)(
-                self.params, *self.cache.buffers(),
-                jnp.asarray(prompt), t0, jnp.asarray(page_arg),
-                float(req.temperature), sub)
-            self.cache.set_buffers(out[:n])
-            self.cache.lengths[slot] = t0
-            # a drafting model's first token comes with its first draft;
-            # a block model's prefill yields its first block and no token
-            first = np.asarray(out[n])
-            tok, draft = (int(t) for t in first) if self._drafts \
-                else (None, None) if self._block else (int(first), None)
-            if len(out) > n + 1:
-                tracer.add_attrs(span_id, **self._note_routing(out[n + 1]))
+            with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
+                             program="prefill") as dispatch_id:
+                self._note_dry(tracer, dispatch_id)
+                out = self._prefill_fn(bucket)(
+                    self.params, *self.cache.buffers(),
+                    jnp.asarray(prompt), t0, jnp.asarray(page_arg),
+                    float(req.temperature), sub)
+                self.cache.set_buffers(out[:n])
+                self.cache.lengths[slot] = t0
+            # the prefill was enqueued behind the step in flight: the
+            # wait holds what was left of that step too
+            with tracer.span(spans.SPAN_STEP_WAIT, step=step,
+                             program="prefill"):
+                first = np.asarray(out[n])
+            with tracer.span(spans.SPAN_STEP_READ, step=step,
+                             program="prefill"):
+                # a drafting model's first token comes with its first
+                # draft; a block model's prefill yields its first block
+                # and no token
+                tok, draft = (int(t) for t in first) if self._drafts \
+                    else (None, None) if self._block \
+                    else (int(first), None)
+                if len(out) > n + 1:
+                    tracer.add_attrs(span_id,
+                                     **self._note_routing(out[n + 1]))
         if req.trace is not None:
             req._tr_admits.append(
                 {"t": t_admit, "dur": time.monotonic() - t_admit,
@@ -1061,57 +1075,61 @@ class LMEngine:
             self._key, sub = jax.random.split(self._key)
             tracer.add_attrs(span_id, bucket=bucket, active=len(running))
         t0 = time.perf_counter()
-        # a LIVE span (not a retroactive reqtrace hop): the continuous
-        # profiler attributes samples landing here to the decode phase
-        # by name.  It covers this step's dispatch and the wait for the
-        # PREVIOUS step's tokens
+        # a LIVE span (not a retroactive reqtrace hop).  It covers this
+        # step's dispatch, then the wait for the PREVIOUS step's tokens
+        # and their read: its three children, and the continuous
+        # profiler attributes a sample to the innermost of them by name
         n = len(self.cache.buffers())
         prev = self._inflight
         with tracer.span(spans.SPAN_STEP_DECODE, bucket=bucket,
                          active=len(running),
                          ahead=int(prev is not None)) as span_id:
-            if self._drafts:
-                host = (jnp.asarray(tokens), jnp.asarray(drafts),
-                        jnp.asarray(owed), jnp.asarray(fresh),
-                        jnp.asarray(active))
-            elif self._block:
-                host = (jnp.asarray(tokens), jnp.asarray(masked),
-                        jnp.asarray(fresh), jnp.asarray(active))
-            else:
-                host = (jnp.asarray(tokens), jnp.asarray(fresh),
-                        jnp.asarray(temps), jnp.asarray(active), sub)
-            out = self._step_fn(
-                self.params, *self.cache.buffers(), tables, lengths,
-                *self._carry, *host)
-            self.cache.set_buffers(out[:n])
-            k = len(self._carry)
-            self._carry = out[n:n + k]
-            # what the host reads: the tokens (a one-token model's are
-            # the carry itself), and an expert model's counts
-            result = out[n + k:] if self._drafts or self._block \
-                else out[n:]
-            for arr in result:
-                # on their way to the host as soon as they exist, not
-                # when the next pump asks for them
-                arr.copy_to_host_async()
-            # the host's state advances at dispatch, by the one token a
-            # step yields at least: the next prep (growth, bucket, who
-            # runs) needs no token.  (A block's step may yield none:
-            # that host's state advances when the step is read.)
-            entries, context = [], 0
-            sure = 0 if self._block else 1
-            for i in running:
-                act = self._slots[i]
-                self.cache.lengths[i] += sure
-                context += int(self.cache.lengths[i])
-                act.remaining -= sure
-                act.unread += 1
-                entries.append((i, act))
-            self._inflight = _InFlight(
-                result[0], result[1] if len(result) > 1 else None,
-                entries, context)
+            with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
+                             program="step") as dispatch_id:
+                self._note_dry(tracer, dispatch_id)
+                if self._drafts:
+                    host = (jnp.asarray(tokens), jnp.asarray(drafts),
+                            jnp.asarray(owed), jnp.asarray(fresh),
+                            jnp.asarray(active))
+                elif self._block:
+                    host = (jnp.asarray(tokens), jnp.asarray(masked),
+                            jnp.asarray(fresh), jnp.asarray(active))
+                else:
+                    host = (jnp.asarray(tokens), jnp.asarray(fresh),
+                            jnp.asarray(temps), jnp.asarray(active), sub)
+                out = self._step_fn(
+                    self.params, *self.cache.buffers(), tables, lengths,
+                    *self._carry, *host)
+                self.cache.set_buffers(out[:n])
+                k = len(self._carry)
+                self._carry = out[n:n + k]
+                # what the host reads: the tokens (a one-token model's
+                # are the carry itself), and an expert model's counts
+                result = out[n + k:] if self._drafts or self._block \
+                    else out[n:]
+                for arr in result:
+                    # on their way to the host as soon as they exist,
+                    # not when the next pump asks for them
+                    arr.copy_to_host_async()
+                # the host's state advances at dispatch, by the one
+                # token a step yields at least: the next prep (growth,
+                # bucket, who runs) needs no token.  (A block's step may
+                # yield none: that host's state advances when the step
+                # is read.)
+                entries, context = [], 0
+                sure = 0 if self._block else 1
+                for i in running:
+                    act = self._slots[i]
+                    self.cache.lengths[i] += sure
+                    context += int(self.cache.lengths[i])
+                    act.remaining -= sure
+                    act.unread += 1
+                    entries.append((i, act))
+                self._inflight = _InFlight(
+                    result[0], result[1] if len(result) > 1 else None,
+                    entries, context)
             if prev is not None:
-                read = self._read(prev)
+                read = self._read(prev, tracer, step)
                 # what the step just read routed and yielded, and the
                 # rows of context it had to read
                 tracer.add_attrs(span_id, **read.attrs)
@@ -1163,15 +1181,32 @@ class LMEngine:
         return max(0, min(reach,
                           act.last_pos - int(self.cache.lengths[slot])))
 
-    def _read(self, rec: _InFlight) -> _StepRead:
-        """Wait for a dispatched step's tokens.  With them come an
-        expert model's routing counts and a drafting model's emitted
+    def _note_dry(self, tracer, dispatch_id):
+        """Say on an open ``serve.dispatch`` whether the chip had run
+        dry: nothing launched before is still running.  Asked of the
+        device buffer without blocking, before the host ships its
+        arrays, and only under a recording tracer."""
+        if tracer.enabled:
+            rec = self._inflight
+            tracer.add_attrs(dispatch_id, dry=int(
+                rec is None or bool(rec.result.is_ready())))
+
+    def _read(self, rec: _InFlight, tracer, step: int) -> _StepRead:
+        """Wait for a dispatched step's tokens (``serve.wait``: the one
+        blocking read), then take them apart (``serve.read``)."""
+        with tracer.span(spans.SPAN_STEP_WAIT, step=step, program="step"):
+            res = np.asarray(rec.result)
+        with tracer.span(spans.SPAN_STEP_READ, step=step, program="step"):
+            if self._block:
+                return self._read_block(rec, res)
+            return self._read_tokens(rec, res)
+
+    def _read_tokens(self, rec: _InFlight, res) -> _StepRead:
+        """A one-token or drafting model's read.  With the tokens come
+        an expert model's routing counts and a drafting model's emitted
         counts: returned as span attributes, beside the rows of context
         that step had to read."""
-        res = np.asarray(rec.result)
         attrs, drafts = {}, {}
-        if self._block:
-            return self._read_block(rec, res)
         if self._drafts:
             first, second, emitted, draft, length = res.T  # DRAFT_RESULT
             toks = np.stack([first, second], axis=1)
@@ -1336,7 +1371,9 @@ class LMEngine:
             return False
         self._inflight = None
         tracer = obs.get_tracer()  # not always inside a pump
-        read = self._read(rec)
+        # the last step dispatched: its wait and read lie outside any
+        # ``serve.decode_step`` and carry its own step
+        read = self._read(rec, tracer, self._steps - 1)
         tracer.event(spans.EVENT_SETTLE, reason=reason, **read.attrs)
         with tracer.span(spans.SPAN_STEP_EMIT, step=self._steps):
             self._emit(rec, read)

@@ -51,6 +51,38 @@ Three families:
                           no ``serve.decode_step`` before it, where a
                           step in flight is settled (``serve.settle``)
   ======================  ==============================================
+  ``serve.decode_step`` and ``serve.prefill`` each hold three children,
+  in this order and disjoint (the parent's length less theirs is the
+  cost of the spans themselves), that say what the HOST was doing.
+  Each carries ``step=`` (the cycle's, as ``serve.prep`` and
+  ``serve.admission`` carry it) and ``program="step"|"prefill"``:
+
+  ==================  ==================================================
+  ``serve.dispatch``  host WORK: the host's arrays to the device, the
+                      jitted call until it returns, ``set_buffers``,
+                      the results sent on their way to the host, and
+                      for a step the bookkeeping over the running slots
+                      (lengths, ``remaining``, ``unread``).  ``dry=`` 1
+                      where, as the host was about to launch, nothing it
+                      had launched before was still running (no step in
+                      flight, or its result ``is_ready()``: asked before
+                      the arrays are shipped, without blocking): the
+                      chip was waiting for the host, as ``ahead=1`` says
+                      the host was not waiting for the chip
+  ``serve.wait``      host SLACK: the one blocking read of a dispatched
+                      program's result and nothing else; a prefill's
+                      also holds what was left of the step in flight
+  ``serve.read``      host WORK: what the host does with the array once
+                      it has it: the per-slot loop over the step's
+                      slots, the draft and block counters, the routing
+                      counts; a prefill's ``int()`` of its token
+  ==================  ==================================================
+  A step's ``serve.wait`` and ``serve.read`` lie in the
+  ``serve.decode_step`` of the NEXT step, which reads it (as the
+  ``moe_*`` attributes do), so the first step after a settle has a
+  ``serve.dispatch`` only.  A settled step's two are top-level spans
+  before the settle's ``serve.emit`` and carry the SETTLED step's own
+  ``step``: every executed step is waited for and read exactly once.
   An **expert model**'s ``serve.decode_step`` and ``serve.prefill``
   also carry what a step routed, summed over its expert layers
   (``nn/experts.py`` ``COUNT_NAMES``; the counts ride back from the
@@ -165,7 +197,8 @@ HOP_ORDER = ("queue", "placement", "retry", "prefill", "decode",
 #: one live batched decode step (its dispatch -> the previous step's
 #: tokens on the host) — stamped by Engine._step as a REAL tracer span
 #: (not a retroactive reqtrace hop) so the continuous profiler
-#: (obs/prof.py) attributes decode-time samples to it
+#: (obs/prof.py) attributes decode-time samples to it: to the innermost
+#: of its three children (dispatch, wait, read), work apart from slack
 SPAN_STEP_DECODE = "serve.decode_step"
 #: placing queued requests into free slots (contains SPAN_STEP_PREFILL)
 SPAN_ADMISSION = "serve.admission"
@@ -175,6 +208,12 @@ SPAN_STEP_PREFILL = "serve.prefill"
 SPAN_STEP_PREP = "serve.prep"
 #: host work of a decode step after its tokens are on the host
 SPAN_STEP_EMIT = "serve.emit"
+#: inside a decode step or a prefill: launching the program (``dry=``)
+SPAN_STEP_DISPATCH = "serve.dispatch"
+#: inside them: the blocking read of a dispatched program's result
+SPAN_STEP_WAIT = "serve.wait"
+#: inside them: the host's work on the array it has read
+SPAN_STEP_READ = "serve.read"
 
 # ------------------------------------------------------------ point events
 #: a request entered a decode slot (engine admission)
@@ -203,7 +242,8 @@ def hop_key(span_name: str) -> str:
 __all__ = ["SPAN_ROUTE", "SPAN_PLACEMENT", "SPAN_RETRY", "SPAN_HANDOFF",
            "SPAN_QUEUE", "SPAN_PREFILL", "SPAN_PREEMPT", "SPAN_DECODE",
            "SPAN_STEP_DECODE", "SPAN_ADMISSION", "SPAN_STEP_PREFILL",
-           "SPAN_STEP_PREP", "SPAN_STEP_EMIT", "HOP_ORDER", "EVENT_ADMIT",
+           "SPAN_STEP_PREP", "SPAN_STEP_EMIT", "SPAN_STEP_DISPATCH",
+           "SPAN_STEP_WAIT", "SPAN_STEP_READ", "HOP_ORDER", "EVENT_ADMIT",
            "EVENT_PREEMPT", "EVENT_SCENARIO", "EVENT_SETTLE",
            "EVENT_WEIGHT_SWAP",
            "EVENT_ROLLOUT_REJECT", "EVENT_ROLLOUT_DECISION", "hop_key"]

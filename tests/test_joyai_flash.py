@@ -519,6 +519,14 @@ def test_spans_carry_what_a_step_verified_and_yielded(tmp_path,
         assert all({"draft_verified", "draft_accepted", "tokens_emitted",
                     "moe_held", "context_tokens"} <= set(a) for a in read)
         st = eng.stats()
+        # every step is split into its dispatch, and the wait for its
+        # result and the read of it, where the next step or a settle
+        # took them
+        kids = [r["name"] for r in recs if r["kind"] == "span"
+                and r["attrs"].get("program") == "step"]
+        assert [kids.count(n) for n in (
+            S.SPAN_STEP_DISPATCH, S.SPAN_STEP_WAIT, S.SPAN_STEP_READ)] \
+            == [len(steps)] * 3 == [st["steps"]] * 3
         assert sum(a["draft_verified"] for a in read) == \
             st["drafts_verified"] == sum(len(r.drafts) for r in reqs)
         assert sum(a["draft_accepted"] for a in read) == \

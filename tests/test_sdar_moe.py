@@ -916,6 +916,14 @@ def test_spans_carry_what_a_step_refined_and_committed(tmp_path,
                     "tokens_emitted", "moe_held", "context_tokens"}
                    <= set(a) for a in read)
         st = eng.stats()
+        # every step is split into its dispatch, and the wait for its
+        # result and the read of it, where the next step or a settle
+        # took them
+        kids = [r["name"] for r in recs if r["kind"] == "span"
+                and r["attrs"].get("program") == "step"]
+        assert [kids.count(n) for n in (
+            S.SPAN_STEP_DISPATCH, S.SPAN_STEP_WAIT, S.SPAN_STEP_READ)] \
+            == [len(steps)] * 3 == [st["steps"]] * 3
         assert sum(a["block_passes"] for a in read) == st["block_passes"]
         assert sum(a["block_commits"] for a in read) == st["block_commits"]
         assert sum(a["positions_unmasked"] for a in read) == \

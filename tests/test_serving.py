@@ -509,6 +509,9 @@ class TestOneStepInFlight:
                 def copy_to_host_async(self):
                     pass
 
+                def is_ready(self):   # asked at the next dispatch
+                    return False
+
                 def __array__(self, dtype=None, copy=None):
                     log.append(("read", self.k))
                     return np.full((2,), 1 + self.k % 40, np.int32)

@@ -345,6 +345,64 @@ def _serve(lm_model, prompts, new):
 
 
 PROMPTS = [[3, 7, 11, 2, 9], [5, 1, 4], [8, 8, 2, 6, 1, 3, 7]]
+# what serve.decode_step and serve.prefill are split into, in order
+CHILDREN = ("serve.dispatch", "serve.wait", "serve.read")
+# clock reads of a steady untraced pump with two slots: two around the
+# step, one stamp a token (the same at the parent of PR 34)
+UNTRACED_CLOCK_READS = 4
+
+
+def _two_busy_stretches(lm_model, tracer):
+    """Two busy stretches (an idle settle between them) over a pool
+    small enough to preempt; the stats after ``close()`` and the
+    tracer's records in the order they were made."""
+    from bigdl_tpu.serving import LMEngine
+
+    eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=8)
+    reqs = [eng.submit(p, 9) for p in PROMPTS[:2]]
+    eng.run_until_idle()
+    reqs += [eng.submit(p, n) for p, n in zip(PROMPTS, (14, 2, 14))]
+    eng.run_until_idle()
+    eng.close()
+    st = eng.stats()
+    assert all(r.done and r.error is None for r in reqs)
+    assert st["preemptions"] >= 1 and st["settles"]["idle"] >= 2
+    return st, sorted(_records(tracer), key=lambda r: r["wall_time"])
+
+
+class _Result:
+    """Stands in for a step's result on the device: says ``ready`` when
+    asked, counts the askings, reads as the array it holds."""
+
+    def __init__(self, arr, ready):
+        self.arr, self.ready, self.asked = arr, ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.arr, dtype)
+
+
+class _Clock:
+    """``time`` with its clock reads counted."""
+
+    def __init__(self):
+        import time
+
+        self._time, self.reads = time, 0
+
+    def __getattr__(self, name):
+        fn = getattr(self._time, name)
+        if name not in ("perf_counter", "monotonic", "time"):
+            return fn
+
+        def counted():
+            self.reads += 1
+            return fn()
+
+        return counted
 
 
 class TestEngineSpans:
@@ -447,6 +505,179 @@ class TestEngineSpans:
         assert sum(s["attrs"]["ahead"] for s in steps) == \
             st["steps_ahead"] == len(steps) - len(settles)
         assert steps[0]["attrs"]["ahead"] == 0
+
+    def test_children_lie_inside_their_parent_in_order(self, traced,
+                                                       lm_model):
+        """``serve.decode_step`` and ``serve.prefill`` are split into
+        dispatch, wait and read: children of the parent, in that order,
+        disjoint, with the cycle's ``step`` (a ``serve.decode_step``
+        has none: joined through the ``serve.prep`` before it)."""
+        from bigdl_tpu.serving import spans as S
+
+        eng, _ = _serve(lm_model, PROMPTS, 6)
+        spans = sorted(_spans(traced), key=lambda s: s["wall_time"])
+        kids = {}
+        for s in spans:
+            if s["name"] in CHILDREN:
+                kids.setdefault(s["parent"], []).append(s)
+        parents = [s for s in spans if s["name"] in (
+            S.SPAN_STEP_DECODE, S.SPAN_STEP_PREFILL)]
+        # a settled step's wait and read are no one's children
+        assert sum(len(v) for v in kids.values()) == \
+            sum(len(kids.get(p["id"], ())) for p in parents) \
+            + 2 * sum(eng.stats()["settles"].values())
+        step_of = None
+        for s in spans:
+            if s["name"] == S.SPAN_STEP_PREP:
+                step_of = s["attrs"]["step"]
+            if s not in parents:
+                continue
+            mine = kids[s["id"]]
+            prefill = s["name"] == S.SPAN_STEP_PREFILL
+            names = [k["name"] for k in mine]
+            # the first step after an idle engine reads nothing
+            assert names == list(CHILDREN) or (
+                not prefill and not s["attrs"]["ahead"]
+                and names == [S.SPAN_STEP_DISPATCH])
+            end = s["wall_time"]
+            for k in mine:
+                assert k["tid"] == s["tid"]
+                assert k["attrs"]["program"] == \
+                    ("prefill" if prefill else "step")
+                assert k["attrs"]["step"] == \
+                    (s["attrs"]["step"] if prefill else step_of)
+                assert k["wall_time"] >= end - 1e-6
+                end = k["wall_time"] + k["dur_s"]
+            assert end <= s["wall_time"] + s["dur_s"] + 1e-6
+            assert set(mine[0]["attrs"]) == {"step", "program", "dry"}
+            assert all(set(k["attrs"]) == {"step", "program"}
+                       for k in mine[1:])
+
+    def test_every_executed_step_is_waited_for_and_read_once(
+            self, traced, lm_model):
+        """One ``serve.dispatch`` a ``serve.decode_step``; one
+        ``serve.wait`` and one ``serve.read`` an executed step, whether
+        the next step read it (its ``serve.decode_step``'s children,
+        with the cycle's ``step``) or a settle did (outside any, with
+        the settled step's own)."""
+        from bigdl_tpu.serving import spans as S
+
+        st, recs = _two_busy_stretches(lm_model, traced)
+        spans = [r for r in recs if r["kind"] == "span"]
+        steps = {s["id"]: s for s in _named(spans, S.SPAN_STEP_DECODE)}
+        of_step = [s for s in spans if s["name"] in CHILDREN
+                   and s["attrs"]["program"] == "step"]
+        dispatches = _named(of_step, S.SPAN_STEP_DISPATCH)
+        assert sorted(d["parent"] for d in dispatches) == sorted(steps)
+        assert [d["attrs"]["step"] for d in dispatches] == \
+            list(range(st["steps"]))
+        for name in (S.SPAN_STEP_WAIT, S.SPAN_STEP_READ):
+            mine = _named(of_step, name)
+            assert len(mine) == st["steps"]
+            settled = [s for s in mine if s["parent"] not in steps]
+            assert len(settled) == sum(st["settles"].values())
+            # a step's child reads the step before it
+            was_read = [s["attrs"]["step"] - (s["parent"] in steps)
+                        for s in mine]
+            assert sorted(was_read) == list(range(st["steps"]))
+        # a wait is followed by its read
+        order = [s["name"] for s in of_step
+                 if s["name"] != S.SPAN_STEP_DISPATCH]
+        assert order == [S.SPAN_STEP_WAIT, S.SPAN_STEP_READ] * st["steps"]
+
+    def test_one_dispatch_wait_and_read_a_prefill(self, traced, lm_model):
+        from bigdl_tpu.serving import spans as S
+
+        st, recs = _two_busy_stretches(lm_model, traced)
+        spans = [r for r in recs if r["kind"] == "span"]
+        prefills = _named(spans, S.SPAN_STEP_PREFILL)
+        assert len(prefills) == 5 + st["preemptions"]
+        of_prefill = [s for s in spans if s["name"] in CHILDREN
+                      and s["attrs"]["program"] == "prefill"]
+        for p in prefills:
+            mine = [s for s in of_prefill if s["parent"] == p["id"]]
+            assert [s["name"] for s in mine] == list(CHILDREN)
+            assert mine[0]["attrs"]["dry"] in (0, 1)
+        assert len(of_prefill) == 3 * len(prefills)
+
+    def test_dry_on_the_first_step_and_after_every_settle(self, traced,
+                                                          lm_model):
+        """With no step in flight nothing the host launched is still
+        running: ``dry`` is 1 wherever ``ahead`` is 0."""
+        from bigdl_tpu.serving import spans as S
+
+        st, recs = _two_busy_stretches(lm_model, traced)
+        spans = [r for r in recs if r["kind"] == "span"]
+        steps = {s["id"]: s for s in _named(spans, S.SPAN_STEP_DECODE)}
+        dispatches = [s for s in _named(spans, S.SPAN_STEP_DISPATCH)
+                      if s["attrs"]["program"] == "step"]
+        assert all(d["attrs"]["dry"] in (0, 1) for d in dispatches)
+        first = [d for d in dispatches
+                 if not steps[d["parent"]]["attrs"]["ahead"]]
+        assert len(first) == sum(st["settles"].values()) \
+            and all(d["attrs"]["dry"] == 1 for d in first)
+
+    @pytest.mark.parametrize("ready", [False, True])
+    def test_dry_is_what_the_result_in_flight_says(self, traced, lm_model,
+                                                   ready):
+        """``dry`` is the device buffer's own ``is_ready()``, asked once
+        a dispatch and before it."""
+        from bigdl_tpu.serving import LMEngine, spans as S
+
+        eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33)
+        for p in PROMPTS[:2]:
+            eng.submit(p, 8)
+        stubs = []
+        while eng.pump():
+            if eng._inflight is not None:
+                stubs.append(_Result(eng._inflight.result, ready))
+                eng._inflight.result = stubs[-1]
+        eng.close()
+        dispatches = [s for s in _named(_spans(traced),
+                                        S.SPAN_STEP_DISPATCH)
+                      if s["attrs"]["program"] == "step"]
+        assert len(dispatches) == len(stubs) == eng.stats()["steps"] > 4
+        assert [d["attrs"]["dry"] for d in dispatches] == \
+            [1] + [int(ready)] * (len(stubs) - 1)
+        # the last step's result is settled, never dispatched upon
+        assert [r.asked for r in stubs] == [1] * (len(stubs) - 1) + [0]
+
+    def test_untraced_steps_ask_nothing_and_read_no_new_clock(
+            self, lm_model, monkeypatch):
+        """With tracing off the split costs the no-op ``span()`` calls
+        alone: the result in flight is never asked whether it is ready,
+        nothing is recorded, and a steady pump (two slots, no admission)
+        reads the engine's clock as often as before the split: around
+        the step, and once a token."""
+        from bigdl_tpu.serving import LMEngine, engine
+
+        monkeypatch.delenv("BIGDL_TRACE_DIR", raising=False)
+        obs.reset()
+        clock = _Clock()
+        monkeypatch.setattr(engine, "time", clock)
+        try:
+            assert obs.get_tracer() is NULL_TRACER
+            eng = LMEngine(lm_model, max_batch=2, page_size=4,
+                           num_pages=33)
+            reqs = [eng.submit(p, 12) for p in PROMPTS[:2]]
+            stubs, per_pump = [], []
+            while True:
+                before = clock.reads
+                if not eng.pump():
+                    break
+                per_pump.append(clock.reads - before)
+                if eng._inflight is not None:
+                    stubs.append(_Result(eng._inflight.result, True))
+                    eng._inflight.result = stubs[-1]
+            eng.close()
+            assert all(r.done and r.error is None for r in reqs)
+            assert len(stubs) == eng.stats()["steps"] == 11
+            assert all(r.asked == 0 for r in stubs)
+            # pumps 2..10: both slots decoding, step k-1 emitted
+            assert per_pump[2:10] == [UNTRACED_CLOCK_READS] * 8
+            assert obs.get_tracer().recent() == []
+        finally:
+            obs.reset()
 
     def test_tokens_are_stamped_and_stats_report_itl(self, lm_model):
         eng, reqs = _serve(lm_model, PROMPTS, 6)
