@@ -28,7 +28,13 @@ over ``jax.lax.ragged_dot`` by measurement; sized by the rows, not by
 rows x experts; an expert without a row is not read) with the held
 experts' group sizes,
 and are added back to their tokens with their weights; the identity
-experts are one weighted add.  There is no capacity: the row buffer
+experts are one weighted add.  The kernel's lane tiles follow from the
+experts' own widths (that module's docstring has the rule and the
+measured table: a 768-wide expert's matrix goes through whole, a
+6144 x 2048 one in 2048 x 1024 blocks); its row tile is 128 whatever
+share of the router's experts the layer holds (2, 16 or 32 rows an
+expert and step in the three models served: smaller row tiles were
+measured and lost).  There is no capacity: the row buffer
 holds every assignment, so no token is dropped at any imbalance.
 
 **Another router, and a shared expert** (``models/joyai_flash.py``;

@@ -60,6 +60,14 @@ name               kind   covers
                           buffer that came back, or one made for it)
 ``validation``, ``checkpoint``, ``build_train_step``  live, as named
 =================  =====  ==================================================
+
+**Events of the kernels' call sites** (instant, made while a program is
+traced, so once a compile and never in a step): ``kernel.fallback``
+(``ops/conv_bn.py``: ``site=`` and the shapes that fell back to XLA) and
+``grouped_matmul.tiling`` (``ops/grouped_matmul.py``: once a distinct
+shape that reaches the grouped kernel, ``m=``, ``groups=``, ``k=``,
+``n=`` and the tiles chosen from them, ``tm=``, ``tk=``, ``tn=``: which
+tiles an expert layer's products run on).
 """
 
 from __future__ import annotations

@@ -631,6 +631,153 @@ def test_the_grouped_kernel_equals_ragged_dot_on_the_rows_of_a_group(m,
         grouped_matmul(lhs, rhs, sizes, impl="dense")
 
 
+#: the three expert cells' shape families scaled down: name -> (rows M,
+#: (K, N), group sizes, the tiles the rule picks).  Each holds an empty
+#: group and one that straddles two 128-row tiles; the first two leave
+#: most rows behind the last group, as a layer that holds a share of its
+#: router's experts does, "sdar" fills every row
+GROUPED_FAMILIES = {
+    "longcat-2-rows-a-group": (384, (512, 256), [2, 0, 3, 1] * 2 + [120, 9],
+                               (128, 512, 256)),
+    "joyai-16-rows-a-group-down": (512, (768, 256),
+                                   [16, 21, 0, 11, 19, 14, 25, 16, 13, 20],
+                                   (128, 768, 256)),
+    "sdar-32-rows-a-group": (384, (256, 768),
+                             [40, 0, 24, 32, 50, 30, 48, 32, 17, 47, 33, 31],
+                             (128, 256, 768)),
+    "sdar-32-rows-a-group-down": (384, (768, 256),
+                                  [40, 0, 24, 32, 50, 30, 48, 32, 17, 47, 33,
+                                   31], (128, 768, 256)),
+}
+
+
+@pytest.mark.parametrize("out", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16-out", "f32-out"])
+@pytest.mark.parametrize("family", GROUPED_FAMILIES)
+def test_the_grouped_kernel_at_the_tiles_the_rule_picks(family, out):
+    """bf16 rows and matrices through the kernel (interpreted) at the
+    tiles the rule picks for 768-wide experts (the whole width, either
+    way round), against ``ragged_dot``: equal on every row of a group,
+    with an empty group, groups that straddle row tiles and rows behind
+    the last."""
+    from bigdl_tpu.ops.grouped_matmul import _tiling, grouped_matmul
+
+    m, (k, n), sizes, tiles = GROUPED_FAMILIES[family]
+    assert _tiling(k, n) == tiles
+    ends = np.cumsum(sizes)
+    straddles = sum((e - s) // 128 != (e - 1) // 128
+                    for e, s in zip(ends, sizes) if s)
+    assert 0 in sizes and straddles and ends[-1] <= m, family
+    rng = np.random.default_rng(len(family))
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.normal(size=(len(sizes), k, n)) * k ** -0.5,
+                      jnp.bfloat16)
+    live = int(ends[-1])
+    sizes = jnp.asarray(sizes, jnp.int32)
+    want = grouped_matmul(lhs, rhs, sizes, impl="ragged",
+                          preferred_element_type=jnp.float32)
+    got = grouped_matmul(lhs, rhs, sizes, impl="pallas_interpret",
+                         preferred_element_type=out)
+    assert got.shape == (m, n) and got.dtype == out
+    # float32 sums of the same bf16 products in another order; a bf16
+    # result is rounded once more (2 ** -8 of values near 1)
+    tol = 1e-5 if out == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[:live].astype(jnp.float32), want[:live],
+                               atol=tol)
+
+
+#: the rule at the cells' REAL widths, as the table in
+#: ``ops/grouped_matmul.py``'s docstring has them: (K, N, bytes a value)
+#: -> (tm, tk, tn)
+TILES_AT_THE_CELLS = {
+    "6144x2048": ((6144, 2048, 2), (128, 2048, 1024)),
+    "2048x6144": ((2048, 6144, 2), (128, 2048, 1024)),
+    "2048x768": ((2048, 768, 2), (128, 2048, 768)),
+    "768x2048": ((768, 2048, 2), (128, 768, 2048)),
+    # float32 matrices: half as many values fit; one K tile before two
+    "2048x768-float32": ((2048, 768, 4), (128, 2048, 384)),
+    "1536x1536": ((1536, 1536, 2), (128, 1536, 768)),
+    "256x128": ((256, 128, 2), (128, 256, 128)),
+}
+
+
+@pytest.mark.parametrize("case", TILES_AT_THE_CELLS)
+def test_the_lane_tiles_follow_from_the_widths(case):
+    """``_tiling`` is a pure function of the matrices' widths: at the
+    three cells' widths it returns what the module's table says; a lane
+    tile is a multiple of 128 that divides its width; and the blocks
+    the kernel keeps in fast memory (two of each operand and of the
+    result, the float32 accumulator) fit what the module says a kernel
+    has."""
+    gm = importlib.import_module("bigdl_tpu.ops.grouped_matmul")
+    (k, n, itemsize), want = TILES_AT_THE_CELLS[case]
+    tm, tk, tn = got = gm._tiling(k, n, itemsize)
+    assert got == want and tm == gm._TM == 128
+    assert tk % 128 == 0 and k % tk == 0 and tn % 128 == 0 and n % tn == 0
+    assert 2 * tk * tn * itemsize <= gm._RHS_BUFFERS
+    assert (2 * tk * tn * itemsize + 2 * tm * tk * itemsize
+            + 2 * tm * tn * 4 + tm * tn * 4) <= gm._FAST_MEMORY
+
+
+def test_the_rule_knows_lanes_and_no_model():
+    """Widths off the 128-lane grid have no tiling (they take
+    ``ragged_dot``); the module tells shapes apart, never models."""
+    gm = importlib.import_module("bigdl_tpu.ops.grouped_matmul")
+    assert gm._tiling(64, 32) is None
+    assert gm._tiling(2048, 700) is None
+    with open(gm.__file__, encoding="utf-8") as fh:
+        source = fh.read().lower()
+    assert not [name for name in ("longcat", "joyai", "sdar")
+                if name in source]
+
+
+def test_the_kernel_path_says_its_tiles_once_a_distinct_shape(
+        tmp_path, monkeypatch):
+    """``grouped_matmul.tiling``: one event of the tracer a distinct
+    ``(M, G, K, N)`` that reaches the kernel, with the shapes and the
+    tiles chosen; ``ragged`` says nothing, and with tracing off nothing
+    is asked of the tracer at all."""
+    from bigdl_tpu.obs.trace import NullTracer
+
+    # (the package's attribute of this name is the function)
+    gm = importlib.import_module("bigdl_tpu.ops.grouped_matmul")
+    lhs = jnp.ones((128, 128), jnp.float32)
+    rhs = jnp.ones((2, 128, 256), jnp.float32)
+    sizes = jnp.asarray([3, 9], jnp.int32)
+
+    def run(rows=128, **kw):
+        return gm.grouped_matmul(lhs[:rows], rhs, sizes, **kw)
+
+    def said(tracer):
+        return [r["attrs"] for r in tracer.recent()
+                if r["name"] == "grouped_matmul.tiling"]
+
+    monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path / "trace"))
+    obs.reset()
+    gm._say.cache_clear()
+    try:
+        tracer = obs.get_tracer()
+        run(impl="ragged")
+        assert said(tracer) == []
+        run(impl="pallas_interpret")
+        run(impl="pallas_interpret")                  # the same shape
+        run(rows=64, impl="pallas_interpret")
+        tiles = dict(groups=2, k=128, n=256, tm=128, tk=128, tn=256)
+        assert said(tracer) == [dict(m=128, **tiles), dict(m=64, **tiles)]
+        # tracing off: the shared null tracer, and not one call of it
+        monkeypatch.delenv("BIGDL_TRACE_DIR")
+        obs.reset()
+        gm._say.cache_clear()
+        monkeypatch.setattr(
+            NullTracer, "event",
+            lambda *a, **k: pytest.fail("the null tracer was asked"))
+        run(impl="pallas_interpret")
+        assert obs.get_tracer().recent() == []
+    finally:
+        obs.reset()
+        gm._say.cache_clear()
+
+
 def test_the_expert_layer_round_trips_through_the_serializer(tmp_path):
     from bigdl_tpu.utils.serializer import load_module, save_module
 

@@ -451,30 +451,38 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
 @pytest.mark.slow
 def test_the_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):
     """megablox's grouped product at the tilings ``ops/grouped_matmul.py``
-    picks for LongCat-Flash's experts (16 held, 6144 x 2048 and back),
-    at the decode step's 1536 rows, a 256-token prefill's 3072 and a
-    16-token prefill's 192 (padded to whole row tiles): Mosaic compiles
-    each without a chip."""
+    picks for the three expert cells, Mosaic compiling each without a
+    chip: LongCat-Flash's experts (16 held, 6144 x 2048 and back) at the
+    decode step's 1536 rows, a 256-token prefill's 3072 and a 16-token
+    prefill's 192 (padded to whole row tiles); JoyAI's and SDAR's (32
+    and 128 groups of 2048 x 768 and back) at a step's 4096 rows and a
+    256-token prefill's 2048."""
     from bigdl_tpu.ops.grouped_matmul import _tiling, grouped_matmul
 
-    assert _tiling(6144, 2048) == (128, 2048, 1024)
-    assert _tiling(2048, 6144) == (128, 2048, 1024)
+    assert _tiling(6144, 2048) == _tiling(2048, 6144) == (128, 2048, 1024)
+    assert _tiling(2048, 768) == (128, 2048, 768)
+    assert _tiling(768, 2048) == (128, 768, 2048)
     assert _tiling(64, 32) is None
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    for m in (1536, 3072, 192):
-        for k, n, out in ((6144, 2048, jnp.bfloat16),
-                          (2048, 6144, jnp.float32)):
-            lowered = jax.jit(
-                lambda a, b, s, out=out: grouped_matmul(
-                    a, b, s, impl="pallas", interpret=False,
-                    preferred_element_type=out)).lower(
-                spec((m, k), jnp.bfloat16), spec((16, k, n), jnp.bfloat16),
-                spec((16,), jnp.int32))
-            assert "tpu_custom_call" in lowered.as_text()
-            lowered.compile()
+    #: groups, (in, hidden), rows a call
+    cells = ((16, (6144, 2048), (1536, 3072, 192)),
+             (32, (2048, 768), (4096, 2048)),
+             (128, (2048, 768), (4096, 2048)))
+    for g, (dim, hidden), calls in cells:
+        for m in calls:
+            for k, n, out in ((dim, hidden, jnp.bfloat16),
+                              (hidden, dim, jnp.float32)):
+                lowered = jax.jit(
+                    lambda a, b, s, out=out: grouped_matmul(
+                        a, b, s, impl="pallas", interpret=False,
+                        preferred_element_type=out)).lower(
+                    spec((m, k), jnp.bfloat16),
+                    spec((g, k, n), jnp.bfloat16), spec((g,), jnp.int32))
+                assert "tpu_custom_call" in lowered.as_text()
+                lowered.compile()
 
 
 @pytest.mark.slow
