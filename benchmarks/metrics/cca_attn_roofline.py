@@ -1,0 +1,17 @@
+"""Kernels: the least time the chip could take for the attention over
+the compressed K/V rows in the window's decode steps, over the device
+time of the ``cca.attn`` scope.
+
+Bytes: the K and V rows of the slots' contexts once a slot and layer
+(``context_tokens`` of each ``serve.decode_step`` span x 2 x 256 values:
+a key head's 4 query heads share one read); operations: scores and mix
+of 8 heads over those rows (``lib/flops_cca_moe.py``)."""
+
+from benchmarks.lib import flops_cca_moe as f
+
+
+def read(run):
+    cfg, c = run.config, run.counters
+    return f.share(run, f.scopes_ms_per_call(run, ("cca.attn",)), lambda a: (
+        f.attn_flops(cfg, a["context_tokens"]),
+        f.attn_bytes(cfg, a["context_tokens"], c["kv_itemsize"])))

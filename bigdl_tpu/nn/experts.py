@@ -33,7 +33,8 @@ experts' own widths (that module's docstring has the rule and the
 measured table: a 768-wide expert's matrix goes through whole, a
 6144 x 2048 one in 2048 x 1024 blocks); its row tile is 128 whatever
 share of the router's experts the layer holds (2, 16 or 32 rows an
-expert and step in the three models served: smaller row tiles were
+expert and step in the three models served through PR 35, 16 rows of
+256 top-1 assignments in ``models/zaya.py``: smaller row tiles were
 measured and lost).  There is no capacity: the row buffer
 holds every assignment, so no token is dropped at any imbalance.
 
@@ -53,6 +54,17 @@ zero-compute expert it is computed where the token lives, whole, so the
 sum over all shares counts it once (a deployment adds the shares'
 routed parts to one ``S(x)``).  It is a dense MLP and runs under the
 scope ``ffn``, not under ``moe.*``.
+
+**A router the model gives it** (``models/zaya.py``: an MLP on a
+narrow down-projection that adds the router row of the layer below, so
+the scores are no function of this layer's input alone):
+``own_router=False`` builds the layer without ``router`` and ``bias``,
+and :meth:`DroplessExperts.apply` then takes ``routed=(idx, w)``, the
+chosen experts ``(N, top_k)`` and their float32 weights, as
+:meth:`DroplessExperts.route` returns them; everything behind the
+choice (held and absent experts, the sort, the grouped products, the
+counts) is the layer above.  ``top_k`` 1 is that model's; handing in
+``routed=layer.route(params, x)`` is the layer above, bit for bit.
 
 It also counts what it routed (``counts``): assignments to held,
 zero-compute and absent experts, how many held experts got a token, and
@@ -89,7 +101,8 @@ class DroplessExperts(AbstractModule):
     def __init__(self, dim: int, hidden: int, n_routed: int, n_zero: int,
                  top_k: int, scale: float = 1.0, held=None,
                  score: str = "softmax", renormalise: bool = False,
-                 shared_hidden: int = 0, init: bool = True):
+                 shared_hidden: int = 0, own_router: bool = True,
+                 init: bool = True):
         super().__init__()
         if score not in ("softmax", "sigmoid"):
             raise ValueError(f"score {score!r}: softmax or sigmoid")
@@ -105,15 +118,21 @@ class DroplessExperts(AbstractModule):
                             n_zero=n_zero, top_k=top_k, scale=scale,
                             held=(lo, hi), score=score,
                             renormalise=renormalise,
-                            shared_hidden=shared_hidden)
+                            shared_hidden=shared_hidden,
+                            own_router=own_router)
         self.dim, self.hidden = dim, hidden
         self.n_routed, self.n_zero = n_routed, n_zero
         self.top_k, self.scale = top_k, float(scale)
         self.lo, self.hi = lo, hi
         self.score, self.renormalise = score, bool(renormalise)
         self.shared_hidden = int(shared_hidden)
+        self.own_router = bool(own_router)
+        if not self.own_router:
+            self.param_names = tuple(
+                n for n in type(self).param_names
+                if n not in ("router", "bias"))
         if self.shared_hidden:
-            self.param_names = type(self).param_names + (
+            self.param_names = self.param_names + (
                 "s_gate", "s_up", "s_down")
         for n in self.param_names:
             setattr(self, n, None)
@@ -130,8 +149,10 @@ class DroplessExperts(AbstractModule):
         from bigdl_tpu.nn.latent import _draw
 
         g = self.n_held
-        self.router = _draw((self.n_routed + self.n_zero, self.dim))
-        self.bias = jnp.zeros((self.n_routed + self.n_zero,), jnp.float32)
+        if self.own_router:
+            self.router = _draw((self.n_routed + self.n_zero, self.dim))
+            self.bias = jnp.zeros((self.n_routed + self.n_zero,),
+                                  jnp.float32)
         # (group, in, out): the grouped product's right-hand side
         self.w_gate = _draw((g, self.dim, self.hidden))
         self.w_up = _draw((g, self.dim, self.hidden))
@@ -163,11 +184,13 @@ class DroplessExperts(AbstractModule):
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return idx, self.scale * w
 
-    def apply(self, params, state, input, *, mask=None, training=False,
-              rng=None):
+    def apply(self, params, state, input, *, mask=None, routed=None,
+              training=False, rng=None):
         """``input`` (N, dim) -> ``((y (N, dim), counts (5,) int32),
         state)``.  ``mask`` (N,) marks the rows that are real tokens;
-        padding rows are routed nowhere, get 0, and are not counted."""
+        padding rows are routed nowhere, get 0, and are not counted.
+        ``routed`` is another router's ``(idx, w)`` in :meth:`route`'s
+        form (a layer without a router of its own needs it)."""
         import jax
         import jax.numpy as jnp
 
@@ -175,8 +198,11 @@ class DroplessExperts(AbstractModule):
 
         x = input
         n, k, g = x.shape[0], self.top_k, self.n_held
+        if routed is None and not self.own_router:
+            raise ValueError("a layer built with own_router=False is "
+                             "handed its routing (routed=(idx, w))")
         with jax.named_scope("moe.route"):
-            idx, w = self.route(params, x)
+            idx, w = self.route(params, x) if routed is None else routed
             real = jnp.ones((n,), bool) if mask is None else mask
             real = real[:, None]
             is_zero = (idx >= self.n_routed) & real

@@ -424,6 +424,11 @@ SERVE_BLOCK_POSITIONS_TOTAL = _m(
     "Masked positions met by the refining passes of a model that "
     "generates by blocks, by outcome: unmasked (the pass made the "
     "position final) or left_masked (a later pass will)")
+SERVE_SLOT_STATE_BYTES = _m(
+    "bigdl_serve_slot_state_bytes", "gauge", policy="last",
+    doc="Bytes of state a decode slot carries beside its pages, over all "
+        "layers, under a model that declares one (state_spec): rows of "
+        "the slot's previous token, not keys and values")
 SERVE_REJECTS_TOTAL = _m(
     "bigdl_serve_rejects_total", "counter",
     doc="Admissions rejected 503 + Retry-After (queue full past the "

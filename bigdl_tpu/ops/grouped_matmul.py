@@ -68,11 +68,23 @@ call, median ms a product; *read* = the hit groups' matrices once at
       (128, 2048, 256) 0.517    (128, 2048, 768) 0.487  (16, ..) 0.548
       and back: (128, 256, 1024) 0.622   (128, 768, 2048) 0.553
       (32, 768, 2048) 0.522     (16, ..) 0.555
+    16 groups of 2048 x 2048, 256 top-1 rows, all live (largest group
+    22), read 0.16  (TPU v5 lite, 2026-09-30, PR 37: the fourth shape)
+      (128, 2048, 1024) 0.294   (64, ..) 0.307   (16, ..) 0.304
+      (128, 1024, 2048) 0.305   (128, 2048, 512) 0.290
+      (128, 1024, 1024) 0.290   (.., 2048, 2048) does not fit
+      and back (float32 out): (128, 2048, 1024) 0.274  (16, ..) 0.311
+      (128, 1024, 2048) 0.293   (64, 2048, 512) 0.287
+      (64, 1024, 1024) 0.295    (16, 2048, 2048) 0.313, wider rows do not fit
+      the rule's (128, 2048, 1024) through ``grouped_matmul``: 0.279, 0.282
 
 Runs of one tiling differ by about 3 %.  The wide experts sit at what
 the copies take under every tiling tried, so theirs is what it was; the
 768-wide ones gain where ``K`` = 768 was cut in three (a sixth of the
-time) and little where ``N`` was.
+time) and little where ``N`` was.  The 2048 x 2048 experts read
+0.27-0.31 under every tiling that fits, 0.11-0.15 over their read (16
+visits of two grid steps and the group metadata, not the tiles): the
+rule's choice stands.
 """
 
 from __future__ import annotations

@@ -42,6 +42,25 @@ written and read through them:
   the padded tail of a bucketed prefill write there, and the decode
   mask (``position <= length``) guarantees it is never read.
 
+**State that is not keys and values** lives here too, beside the pages
+(a model's ``state_spec``: ``serving/engine.py`` says what the engine
+asks).  Some layers need, to form a token's rows, a few rows of the
+slot's PREVIOUS token (``models/zaya.py``: two causal convolutions and
+a shifted value) or a running state (a state-space or linear-attention
+layer): not a function of the token alone, so no page holds it.  It is
+one array a declared shape, ``(layers, max_slots, *shape)``,
+indexed by SLOT (not by page: a slot has exactly one, whatever its
+length), handed to the step and the prefill and taken back with the
+pools (:meth:`PagedKVCache.buffers`), written by
+:func:`write_slot_state` (a prefill: the whole of one slot's, so
+nothing of the slot's previous occupant survives) and guarded by
+:func:`keep_inactive` (a step: a slot that did not run keeps what it
+had).  Nothing is snapshotted: a preempted request's second prefill
+rebuilds its state from its tokens, which is exact for a state that is
+a function of a bounded past (a layer whose state sums over the whole
+past, re-prefilled in chunks, would need its state at the chunk's
+start kept).
+
 The allocator is plain host Python — a free list and per-slot page
 lists.  Decode grows a slot one page at a time as its length crosses a
 page boundary; exhaustion is surfaced to the engine, which preempts the
@@ -66,7 +85,7 @@ class PagedKVCache:
                  row_width: Optional[int] = None, buffers: int = 2,
                  page_size: int = 16, num_pages: int = 64,
                  max_slots: int = 8, max_len: int = 256,
-                 dtype=None):
+                 dtype=None, state_spec: Optional[dict] = None):
         import jax.numpy as jnp
 
         if page_size < 1:
@@ -97,6 +116,16 @@ class PagedKVCache:
         # a model without a separate V has one buffer, not a second of
         # zeros
         self.vp = jnp.zeros(shape, self.dtype) if buffers == 2 else None
+        #: what a slot carries a layer beside its pages (module
+        #: docstring): one ``(layers, max_slots, *shape)`` array a shape
+        #: of the model's ``state_spec`` (``layers``, ``shapes``,
+        #: ``dtype``), none for a model without one
+        spec = state_spec or {"layers": 0, "shapes": ()}
+        self.state = tuple(
+            jnp.zeros((int(spec["layers"]), self.max_slots)
+                      + tuple(int(n) for n in shp),
+                      spec.get("dtype") or self.dtype)
+            for shp in spec["shapes"])
         self.page_tables = np.zeros(
             (self.max_slots, self.max_pages_per_slot), np.int32)
         self.lengths = np.zeros((self.max_slots,), np.int32)
@@ -174,14 +203,26 @@ class PagedKVCache:
         return list(self._slot_pages[slot])
 
     # ------------------------------------------------------ device state
-    def buffers(self) -> tuple:
-        """The device buffers a step takes and returns, in order."""
+    def pools(self) -> tuple:
+        """The page pools: K, or K and V."""
         return (self.kp,) if self.vp is None else (self.kp, self.vp)
+
+    def buffers(self) -> tuple:
+        """The device buffers a step takes and returns, in order: the
+        page pools, then the slots' state (none for most models)."""
+        return self.pools() + self.state
 
     def set_buffers(self, bufs):
         self.kp = bufs[0]
-        if len(bufs) > 1:
+        n = len(self.pools())
+        if n > 1:
             self.vp = bufs[1]
+        self.state = tuple(bufs[n:])
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of state a slot carries over all its layers."""
+        return sum(s.dtype.itemsize * s.size // self.max_slots
+                   for s in self.state)
 
     def device_tables(self, pages: Optional[int] = None):
         """(page_tables, lengths) as jnp arrays for the next step.
@@ -262,6 +303,25 @@ def write_prompt_pages(pages, layer: int, page_ids, rows):
         .astype(pages.dtype))
 
 
+def write_slot_state(state, slot, rows):
+    """A prefill's write: ``rows`` (one ``(layers, *shape)`` array a
+    declared shape: the state after the prompt's last REAL token) become
+    the whole state of ``slot`` (a traced scalar): every layer of it, so
+    nothing of the slot's previous occupant is left."""
+    return tuple(s.at[:, slot].set(r.astype(s.dtype))
+                 for s, r in zip(state, rows))
+
+
+def keep_inactive(new, old, active):
+    """A step's guard: the state ``new`` where ``active`` (slots,), what
+    the slot had (``old``) where it did not run."""
+    import jax.numpy as jnp
+
+    return tuple(
+        jnp.where(active.reshape((1, -1) + (1,) * (n.ndim - 2)), n, o)
+        for n, o in zip(new, old))
+
+
 def gather_pages(pages, page_table, layer: Optional[int] = None):
     """The pages a ``(B, maxp)`` table names, as per-slot contiguous
     token rows ``(B, maxp*P, H*Dh)``: position ``t`` of slot ``b`` is
@@ -276,5 +336,5 @@ def gather_pages(pages, page_table, layer: Optional[int] = None):
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], g.shape[3])
 
 
-__all__ = ["PagedKVCache", "gather_pages", "pool_shape",
-           "write_prompt_pages", "write_token_rows"]
+__all__ = ["PagedKVCache", "gather_pages", "keep_inactive", "pool_shape",
+           "write_prompt_pages", "write_slot_state", "write_token_rows"]

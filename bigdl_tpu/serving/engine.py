@@ -70,7 +70,26 @@ that with the production shape:
   before it are final, so a step yields 0 to ``B`` tokens a slot and a
   commit none; ``ServeRequest.unmasked`` keeps every generated
   position's token and the pass that unmasked it.  Same loop, same
-  settles; greedy only.
+  settles; greedy only;
+* **state a slot carries that is not keys and values**: a model whose
+  layers need rows of the slot's previous token to form this token's
+  (``state_spec``; ``models/zaya.py``: two causal convolutions and a
+  shifted value) declares their shapes, and the engine keeps them for
+  ``max_batch`` slots BESIDE the pages, in the cache manager
+  (``serving/cache.py``), donated to ``jit_step`` and ``jit_prefill``
+  with the pools and carried on the device from step to step like a
+  draft's or a block's state.  Its life is the slot's: **the prefill
+  writes it** (the state after the prompt's last REAL token, whatever
+  the bucket's padded tail holds), all of it, so nothing of the slot's
+  previous occupant survives an admission; a step advances it for the
+  slots that ran and leaves an inactive slot's alone; a release leaves
+  it where it is (the next admission overwrites it).  **No snapshot is
+  taken at a preemption**: the request comes back with its generated
+  prefix as prompt and its second prefill rebuilds the state from the
+  tokens, exactly, because this state is a function of the last
+  position alone.  A recurrent layer (a state that sums over the whole
+  past) prefilled in chunks WILL need its state kept at the chunk's
+  start; nothing here does that yet.
 
 **What the engine asks of a model** (``models/transformer.py`` and
 ``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
@@ -101,7 +120,20 @@ pick=)`` -> ``(caches, (tokens, masked, passes, lengths) after the
 step, kind (S,), counts)``: it forwards every slot's block, writes its
 rows at ``lengths + 0 .. B-1``, unmasks where a position is masked and
 commits (``lengths + B``, a new block all masked) where none is; the
-engine carries that state to the next step untouched.  ``int8=True``
+engine carries that state to the next step untouched.  **A model
+whose slots carry state** (``models/zaya.py``) answers
+``state_spec(params)`` -> ``{"layers", "shapes", "dtype"}`` (one array
+``(layers, max_batch, *shape)`` a shape) beside ``cache_spec``; it
+generates one token a step and is sampled by the engine like
+``models/transformer.py``, and its two entry points hand the state
+through: ``paged_prefill(params, caches, prompt, t0, pages)`` ->
+``(caches, logits, counts, rows)`` with ``rows`` one ``(layers,
+*shape)`` array a shape, the state after position ``t0 - 1`` (the
+engine writes them into the slot), and ``paged_decode(params, caches,
+tables, lengths, tokens, active, state=, ...)`` -> ``(caches, logits,
+counts, state)`` (the engine keeps an inactive slot's old state).  A
+model without ``state_spec`` runs the programs it always ran; one with
+it neither drafts nor generates by blocks.  ``int8=True``
 needs the model's
 ``quantize_for_decode``, ``tp > 1`` its ``tp_decode_step``; a model
 without them is refused with a ``ValueError`` that says so.
@@ -124,7 +156,8 @@ import numpy as np
 
 from bigdl_tpu import obs
 from bigdl_tpu.serving.batcher import RequestQueue, ServeRequest
-from bigdl_tpu.serving.cache import PagedKVCache
+from bigdl_tpu.serving.cache import (PagedKVCache, keep_inactive,
+                                     write_slot_state)
 from bigdl_tpu.serving.drain import HANDOFF_ERROR
 from bigdl_tpu.serving import spans
 from bigdl_tpu.obs import names
@@ -332,6 +365,12 @@ class LMEngine:
         if self._block and (self._drafts or self.int8 or self.tp > 1):
             raise ValueError("a model that generates by blocks neither "
                              "drafts nor offers int8 or tp decode")
+        # ... or carries state that is not keys and values, a slot
+        state = model.state_spec(self.params) \
+            if hasattr(model, "state_spec") else None
+        if state and (self._drafts or self._block):
+            raise ValueError("a model whose slots carry state neither "
+                             "drafts nor generates by blocks")
         self.max_len = int(spec["max_len"])
         if self._block and (self.page_size % self._block
                             or self.max_len % self._block):
@@ -348,7 +387,7 @@ class LMEngine:
             row_width=int(spec["row_width"]), buffers=int(spec["buffers"]),
             page_size=self.page_size, num_pages=pages,
             max_slots=self.max_batch, max_len=self.max_len,
-            dtype=cache_dtype)
+            dtype=cache_dtype, state_spec=state)
         self.queue = RequestQueue(queue_capacity or cfg.queue_capacity)
         self._slots: List[Optional[_Active]] = [None] * self.max_batch
         self._stash: collections.deque = collections.deque()
@@ -461,6 +500,11 @@ class LMEngine:
             names.SERVE_BLOCK_POSITIONS_TOTAL,
             "Masked positions a block model's refining passes met, by "
             "outcome", labels=("outcome",)) if self._block else None
+        if self.cache.state:
+            reg.gauge(
+                names.SERVE_SLOT_STATE_BYTES,
+                "Bytes of state a slot carries beside its pages, over "
+                "all layers").set(float(self.cache.state_bytes_per_slot()))
         self._swap_counter = reg.counter(
             names.SERVE_WEIGHT_SWAPS_TOTAL,
             "Live weight hot-swaps completed, by promoted version",
@@ -574,7 +618,9 @@ class LMEngine:
 
         model, page_size = self.model, self.page_size
         qparams = self._qparams
-        n = len(self.cache.buffers())
+        # the cache's buffers: its pools and, behind them, the slots'
+        # state (none unless the model declares one)
+        n, pools = len(self.cache.buffers()), len(self.cache.pools())
 
         # one name for both programs: the profiler and the readers of its
         # trace know the decode step as ``jit_step``
@@ -646,9 +692,19 @@ class LMEngine:
                 tables, lengths, prev, tokens, fresh, temps, active, key = \
                     rest[n:]
                 tokens = jnp.where(fresh, tokens, prev)
-                caches, logits, counts = model.paged_decode(
-                    params, rest[:n], tables, lengths, tokens, active,
-                    page_size=page_size, qparams=qparams)
+                if n > pools:
+                    # the slots' state goes through the model and comes
+                    # back advanced where the slot ran
+                    caches, logits, counts, state = model.paged_decode(
+                        params, rest[:pools], tables, lengths, tokens,
+                        active, state=rest[pools:n], page_size=page_size,
+                        qparams=qparams)
+                    caches = (*caches,
+                              *keep_inactive(state, rest[pools:n], active))
+                else:
+                    caches, logits, counts = model.paged_decode(
+                        params, rest[:n], tables, lengths, tokens, active,
+                        page_size=page_size, qparams=qparams)
                 nxt = sample_step(logits, temps, active, key)
                 # the routing counts ride back with the tokens
                 return (*caches, nxt) if counts is None \
@@ -663,7 +719,7 @@ class LMEngine:
         import jax
 
         model = self.model
-        n = len(self.cache.buffers())
+        n, pools = len(self.cache.buffers()), len(self.cache.pools())
 
         if self._drafts:
             def prefill(params, *rest):
@@ -690,10 +746,16 @@ class LMEngine:
             def prefill(params, *rest):
                 # rest: the cache's buffers (donated), then the prompt
                 # (1, bucket) zero-padded past t0, t0, the bucket's pages,
-                # the temperature, the key
-                prompt, t0, pages, temp, key = rest[n:]
-                caches, logits, counts = model.paged_prefill(
-                    params, rest[:n], prompt, t0, pages)
+                # the temperature, the key; with state, the slot it is for
+                prompt, t0, pages, temp, key = rest[n:n + 5]
+                if n > pools:
+                    caches, logits, counts, rows = model.paged_prefill(
+                        params, rest[:pools], prompt, t0, pages)
+                    caches = (*caches, *write_slot_state(
+                        rest[pools:n], rest[n + 5], rows))
+                else:
+                    caches, logits, counts = model.paged_prefill(
+                        params, rest[:n], prompt, t0, pages)
                 first = sample_first(logits, temp, key)
                 return (*caches, first) if counts is None \
                     else (*caches, first, counts)
@@ -819,16 +881,22 @@ class LMEngine:
         tracer = self._tracer
         n = len(self.cache.buffers())
         step = self._steps
+        # a model whose slots carry state: the slot the prefill writes
+        extra = (np.int32(slot),) if self.cache.state else ()
         with tracer.span(spans.SPAN_STEP_PREFILL, step=step,
                          bucket=bucket, prompt_len=t0,
                          request=req.id) as span_id:
+            if extra:
+                tracer.add_attrs(
+                    span_id,
+                    state_bytes=self.cache.state_bytes_per_slot())
             with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
                              program="prefill") as dispatch_id:
                 self._note_dry(tracer, dispatch_id)
                 out = self._prefill_fn(bucket)(
                     self.params, *self.cache.buffers(),
                     jnp.asarray(prompt), t0, jnp.asarray(page_arg),
-                    float(req.temperature), sub)
+                    float(req.temperature), sub, *extra)
                 self.cache.set_buffers(out[:n])
                 self.cache.lengths[slot] = t0
             # the prefill was enqueued behind the step in flight: the
@@ -1145,7 +1213,7 @@ class LMEngine:
             heads = self._cache_spec.get("heads", 1)
             kv_heads = self._cache_spec.get("kv_heads", heads)
             step_bytes = self._weight_bytes + \
-                self.cache.n_layer * len(self.cache.buffers()) / 2.0 \
+                self.cache.n_layer * len(self.cache.pools()) / 2.0 \
                 * decode_hbm_bytes(
                     self.max_batch, heads,
                     self.cache.row_width // kv_heads, self.page_size,
@@ -1493,6 +1561,8 @@ class LMEngine:
             "queue_depth": self.queue.depth(),
             "kv_pages_in_use": self.cache.pages_in_use(),
             "kv_pages_total": self.cache.num_pages - 1,
+            # what a slot carries beside its pages (0: nothing)
+            "state_bytes_per_slot": self.cache.state_bytes_per_slot(),
             "draining": self.draining,
             "weight_version": self.weight_version,
             "manifest_sha": self.manifest_sha,

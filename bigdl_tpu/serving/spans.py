@@ -164,6 +164,14 @@ Three families:
   every generated position, its token and the pass of its block that
   unmasked it.  ``serve.decode_step``'s length is still one step period
   less the host's work; a reader sees a gap of one to five periods.
+
+  **Under a model whose slots carry state beside their pages**
+  (``state_spec``; ``models/zaya.py``) ``serve.prefill`` also carries
+  ``state_bytes=``, what the prefill wrote into the slot over all its
+  layers; the same number is ``stats()["state_bytes_per_slot"]`` and
+  the gauge ``bigdl_serve_slot_state_bytes``.  ``serve.decode_step``
+  carries the routing counts and ``context_tokens`` as under any expert
+  model.
 * ``EVENT_*`` — point events the engine/simulator stamp regardless of
   request tracing.
 """

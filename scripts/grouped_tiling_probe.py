@@ -42,6 +42,7 @@ FAMILIES = {
     "joyai_decode": (512, 8, 256, 32, 2048, 768),
     "sdar_decode": (512, 8, 128, 128, 2048, 768),
     "sdar_prefill": (256, 8, 128, 128, 2048, 768),
+    "zaya_decode": (256, 1, 16, 16, 2048, 2048),
 }
 TOY = {
     "toy_few_rows": (16, 4, 24, 4, 256, 128),
@@ -57,6 +58,8 @@ LANES = {
                    (6144, 256), (2048, 512)],
     (2048, 6144): [(2048, 1024), (2048, 1536), (1024, 2048), (2048, 2048),
                    (2048, 512)],
+    (2048, 2048): [(2048, 1024), (1024, 2048), (2048, 512), (1024, 1024),
+                   (2048, 2048)],
     (256, 128): [(128, 128), (256, 128)],
     (128, 256): [(128, 128), (128, 256)],
     (128, 384): [(128, 128), (128, 384)],

@@ -404,7 +404,8 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     # the engine's own builders, given shapes in place of an engine
     eng = types.SimpleNamespace(
         model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=0, cache=types.SimpleNamespace(buffers=lambda: (buf,)),
+        _block=0, cache=types.SimpleNamespace(buffers=lambda: (buf,),
+                                    pools=lambda: (buf,)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)
     b = slots
@@ -456,12 +457,14 @@ def test_the_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):
     decode step's 1536 rows, a 256-token prefill's 3072 and a 16-token
     prefill's 192 (padded to whole row tiles); JoyAI's and SDAR's (32
     and 128 groups of 2048 x 768 and back) at a step's 4096 rows and a
-    256-token prefill's 2048."""
+    256-token prefill's 2048; ZAYA1's (16 groups of 2048 x 2048 and
+    back) at a step's and a 256-token prefill's 256 top-1 rows."""
     from bigdl_tpu.ops.grouped_matmul import _tiling, grouped_matmul
 
     assert _tiling(6144, 2048) == _tiling(2048, 6144) == (128, 2048, 1024)
     assert _tiling(2048, 768) == (128, 2048, 768)
     assert _tiling(768, 2048) == (128, 768, 2048)
+    assert _tiling(2048, 2048) == (128, 2048, 1024)
     assert _tiling(64, 32) is None
 
     def spec(shape, dtype):
@@ -470,7 +473,8 @@ def test_the_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):
     #: groups, (in, hidden), rows a call
     cells = ((16, (6144, 2048), (1536, 3072, 192)),
              (32, (2048, 768), (4096, 2048)),
-             (128, (2048, 768), (4096, 2048)))
+             (128, (2048, 768), (4096, 2048)),
+             (16, (2048, 2048), (256,)))
     for g, (dim, hidden), calls in cells:
         for m in calls:
             for k, n, out in ((dim, hidden, jnp.bfloat16),
@@ -524,7 +528,8 @@ def test_draft_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
     eng = types.SimpleNamespace(
         model=probe, page_size=page, _qparams=None, _drafts=True,
-        cache=types.SimpleNamespace(buffers=lambda: (buf,)),
+        cache=types.SimpleNamespace(buffers=lambda: (buf,),
+                                    pools=lambda: (buf,)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)
     ints = spec((slots,), jnp.int32)
@@ -591,7 +596,8 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
     eng = types.SimpleNamespace(
         model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=block, cache=types.SimpleNamespace(buffers=lambda: (buf, buf)),
+        _block=block, cache=types.SimpleNamespace(
+            buffers=lambda: (buf, buf), pools=lambda: (buf, buf)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)
     ints = spec((slots,), jnp.int32)
@@ -630,3 +636,111 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
             # the float32 logits of 512 positions (311 MB) and little
             # else: the gather body's step held 475 MB
             assert temp < 350e6, temp
+
+
+# ZAYA1-8B's attention at the cell's engine size
+# (benchmarks/configs/zaya1_8b.json): 256 slots, one token a slot, 8
+# query heads over 2 key heads of 128 lanes, pages of 16, 128 pages a
+# slot, the stacked bfloat16 pools of ten layers
+CCA = dict(slots=256, block=1, heads=8, kv_heads=2, head_dim=128, page=16,
+           maxp=128, layers=10)
+
+
+def test_cca_decode_is_one_kernel_at_the_engine_shape(monkeypatch):
+    """4 query rows a key head over 256-value rows: the page-walking
+    kernel's case (``S x H > H_kv``), ONE Mosaic call and no gather."""
+    b = CCA["slots"]
+    shapes = _grouped_shapes(CCA)
+    shapes = (((b, CCA["heads"], CCA["head_dim"]), jnp.bfloat16),) \
+        + shapes[1:]
+
+    def text():   # a new function a call: nothing traced is reused
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+        return jax.jit(lambda *a: _grouped(*a)).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+    lowered = text()
+    assert lowered.count("tpu_custom_call") == 1
+    assert 'kernel_name = "grouped_decode_attention"' in lowered
+    assert "stablehlo.gather" not in lowered
+
+
+@pytest.mark.slow
+def test_state_engine_programs_work_on_the_cache_as_it_lies(one_chip,
+                                                            monkeypatch):
+    """ZAYA1-8B's decode step and a prefill at the published widths and
+    the cell's engine size (two layers, all 16 experts, the whole
+    vocabulary; 256 slots, the default pool of 32769 pages, the slots'
+    three state arrays), from shapes alone, compiled for the described
+    v5e with both pools AND the state donated: one row written a slot
+    and pool, then ONE ``grouped_decode_attention`` kernel a layer at 4
+    query rows a key head, no instruction of a whole pool's size but
+    the scatters, and temporaries that are the head's float32 logits
+    and little else."""
+    import functools
+    import types
+
+    from bigdl_tpu.models import zaya_reference as ref
+    from bigdl_tpu.models.zaya import PUBLISHED, Zaya
+    from bigdl_tpu.serving.engine import LMEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = one_chip
+    dt = jnp.bfloat16
+    sizes = dict(PUBLISHED, num_hidden_layers=2)
+    slots, page, max_len = 256, 16, 2048
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    cfg = dict(sizes, max_len=max_len, rope_parameters={"hybrid": dict(
+        partial_rotary_factor=0.5, rope_theta=5e6)})
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(cfg), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    probe = Zaya(max_len=max_len, params=weights, **sizes)
+    cs, ss = probe.cache_spec(weights), probe.state_spec(weights)
+    assert (cs["row_width"], cs["kv_heads"], cs["heads"], cs["buffers"]) \
+        == (256, 2, 8, 2)
+    assert ss["shapes"] == ((1280,), (1280,), (128,))
+    buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
+    state = tuple(spec((ss["layers"], slots) + shp, dt)
+                  for shp in ss["shapes"])
+    eng = types.SimpleNamespace(
+        model=probe, page_size=page, _qparams=None, _drafts=False,
+        _block=0, cache=types.SimpleNamespace(
+            buffers=lambda: (buf, buf) + state, pools=lambda: (buf, buf)),
+        _prefill_fns={})
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((slots,), jnp.int32)
+    flags = spec((slots,), jnp.bool_)
+    programs = {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, buf, *state, spec((slots, 128), jnp.int32), ints,
+            ints, ints, flags, spec((slots,), jnp.float32), flags, key),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, buf, *state, spec((1, 256), jnp.int32),
+            spec((), jnp.int32), spec((256 // page,), jnp.int32),
+            spec((), jnp.float32), key, spec((), jnp.int32))}
+    buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
+    for name, lowered in programs.items():
+        text = lowered.as_text()
+        assert text.count("tpu_custom_call") >= 2, name
+        if name == "step":
+            assert _kernel_calls(text, "grouped_decode_attention") \
+                == sizes["num_hidden_layers"]
+        else:
+            assert "grouped_decode_attention" not in text
+        compiled = lowered.compile()
+        ops = _whole_cache_ops(compiled, buf)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        print(f"state {name}: whole-cache instructions {ops}, "
+              f"temporaries {temp / 1e6:.1f} MB, one cache buffer "
+              f"{buffer_bytes / 1e6:.1f} MB")
+        assert set(ops) <= {"parameter", "scatter",
+                            "scatter fusion"}, (name, ops)
+        # 256 x 262272 float32 logits are 269 MB
+        assert temp < 700e6, (name, temp)
+
