@@ -292,6 +292,9 @@ class JoyAIFlash(_Composite):
                 "row_width": layer["attn"].row_width,
                 "buffers": 1, "max_len": self._config["max_len"],
                 "dtype": params["embed"]["weight"].dtype,
+                # the attention kernel's query rows a slot: the heads
+                # of both verified positions
+                "attn_query_rows": DRAFT_TOKENS * layer["attn"].n_head,
                 "expert_slots": n_moe * layer["moe"].n_held}
 
     def draft_spec(self, params) -> dict:

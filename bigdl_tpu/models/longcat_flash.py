@@ -222,6 +222,8 @@ class LongCatFlash(_Composite):
                 "row_width": layer["attn0"].row_width,
                 "buffers": 1, "max_len": self._config["max_len"],
                 "dtype": params["embed"]["weight"].dtype,
+                # the attention kernel's query rows a slot
+                "attn_query_rows": layer["attn0"].n_head,
                 # held experts over the step's expert layers: what the
                 # mean load of a held expert is taken over
                 "expert_slots": self.n_layer * layer["moe"].n_held}

@@ -395,6 +395,9 @@ class SDARMoE(_Composite):
                 "row_width": attn.row_width, "buffers": 2,
                 "max_len": self._config["max_len"],
                 "dtype": params["embed"]["weight"].dtype,
+                # the attention kernel's query rows a slot: every head
+                # at each of the block's positions
+                "attn_query_rows": self.block * attn.n_head,
                 "expert_slots": self.n_layer * layer["moe"].n_held}
 
     def block_spec(self, params) -> dict:

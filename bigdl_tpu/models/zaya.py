@@ -512,6 +512,8 @@ class Zaya(_Composite):
                 "row_width": attn.row_width, "buffers": 2,
                 "max_len": self._config["max_len"],
                 "dtype": params["embed"]["weight"].dtype,
+                # the attention kernel's query rows a slot
+                "attn_query_rows": attn.n_head,
                 "expert_slots": self.n_layer * layer["moe"].n_held}
 
     def state_spec(self, params) -> dict:

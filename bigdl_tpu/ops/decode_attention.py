@@ -27,6 +27,8 @@ between them:
     each block into a float32 online softmax, key head by key head:
     a key head's ``S x H / H_kv`` query rows share the one read of its
     lanes.  Nothing is gathered in HBM and no score plane is written.
+    ZAYA1 (``models/zaya.py``: 8 query heads over 2 key heads, one
+    token a slot) takes the same kernel at 4 query rows a key head.
 
   The two paths share no logic: their needs conflict (PERF.md section
   6, PR 33), so they are separated, not adapted;
@@ -35,6 +37,17 @@ between them:
   kernel of the same build (the two kernels share the ring of buffers
   their pages stream through, :func:`_page_stream`): the contexts' rows
   are read once, and nothing is gathered in HBM.
+
+**What the kernels' stream copies** (PR 38): of each slot the pages
+its length needs (``length // P + 1``), rounded up to a group of
+``_COPIES_A_TRIP`` = 8 pages, and no more: every block of a slot but
+the last whole, the last in whole groups.  A block is still CONTRACTED
+whole (static shapes); the rows of its buffer that were not copied are
+an earlier block's or the zeros the ring starts with, all finite, all
+masked.  Over the long-generation mixes' lengths that is 1.07 rows
+copied for every row a slot holds, where whole blocks copied 1.275 (32
+pages a block) and 1.58 (ZAYA1's 64); :func:`stream_rows_copied` is
+the count, and the engine puts it on ``serve.decode_step``.
 
 Mask contract (every path, pinned by tests): position ``pos <=
 length`` attends, everything else is ``-inf`` before the softmax — so
@@ -45,8 +58,8 @@ output.
 The engine slices each step's page tables to the used-page bucket
 (:func:`used_page_bucket`): the pow2 count of pages covering
 ``max(lengths)//P + 1``, so the gather path does not pay for the empty
-pool (the kernels stop at each slot's length whatever the width; the
-bucket only bounds the table they are handed).
+pool (the kernels stop at each slot's last needed group of pages
+whatever the width; the bucket only bounds the table they are handed).
 
 A faster body REPLACES one of these, in a ``perf_opt`` PR that shows
 its gain in a cell of the benchmark; it is not added beside one behind
@@ -91,10 +104,12 @@ def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
     pages the tables name are read, and the gathered contiguous copy is
     written and read again (the gather tax), plus the f32 score plane's
     round trip.  Rows that share a key head (the kernel): the bucket's
-    K and V pages ONCE (an upper bound: the kernel stops at each slot's
-    length, which the gauge does not know), the queries and the
-    outputs; nothing is gathered and no score plane leaves fast
-    memory."""
+    K and V pages ONCE, the queries and the outputs; nothing is
+    gathered and no score plane leaves fast memory.  That is an upper
+    bound, since the gauge does not know the slots' lengths: the kernel
+    copies each slot's pages up to its length, rounded up to a group of
+    ``_COPIES_A_TRIP`` pages (:func:`stream_rows_copied` counts them
+    for given lengths)."""
     k = maxp * page_size
     rows = h if kv_heads is None else kv_heads
     pages = 2.0 * b * maxp * page_size * rows * d * kv_itemsize  # K + V
@@ -102,6 +117,29 @@ def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
     if positions * h > rows:
         return pages + qio
     return pages * 3 + 2.0 * b * positions * h * k * 4 + qio
+
+
+def stream_rows_copied(lengths, page_size: int, maxp: int, row_width: int,
+                       itemsize: int, query_rows: int) -> int:
+    """Rows of a pool that ONE call of a kernel's page stream
+    (:func:`_page_stream`) copies for slots that attend ``pos <=
+    lengths``, by the kernel's own arithmetic: a slot needs ``length //
+    P + 1`` pages (clipped to the table's ``maxp``), in blocks of
+    :func:`_block_pages` pages (from the page, the pool's
+    ``row_width`` and ``itemsize`` and the ``query_rows`` a slot hands
+    the kernel); every block but the last is copied whole, the last in
+    whole groups of ``_COPIES_A_TRIP`` pages.  Over the rows the slots
+    hold (``sum(lengths + 1)``) it says what the stream reads for
+    every row it must: the engine puts both on ``serve.decode_step``
+    (``attn_rows_copied``, ``context_tokens``)."""
+    import numpy as np
+
+    bp = _block_pages(page_size, row_width, itemsize, query_rows)
+    trip = min(_COPIES_A_TRIP, bp)
+    need = np.clip(np.asarray(lengths, np.int64) // page_size + 1, 1, maxp)
+    whole, last = np.divmod(need - 1, bp)    # last block: ``last + 1`` pages
+    pages = whole * bp + _groups(last + 1, trip) * trip
+    return int(pages.sum()) * page_size
 
 
 # --------------------------------------------------------------------------
@@ -175,7 +213,8 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
     * ``S x H > H_kv`` (rows that share a key head): the Pallas kernel
       of :func:`_grouped_program` — each slot's pages of K and of V are
       copied from ``[layer, page]`` where they lie, a block at a time,
-      up to the slot's own length, and contracted in fast memory:
+      up to the slot's own length (in groups of 8 pages:
+      :func:`_page_stream`), and contracted in fast memory:
       operands in the pools' dtype (``q`` is scaled in float32 first),
       float32 scores, online softmax and accumulation.  Off the CPU it
       is the Mosaic kernel or an error; on the CPU backend the Pallas
@@ -228,8 +267,9 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
 # or fourth buffer buys nothing.
 _BLOCK_BYTES = 640 * 1024
 _BUFFERS = 2
-# copies started a trip of the kernels' issue loop: a branch a page
-# would cost the scalar core as much as the copy's descriptor
+# copies started a trip of the kernels' issue loop (a branch a page
+# would cost the scalar core as much as the copy's descriptor), and so
+# the granule in which a slot's last block is copied and awaited
 _COPIES_A_TRIP = 8
 
 
@@ -246,6 +286,13 @@ def _block_pages(page_size: int, row_width: int, itemsize: int,
     return bp if bp < _COPIES_A_TRIP else bp - bp % _COPIES_A_TRIP
 
 
+def _groups(pages, trip: int):
+    """Groups of ``trip`` pages that hold ``pages`` pages: what a
+    kernel's stream copies of a block (traced in the kernel, numbers in
+    :func:`stream_rows_copied`)."""
+    return (pages + trip - 1) // trip
+
+
 def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
     """What both kernels do about their pages, inside the kernel body
     (one grid step a slot): the blocks of ``bp`` pages of all slots, in
@@ -259,10 +306,20 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
     ints: [0] slot and [1] block the next copies are for, [2] blocks
     issued, [3] blocks contracted.
 
+    **Of a block only the pages the slot needs are copied, a group of
+    ``_COPIES_A_TRIP`` at a time** (:func:`_groups`): every block but a
+    slot's last is whole, the last is cut to the groups that hold its
+    ``need``.  The rest of that buffer keeps what it held: the rows of
+    an earlier block (of any slot), or the zeros the ring is filled
+    with at the first grid step.  Both are finite, and the caller masks
+    every position past the slot's length, so they meet probability
+    exactly 0 and add exactly 0: a block contracted whole gives the
+    bits it would give with the whole block copied.
+
     Returns ``(blocks, next_block)``: the blocks of this grid step's
-    slot, and a function that puts one more block's copies under way
-    (before the very first block: the whole ring's), waits for the
-    oldest block's bytes (one wait a pool, whichever copy ends last)
+    slot, and a function of the slot's block index that puts one more
+    block's copies under way (before the very first block: the whole
+    ring's), waits for that block's bytes (one wait a group and pool)
     and returns the index of the buffer that holds it."""
     import jax.numpy as jnp
     from jax import lax
@@ -279,6 +336,9 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
     def blocks_of(slot):
         return (need[slot] + bp - 1) // bp
 
+    def groups_of(slot, blk):
+        return _groups(jnp.minimum(need[slot] - blk * bp, bp), unroll)
+
     def issue():
         slot, blk = ring[0], ring[1]
 
@@ -289,9 +349,10 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
             def group(g, c):
                 for j in range(unroll):
                     j += g * unroll
-                    # past the slot's last page: what the table names
-                    # there (page 0, finite by the cache's contract;
-                    # past the table's width, its last entry again)
+                    # past the slot's last page, in its last group:
+                    # what the table names there (page 0, finite by
+                    # the cache's contract; past the table's width,
+                    # its last entry again)
                     pg = tables[slot * maxp
                                 + jnp.minimum(blk * bp + j, maxp - 1)]
                     for pool, buf, sems in streams:
@@ -300,7 +361,7 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
                                               sems.at[half]).start()
                 return c
 
-            lax.fori_loop(0, bp // unroll, group, 0)
+            lax.fori_loop(0, groups_of(slot, blk), group, 0)
             last = blk + 1 >= blocks_of(slot)
             ring[0] = jnp.where(last, slot + 1, slot)
             ring[1] = jnp.where(last, 0, blk + 1)
@@ -310,8 +371,11 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
     def _():
         for k in range(4):
             ring[k] = 0
+        # fast memory starts as anything: 0 x NaN would be NaN
+        for _, buf, _ in streams:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
 
-    def next_block():
+    def next_block(blk):
         def more(_, c):
             issue()
             return c
@@ -319,9 +383,15 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
         lax.fori_loop(0, jnp.where(ring[2] == 0, nbuf, 1), more, 0)
         half = ring[3] % nbuf
         ring[3] = ring[3] + 1
-        for pool, buf, sems in streams:
-            pltpu.make_async_copy(pool.at[lyr, pl.ds(0, bp)], buf.at[half],
-                                  sems.at[half]).wait()
+
+        def arrived(_, c):
+            for pool, buf, sems in streams:
+                pltpu.make_async_copy(pool.at[lyr, pl.ds(0, unroll)],
+                                      buf.at[half, pl.ds(0, unroll)],
+                                      sems.at[half]).wait()
+            return c
+
+        lax.fori_loop(0, groups_of(b, blk), arrived, 0)
         return half
 
     return blocks_of(b), next_block
@@ -381,7 +451,7 @@ def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int):
         r = qs[0].shape[0]
 
         def block(i, carry):
-            half = next_block()
+            half = next_block(i)
             krows = kbuf[half].reshape(rows_blk, hkv * d)
             vrows = vbuf[half].reshape(rows_blk, hkv * d)
             live = i * rows_blk + lax.broadcasted_iota(
@@ -489,7 +559,7 @@ def _latent_kernel(bp: int, page: int, maxp: int, vw: int):
         h = qs.shape[0]
 
         def block(i, carry):
-            rows = buf[next_block()].reshape(rows_blk, buf.shape[-1])
+            rows = buf[next_block(i)].reshape(rows_blk, buf.shape[-1])
             s = lax.dot_general(qs, rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             pos = i * rows_blk + lax.broadcasted_iota(
@@ -524,16 +594,16 @@ def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
     A Pallas kernel, one grid step a slot.  Tables, lengths and
     ``layer`` are scalar prefetch; the pool stays in HBM and a slot's
     pages are copied from ``[layer, page]`` into fast memory a block at
-    a time, up to the slot's OWN length (the blocks that cover the
-    ``length // P + 1`` pages of its longest query; the last block is
-    copied whole, from what the table names there: page 0, finite by
-    the cache's contract, and masked) — not the table's width, and
-    with no gathered copy in HBM — the next blocks' copies (at a slot's
-    end: the next slot's first) in flight while this one is
-    contracted.  It is multi-query attention with ``H`` query heads on
-    one row: a block is one ``(H, R) x (R, rows)`` and one ``(H, rows)
-    x (rows, value_width)`` product on the MXU with float32
-    accumulation, operands in the pool's dtype (``q`` is scaled in
+    a time, up to the slot's OWN length (the ``length // P + 1`` pages
+    of its longest query, rounded up to a group of 8: of the last
+    group's pages past the slot's the table names page 0, finite by the
+    cache's contract, and masked; :func:`_page_stream`) — not the
+    table's width, and with no gathered copy in HBM — the next blocks'
+    copies (at a slot's end: the next slot's first) in flight while
+    this one is contracted.  It is multi-query attention with ``H``
+    query heads on one row: a block is one ``(H, R) x (R, rows)`` and
+    one ``(H, rows) x (rows, value_width)`` product on the MXU with
+    float32 accumulation, operands in the pool's dtype (``q`` is scaled in
     float32 first), folded into a running float32 ``(m, l, acc)`` (an
     online softmax).
     The pages a block are taken from the shapes (:func:`_block_pages`),
@@ -602,4 +672,4 @@ def _latent_program(scale: float, vw: int, interpret: bool):
 
 
 __all__ = ["paged_decode_attention", "latent_decode_attention",
-           "used_page_bucket", "decode_hbm_bytes"]
+           "used_page_bucket", "decode_hbm_bytes", "stream_rows_copied"]

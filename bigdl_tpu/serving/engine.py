@@ -94,8 +94,12 @@ that with the production shape:
 **What the engine asks of a model** (``models/transformer.py`` and
 ``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
 many cached layers, the width of a token's row, one buffer or two, the
-longest context; ``paged_prefill(params, caches, prompt, t0, pages)``
-and ``paged_decode(params, caches, tables, lengths, tokens, active,
+longest context, and, where its decode attention is a page-walking
+kernel of ``ops/decode_attention.py``, the query rows a slot it hands
+that kernel (``attn_query_rows``: the engine then says on every
+``serve.decode_step`` what the kernel's stream copied,
+``attn_rows_copied``); ``paged_prefill(params, caches, prompt, t0,
+pages)`` and ``paged_decode(params, caches, tables, lengths, tokens, active,
 ...)``, each returning ``(caches, logits, counts)`` with ``counts`` the
 step's expert-routing counts or None.  Sampling, buckets, donation, the
 page tables, spans and ``stats()`` are the engine's; the layers'
@@ -272,15 +276,17 @@ class _Block:
 class _InFlight:
     """A dispatched decode step whose tokens the host has not read."""
 
-    __slots__ = ("result", "counts", "entries", "context_tokens")
+    __slots__ = ("result", "counts", "entries", "context_rows")
 
-    def __init__(self, result, counts, entries, context_tokens):
+    def __init__(self, result, counts, entries, context_rows):
         # (B,) device array, the step's tokens; a drafting model's
         # (B, len(DRAFT_RESULT)) rows
         self.result = result
         self.counts = counts        # an expert model's routing counts
         self.entries = entries      # [(slot, _Active)] it ran for
-        self.context_tokens = context_tokens
+        # the rows of context it reads, a slot it ran for (a one-token
+        # model's: the others' come back with the step)
+        self.context_rows = context_rows
 
 
 #: columns of a drafting step's result, a slot: the two tokens picked,
@@ -1184,12 +1190,12 @@ class LMEngine:
                 # bucket, who runs) needs no token.  (A block's step may
                 # yield none: that host's state advances when the step
                 # is read.)
-                entries, context = [], 0
+                entries, context = [], []
                 sure = 0 if self._block else 1
                 for i in running:
                     act = self._slots[i]
                     self.cache.lengths[i] += sure
-                    context += int(self.cache.lengths[i])
+                    context.append(int(self.cache.lengths[i]))
                     act.remaining -= sure
                     act.unread += 1
                     entries.append((i, act))
@@ -1278,7 +1284,8 @@ class LMEngine:
         if self._drafts:
             first, second, emitted, draft, length = res.T  # DRAFT_RESULT
             toks = np.stack([first, second], axis=1)
-            accepted = tokens = context = 0
+            accepted = tokens = 0
+            context = []
             for slot, act in rec.entries:
                 if self._slots[slot] is not act or not emitted[slot]:
                     continue    # completed since, or owed nothing there
@@ -1292,7 +1299,7 @@ class LMEngine:
                 tokens += int(emitted[slot])
                 # the rows the step had to read, once a slot: up to
                 # its second query's position
-                context += int(length[slot]) + 2
+                context.append(int(length[slot]) + 2)
             attrs.update(draft_verified=len(drafts),
                          draft_accepted=accepted, tokens_emitted=tokens)
             self._draft_verified += len(drafts)
@@ -1301,11 +1308,30 @@ class LMEngine:
             self._draft_counter.labels(outcome="rejected").inc(
                 len(drafts) - accepted)
         else:
-            toks, emitted, context = res[:, None], None, rec.context_tokens
+            toks, emitted, context = res[:, None], None, rec.context_rows
         if rec.counts is not None:
             attrs.update(self._note_routing(rec.counts),
-                         context_tokens=context)
+                         **self._context_attrs(context))
         return _StepRead(toks, emitted, drafts, attrs)
+
+    def _context_attrs(self, rows) -> dict:
+        """What a step's attention had to read and what it copied:
+        ``context_tokens``, the sum of ``rows`` (the rows of context a
+        slot the step ran for, its own positions included), and, under
+        a model whose decode attention is a page-walking kernel
+        (``cache_spec``'s ``attn_query_rows``), ``attn_rows_copied``:
+        the rows one call of that kernel copies a pool for those
+        slots."""
+        attrs = {"context_tokens": sum(rows)}
+        query_rows = self._cache_spec.get("attn_query_rows")
+        if query_rows:
+            from bigdl_tpu.ops.decode_attention import stream_rows_copied
+
+            attrs["attn_rows_copied"] = stream_rows_copied(
+                np.asarray(rows, np.int64) - 1, self.page_size,
+                self.cache.max_pages_per_slot, self.cache.row_width,
+                self.cache.dtype.itemsize, query_rows)
+        return attrs
 
     def _read_block(self, rec: _InFlight, res) -> _StepRead:
         """A block model's read: what each live slot's step did, how
@@ -1317,12 +1343,13 @@ class LMEngine:
         length, kind, done = res[:, 2 * b:].T          # BLOCK_RESULT
         emitted = np.zeros((self.max_batch,), np.int32)
         blocks = {}
-        passes = commits = unmasked = left = context = 0
+        passes = commits = unmasked = left = 0
+        context = []
         for slot, act in rec.entries:
             if self._slots[slot] is not act or not kind[slot]:
                 continue    # completed since: a wasted block
             blocks[slot] = (after[slot], int(kind[slot]), int(done[slot]))
-            context += int(length[slot]) + b
+            context.append(int(length[slot]) + b)
             if kind[slot] == BLOCK_COMMITTED:
                 commits += 1
                 continue
@@ -1348,7 +1375,7 @@ class LMEngine:
                      tokens_emitted=int(emitted.sum()))
         if rec.counts is not None:
             attrs.update(self._note_routing(rec.counts),
-                         context_tokens=context)
+                         **self._context_attrs(context))
         return _StepRead(toks, emitted, {}, attrs, blocks)
 
     def _advance_block(self, slot: int, act: _Active, read: _StepRead):

@@ -103,6 +103,15 @@ Three families:
                         active slots' contexts, the step's own token
                         included (the cache rows attention has to
                         read), of the same step as the counts
+  ``attn_rows_copied``  beside ``context_tokens``, under a model whose
+                        decode attention is a page-walking kernel
+                        (``cache_spec``'s ``attn_query_rows``): the
+                        rows ONE call of that kernel copies a pool for
+                        the same slots (``ops/decode_attention.py``
+                        ``stream_rows_copied``: whole blocks, the last
+                        in groups of 8 pages); over ``context_tokens``
+                        it is what the stream reads for every row it
+                        must
   ====================  ================================================
 
   The registry has the same counts as

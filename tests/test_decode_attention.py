@@ -26,10 +26,16 @@ The load-bearing contracts:
   table names change no bit;
 * a table sliced to the used-page bucket gives the full table's
   output to the last bit (what lets the engine slice every step);
+* the kernels' page stream copies what a slot's length needs, a group
+  of 8 pages at a time: NaNs in the pages a table names past a slot's
+  last needed group change no bit, the outputs are those of the stream
+  that copied every block whole (kept here as the second witness), and
+  ``stream_rows_copied`` counts what the kernel copies;
 * the ``int8_mm`` auto-tuner site: golden key, never-lose, the
   measured prewarm cycle persists.
 """
 
+import contextlib
 import json
 
 import numpy as np
@@ -42,6 +48,7 @@ from bigdl_tpu.ops import decode_attention as D
 from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
                                             latent_decode_attention,
                                             paged_decode_attention,
+                                            stream_rows_copied,
                                             used_page_bucket)
 from bigdl_tpu.serving.cache import pool_shape
 
@@ -480,6 +487,257 @@ class TestLatentDecodeParity:
         got = np.asarray(latent_decode_attention(
             q, poisoned, tbl, lens, scale=self.SCALE, value_width=512))
         np.testing.assert_array_equal(got, clean)
+
+
+# --------------------------------------------------------------------------
+# the page stream: what a slot's length needs, a group of pages at a time
+# --------------------------------------------------------------------------
+
+
+def _whole_block_page_stream(tables, need, layer, ring, streams, bp, maxp):
+    """The kernels' page stream as it was before it followed the
+    slots' lengths (PR 31 to PR 37): every block is copied whole, page
+    0 standing in past a slot's pages, and awaited with one wait a
+    pool.  Kept as the second witness of the stream that replaced it:
+    same outputs, bit for bit."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    unroll = min(D._COPIES_A_TRIP, bp)
+    b, nslots = pl.program_id(0), pl.num_programs(0)
+    nbuf = streams[0][1].shape[0]
+    lyr = layer[0]
+
+    def blocks_of(slot):
+        return (need[slot] + bp - 1) // bp
+
+    def issue():
+        slot, blk = ring[0], ring[1]
+
+        @pl.when(slot < nslots)
+        def _():
+            half = ring[2] % nbuf
+
+            def group(g, c):
+                for j in range(unroll):
+                    j += g * unroll
+                    pg = tables[slot * maxp
+                                + jnp.minimum(blk * bp + j, maxp - 1)]
+                    for pool, buf, sems in streams:
+                        pltpu.make_async_copy(pool.at[lyr, pg],
+                                              buf.at[half, j],
+                                              sems.at[half]).start()
+                return c
+
+            lax.fori_loop(0, bp // unroll, group, 0)
+            last = blk + 1 >= blocks_of(slot)
+            ring[0] = jnp.where(last, slot + 1, slot)
+            ring[1] = jnp.where(last, 0, blk + 1)
+            ring[2] = ring[2] + 1
+
+    @pl.when(b == 0)
+    def _():
+        for k in range(4):
+            ring[k] = 0
+
+    def next_block(_blk):
+        def more(_, c):
+            issue()
+            return c
+
+        lax.fori_loop(0, jnp.where(ring[2] == 0, nbuf, 1), more, 0)
+        half = ring[3] % nbuf
+        ring[3] = ring[3] + 1
+        for pool, buf, sems in streams:
+            pltpu.make_async_copy(pool.at[lyr, pl.ds(0, bp)], buf.at[half],
+                                  sems.at[half]).wait()
+        return half
+
+    return blocks_of(b), next_block
+
+
+@contextlib.contextmanager
+def _whole_blocks():
+    """Both kernels over :func:`_whole_block_page_stream` (their
+    programs are built anew on both sides of the swap)."""
+    programs = (D._grouped_program, D._latent_program)
+    stream, D._page_stream = D._page_stream, _whole_block_page_stream
+    for prog in programs:
+        prog.cache_clear()
+    try:
+        yield
+    finally:
+        D._page_stream = stream
+        for prog in programs:
+            prog.cache_clear()
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+def _stream_lengths(bp, p, maxp):
+    """The table's last row (then a short slot: the long one's rows
+    lie in both buffers when it is contracted), the last row of a
+    group of 8 pages and the first of the next, a block's last row and
+    the page after it, length 0, a released slot."""
+    assert 8 < bp < maxp
+    return [maxp * p - 1, 5, 8 * p - 1, 8 * p, bp * p - 1, bp * p + 3, 0,
+            None]
+
+
+def _grouped_run(s, g, dtype, lengths=None, seed=3):
+    """``(run, tables, pools)`` of one grouped-kernel call:
+    ``run(tables, kp, vp)`` -> the output."""
+    c = TestGroupedDecodeParity
+    if lengths is None:
+        lengths = c()._lengths(s, g, dtype)
+    q, kp, vp, tbl, lens = _grouped_state(lengths, s, g, dtype=dtype,
+                                          seed=seed)
+
+    def run(tbl, kp, vp):
+        return np.asarray(paged_decode_attention(q, kp, vp, tbl, lens,
+                                                 page_size=c.P))
+
+    return run, tbl, (kp, vp)
+
+
+def _latent_run(queries, dtype, lengths=None, seed=3):
+    c = TestLatentDecodeParity
+    wide = c.WIDE
+    if lengths is None:
+        lengths = c.WIDE_LENGTHS
+    q, pages, tbl, lens = _latent_state(lengths, seed=seed, **wide)
+    q, pages = q.astype(dtype), pages.astype(dtype)
+    if queries == 2:   # heads 0-1 at the length, 2-3 one past it
+        lens = jnp.minimum(lens[:, None] + jnp.asarray([0, 0, 1, 1]),
+                           wide["maxp"] * wide["p"] - 1)
+
+    def run(tbl, pages):
+        return np.asarray(latent_decode_attention(
+            q, pages, tbl, lens, scale=c.SCALE, value_width=512))
+
+    return run, tbl, (pages,)
+
+
+_STREAM_CASES = (
+    [("grouped", s, g, dt) for s in (1, 4) for g in (2, 8)
+     for dt in ("float32", "bfloat16")]
+    + [("latent", qn, 0, dt) for qn in (1, 2)
+       for dt in ("float32", "bfloat16")])
+
+
+def _stream_case(body, a, g, dtype, **kw):
+    return _grouped_run(a, g, dtype, **kw) if body == "grouped" \
+        else _latent_run(a, dtype, **kw)
+
+
+class TestThePageStreamFollowsTheLength:
+    """Both kernels, under the interpreter (whose fast memory starts as
+    NaN, like nothing a kernel may count on)."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("body", ["grouped", "latent"])
+    def test_pages_past_the_last_needed_group_are_never_copied(self, body,
+                                                               dtype):
+        """Every table entry past a slot's last needed GROUP of 8 pages
+        names a page of NaNs, in K and in V: no bit of any output
+        changes.  (Copied whole, the block would bring the NaNs into
+        the mix's product: 0 x NaN.)  Lengths that end a group, a block
+        and the table exactly, length 0, and a short slot behind a full
+        one, whose rows lie in the buffers it is contracted from."""
+        c = TestGroupedDecodeParity if body == "grouped" \
+            else TestLatentDecodeParity
+        p, maxp = (c.P, c.MAXP) if body == "grouped" \
+            else (c.WIDE["p"], c.WIDE["maxp"])
+        item = jnp.dtype(dtype).itemsize
+        bp = D._block_pages(p, 256, item, 4) if body == "grouped" \
+            else D._block_pages(p, 640, item, 4)
+        lengths = _stream_lengths(bp, p, maxp)
+        run, tbl, pools = _stream_case(body, 1, 2, dtype, lengths=lengths)
+        clean = run(tbl, *pools)
+        assert np.isfinite(clean.astype(np.float32)).all()
+        named = np.zeros(pools[0].shape[0], bool)
+        named[np.asarray(tbl).ravel()] = True
+        spare = int(np.flatnonzero(~named)[0])
+        poisoned = np.array(tbl)
+        for i, ln in enumerate(lengths):
+            need = (ln or 0) // p + 1
+            poisoned[i, -(-need // 8) * 8:] = spare
+        assert (poisoned == spare).sum() > 8 * len(lengths)
+        got = run(jnp.asarray(poisoned),
+                  *(pool.at[spare].set(jnp.nan) for pool in pools))
+        np.testing.assert_array_equal(_bits(got), _bits(clean))
+
+    @pytest.mark.parametrize("case", _STREAM_CASES,
+                             ids=["-".join(map(str, c))
+                                  for c in _STREAM_CASES])
+    def test_outputs_are_the_whole_block_streams_to_the_last_bit(self,
+                                                                 case):
+        """Over the float64 oracle's cases: what a block's buffer keeps
+        past the groups that were copied (an earlier block's rows, or
+        the zeros of the first grid step) meets probability exactly 0,
+        as page 0's rows did when every block was copied whole."""
+        run, tbl, pools = _stream_case(*case)
+        got = run(tbl, *pools)
+        with _whole_blocks():
+            want = run(tbl, *pools)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+# the four cells whose attention is a kernel: the pool's row (values),
+# the query rows a slot, the pages a block (bfloat16, pages of 16) and
+# what the stream copied for every row a slot holds when every block
+# was copied whole
+_STREAM_CELLS = {
+    "longcat_flash_long_gen": (640, 64, 32, 1.275),
+    "joyai_flash_draft_gen": (640, 64, 32, 1.275),
+    "sdar_moe_block_gen": (512, 128, 32, 1.275),
+    "zaya1_cca_long_gen": (256, 8, 64, 1.58),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_STREAM_CELLS))
+def test_stream_rows_copied_counts_what_the_kernel_copies(cell):
+    """``stream_rows_copied`` against a count made by walking every
+    slot's blocks and groups the slow way, over the lengths the long
+    generation mixes visit (prompts of 128-256, 1024-1790 new tokens: a
+    request passes every length from its prompt's to its last, a step
+    each): 1.07 rows copied for every row held, where whole blocks
+    copied 1.275 (blocks of 32 pages) and 1.58 (ZAYA1's 64)."""
+    row, query_rows, bp, whole_ratio = _STREAM_CELLS[cell]
+    p, maxp, n = 16, 128, 512
+    assert D._block_pages(p, row, 2, query_rows) == bp
+    prompts = np.rint(np.linspace(128, 256, n)).astype(int)
+    news = np.rint(np.linspace(1024, 1790, n)).astype(int)
+    lengths = np.concatenate([
+        np.arange(prompts[i], prompts[i] + news[(i * 317) % n])
+        for i in range(n)])
+    assert lengths.min() == 128 and lengths.max() > 2030
+    values, counts = np.unique(lengths, return_counts=True)
+
+    def walk(length, granule):
+        need, pages, blk = min(length // p + 1, maxp), 0, 0
+        while blk * bp < need:
+            in_block, g = min(bp, need - blk * bp), 0
+            while g * granule < in_block:
+                pages, g = pages + granule, g + 1
+            blk += 1
+        return pages * p
+
+    held = int((lengths + 1).sum())
+    slow = sum(walk(int(v), 8) * int(k) for v, k in zip(values, counts))
+    whole = sum(walk(int(v), bp) * int(k) for v, k in zip(values, counts))
+    got = stream_rows_copied(lengths, p, maxp, row, 2, query_rows)
+    assert got == slow
+    assert abs(got / held - 1.07) < 0.02
+    assert abs(whole / held - whole_ratio) < 0.02
+    # a table narrower than a slot's pages bounds what is copied
+    assert stream_rows_copied([2000, 5], p, 16, row, 2, query_rows) \
+        == (16 + 8) * p
+
 
 
 @pytest.mark.parametrize("body", ["paged", "grouped", "latent"])

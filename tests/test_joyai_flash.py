@@ -538,6 +538,9 @@ def test_spans_carry_what_a_step_verified_and_yielded(tmp_path,
         # the first step read: both slots, their prompts' rows, the
         # token's own and the draft's
         assert read[0]["context_tokens"] == (3 + 2) + (5 + 2)
+        # ... and what the attention kernel's stream copied for them:
+        # each slot's few rows lie in one group of 8 pages of 4
+        assert read[0]["attn_rows_copied"] == 2 * 8 * 4
         # both positions of both slots went through three expert layers
         assert read[0]["moe_held"] + read[0]["moe_absent"] == 4 * 3 * 4
         fam = obs.get_registry().counter(

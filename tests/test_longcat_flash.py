@@ -520,6 +520,9 @@ def test_spans_carry_the_routing_counts_and_the_registry_counts_them(
             # the rows of context that step had to read, its own included
             assert a["context_tokens"] >= tokens
         assert steps[1]["attrs"]["context_tokens"] == (5 + 1) + (6 + 1)
+        # ... and what the attention kernel's stream copied for them:
+        # each slot's few rows lie in one group of 8 pages of 4
+        assert steps[1]["attrs"]["attn_rows_copied"] == 2 * 8 * 4
         reg = obs.get_registry()
         fam = reg.counter(names.SERVE_MOE_ASSIGNMENTS_TOTAL, "",
                           labels=("kind",))

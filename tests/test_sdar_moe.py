@@ -935,6 +935,9 @@ def test_spans_carry_what_a_step_refined_and_committed(tmp_path,
             assert a["positions_unmasked"] <= B * a["block_passes"]
         # the first step read: both slots' blocks end at 4 and 8
         assert read[0]["context_tokens"] == 4 + 8
+        # ... and what the attention kernel's stream copied for them:
+        # each slot's few rows lie in one group of 8 pages of 4
+        assert read[0]["attn_rows_copied"] == 2 * 8 * 4
         # every position of both slots went through two expert layers
         assert read[0]["moe_held"] == 2 * B * 2 * 2
         fam = obs.get_registry().counter(

@@ -647,6 +647,9 @@ def test_spans_and_stats_say_the_state_and_the_routing(roomy, tmp_path,
         assert a["moe_held"] == 2 * 2 and a["moe_absent"] == 0
         assert a["moe_hit"] <= 2 * 2 and a["moe_max_load"] <= 2
         assert a["context_tokens"] == (5 + 1) + (7 + 1)
+        # ... and what the attention kernel's stream copied for them:
+        # each slot's few rows lie in one group of 8 pages of 4
+        assert a["attn_rows_copied"] == 2 * 8 * 4
     finally:
         obs.reset()
 
