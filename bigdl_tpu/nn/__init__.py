@@ -45,6 +45,7 @@ from bigdl_tpu.nn.latent import (
     RMSNorm,
 )
 from bigdl_tpu.nn.experts import DroplessExperts
+from bigdl_tpu.nn.ssm import Mamba2Mixer
 from bigdl_tpu.nn.attention import (
     LayerNorm,
     MultiHeadAttention,
@@ -169,6 +170,7 @@ __all__ = (
         "LayerNorm", "MultiHeadAttention", "TransformerBlock",
         "PositionalEmbedding",
         "RMSNorm", "GatedMLP", "LatentAttention", "DroplessExperts",
+        "Mamba2Mixer",
         "SpatialConvolutionBatchNorm", "fuse_conv_bn",
     ]
     + list(_layers_all)

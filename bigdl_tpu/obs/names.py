@@ -429,6 +429,11 @@ SERVE_SLOT_STATE_BYTES = _m(
     doc="Bytes of state a decode slot carries beside its pages, over all "
         "layers, under a model that declares one (state_spec): rows of "
         "the slot's previous token, not keys and values")
+SERVE_STATE_REBUILDS_TOTAL = _m(
+    "bigdl_serve_state_rebuilds_total", "counter",
+    doc="Prefills of a preempted request under a model whose slots carry "
+        "state: the state was rebuilt from the request's tokens (nothing "
+        "is snapshotted at a preemption)")
 SERVE_REJECTS_TOTAL = _m(
     "bigdl_serve_rejects_total", "counter",
     doc="Admissions rejected 503 + Retry-After (queue full past the "

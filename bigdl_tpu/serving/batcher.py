@@ -63,6 +63,9 @@ class ServeRequest:
     # request ended and -2 where a preemption made it part of the
     # prompt (serving/engine.py NEVER_UNMASKED, GIVEN)
     unmasked: List[tuple] = dataclasses.field(default_factory=list)
+    # times the LM engine preempted it (pages reclaimed, the generated
+    # prefix folded into the prompt, prefilled again)
+    preempted: int = 0
     result: Optional[np.ndarray] = None  # classifier output row(s)
     error: Optional[str] = None
     # request-trace context (obs.reqtrace.RequestTraceContext) when the

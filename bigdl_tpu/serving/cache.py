@@ -44,22 +44,35 @@ written and read through them:
 
 **State that is not keys and values** lives here too, beside the pages
 (a model's ``state_spec``: ``serving/engine.py`` says what the engine
-asks).  Some layers need, to form a token's rows, a few rows of the
-slot's PREVIOUS token (``models/zaya.py``: two causal convolutions and
-a shifted value) or a running state (a state-space or linear-attention
-layer): not a function of the token alone, so no page holds it.  It is
-one array a declared shape, ``(layers, max_slots, *shape)``,
-indexed by SLOT (not by page: a slot has exactly one, whatever its
-length), handed to the step and the prefill and taken back with the
-pools (:meth:`PagedKVCache.buffers`), written by
-:func:`write_slot_state` (a prefill: the whole of one slot's, so
-nothing of the slot's previous occupant survives) and guarded by
-:func:`keep_inactive` (a step: a slot that did not run keeps what it
-had).  Nothing is snapshotted: a preempted request's second prefill
-rebuilds its state from its tokens, which is exact for a state that is
-a function of a bounded past (a layer whose state sums over the whole
-past, re-prefilled in chunks, would need its state at the chunk's
-start kept).
+asks).  Some layers need more than a token's own rows, in one of two
+ways.  **A bounded past**: to form a token's rows, a few rows of the
+slot's PREVIOUS token (``models/zaya.py``: two causal convolutions and a
+shifted value; 5.4 KB a slot and layer).  **The whole past**: a running
+state that every token decays and adds to (``models/falcon_h1.py``: a
+state-space mixer's ``H``, 32 x 256 x 128 float32, 4.19 MB a slot and
+layer, and its convolution's last 3 rows).  Neither is a function of the
+token alone, so no page holds it.  It is one array a declared shape,
+``(layers, max_slots, *shape)``, indexed by SLOT (not by page: a slot
+has exactly one, whatever its length), handed to the step and the
+prefill and taken back with the pools (:meth:`PagedKVCache.buffers`),
+written by :func:`write_slot_state` (a prefill: the whole of one slot's,
+so nothing of the slot's previous occupant survives) and, in a step,
+left alone for a slot that did not run: by :func:`keep_inactive` (a
+``where`` over the state the model handed back), or by the model's own
+update where the model says so (``state_spec``'s ``keeps_inactive``).
+**What a step costs** is the state of the slots that run, once in and
+once out: 14 MB for ZAYA1's 256 slots, where one more pass to guard it
+shows nowhere; 4.3 GB for Falcon-H1's 128, as much as the page pools
+hold and more than the layers' weights, where the guard's pass would be
+a third of the step's floor: there the update itself leaves an idle
+slot bit for bit (``dt = 0``) and runs in place (``ops/ssm_state.py``).
+**Nothing is snapshotted**: a preempted request's second prefill
+rebuilds its state from its tokens.  That is exact for a bounded past,
+and for the whole past it is the prefill's scan over prompt + generated
+prefix: the state a step-by-step run would hold, to rounding
+(``tests/test_falcon_h1.py`` bounds it).  It holds because a prefill is
+never cut into chunks (``LMEngine._bucket`` goes to ``max_len``); a
+prefill in chunks would need the state at a chunk's start kept.
 
 The allocator is plain host Python — a free list and per-slot page
 lists.  Decode grows a slot one page at a time as its length crosses a

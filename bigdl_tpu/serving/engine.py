@@ -72,24 +72,37 @@ that with the production shape:
   position's token and the pass that unmasked it.  Same loop, same
   settles; greedy only;
 * **state a slot carries that is not keys and values**: a model whose
-  layers need rows of the slot's previous token to form this token's
-  (``state_spec``; ``models/zaya.py``: two causal convolutions and a
-  shifted value) declares their shapes, and the engine keeps them for
-  ``max_batch`` slots BESIDE the pages, in the cache manager
-  (``serving/cache.py``), donated to ``jit_step`` and ``jit_prefill``
-  with the pools and carried on the device from step to step like a
-  draft's or a block's state.  Its life is the slot's: **the prefill
-  writes it** (the state after the prompt's last REAL token, whatever
-  the bucket's padded tail holds), all of it, so nothing of the slot's
-  previous occupant survives an admission; a step advances it for the
-  slots that ran and leaves an inactive slot's alone; a release leaves
-  it where it is (the next admission overwrites it).  **No snapshot is
-  taken at a preemption**: the request comes back with its generated
-  prefix as prompt and its second prefill rebuilds the state from the
-  tokens, exactly, because this state is a function of the last
-  position alone.  A recurrent layer (a state that sums over the whole
-  past) prefilled in chunks WILL need its state kept at the chunk's
-  start; nothing here does that yet.
+  layers need more than a token's own rows declares the shapes
+  (``state_spec``), and the engine keeps them for ``max_batch`` slots
+  BESIDE the pages, in the cache manager (``serving/cache.py``),
+  donated to ``jit_step`` and ``jit_prefill`` with the pools and
+  carried on the device from step to step like a draft's or a block's
+  state.  Two cases.  **A bounded past** (``models/zaya.py``: two
+  causal convolutions and a shifted value need rows of the slot's
+  previous token; 5.4 KB a slot and layer).  **The whole past**
+  (``models/falcon_h1.py``: a state-space mixer's running state, 4.19 MB
+  of float32 a slot and layer, which every token decays and adds to).
+  Its life is the slot's either way: **the prefill writes it** (the
+  state after the prompt's last REAL token, whatever the bucket's
+  padded tail holds), all of it, so nothing of the slot's previous
+  occupant survives an admission; a step advances it for the slots that
+  ran and leaves an inactive slot's alone; a release leaves it where it
+  is (the next admission overwrites it).  **What a step costs in
+  bytes** is the running slots' state once in and once out
+  (``serve.decode_step``'s ``state_bytes``): 14 MB under ZAYA1, 4.3 GB
+  under Falcon-H1 at 128 slots, as much as the page pools hold.  So the
+  guard of an idle slot is the engine's ``where`` over what the model
+  handed back (``keep_inactive``) unless the model's own update keeps an
+  idle slot bit for bit (``state_spec``'s ``keeps_inactive``): then the
+  engine adds no pass over the state.  **No snapshot is taken at a
+  preemption**: the request comes back with its generated prefix as
+  prompt and its second prefill rebuilds the state from the tokens
+  (``serve.prefill``'s ``rebuilt=1``, ``stats()["state_rebuilds"]``):
+  exactly for a bounded past; for the whole past by the prefill's scan
+  over all its tokens, which gives the stepped state to rounding.  This
+  holds because a prefill is never cut into chunks (``_bucket`` goes to
+  ``max_len``): a prefill in chunks WILL need the state kept at a
+  chunk's start; nothing here does that yet.
 
 **What the engine asks of a model** (``models/transformer.py`` and
 ``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
@@ -135,7 +148,9 @@ through: ``paged_prefill(params, caches, prompt, t0, pages)`` ->
 *shape)`` array a shape, the state after position ``t0 - 1`` (the
 engine writes them into the slot), and ``paged_decode(params, caches,
 tables, lengths, tokens, active, state=, ...)`` -> ``(caches, logits,
-counts, state)`` (the engine keeps an inactive slot's old state).  A
+counts, state)`` (the engine keeps an inactive slot's old state,
+unless ``state_spec`` says ``keeps_inactive``: the model's step then
+hands an idle slot's state back as it was, ``models/falcon_h1.py``).  A
 model without ``state_spec`` runs the programs it always ran; one with
 it neither drafts nor generates by blocks.  ``int8=True``
 needs the model's
@@ -374,6 +389,8 @@ class LMEngine:
         # ... or carries state that is not keys and values, a slot
         state = model.state_spec(self.params) \
             if hasattr(model, "state_spec") else None
+        #: the model's step keeps an inactive slot's state itself
+        self._state_guarded = bool(state and state.get("keeps_inactive"))
         if state and (self._drafts or self._block):
             raise ValueError("a model whose slots carry state neither "
                              "drafts nor generates by blocks")
@@ -506,11 +523,18 @@ class LMEngine:
             names.SERVE_BLOCK_POSITIONS_TOTAL,
             "Masked positions a block model's refining passes met, by "
             "outcome", labels=("outcome",)) if self._block else None
+        self._rebuild_counter, self._state_rebuilds = None, 0
+        #: fixed for the engine's life: read on every step
+        self._slot_state_bytes = self.cache.state_bytes_per_slot()
         if self.cache.state:
             reg.gauge(
                 names.SERVE_SLOT_STATE_BYTES,
                 "Bytes of state a slot carries beside its pages, over "
                 "all layers").set(float(self.cache.state_bytes_per_slot()))
+            self._rebuild_counter = reg.counter(
+                names.SERVE_STATE_REBUILDS_TOTAL,
+                "Prefills of a preempted request under a model whose "
+                "slots carry state: the state rebuilt from the tokens")
         self._swap_counter = reg.counter(
             names.SERVE_WEIGHT_SWAPS_TOTAL,
             "Live weight hot-swaps completed, by promoted version",
@@ -705,8 +729,12 @@ class LMEngine:
                         params, rest[:pools], tables, lengths, tokens,
                         active, state=rest[pools:n], page_size=page_size,
                         qparams=qparams)
-                    caches = (*caches,
-                              *keep_inactive(state, rest[pools:n], active))
+                    # ... unless the model's own update leaves a slot
+                    # that did not run as it was (no pass over the state
+                    # to put the old values back)
+                    caches = (*caches, *(
+                        state if self._state_guarded
+                        else keep_inactive(state, rest[pools:n], active)))
                 else:
                     caches, logits, counts = model.paged_decode(
                         params, rest[:n], tables, lengths, tokens, active,
@@ -896,6 +924,12 @@ class LMEngine:
                 tracer.add_attrs(
                     span_id,
                     state_bytes=self.cache.state_bytes_per_slot())
+                if req.preempted:
+                    # its whole past is computed again: the state is
+                    # rebuilt from the tokens, not restored
+                    tracer.add_attrs(span_id, rebuilt=1)
+                    self._state_rebuilds += 1
+                    self._rebuild_counter.inc()
             with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
                              program="prefill") as dispatch_id:
                 self._note_dry(tracer, dispatch_id)
@@ -988,6 +1022,7 @@ class LMEngine:
         self._slots[slot] = None
         self._stash.appendleft(req)
         self._preempt_counter.inc()
+        req.preempted += 1
         if req.trace is not None:
             req._tr_preempts.append(time.monotonic())
         obs.get_tracer().event(spans.EVENT_PREEMPT, slot=slot,
@@ -1310,8 +1345,14 @@ class LMEngine:
         else:
             toks, emitted, context = res[:, None], None, rec.context_rows
         if rec.counts is not None:
-            attrs.update(self._note_routing(rec.counts),
-                         **self._context_attrs(context))
+            attrs.update(self._note_routing(rec.counts))
+        if rec.counts is not None or self.cache.state:
+            attrs.update(self._context_attrs(context))
+        if self.cache.state:
+            # what the step read and wrote of the slots' state: in and
+            # out, for the slots that ran
+            attrs["state_bytes"] = (2 * len(rec.entries)
+                                    * self._slot_state_bytes)
         return _StepRead(toks, emitted, drafts, attrs)
 
     def _context_attrs(self, rows) -> dict:
@@ -1590,6 +1631,8 @@ class LMEngine:
             "kv_pages_total": self.cache.num_pages - 1,
             # what a slot carries beside its pages (0: nothing)
             "state_bytes_per_slot": self.cache.state_bytes_per_slot(),
+            # prefills that rebuilt a preempted request's state
+            "state_rebuilds": self._state_rebuilds,
             "draining": self.draining,
             "weight_version": self.weight_version,
             "manifest_sha": self.manifest_sha,

@@ -284,6 +284,31 @@ class TestGroupedDecodeParity:
             atol=2e-5 if dtype == "float32" else 2e-2)
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_five_query_rows_a_key_head_match_the_oracle(self, dtype):
+        """The hybrid cell's shape (``models/falcon_h1.py``): 20 query
+        heads over 4 key heads of 128, rows of 512 lanes in pages of
+        16, 128 pages a slot.  5 query rows a key head is no multiple
+        of the 8 sublanes; bfloat16 blocks are 40 pages.  Lengths at a
+        copy group's edge (8 pages), a block's, and the table's end."""
+        p, maxp, hkv, g = 16, 128, 4, 5
+        bp = D._block_pages(p, hkv * 128, jnp.dtype(dtype).itemsize,
+                            hkv * g)
+        assert bp == (40 if dtype == "bfloat16" else 16)
+        lengths = [0, 8 * p - 1, 8 * p, bp * p - 1, bp * p,
+                   maxp * p - 1, None, 2 * bp * p + 5]
+        q, kp, vp, tbl, lens = _grouped_state(
+            lengths, 1, g, hkv=hkv, p=p, maxp=maxp, dtype=dtype, seed=11)
+        want = _grouped_reference(q, kp, vp, tbl, lens, hkv)
+        got = paged_decode_attention(q[:, 0], _stacked(kp, 1),
+                                     _stacked(vp, 1), tbl, lens,
+                                     page_size=p, layer=1)
+        assert got.shape == (len(lengths), hkv * g, 128)
+        assert got.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64).reshape(want.shape), want,
+            atol=2e-5 if dtype == "float32" else 2e-2)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     def test_trash_page_and_unwritten_tail_never_reach_an_output(
             self, dtype):
         """Huge finite values in page 0 (what unallocated table entries

@@ -248,6 +248,8 @@ def _layer_cases():
         (N.RMSNorm(6), seq),
         (N.GatedMLP(6, 10), seq),
         (N.LatentAttention(6, 2, 4, 4, 2, 2, 2), seq),
+        (N.Mamba2Mixer(6, 4, 2, 4, 2, chunk=2, in_multiplier=0.5,
+                       zone_multipliers=(0.5, 1.0, 2.0, 1.0, 0.5)), seq),
     ]
     return cases
 

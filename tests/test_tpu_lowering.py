@@ -13,6 +13,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from bigdl_tpu.ops.attention import flash_attention
@@ -336,11 +337,13 @@ def _kernel_calls(text: str, kernel: str) -> int:
 
 def _whole_cache_ops(compiled, buf) -> dict:
     """Instructions of a compiled program whose result has the whole
-    cache's shape, by kind (the cache write is a ``scatter fusion``)."""
+    shape and element type of ``buf`` (a page pool, or the slots'
+    state), by kind (the cache write is a ``scatter fusion``)."""
     import re
 
     dims = ",".join(str(n) for n in buf.shape)
-    whole = re.compile(r"= bf16\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
+    kind = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(buf.dtype).name]
+    whole = re.compile(r"= %s\[%s\]\{[^}]*\} ([\w-]+)\(" % (kind, dims))
     ops = {}
     for line in compiled.as_text().splitlines():
         m = whole.search(line)
@@ -710,7 +713,7 @@ def test_state_engine_programs_work_on_the_cache_as_it_lies(one_chip,
                   for shp in ss["shapes"])
     eng = types.SimpleNamespace(
         model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=0, cache=types.SimpleNamespace(
+        _block=0, _state_guarded=False, cache=types.SimpleNamespace(
             buffers=lambda: (buf, buf) + state, pools=lambda: (buf, buf)),
         _prefill_fns={})
     key = spec((), jax.random.key(0).dtype)
@@ -744,3 +747,207 @@ def test_state_engine_programs_work_on_the_cache_as_it_lies(one_chip,
         # 256 x 262272 float32 logits are 269 MB
         assert temp < 700e6, (name, temp)
 
+
+
+# Falcon-H1-34B's attention at the cell's engine size
+# (benchmarks/configs/falcon_h1_34b.json): 128 slots, one token a slot,
+# 20 query heads over 4 key heads of 128 lanes, pages of 16, 128 pages a
+# slot, the stacked bfloat16 pools of four layers
+HYBRID = dict(slots=128, block=1, heads=20, kv_heads=4, head_dim=128,
+              page=16, maxp=128, layers=4)
+
+
+def test_hybrid_decode_is_one_kernel_at_the_engine_shape(monkeypatch):
+    """5 query rows a key head (no multiple of the 8 sublanes) over
+    512-value rows: the page-walking kernel's case, ONE Mosaic call and
+    no gather."""
+    b = HYBRID["slots"]
+    shapes = _grouped_shapes(HYBRID)
+    shapes = (((b, HYBRID["heads"], HYBRID["head_dim"]), jnp.bfloat16),) \
+        + shapes[1:]
+
+    def text():   # a new function a call: nothing traced is reused
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+        return jax.jit(lambda *a: _grouped(*a)).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+    lowered = text()
+    assert lowered.count("tpu_custom_call") == 1
+    assert 'kernel_name = "grouped_decode_attention"' in lowered
+    assert "stablehlo.gather" not in lowered
+
+
+def _hybrid_programs(one_chip, layers, slots=128):
+    """Falcon-H1-34B's ``jit_step`` and a prefill of 256 at the
+    published widths, ``layers`` layers and the cell's engine size (the
+    default pool of 16385 pages, the slots' two float32 state arrays),
+    lowered from shapes alone for the described chip; with them the
+    pools' and the states' specs."""
+    import functools
+    import types
+
+    from benchmarks.reference import falcon_h1_34b as ref
+    from bigdl_tpu.models.falcon_h1 import PUBLISHED, FalconH1
+    from bigdl_tpu.serving.engine import LMEngine
+
+    dt = jnp.bfloat16
+    sizes = dict(PUBLISHED, num_hidden_layers=layers)
+    page, max_len = 16, 2048
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(dict(sizes, max_len=max_len)), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    probe = FalconH1(max_len=max_len, params=weights, **sizes)
+    cs, ss = probe.cache_spec(weights), probe.state_spec(weights)
+    assert (cs["row_width"], cs["kv_heads"], cs["heads"], cs["buffers"]) \
+        == (512, 4, 20, 2)
+    assert ss["shapes"] == ((32, 256, 128), (3, 5120))
+    assert ss["keeps_inactive"]
+    buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
+    state = tuple(spec((ss["layers"], slots) + shp, ss["dtype"])
+                  for shp in ss["shapes"])
+    eng = types.SimpleNamespace(
+        model=probe, page_size=page, _qparams=None, _drafts=False,
+        _block=0, _state_guarded=True, cache=types.SimpleNamespace(
+            buffers=lambda: (buf, buf) + state, pools=lambda: (buf, buf)),
+        _prefill_fns={})
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((slots,), jnp.int32)
+    flags = spec((slots,), jnp.bool_)
+    return {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, buf, *state, spec((slots, 128), jnp.int32), ints,
+            ints, ints, flags, spec((slots,), jnp.float32), flags, key),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, buf, *state, spec((1, 256), jnp.int32),
+            spec((), jnp.int32), spec((256 // page,), jnp.int32),
+            spec((), jnp.float32), key, spec((), jnp.int32))}, buf, state
+
+
+@pytest.mark.slow
+def test_hybrid_engine_programs_work_on_cache_and_state_as_they_lie(
+        one_chip, monkeypatch):
+    """Falcon-H1-34B's decode step and a prefill (two layers; 128 slots)
+    compiled for the described v5e with both pools AND the state
+    donated: ONE ``grouped_decode_attention`` kernel a layer at 5 query
+    rows a key head, no instruction of a whole pool's size but the
+    scatters, NO COPY of the slots' ``H`` (1 GB at two layers) and none
+    of its size among the temporaries: the state is updated where it
+    lies."""
+    import functools
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers = 2
+    programs, buf, state = _hybrid_programs(one_chip, layers)
+    h_bytes = 4 * functools.reduce(lambda a, n: a * n, state[0].shape)
+    for name, lowered in programs.items():
+        text = lowered.as_text()
+        if name == "step":
+            assert _kernel_calls(text, "grouped_decode_attention") == layers
+        else:
+            assert "grouped_decode_attention" not in text
+        compiled = lowered.compile()
+        ops = _whole_cache_ops(compiled, buf)
+        held = _whole_cache_ops(compiled, state[0])
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        print(f"hybrid {name}: whole-cache instructions {ops}, whole-state "
+              f"instructions {held}, temporaries {temp / 1e6:.1f} MB, the "
+              f"slots' H {h_bytes / 1e6:.1f} MB")
+        assert set(ops) <= {"parameter", "scatter",
+                            "scatter fusion"}, (name, ops)
+        assert "copy" not in held and "copy-start" not in held, (name, held)
+        # 128 x 261120 float32 logits are 134 MB; a layer's slice of H
+        # would be 537 MB
+        assert temp < 500e6, (name, temp)
+
+
+# ---------------------------------------------------------------------------
+# The serving programs of the models that were there before a model
+# whose state sums over the whole past: PR 39 gave the engine a branch
+# for it (``state_spec``'s ``keeps_inactive``) and must have changed
+# nothing for the others.  The first 16 hex digits of the sha256 of the
+# StableHLO of ``jit_step`` and of a two-page ``jit_prefill`` at the
+# benchmark's tiny configurations, recorded at PR 38's commit (the same
+# text on both trees).  A PR that MEANS to change one of these programs
+# records its hash anew and says so.
+LOWERED = {
+    "tiny_gpt": ("44c1efaacc306ca7", "2758d6a589a3d91d"),
+    "tiny_longcat": ("01b7469fc8c47785", "9f689df787f426e7"),
+    "tiny_joyai": ("1f52c73cb02fe133", "cc5edd3f0a3bdc24"),
+    "tiny_sdar": ("22f94d15730cea8f", "97b140ade177e8e2"),
+    "tiny_zaya": ("41367d8e455b0505", "74ca57d30002b6b2"),
+}
+
+
+def _tiny_engine(name):
+    """The engine the benchmark's drivers build for a tiny
+    configuration of its tests, weights from seed 3."""
+    import json
+
+    from benchmarks.drivers import serve, serve_lm
+    from benchmarks.lib import harness
+
+    with open(os.path.join(REPO, "benchmarks", "tests", "data",
+                           name + ".json"), encoding="utf-8") as fh:
+        config = json.load(fh)
+    ref = harness.reference_for(config)
+    sizes = ref.sizes_of(config)
+    params = ref.init_params(
+        3, sizes, jnp.dtype(config["assumed"]["serving_dtype"]))
+    if config["kind"] == "serve":
+        return serve.build_engine(config, params, sizes)
+    return serve_lm.build_engine(config, params)
+
+
+@pytest.fixture(scope="module")
+def tiny_engines():
+    built = {}
+
+    def get(name):
+        if name not in built:
+            built[name] = _tiny_engine(name)
+        return built[name]
+
+    return get
+
+
+def _sha(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+@pytest.mark.parametrize("name", sorted(LOWERED))
+def test_the_other_serving_models_programs_lower_unchanged(
+        tiny_engines, name, program):
+    eng = tiny_engines(name)
+    b = eng.max_batch
+    ints, flags = jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool)
+    if program == "step":
+        tables, lengths = eng.cache.device_tables(pages=2)
+        if eng._drafts:
+            host = (ints, ints, ints, flags, flags)
+        elif eng._block:
+            wide = jnp.zeros((b, eng._block), jnp.int32)
+            host = (wide, wide.astype(bool), flags, flags)
+        else:
+            host = (ints, flags, jnp.zeros((b,), jnp.float32), flags,
+                    jax.random.key(0))
+        text = eng._step_fn.lower(
+            eng.params, *eng.cache.buffers(), tables, lengths,
+            *eng._carry, *host).as_text()
+    else:
+        bucket = 2 * eng.page_size
+        extra = (np.int32(1),) if eng.cache.state else ()
+        text = eng._prefill_fn(bucket).lower(
+            eng.params, *eng.cache.buffers(),
+            jnp.zeros((1, bucket), jnp.int32), 5,
+            jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
+            *extra).as_text()
+    assert _sha(text) == LOWERED[name][program == "prefill"], (name, program)
