@@ -398,6 +398,11 @@ SERVE_STEPS_AHEAD_TOTAL = _m(
     "bigdl_serve_steps_ahead_total", "counter",
     doc="Decode steps dispatched while the previous step's tokens were "
         "still unread (the pipelined loop engaging)")
+SERVE_STEPS_TOTAL = _m(
+    "bigdl_serve_steps_total", "counter", ("pick",), 2,
+    "Decode steps dispatched, by the arm their pick took: greedy (no "
+    "running slot had a temperature above 0: one pass over the logits, "
+    "no draw) or sampled")
 SERVE_SETTLES_TOTAL = _m(
     "bigdl_serve_settles_total", "counter", ("reason",), 4,
     "Steps in flight read outside the pipelined loop, by reason "

@@ -190,24 +190,32 @@ SETTLE_REASONS = ("preempt", "swap", "idle", "close")
 
 def sample_step(logits, temps, active, key):
     """The decode step's next tokens, ``logits`` (B, vocab): greedy at
-    temperature 0, categorical above it, 0 for an inactive slot."""
+    temperature 0, categorical above it, 0 for an inactive slot.  The
+    draw (a random word and two logarithms a logit) runs only in a step
+    in which a running slot samples: what decides is the batch's own
+    temperatures, inside the one program."""
     import jax
     import jax.numpy as jnp
 
-    with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def draw():
         sampled = jax.random.categorical(
             key, logits / jnp.maximum(temps, 1e-6)[:, None],
             axis=-1).astype(jnp.int32)
-        nxt = jnp.where(temps > 0.0, sampled, greedy)
+        return jnp.where(temps > 0.0, sampled, pick_greedy(logits))
+
+    with jax.named_scope("sample"):
+        nxt = jax.lax.cond(jnp.any(active & (temps > 0.0)), draw,
+                           lambda: pick_greedy(logits))
         nxt = jnp.where(active, nxt, 0)
     return nxt
 
 
 def pick_greedy(logits):
-    """What a drafting model's step picks with, ``logits`` (N, vocab)
-    -> (N,): the largest logit (a draft is verified by exact match
-    against it)."""
+    """The greedy pick, ``logits`` (N, vocab) -> (N,) int32: the largest
+    logit, the first among equals.  What a drafting or block model's
+    step picks with (a draft is verified by exact match against it) and
+    what ``sample_step`` and ``sample_first`` pick with where nothing
+    samples."""
     import jax
     import jax.numpy as jnp
 
@@ -221,11 +229,12 @@ def sample_first(logits, temp, key):
     import jax.numpy as jnp
 
     with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        sampled = jax.random.categorical(
-            key, logits / jnp.maximum(temp, 1e-6),
-            axis=-1).astype(jnp.int32)
-        first = jnp.where(temp > 0.0, sampled, greedy)
+        first = jax.lax.cond(
+            temp > 0.0,
+            lambda: jax.random.categorical(
+                key, logits / jnp.maximum(temp, 1e-6),
+                axis=-1).astype(jnp.int32),
+            lambda: pick_greedy(logits))
     return first[0]
 
 
@@ -452,6 +461,7 @@ class LMEngine:
         self._positions_unmasked = 0
         self._slot_steps = self._step_tokens = 0
         self._steps_ahead = 0
+        self._steps_sampled = 0
         self._settles = dict.fromkeys(SETTLE_REASONS, 0)
         self._weight_bytes = self._decode_weight_bytes()
         if self.tp > 1:
@@ -505,6 +515,10 @@ class LMEngine:
             names.SERVE_STEPS_AHEAD_TOTAL,
             "Decode steps dispatched while the previous step's tokens "
             "were still unread")
+        self._pick_counter = reg.counter(
+            names.SERVE_STEPS_TOTAL,
+            "Decode steps dispatched, by the arm their pick took",
+            labels=("pick",))
         self._settle_counter = reg.counter(
             names.SERVE_SETTLES_TOTAL,
             "Steps in flight read outside the pipelined loop, by "
@@ -1182,6 +1196,9 @@ class LMEngine:
             self._last_bucket = bucket
             tables, lengths = self.cache.device_tables(pages=bucket)
             self._key, sub = jax.random.split(self._key)
+            # running slots that sample: 0 means the step's pick takes
+            # its greedy arm (``sample_step``)
+            sampling = int(np.count_nonzero(temps > 0.0))
             tracer.add_attrs(span_id, bucket=bucket, active=len(running))
         t0 = time.perf_counter()
         # a LIVE span (not a retroactive reqtrace hop).  It covers this
@@ -1191,7 +1208,7 @@ class LMEngine:
         n = len(self.cache.buffers())
         prev = self._inflight
         with tracer.span(spans.SPAN_STEP_DECODE, bucket=bucket,
-                         active=len(running),
+                         active=len(running), sampling=sampling,
                          ahead=int(prev is not None)) as span_id:
             with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
                              program="step") as dispatch_id:
@@ -1263,6 +1280,9 @@ class LMEngine:
             self._decode_bytes_gauge.set(step_bytes / len(running))
             self._occ_sum += len(running) / self.max_batch
             self._occ_gauge.set(self._occ_sum / self._steps)
+            self._steps_sampled += sampling > 0
+            self._pick_counter.labels(
+                pick="sampled" if sampling else "greedy").inc()
             if prev is not None:
                 self._steps_ahead += 1
                 self._ahead_counter.inc()
@@ -1602,6 +1622,10 @@ class LMEngine:
             "tokens": self._tokens_total,
             "steps": self._steps,
             "steps_ahead": self._steps_ahead,
+            # the share of steps in which no running slot sampled: the
+            # pick made one pass over the logits and drew nothing
+            "greedy_step_share": (1.0 - self._steps_sampled / self._steps
+                                  if self._steps else None),
             # tokens a slot and step that yielded any (1 unless the
             # model drafts), and the share of verified drafts accepted
             "tokens_per_step": (self._step_tokens / self._slot_steps

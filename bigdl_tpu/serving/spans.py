@@ -41,7 +41,11 @@ Three families:
                           (one step is in flight: engine.py), so its
                           length is about the step period less the
                           host's own work (``bucket=``, ``active=`` of
-                          step k; ``ahead=`` 1 where step k-1 was still
+                          step k; ``sampling=`` its running slots with
+                          a temperature above 0: 0 means its pick drew
+                          nothing, ``engine.sample_step``; the registry
+                          counts the same as ``bigdl_serve_steps_total
+                          {pick}``; ``ahead=`` 1 where step k-1 was still
                           unread at the dispatch, 0 for the first step
                           after an idle engine or a settle; no ``step``:
                           older than the others, and read as it is by

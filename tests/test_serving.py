@@ -487,6 +487,58 @@ class TestOneStepInFlight:
         greedy.close()
         assert runs[0][0] != list(g.tokens)   # it did sample
 
+    def test_a_greedy_request_is_untouched_by_sampling_neighbours(
+            self, lm_model, lm_params, tmp_path, monkeypatch):
+        """The draw runs only in a step in which a running slot samples
+        (``sample_step``), and which arm a step took changes no greedy
+        slot's token: the host counts the arms."""
+        import json
+
+        from bigdl_tpu import obs
+        from bigdl_tpu.obs import names
+        from bigdl_tpu.serving import LMEngine, spans as S
+
+        prompt, new = [3, 7, 11, 2], 10
+        want = _ref(lm_model, lm_params, prompt, new)
+        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path / "trace"))
+        shares = {}
+        for temp in (0.0, 0.9):
+            obs.reset()
+            try:
+                eng = LMEngine(lm_model, max_batch=3, page_size=4, seed=7)
+                g = eng.submit(prompt, new)
+                # the neighbours end before the greedy request does:
+                # the mixed run's last steps take the greedy arm
+                others = [eng.submit(p, 5, temperature=temp)
+                          for p in ([5, 1, 4, 8], [9, 9])]
+                eng.run_until_idle(60)
+                st = eng.stats()
+                eng.close()
+                assert _out(prompt, g) == want
+                assert all(len(r.tokens) == 5 for r in others)
+                shares[temp] = st["greedy_step_share"]
+                picks = obs.get_registry().counter(
+                    names.SERVE_STEPS_TOTAL, "", labels=("pick",))
+                took = {k: picks.labels(pick=k).value
+                        for k in ("greedy", "sampled")}
+                assert sum(took.values()) == st["steps"]
+                assert took["greedy"] == round(
+                    st["greedy_step_share"] * st["steps"])
+                tracer = obs.get_tracer()
+                tracer.flush()
+                with open(tracer.jsonl_path, encoding="utf-8") as fh:
+                    recs = [json.loads(line) for line in fh]
+                sampling = [r["attrs"]["sampling"] for r in recs
+                            if r["kind"] == "span"
+                            and r["name"] == S.SPAN_STEP_DECODE]
+                assert len(sampling) == st["steps"]
+                assert sum(1 for n in sampling if n == 0) == took["greedy"]
+                assert max(sampling) == (2 if temp else 0)
+            finally:
+                obs.reset()
+        assert shares[0.0] == 1.0
+        assert 0.0 < shares[0.9] < 1.0
+
     def test_step_k_plus_1_is_dispatched_before_step_k_is_read(
             self, lm_model, tmp_path, monkeypatch):
         """The order, without a clock: a stub step whose tokens record
