@@ -3,8 +3,8 @@ query heads over 4 key/value heads, an RMS norm on every head's query
 and key, rotary positions by halves), **softmax top-8 experts with
 renormalised weights** in every layer, and **generation by blocks**: a
 block of ``B`` positions is refined over several passes, each pass
-unmasking the positions the model is most confident of, and committed
-to the cache when its last mask is gone.
+unmasking the positions the model is most confident of, and its final
+rows are in the cache before the next block attends.
 
 Source: ``huggingface.co/JetLM/SDAR-30B-A3B-Chat`` ``config.json``
 (``model_type`` ``sdar_moe``).  What that file does not state (block
@@ -46,22 +46,48 @@ With ``m`` positions masked and ``n_s`` the static count of pass ``s``
 position with ``c > threshold`` is unmasked if there are at least
 ``n_s`` of them, otherwise the ``min(n_s, m)`` most confident (ties to
 the earlier position).  An unmasked position is never changed.  When
-none is masked, one more forward of the block writes its final rows
-(**the commit**) and the next block starts all masked.  **Departures**:
-the program keeps a mask FLAG a position and does not test ``token ==
-mask id`` (a prompt or a pick may hold that id), and ``min(n_s, m)``.
+none is masked, the block's final rows are written (**the commit**: its
+final tokens forwarded once more) and the next block starts all masked.
+**Departures**: the program keeps a mask FLAG a position and does not
+test ``token == mask id`` (a prompt or a pick may hold that id),
+``min(n_s, m)``, and the commit is no forward of its own (below).
 
 **Serving.**  Beside ``cache_spec`` the model declares
 :meth:`SDARMoE.block_spec` and carries a slot's state from step to
-step (``serving/engine.py`` "What the engine asks of a model"): one
-``paged_decode`` forwards ``B`` positions for every slot, whatever its
-phase.  The block's provisional rows are WRITTEN before it attends (the
-rows at ``length .. length + B - 1``; the attention's mask is ``pos <
-length + B``), and the commit overwrites them with the final ones.
-The attention is ``ops/decode_attention.paged_decode_attention``'s
-KERNEL path (a key head's ``B x H / H_kv`` query rows share its rows):
-each slot's pages of K and of V are read once, where they lie, up to
-the block's end, and nothing is gathered.
+step (``serving/engine.py`` "What the engine asks of a model").  **A
+block whose last mask has gone is given no forward of its own: its
+final rows are written by the next forward of its slot.**  The mask is
+block-causal, so a block's final rows depend on the earlier blocks and
+on its own final tokens, never on the block behind it: the forward that
+computes them can be the next block's first pass.  One ``paged_decode``
+forwards ``2B`` positions for every slot: the **tail** (the block that
+became final in the slot's last step, its final tokens, at ``length - B
+.. length - 1``) and the **current** block (``length .. length + B -
+1``).  The rows of both are WRITTEN before anything attends; the tail's
+query rows attend ``pos < length`` and the current block's ``pos <
+length + B``, so every row the cache ends up holding and every logit a
+pick is made from is what a forward of the tail alone and then one of
+the block would give.  In the step whose pass unmasks a block's last
+position the device rolls the state over: the block becomes the tail,
+the length advances by ``B``, a new block starts all masked.  A slot
+with NO tail pending (passes 2 to ``T`` of a block, the first block
+behind a prompt, an inactive slot) forwards tail rows that are
+**padding**: written nowhere, not real to the expert layer (routed
+nowhere, 0, not counted), attending nothing, read by nobody.
+The head, the pick and the unmasking rule see the current block's ``B``
+rows only.  **A request's last block never gets final rows**: nothing
+reads them (a preemption folds what was shown into the prompt and
+prefills again).  The attention is
+``ops/decode_attention.paged_decode_attention``'s KERNEL path (a key
+head's ``2B x H / H_kv`` query rows share its rows, each under the
+length of its position): each slot's pages of K and of V are read once,
+where they lie, up to the current block's end, and nothing is gathered.
+The expert layer is handed the real rows packed into the rows it is
+told to expect (every block and the tails of half the slots), so the
+padding costs it what a quarter of a block a slot costs, not what a
+block does; a step with more tails runs it a second time over the rest
+(:meth:`SDARLayer.run`: one expert layer in the program, in a loop
+over the mask's own count).
 
 **A chip's share**, weights brought by the caller, no weights drawn:
 as ``models/longcat_flash.py``.
@@ -91,8 +117,9 @@ GENERATION = dict(block_length=4, denoising_steps=4,
                   mask_token_id=151669)
 RULES = ("low_confidence_dynamic", "low_confidence_static")
 
-#: what a step did for a slot (``paged_decode``'s ``kind``)
-IDLE, REFINED, COMMITTED = 0, 1, 2
+#: what a step did for a slot (``paged_decode``'s ``kind``): nothing,
+#: a refining pass, or the refining pass that left its block final
+IDLE, REFINED, FINISHED = 0, 1, 2
 
 
 class GroupedQueryAttention(AbstractModule):
@@ -177,33 +204,43 @@ class GroupedQueryAttention(AbstractModule):
         with jax.named_scope("dense"):
             return jnp.matmul(o, params["wo"].T), k_rows, v_rows
 
-    def decode(self, params, x, kp, vp, layer: int, tables, lengths):
-        """A block a slot, ``x`` (S, B, dim) at positions ``lengths +
-        0 .. B-1``: its K and V rows are written first, then every
-        position attends every row up to the block's last (module
-        docstring): one call of the page-walking kernel over the
-        stacked buffers at ``layer``, the ``B x H`` queries of a slot
-        under the one length ``lengths + B - 1``, float32 softmax
-        whatever the rows' dtype.  Returns ``(y, kp, vp)``."""
+    def decode(self, params, x, kp, vp, layer: int, tables, lengths, real):
+        """Two blocks a slot, ``x`` (S, 2B, dim): the **tail** (the
+        block before ``lengths``, final tokens) and the **current**
+        block (``lengths + 0 .. B-1``), where ``real`` (S, 2B).  The
+        rows of both are written first, a row that is not real nowhere
+        (``write_token_rows``); then the tail's positions attend every
+        row up to the tail's last and the current block's up to theirs
+        (module docstring): one call of the page-walking kernel over
+        the stacked buffers at ``layer``, the ``2B x H`` queries of a
+        slot each under its own length, float32 softmax whatever the
+        rows' dtype.  A tail that is not real attends nothing and comes
+        out 0.  Returns ``(y, kp, vp)``."""
         import jax
         import jax.numpy as jnp
 
         from bigdl_tpu.ops.decode_attention import paged_decode_attention
         from bigdl_tpu.serving.cache import write_token_rows
 
-        s, b, _ = x.shape
-        pos = lengths[:, None] + jnp.arange(b, dtype=lengths.dtype)
+        s, wide, _ = x.shape
+        b = wide // 2
+        first = lengths - b
+        pos = first[:, None] + jnp.arange(wide, dtype=lengths.dtype)
         with jax.named_scope("dense"):
             q, k_rows, v_rows = self.project(params, x, pos)
         with jax.named_scope("kv_write"):
-            kp = write_token_rows(kp, layer, tables, lengths, k_rows)
-            vp = write_token_rows(vp, layer, tables, lengths, v_rows)
+            kp = write_token_rows(kp, layer, tables, first, k_rows, real)
+            vp = write_token_rows(vp, layer, tables, first, v_rows, real)
         with jax.named_scope("gqa.attn"):
+            ends = jnp.where(real[:, :b], lengths[:, None] - 1, -1)
+            ends = jnp.concatenate(
+                [ends, jnp.broadcast_to(lengths[:, None] + (b - 1), (s, b))],
+                axis=1)
             o = paged_decode_attention(
-                q, kp, vp, tables, lengths + (b - 1), layer=layer,
+                q, kp, vp, tables, ends, layer=layer,
                 page_size=kp.shape[2])
         with jax.named_scope("dense"):
-            y = jnp.matmul(o.reshape(s, b, self.n_head * self.head_dim),
+            y = jnp.matmul(o.reshape(s, wide, self.n_head * self.head_dim),
                            params["wo"].T)
         return y, kp, vp
 
@@ -227,17 +264,50 @@ class SDARLayer(_Composite):
             held=cfg["held_experts"], score="softmax",
             renormalise=cfg["norm_topk_prob"], shared_hidden=0, init=init))
 
-    def run(self, params, h, attend, mask):
+    def run(self, params, h, attend, mask, expected=None):
         """The layer's wiring, once, for every path: ``attend(x)`` is
         the attention over the normalised input; ``mask`` marks the real
-        tokens for the expert layer's counts.  Returns ``(h',
-        counts)``."""
+        tokens for the expert layer's counts.  ``expected`` (a static
+        count under the rows of ``h``) is how many of them the caller
+        expects to be real at most: the expert layer is then handed the
+        real rows PACKED, ``expected`` of them a run, and runs as often
+        as the mask's own count needs (once while it holds no more: its
+        padding then costs what ``expected`` rows cost, not what all
+        do; a second run reads the experts' matrices again, and ``hit``
+        and ``max_load`` count it as they count another layer).
+        Returns ``(h', counts)``."""
+        import jax
+        import jax.numpy as jnp
+
         c = self._children
         a = h + attend(c["norm_attn"].apply(params["norm_attn"], {}, h)[0])
         u = c["norm_mlp"].apply(params["norm_mlp"], {}, a)[0]
-        (m, counts), _ = c["moe"].apply(
-            params["moe"], {}, u.reshape(-1, u.shape[-1]),
-            mask=None if mask is None else mask.reshape(-1))
+        rows = u.reshape(-1, u.shape[-1])
+        real = None if mask is None else mask.reshape(-1)
+        n = rows.shape[0]
+
+        def experts(x, real):
+            return c["moe"].apply(params["moe"], {}, x, mask=real)[0]
+
+        if expected is None or expected >= n:
+            m, counts = experts(rows, real)
+            return a + m.reshape(u.shape), counts
+        # the real rows first, in their order; behind the last of them
+        # a run's rows are padding: not real, and written nowhere
+        order = jnp.concatenate([jnp.argsort(~real, stable=True),
+                                 jnp.full((-n % expected,), n, jnp.int32)])
+
+        def one_run(i, carry):
+            m, counts = carry
+            at = jax.lax.dynamic_slice(order, (i * expected,), (expected,))
+            out, more = experts(
+                jnp.take(rows, at, axis=0, mode="clip"),
+                jnp.take(real, at, mode="fill", fill_value=False))
+            return m.at[at].set(out, mode="drop"), merge_counts(counts, more)
+
+        m, counts = jax.lax.fori_loop(
+            0, -(-jnp.sum(real) // expected), one_run,
+            (jnp.zeros_like(rows), jnp.zeros((5,), jnp.int32)))
         return a + m.reshape(u.shape), counts
 
 
@@ -354,7 +424,7 @@ class SDARMoE(_Composite):
         with jax.named_scope("dense"):
             return jnp.matmul(h, params["head"]["weight"].T)
 
-    def _layers(self, params, x, attend, mask):
+    def _layers(self, params, x, attend, mask, expected=None):
         """Every layer over ``x``; ``attend(i, attn, p, xn)`` is layer
         ``i``'s attention.  Returns the last layer's output and the
         summed routing counts."""
@@ -363,7 +433,8 @@ class SDARMoE(_Composite):
             layer, p = self._children[f"l{i}"], params[f"l{i}"]
             x, n = layer.run(
                 p, x, lambda xn, i=i, layer=layer, p=p: attend(
-                    i, layer._children["attn"], p["attn"], xn), mask)
+                    i, layer._children["attn"], p["attn"], xn), mask,
+                expected)
             counts = merge_counts(counts, n)
         return x, counts
 
@@ -396,14 +467,14 @@ class SDARMoE(_Composite):
                 "max_len": self._config["max_len"],
                 "dtype": params["embed"]["weight"].dtype,
                 # the attention kernel's query rows a slot: every head
-                # at each of the block's positions
-                "attn_query_rows": self.block * attn.n_head,
+                # at each position of the tail and of the block
+                "attn_query_rows": 2 * self.block * attn.n_head,
                 "expert_slots": self.n_layer * layer["moe"].n_held}
 
     def block_spec(self, params) -> dict:
-        """The model generates by blocks: a decode step forwards
-        ``block_length`` positions a slot, a block takes 1 to
-        ``passes`` refining passes and one that commits it."""
+        """The model generates by blocks of ``block_length``
+        positions; a block takes 1 to ``passes`` refining passes, and
+        the first of the next block's writes its final rows."""
         del params
         g = self.generation
         return {"block_length": self.block, "passes": g["denoising_steps"],
@@ -459,37 +530,60 @@ class SDARMoE(_Composite):
         return (kp, vp), self.first_block(prompt, t0), counts
 
     def block_logits(self, params, caches, tables, lengths, tokens, masked,
-                     active):
-        """One forward of a block a slot: ``tokens`` (S, B) at positions
-        ``lengths + 0 .. B-1``, the mask token where ``masked``.  Writes
-        the block's rows and returns ``(caches, logits (S, B, vocab),
-        counts)``."""
+                     active, tail=None, pending=None):
+        """One forward of a slot's pending tail and its current block
+        (module docstring, Serving): ``tokens`` (S, B) at positions
+        ``lengths + 0 .. B-1``, the mask token where ``masked``, behind
+        ``tail`` (S, B), the final tokens of the block before it, real
+        where ``pending`` (S,) (None: no slot has one).  Writes the rows
+        of both and returns ``(caches, logits (S, B, vocab), counts)``:
+        the logits of the current block only, the counts of the real
+        rows only."""
         import jax.numpy as jnp
 
         kp, vp = caches
-        every = jnp.broadcast_to(active[:, None], tokens.shape)
+        b = tokens.shape[1]
+        if tail is None:
+            tail, pending = jnp.zeros_like(tokens), jnp.zeros_like(active)
+        real = jnp.concatenate(
+            [jnp.broadcast_to((pending & active)[:, None], tokens.shape),
+             jnp.broadcast_to(active[:, None], tokens.shape)], axis=1)
 
         def attend(i, attn, p, xn):
             nonlocal kp, vp
-            y, kp, vp = attn.decode(p, xn, kp, vp, i, tables, lengths)
+            y, kp, vp = attn.decode(p, xn, kp, vp, i, tables, lengths, real)
             return y
 
-        fed = jnp.where(masked, self.generation["mask_token_id"], tokens)
+        fed = jnp.concatenate(
+            [tail, jnp.where(masked, self.generation["mask_token_id"],
+                             tokens)], axis=1)
+        # the expert layer expects every block and the tails of half
+        # the slots: one slot in four has a tail pending where a block
+        # takes four passes, and a step with more runs it twice
         h, counts = self._layers(params, self._embed(params, fed), attend,
-                                 every)
-        return (kp, vp), self._logits(params, h), counts
+                                 real, expected=real.size * 3 // 4)
+        # the block's rows go to the head flat: logits of (S, B, vocab)
+        # out of the product are relaid twice before the pick reads them
+        logits = self._logits(params, h[:, b:].reshape(-1, h.shape[-1]))
+        return (kp, vp), logits.reshape(*tokens.shape, -1), counts
 
     def paged_decode(self, params, caches, tables, lengths, tokens, masked,
-                     passes, active, *, pick, page_size=None, qparams=None):
-        """One step of every slot, whatever its phase (module
-        docstring): ``tokens`` / ``masked`` (S, B) the block at
-        positions ``lengths + 0 .. B-1``, ``passes`` (S,) the refining
-        passes it has had.  A slot with a masked position refines
-        (``pick`` chooses ``x0``); one with none commits: its rows are
-        final, its length advances by ``B`` and its next block starts
-        all masked.  Returns ``(caches, (tokens, masked, passes,
-        lengths) after the step, kind (S,), counts)`` with ``kind`` one
-        of ``IDLE``, ``REFINED``, ``COMMITTED``."""
+                     passes, tail, pending, active, *, pick, page_size=None,
+                     qparams=None):
+        """One step of every slot (module docstring, Serving):
+        ``tokens`` / ``masked`` (S, B) the block at positions ``lengths
+        + 0 .. B-1``, ``passes`` (S,) the refining passes it has had,
+        ``tail`` (S, B) / ``pending`` (S,) the block before it where its
+        final rows are still to be written.  Every active slot refines
+        (``pick`` chooses ``x0``) and, in the same forward, a pending
+        tail's final rows are written.  Where the pass unmasks the
+        block's last position the state rolls over: the block becomes
+        the pending tail, the length advances by ``B`` and the next
+        block starts all masked.  Returns ``(caches, (tokens, masked,
+        passes, lengths, tail, pending) after the step, kind (S,),
+        counts)`` with ``kind`` one of ``IDLE``, ``REFINED``,
+        ``FINISHED`` (refined, and the block is final: its tokens are
+        the ``tail`` handed back)."""
         import jax
         import jax.numpy as jnp
 
@@ -498,7 +592,8 @@ class SDARMoE(_Composite):
             raise ValueError("SDARMoE offers no int8 decode")
         s, b = tokens.shape
         caches, logits, counts = self.block_logits(
-            params, caches, tables, lengths, tokens, masked, active)
+            params, caches, tables, lengths, tokens, masked, active, tail,
+            pending)
         with jax.named_scope("unmask"):
             flat = logits.reshape(s * b, -1)
             x0 = pick(flat).reshape(s, b)
@@ -510,17 +605,21 @@ class SDARMoE(_Composite):
             newly = unmask(conf, masked, passes,
                            counts=pass_counts(b, spec["passes"]),
                            threshold=spec["threshold"])
-        commit = active & ~jnp.any(masked, axis=1)
-        refine = active & ~commit
-        newly = newly & refine[:, None]
+        newly = newly & active[:, None]
         tokens = jnp.where(newly, x0, tokens)
-        tokens = jnp.where(commit[:, None], 0, tokens)
-        masked = jnp.where(commit[:, None], True, masked & ~newly)
-        passes = jnp.where(commit, 0, passes + refine.astype(passes.dtype))
-        lengths = lengths + jnp.where(commit, b, 0).astype(lengths.dtype)
-        kind = jnp.where(commit, COMMITTED,
-                         jnp.where(refine, REFINED, IDLE)).astype(jnp.int32)
-        return caches, (tokens, masked, passes, lengths), kind, counts
+        masked = masked & ~newly
+        done = (active & ~jnp.any(masked, axis=1))[:, None]
+        tail = jnp.where(done, tokens, tail)
+        tokens = jnp.where(done, 0, tokens)
+        masked = masked | done
+        done = done[:, 0]
+        pending = jnp.where(active, done, pending)
+        passes = jnp.where(done, 0, passes + active.astype(passes.dtype))
+        lengths = lengths + jnp.where(done, b, 0).astype(lengths.dtype)
+        kind = jnp.where(done, FINISHED,
+                         jnp.where(active, REFINED, IDLE)).astype(jnp.int32)
+        return (caches, (tokens, masked, passes, lengths, tail, pending),
+                kind, counts)
 
     def __repr__(self):
         return (f"SDARMoE(vocab={self.vocab_size}, dim={self.dim}, "
@@ -535,6 +634,6 @@ def build_sdar_moe(config: Optional[dict] = None,
     return SDARMoE(params=params, **kw)
 
 
-__all__ = ["COMMITTED", "GENERATION", "GroupedQueryAttention", "IDLE",
+__all__ = ["FINISHED", "GENERATION", "GroupedQueryAttention", "IDLE",
            "PUBLISHED", "REFINED", "SDARLayer", "SDARMoE",
            "build_sdar_moe", "pass_counts", "unmask"]

@@ -279,9 +279,14 @@ def _block_pages(page_size: int, row_width: int, itemsize: int,
     the table's width: a narrower bucket must change no bit): as many
     whole pages as ``_BLOCK_BYTES`` hold, and no more positions than
     1024 or than keep a block's float32 scores (``head_rows`` x
-    positions) within 256 KB."""
+    positions) within 256 KB, but 512 positions whatever the rows: over
+    128 query rows a slot (``models/sdar_moe.py``: a tail and a block,
+    256) the scores pass 256 KB and a key head's share of them, which
+    is what the grouped kernel folds at a time, does not, while blocks
+    of 256 positions cost that step 0.4 of its attention's 4.15 ms
+    (chip run, PR 41; PR 31 found the same tenth)."""
     by_bytes = _BLOCK_BYTES // (page_size * row_width * itemsize)
-    positions = min(1024, (64 * 1024) // max(1, head_rows))
+    positions = min(1024, max(512, (64 * 1024) // max(1, head_rows)))
     bp = max(1, min(by_bytes, positions // page_size))
     return bp if bp < _COPIES_A_TRIP else bp - bp % _COPIES_A_TRIP
 
@@ -429,24 +434,35 @@ def _fold_start(rows: int, width: int):
 # --------------------------------------------------------------------------
 
 
-def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int):
+def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int,
+                    per_row: bool):
     """The kernel body of :func:`_grouped_program` for blocks of ``bp``
     pages of ``page`` rows of ``hkv`` heads of ``d`` lanes and a table
     ``maxp`` wide.  A block of K pages and the same pages of V arrive
     together (:func:`_page_stream`); per key head, its query rows meet
-    its lanes of the K rows, then of the V rows."""
+    its lanes of the K rows, then of the V rows.  ``per_row``: every
+    query row masks by a length of its own (an input beside the
+    queries, as :func:`_latent_kernel`'s) and not by the slot's one
+    prefetched length; nothing else differs."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
     rows_blk = bp * page
 
-    def kernel(tables, need, lens, layer, q_ref, kpool, vpool, o_ref,
-               kbuf, vbuf, ksems, vsems, ring):
+    def kernel(tables, need, *refs):
+        if per_row:
+            (layer, q_ref, lens, kpool, vpool, o_ref,
+             kbuf, vbuf, ksems, vsems, ring) = refs
+        else:
+            (lens, layer, q_ref, kpool, vpool, o_ref,
+             kbuf, vbuf, ksems, vsems, ring) = refs
         nblk, next_block = _page_stream(
             tables, need, layer, ring,
             ((kpool, kbuf, ksems), (vpool, vbuf, vsems)), bp, maxp)
-        length = lens[pl.program_id(0)]
+        # a length a query row, (R, 1) beside the queries; or the slot's
+        # one, a prefetched scalar
+        length = lens[0] if per_row else lens[pl.program_id(0)]
         qs = [q_ref[0, j] for j in range(hkv)]         # (R, Dh) each
         r = qs[0].shape[0]
 
@@ -504,14 +520,26 @@ def _grouped_program(scale: float, interpret: bool):
         qs = qs.reshape(b, s, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4) \
             .reshape(b, hkv, r, d)
         lens = lengths.astype(jnp.int32)
+        per_row = lens.ndim == 2
+        if per_row:
+            # a position's length for each of its query heads, in the
+            # order of a key head's query rows; the pages a slot must
+            # read reach its longest position
+            scalars = (layer,)
+            rows = [jnp.repeat(lens, h // hkv, axis=1)[:, :, None]]
+            row_specs = [pl.BlockSpec((1, r, 1), lambda i, *_: (i, 0, 0))]
+            lens = jnp.max(lens, axis=1)
+        else:
+            scalars, rows, row_specs = (lens, layer), [], []
         need = jnp.clip(lens // p + 1, 1, maxp)
         buffers = pltpu.VMEM((_BUFFERS, bp, p, row), kpool.dtype)
         sems = pltpu.SemaphoreType.DMA((_BUFFERS,))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=2 + len(scalars),
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, hkv, r, d), lambda i, *_: (i, 0, 0, 0)),
+                *row_specs,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -520,14 +548,14 @@ def _grouped_program(scale: float, interpret: bool):
             scratch_shapes=[buffers, buffers, sems, sems,
                             pltpu.SMEM((4,), jnp.int32)])
         out = pl.pallas_call(
-            _grouped_kernel(bp, p, maxp, hkv, d),
+            _grouped_kernel(bp, p, maxp, hkv, d, per_row),
             out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
             grid_spec=grid_spec,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="grouped_decode_attention",
-        )(tables.reshape(-1).astype(jnp.int32), need, lens, layer, qs,
+        )(tables.reshape(-1).astype(jnp.int32), need, *scalars, qs, *rows,
           kpool, vpool)
         return out.reshape(b, hkv, s, h // hkv, d).transpose(0, 2, 1, 3, 4) \
             .reshape(q.shape)

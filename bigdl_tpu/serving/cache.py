@@ -277,7 +277,7 @@ def pool_shape(num_pages: int, page_size: int, kv_heads: int,
     return pool if n_layer is None else (int(n_layer),) + pool
 
 
-def write_token_rows(pages, layer: int, tables, lengths, rows):
+def write_token_rows(pages, layer: int, tables, lengths, rows, real=None):
     """Decode write: slot ``b``'s new token row ``rows[b]`` (``(B,
     kv_heads * head_dim)``, the projection's output as it comes) lands
     at position ``lengths[b]`` of its table — exactly the one row
@@ -288,7 +288,11 @@ def write_token_rows(pages, layer: int, tables, lengths, rows):
     positions ``lengths[b] + 0 .. Q-1`` (a step that verifies a draft
     writes two, a step that refines a block its block's), still one
     scatter; the engine names the page of the last of them before it
-    dispatches the step."""
+    dispatches the step.  ``real`` ``(B, Q)`` marks the rows that are
+    tokens: the others are padding (``lengths[b]`` may be negative
+    there) and are written nowhere, whatever the table says (they name
+    a page past the pool's last, and a scatter drops what is out of
+    bounds)."""
     import jax.numpy as jnp
 
     page_size = pages.shape[2]
@@ -299,7 +303,11 @@ def write_token_rows(pages, layer: int, tables, lengths, rows):
     else:
         pos = lengths[:, None] + jnp.arange(rows.shape[1],
                                             dtype=lengths.dtype)
+        if real is not None:
+            pos = jnp.where(real, pos, 0)
         page = jnp.take_along_axis(tables, pos // page_size, axis=1)
+        if real is not None:
+            page = jnp.where(real, page, pages.shape[1])
     return pages.at[layer, page, pos % page_size, :].set(
         rows.astype(pages.dtype))
 

@@ -54,23 +54,31 @@ that with the production shape:
   wasted row, as after an EOS.  Same loop, same settles; greedy only
   (a temperature on such a model is refused at ``submit``);
 * **a step that refines a block a slot**: a model that generates by
-  blocks (``block_spec``; ``models/sdar_moe.py``) has each step forward
-  ``B`` positions a slot.  A pass unmasks between 1 and ``B`` of them
-  by confidence; a block takes 1 to ``T`` passes and then one that
-  commits its rows, and only then does the slot's length advance, by
-  ``B``.  How many positions a pass unmasked is known on the device
-  one step before the host reads it, so the block's tokens, its mask
-  flags, its pass count and the slot's length are device arrays
-  carried from step to step, as a draft is (a freshly admitted slot's
-  come from the host, selected inside the program); the host keeps
-  bounds (pages and the bucket are named for the end of the block
-  after the one it last saw, a slot runs until its request is done)
-  and reconciles when it reads.  **Emission is by prefix**: a token is
-  handed to its request by the step after which it and every position
-  before it are final, so a step yields 0 to ``B`` tokens a slot and a
-  commit none; ``ServeRequest.unmasked`` keeps every generated
-  position's token and the pass that unmasked it.  Same loop, same
-  settles; greedy only;
+  blocks (``block_spec``; ``models/sdar_moe.py``) has each step refine
+  a block of ``B`` positions a slot.  A pass unmasks between 1 and
+  ``B`` of them by confidence; a block takes 1 to ``T`` passes.  In
+  the step whose pass unmasks its last position the slot's length
+  advances by ``B`` ON THE DEVICE and a new block starts; the finished
+  block's final rows are written by the slot's NEXT forward, which is
+  the new block's first pass (the step forwards ``2B`` positions a
+  slot: that **tail**, or padding where none is pending, and the
+  current block), so no step of a slot yields nothing.  How many
+  positions a pass unmasked is known on the device one step before
+  the host reads it, so the block's tokens, its mask flags, its pass
+  count, the slot's length, the tail's tokens and whether one is
+  pending are device arrays carried from step to step, as a draft is
+  (a freshly admitted slot's come from the host, selected inside the
+  program); the host keeps bounds (pages and the bucket are named for
+  the end of the block after the one it last saw, a slot runs until
+  its request is done) and reconciles when it reads: a result row says
+  whether the block it shows became final, and the host then records
+  it, advances its own length and renews its view in that same read.
+  **Emission is by prefix**: a token is handed to its request by the
+  step after which it and every position before it are final, so a
+  step yields 0 to ``B`` tokens a slot; ``ServeRequest.unmasked``
+  keeps every generated position's token and the pass that unmasked
+  it.  A request's last block is never given final rows (nothing reads
+  them).  Same loop, same settles; greedy only;
 * **state a slot carries that is not keys and values**: a model whose
   layers need more than a token's own rows declares the shapes
   (``state_spec``), and the engine keeps them for ``max_batch`` slots
@@ -132,12 +140,16 @@ rule by which a pass unmasks: ``paged_prefill(..., pick=)`` ->
 ``(caches, (tokens (B,), masked (B,)), counts)``, the first block's
 state and no token (what the prompt's whole blocks leave over sits,
 fixed, at that block's head), and ``paged_decode(params, caches,
-tables, lengths, tokens (S, B), masked (S, B), passes (S,), active,
-pick=)`` -> ``(caches, (tokens, masked, passes, lengths) after the
-step, kind (S,), counts)``: it forwards every slot's block, writes its
-rows at ``lengths + 0 .. B-1``, unmasks where a position is masked and
-commits (``lengths + B``, a new block all masked) where none is; the
-engine carries that state to the next step untouched.  **A model
+tables, lengths, tokens (S, B), masked (S, B), passes (S,), tail (S,
+B), pending (S,), active, pick=)`` -> ``(caches, (tokens, masked,
+passes, lengths, tail, pending) after the step, kind (S,), counts)``:
+it forwards every slot's block, writes its rows at ``lengths + 0 ..
+B-1`` and unmasks; where ``pending`` it also writes the final rows of
+the block before (``tail``, at ``lengths - B .. lengths - 1``) in the
+same forward; where the pass leaves no position masked it rolls the
+state over (the block becomes the pending tail, ``lengths + B``, a new
+block all masked) and says so in ``kind``; the engine carries that
+state to the next step untouched.  **A model
 whose slots carry state** (``models/zaya.py``) answers
 ``state_spec(params)`` -> ``{"layers", "shapes", "dtype"}`` (one array
 ``(layers, max_batch, *shape)`` a shape) beside ``cache_spec``; it
@@ -319,10 +331,10 @@ class _InFlight:
 DRAFT_RESULT = ("first", "second", "emitted", "draft", "length")
 #: what follows the block's tokens and its mask flags in a row of a
 #: block step's result: the slot's length before the step, what the
-#: step did (the model's ``kind``: 0 nothing, then the two below) and
-#: the pass index it ran
-BLOCK_RESULT = ("length", "kind", "pass")
-BLOCK_REFINED, BLOCK_COMMITTED = 1, 2
+#: step did (the model's ``kind``: 0 nothing, then the two below), the
+#: pass index it ran, and whether it wrote a pending tail's final rows
+BLOCK_RESULT = ("length", "kind", "pass", "tail")
+BLOCK_REFINED, BLOCK_FINISHED = 1, 2
 #: ``ServeRequest.unmasked``'s pass for a position that was still masked
 #: when its request ended, and for one that was with the request when a
 #: preemption folded it into the prompt (the state that chose it is gone)
@@ -453,11 +465,13 @@ class LMEngine:
         zeros = jnp.zeros((self.max_batch,), jnp.int32)
         self._carry = (zeros,) * (4 if self._drafts else 1)
         if self._block:
-            # a block model's: tokens, mask flags, pass count, length
+            # a block model's: tokens, mask flags, pass count, length,
+            # and the block before it while its final rows are pending
             wide = jnp.zeros((self.max_batch, self._block), jnp.int32)
-            self._carry = (wide, wide.astype(bool), zeros, zeros)
+            self._carry = (wide, wide.astype(bool), zeros, zeros, wide,
+                           zeros.astype(bool))
         self._draft_verified = self._draft_accepted = 0
-        self._block_passes = self._block_commits = 0
+        self._block_passes = self._block_tails = 0
         self._positions_unmasked = 0
         self._slot_steps = self._step_tokens = 0
         self._steps_ahead = 0
@@ -700,32 +714,39 @@ class LMEngine:
         elif self._block:
             def step(params, *rest):
                 # rest: the cache's buffers (donated), then tables, the
-                # host's lengths, the four arrays the last step carried
-                # (block tokens, mask flags, pass count, length), the
-                # host's block for the slots in fresh (admitted since
-                # that step; their pass count is 0), active
-                (tables, h_len, c_tok, c_mask, c_pass, c_len,
+                # host's lengths, the six arrays the last step carried
+                # (block tokens, mask flags, pass count, length, the
+                # tail's tokens, whether a tail is pending), the host's
+                # block for the slots in fresh (admitted since that
+                # step; their pass count is 0 and no tail is pending),
+                # active
+                (tables, h_len, c_tok, c_mask, c_pass, c_len, c_tail, c_pend,
                  h_tok, h_mask, fresh, active) = rest[n:]
                 tok = jnp.where(fresh[:, None], h_tok, c_tok)
                 mask = jnp.where(fresh[:, None], h_mask, c_mask)
                 done = jnp.where(fresh, 0, c_pass)
                 length = jnp.where(fresh, h_len, c_len)
+                pend = c_pend & ~fresh
                 # an inactive slot computes a wasted block at position 0
                 # of the trash page
                 caches, state, kind, counts = model.paged_decode(
                     params, rest[:n], tables, jnp.where(active, length, 0),
-                    tok, mask, done, active, pick=pick_greedy,
+                    tok, mask, done, c_tail, pend, active, pick=pick_greedy,
                     page_size=page_size, qparams=qparams)
-                new_tok, new_mask, new_pass, new_len = state
-                # where the step committed, the host wants the block it
-                # committed, not the fresh one behind it
-                kept = kind[:, None] == BLOCK_COMMITTED
+                new_tok, new_mask, new_pass, new_len, new_tail, new_pend = \
+                    state
+                # where the pass left the block final, the host wants
+                # that block (the new tail), not the fresh one behind it
+                final = kind[:, None] == BLOCK_FINISHED
                 result = jnp.concatenate(
-                    [jnp.where(kept, tok, new_tok),
-                     jnp.where(kept, mask, new_mask).astype(jnp.int32),
-                     jnp.stack([length, kind, done], axis=1)], axis=1)
+                    [jnp.where(final, new_tail, new_tok),
+                     (new_mask & ~final).astype(jnp.int32),
+                     jnp.stack([length, kind, done,
+                                (pend & active).astype(jnp.int32)], axis=1)],
+                    axis=1)
                 out = (*caches, new_tok, new_mask, new_pass,
-                       jnp.where(active, new_len, length), result)
+                       jnp.where(active, new_len, length), new_tail,
+                       new_pend, result)
                 return out if counts is None else (*out, counts)
         else:
             def step(params, *rest):
@@ -1276,7 +1297,7 @@ class LMEngine:
                     self.max_batch, heads,
                     self.cache.row_width // kv_heads, self.page_size,
                     bucket, kv_item, kv_heads=kv_heads,
-                    positions=self._block or 1)
+                    positions=2 * self._block or 1)
             self._decode_bytes_gauge.set(step_bytes / len(running))
             self._occ_sum += len(running) / self.max_batch
             self._occ_gauge.set(self._occ_sum / self._steps)
@@ -1300,8 +1321,8 @@ class LMEngine:
         may write: 0 for a one-token model; a drafting model's step
         writes a second row, and a step not yet read may have taken its
         draft; a block's step writes its block's rows, and every step
-        not yet read may have committed a block.  Never past the
-        request's last position."""
+        not yet read may have finished a block and moved on to the
+        next.  Never past the request's last position."""
         if not (self._drafts or self._block):
             return 0
         act = self._slots[slot]
@@ -1401,20 +1422,18 @@ class LMEngine:
         request's last token or an EOS)."""
         b = self._block
         toks, after = res[:, :b], res[:, b:2 * b].astype(bool)
-        length, kind, done = res[:, 2 * b:].T          # BLOCK_RESULT
+        length, kind, done, tail = res[:, 2 * b:].T    # BLOCK_RESULT
         emitted = np.zeros((self.max_batch,), np.int32)
         blocks = {}
-        passes = commits = unmasked = left = 0
+        passes = tails = unmasked = left = 0
         context = []
         for slot, act in rec.entries:
             if self._slots[slot] is not act or not kind[slot]:
                 continue    # completed since: a wasted block
             blocks[slot] = (after[slot], int(kind[slot]), int(done[slot]))
             context.append(int(length[slot]) + b)
-            if kind[slot] == BLOCK_COMMITTED:
-                commits += 1
-                continue
             passes += 1
+            tails += int(tail[slot])
             unmasked += int(np.sum(act.block.masked & ~after[slot]))
             left += int(np.sum(after[slot]))
             shown = act.block.shown
@@ -1427,11 +1446,14 @@ class LMEngine:
                     break
             emitted[slot] = n
         self._block_passes += passes
-        self._block_commits += commits
+        self._block_tails += tails
         self._positions_unmasked += unmasked
         self._block_counter.labels(outcome="unmasked").inc(unmasked)
         self._block_counter.labels(outcome="left_masked").inc(left)
-        attrs = dict(block_passes=passes, block_commits=commits,
+        # (no forward of a slot only commits a block: the count stays
+        # for the readers that add it to the passes)
+        attrs = dict(block_passes=passes, block_tails=tails,
+                     block_commits=0,
                      positions_unmasked=unmasked,
                      tokens_emitted=int(emitted.sum()))
         if rec.counts is not None:
@@ -1442,14 +1464,8 @@ class LMEngine:
     def _advance_block(self, slot: int, act: _Active, read: _StepRead):
         """Bring the host's view of ``slot``'s block up to the step
         read; returns the block position its shown tokens start at."""
-        after, kind, done = read.blocks[slot]
+        after, _, done = read.blocks[slot]
         blk = act.block
-        if kind == BLOCK_COMMITTED:
-            # committed: on record, the length advances, a new block
-            self._record_block(act, slot)
-            self.cache.lengths[slot] += self._block
-            blk.renew()
-            return 0
         newly = blk.masked & ~after
         blk.tokens[newly] = read.tokens[slot][newly]
         blk.passes[newly] = done
@@ -1489,32 +1505,38 @@ class LMEngine:
             start = 0
             if slot in read.blocks:
                 start = self._advance_block(slot, act, read)
-            if not n:
-                continue    # owed nothing on the device: a wasted row
-            req = act.req
-            if slot in read.drafts:
-                req.drafts.append((len(req.tokens), read.drafts[slot]))
-            # the dispatch counted one token; the step may have yielded
-            # another (a block's step was counted for none, and its
-            # length advances where it commits)
-            if self._block:
-                act.remaining -= n
-            else:
-                self.cache.lengths[slot] += n - 1
-                act.remaining -= n - 1
-            self._slot_steps += 1
-            self._first_token(req)
-            for j in range(n):
-                tok = int(read.tokens[slot, start + j])
-                req.tokens.append(tok)
-                req.token_times.append(time.perf_counter())
-                self._tokens_total += 1
-                self._step_tokens += 1
-                self._tokens_counter.inc()
-                act.left -= 1
-                if act.left <= 0 or tok == self.eos_id:
-                    self._complete(slot)
-                    break
+            if n:       # 0: owed nothing on the device, a wasted row
+                req = act.req
+                if slot in read.drafts:
+                    req.drafts.append((len(req.tokens), read.drafts[slot]))
+                # the dispatch counted one token; the step may have
+                # yielded another (a block's step was counted for none)
+                if self._block:
+                    act.remaining -= n
+                else:
+                    self.cache.lengths[slot] += n - 1
+                    act.remaining -= n - 1
+                self._slot_steps += 1
+                self._first_token(req)
+                for j in range(n):
+                    tok = int(read.tokens[slot, start + j])
+                    req.tokens.append(tok)
+                    req.token_times.append(time.perf_counter())
+                    self._tokens_total += 1
+                    self._step_tokens += 1
+                    self._tokens_counter.inc()
+                    act.left -= 1
+                    if act.left <= 0 or tok == self.eos_id:
+                        self._complete(slot)
+                        break
+            if slot in read.blocks and self._slots[slot] is act \
+                    and read.blocks[slot][1] == BLOCK_FINISHED:
+                # the pass left the block final and its request goes
+                # on: on record, the length advances (the device's did
+                # in that step), a new block
+                self._record_block(act, slot)
+                self.cache.lengths[slot] += self._block
+                act.block.renew()
 
     def _settle(self, reason: str) -> bool:
         """Read and emit the step in flight, outside the pipelined loop:
@@ -1602,7 +1624,7 @@ class LMEngine:
 
     # ------------------------------------------------------------- stats
     def stats(self) -> dict:
-        forwards = self._block_passes + self._block_commits
+        passes = self._block_passes
         e2e = [c["e2e_s"] for c in self.completed]
         ttft = [c["ttft_s"] for c in self.completed
                 if c["ttft_s"] is not None]
@@ -1635,15 +1657,17 @@ class LMEngine:
             "draft_accept_share": (
                 self._draft_accepted / self._draft_verified
                 if self._draft_verified else None),
-            # a block model's: refining passes and commits its slots
-            # ran, tokens a forward of a block, the commits' share
-            "block_passes": self._block_passes,
-            "block_commits": self._block_commits,
+            # a block model's: forwards of a slot (each a refining
+            # pass), those that also wrote a pending tail's final rows,
+            # those that only committed a block (none: the tail rides a
+            # pass), tokens a forward, the tails' share of the forwards
+            "block_passes": passes,
+            "block_tails": self._block_tails,
+            "block_commits": 0,
             "positions_unmasked": self._positions_unmasked,
-            "tokens_per_forward": (self._step_tokens / forwards
-                                   if forwards else None),
-            "commit_share": (self._block_commits / forwards
-                             if forwards else None),
+            "tokens_per_forward": (self._step_tokens / passes
+                                   if passes else None),
+            "tail_share": (self._block_tails / passes if passes else None),
             "settles": dict(self._settles),
             "busy_s": busy,
             "tokens_per_s": (self._tokens_total / busy

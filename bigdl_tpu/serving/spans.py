@@ -148,35 +148,49 @@ Three families:
   ``active=`` still counts slots, not tokens.
 
   A **model that generates by blocks** (``block_spec``;
-  ``models/sdar_moe.py``) forwards a block of ``B`` positions a slot in
-  a step, so its ``moe_*`` count every position of every live slot's
-  block, its ``context_tokens`` is the rows the step had to read ONCE a
-  slot, up to its block's end (the block's positions and a key head's
-  query heads share one read), and its ``serve.decode_step`` (of the
-  step it READ; a settled step's on ``serve.settle``) also carries:
+  ``models/sdar_moe.py``) refines a block of ``B`` positions a slot in
+  a step and, in the same forward, writes the final rows of the block
+  the slot finished in its last step (the pending **tail**), so its
+  ``moe_*`` count every REAL position of every live slot (the block's,
+  and the tail's where one was pending; padding is not counted; in a
+  step with more tails than the expert layer expects, it runs a second
+  time over the rest and ``moe_hit`` / ``moe_max_load`` count that run
+  as another layer's), its
+  ``context_tokens`` is the rows the step had to read ONCE a slot, up
+  to its current block's end (the positions of both blocks and a key
+  head's query heads share one read), and its ``serve.decode_step`` (of
+  the step it READ; a settled step's on ``serve.settle``) also carries:
 
   ======================  ==============================================
-  ``block_passes``        live slots that ran a refining pass (a wasted
-                          block of a slot that had completed is none)
-  ``block_commits``       live slots whose block was final and was
-                          committed: its rows written, its length
-                          advanced by ``B``; no token comes of it
+  ``block_passes``        live slots that ran a refining pass: the
+                          forwards of a slot (a wasted block of a slot
+                          that had completed is none)
+  ``block_tails``         of those, the slots whose forward also wrote
+                          a pending block's final rows: the blocks that
+                          became final a step before and did not end
+                          their request
+  ``block_commits``       forwards of a slot that ONLY commit a block:
+                          0 since the final rows ride the next pass
+                          (kept for the readers that add it to the
+                          passes)
   ``positions_unmasked``  positions the refining passes made final:
                           between ``block_passes`` and ``B`` times it
   ``tokens_emitted``      tokens the step handed to requests: a
                           position's token goes out with the step after
                           which it and every position before it are
-                          final, so 0 to ``B`` a refining slot
+                          final, so 0 to ``B`` a slot
   ======================  ==============================================
 
   Registry: ``bigdl_serve_block_positions_total{outcome="unmasked"|
   "left_masked"}``; ``stats()["tokens_per_forward"]`` (tokens over
-  passes + commits of a slot), ``["commit_share"]``,
-  ``["block_passes"]``, ``["block_commits"]``,
+  passes of a slot), ``["tail_share"]`` (tails over passes: 1 / T where
+  every block takes ``T`` passes, up to 1 where blocks take one),
+  ``["block_passes"]``, ``["block_tails"]``, ``["block_commits"]``,
   ``["positions_unmasked"]``.  ``ServeRequest.unmasked`` keeps, for
   every generated position, its token and the pass of its block that
   unmasked it.  ``serve.decode_step``'s length is still one step period
-  less the host's work; a reader sees a gap of one to five periods.
+  less the host's work; a reader sees a gap of one to FOUR periods
+  (``B`` passes a block at most, and no step between two blocks).
 
   **Under a model whose slots carry state beside their pages**
   (``state_spec``; ``models/zaya.py``) ``serve.prefill`` also carries

@@ -50,7 +50,8 @@ CELLS = {
                                "long_gen_closed128"),
     "joyai_flash_draft_gen": ("latent", 256, 640, 32, 2, 1,
                               "long_gen_closed256"),
-    "sdar_moe_block_gen": ("grouped", 128, 512, 32, 4, 4,
+    # 8 positions: a step forwards the pending tail beside the block
+    "sdar_moe_block_gen": ("grouped", 128, 512, 32, 8, 4,
                            "long_gen_closed128"),
     "zaya1_cca_long_gen": ("grouped", 256, 256, 8, 1, 2,
                            "long_gen_closed256"),

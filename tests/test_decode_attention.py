@@ -17,6 +17,11 @@ The load-bearing contracts:
   answer for 1 and 4 positions a slot, 2 and 8 query heads a key head,
   float32 and bfloat16 pools, over several blocks of pages a slot and
   at their edges;
+* ``lengths`` of rank 2 gives every position of a slot a length of its
+  own (a position that attends nothing comes out zero); one length a
+  slot builds the program it built before (operations counted on the
+  parent), and ``_block_pages`` answers as it did for every count of
+  query rows up to 128;
 * the latent kernel gives the oracle's answer over several blocks of
   pages a slot, with one and two queries a slot (a length each), in
   float32 and bfloat16, at rows 640 lanes wide;
@@ -224,7 +229,9 @@ def _grouped_state(lengths, s, g, hkv=2, d=128, p=32, maxp=40, seed=0,
 def _grouped_reference(q, kp, vp, tables, lengths, hkv):
     """Float64 oracle for query rows that share a key head: query head
     ``h`` of every position reads key head ``h // (H / hkv)``, all
-    under the slot's one length.  The contract's operands: ``q`` scaled
+    under the slot's one length, or each position under its own
+    (``lengths`` (B, S); -1 attends nothing and gives zeros).  The
+    contract's operands: ``q`` scaled
     in float32 and cast to the pools' dtype, the probabilities cast to
     the pools' dtype before the mix."""
     dtype = kp.dtype
@@ -234,14 +241,21 @@ def _grouped_reference(q, kp, vp, tables, lengths, hkv):
     kp, vp = np.asarray(kp, np.float64), np.asarray(vp, np.float64)
     tables, lengths = np.asarray(tables), np.asarray(lengths)
     out = np.zeros((b, s, h, d))
+    per_position = np.broadcast_to(
+        lengths[:, None] if lengths.ndim == 1 else lengths, (b, s))
     for i in range(b):
-        n = int(lengths[i]) + 1
-        k = np.concatenate(kp[tables[i]], axis=0)[:n].reshape(n, hkv, d)
-        v = np.concatenate(vp[tables[i]], axis=0)[:n].reshape(n, hkv, d)
-        for head in range(h):
-            j = head // (h // hkv)
-            pr = _softmax_rows(qs[i, :, head] @ k[:, j].T)
-            out[i, :, head] = pr @ v[:, j]
+        rows_k = np.concatenate(kp[tables[i]], axis=0)
+        rows_v = np.concatenate(vp[tables[i]], axis=0)
+        for t in range(s):
+            n = int(per_position[i, t]) + 1
+            if n <= 0:
+                continue                # attends nothing: zeros
+            k = rows_k[:n].reshape(n, hkv, d)
+            v = rows_v[:n].reshape(n, hkv, d)
+            for head in range(h):
+                j = head // (h // hkv)
+                pr = _softmax_rows(qs[i, t:t + 1, head] @ k[:, j].T)
+                out[i, t, head] = (pr @ v[:, j])[0]
     return out
 
 
@@ -374,6 +388,168 @@ class TestGroupedDecodeParity:
                                        scale=0.5 * d ** -0.5)
         np.testing.assert_allclose(np.asarray(given), np.asarray(default),
                                    atol=1e-6)
+
+
+class TestALengthAQueryPosition:
+    """``lengths`` of rank 2, ``(B, S)``: every position of a slot
+    attends up to a length of its own (a block model's step forwards
+    the block that just became final beside the current one,
+    ``models/sdar_moe.py``).  One kernel: what differs is the rank of
+    ``lengths``, and a call with one length a slot builds the program
+    it built before lengths could have a rank."""
+    P, MAXP, HKV, S, G = 32, 40, 2, 8, 4
+
+    def _lengths(self, dtype):
+        """A slot each: a tail that ends a page behind a block that
+        starts the next; both inside one page; a tail that attends
+        NOTHING behind the slot's first block; a block that ends the
+        table; a block's worth of pages exactly, and one row more; a
+        tail alone past a block's edge (the current block attends
+        nothing: no caller's case, the kernel's all the same)."""
+        bp = D._block_pages(self.P, self.HKV * 128,
+                            jnp.dtype(dtype).itemsize,
+                            self.HKV * self.S * self.G)
+        assert 8 <= bp < self.MAXP
+        edge, half = bp * self.P, self.S // 2
+        ends = [(self.P - 1, self.P + half - 1), (5, 9), (-1, 3),
+                (self.MAXP * self.P - half - 1, self.MAXP * self.P - 1),
+                (edge - half - 1, edge - 1), (edge - 1, edge + half - 1),
+                (edge + 2, -1)]
+        return np.asarray([[a] * half + [b] * half for a, b in ends])
+
+    def _state(self, dtype, seed=0):
+        per = self._lengths(dtype)
+        q, kp, vp, tbl, _ = _grouped_state(
+            [int(x) for x in per.max(axis=1)], self.S, self.G,
+            dtype=dtype, seed=seed)
+        return q, kp, vp, tbl, jnp.asarray(per, jnp.int32)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layer", [None, 1])
+    def test_every_position_attends_up_to_its_own_length(self, layer,
+                                                         dtype):
+        q, kp, vp, tbl, per = self._state(dtype, seed=3)
+        want = _grouped_reference(q, kp, vp, tbl, per, self.HKV)
+        kw = {}
+        if layer is not None:
+            kp, vp, kw = _stacked(kp, layer), _stacked(vp, layer), \
+                {"layer": layer}
+        got = np.asarray(paged_decode_attention(
+            q, kp, vp, tbl, per, page_size=self.P, **kw), np.float64)
+        assert got.shape == want.shape and np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, want, atol=2e-5 if dtype == "float32" else 2e-2)
+        # a position that attends nothing: finite, and exactly zero
+        nothing = np.asarray(per) < 0
+        assert nothing.sum() == self.S and not got[nothing].any()
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_one_length_broadcast_is_the_rank_one_call_bit_for_bit(
+            self, dtype):
+        lengths = TestGroupedDecodeParity()._lengths(4, 8, dtype)
+        q, kp, vp, tbl, lens = _grouped_state(lengths, 4, 8, dtype=dtype,
+                                              seed=6)
+        one = paged_decode_attention(q, kp, vp, tbl, lens, page_size=self.P)
+        wide = paged_decode_attention(
+            q, kp, vp, tbl, jnp.broadcast_to(lens[:, None], (len(lengths), 4)),
+            page_size=self.P)
+        np.testing.assert_array_equal(_bits(wide), _bits(one))
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_pages_past_the_longest_position_s_group_are_never_copied(
+            self, dtype):
+        """The stream follows the slot's LONGEST position: every table
+        entry past its last needed group of 8 pages names a page of
+        NaNs, and no bit changes."""
+        q, kp, vp, tbl, per = self._state(dtype, seed=4)
+        clean = np.asarray(paged_decode_attention(
+            q, kp, vp, tbl, per, page_size=self.P))
+        named = np.zeros(kp.shape[0], bool)
+        named[np.asarray(tbl).ravel()] = True
+        spare = int(np.flatnonzero(~named)[0])
+        poisoned = np.array(tbl)
+        for i, longest in enumerate(np.asarray(per).max(axis=1)):
+            need = max(int(longest), 0) // self.P + 1
+            poisoned[i, -(-need // 8) * 8:] = spare
+        assert (poisoned == spare).sum() > 8 * len(poisoned)
+        got = np.asarray(paged_decode_attention(
+            q, kp.at[spare].set(jnp.nan), vp.at[spare].set(jnp.nan),
+            jnp.asarray(poisoned), per, page_size=self.P))
+        np.testing.assert_array_equal(_bits(got), _bits(clean))
+
+    #: the operations of the rank-1 call's program at the shapes below,
+    #: counted on the commit before lengths could have a rank (6a5aff6)
+    PARENT_OPS = {
+        "float32": 342, "bfloat16": 348, "dot_general": 4, "exp": 4,
+        "dma_start": 16, "dma_wait": 2, "get": 25, "swap": 12, "while": 4,
+        "cond": 2, "select_n": 16, "le": 1, "broadcast_in_dim": 17,
+        "pallas_call": 1}
+
+    @staticmethod
+    def _eqns(jaxpr):
+        """Every equation of ``jaxpr`` and of the programs inside it."""
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for inner in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from TestALengthAQueryPosition._eqns(inner)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_one_length_a_slot_builds_the_program_it_built(self, dtype):
+        """Operation for operation: the rank-1 call's program holds the
+        parent's count of every primitive (the lengths a prefetched
+        scalar a slot, no input beside the queries); the rank-2 call's
+        differs from it by the one input and by no product, copy or
+        loop."""
+        import jax
+
+        b, s, h, d = 3, 4, 16, 128
+        S = jax.ShapeDtypeStruct
+        pool = S((2, 1 + b * self.MAXP, self.P, self.HKV * d), dtype)
+
+        def program(lens):
+            return jax.make_jaxpr(
+                lambda *a: paged_decode_attention(
+                    *a, layer=1, page_size=self.P))(
+                S((b, s, h, d), dtype), pool, pool,
+                S((b, self.MAXP), jnp.int32), S(lens, jnp.int32))
+
+        import collections
+
+        def count(lens):
+            eqns = list(self._eqns(program(lens).jaxpr))
+            call, = (e for e in eqns if e.primitive.name == "pallas_call")
+            return collections.Counter(e.primitive.name for e in eqns), \
+                len(call.invars)
+
+        one, operands = count((b,))
+        assert sum(one.values()) == self.PARENT_OPS[dtype]
+        for name, n in self.PARENT_OPS.items():
+            assert one.get(name, n) == n, name
+        # tables, need, lengths, layer; the queries; the two pools
+        assert operands == 7
+        two, operands = count((b, s))
+        assert operands == 7    # ... the lengths beside the queries
+        for name in ("dot_general", "exp", "dma_start", "dma_wait", "while",
+                     "cond", "swap", "pallas_call"):
+            assert two[name] == one[name], name
+
+    def test_block_pages_answers_as_it_did_up_to_128_query_rows(self):
+        """The rule that sizes a kernel's block, for every count of
+        query rows a slot that a kernel had before this one's doubled:
+        the formula as it stood, written out."""
+        for page, row, item in [(16, 512, 2), (16, 640, 2), (16, 256, 2),
+                                (32, 256, 4), (32, 256, 2), (4, 16, 4),
+                                (16, 512, 4)]:
+            for head_rows in range(1, 129):
+                by_bytes = (640 * 1024) // (page * row * item)
+                positions = min(1024, (64 * 1024) // head_rows)
+                bp = max(1, min(by_bytes, positions // page))
+                want = bp if bp < 8 else bp - bp % 8
+                assert D._block_pages(page, row, item, head_rows) == want, \
+                    (page, row, item, head_rows)
 
 
 class TestThePathIsChosenByTheShapes:
@@ -719,7 +895,9 @@ class TestThePageStreamFollowsTheLength:
 _STREAM_CELLS = {
     "longcat_flash_long_gen": (640, 64, 32, 1.275),
     "joyai_flash_draft_gen": (640, 64, 32, 1.275),
-    "sdar_moe_block_gen": (512, 128, 32, 1.275),
+    # 256 query rows since a step forwards the pending tail beside the
+    # block (PR 41), and still 32 pages a block
+    "sdar_moe_block_gen": (512, 256, 32, 1.275),
     "zaya1_cca_long_gen": (256, 8, 64, 1.58),
 }
 
@@ -813,7 +991,13 @@ class TestBucketHelpers:
         # head rows -> 32 pages (655 KB, 512 positions)
         assert D._block_pages(16, 640, 2, 64) == 32
         assert D._block_pages(16, 640, 4, 64) == 16     # float32 rows
-        assert D._block_pages(16, 640, 2, 512) == 8     # scores bound it
+        # over 128 head rows a block keeps 512 positions (PR 41: the
+        # block cell's step hands the kernel 256, a tail and a block,
+        # and blocks of 16 pages cost it a tenth)
+        assert D._block_pages(16, 512, 2, 256) == 32
+        assert D._block_pages(16, 640, 2, 512) == 32
+        assert D._block_pages(16, 640, 2, 100) == 32    # scores: 655 -> 32
+        assert D._block_pages(32, 256, 2, 90) == 16     # scores bound it
         assert D._block_pages(4096, 640, 2, 64) == 1    # never under 1
 
     def test_decode_hbm_bytes_carries_gather_tax(self):

@@ -12,9 +12,12 @@
     with page 0 full of garbage; ``decode_hbm_bytes`` by key/value
     heads;
 (d) prefill, then block passes through the paged cache (a prompt that
-    ends inside a block, a context that crosses pages, two slots in
-    different phases in one step) against the reference's two-pass
-    form, and failing where the commit is left out;
+    ends inside a block, a context that crosses pages, a slot whose
+    step also writes the final rows of the block before beside one
+    whose tail is padding) against the reference's two-pass form: the
+    rows the cache ends up holding for every finished block are the
+    committed ones, a padding tail changes nothing, and the oracle
+    fails where the commit is left out;
 (e) the engine: seeded and CONSTRUCTED weights (:func:`confident`: the
     seeded model with its head scaled up, so that confidences pass the
     threshold and a block is done in 1, 2 or 3 passes) against a
@@ -22,7 +25,9 @@
     forward; EOS inside a block; an answer that is no whole number of
     blocks; preemption, a settle and a weight swap with a block half
     refined; emission by prefix; one step in flight against a settled
-    loop; spans, counters, scopes and refusals.
+    loop; no forward that yields nothing (a token a forward on seeded
+    weights, a tail for every block that finished and did not end its
+    request); spans, counters, scopes and refusals.
 
 Tolerances: ``F32_TOL`` bounds float32 accumulation-order noise on
 logits of magnitude about 5 (measured 4e-6); ``GAP_LIMIT`` bounds a
@@ -39,7 +44,7 @@ import pytest
 
 from bigdl_tpu import obs
 from bigdl_tpu.models import sdar_moe_reference as ref
-from bigdl_tpu.models.sdar_moe import (COMMITTED, REFINED, SDARMoE,
+from bigdl_tpu.models.sdar_moe import (FINISHED, REFINED, SDARMoE,
                                        build_sdar_moe, pass_counts, unmask)
 from bigdl_tpu.nn.experts import DroplessExperts
 from bigdl_tpu.ops.decode_attention import (decode_hbm_bytes,
@@ -234,6 +239,42 @@ def test_the_shares_add_up_to_the_uncut_layer_softmax_renormalised():
     assert float(jnp.max(jnp.abs(other - want))) > 10 * F32_TOL
 
 
+@pytest.mark.parametrize("real_rows", [0, 9, 16, 17, 24])
+def test_an_expected_count_of_real_rows_changes_no_count_and_no_row(
+        real_rows):
+    """A layer told to expect 16 real rows of 24: the expert layer
+    sees the real rows packed, 16 of them a run; one run while the mask
+    holds no more, two with more, none with none.  Either way the real
+    rows' outputs, the padding's zeros and the assignments counted are
+    those of the layer without the hint; a second run counts its
+    experts as another layer's."""
+    model, params, _ = make(5)
+    layer, p = model._children["l1"], params["l1"]
+    rng = np.random.default_rng(real_rows)
+    x = jnp.asarray(rng.normal(size=(6, 4, 32)), jnp.float32)
+    mask = jnp.asarray((rng.permutation(24) < real_rows).reshape(6, 4))
+    want, counts = layer.run(p, x, lambda xn: xn, mask)
+    got, same = layer.run(p, x, lambda xn: xn, mask, expected=16)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    same, counts = np.asarray(same), np.asarray(counts)
+    assert np.array_equal(same[:3], counts[:3])
+    assert int(counts[0]) == real_rows * 2
+    if real_rows <= 16:
+        assert np.array_equal(same, counts)
+    else:
+        assert same[3] >= counts[3] and same[4] <= counts[4]
+    def program(expected):
+        return str(jax.make_jaxpr(lambda x, m: layer.run(
+            p, x, lambda xn: xn, m, expected=expected))(x, mask))
+
+    # one expert layer in the program, inside a loop: the products of
+    # the layer without the hint and no more, and no second arm
+    packed, whole = program(16), program(24)
+    assert "while[" in packed and "while[" not in whole
+    assert "cond[" not in packed
+    assert packed.count("ragged_dot") == whole.count("ragged_dot") > 0
+
+
 # ------------------------------------------ (c) the attention body
 def _pools(rng, n_pages, page, hkv, d, dtype=jnp.float32):
     shape = pool_shape(n_pages, page, hkv, d)
@@ -344,8 +385,9 @@ def test_decode_hbm_bytes_by_key_value_heads():
         eng.submit([1, 2, 3], 2)
         eng.run_until_idle()
         bucket = eng.stats()["last_bucket_pages"]
+        # a step hands the kernel the tail's positions and the block's
         want = eng._weight_bytes + 2 * decode_hbm_bytes(
-            2, 8, 8, 4, bucket, 4, kv_heads=2, positions=4)
+            2, 8, 8, 4, bucket, 4, kv_heads=2, positions=2 * B)
         assert eng.stats()["decode_hbm_bytes_per_token"] == want
     finally:
         eng.close()
@@ -399,14 +441,40 @@ def _reference_pass(params, sizes, prefix, block_tokens, block_masked,
                                          variant=variant))[-B:]
 
 
+def _rows_at(cache, slot, positions):
+    """The K and V rows the cache holds for ``slot`` at ``positions``,
+    every layer: (2, layers, n, row)."""
+    at = np.asarray(positions)
+    pages = np.asarray(cache.page_tables[slot])[at // cache.page_size]
+    return np.stack([np.asarray(buf)[:, pages, at % cache.page_size]
+                     for buf in (cache.kp, cache.vp)])
+
+
+def _committed_rows(model, params, page, final):
+    """The rows a commit writes for the whole blocks of ``final``: the
+    dense prefill of the final tokens under the block-causal mask (the
+    program's other path, which (a) holds to the reference's logits)."""
+    bucket = 16
+    while bucket < len(final):
+        bucket *= 2
+    cache = _stepper(model, params, page, 1, bucket // page + 2)
+    _prefill(model, params, cache, 0, np.asarray(final, np.int32), bucket)
+    return _rows_at(cache, 0, np.arange(len(final)))
+
+
 @pytest.mark.parametrize("page,t0", [(4, 6), (8, 13), (4, 8)],
                          ids=["ends_inside_a_block", "crosses_pages",
                               "ends_with_a_block"])
 def test_prefill_then_block_passes_equal_the_reference(page, t0):
-    """One slot through ``paged_prefill`` and six ``paged_decode`` steps
-    (passes and commits), every pass's logits against the reference's
-    forward over the final tokens before the block and the block in its
-    state."""
+    """One slot through ``paged_prefill`` and eleven ``paged_decode``
+    steps: every pass's logits against the reference's forward over the
+    final tokens before the block and the block in its state, whether
+    or not the same forward writes the final rows of the block before;
+    the state rolls over in the step whose pass leaves the block final;
+    and the rows the cache ends up holding for every finished block
+    whose tail has been written are the committed ones."""
+    from bigdl_tpu.serving.engine import pick_greedy
+
     model, params, sizes = make(13)
     cache = _stepper(model, params, page, 1, 64 // page)
     prompt = tokens_of(t0, 5)
@@ -417,37 +485,53 @@ def test_prefill_then_block_passes_equal_the_reference(page, t0):
     final = list(prompt[:t0 - rem])
     state = (jnp.asarray(tok)[None], jnp.asarray(msk)[None],
              jnp.zeros((1,), jnp.int32),
-             jnp.asarray(cache.lengths[:1], jnp.int32))
+             jnp.asarray(cache.lengths[:1], jnp.int32),
+             jnp.zeros((1, B), jnp.int32), jnp.zeros((1,), bool))
     active = jnp.ones((1,), bool)
-    kinds = []
+    kinds, tails = [], 0
     for _ in range(11):
-        tokens, masked, passes, lengths = state
+        tokens, masked, passes, lengths, tail, pending = state
         tables = jnp.asarray(cache.page_tables[:1])
         bufs, logits, _ = model.block_logits(
-            params, cache.buffers(), tables, lengths, tokens, masked, active)
+            params, cache.buffers(), tables, lengths, tokens, masked, active,
+            tail, pending)
         want = _reference_pass(params, sizes, final, np.asarray(tokens[0]),
                                np.asarray(masked[0]))
         np.testing.assert_allclose(logits[0], want, atol=F32_TOL)
-        from bigdl_tpu.serving.engine import pick_greedy
         bufs, state, kind, _ = model.paged_decode(
             params, cache.buffers(), tables, lengths, tokens, masked,
-            passes, active, pick=pick_greedy)
+            passes, tail, pending, active, pick=pick_greedy)
         cache.set_buffers(bufs)
         kinds.append(int(kind[0]))
-        if kinds[-1] == COMMITTED:
-            final += [int(t) for t in tokens[0]]
-            assert int(state[3][0]) == len(final)
+        tails += int(pending[0])
+        # an unmasked position is never changed
+        keep = ~np.asarray(masked[0])
+        shown = state[4] if kinds[-1] == FINISHED else state[0]
+        assert np.array_equal(np.asarray(shown[0])[keep],
+                              np.asarray(tokens[0])[keep])
+        if kinds[-1] == FINISHED:
+            # the block is the tail now, and a new one starts behind it
+            final += [int(t) for t in state[4][0]]
+            assert int(state[3][0]) == len(final) and bool(state[5][0])
             assert bool(jnp.all(state[1])) and int(state[2][0]) == 0
+            assert not np.asarray(state[0]).any()
             cache.lengths[0] = len(final)
             while cache.needs_growth(0, B - 1):
                 assert cache.grow(0)
         else:
             assert int(state[2][0]) == int(passes[0]) + 1
-            # an unmasked position is never changed
-            keep = ~np.asarray(masked[0])
-            assert np.array_equal(np.asarray(state[0][0])[keep],
-                                  np.asarray(tokens[0])[keep])
-    assert kinds.count(COMMITTED) >= 2 and kinds.count(REFINED) >= 8
+            assert not bool(state[5][0])
+            assert int(state[3][0]) == int(lengths[0])
+    assert kinds.count(FINISHED) >= 2 and kinds.count(REFINED) >= 8
+    assert tails == kinds.count(FINISHED) - int(kinds[-1] == FINISHED)
+    # every block that finished and whose tail rode a later forward
+    # holds its committed rows (the blocks before them the prefill's)
+    written = len(final) - B * int(bool(state[5][0]))
+    assert written >= t0 - rem + B
+    np.testing.assert_allclose(
+        _rows_at(cache, 0, np.arange(written)),
+        _committed_rows(model, params, page, final)[:, :, :written],
+        atol=F32_TOL)
 
 
 def test_bfloat16_matrices_fail_through_the_cache_too():
@@ -470,8 +554,10 @@ def test_bfloat16_matrices_fail_through_the_cache_too():
 
 
 def test_two_slots_in_different_phases_in_one_step():
-    """Slot 0 commits while slot 1 refines, in one ``paged_decode``:
-    each does what it would alone."""
+    """Slot 0's step writes the final rows of the block it finished
+    beside the first pass of its next block; slot 1 refines its first
+    block and its tail is padding, in one ``paged_decode``: each does
+    what it would alone."""
     from bigdl_tpu.serving.engine import pick_greedy
 
     model, params, sizes = make(14)
@@ -479,46 +565,97 @@ def test_two_slots_in_different_phases_in_one_step():
     p0, p1 = tokens_of(8, 1), tokens_of(5, 2)
     _prefill(model, params, cache, 0, p0, 16)
     t1, m1 = _prefill(model, params, cache, 1, p1, 16)
-    done = tokens_of(B, 9)              # slot 0's block, all final
-    tokens = jnp.asarray(np.stack([done, t1]))
-    masked = jnp.asarray(np.stack([np.zeros(B, bool), m1]))
-    lengths = jnp.asarray(cache.lengths[:2], jnp.int32)
-    bufs, state, kind, counts = model.paged_decode(
-        params, cache.buffers(), jnp.asarray(cache.page_tables[:2]),
-        lengths, tokens, masked, jnp.asarray([3, 0], jnp.int32),
-        jnp.ones((2,), bool), pick=pick_greedy)
-    assert list(np.asarray(kind)) == [COMMITTED, REFINED]
-    assert list(np.asarray(state[3])) == [12, 4]
-    assert list(np.asarray(state[2])) == [0, 1]
-    assert int(counts[0]) == 2 * B * 2 * 2     # slots x B x top-2 x layers
-    # slot 1 unmasked one position: the reference's most confident
-    want = _reference_pass(params, sizes, p1[:4], t1, m1)
-    conf = np.where(m1, np.max(jax.nn.softmax(want, axis=-1), axis=-1), -1)
-    newly = np.asarray(masked[1]) & ~np.asarray(state[1][1])
-    assert newly.sum() == 1 and int(np.argmax(newly)) == int(np.argmax(conf))
-    assert int(state[0][1][np.argmax(newly)]) == int(
-        np.argmax(want[np.argmax(newly)]))
-    # slot 0's committed rows are what later blocks attend: a pass of
-    # its NEXT block equals the reference over the final sequence
-    cache.set_buffers(bufs)
+    done = tokens_of(B, 9)              # slot 0's last block, all final
     cache.lengths[0] = 12
     while cache.needs_growth(0, B - 1):
         assert cache.grow(0)
-    nxt = jnp.zeros((2, B), jnp.int32)
-    _, logits, _ = model.block_logits(
+    tokens = jnp.asarray(np.stack([np.zeros(B, np.int32), t1]))
+    masked = jnp.asarray(np.stack([np.ones(B, bool), m1]))
+    tail = jnp.asarray(np.stack([done, tokens_of(B, 10)]))   # 1: garbage
+    lengths = jnp.asarray(cache.lengths[:2], jnp.int32)
+    bufs, state, kind, counts = model.paged_decode(
         params, cache.buffers(), jnp.asarray(cache.page_tables[:2]),
-        jnp.asarray([12, 4], jnp.int32), nxt, jnp.ones((2, B), bool),
-        jnp.asarray([True, False]))
-    want = _reference_pass(params, sizes, list(p0) + list(done),
-                           np.zeros(B), np.ones(B, bool))
-    np.testing.assert_allclose(logits[0], want, atol=F32_TOL)
+        lengths, tokens, masked, jnp.zeros((2,), jnp.int32), tail,
+        jnp.asarray([True, False]), jnp.ones((2,), bool), pick=pick_greedy)
+    assert list(np.asarray(kind)) == [REFINED, REFINED]
+    assert list(np.asarray(state[3])) == [12, 4]
+    assert list(np.asarray(state[2])) == [1, 1]
+    assert list(np.asarray(state[5])) == [False, False]
+    # real rows x top-2 x layers: slot 0's tail and block, slot 1's block
+    assert int(counts[0]) == 3 * B * 2 * 2
+    # each unmasked one position: the reference's most confident there
+    wants = [_reference_pass(params, sizes, list(p0) + list(done),
+                             np.zeros(B), np.ones(B, bool)),
+             _reference_pass(params, sizes, p1[:4], t1, m1)]
+    for slot, want in enumerate(wants):
+        before = np.asarray(masked[slot])
+        conf = np.where(before, np.max(jax.nn.softmax(want, axis=-1),
+                                       axis=-1), -1)
+        newly = before & ~np.asarray(state[1][slot])
+        assert newly.sum() == 1
+        assert int(np.argmax(newly)) == int(np.argmax(conf))
+        assert int(state[0][slot][np.argmax(newly)]) == int(
+            np.argmax(want[np.argmax(newly)]))
+    # slot 0's tail rows are what later blocks attend: the committed
+    # rows of the final sequence; slot 1's garbage tail went nowhere
+    cache.set_buffers(bufs)
+    np.testing.assert_allclose(
+        _rows_at(cache, 0, np.arange(8, 12)),
+        _committed_rows(model, params, 4, list(p0) + list(done))[:, :, 8:],
+        atol=F32_TOL)
+    np.testing.assert_allclose(
+        _rows_at(cache, 1, np.arange(4)),
+        _committed_rows(model, params, 4, list(p1[:4])), atol=F32_TOL)
+
+
+def test_a_padding_tail_changes_no_live_row_and_no_routing_count():
+    """A slot with no tail pending forwards tail rows that are padding:
+    whatever tokens they hold, no row of any page but the trash page
+    changes, nor a routing count, a logit or the state after the step;
+    and the pages of a slot that is not active are left alone."""
+    from bigdl_tpu.serving.engine import pick_greedy
+
+    model, params, _ = make(15)
+    outs = []
+    for garbage in (False, True):
+        cache = _stepper(model, params, 4, 2, 16)
+        t0, m0 = _prefill(model, params, cache, 0, tokens_of(6, 1), 16)
+        t1, m1 = _prefill(model, params, cache, 1, tokens_of(9, 2), 16)
+        before = [np.asarray(b) for b in cache.buffers()]
+        tail = np.stack([tokens_of(B, 20), tokens_of(B, 21)]) * int(garbage)
+        bufs, state, kind, counts = model.paged_decode(
+            params, cache.buffers(), jnp.asarray(cache.page_tables[:2]),
+            jnp.asarray(cache.lengths[:2], jnp.int32),
+            jnp.asarray(np.stack([t0, t1])), jnp.asarray(np.stack([m0, m1])),
+            jnp.zeros((2,), jnp.int32), jnp.asarray(tail),
+            # slot 1 is not active, though a tail is pending there
+            jnp.asarray([False, True]), jnp.asarray([True, False]),
+            pick=pick_greedy)
+        outs.append(([np.asarray(b)[:, 1:] for b in bufs],
+                     [np.asarray(a) for a in state], np.asarray(kind),
+                     np.asarray(counts)))
+        # only slot 0's block changed: positions 4..7 of its pages
+        for old, new in zip(before, bufs):
+            changed = np.any(np.asarray(new) != old, axis=(0, 3))
+            changed[0] = False                      # the trash page
+            pages, at = np.nonzero(changed)
+            assert set(pages) == {int(cache.page_tables[0][1])}
+            assert set(at) == {0, 1, 2, 3}
+        assert list(np.asarray(kind)) == [REFINED, 0]
+        assert int(counts[0]) == B * 2 * 2          # slot 0's block alone
+        assert bool(state[5][1]) and not bool(state[5][0])
+    clean, dirty = outs
+    for a, b in zip(clean[0] + clean[1][:4] + list(clean[2:]),
+                    dirty[0] + dirty[1][:4] + list(dirty[2:])):
+        assert np.array_equal(a, b)
 
 
 def test_the_engine_and_the_model_name_a_step_s_kinds_alike():
     from bigdl_tpu.serving import engine
 
-    assert (engine.BLOCK_REFINED, engine.BLOCK_COMMITTED) == \
-        (REFINED, COMMITTED)
+    assert (engine.BLOCK_REFINED, engine.BLOCK_FINISHED) == \
+        (REFINED, FINISHED)
+    assert engine.BLOCK_RESULT == ("length", "kind", "pass", "tail")
     assert (engine.NEVER_UNMASKED, engine.GIVEN) == \
         (ref.NEVER_UNMASKED, ref.GIVEN)
 
@@ -647,10 +784,13 @@ def test_engine_serves_what_the_plain_procedure_generates(build):
                     lasts.append(int(blk.max()) + 1)
         st = eng.stats()
         assert st["tokens"] == sum(n for _, n in JOBS)
-        assert st["block_passes"] > st["block_commits"] > 0
+        # no forward of a slot only commits: a block's final rows ride
+        # the first pass of the next
+        assert st["block_commits"] == 0
+        assert st["block_passes"] > st["block_tails"] > 0
         assert st["tokens_per_forward"] == pytest.approx(
-            st["tokens"] / (st["block_passes"] + st["block_commits"]))
-        assert 0 < st["commit_share"] < 0.5
+            st["tokens"] / st["block_passes"])
+        assert 0 < st["tail_share"] < 0.5
         if build is confident:
             # blocks done in 1, 2 and 3 passes, all in this run (the
             # seeded weights' take 4)
@@ -706,8 +846,7 @@ def test_one_step_in_flight_gives_the_tokens_of_a_settled_loop():
 
 def test_emission_is_always_a_prefix():
     """Whatever order a block's positions are unmasked in, a request's
-    tokens at any moment are the first ones of its final answer, and a
-    commit yields none."""
+    tokens at any moment are the first ones of its final answer."""
     model, params, sizes = confident(9)
     prompt, new = tokens_of(6, 3), 14
     answer, _ = replay(params, sizes, prompt, new)
@@ -722,7 +861,7 @@ def test_emission_is_always_a_prefix():
             seen.append(len(now))
         assert seen == sorted(seen) and seen[-1] == new
         steps = np.diff([0] + seen)
-        assert steps.max() <= B and (steps == 0).sum() >= new // B
+        assert steps.max() <= B
         assert len(req.token_times) == new
     finally:
         eng.close()
@@ -803,7 +942,7 @@ def test_host_and_chip_agree_whenever_a_step_is_settled():
         with eng._lock:
             assert eng._settle("preempt") and eng._inflight is None
         act = _pump_until_half_refined(eng)
-        tok, msk, pas, length = (np.asarray(a) for a in eng._carry)
+        tok, msk, pas, length = (np.asarray(a) for a in eng._carry[:4])
         assert np.array_equal(msk[0], act.block.masked)
         assert np.array_equal(tok[0][~msk[0]], act.block.tokens[~msk[0]])
         assert int(length[0]) == int(eng.cache.lengths[0])
@@ -844,6 +983,69 @@ def test_a_weight_swap_between_passes_serves_the_new_weights_after_it():
         assert list(second.unmasked) == record
     finally:
         eng.model.set_params(params)
+        eng.close()
+
+
+WHOLE = [(5, 7), (8, 8), (3, 1), (10, 10), (16, 4), (4, 12), (7, 13)]
+
+
+def test_no_forward_yields_nothing_on_seeded_weights():
+    """Seeded weights never pass the threshold: a pass unmasks one
+    position.  Requests whose answers end with a block get every
+    position they generate, so the engine runs exactly ONE forward of a
+    slot a token (the parent ran five for four: a commit a block), none
+    of them only commits, and the forwards that also wrote a pending
+    tail's final rows are the blocks that finished less those that
+    ended their request."""
+    model, params, sizes = make(7)
+    jobs = [(tokens_of(p, 90 + i), n) for i, (p, n) in enumerate(WHOLE)]
+    eng, reqs = serve(model, params, jobs)
+    try:
+        blocks = 0
+        for (prompt, new), req in zip(jobs, reqs):
+            assert (len(prompt) + new) % B == 0
+            assert req.error is None and len(req.tokens) == new
+            answer, record = replay(params, sizes, prompt, new)
+            assert [int(t) for t in req.tokens] == answer
+            assert list(req.unmasked) == record
+            blocks += -(-(len(prompt) % B + new) // B)
+        st = eng.stats()
+        assert st["block_commits"] == 0
+        assert st["block_passes"] == st["tokens"] == sum(n for _, n in WHOLE)
+        assert st["tokens_per_forward"] == 1.0
+        assert st["positions_unmasked"] == st["tokens"]
+        assert st["block_tails"] == blocks - len(WHOLE)
+        assert st["tail_share"] == st["block_tails"] / st["block_passes"]
+        assert st["kv_pages_in_use"] == 0
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("settled", [True, False],
+                         ids=["settled", "one_in_flight"])
+def test_a_request_that_ends_with_a_block_takes_no_step_more(settled):
+    """A request whose last token is its block's last position is done
+    when that pass is read: no step commits the block (nothing reads
+    its final rows).  Settled every cycle the engine dispatches a step
+    a token; with one step in flight, the one it had dispatched before
+    it read the last pass, and no other."""
+    model, params, sizes = make(7)
+    prompt, new = tokens_of(8, 95), 12
+    eng = LMEngine(model, params=params, max_batch=2, page_size=4)
+    try:
+        req = eng.submit(prompt, new)
+        while eng.pump(wait_s=0.01) or eng.active_count():
+            if settled:
+                with eng._lock:
+                    eng._settle("idle")
+        assert [int(t) for t in req.tokens] == replay(
+            params, sizes, prompt, new)[0]
+        st = eng.stats()
+        assert st["block_passes"] == new and st["block_commits"] == 0
+        assert st["block_tails"] == new // B - 1
+        assert st["steps"] == new + (0 if settled else 1)
+        assert st["kv_pages_in_use"] == 0
+    finally:
         eng.close()
 
 
@@ -890,6 +1092,9 @@ def test_what_a_block_model_cannot_be_served_with_is_refused(kw, what):
 
 def test_spans_carry_what_a_step_refined_and_committed(tmp_path,
                                                        monkeypatch):
+    """... and which of its forwards also wrote a pending tail's final
+    rows (``block_tails``); no forward only commits
+    (``block_commits`` stays, at 0, for the readers that add it)."""
     from bigdl_tpu.obs import names
     from bigdl_tpu.serving import spans as S
 
@@ -912,9 +1117,9 @@ def test_spans_carry_what_a_step_refined_and_committed(tmp_path,
         # a step's numbers ride on the span that READ it
         assert "block_passes" not in steps[0]["attrs"]
         read = [s["attrs"] for s in steps[1:] + settles]
-        assert all({"block_passes", "block_commits", "positions_unmasked",
-                    "tokens_emitted", "moe_held", "context_tokens"}
-                   <= set(a) for a in read)
+        assert all({"block_passes", "block_tails", "block_commits",
+                    "positions_unmasked", "tokens_emitted", "moe_held",
+                    "context_tokens"} <= set(a) for a in read)
         st = eng.stats()
         # every step is split into its dispatch, and the wait for its
         # result and the read of it, where the next step or a settle
@@ -925,12 +1130,18 @@ def test_spans_carry_what_a_step_refined_and_committed(tmp_path,
             S.SPAN_STEP_DISPATCH, S.SPAN_STEP_WAIT, S.SPAN_STEP_READ)] \
             == [len(steps)] * 3 == [st["steps"]] * 3
         assert sum(a["block_passes"] for a in read) == st["block_passes"]
-        assert sum(a["block_commits"] for a in read) == st["block_commits"]
+        assert sum(a["block_tails"] for a in read) == st["block_tails"] > 0
+        assert sum(a["block_commits"] for a in read) == \
+            st["block_commits"] == 0
         assert sum(a["positions_unmasked"] for a in read) == \
             st["positions_unmasked"]
         assert sum(a["tokens_emitted"] for a in read) == 2 * 11
         for a in read:
-            assert a["block_passes"] + a["block_commits"] <= 2
+            assert a["block_tails"] <= a["block_passes"] <= 2
+            # the real rows that went through the two expert layers (a
+            # slot whose request has ended since ran in the step too)
+            assert a["moe_held"] + a["moe_absent"] >= \
+                (a["block_passes"] + a["block_tails"]) * B * 2 * 2
             assert a["tokens_emitted"] <= B * a["block_passes"]
             assert a["positions_unmasked"] <= B * a["block_passes"]
         # the first step read: both slots' blocks end at 4 and 8
@@ -961,7 +1172,7 @@ def test_step_programs_carry_the_scopes():
         text = eng._step_fn.lower(
             eng.params, eng.cache.kp, eng.cache.vp,
             jnp.zeros((b, 4), jnp.int32), ints, wide, wide.astype(bool),
-            ints, ints, wide, wide.astype(bool), flags, flags
+            ints, ints, wide, flags, wide, wide.astype(bool), flags, flags
         ).as_text(debug_info=True)
         for scope in ("gqa.attn", "unmask", "moe.route", "moe.experts",
                       "kv_write", "dense"):
@@ -1025,8 +1236,10 @@ def test_a_one_token_model_s_step_holds_no_kernel(dtype, monkeypatch):
 def test_the_block_step_holds_one_kernel_a_layer_and_gathers_no_pool(
         monkeypatch):
     """SDAR's block step (32 query rows a key head at the tests' size:
-    4 positions x 4 heads): one call of the page-walking kernel a
-    layer, both buffers handed to it whole, and no gather of a pool."""
+    the tail's 4 positions and the block's 4 x 4 heads): one call of
+    the page-walking kernel a layer for both blocks, both buffers
+    handed to it whole, no gather of a pool, and the head's product
+    over the block's positions alone, flat."""
     import re
 
     model, params, _ = make(3)
@@ -1041,8 +1254,8 @@ def test_the_block_step_holds_one_kernel_a_layer_and_gathers_no_pool(
         text = _lowered_for_a_chip(eng._step_fn, (
             jax.tree.map(_like, eng.params), *bufs,
             jax.ShapeDtypeStruct((b, 4), jnp.int32), ints, wide,
-            wide_flags, ints, ints, wide, wide_flags, flags, flags),
-            monkeypatch)
+            wide_flags, ints, ints, wide, flags, wide, wide_flags, flags,
+            flags), monkeypatch)
     finally:
         eng.close()
     # a model's attentions share one traced program: one private
@@ -1057,3 +1270,11 @@ def test_the_block_step_holds_one_kernel_a_layer_and_gathers_no_pool(
     assert f"tensor<{pool}x" in text
     assert not re.findall(
         r'"stablehlo\.gather"\([^)]*\)[^\n]*: \(tensor<%sx' % pool, text)
+    # the head sees B positions a slot, flat (logits that leave the
+    # product as (slots, B, vocab) are relaid before the pick reads
+    # them); the layers see 2B
+    assert re.findall(r"stablehlo\.dot_general[^\n]*-> tensor<%dx%dx"
+                      % (b * B, VOCAB), text)
+    assert not re.findall(r"stablehlo\.dot_general[^\n]*tensor<\d+x\d+x%dx"
+                          % VOCAB, text)
+    assert not re.findall(r"tensor<%dx%dx" % (b * 2 * B, VOCAB), text)
