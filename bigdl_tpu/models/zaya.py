@@ -7,7 +7,7 @@ sublayers under a scaled residual.
 
 Source: ``huggingface.co/Zyphra/ZAYA1-8B`` ``config.json``
 (``model_type`` ``zaya``).  What that file does not state is marked
-*(assumed)* in ``models/zaya_reference.py``, which has the layer's
+*(assumed)* in ``benchmarks/reference/zaya1_8b.py``, which has the layer's
 equations; the names here are its names.
 
 **What is new to serve.**  A token's cached rows are NOT a function of

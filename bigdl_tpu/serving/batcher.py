@@ -61,7 +61,7 @@ class ServeRequest:
     # off included), (its token, the pass of its block that unmasked
     # it); the pass is -1 where the position was still masked when the
     # request ended and -2 where a preemption made it part of the
-    # prompt (serving/engine.py NEVER_UNMASKED, GIVEN)
+    # prompt (serving/steps.py NEVER_UNMASKED, GIVEN)
     unmasked: List[tuple] = dataclasses.field(default_factory=list)
     # times the LM engine preempted it (pages reclaimed, the generated
     # prefix folded into the prompt, prefilled again)

@@ -43,8 +43,8 @@ written and read through them:
   mask (``position <= length``) guarantees it is never read.
 
 **State that is not keys and values** lives here too, beside the pages
-(a model's ``state_spec``: ``serving/engine.py`` says what the engine
-asks).  Some layers need more than a token's own rows, in one of two
+(a model's ``state_spec``: ``serving/steps.py`` ``OneToken`` says what
+the engine asks).  Some layers need more than a token's own rows, in one of two
 ways.  **A bounded past**: to form a token's rows, a few rows of the
 slot's PREVIOUS token (``models/zaya.py``: two causal convolutions and a
 shifted value; 5.4 KB a slot and layer).  **The whole past**: a running

@@ -1,171 +1,57 @@
 """Continuous-batching LM decode engine.
 
-``TransformerLM.generate`` decodes one fixed batch to completion — the
-whole batch waits for its slowest member (head-of-line blocking), and a
-new request waits for the whole batch to drain.  This engine replaces
-that with the production shape:
+``TransformerLM.generate`` decodes one fixed batch to completion: the
+batch waits for its slowest member, and a new request for the batch to
+drain.  This engine is the production shape:
 
 * **slots**: up to ``max_batch`` requests decode together in one jitted
   step over the paged KV cache (serving/cache.py);
-* **continuous admission**: at every step boundary, free slots are
-  refilled from the request queue (serving/batcher.py) — a finished
-  request's slot and pages are reused immediately, not when the batch
-  drains;
+* **continuous admission**: at every step boundary free slots are
+  refilled from the request queue (serving/batcher.py); a finished
+  request's slot and pages are reused at once;
 * **prefill/decode split**: a new request's prompt runs one batched
-  forward (``TransformerBlock.prefill`` — the identical attention path
-  training uses) padded to a page-aligned bucket, writing its K/V pages
-  and producing its first token; the shared decode step then advances
-  every active slot one token;
+  forward (the attention path training uses) padded to a page-aligned
+  bucket, writing its K/V pages and producing its first token; the
+  shared decode step then advances every active slot;
 * **int8 decode** (``int8=True``): the decode matmuls run on
-  pre-quantized per-output-channel int8 weights via the existing
-  ``ops.quantized_matmul`` path (the same math ``module.quantize()``
-  rides) — decode is memory-bound, so halving/quartering weight bytes
-  is the lever; prefill stays float (it is compute-bound);
+  pre-quantized per-output-channel int8 weights
+  (``ops.quantized_matmul``): decode is memory-bound, so the weights'
+  bytes are the lever; prefill stays float;
 * **TP-sharded decode** (``tp=N``): the step runs under shard_map with
   Megatron row/col-split weights and the block reductions on
   ``parallel/wire.py``'s compressed collectives (serving/tp.py);
 * **preemption**: if the page pool is exhausted mid-decode, the
-  youngest request is preempted — pages freed, the request re-queued
-  with its generated prefix as prompt — instead of deadlocking the
-  batch;
+  youngest request is preempted (pages freed, the request re-queued
+  with its generated prefix as prompt) instead of deadlocking the batch;
 * **one step in flight**: step k+1 is dispatched before step k's
   tokens are read, so the host's emit, admission and prep run while the
-  chip decodes.  A step's tokens are an argument of the next step and
-  never visit the host on the way (a slot admitted since is overridden
-  from the host inside the program); lengths, pages and the owed count
-  advance at dispatch, tokens are emitted one step behind, and
-  whatever needs host and chip to agree (preemption, a weight swap,
-  ``close``, an engine with nothing left to run) settles the step in
-  flight first.  An EOS is learnt one step late: the slot's row in the
-  step already dispatched is wasted, never emitted;
-* **a step that yields one or two tokens a slot**: a model that drafts
-  its own next-but-one token (``draft_spec``; ``models/joyai_flash.py``)
-  has each step verify two positions a slot, the certain token and the
-  draft, and emit the second token where the draft was right.  How many
-  tokens a step yielded is known on the device one step after the host
-  has dispatched the next, so the slot's next input token, next draft,
-  length and owed count are device arrays carried from step to step
-  (a freshly admitted slot's come from the host, selected inside the
-  program), the host keeps bounds (a length's lower bound advances by
-  one a dispatch, pages and the bucket are named for the upper bound,
-  a slot runs while it MAY still owe a token) and reconciles when it
-  reads the step's tokens and emitted counts one step late.  A slot
-  that owed nothing on the device in a step already dispatched is a
-  wasted row, as after an EOS.  Same loop, same settles; greedy only
-  (a temperature on such a model is refused at ``submit``);
-* **a step that refines a block a slot**: a model that generates by
-  blocks (``block_spec``; ``models/sdar_moe.py``) has each step refine
-  a block of ``B`` positions a slot.  A pass unmasks between 1 and
-  ``B`` of them by confidence; a block takes 1 to ``T`` passes.  In
-  the step whose pass unmasks its last position the slot's length
-  advances by ``B`` ON THE DEVICE and a new block starts; the finished
-  block's final rows are written by the slot's NEXT forward, which is
-  the new block's first pass (the step forwards ``2B`` positions a
-  slot: that **tail**, or padding where none is pending, and the
-  current block), so no step of a slot yields nothing.  How many
-  positions a pass unmasked is known on the device one step before
-  the host reads it, so the block's tokens, its mask flags, its pass
-  count, the slot's length, the tail's tokens and whether one is
-  pending are device arrays carried from step to step, as a draft is
-  (a freshly admitted slot's come from the host, selected inside the
-  program); the host keeps bounds (pages and the bucket are named for
-  the end of the block after the one it last saw, a slot runs until
-  its request is done) and reconciles when it reads: a result row says
-  whether the block it shows became final, and the host then records
-  it, advances its own length and renews its view in that same read.
-  **Emission is by prefix**: a token is handed to its request by the
-  step after which it and every position before it are final, so a
-  step yields 0 to ``B`` tokens a slot; ``ServeRequest.unmasked``
-  keeps every generated position's token and the pass that unmasked
-  it.  A request's last block is never given final rows (nothing reads
-  them).  Same loop, same settles; greedy only;
-* **state a slot carries that is not keys and values**: a model whose
-  layers need more than a token's own rows declares the shapes
-  (``state_spec``), and the engine keeps them for ``max_batch`` slots
-  BESIDE the pages, in the cache manager (``serving/cache.py``),
-  donated to ``jit_step`` and ``jit_prefill`` with the pools and
-  carried on the device from step to step like a draft's or a block's
-  state.  Two cases.  **A bounded past** (``models/zaya.py``: two
-  causal convolutions and a shifted value need rows of the slot's
-  previous token; 5.4 KB a slot and layer).  **The whole past**
-  (``models/falcon_h1.py``: a state-space mixer's running state, 4.19 MB
-  of float32 a slot and layer, which every token decays and adds to).
-  Its life is the slot's either way: **the prefill writes it** (the
-  state after the prompt's last REAL token, whatever the bucket's
-  padded tail holds), all of it, so nothing of the slot's previous
-  occupant survives an admission; a step advances it for the slots that
-  ran and leaves an inactive slot's alone; a release leaves it where it
-  is (the next admission overwrites it).  **What a step costs in
-  bytes** is the running slots' state once in and once out
-  (``serve.decode_step``'s ``state_bytes``): 14 MB under ZAYA1, 4.3 GB
-  under Falcon-H1 at 128 slots, as much as the page pools hold.  So the
-  guard of an idle slot is the engine's ``where`` over what the model
-  handed back (``keep_inactive``) unless the model's own update keeps an
-  idle slot bit for bit (``state_spec``'s ``keeps_inactive``): then the
-  engine adds no pass over the state.  **No snapshot is taken at a
-  preemption**: the request comes back with its generated prefix as
-  prompt and its second prefill rebuilds the state from the tokens
-  (``serve.prefill``'s ``rebuilt=1``, ``stats()["state_rebuilds"]``):
-  exactly for a bounded past; for the whole past by the prefill's scan
-  over all its tokens, which gives the stepped state to rounding.  This
-  holds because a prefill is never cut into chunks (``_bucket`` goes to
-  ``max_len``): a prefill in chunks WILL need the state kept at a
-  chunk's start; nothing here does that yet.
+  chip decodes.  What a step hands the next is an argument of the next
+  step and never visits the host on the way; the host's lengths, pages
+  and owed counts advance at dispatch, tokens are emitted one step
+  behind, and whatever needs host and chip to agree (preemption, a
+  weight swap, ``close``, an engine with nothing left to run) settles
+  the step in flight first.  An EOS is learnt one step late: the
+  slot's row in the step already dispatched is wasted, never emitted.
 
-**What the engine asks of a model** (``models/transformer.py`` and
-``models/longcat_flash.py`` both answer): ``cache_spec(params)`` — how
-many cached layers, the width of a token's row, one buffer or two, the
+**What the engine asks of a model**: ``cache_spec(params)`` — how many
+cached layers, the width of a token's row, one buffer or two, the
 longest context, and, where its decode attention is a page-walking
 kernel of ``ops/decode_attention.py``, the query rows a slot it hands
-that kernel (``attn_query_rows``: the engine then says on every
-``serve.decode_step`` what the kernel's stream copied,
-``attn_rows_copied``); ``paged_prefill(params, caches, prompt, t0,
-pages)`` and ``paged_decode(params, caches, tables, lengths, tokens, active,
-...)``, each returning ``(caches, logits, counts)`` with ``counts`` the
-step's expert-routing counts or None.  Sampling, buckets, donation, the
-page tables, spans and ``stats()`` are the engine's; the layers'
-internals are the model's.  **A model that drafts**
-(``models/joyai_flash.py``) also answers ``draft_spec(params)``
-(``tokens_per_step``: 2) and must choose tokens in the middle of its
-step, so it is handed the engine's ``pick`` (logits ``(N, vocab)`` ->
-tokens ``(N,)``) and returns tokens, not logits:
-``paged_prefill(..., pick=)`` -> ``(caches, first, draft, counts)`` and
-``paged_decode(params, caches, tables, lengths, tokens, drafts, owed,
-active, pick=)`` -> ``(caches, picked (B, 2), accepted (B,), next_draft
-(B,), counts)``.  A model that declares no draft runs the programs it
-always ran.  **A model that generates by blocks**
-(``models/sdar_moe.py``) answers ``block_spec(params)``
-(``block_length``, ``passes``, ``threshold``) instead and owns the
-rule by which a pass unmasks: ``paged_prefill(..., pick=)`` ->
-``(caches, (tokens (B,), masked (B,)), counts)``, the first block's
-state and no token (what the prompt's whole blocks leave over sits,
-fixed, at that block's head), and ``paged_decode(params, caches,
-tables, lengths, tokens (S, B), masked (S, B), passes (S,), tail (S,
-B), pending (S,), active, pick=)`` -> ``(caches, (tokens, masked,
-passes, lengths, tail, pending) after the step, kind (S,), counts)``:
-it forwards every slot's block, writes its rows at ``lengths + 0 ..
-B-1`` and unmasks; where ``pending`` it also writes the final rows of
-the block before (``tail``, at ``lengths - B .. lengths - 1``) in the
-same forward; where the pass leaves no position masked it rolls the
-state over (the block becomes the pending tail, ``lengths + B``, a new
-block all masked) and says so in ``kind``; the engine carries that
-state to the next step untouched.  **A model
-whose slots carry state** (``models/zaya.py``) answers
-``state_spec(params)`` -> ``{"layers", "shapes", "dtype"}`` (one array
-``(layers, max_batch, *shape)`` a shape) beside ``cache_spec``; it
-generates one token a step and is sampled by the engine like
-``models/transformer.py``, and its two entry points hand the state
-through: ``paged_prefill(params, caches, prompt, t0, pages)`` ->
-``(caches, logits, counts, rows)`` with ``rows`` one ``(layers,
-*shape)`` array a shape, the state after position ``t0 - 1`` (the
-engine writes them into the slot), and ``paged_decode(params, caches,
-tables, lengths, tokens, active, state=, ...)`` -> ``(caches, logits,
-counts, state)`` (the engine keeps an inactive slot's old state,
-unless ``state_spec`` says ``keeps_inactive``: the model's step then
-hands an idle slot's state back as it was, ``models/falcon_h1.py``).  A
-model without ``state_spec`` runs the programs it always ran; one with
-it neither drafts nor generates by blocks.  ``int8=True``
-needs the model's
+that kernel (``attn_query_rows``: every ``serve.decode_step`` then says
+what the kernel's stream copied, ``attn_rows_copied``) — and
+``paged_prefill`` / ``paged_decode`` over the cache's buffers, with the
+signature of its **kind of step**.  What a kind carries from step to
+step, hands a fresh slot, reads back, how far ahead it names pages and
+how a read reconciles the host's bounds is ONE object the loop holds
+and never asks the name of (``serving/steps.py``, one class a kind,
+each with its contract): no further declaration, ``OneToken``
+(``models/transformer.py``, ``models/longcat_flash.py``); ``state_spec``,
+the same with state a slot carries beside its pages (``models/zaya.py``,
+``models/falcon_h1.py``); ``draft_spec``, ``Drafting``, one or two
+tokens a slot (``models/joyai_flash.py``); ``block_spec``, ``Block``, a
+block refined in place (``models/sdar_moe.py``).  Sampling, buckets,
+donation, the page tables, spans and ``stats()`` are the engine's; the
+layers' internals are the model's.  ``int8=True`` needs the model's
 ``quantize_for_decode``, ``tp > 1`` its ``tp_decode_step``; a model
 without them is refused with a ``ValueError`` that says so.
 
@@ -186,11 +72,11 @@ from typing import List, Optional
 import numpy as np
 
 from bigdl_tpu import obs
+from bigdl_tpu.serving import spans, steps
 from bigdl_tpu.serving.batcher import RequestQueue, ServeRequest
 from bigdl_tpu.serving.cache import (PagedKVCache, keep_inactive,
                                      write_slot_state)
 from bigdl_tpu.serving.drain import HANDOFF_ERROR
-from bigdl_tpu.serving import spans
 from bigdl_tpu.obs import names
 
 LAT_META = (names.REQUEST_LATENCY_SECONDS,
@@ -250,110 +136,20 @@ def sample_first(logits, temp, key):
     return first[0]
 
 
-class _Active:
-    """Host bookkeeping for one occupied slot.  ``remaining`` counts
-    the tokens not yet DISPATCHED (emission lags one step) and ``left``
-    those not yet EMITTED; a dispatched step is taken to yield one
-    token until it is read, so under a drafting model ``remaining`` is
-    an upper bound between a dispatch and its read, and the two agree
-    whenever nothing is in flight.  ``first_token`` (and a drafting
-    model's ``first_draft``) is the prefill's until the slot's first
-    decode step has taken it from the host, then None: the slot's
-    input is the previous step's output, on the device.  Under a model
-    that generates by blocks nothing is counted at dispatch
-    (``remaining`` is ``left``), ``block`` is the host's view of the
-    slot's current block as of the last step read, and ``first_token``
-    only marks the slot as fresh.  ``unread`` is
-    1 while a step the slot ran in has not been read; ``last_pos`` is
-    the last position the request can ever write a row at."""
-
-    __slots__ = ("req", "remaining", "left", "first_token", "first_draft",
-                 "prompt_len", "last_pos", "unread", "t_admit", "order",
-                 "block")
-
-    def __init__(self, req, remaining, first_token, prompt_len, order,
-                 first_draft=None):
-        self.req = req
-        self.remaining = self.left = remaining
-        self.first_token = first_token
-        self.first_draft = first_draft
-        self.block: Optional[_Block] = None
-        self.prompt_len = prompt_len
-        self.last_pos = prompt_len + remaining
-        self.unread = 0
-        self.t_admit = time.monotonic()
-        self.order = order
-
-
-class _Block:
-    """The host's view of a slot's current block, as of the last step
-    read: its tokens, which positions are still masked, the pass that
-    unmasked each (-1: none yet), and ``shown``, how many of its
-    leading positions are with the request already (emitted, or the
-    prompt's: ``origin`` is the position the request's first generated
-    token has)."""
-
-    __slots__ = ("tokens", "masked", "passes", "shown", "origin")
-
-    def __init__(self, tokens, masked, origin: int):
-        self.tokens = np.array(tokens, np.int32)
-        self.masked = np.array(masked, bool)
-        self.passes = np.full(self.tokens.shape, -1, np.int32)
-        self.shown = int(np.sum(~self.masked))
-        self.origin = origin
-
-    def renew(self):
-        self.tokens[:] = 0
-        self.masked[:] = True
-        self.passes[:] = -1
-        self.shown = 0
-
-
 class _InFlight:
-    """A dispatched decode step whose tokens the host has not read."""
+    """A dispatched decode step whose tokens the host has not read:
+    ``result``, a (B,) device array, the step's tokens (a drafting or
+    block model's: a row of results a slot); ``counts``, an expert
+    model's routing counts or None; ``entries``, the [(slot, _Active)]
+    it ran for; ``context_rows``, the rows of context it reads a slot it
+    ran for (a one-token model's: the others' come back with the
+    step)."""
 
     __slots__ = ("result", "counts", "entries", "context_rows")
 
     def __init__(self, result, counts, entries, context_rows):
-        # (B,) device array, the step's tokens; a drafting model's
-        # (B, len(DRAFT_RESULT)) rows
-        self.result = result
-        self.counts = counts        # an expert model's routing counts
-        self.entries = entries      # [(slot, _Active)] it ran for
-        # the rows of context it reads, a slot it ran for (a one-token
-        # model's: the others' come back with the step)
-        self.context_rows = context_rows
-
-
-#: columns of a drafting step's result, a slot: the two tokens picked,
-#: how many of them the step yields (0 where the slot owed nothing), the
-#: draft it verified and the slot's length before the step
-DRAFT_RESULT = ("first", "second", "emitted", "draft", "length")
-#: what follows the block's tokens and its mask flags in a row of a
-#: block step's result: the slot's length before the step, what the
-#: step did (the model's ``kind``: 0 nothing, then the two below), the
-#: pass index it ran, and whether it wrote a pending tail's final rows
-BLOCK_RESULT = ("length", "kind", "pass", "tail")
-BLOCK_REFINED, BLOCK_FINISHED = 1, 2
-#: ``ServeRequest.unmasked``'s pass for a position that was still masked
-#: when its request ended, and for one that was with the request when a
-#: preemption folded it into the prompt (the state that chose it is gone)
-NEVER_UNMASKED, GIVEN = -1, -2
-
-
-class _StepRead:
-    """A read step on the host: ``tokens`` (B, k), ``emitted`` (B,)
-    tokens each slot yields (None: one each), a drafting model's
-    verified ``drafts`` by slot, a block model's ``blocks`` by slot
-    (mask flags after the step, what the step did, its pass index), and
-    the span's attributes."""
-
-    __slots__ = ("tokens", "emitted", "drafts", "blocks", "attrs")
-
-    def __init__(self, tokens, emitted, drafts, attrs, blocks=None):
-        self.tokens, self.emitted = tokens, emitted
-        self.drafts, self.attrs = drafts, attrs
-        self.blocks = blocks or {}
+        self.result, self.counts = result, counts
+        self.entries, self.context_rows = entries, context_rows
 
 
 class LMEngine:
@@ -394,33 +190,12 @@ class LMEngine:
         # the model states its cache; the engine builds and owns it
         spec = model.cache_spec(self.params)
         self._cache_spec = spec
-        # ... and whether it drafts: tokens a step verifies a slot
-        self._tokens_per_step = int(
-            model.draft_spec(self.params)["tokens_per_step"]
-            if hasattr(model, "draft_spec") else 1)
-        if self._tokens_per_step not in (1, 2):
-            raise ValueError("a step verifies one draft a slot at most")
-        self._drafts = self._tokens_per_step > 1
-        # ... or generates by blocks: positions a step forwards a slot
-        self._block = int(model.block_spec(self.params)["block_length"]
-                          if hasattr(model, "block_spec") else 0)
-        if self._block and (self._drafts or self.int8 or self.tp > 1):
-            raise ValueError("a model that generates by blocks neither "
-                             "drafts nor offers int8 or tp decode")
-        # ... or carries state that is not keys and values, a slot
-        state = model.state_spec(self.params) \
-            if hasattr(model, "state_spec") else None
-        #: the model's step keeps an inactive slot's state itself
-        self._state_guarded = bool(state and state.get("keeps_inactive"))
-        if state and (self._drafts or self._block):
-            raise ValueError("a model whose slots carry state neither "
-                             "drafts nor generates by blocks")
         self.max_len = int(spec["max_len"])
-        if self._block and (self.page_size % self._block
-                            or self.max_len % self._block):
-            raise ValueError(
-                f"blocks of {self._block} do not divide the page size "
-                f"{self.page_size} and the longest context {self.max_len}")
+        # ... and the kind of step it takes, with the state a slot
+        # carries beside its pages (serving/steps.py)
+        kind, state = steps.choose(
+            model, self.params, page_size=self.page_size,
+            max_len=self.max_len, int8=self.int8, tp=self.tp)
         if cache_dtype is None:
             cache_dtype = spec["dtype"]
         pages = num_pages or cfg.num_pages or (
@@ -433,7 +208,15 @@ class LMEngine:
             max_slots=self.max_batch, max_len=self.max_len,
             dtype=cache_dtype, state_spec=state)
         self.queue = RequestQueue(queue_capacity or cfg.queue_capacity)
-        self._slots: List[Optional[_Active]] = [None] * self.max_batch
+        self._slots: List[Optional[steps._Active]] = [None] * self.max_batch
+        #: all that differs between kinds of step.  The picks and the
+        #: cache's writes of a slot's state go in by THIS module's names:
+        #: replaced here (a fault the benchmark's tests inject), they
+        #: are replaced in the programs
+        self._kind = kind(model, spec, self.cache, self.page_size, eos_id,
+                          steps.DeviceOps(sample_step, sample_first,
+                                          pick_greedy, write_slot_state,
+                                          keep_inactive))
         self._stash: collections.deque = collections.deque()
         self._key = jax.random.key(int(seed))
         self._qparams = (model.quantize_for_decode(self.params)
@@ -459,20 +242,8 @@ class LMEngine:
         # the step in flight (dispatched, its tokens unread) and the
         # last dispatched step's tokens, the next step's input
         self._inflight: Optional[_InFlight] = None
-        # what a step hands the next on the device: its tokens; under a
-        # drafting model each slot's next token, next draft, length and
-        # owed count
-        zeros = jnp.zeros((self.max_batch,), jnp.int32)
-        self._carry = (zeros,) * (4 if self._drafts else 1)
-        if self._block:
-            # a block model's: tokens, mask flags, pass count, length,
-            # and the block before it while its final rows are pending
-            wide = jnp.zeros((self.max_batch, self._block), jnp.int32)
-            self._carry = (wide, wide.astype(bool), zeros, zeros, wide,
-                           zeros.astype(bool))
-        self._draft_verified = self._draft_accepted = 0
-        self._block_passes = self._block_tails = 0
-        self._positions_unmasked = 0
+        # what a step hands the next on the device
+        self._carry = self._kind.carry()
         self._slot_steps = self._step_tokens = 0
         self._steps_ahead = 0
         self._steps_sampled = 0
@@ -543,26 +314,6 @@ class LMEngine:
             "weights + the KV pages the step's page-table bucket "
             "names)")
         self._moe_counter = self._moe_gauge = None  # an expert model's
-        self._draft_counter = reg.counter(
-            names.SERVE_DRAFT_TOKENS_TOTAL,
-            "Drafts a self-drafting model's steps verified, by outcome",
-            labels=("outcome",)) if self._drafts else None
-        self._block_counter = reg.counter(
-            names.SERVE_BLOCK_POSITIONS_TOTAL,
-            "Masked positions a block model's refining passes met, by "
-            "outcome", labels=("outcome",)) if self._block else None
-        self._rebuild_counter, self._state_rebuilds = None, 0
-        #: fixed for the engine's life: read on every step
-        self._slot_state_bytes = self.cache.state_bytes_per_slot()
-        if self.cache.state:
-            reg.gauge(
-                names.SERVE_SLOT_STATE_BYTES,
-                "Bytes of state a slot carries beside its pages, over "
-                "all layers").set(float(self.cache.state_bytes_per_slot()))
-            self._rebuild_counter = reg.counter(
-                names.SERVE_STATE_REBUILDS_TOTAL,
-                "Prefills of a preempted request under a model whose "
-                "slots carry state: the state rebuilt from the tokens")
         self._swap_counter = reg.counter(
             names.SERVE_WEIGHT_SWAPS_TOTAL,
             "Live weight hot-swaps completed, by promoted version",
@@ -672,165 +423,20 @@ class LMEngine:
     # -------------------------------------------------------- jit builders
     def _build_step(self):
         import jax
-        import jax.numpy as jnp
 
-        model, page_size = self.model, self.page_size
-        qparams = self._qparams
-        # the cache's buffers: its pools and, behind them, the slots'
-        # state (none unless the model declares one)
-        n, pools = len(self.cache.buffers()), len(self.cache.pools())
-
-        # one name for both programs: the profiler and the readers of its
-        # trace know the decode step as ``jit_step``
-        if self._drafts:
-            def step(params, *rest):
-                # rest: the cache's buffers (donated), then tables, the
-                # host's lengths, the four arrays the last step carried
-                # (token, draft, length, owed), the host's values of the
-                # four for the slots in fresh (admitted since that step),
-                # active
-                (tables, h_len, c_tok, c_draft, c_len, c_owed,
-                 h_tok, h_draft, h_owed, fresh, active) = rest[n:]
-                tok = jnp.where(fresh, h_tok, c_tok)
-                draft = jnp.where(fresh, h_draft, c_draft)
-                length = jnp.where(fresh, h_len, c_len)
-                owed = jnp.where(fresh, h_owed, c_owed)
-                # the host runs a slot while it MAY owe a token; the count
-                # here is exact, and a slot that owes nothing computes a
-                # wasted row at position 0 of its own pages
-                run = active & (owed > 0)
-                caches, picked, accepted, next_draft, counts = \
-                    model.paged_decode(
-                        params, rest[:n], tables, jnp.where(run, length, 0),
-                        tok, draft, owed, run, pick=pick_greedy,
-                        page_size=page_size, qparams=qparams)
-                emitted = jnp.where(run, 1 + accepted.astype(jnp.int32), 0)
-                nxt = jnp.where(accepted, picked[:, 1], picked[:, 0])
-                result = jnp.stack([picked[:, 0], picked[:, 1], emitted,
-                                    draft, length], axis=1)
-                out = (*caches, nxt, next_draft, length + emitted,
-                       owed - emitted, result)
-                return out if counts is None else (*out, counts)
-        elif self._block:
-            def step(params, *rest):
-                # rest: the cache's buffers (donated), then tables, the
-                # host's lengths, the six arrays the last step carried
-                # (block tokens, mask flags, pass count, length, the
-                # tail's tokens, whether a tail is pending), the host's
-                # block for the slots in fresh (admitted since that
-                # step; their pass count is 0 and no tail is pending),
-                # active
-                (tables, h_len, c_tok, c_mask, c_pass, c_len, c_tail, c_pend,
-                 h_tok, h_mask, fresh, active) = rest[n:]
-                tok = jnp.where(fresh[:, None], h_tok, c_tok)
-                mask = jnp.where(fresh[:, None], h_mask, c_mask)
-                done = jnp.where(fresh, 0, c_pass)
-                length = jnp.where(fresh, h_len, c_len)
-                pend = c_pend & ~fresh
-                # an inactive slot computes a wasted block at position 0
-                # of the trash page
-                caches, state, kind, counts = model.paged_decode(
-                    params, rest[:n], tables, jnp.where(active, length, 0),
-                    tok, mask, done, c_tail, pend, active, pick=pick_greedy,
-                    page_size=page_size, qparams=qparams)
-                new_tok, new_mask, new_pass, new_len, new_tail, new_pend = \
-                    state
-                # where the pass left the block final, the host wants
-                # that block (the new tail), not the fresh one behind it
-                final = kind[:, None] == BLOCK_FINISHED
-                result = jnp.concatenate(
-                    [jnp.where(final, new_tail, new_tok),
-                     (new_mask & ~final).astype(jnp.int32),
-                     jnp.stack([length, kind, done,
-                                (pend & active).astype(jnp.int32)], axis=1)],
-                    axis=1)
-                out = (*caches, new_tok, new_mask, new_pass,
-                       jnp.where(active, new_len, length), new_tail,
-                       new_pend, result)
-                return out if counts is None else (*out, counts)
-        else:
-            def step(params, *rest):
-                # rest: the cache's buffers (donated), then tables, lengths,
-                # prev (the last step's tokens, still on the device), the
-                # host's tokens and fresh (the slots that take theirs from
-                # the host: admitted since that step), temps, active, key
-                tables, lengths, prev, tokens, fresh, temps, active, key = \
-                    rest[n:]
-                tokens = jnp.where(fresh, tokens, prev)
-                if n > pools:
-                    # the slots' state goes through the model and comes
-                    # back advanced where the slot ran
-                    caches, logits, counts, state = model.paged_decode(
-                        params, rest[:pools], tables, lengths, tokens,
-                        active, state=rest[pools:n], page_size=page_size,
-                        qparams=qparams)
-                    # ... unless the model's own update leaves a slot
-                    # that did not run as it was (no pass over the state
-                    # to put the old values back)
-                    caches = (*caches, *(
-                        state if self._state_guarded
-                        else keep_inactive(state, rest[pools:n], active)))
-                else:
-                    caches, logits, counts = model.paged_decode(
-                        params, rest[:n], tables, lengths, tokens, active,
-                        page_size=page_size, qparams=qparams)
-                nxt = sample_step(logits, temps, active, key)
-                # the routing counts ride back with the tokens
-                return (*caches, nxt) if counts is None \
-                    else (*caches, nxt, counts)
-
-        return jax.jit(step, donate_argnums=tuple(range(1, 1 + n)))
+        # the kind's body under the one name the profiler and the
+        # readers of its trace know the decode step by: ``jit_step``
+        return jax.jit(self._kind.step(self._qparams), donate_argnums=tuple(
+            range(1, 1 + len(self.cache.buffers()))))
 
     def _prefill_fn(self, bucket: int):
         fn = self._prefill_fns.get(bucket)
-        if fn is not None:
-            return fn
-        import jax
+        if fn is None:
+            import jax
 
-        model = self.model
-        n, pools = len(self.cache.buffers()), len(self.cache.pools())
-
-        if self._drafts:
-            def prefill(params, *rest):
-                # as below; greedy, so the temperature and the key are not
-                # read, and the first token comes with the first draft
-                prompt, t0, pages = rest[n:n + 3]
-                caches, first, draft, counts = model.paged_prefill(
-                    params, rest[:n], prompt, t0, pages, pick=pick_greedy)
-                pair = jax.numpy.stack([first, draft])
-                return (*caches, pair) if counts is None \
-                    else (*caches, pair, counts)
-        elif self._block:
-            def prefill(params, *rest):
-                # as below; no token is picked: the prompt's whole blocks
-                # are cached, and the first block's state comes back
-                prompt, t0, pages = rest[n:n + 3]
-                caches, (tokens, masked), counts = model.paged_prefill(
-                    params, rest[:n], prompt, t0, pages, pick=pick_greedy)
-                first = jax.numpy.stack(
-                    [tokens, masked.astype(tokens.dtype)])
-                return (*caches, first) if counts is None \
-                    else (*caches, first, counts)
-        else:
-            def prefill(params, *rest):
-                # rest: the cache's buffers (donated), then the prompt
-                # (1, bucket) zero-padded past t0, t0, the bucket's pages,
-                # the temperature, the key; with state, the slot it is for
-                prompt, t0, pages, temp, key = rest[n:n + 5]
-                if n > pools:
-                    caches, logits, counts, rows = model.paged_prefill(
-                        params, rest[:pools], prompt, t0, pages)
-                    caches = (*caches, *write_slot_state(
-                        rest[pools:n], rest[n + 5], rows))
-                else:
-                    caches, logits, counts = model.paged_prefill(
-                        params, rest[:n], prompt, t0, pages)
-                first = sample_first(logits, temp, key)
-                return (*caches, first) if counts is None \
-                    else (*caches, first, counts)
-
-        fn = jax.jit(prefill, donate_argnums=tuple(range(1, 1 + n)))
-        self._prefill_fns[bucket] = fn
+            fn = self._prefill_fns[bucket] = jax.jit(
+                self._kind.prefill(), donate_argnums=tuple(
+                    range(1, 1 + len(self.cache.buffers()))))
         return fn
 
     def _bucket(self, t0: int) -> int:
@@ -855,16 +461,7 @@ class LMEngine:
                 f"exceeds max_len {self.max_len}")
         if int(max_new_tokens) < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        if self._drafts and float(temperature) > 0.0:
-            raise ValueError(
-                f"{type(self.model).__name__} verifies its own drafts by "
-                "exact match against the greedy token: temperature "
-                f"{temperature:g} is not served (give 0)")
-        if self._block and float(temperature) > 0.0:
-            raise ValueError(
-                f"{type(self.model).__name__} unmasks a block's positions "
-                "by the confidence of the greedy token: temperature "
-                f"{temperature:g} is not served (give 0)")
+        self._kind.refuse(temperature)
         # feasibility: a request that can NEVER fit the page pool even
         # alone would preempt-loop forever — reject it at the door
         worst = self.cache.pages_for(len(prompt) + int(max_new_tokens))
@@ -950,21 +547,11 @@ class LMEngine:
         tracer = self._tracer
         n = len(self.cache.buffers())
         step = self._steps
-        # a model whose slots carry state: the slot the prefill writes
-        extra = (np.int32(slot),) if self.cache.state else ()
+        self._order += 1
         with tracer.span(spans.SPAN_STEP_PREFILL, step=step,
                          bucket=bucket, prompt_len=t0,
                          request=req.id) as span_id:
-            if extra:
-                tracer.add_attrs(
-                    span_id,
-                    state_bytes=self.cache.state_bytes_per_slot())
-                if req.preempted:
-                    # its whole past is computed again: the state is
-                    # rebuilt from the tokens, not restored
-                    tracer.add_attrs(span_id, rebuilt=1)
-                    self._state_rebuilds += 1
-                    self._rebuild_counter.inc()
+            extra = self._kind.begin_prefill(slot, req, tracer, span_id)
             with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
                              program="prefill") as dispatch_id:
                 self._note_dry(tracer, dispatch_id)
@@ -981,12 +568,9 @@ class LMEngine:
                 first = np.asarray(out[n])
             with tracer.span(spans.SPAN_STEP_READ, step=step,
                              program="prefill"):
-                # a drafting model's first token comes with its first
-                # draft; a block model's prefill yields its first block
-                # and no token
-                tok, draft = (int(t) for t in first) if self._drafts \
-                    else (None, None) if self._block \
-                    else (int(first), None)
+                # what the first result means is the kind's: the slot's
+                # bookkeeping, and the token to emit now or none yet
+                act, tok = self._kind.admit(slot, req, first, self._order)
                 if len(out) > n + 1:
                     tracer.add_attrs(span_id,
                                      **self._note_routing(out[n + 1]))
@@ -996,25 +580,12 @@ class LMEngine:
                  "bucket": bucket, "prompt_len": t0, "slot": slot})
         if self._t_first_work is None:
             self._t_first_work = time.monotonic()
-        self._order += 1
-        if self._block:
-            # the slot's length is its block's first position; every
-            # generated position so far is on record (a preemption
-            # folded them into the prompt)
-            b = self._block
-            self.cache.lengths[slot] = t0 - t0 % b
-            act = _Active(req, req.max_new_tokens, -1, t0, self._order)
-            act.last_pos = -(-(t0 + req.max_new_tokens) // b) * b - 1
-            act.block = _Block(first[0], first[1],
-                               origin=t0 - len(req.unmasked))
-        else:
+        if tok is not None:
             self._first_token(req)
             req.tokens.append(tok)
             req.token_times.append(time.perf_counter())
             self._tokens_total += 1
             self._tokens_counter.inc()
-            act = _Active(req, req.max_new_tokens - 1, tok, t0, self._order,
-                          first_draft=draft)
         self._slots[slot] = act
         tracer.event(spans.EVENT_ADMIT, slot=slot, request=req.id,
                      prompt_len=t0, bucket=bucket)
@@ -1049,10 +620,7 @@ class LMEngine:
         req.payload = list(req.payload) + [
             int(t) for t in req.tokens[len(req.tokens) - gen:]]
         req.max_new_tokens = act.remaining
-        if act.block is not None:
-            # what the block has shown is the request's; the rest of it
-            # is generated again
-            self._record_block(act, slot, GIVEN)
+        self._kind.record(slot, act, preempted=True)
         self.cache.release(slot)
         self._slots[slot] = None
         self._stash.appendleft(req)
@@ -1067,8 +635,7 @@ class LMEngine:
     # ---------------------------------------------------------------- step
     def _complete(self, slot: int, error: Optional[str] = None):
         act = self._slots[slot]
-        if act.block is not None:
-            self._record_block(act, slot)
+        self._kind.record(slot, act)
         self.cache.release(slot)
         self._slots[slot] = None
         req = act.req
@@ -1184,42 +751,21 @@ class LMEngine:
             running = [i for i in range(self.max_batch) if self._runs(i)]
             if not running:
                 return False
-            tokens = np.zeros((self.max_batch,), np.int32)
-            fresh = np.zeros((self.max_batch,), bool)
-            temps = np.zeros((self.max_batch,), np.float32)
-            active = np.zeros((self.max_batch,), bool)
-            drafts = np.zeros((self.max_batch,), np.int32)
-            owed = np.zeros((self.max_batch,), np.int32)
-            if self._block:
-                tokens = np.zeros((self.max_batch, self._block), np.int32)
-                masked = np.zeros((self.max_batch, self._block), bool)
-            for i in running:
-                act = self._slots[i]
-                if act.first_token is not None:
-                    # admitted since the last step: its input is the
-                    # prefill's token (a block model's: its first
-                    # block), from the host, this once
-                    fresh[i] = True
-                    if self._block:
-                        tokens[i], masked[i] = (act.block.tokens,
-                                                act.block.masked)
-                    else:
-                        tokens[i] = act.first_token
-                    if self._drafts:
-                        drafts[i], owed[i] = act.first_draft, act.left
-                    act.first_token = act.first_draft = None
-                temps[i] = act.req.temperature
-                active[i] = True
+            acts = [(i, self._slots[i]) for i in running]
+            self._key, sub = jax.random.split(self._key)
+            # the step's arguments behind the carry, as host arrays: a
+            # slot admitted since the last step takes its input from
+            # the host, this once
+            host = self._kind.host_args(acts, sub)
             longest = max(int(self.cache.lengths[i]) + self._ahead(i)
                           for i in running)
             bucket = used_page_bucket(longest, self.page_size,
                                       self.cache.max_pages_per_slot)
             self._last_bucket = bucket
             tables, lengths = self.cache.device_tables(pages=bucket)
-            self._key, sub = jax.random.split(self._key)
             # running slots that sample: 0 means the step's pick takes
             # its greedy arm (``sample_step``)
-            sampling = int(np.count_nonzero(temps > 0.0))
+            sampling = sum(act.req.temperature > 0.0 for _, act in acts)
             tracer.add_attrs(span_id, bucket=bucket, active=len(running))
         t0 = time.perf_counter()
         # a LIVE span (not a retroactive reqtrace hop).  It covers this
@@ -1234,47 +780,36 @@ class LMEngine:
             with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
                              program="step") as dispatch_id:
                 self._note_dry(tracer, dispatch_id)
-                if self._drafts:
-                    host = (jnp.asarray(tokens), jnp.asarray(drafts),
-                            jnp.asarray(owed), jnp.asarray(fresh),
-                            jnp.asarray(active))
-                elif self._block:
-                    host = (jnp.asarray(tokens), jnp.asarray(masked),
-                            jnp.asarray(fresh), jnp.asarray(active))
-                else:
-                    host = (jnp.asarray(tokens), jnp.asarray(fresh),
-                            jnp.asarray(temps), jnp.asarray(active), sub)
+                # (a key among them is the device's already)
+                host = [jnp.asarray(a) if isinstance(a, np.ndarray) else a
+                        for a in host]
                 out = self._step_fn(
                     self.params, *self.cache.buffers(), tables, lengths,
                     *self._carry, *host)
                 self.cache.set_buffers(out[:n])
                 k = len(self._carry)
                 self._carry = out[n:n + k]
-                # what the host reads: the tokens (a one-token model's
-                # are the carry itself), and an expert model's counts
-                result = out[n + k:] if self._drafts or self._block \
-                    else out[n:]
+                # what the host reads: the tokens or result rows, and an
+                # expert model's counts
+                result = out[n + self._kind.result_at:]
                 for arr in result:
                     # on their way to the host as soon as they exist,
                     # not when the next pump asks for them
                     arr.copy_to_host_async()
-                # the host's state advances at dispatch, by the one
-                # token a step yields at least: the next prep (growth,
-                # bucket, who runs) needs no token.  (A block's step may
-                # yield none: that host's state advances when the step
+                # the host's state advances at dispatch, by the tokens
+                # a step is sure to yield: the next prep (growth,
+                # bucket, who runs) needs no token.  (Where a step may
+                # yield none, the host's state advances when the step
                 # is read.)
-                entries, context = [], []
-                sure = 0 if self._block else 1
-                for i in running:
-                    act = self._slots[i]
+                context, sure = [], self._kind.sure
+                for i, act in acts:
                     self.cache.lengths[i] += sure
                     context.append(int(self.cache.lengths[i]))
                     act.remaining -= sure
                     act.unread += 1
-                    entries.append((i, act))
                 self._inflight = _InFlight(
                     result[0], result[1] if len(result) > 1 else None,
-                    entries, context)
+                    acts, context)
             if prev is not None:
                 read = self._read(prev, tracer, step)
                 # what the step just read routed and yielded, and the
@@ -1297,7 +832,7 @@ class LMEngine:
                     self.max_batch, heads,
                     self.cache.row_width // kv_heads, self.page_size,
                     bucket, kv_item, kv_heads=kv_heads,
-                    positions=2 * self._block or 1)
+                    positions=self._kind.positions)
             self._decode_bytes_gauge.set(step_bytes / len(running))
             self._occ_sum += len(running) / self.max_batch
             self._occ_gauge.set(self._occ_sum / self._steps)
@@ -1318,18 +853,9 @@ class LMEngine:
 
     def _ahead(self, slot: int) -> int:
         """How far past its (lower-bound) length the slot's next step
-        may write: 0 for a one-token model; a drafting model's step
-        writes a second row, and a step not yet read may have taken its
-        draft; a block's step writes its block's rows, and every step
-        not yet read may have finished a block and moved on to the
-        next.  Never past the request's last position."""
-        if not (self._drafts or self._block):
-            return 0
-        act = self._slots[slot]
-        reach = self._block * (1 + act.unread) - 1 if self._block \
-            else 1 + act.unread
-        return max(0, min(reach,
-                          act.last_pos - int(self.cache.lengths[slot])))
+        may write."""
+        return self._kind.ahead(self._slots[slot],
+                                int(self.cache.lengths[slot]))
 
     def _note_dry(self, tracer, dispatch_id):
         """Say on an open ``serve.dispatch`` whether the chip had run
@@ -1341,158 +867,16 @@ class LMEngine:
             tracer.add_attrs(dispatch_id, dry=int(
                 rec is None or bool(rec.result.is_ready())))
 
-    def _read(self, rec: _InFlight, tracer, step: int) -> _StepRead:
+    def _read(self, rec: _InFlight, tracer, step: int) -> steps._StepRead:
         """Wait for a dispatched step's tokens (``serve.wait``: the one
         blocking read), then take them apart (``serve.read``)."""
         with tracer.span(spans.SPAN_STEP_WAIT, step=step, program="step"):
             res = np.asarray(rec.result)
         with tracer.span(spans.SPAN_STEP_READ, step=step, program="step"):
-            if self._block:
-                return self._read_block(rec, res)
-            return self._read_tokens(rec, res)
+            return self._kind.read(rec, res, self._slots,
+                                   self._note_routing)
 
-    def _read_tokens(self, rec: _InFlight, res) -> _StepRead:
-        """A one-token or drafting model's read.  With the tokens come
-        an expert model's routing counts and a drafting model's emitted
-        counts: returned as span attributes, beside the rows of context
-        that step had to read."""
-        attrs, drafts = {}, {}
-        if self._drafts:
-            first, second, emitted, draft, length = res.T  # DRAFT_RESULT
-            toks = np.stack([first, second], axis=1)
-            accepted = tokens = 0
-            context = []
-            for slot, act in rec.entries:
-                if self._slots[slot] is not act or not emitted[slot]:
-                    continue    # completed since, or owed nothing there
-                # a draft counts as verified where the slot owed the
-                # token it drafts (the device's owed count is the
-                # host's ``left`` once every earlier step is emitted,
-                # as here)
-                if act.left >= 2:
-                    drafts[slot] = int(draft[slot])
-                accepted += int(emitted[slot] == 2)
-                tokens += int(emitted[slot])
-                # the rows the step had to read, once a slot: up to
-                # its second query's position
-                context.append(int(length[slot]) + 2)
-            attrs.update(draft_verified=len(drafts),
-                         draft_accepted=accepted, tokens_emitted=tokens)
-            self._draft_verified += len(drafts)
-            self._draft_accepted += accepted
-            self._draft_counter.labels(outcome="accepted").inc(accepted)
-            self._draft_counter.labels(outcome="rejected").inc(
-                len(drafts) - accepted)
-        else:
-            toks, emitted, context = res[:, None], None, rec.context_rows
-        if rec.counts is not None:
-            attrs.update(self._note_routing(rec.counts))
-        if rec.counts is not None or self.cache.state:
-            attrs.update(self._context_attrs(context))
-        if self.cache.state:
-            # what the step read and wrote of the slots' state: in and
-            # out, for the slots that ran
-            attrs["state_bytes"] = (2 * len(rec.entries)
-                                    * self._slot_state_bytes)
-        return _StepRead(toks, emitted, drafts, attrs)
-
-    def _context_attrs(self, rows) -> dict:
-        """What a step's attention had to read and what it copied:
-        ``context_tokens``, the sum of ``rows`` (the rows of context a
-        slot the step ran for, its own positions included), and, under
-        a model whose decode attention is a page-walking kernel
-        (``cache_spec``'s ``attn_query_rows``), ``attn_rows_copied``:
-        the rows one call of that kernel copies a pool for those
-        slots."""
-        attrs = {"context_tokens": sum(rows)}
-        query_rows = self._cache_spec.get("attn_query_rows")
-        if query_rows:
-            from bigdl_tpu.ops.decode_attention import stream_rows_copied
-
-            attrs["attn_rows_copied"] = stream_rows_copied(
-                np.asarray(rows, np.int64) - 1, self.page_size,
-                self.cache.max_pages_per_slot, self.cache.row_width,
-                self.cache.dtype.itemsize, query_rows)
-        return attrs
-
-    def _read_block(self, rec: _InFlight, res) -> _StepRead:
-        """A block model's read: what each live slot's step did, how
-        many positions it unmasked and how many tokens that shows (the
-        unmasked prefix beyond what is shown already, up to the
-        request's last token or an EOS)."""
-        b = self._block
-        toks, after = res[:, :b], res[:, b:2 * b].astype(bool)
-        length, kind, done, tail = res[:, 2 * b:].T    # BLOCK_RESULT
-        emitted = np.zeros((self.max_batch,), np.int32)
-        blocks = {}
-        passes = tails = unmasked = left = 0
-        context = []
-        for slot, act in rec.entries:
-            if self._slots[slot] is not act or not kind[slot]:
-                continue    # completed since: a wasted block
-            blocks[slot] = (after[slot], int(kind[slot]), int(done[slot]))
-            context.append(int(length[slot]) + b)
-            passes += 1
-            tails += int(tail[slot])
-            unmasked += int(np.sum(act.block.masked & ~after[slot]))
-            left += int(np.sum(after[slot]))
-            shown = act.block.shown
-            prefix = b if not after[slot].any() \
-                else int(np.argmax(after[slot]))
-            n = max(0, min(prefix - shown, act.left))
-            for j in range(n):
-                if int(toks[slot, shown + j]) == self.eos_id:
-                    n = j + 1
-                    break
-            emitted[slot] = n
-        self._block_passes += passes
-        self._block_tails += tails
-        self._positions_unmasked += unmasked
-        self._block_counter.labels(outcome="unmasked").inc(unmasked)
-        self._block_counter.labels(outcome="left_masked").inc(left)
-        # (no forward of a slot only commits a block: the count stays
-        # for the readers that add it to the passes)
-        attrs = dict(block_passes=passes, block_tails=tails,
-                     block_commits=0,
-                     positions_unmasked=unmasked,
-                     tokens_emitted=int(emitted.sum()))
-        if rec.counts is not None:
-            attrs.update(self._note_routing(rec.counts),
-                         **self._context_attrs(context))
-        return _StepRead(toks, emitted, {}, attrs, blocks)
-
-    def _advance_block(self, slot: int, act: _Active, read: _StepRead):
-        """Bring the host's view of ``slot``'s block up to the step
-        read; returns the block position its shown tokens start at."""
-        after, _, done = read.blocks[slot]
-        blk = act.block
-        newly = blk.masked & ~after
-        blk.tokens[newly] = read.tokens[slot][newly]
-        blk.passes[newly] = done
-        blk.masked = after.copy()
-        start = blk.shown
-        blk.shown += int(read.emitted[slot])
-        return start
-
-    def _record_block(self, act: _Active, slot: int, given=None):
-        """Put the block's generated positions that are not on record
-        yet into ``ServeRequest.unmasked``: all of them, or with
-        ``given`` (a preemption) only those already shown, marked
-        so."""
-        blk, req = act.block, act.req
-        at = int(self.cache.lengths[slot]) - blk.origin
-        for i in range(blk.shown if given is not None else self._block):
-            if at + i < len(req.unmasked):
-                continue    # the prompt's, or recorded before
-            if given is not None:
-                req.unmasked.append((int(blk.tokens[i]), given))
-            elif blk.masked[i]:
-                req.unmasked.append((0, NEVER_UNMASKED))
-            else:
-                req.unmasked.append((int(blk.tokens[i]),
-                                     int(blk.passes[i])))
-
-    def _emit(self, rec: _InFlight, read: _StepRead):
+    def _emit(self, rec: _InFlight, read: steps._StepRead):
         """Hand a read step's tokens to their requests, and bring the
         host's bounds up to what the step turned out to yield."""
         for slot, act in rec.entries:
@@ -1501,21 +885,9 @@ class LMEngine:
                 # completed on an EOS after this step was dispatched:
                 # the row was wasted, its token is no one's
                 continue
-            n = 1 if read.emitted is None else int(read.emitted[slot])
-            start = 0
-            if slot in read.blocks:
-                start = self._advance_block(slot, act, read)
-            if n:       # 0: owed nothing on the device, a wasted row
+            n, start = self._kind.yielded(slot, act, read)
+            if n:       # 0: a wasted row, or no position final yet
                 req = act.req
-                if slot in read.drafts:
-                    req.drafts.append((len(req.tokens), read.drafts[slot]))
-                # the dispatch counted one token; the step may have
-                # yielded another (a block's step was counted for none)
-                if self._block:
-                    act.remaining -= n
-                else:
-                    self.cache.lengths[slot] += n - 1
-                    act.remaining -= n - 1
                 self._slot_steps += 1
                 self._first_token(req)
                 for j in range(n):
@@ -1529,14 +901,8 @@ class LMEngine:
                     if act.left <= 0 or tok == self.eos_id:
                         self._complete(slot)
                         break
-            if slot in read.blocks and self._slots[slot] is act \
-                    and read.blocks[slot][1] == BLOCK_FINISHED:
-                # the pass left the block final and its request goes
-                # on: on record, the length advances (the device's did
-                # in that step), a new block
-                self._record_block(act, slot)
-                self.cache.lengths[slot] += self._block
-                act.block.renew()
+            if self._slots[slot] is act:
+                self._kind.after_emit(slot, act, read)
 
     def _settle(self, reason: str) -> bool:
         """Read and emit the step in flight, outside the pipelined loop:
@@ -1624,7 +990,6 @@ class LMEngine:
 
     # ------------------------------------------------------------- stats
     def stats(self) -> dict:
-        passes = self._block_passes
         e2e = [c["e2e_s"] for c in self.completed]
         ttft = [c["ttft_s"] for c in self.completed
                 if c["ttft_s"] is not None]
@@ -1649,25 +1014,11 @@ class LMEngine:
             "greedy_step_share": (1.0 - self._steps_sampled / self._steps
                                   if self._steps else None),
             # tokens a slot and step that yielded any (1 unless the
-            # model drafts), and the share of verified drafts accepted
+            # model drafts or generates by blocks)
             "tokens_per_step": (self._step_tokens / self._slot_steps
                                 if self._slot_steps else None),
-            "drafts_verified": self._draft_verified,
-            "drafts_accepted": self._draft_accepted,
-            "draft_accept_share": (
-                self._draft_accepted / self._draft_verified
-                if self._draft_verified else None),
-            # a block model's: forwards of a slot (each a refining
-            # pass), those that also wrote a pending tail's final rows,
-            # those that only committed a block (none: the tail rides a
-            # pass), tokens a forward, the tails' share of the forwards
-            "block_passes": passes,
-            "block_tails": self._block_tails,
-            "block_commits": 0,
-            "positions_unmasked": self._positions_unmasked,
-            "tokens_per_forward": (self._step_tokens / passes
-                                   if passes else None),
-            "tail_share": (self._block_tails / passes if passes else None),
+            # the kind's tallies: every kind reports every key
+            **self._kind.stats(self._step_tokens),
             "settles": dict(self._settles),
             "busy_s": busy,
             "tokens_per_s": (self._tokens_total / busy
@@ -1680,7 +1031,7 @@ class LMEngine:
             # what a slot carries beside its pages (0: nothing)
             "state_bytes_per_slot": self.cache.state_bytes_per_slot(),
             # prefills that rebuilt a preempted request's state
-            "state_rebuilds": self._state_rebuilds,
+            "state_rebuilds": self._kind.state_rebuilds,
             "draining": self.draining,
             "weight_version": self.weight_version,
             "manifest_sha": self.manifest_sha,

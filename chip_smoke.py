@@ -290,7 +290,7 @@ def _serve_latent_expert_model(platform) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from bigdl_tpu.models import longcat_flash_reference as ref
+    from benchmarks.reference import longcat_flash_chat as ref
     from bigdl_tpu.models.longcat_flash import build_longcat_flash
     from bigdl_tpu.serving import LMEngine
 

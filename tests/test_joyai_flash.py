@@ -2,7 +2,7 @@
 layer, sigmoid-routed experts with a shared expert, a prediction layer),
 the two-position decode step and the engine's loop when a step yields
 one or two tokens, each against the plain float32 reference
-(``bigdl_tpu/models/joyai_flash_reference.py``, the repo's own copy of
+(``benchmarks/reference/joyai_llm_flash.py``, the repo's own copy of
 ``benchmarks/reference/joyai_llm_flash.py``).
 
 A small size with every ratio of the published one kept: a dense layer
@@ -28,15 +28,14 @@ layer's ``W_eh`` maps the next token ``x`` to ``pi(x)``, so its draft
 """
 
 import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.reference import joyai_llm_flash as ref
 from bigdl_tpu import obs
-from bigdl_tpu.models import joyai_flash_reference as ref
 from bigdl_tpu.models.joyai_flash import JoyAIFlash, build_joyai_flash
 from bigdl_tpu.nn.experts import DroplessExperts
 from bigdl_tpu.nn.latent import LatentAttention, gated_mlp
@@ -107,15 +106,6 @@ def test_bfloat16_matrices_fail_the_float32_tolerance():
         if a.ndim >= 2 else a, params)
     got, _ = model.apply(low, {}, jnp.asarray(toks)[None])
     assert float(jnp.max(jnp.abs(got[0] - want))) > 10 * F32_TOL
-
-
-def test_the_two_reference_copies_are_one_text():
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "reference",
-                           "joyai_llm_flash.py"), encoding="utf-8") as fh:
-        bench = fh.read()
-    with open(ref.__file__, encoding="utf-8") as fh:
-        assert fh.read() == bench
 
 
 def test_a_model_given_params_draws_no_weights_and_builds_from_a_config(

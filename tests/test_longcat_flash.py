@@ -1,6 +1,6 @@
 """LongCat-Flash behind ``LMEngine``: the model, its latent paged
 cache, the dropless expert layer and the chip's share, each against the
-plain float32 reference (``bigdl_tpu/models/longcat_flash_reference.py``,
+plain float32 reference (``benchmarks/reference/longcat_flash_chat.py``,
 the repo's own copy of ``benchmarks/reference/longcat_flash_chat.py``).
 
 A small size with every ratio of the published one kept: two double
@@ -22,15 +22,14 @@ experts, top-4.  Tolerances, each with its reason:
 
 import importlib.util
 import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.reference import longcat_flash_chat as ref
 from bigdl_tpu import obs
-from bigdl_tpu.models import longcat_flash_reference as ref
 from bigdl_tpu.models.longcat_flash import LongCatFlash
 from bigdl_tpu.nn.experts import COUNT_NAMES, DroplessExperts, counts_dict
 from bigdl_tpu.nn.latent import (GatedMLP, LatentAttention, RMSNorm,
@@ -86,31 +85,6 @@ def test_full_forward_equals_the_reference(held, seed, length):
     assert float(jnp.max(jnp.abs(want))) > 0.5     # not a trivial model
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want),
                                atol=F32_TOL, rtol=0)
-
-
-def test_the_two_reference_copies_agree_on_seeded_weights():
-    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                        "reference", "longcat_flash_chat.py")
-    spec = importlib.util.spec_from_file_location("bench_longcat_ref", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    cfg = dict(SMALL, held_experts=[4, 8], max_len=MAX_LEN,
-               initializer_range=0.1)
-    assert bench.sizes_of(cfg) == ref.sizes_of(cfg)
-    sizes = ref.sizes_of(cfg)
-    pa, pb = ref.init_params(11, sizes, jnp.float32), \
-        bench.init_params(11, sizes, jnp.float32)
-    for a, b in zip(jax.tree.leaves(pa), jax.tree.leaves(pb)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    toks = tokens_of(19, 3)
-    for prec in ("float32", "int8"):
-        np.testing.assert_array_equal(
-            np.asarray(ref.forward_logits(pa, sizes, toks, prec)),
-            np.asarray(bench.forward_logits(pb, sizes, toks, prec)))
-    ga, fa = ref.served_gaps(pa, sizes, toks[:7], toks[7:])
-    gb, fb = bench.served_gaps(pb, sizes, toks[:7], toks[7:])
-    np.testing.assert_array_equal(ga, gb)
-    np.testing.assert_array_equal(fa, fb)
 
 
 def test_a_model_given_params_draws_no_weights(monkeypatch):

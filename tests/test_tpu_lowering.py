@@ -323,6 +323,28 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
               f"{buffer_bytes / 1e6:.1f} MB")
 
 
+
+def _engine_of_shapes(probe, weights, page, max_len, pools, state=()):
+    """The engine's own builders (``LMEngine._build_step``,
+    ``_prefill_fn``) over shapes in place of an engine: the kind of step
+    ``probe`` declares (``serving/steps.py``), over a cache that holds
+    the buffers' specs and nothing else."""
+    import types
+
+    from bigdl_tpu.serving import engine, steps
+
+    cache = types.SimpleNamespace(
+        buffers=lambda: pools + state, pools=lambda: pools, state=state,
+        state_bytes_per_slot=lambda: 0)
+    kind, _ = steps.choose(probe, weights, page_size=page, max_len=max_len)
+    ops = steps.DeviceOps(engine.sample_step, engine.sample_first,
+                          engine.pick_greedy, engine.write_slot_state,
+                          engine.keep_inactive)
+    return types.SimpleNamespace(
+        _kind=kind(probe, probe.cache_spec(weights), cache, page, None, ops),
+        _qparams=None, cache=cache, _prefill_fns={})
+
+
 def _kernel_calls(text: str, kernel: str) -> int:
     """How often a lowered program runs the Mosaic kernel ``kernel``:
     the kernel's jitted program is one private function of the module
@@ -374,9 +396,8 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     on.  That is the evidence the padding was chosen by; run it
     before spending chip minutes on the latent cache."""
     import functools
-    import types
 
-    from bigdl_tpu.models import longcat_flash_reference as ref
+    from benchmarks.reference import longcat_flash_chat as ref
     from bigdl_tpu.models.longcat_flash import LongCatFlash, PUBLISHED
     from bigdl_tpu.serving.engine import LMEngine
 
@@ -404,12 +425,7 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     cs = probe.cache_spec(weights)
     assert cs["row_width"] == (640 if row_align == 128 else 576)
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
-    # the engine's own builders, given shapes in place of an engine
-    eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=0, cache=types.SimpleNamespace(buffers=lambda: (buf,),
-                                    pools=lambda: (buf,)),
-        _prefill_fns={})
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf,))
     key = spec((), jax.random.key(0).dtype)
     b = slots
     programs = {
@@ -503,9 +519,8 @@ def test_draft_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     rows written a slot and two queries a slot on the head axis, and
     still no instruction of the whole cache's size but the scatters."""
     import functools
-    import types
 
-    from bigdl_tpu.models import joyai_flash_reference as ref
+    from benchmarks.reference import joyai_llm_flash as ref
     from bigdl_tpu.models.joyai_flash import JoyAIFlash, PUBLISHED
     from bigdl_tpu.serving.engine import LMEngine
 
@@ -529,11 +544,7 @@ def test_draft_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     cs = probe.cache_spec(weights)
     assert (cs["row_width"], cs["layers"]) == (640, 3)
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
-    eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None, _drafts=True,
-        cache=types.SimpleNamespace(buffers=lambda: (buf,),
-                                    pools=lambda: (buf,)),
-        _prefill_fns={})
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf,))
     key = spec((), jax.random.key(0).dtype)
     ints = spec((slots,), jnp.int32)
     flags = spec((slots,), jnp.bool_)
@@ -573,9 +584,8 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     step's temporaries are the head's float32 logits and little else
     (the gather body's gathered pages and score plane are gone)."""
     import functools
-    import types
 
-    from bigdl_tpu.models import sdar_moe_reference as ref
+    from benchmarks.reference import sdar_30b_a3b_chat as ref
     from bigdl_tpu.models.sdar_moe import PUBLISHED, SDARMoE
     from bigdl_tpu.serving.engine import LMEngine
 
@@ -598,11 +608,7 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     assert (cs["row_width"], cs["kv_heads"], cs["heads"], cs["buffers"]) \
         == (512, 4, 32, 2)
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
-    eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=block, cache=types.SimpleNamespace(
-            buffers=lambda: (buf, buf), pools=lambda: (buf, buf)),
-        _prefill_fns={})
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf, buf))
     key = spec((), jax.random.key(0).dtype)
     ints = spec((slots,), jnp.int32)
     flags = spec((slots,), jnp.bool_)
@@ -687,9 +693,8 @@ def test_state_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     the scatters, and temporaries that are the head's float32 logits
     and little else."""
     import functools
-    import types
 
-    from bigdl_tpu.models import zaya_reference as ref
+    from benchmarks.reference import zaya1_8b as ref
     from bigdl_tpu.models.zaya import PUBLISHED, Zaya
     from bigdl_tpu.serving.engine import LMEngine
 
@@ -716,11 +721,8 @@ def test_state_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
     state = tuple(spec((ss["layers"], slots) + shp, dt)
                   for shp in ss["shapes"])
-    eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=0, _state_guarded=False, cache=types.SimpleNamespace(
-            buffers=lambda: (buf, buf) + state, pools=lambda: (buf, buf)),
-        _prefill_fns={})
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf, buf),
+                            state=state)
     key = spec((), jax.random.key(0).dtype)
     ints = spec((slots,), jnp.int32)
     flags = spec((slots,), jnp.bool_)
@@ -790,7 +792,6 @@ def _hybrid_programs(one_chip, layers, slots=128):
     lowered from shapes alone for the described chip; with them the
     pools' and the states' specs."""
     import functools
-    import types
 
     from benchmarks.reference import falcon_h1_34b as ref
     from bigdl_tpu.models.falcon_h1 import PUBLISHED, FalconH1
@@ -816,11 +817,8 @@ def _hybrid_programs(one_chip, layers, slots=128):
     buf = spec((cs["layers"], pages, page, cs["row_width"]), dt)
     state = tuple(spec((ss["layers"], slots) + shp, ss["dtype"])
                   for shp in ss["shapes"])
-    eng = types.SimpleNamespace(
-        model=probe, page_size=page, _qparams=None, _drafts=False,
-        _block=0, _state_guarded=True, cache=types.SimpleNamespace(
-            buffers=lambda: (buf, buf) + state, pools=lambda: (buf, buf)),
-        _prefill_fns={})
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf, buf),
+                            state=state)
     key = spec((), jax.random.key(0).dtype)
     ints = spec((slots,), jnp.int32)
     flags = spec((slots,), jnp.bool_)
@@ -885,13 +883,18 @@ def test_hybrid_engine_programs_work_on_cache_and_state_as_they_lie(
 # that call ``pick_greedy`` themselves kept PR 38's.  PR 41 meant to
 # change ONE: SDAR's block step (a pending tail's rows beside the
 # block's; its prefill kept PR 38's hash); ZAYA1's step, which runs the
-# same grouped kernel with one length a slot, kept its own.
+# same grouped kernel with one length a slot, kept its own.  PR 42
+# meant to change NONE: it moved the bodies of ``step`` and ``prefill``
+# from ``serving/engine.py`` into ``serving/steps.py`` and first pinned
+# the sixth model, Falcon-H1 (the one whose ``state_spec`` says
+# ``keeps_inactive``), with the hashes PR 41's tree gives.
 LOWERED = {
     "tiny_gpt": ("ecfcedf2071ee787", "46c449c19d770f24"),
     "tiny_longcat": ("8915adb05bc5a3eb", "1d9aa96cdb0e528a"),
     "tiny_joyai": ("1f52c73cb02fe133", "cc5edd3f0a3bdc24"),
     "tiny_sdar": ("6dd9578b515609a5", "97b140ade177e8e2"),
     "tiny_zaya": ("792116b44675ebac", "35e5c200d9a20b14"),
+    "tiny_falcon_h1": ("dd5e4933b5bc20de", "f0c7c89cc405a2ea"),
 }
 
 
@@ -942,14 +945,8 @@ def test_the_other_serving_models_programs_lower_unchanged(
     ints, flags = jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool)
     if program == "step":
         tables, lengths = eng.cache.device_tables(pages=2)
-        if eng._drafts:
-            host = (ints, ints, ints, flags, flags)
-        elif eng._block:
-            wide = jnp.zeros((b, eng._block), jnp.int32)
-            host = (wide, wide.astype(bool), flags, flags)
-        else:
-            host = (ints, flags, jnp.zeros((b,), jnp.float32), flags,
-                    jax.random.key(0))
+        # no slot runs: the kind's own host arrays, all zeros
+        host = eng._kind.host_args((), jax.random.key(0))
         text = eng._step_fn.lower(
             eng.params, *eng.cache.buffers(), tables, lengths,
             *eng._carry, *host).as_text()
@@ -962,3 +959,61 @@ def test_the_other_serving_models_programs_lower_unchanged(
             jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
             *extra).as_text()
     assert _sha(text) == LOWERED[name][program == "prefill"], (name, program)
+
+
+# ---------------------------------------------------------------------------
+# The seam itself (PR 42): ``LMEngine`` asks one object of
+# ``serving/steps.py`` for everything that differs between kinds of
+# step.  ``stats()`` has the parent's keys, in the parent's order, under
+# every kind, and a request served to its end moves the tallies of its
+# engine's kind and no other's.
+STATS_KEYS = [
+    "requests", "tokens", "steps", "steps_ahead", "greedy_step_share",
+    "tokens_per_step", "drafts_verified", "drafts_accepted",
+    "draft_accept_share", "block_passes", "block_tails", "block_commits",
+    "positions_unmasked", "tokens_per_forward", "tail_share", "settles",
+    "busy_s", "tokens_per_s", "occupancy_mean", "queue_depth",
+    "kv_pages_in_use", "kv_pages_total", "state_bytes_per_slot",
+    "state_rebuilds", "draining", "weight_version", "manifest_sha",
+    "weight_swaps", "preemptions", "e2e_p50_s", "e2e_p99_s", "ttft_p50_s",
+    "ttft_p99_s", "itl_p50_s", "itl_p95_s", "int8", "tp",
+    "last_bucket_pages", "decode_ms_mean", "decode_hbm_bytes_per_token"]
+#: the kind a tiny configuration's model declares, whether its slots
+#: carry state, and the tallies that kind alone moves (seeded weights
+#: accept no draft, so ``drafts_accepted`` may stay 0)
+KINDS = {
+    "tiny_gpt": ("OneToken", False, set()),
+    "tiny_longcat": ("OneToken", False, set()),
+    "tiny_zaya": ("OneToken", True, set()),
+    "tiny_falcon_h1": ("OneToken", True, set()),
+    "tiny_joyai": ("Drafting", False, {"drafts_verified",
+                                       "draft_accept_share"}),
+    "tiny_sdar": ("Block", False, {"block_passes", "block_tails",
+                                   "positions_unmasked",
+                                   "tokens_per_forward", "tail_share"}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KINDS))
+def test_a_request_served_to_its_end_moves_its_kind_s_tallies_alone(
+        tiny_engines, name):
+    eng = tiny_engines(name)
+    kind, state, own = KINDS[name]
+    assert type(eng._kind).__name__ == kind
+    before = eng.stats()
+    assert list(before) == STATS_KEYS
+    req = eng.submit([3, 1, 4, 1, 5], 6)
+    eng.run_until_idle(timeout_s=300)
+    assert req.error is None and 1 <= len(req.tokens) <= 6
+    after = eng.stats()
+    assert list(after) == STATS_KEYS
+    assert after["requests"] == before["requests"] + 1
+    assert after["tokens"] == before["tokens"] + len(req.tokens)
+    assert bool(after["state_bytes_per_slot"]) == state
+    assert after["block_commits"] == 0 and after["state_rebuilds"] == 0
+    tallies = {"drafts_verified", "drafts_accepted", "draft_accept_share",
+               "block_passes", "block_tails", "positions_unmasked",
+               "tokens_per_forward", "tail_share"}
+    moved = {k for k in tallies if after[k] != before[k]}
+    may = {"drafts_accepted"} if kind == "Drafting" else set()
+    assert own <= moved <= own | may, moved

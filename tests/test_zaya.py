@@ -1,7 +1,7 @@
 """ZAYA1 at a tiny size on the CPU, seeded weights, float32:
 
 (a) the whole model against the plain reference
-    (``models/zaya_reference.py``) on LOGITS, tight enough that
+    (``benchmarks/reference/zaya1_8b.py``) on LOGITS, tight enough that
     bfloat16 matrices fail, and failing with any one part of the
     mathematics left out;
 (b) the expert layer: the router handed in as the layer's own leaves
@@ -33,8 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.reference import zaya1_8b as ref
 from bigdl_tpu import obs
-from bigdl_tpu.models import zaya_reference as ref
 from bigdl_tpu.models.zaya import Zaya, build_zaya
 from bigdl_tpu.nn.experts import DroplessExperts
 from bigdl_tpu.ops.decode_attention import (_block_pages,
@@ -167,17 +167,21 @@ def test_the_int8_control_separates_from_float32():
     assert gaps.shape == (11,) and gaps[0] == 0.0 and first[0] == served[0]
 
 
-def test_the_two_reference_copies_are_one_text():
+@pytest.mark.parametrize("name", [
+    "longcat_flash_chat", "joyai_llm_flash", "sdar_30b_a3b_chat", "zaya1_8b",
+    "falcon_h1_34b"])
+def test_a_reference_the_tests_compare_with_imports_nothing_of_the_program(
+        name):
+    """The float32 references under ``benchmarks/reference/`` that
+    ``tests/`` imports (one text each since PR 42) are independent of
+    the code under test."""
     import os
 
     import benchmarks
 
-    here = os.path.dirname(os.path.abspath(ref.__file__))
-    with open(os.path.join(here, "zaya_reference.py")) as fh:
-        program = fh.read()
     with open(os.path.join(os.path.dirname(benchmarks.__file__),
-                           "reference", "zaya1_8b.py")) as fh:
-        assert fh.read() == program
+                           "reference", name + ".py")) as fh:
+        program = fh.read()
     assert "import bigdl_tpu" not in program and "from bigdl_tpu" \
         not in program
 
