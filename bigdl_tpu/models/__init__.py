@@ -5,7 +5,14 @@ resnet (CIFAR + ImageNet), inception, vgg, alexnet, rnn (PTB LM),
 autoencoder — each with a builder and a runnable train entry point —
 and the language models served behind ``serving.LMEngine``:
 ``TransformerLM`` and ``LongCatFlash`` (latent attention, the
-shortcut-connected double layer, a dropless expert layer).
+shortcut-connected double layer, a dropless expert layer); by module,
+not re-exported here: ``joyai_flash`` (a step that verifies its own
+draft), ``sdar_moe`` (generation by blocks), ``zaya`` (attention in a
+compressed latent, a bounded-past slot state), ``falcon_h1`` (a
+state-space mixer beside grouped attention, a slot state that sums over
+the whole past) and ``ling_flash`` (a delta-rule linear attention with a
+decay a channel in five layers of six, latent attention in the sixth,
+experts chosen by groups: layers that differ in what a slot keeps).
 """
 
 from bigdl_tpu.models.lenet import build_lenet5

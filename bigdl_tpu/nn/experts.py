@@ -66,6 +66,19 @@ choice (held and absent experts, the sort, the grouped products, the
 counts) is the layer above.  ``top_k`` 1 is that model's; handing in
 ``routed=layer.route(params, x)`` is the layer above, bit for bit.
 
+**A choice limited to groups** (``models/ling_flash.py``;
+``groups=(n_group, topk_group)``, default none: every route above bit
+for bit).  The routed experts lie in ``n_group`` groups of equal size, in
+order.  A group's score is the sum of its 2 largest ``s + b``; the
+``topk_group`` best groups are kept and the ``top_k`` are taken inside
+them (what lies outside them is never chosen, whatever its score);
+weights as above.  In a deployment a group is what one chip holds
+(``held`` then names one group): a token sends a chip nothing unless
+it kept the chip's group.  Such a layer's ``counts`` carry two values
+more, behind the five: the real tokens that KEPT a group this chip
+holds experts of, and the real tokens (:func:`counts_dict`'s
+``group_hit_share`` is the one over the other).
+
 It also counts what it routed (``counts``): assignments to held,
 zero-compute and absent experts, how many held experts got a token, and
 the largest load of a held expert — the work of a step varies with the
@@ -89,7 +102,10 @@ def merge_counts(a, b):
 
     if a is None:
         return b
-    return jnp.concatenate([a[:4] + b[:4], jnp.maximum(a[4:], b[4:])])
+    parts = [a[:4] + b[:4], jnp.maximum(a[4:5], b[4:5])]
+    if a.shape[0] > 5:      # a group-limited layer's two sums more
+        parts.append(a[5:] + b[5:])
+    return jnp.concatenate(parts)
 
 
 class DroplessExperts(AbstractModule):
@@ -102,7 +118,7 @@ class DroplessExperts(AbstractModule):
                  top_k: int, scale: float = 1.0, held=None,
                  score: str = "softmax", renormalise: bool = False,
                  shared_hidden: int = 0, own_router: bool = True,
-                 init: bool = True):
+                 groups=None, init: bool = True):
         super().__init__()
         if score not in ("softmax", "sigmoid"):
             raise ValueError(f"score {score!r}: softmax or sigmoid")
@@ -114,12 +130,24 @@ class DroplessExperts(AbstractModule):
         if top_k > n_routed + n_zero:
             raise ValueError(f"top_k {top_k} over {n_routed + n_zero} "
                              "experts")
+        if groups is not None:
+            n_group, topk_group = (int(g) for g in groups)
+            if n_zero or n_routed % n_group or not (
+                    0 < topk_group <= n_group) or not own_router \
+                    or n_routed // n_group < 2 \
+                    or topk_group * (n_routed // n_group) < top_k:
+                raise ValueError(
+                    f"groups {groups}: {n_routed} routed experts (and no "
+                    f"zero-compute ones) in {n_group} equal groups of 2 "
+                    f"or more, the layer's own router keeping "
+                    f"{topk_group} of them with room for top_k {top_k}")
+            groups = (n_group, topk_group)
         self._config = dict(dim=dim, hidden=hidden, n_routed=n_routed,
                             n_zero=n_zero, top_k=top_k, scale=scale,
                             held=(lo, hi), score=score,
                             renormalise=renormalise,
                             shared_hidden=shared_hidden,
-                            own_router=own_router)
+                            own_router=own_router, groups=groups)
         self.dim, self.hidden = dim, hidden
         self.n_routed, self.n_zero = n_routed, n_zero
         self.top_k, self.scale = top_k, float(scale)
@@ -127,6 +155,7 @@ class DroplessExperts(AbstractModule):
         self.score, self.renormalise = score, bool(renormalise)
         self.shared_hidden = int(shared_hidden)
         self.own_router = bool(own_router)
+        self.groups = groups
         if not self.own_router:
             self.param_names = tuple(
                 n for n in type(self).param_names
@@ -169,6 +198,11 @@ class DroplessExperts(AbstractModule):
         """``x`` (N, dim) -> chosen expert ids (N, top_k) and their
         weights ``scale * s_e`` (N, top_k), float32 (``s_e`` over the
         sum of the chosen where the layer renormalises)."""
+        return self._choose(params, x)[:2]
+
+    def _choose(self, params, x):
+        """:meth:`route`, and behind it the groups each token kept (N,
+        n_group) bool, None for a layer without groups."""
         import jax
         import jax.numpy as jnp
 
@@ -177,17 +211,25 @@ class DroplessExperts(AbstractModule):
                             precision="highest")
         s = jax.nn.softmax(logits, axis=-1) if self.score == "softmax" \
             else jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(s + params["bias"].astype(jnp.float32),
-                               self.top_k)
+        biased, kept = s + params["bias"].astype(jnp.float32), None
+        if self.groups is not None:
+            n_group, topk_group = self.groups
+            by_group = biased.reshape(x.shape[0], n_group, -1)
+            best2, _ = jax.lax.top_k(by_group, 2)
+            _, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+            kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
+            biased = jnp.where(kept[:, :, None], by_group,
+                               -jnp.inf).reshape(biased.shape)
+        _, idx = jax.lax.top_k(biased, self.top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if self.renormalise:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        return idx, self.scale * w
+        return idx, self.scale * w, kept
 
     def apply(self, params, state, input, *, mask=None, routed=None,
               training=False, rng=None):
-        """``input`` (N, dim) -> ``((y (N, dim), counts (5,) int32),
-        state)``.  ``mask`` (N,) marks the rows that are real tokens;
+        """``input`` (N, dim) -> ``((y (N, dim), counts (5,) int32: 7
+        under ``groups``), state)``.  ``mask`` (N,) marks the rows that are real tokens;
         padding rows are routed nowhere, get 0, and are not counted.
         ``routed`` is another router's ``(idx, w)`` in :meth:`route`'s
         form (a layer without a router of its own needs it)."""
@@ -202,7 +244,8 @@ class DroplessExperts(AbstractModule):
             raise ValueError("a layer built with own_router=False is "
                              "handed its routing (routed=(idx, w))")
         with jax.named_scope("moe.route"):
-            idx, w = self.route(params, x) if routed is None else routed
+            idx, w, kept = self._choose(params, x) if routed is None \
+                else (*routed, None)
             real = jnp.ones((n,), bool) if mask is None else mask
             real = real[:, None]
             is_zero = (idx >= self.n_routed) & real
@@ -220,6 +263,13 @@ class DroplessExperts(AbstractModule):
                 held, jnp.sum(is_zero),
                 jnp.sum(real) * k - held - jnp.sum(is_zero),
                 jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
+            if kept is not None:
+                # the groups this chip holds experts of
+                per = self.n_routed // self.groups[0]
+                mine = kept[:, self.lo // per:-(-self.hi // per)]
+                counts = jnp.concatenate([counts, jnp.stack([
+                    jnp.sum(jnp.any(mine, axis=1) & real[:, 0]),
+                    jnp.sum(real)]).astype(jnp.int32)])
         with jax.named_scope("moe.experts"):
             xs = jnp.take(x, token, axis=0)                  # (N*k, dim)
             h = jax.nn.silu(grouped_matmul(xs, params["w_gate"], sizes)) \
@@ -250,7 +300,12 @@ class DroplessExperts(AbstractModule):
 def counts_dict(counts) -> dict:
     """A ``counts`` vector on the host as ``{name: int}``."""
     vals = np.asarray(counts).reshape(-1)
-    return {name: int(v) for name, v in zip(COUNT_NAMES, vals)}
+    out = {name: int(v) for name, v in zip(COUNT_NAMES, vals)}
+    if len(vals) > len(COUNT_NAMES):
+        # a group-limited layer: the share of the real tokens that kept
+        # a group this chip holds experts of
+        out["group_hit_share"] = float(vals[5]) / max(int(vals[6]), 1)
+    return out
 
 
 __all__ = ["COUNT_NAMES", "DroplessExperts", "counts_dict", "merge_counts"]

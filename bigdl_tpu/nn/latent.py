@@ -23,6 +23,13 @@ with ``s_q = sqrt(dim / q_rank)`` and ``s_kv = sqrt(dim / kv_rank)``
 (LongCat-Flash's ``mla_scale_*``) unless the constructor is given
 ``q_scale`` / ``kv_scale``: a model without such factors
 (``models/joyai_flash.py``) gives 1, and nothing is multiplied.
+Two options whose defaults are the layer above, bit for bit
+(``models/ling_flash.py`` takes both): ``q_rank=None`` is a query WITHOUT
+the low-rank step, ``q = W_q x`` (one matrix ``wq`` in place of ``wq_a``,
+``q_norm``, ``wq_b``; no factor unless ``q_scale`` is given);
+``head_gate=True`` multiplies head ``h``'s mix by ``sigmoid(w_gate,h .
+x)`` before ``W_o`` (one gate a head and position, from the layer's own
+input).
 **The cached row of a token is ``[c | rotated k_rope]``** — ``kv_rank +
 rope`` values for all heads together, and zeros up to a multiple of
 ``row_align`` lanes (a TPU works on a buffer of 640-lane rows as it
@@ -183,13 +190,22 @@ class LatentAttention(AbstractModule):
     def __init__(self, dim: int, n_head: int, q_rank: int, kv_rank: int,
                  nope_dim: int, rope_dim: int, v_dim: int,
                  eps: float = 1e-5, theta: float = 1e4, row_align: int = 1,
-                 q_scale=None, kv_scale=None, init: bool = True):
+                 q_scale=None, kv_scale=None, head_gate: bool = False,
+                 init: bool = True):
         super().__init__()
         self._config = dict(dim=dim, n_head=n_head, q_rank=q_rank,
                             kv_rank=kv_rank, nope_dim=nope_dim,
                             rope_dim=rope_dim, v_dim=v_dim, eps=eps,
                             theta=theta, row_align=row_align,
-                            q_scale=q_scale, kv_scale=kv_scale)
+                            q_scale=q_scale, kv_scale=kv_scale,
+                            head_gate=head_gate)
+        self.head_gate = bool(head_gate)
+        if q_rank is None:
+            # a full-rank query: one matrix, no norm, no factor
+            self.param_names = ("wq",) + type(self).param_names[3:]
+            q_scale = 1.0 if q_scale is None else q_scale
+        if self.head_gate:
+            self.param_names = self.param_names + ("w_gate",)
         self.dim, self.n_head = dim, n_head
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
@@ -211,9 +227,15 @@ class LatentAttention(AbstractModule):
     def reset(self):
         jnp = _jnp()
         h = self.n_head
-        self.wq_a = _draw((self.q_rank, self.dim))
-        self.q_norm = jnp.ones((self.q_rank,), jnp.float32)
-        self.wq_b = _draw((h * (self.nope_dim + self.rope_dim), self.q_rank))
+        if self.q_rank is None:
+            self.wq = _draw((h * (self.nope_dim + self.rope_dim), self.dim))
+        else:
+            self.wq_a = _draw((self.q_rank, self.dim))
+            self.q_norm = jnp.ones((self.q_rank,), jnp.float32)
+            self.wq_b = _draw((h * (self.nope_dim + self.rope_dim),
+                               self.q_rank))
+        if self.head_gate:
+            self.w_gate = _draw((h, self.dim))
         self.wkv_a = _draw((self.kv_rank + self.rope_dim, self.dim))
         self.kv_norm = jnp.ones((self.kv_rank,), jnp.float32)
         self.wkv_b = _draw((h * (self.nope_dim + self.v_dim), self.kv_rank))
@@ -228,9 +250,12 @@ class LatentAttention(AbstractModule):
         zeros]`` (..., row_width)."""
         jnp = _jnp()
         h = self.n_head
-        c_q = rms_norm(jnp.matmul(x, params["wq_a"].T), params["q_norm"],
-                       self.eps)
-        q = jnp.matmul(c_q, params["wq_b"].T)
+        if self.q_rank is None:
+            q = jnp.matmul(x, params["wq"].T)
+        else:
+            c_q = rms_norm(jnp.matmul(x, params["wq_a"].T),
+                           params["q_norm"], self.eps)
+            q = jnp.matmul(c_q, params["wq_b"].T)
         if self.q_scale != 1.0:
             q = q * jnp.asarray(self.q_scale, x.dtype)
         q = q.reshape(*x.shape[:-1], h, self.nope_dim + self.rope_dim)
@@ -254,6 +279,17 @@ class LatentAttention(AbstractModule):
             [jnp.zeros(latent.shape[:-1] + (pad,), latent.dtype)]
             if pad else [])
         return jnp.concatenate(parts, axis=-1)
+
+    def _gated(self, params, x, o):
+        """The heads' mixes ``o`` (..., H, v) under the head-wise gate
+        of the layer's input ``x`` (..., dim); ``o`` itself without
+        one."""
+        import jax
+
+        if not self.head_gate:
+            return o
+        gate = jax.nn.sigmoid(_jnp().matmul(x, params["w_gate"].T))
+        return o * gate[..., None].astype(o.dtype)
 
     def _kv_heads(self, params):
         """``W_kvb`` as ``(H, nope + v, kv_rank)``: head ``h``'s rows
@@ -292,6 +328,7 @@ class LatentAttention(AbstractModule):
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
         with jax.named_scope("mla.proj"):
+            o = self._gated(params, x, o)
             y = jnp.matmul(o.reshape(b, t, self.n_head * self.v_dim),
                            params["wo"].T)
         return y, rows
@@ -347,6 +384,7 @@ class LatentAttention(AbstractModule):
             o = jnp.einsum("...hc,hdc->...hd", o_lat.astype(x.dtype),
                            wkv[:, self.nope_dim:, :])
         with jax.named_scope("mla.proj"):
+            o = self._gated(params, x, o)
             y = jnp.matmul(o.reshape(*lead, self.n_head * self.v_dim),
                            params["wo"].T)
         return y, pages

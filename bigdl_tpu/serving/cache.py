@@ -44,14 +44,21 @@ written and read through them:
 
 **State that is not keys and values** lives here too, beside the pages
 (a model's ``state_spec``: ``serving/steps.py`` ``OneToken`` says what
-the engine asks).  Some layers need more than a token's own rows, in one of two
-ways.  **A bounded past**: to form a token's rows, a few rows of the
+the engine asks).  Some layers need more than a token's own rows, in one of
+three ways.  **A bounded past**: to form a token's rows, a few rows of the
 slot's PREVIOUS token (``models/zaya.py``: two causal convolutions and a
 shifted value; 5.4 KB a slot and layer).  **The whole past**: a running
 state that every token decays and adds to (``models/falcon_h1.py``: a
 state-space mixer's ``H``, 32 x 256 x 128 float32, 4.19 MB a slot and
-layer, and its convolution's last 3 rows).  Neither is a function of the
-token alone, so no page holds it.  It is one array a declared shape,
+layer, and its convolution's last 3 rows).  **The whole past, read
+before it is written**: a matrix a head whose transition is not diagonal
+(``models/ling_flash.py``: a delta-rule linear attention's ``S``, 32 x
+128 x 128 float32, 2.10 MB a slot and layer: every token reads the
+decayed state along its key, writes a rank-1 correction, reads again
+along its query; kept only by the layers that mix so, 6 of that model's
+7, while the seventh keeps pages and no state: ``state_spec``'s
+``layers`` and ``cache_spec``'s count different layers).  None is a
+function of the token alone, so no page holds it.  It is one array a declared shape,
 ``(layers, max_slots, *shape)``, indexed by SLOT (not by page: a slot
 has exactly one, whatever its length), handed to the step and the
 prefill and taken back with the pools (:meth:`PagedKVCache.buffers`),
@@ -65,7 +72,16 @@ once out: 14 MB for ZAYA1's 256 slots, where one more pass to guard it
 shows nowhere; 4.3 GB for Falcon-H1's 128, as much as the page pools
 hold and more than the layers' weights, where the guard's pass would be
 a third of the step's floor: there the update itself leaves an idle
-slot bit for bit (``dt = 0``) and runs in place (``ops/ssm_state.py``).
+slot bit for bit (``dt = 0``) and runs in place (``ops/ssm_state.py``);
+6.9 GB for Ling's 256, half of everything its step moves
+(``ops/delta_state.py``, ``beta = 0`` and ``g = 0``).  **One declared
+shape is one buffer, and a buffer may not pass 2 GiB**
+(:data:`STATE_BUFFER_BYTES`): Ling's ``S`` as ONE array of 6 layers x
+256 slots x 32 heads (3.0 GiB) served wrong tokens on the chip and as
+two arrays of 16 heads serves right ones (``PERF.md`` section 6, PR 44;
+Falcon-H1's 2.0 GiB is the largest that is known to work), so a model
+whose state is larger declares it in parts and the constructor refuses
+a larger buffer.
 **Nothing is snapshotted**: a preempted request's second prefill
 rebuilds its state from its tokens.  That is exact for a bounded past,
 and for the whole past it is the prefill's scan over prompt + generated
@@ -88,6 +104,25 @@ from typing import List, Optional
 
 import numpy as np
 from bigdl_tpu.obs import names
+
+
+#: the largest buffer of slot state the cache builds (module docstring)
+STATE_BUFFER_BYTES = 1 << 31
+
+
+def state_buffer_bytes(layers: int, slots: int, shape, itemsize: int) -> int:
+    """Bytes of the ``(layers, slots, *shape)`` buffer of one declared
+    shape; a ``ValueError`` over :data:`STATE_BUFFER_BYTES`."""
+    nbytes = int(layers) * int(slots) * int(np.prod(shape, dtype=np.int64)) \
+        * int(itemsize)
+    if nbytes > STATE_BUFFER_BYTES:
+        raise ValueError(
+            f"a slot-state buffer of {layers} layers x {slots} slots x "
+            f"{tuple(shape)} is {nbytes / 2 ** 30:.2f} GiB: over 2 GiB the "
+            "served tokens came out wrong on the chip (PERF.md section 6, "
+            "PR 44); the model declares such a state in parts (state_spec's "
+            "shapes: one buffer a shape)")
+    return nbytes
 
 
 class PagedKVCache:
@@ -134,6 +169,10 @@ class PagedKVCache:
         #: of the model's ``state_spec`` (``layers``, ``shapes``,
         #: ``dtype``), none for a model without one
         spec = state_spec or {"layers": 0, "shapes": ()}
+        for shp in spec["shapes"]:
+            state_buffer_bytes(
+                spec["layers"], self.max_slots, shp,
+                jnp.dtype(spec.get("dtype") or self.dtype).itemsize)
         self.state = tuple(
             jnp.zeros((int(spec["layers"]), self.max_slots)
                       + tuple(int(n) for n in shp),
@@ -357,5 +396,6 @@ def gather_pages(pages, page_table, layer: Optional[int] = None):
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], g.shape[3])
 
 
-__all__ = ["PagedKVCache", "gather_pages", "keep_inactive", "pool_shape",
+__all__ = ["PagedKVCache", "STATE_BUFFER_BYTES", "gather_pages",
+           "keep_inactive", "pool_shape", "state_buffer_bytes",
            "write_prompt_pages", "write_slot_state", "write_token_rows"]

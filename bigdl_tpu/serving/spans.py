@@ -103,6 +103,12 @@ Three families:
   ``moe_hit``           held experts that got a token (their weights are
                         what the grouped product has to read)
   ``moe_max_load``      the largest load of a held expert, a layer
+  ``moe_group_hit_share``  under a router that chooses by groups
+                        (``nn/experts.py`` ``groups``;
+                        ``models/ling_flash.py``) only: the share of
+                        the step's tokens (a layer) that kept a group
+                        this chip holds experts of; the others send
+                        this chip nothing
   ``context_tokens``    ``serve.decode_step`` only: the sum of the
                         active slots' contexts, the step's own token
                         included (the cache rows attention has to

@@ -143,9 +143,12 @@ class OneToken:
 
     **With state a slot carries that is not keys and values**
     (``state_spec(params)`` -> ``{"layers", "shapes", "dtype"}``:
-    ``models/zaya.py``, ``models/falcon_h1.py``; ``serving/cache.py``
-    keeps it beside the pages and tells its life, its cost and why a
-    preemption snapshots nothing) both entry points hand it through:
+    ``models/zaya.py``, ``models/falcon_h1.py``, ``models/ling_flash.py``;
+    ``serving/cache.py`` keeps it beside the pages and tells its life,
+    its cost and why a preemption snapshots nothing; ``layers`` counts
+    the layers that KEEP state and ``cache_spec``'s the layers that keep
+    pages, each indexed by its own count: Ling's are 6 and 1 of 7) both
+    entry points hand it through:
     ``paged_prefill`` -> ``(caches, logits, counts, rows)``, ``rows``
     one ``(layers, *shape)`` array a shape, the state after the
     prompt's last REAL token, which the prefill writes into the slot

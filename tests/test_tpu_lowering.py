@@ -869,6 +869,114 @@ def test_hybrid_engine_programs_work_on_cache_and_state_as_they_lie(
         assert temp < 500e6, (name, temp)
 
 
+# (benchmarks/configs/ling_3_flash_vl.json): 256 slots, six KDA layers'
+# stacked float32 state, 32 heads of 128 x 128
+KDA = dict(layers=6, slots=256, heads=32, dk=128, dv=128)
+
+
+def test_kda_state_update_is_one_kernel_in_place_at_the_engine_shape():
+    """The delta-rule state's decode step at the cell's shape: ONE Mosaic
+    call, 16 heads of a slot a grid step, and the stacked state its
+    input AND its output (operand 1, behind the prefetched layer)."""
+    from bigdl_tpu.ops import delta_state
+
+    c = KDA
+    f32 = jnp.float32
+    row = ((c["slots"], c["heads"], c["dk"]), f32)
+    shapes = (((c["layers"], c["slots"], c["heads"], c["dk"], c["dv"]),
+               f32), ((1,), jnp.int32), row, row, row,
+              ((c["slots"], c["heads"], c["dv"]), f32),
+              ((c["slots"], c["heads"]), f32))
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    lowered = delta_state._program(False).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert lowered.count("tpu_custom_call") == 1
+    assert 'kernel_name = "kda_state_update"' in lowered
+    assert "output_operand_aliases" in lowered
+    assert delta_state._heads_a_block(c["heads"], c["dk"] * c["dv"] * 4) \
+        == 16
+
+
+@pytest.mark.slow
+def test_kda_engine_programs_work_on_cache_and_state_as_they_lie(
+        one_chip, monkeypatch):
+    """Ling-3.0-flash's decode step and a prefill of 256 at the published
+    widths and the cell's size (7 layers, 256 slots, 64 held experts a
+    layer, 32769 pages of ONE cached layer, six layers of float32 state)
+    compiled for the described v5e with the pool AND the state donated:
+    ONE ``kda_state_update`` program called twice a KDA layer and one
+    ``latent_decode_attention``, NO COPY of the slots' ``S`` (two
+    arrays of 1.6 GB) and nothing of its size among the temporaries."""
+    import functools
+    import json
+
+    from benchmarks.reference import ling_3_flash_vl as ref
+    from bigdl_tpu.models.ling_flash import build_ling_flash
+    from bigdl_tpu.serving.engine import LMEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "ling_3_flash_vl.json"), encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    dt, slots, page, max_len = jnp.bfloat16, 256, 16, 2048
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(cfg), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+    assert 5.92e9 < held < 5.95e9
+    probe = build_ling_flash(cfg, params=weights)
+    cs, ss = probe.cache_spec(weights), probe.state_spec(weights)
+    assert (cs["layers"], cs["row_width"], cs["buffers"],
+            cs["attn_query_rows"], cs["expert_slots"]) == (1, 640, 1, 32,
+                                                           384)
+    assert ss["layers"] == 6 and ss["keeps_inactive"]
+    # S in two arrays of 16 heads: 1.6 GB each over 6 layers x 256 slots
+    assert ss["shapes"] == ((16, 128, 128), (16, 128, 128), (3, 12288))
+    buf = spec((1, pages, page, 640), dt)
+    state = tuple(spec((6, slots) + shp, ss["dtype"])
+                  for shp in ss["shapes"])
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf,),
+                            state=state)
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((slots,), jnp.int32)
+    flags = spec((slots,), jnp.bool_)
+    programs = {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, *state, spec((slots, 128), jnp.int32), ints, ints,
+            ints, flags, spec((slots,), jnp.float32), flags, key),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, *state, spec((1, 256), jnp.int32),
+            spec((), jnp.int32), spec((256 // page,), jnp.int32),
+            spec((), jnp.float32), key, spec((), jnp.int32))}
+    for name, lowered in programs.items():
+        text = lowered.as_text()
+        if name == "step":
+            assert _kernel_calls(text, "kda_state_update") == 2 * 6
+            assert _kernel_calls(text, "latent_decode_attention") == 1
+        else:
+            assert "kda_state_update" not in text
+        compiled = lowered.compile()
+        ops = _whole_cache_ops(compiled, buf)
+        kept = _whole_cache_ops(compiled, state[0])
+        mem = compiled.memory_analysis()
+        print(f"kda {name}: whole-cache instructions {ops}, whole-state "
+              f"instructions {kept}, arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e6:.1f} MB")
+        # a pool of ONE cached layer: its layer axis goes by a bitcast
+        assert set(ops) <= {"parameter", "scatter", "scatter fusion",
+                            "bitcast"}, (name, ops)
+        assert "copy" not in kept and "copy-start" not in kept, (name, kept)
+        assert 10.0e9 < mem.argument_size_in_bytes < 10.1e9
+        # a layer's slice of S would be 537 MB
+        assert mem.temp_size_in_bytes < 400e6, (name, mem.temp_size_in_bytes)
+
+
 # ---------------------------------------------------------------------------
 # The serving programs of the models that were there before a model
 # whose state sums over the whole past: PR 39 gave the engine a branch
@@ -887,7 +995,10 @@ def test_hybrid_engine_programs_work_on_cache_and_state_as_they_lie(
 # meant to change NONE: it moved the bodies of ``step`` and ``prefill``
 # from ``serving/engine.py`` into ``serving/steps.py`` and first pinned
 # the sixth model, Falcon-H1 (the one whose ``state_spec`` says
-# ``keeps_inactive``), with the hashes PR 41's tree gives.
+# ``keeps_inactive``), with the hashes PR 41's tree gives.  PR 44 meant
+# to change NONE of the six (``nn/experts.py`` and ``nn/latent.py``
+# gained options whose defaults trace the programs they traced) and
+# pinned the seventh, Ling-3.0-flash, with its own tree's.
 LOWERED = {
     "tiny_gpt": ("ecfcedf2071ee787", "46c449c19d770f24"),
     "tiny_longcat": ("8915adb05bc5a3eb", "1d9aa96cdb0e528a"),
@@ -895,6 +1006,7 @@ LOWERED = {
     "tiny_sdar": ("6dd9578b515609a5", "97b140ade177e8e2"),
     "tiny_zaya": ("792116b44675ebac", "35e5c200d9a20b14"),
     "tiny_falcon_h1": ("dd5e4933b5bc20de", "f0c7c89cc405a2ea"),
+    "tiny_ling": ("52a7d173fcc09d24", "8a1ca5abc25ad51b"),
 }
 
 
@@ -986,6 +1098,7 @@ KINDS = {
     "tiny_longcat": ("OneToken", False, set()),
     "tiny_zaya": ("OneToken", True, set()),
     "tiny_falcon_h1": ("OneToken", True, set()),
+    "tiny_ling": ("OneToken", True, set()),
     "tiny_joyai": ("Drafting", False, {"drafts_verified",
                                        "draft_accept_share"}),
     "tiny_sdar": ("Block", False, {"block_passes", "block_tails",
