@@ -31,7 +31,20 @@ drain.  This engine is the production shape:
   behind, and whatever needs host and chip to agree (preemption, a
   weight swap, ``close``, an engine with nothing left to run) settles
   the step in flight first.  An EOS is learnt one step late: the
-  slot's row in the step already dispatched is wasted, never emitted.
+  slot's row in the step already dispatched is wasted, never emitted;
+* **a prefill is read late too**: what the step behind an admission
+  needs of the prefill's result, the prefill itself writes into the
+  slot's row of what the steps carry on the device, and the slot's
+  bookkeeping is made at the prefill's dispatch from the prompt and the
+  request alone.  So a cycle that admits runs ``dispatch prefill(s) ->
+  prep -> dispatch step k -> wait/read/emit step k-1 -> wait/read the
+  prefill(s)`` and the chip's queue reads ``step k-1, prefill, step k``
+  with no gap.  **The order that holds**: a prefill's result is read in
+  the pump that dispatched it, after that pump's emit and so BEFORE the
+  read of any step dispatched behind it (which needs what the read
+  leaves: a block's mask flags, a first token that was an EOS), and a
+  settle reads the prefills not yet read after the step in flight,
+  which was launched before them.
 
 **What the engine asks of a model**: ``cache_spec(params)`` — how many
 cached layers, the width of a token's row, one buffer or two, the
@@ -152,6 +165,19 @@ class _InFlight:
         self.entries, self.context_rows = entries, context_rows
 
 
+class _Prefilled:
+    """A dispatched prefill whose first result the host has not read:
+    ``result``, the device array; ``counts``, an expert model's routing
+    counts or None; the ``slot`` and its ``act``; ``step``, the cycle's;
+    ``admit``, the request trace's record of this admission or None."""
+
+    __slots__ = ("result", "counts", "slot", "act", "step", "admit")
+
+    def __init__(self, result, counts, slot, act, step, admit):
+        self.result, self.counts = result, counts
+        self.slot, self.act, self.step, self.admit = slot, act, step, admit
+
+
 class LMEngine:
     """Continuous-batching decode over a :class:`PagedKVCache`."""
 
@@ -242,6 +268,10 @@ class LMEngine:
         # the step in flight (dispatched, its tokens unread) and the
         # last dispatched step's tokens, the next step's input
         self._inflight: Optional[_InFlight] = None
+        # the prefills dispatched since, their first results unread:
+        # empty between two pumps
+        self._unread: List[_Prefilled] = []
+        self._admitted = self._read_late = 0
         # what a step hands the next on the device
         self._carry = self._kind.carry()
         self._slot_steps = self._step_tokens = 0
@@ -533,6 +563,9 @@ class LMEngine:
         return admitted
 
     def _prefill_into(self, slot: int, req: ServeRequest, bucket: int):
+        """Dispatch ``req``'s prefill for ``slot`` and make the slot's
+        bookkeeping: nothing of the result is read here
+        (``_read_prefills``)."""
         import jax
         import jax.numpy as jnp
 
@@ -545,52 +578,87 @@ class LMEngine:
         prompt[0, :t0] = req.payload
         self._key, sub = jax.random.split(self._key)
         tracer = self._tracer
-        n = len(self.cache.buffers())
+        n, handed = len(self.cache.buffers()), self._kind.handed
         step = self._steps
         self._order += 1
         with tracer.span(spans.SPAN_STEP_PREFILL, step=step,
                          bucket=bucket, prompt_len=t0,
                          request=req.id) as span_id:
-            extra = self._kind.begin_prefill(slot, req, tracer, span_id)
+            self._kind.begin_prefill(req, tracer, span_id)
             with tracer.span(spans.SPAN_STEP_DISPATCH, step=step,
                              program="prefill") as dispatch_id:
                 self._note_dry(tracer, dispatch_id)
+                # behind the engine's arguments, the slot and the arrays
+                # of the carry the prefill writes the slot's row of
                 out = self._prefill_fn(bucket)(
                     self.params, *self.cache.buffers(),
                     jnp.asarray(prompt), t0, jnp.asarray(page_arg),
-                    float(req.temperature), sub, *extra)
+                    float(req.temperature), sub, np.int32(slot),
+                    *self._carry[:handed])
                 self.cache.set_buffers(out[:n])
+                self._carry = (*out[n:n + handed], *self._carry[handed:])
+                # what the host reads: the first result, and an expert
+                # model's counts
+                result = out[n + handed:]
+                for arr in result:
+                    arr.copy_to_host_async()
                 self.cache.lengths[slot] = t0
-            # the prefill was enqueued behind the step in flight: the
-            # wait holds what was left of that step too
-            with tracer.span(spans.SPAN_STEP_WAIT, step=step,
-                             program="prefill"):
-                first = np.asarray(out[n])
-            with tracer.span(spans.SPAN_STEP_READ, step=step,
-                             program="prefill"):
-                # what the first result means is the kind's: the slot's
-                # bookkeeping, and the token to emit now or none yet
-                act, tok = self._kind.admit(slot, req, first, self._order)
-                if len(out) > n + 1:
-                    tracer.add_attrs(span_id,
-                                     **self._note_routing(out[n + 1]))
+                act = self._kind.admit(slot, req, self._order)
+        admit = None
         if req.trace is not None:
-            req._tr_admits.append(
-                {"t": t_admit, "dur": time.monotonic() - t_admit,
-                 "bucket": bucket, "prompt_len": t0, "slot": slot})
+            # (``dur`` runs to the read of the first result)
+            admit = {"t": t_admit, "dur": time.monotonic() - t_admit,
+                     "bucket": bucket, "prompt_len": t0, "slot": slot}
+            req._tr_admits.append(admit)
         if self._t_first_work is None:
             self._t_first_work = time.monotonic()
-        if tok is not None:
-            self._first_token(req)
-            req.tokens.append(tok)
-            req.token_times.append(time.perf_counter())
-            self._tokens_total += 1
-            self._tokens_counter.inc()
         self._slots[slot] = act
+        self._admitted += 1
+        self._unread.append(_Prefilled(
+            result[0], result[1] if len(result) > 1 else None, slot, act,
+            step, admit))
         tracer.event(spans.EVENT_ADMIT, slot=slot, request=req.id,
                      prompt_len=t0, bucket=bucket)
-        if tok is not None and (act.remaining <= 0 or tok == self.eos_id):
-            self._complete(slot)
+
+    def _read_prefills(self, tracer, late: bool) -> bool:
+        """Read, in the order of their dispatch, the prefills not read
+        yet: the first token to its request, the kind's bookkeeping, an
+        expert model's counts, and the end of a request whose first
+        token was its last.  ``late``: a step was dispatched behind
+        them, the pipelined loop's case.  False where there was none."""
+        if not self._unread:
+            return False
+        pending, self._unread = self._unread, []
+        for rec in pending:
+            with tracer.span(spans.SPAN_STEP_WAIT, step=rec.step,
+                             program="prefill"):
+                first = np.asarray(rec.result)
+            slot, act = rec.slot, rec.act
+            req = act.req
+            with tracer.span(spans.SPAN_STEP_READ, step=rec.step,
+                             program="prefill", request=req.id,
+                             late=int(late)) as span_id:
+                tok = self._kind.prefilled(act, first)
+                if rec.counts is not None:
+                    tracer.add_attrs(span_id,
+                                     **self._note_routing(rec.counts))
+                if rec.admit is not None:
+                    rec.admit["dur"] = time.monotonic() - rec.admit["t"]
+                self._read_late += late
+                if tok is not None:
+                    self._first_token(req)
+                    self._give(req, tok)
+                    # by count, or an EOS learnt one cycle late: the
+                    # slot's row in a step dispatched since is wasted
+                    if act.left <= 0 or tok == self.eos_id:
+                        self._complete(slot)
+        return True
+
+    def _give(self, req: ServeRequest, tok: int):
+        req.tokens.append(tok)
+        req.token_times.append(time.perf_counter())
+        self._tokens_total += 1
+        self._tokens_counter.inc()
 
     def _first_token(self, req: ServeRequest):
         """Stamp a request's first token (once: a preempted request's
@@ -603,8 +671,9 @@ class LMEngine:
     def _preempt_youngest(self) -> Optional[int]:
         """Free the youngest active slot's pages; its request re-queues
         with the generated prefix folded into the prompt.  The step in
-        flight is settled first: the fold needs every dispatched token
-        in ``req.tokens`` (and a slot it completes is no victim)."""
+        flight and the prefills unread are settled first: the fold needs
+        every dispatched token in ``req.tokens``, a first token
+        included (and a slot it completes is no victim)."""
         self._settle("preempt")
         victims = [(s.order, i) for i, s in enumerate(self._slots)
                    if s is not None]
@@ -720,8 +789,9 @@ class LMEngine:
 
     def _step(self):
         """Dispatch the next decode step, THEN read and emit the one
-        before it: while the host does that, admits and prepares again,
-        the chip runs the step just dispatched."""
+        before it: while the host does that, reads the cycle's prefills
+        (``pump``), admits and prepares again, the chip runs the step
+        just dispatched."""
         import jax
         import jax.numpy as jnp
 
@@ -753,9 +823,10 @@ class LMEngine:
                 return False
             acts = [(i, self._slots[i]) for i in running]
             self._key, sub = jax.random.split(self._key)
-            # the step's arguments behind the carry, as host arrays: a
-            # slot admitted since the last step takes its input from
-            # the host, this once
+            # the step's arguments behind the carry, as host arrays:
+            # what the host knows of a slot admitted since the last
+            # step without reading its prefill (the rest of its input
+            # the prefill wrote into the carry)
             host = self._kind.host_args(acts, sub)
             longest = max(int(self.cache.lengths[i]) + self._ahead(i)
                           for i in running)
@@ -859,13 +930,15 @@ class LMEngine:
 
     def _note_dry(self, tracer, dispatch_id):
         """Say on an open ``serve.dispatch`` whether the chip had run
-        dry: nothing launched before is still running.  Asked of the
-        device buffer without blocking, before the host ships its
-        arrays, and only under a recording tracer."""
+        dry: nothing launched before is still running, neither the
+        step in flight nor a prefill not yet read.  Asked of the device
+        buffers without blocking, before the host ships its arrays, and
+        only under a recording tracer."""
         if tracer.enabled:
-            rec = self._inflight
-            tracer.add_attrs(dispatch_id, dry=int(
-                rec is None or bool(rec.result.is_ready())))
+            launched = [rec for rec in (self._inflight, *self._unread)
+                        if rec is not None]
+            tracer.add_attrs(dispatch_id, dry=int(all(
+                bool(rec.result.is_ready()) for rec in launched)))
 
     def _read(self, rec: _InFlight, tracer, step: int) -> steps._StepRead:
         """Wait for a dispatched step's tokens (``serve.wait``: the one
@@ -892,11 +965,8 @@ class LMEngine:
                 self._first_token(req)
                 for j in range(n):
                     tok = int(read.tokens[slot, start + j])
-                    req.tokens.append(tok)
-                    req.token_times.append(time.perf_counter())
-                    self._tokens_total += 1
+                    self._give(req, tok)
                     self._step_tokens += 1
-                    self._tokens_counter.inc()
                     act.left -= 1
                     if act.left <= 0 or tok == self.eos_id:
                         self._complete(slot)
@@ -905,38 +975,47 @@ class LMEngine:
                 self._kind.after_emit(slot, act, read)
 
     def _settle(self, reason: str) -> bool:
-        """Read and emit the step in flight, outside the pipelined loop:
-        before anything that needs the host and the chip to agree
-        (``reason`` one of ``SETTLE_REASONS``).  An event, not a
+        """Read and emit the step in flight, then the prefills not yet
+        read (launched behind it), outside the pipelined loop: before
+        anything that needs the host and the chip to agree (``reason``
+        one of ``SETTLE_REASONS``).  An event, not a
         ``serve.decode_step`` span: that step has its span already.
-        False where nothing was in flight."""
+        False where nothing was in flight; only a step counts as a
+        settle."""
         rec = self._inflight
-        if rec is None:
+        if rec is None and not self._unread:
             return False
-        self._inflight = None
         tracer = obs.get_tracer()  # not always inside a pump
-        # the last step dispatched: its wait and read lie outside any
-        # ``serve.decode_step`` and carry its own step
-        read = self._read(rec, tracer, self._steps - 1)
-        tracer.event(spans.EVENT_SETTLE, reason=reason, **read.attrs)
-        with tracer.span(spans.SPAN_STEP_EMIT, step=self._steps):
-            self._emit(rec, read)
-        self._settles[reason] += 1
-        self._settle_counter.labels(reason=reason).inc()
+        if rec is not None:
+            self._inflight = None
+            # the last step dispatched: its wait and read lie outside
+            # any ``serve.decode_step`` and carry its own step
+            read = self._read(rec, tracer, self._steps - 1)
+            tracer.event(spans.EVENT_SETTLE, reason=reason, **read.attrs)
+            with tracer.span(spans.SPAN_STEP_EMIT, step=self._steps):
+                self._emit(rec, read)
+            self._settles[reason] += 1
+            self._settle_counter.labels(reason=reason).inc()
+        self._read_prefills(tracer, late=False)
         return True
 
     # ---------------------------------------------------------- driving
     def pump(self, wait_s: float = 0.0) -> bool:
-        """One cycle: admit, dispatch the next decode step, read and
-        emit the one before it.  True while there is work, a step in
-        flight included: a request's last token is emitted by the cycle
-        AFTER the one that dispatched it (drive with
-        :meth:`run_until_idle`, not with a counted number of pumps)."""
+        """One cycle: admit (the prefills dispatched, nothing read),
+        dispatch the next decode step, read and emit the one before it,
+        then read the cycle's prefills: their first tokens.  True while
+        there is work, a step in flight included: a request's last
+        token is emitted by the cycle AFTER the one that dispatched it
+        (drive with :meth:`run_until_idle`, not with a counted number of
+        pumps)."""
         with self._lock:
             # one look at the configuration a cycle; its spans share it
             self._tracer = obs.get_tracer()
             self._admit(wait_s=wait_s if not self.active_count() else 0.0)
             stepped = self._step()
+            # the prefills a settle has not read lie behind a step this
+            # cycle dispatched: no pump ends with one unread
+            self._read_prefills(self._tracer, late=True)
             return stepped or self._inflight is not None \
                 or bool(self._stash) or self.queue.depth() > 0
 
@@ -1009,6 +1088,10 @@ class LMEngine:
             "tokens": self._tokens_total,
             "steps": self._steps,
             "steps_ahead": self._steps_ahead,
+            # admissions, and those whose first result was read behind
+            # the next step's dispatch (all of them but a settle's)
+            "admitted": self._admitted,
+            "prefills_read_late": self._read_late,
             # the share of steps in which no running slot sampled: the
             # pick made one pass over the logits and drew nothing
             "greedy_step_share": (1.0 - self._steps_sampled / self._steps

@@ -29,9 +29,13 @@ Three families:
                           a request to place (``offered=``,
                           ``admitted=``); not ``serve.admit``, the point
                           event of one request entering a slot
-  ``serve.prefill``       inside it: the jitted prefill call and the
-                          read-back of its first token (``bucket=``,
-                          ``prompt_len=``, ``request=``)
+  ``serve.prefill``       inside it: the DISPATCH of one request's
+                          jitted prefill and the slot's bookkeeping,
+                          made from the prompt and the request alone
+                          (``bucket=``, ``prompt_len=``, ``request=``).
+                          Nothing is read inside it since PR 45: its
+                          length is a dispatch, and the benchmark's
+                          ``prefill_wall_ms`` (its median) with it
   ``serve.prep``          ``_step`` from its top to the dispatch: page
                           growth, preemption, host arrays, page tables
                           to the device, the key split (``bucket=``,
@@ -55,11 +59,17 @@ Three families:
                           no ``serve.decode_step`` before it, where a
                           step in flight is settled (``serve.settle``)
   ======================  ==============================================
-  ``serve.decode_step`` and ``serve.prefill`` each hold three children,
-  in this order and disjoint (the parent's length less theirs is the
-  cost of the spans themselves), that say what the HOST was doing.
-  Each carries ``step=`` (the cycle's, as ``serve.prep`` and
-  ``serve.admission`` carry it) and ``program="step"|"prefill"``:
+  What the HOST was doing with a program is said by three spans, each
+  with ``step=`` (the cycle's, as ``serve.prep`` and ``serve.admission``
+  carry it) and ``program="step"|"prefill"``.  ``serve.decode_step``
+  holds the three as children, in this order and disjoint (the parent's
+  length less theirs is the cost of the spans themselves);
+  ``serve.prefill`` holds its ``serve.dispatch`` alone, and a prefill's
+  ``serve.wait`` and ``serve.read`` stand where its result is read,
+  LATE: after the cycle's ``serve.emit``, inside no span of the cycle,
+  behind the dispatch of the cycle's step and before anything waits
+  for that step (a settle's stand behind the settled step's two and
+  its ``serve.emit``):
 
   ==================  ==================================================
   ``serve.dispatch``  host WORK: the host's arrays to the device, the
@@ -69,17 +79,32 @@ Three families:
                       (lengths, ``remaining``, ``unread``).  ``dry=`` 1
                       where, as the host was about to launch, nothing it
                       had launched before was still running (no step in
-                      flight, or its result ``is_ready()``: asked before
-                      the arrays are shipped, without blocking): the
-                      chip was waiting for the host, as ``ahead=1`` says
-                      the host was not waiting for the chip
+                      flight and no prefill unread, or their results
+                      ``is_ready()``: asked before the arrays are
+                      shipped, without blocking): the chip was waiting
+                      for the host, as ``ahead=1`` says the host was
+                      not waiting for the chip.  A step dispatched
+                      behind a prefill that has not finished is not
+                      dry, so the benchmark's ``serve_dry_steps.admit``
+                      is no longer the share of cycles that admit: it
+                      is the share of steps that admitted AND still
+                      found the chip dry
   ``serve.wait``      host SLACK: the one blocking read of a dispatched
                       program's result and nothing else; a prefill's
-                      also holds what was left of the step in flight
+                      holds what is left of the prefill once the step
+                      behind it is dispatched and the step before it
+                      emitted (often nothing)
   ``serve.read``      host WORK: what the host does with the array once
                       it has it: the per-slot loop over the step's
                       slots, the draft and block counters, the routing
-                      counts; a prefill's ``int()`` of its token
+                      counts; a prefill's: the kind's bookkeeping, the
+                      first token to its request, stamped, and the end
+                      of a request whose first token was its last
+                      (``request=``; ``late=`` 1 where a step was
+                      dispatched behind the prefill before this read, 0
+                      where a settle read it:
+                      ``stats()["prefills_read_late"]`` counts the
+                      ones, against ``["admitted"]``)
   ==================  ==================================================
   A step's ``serve.wait`` and ``serve.read`` lie in the
   ``serve.decode_step`` of the NEXT step, which reads it (as the
@@ -87,8 +112,9 @@ Three families:
   ``serve.dispatch`` only.  A settled step's two are top-level spans
   before the settle's ``serve.emit`` and carry the SETTLED step's own
   ``step``: every executed step is waited for and read exactly once.
-  An **expert model**'s ``serve.decode_step`` and ``serve.prefill``
-  also carry what a step routed, summed over its expert layers
+  An **expert model**'s ``serve.decode_step`` and a prefill's
+  ``serve.read`` also carry what the program routed, summed over its
+  expert layers
   (``nn/experts.py`` ``COUNT_NAMES``; the counts ride back from the
   device with the tokens, in the same transfer, so a
   ``serve.decode_step`` carries those of the step it READ, k-1, and a
@@ -243,7 +269,8 @@ HOP_ORDER = ("queue", "placement", "retry", "prefill", "decode",
 SPAN_STEP_DECODE = "serve.decode_step"
 #: placing queued requests into free slots (contains SPAN_STEP_PREFILL)
 SPAN_ADMISSION = "serve.admission"
-#: one request's jitted prefill and the read-back of its first token
+#: the dispatch of one request's jitted prefill (its result is read
+#: late: SPAN_STEP_WAIT / SPAN_STEP_READ with ``program="prefill"``)
 SPAN_STEP_PREFILL = "serve.prefill"
 #: host work of a decode step before its dispatch
 SPAN_STEP_PREP = "serve.prep"
@@ -251,9 +278,10 @@ SPAN_STEP_PREP = "serve.prep"
 SPAN_STEP_EMIT = "serve.emit"
 #: inside a decode step or a prefill: launching the program (``dry=``)
 SPAN_STEP_DISPATCH = "serve.dispatch"
-#: inside them: the blocking read of a dispatched program's result
+#: the blocking read of a dispatched program's result (a step's: inside
+#: the next decode step; a prefill's or a settled step's: in no span)
 SPAN_STEP_WAIT = "serve.wait"
-#: inside them: the host's work on the array it has read
+#: behind it: the host's work on the array it has read
 SPAN_STEP_READ = "serve.read"
 
 # ------------------------------------------------------------ point events

@@ -15,6 +15,7 @@ import collections
 import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -44,22 +45,23 @@ class _Active:
     the tokens not yet DISPATCHED (a dispatched step is taken to yield
     the kind's ``sure`` tokens until it is read, one step late) and
     ``left`` those not yet EMITTED: the two agree whenever nothing is in
-    flight.  ``first_token`` (and ``first_draft``) is the prefill's
-    until the slot's first step has taken it from the host, then None:
-    the slot's input is the last step's output, on the device (under
-    :class:`Block` it only marks the slot as fresh; ``block`` is the
-    host's view).  ``unread`` is 1 while a step the slot ran in is
-    unread; ``last_pos`` is the last position it can ever write at."""
+    flight.  It is made at the prefill's DISPATCH, from the prompt and
+    the request alone: what the prefill yields (the first token;
+    ``block``, the host's view under :class:`Block`) comes with the late
+    read of its result (``prefilled``).  ``fresh`` is True until the
+    slot's first step has taken from the host what the host knows of a
+    slot admitted since the last step (its length, its owed count);
+    what the prefill computed for that step is on the device already, in
+    the carry.  ``unread`` is 1 while a step the slot ran in is unread;
+    ``last_pos`` is the last position it can ever write at."""
 
-    __slots__ = ("req", "remaining", "left", "first_token", "first_draft",
-                 "last_pos", "unread", "order", "block")
+    __slots__ = ("req", "remaining", "left", "fresh", "last_pos", "unread",
+                 "order", "block")
 
-    def __init__(self, req, remaining, first_token, prompt_len, order,
-                 first_draft=None):
+    def __init__(self, req, remaining, prompt_len, order):
         self.req = req
         self.remaining = self.left = remaining
-        self.first_token = first_token
-        self.first_draft = first_draft
+        self.fresh = True
         self.block: Optional[_Block] = None
         self.last_pos = prompt_len + remaining
         self.unread = 0
@@ -137,8 +139,10 @@ class OneToken:
     ``paged_decode(params, caches, tables, lengths, tokens, active,
     ...)`` each return ``(caches, logits, counts)``, ``counts`` the
     step's expert-routing counts or None.  A step's tokens are the next
-    step's input and never visit the host on the way (a slot admitted
-    since is overridden from the host inside the program); the host's
+    step's input and never visit the host on the way, and neither does
+    a prefill's first token: the prefill writes it into the slot's row
+    of the same array (``handed``), so the step behind an admission is
+    dispatched before anything of the prefill is read.  The host's
     lengths and owed counts advance by one at dispatch.
 
     **With state a slot carries that is not keys and values**
@@ -162,8 +166,10 @@ class OneToken:
     #: slot a step forwards; where, in a step's outputs behind the
     #: cache's buffers, what the host reads begins (behind the next
     #: step's carry, or with it where the tokens are both); why this
-    #: kind serves no temperature (None: it serves any)
-    sure, positions, result_at, greedy_because = 1, 1, 0, None
+    #: kind serves no temperature (None: it serves any); how many of
+    #: the carry's leading arrays a prefill is handed, to write the
+    #: fresh slot's row of: what the step behind it needs of its result
+    sure, positions, result_at, greedy_because, handed = 1, 1, 0, None, 1
     # tallies (``stats``): 0 under the kinds that do not count one
     verified = accepted = passes = tails = unmasked = state_rebuilds = 0
 
@@ -199,12 +205,9 @@ class OneToken:
 
         def step(params, *rest):
             # rest: the cache's buffers (donated), then tables, lengths,
-            # prev (the last step's tokens, still on the device), the
-            # host's tokens and fresh (the slots that take theirs from
-            # the host: admitted since that step), temps, active, key
-            tables, lengths, prev, tokens, fresh, temps, active, key = \
-                rest[n:]
-            tokens = jnp.where(fresh, tokens, prev)
+            # the tokens on the device (the last step's, and in the row
+            # of a slot admitted since its prefill's), temps, active, key
+            tables, lengths, tokens, temps, active, key = rest[n:]
             if n > pools:
                 caches, logits, counts, state = model.paged_decode(
                     params, rest[:pools], tables, lengths, tokens,
@@ -233,36 +236,42 @@ class OneToken:
         def prefill(params, *rest):
             # rest: the cache's buffers (donated), then the prompt
             # (1, bucket) zero-padded past t0, t0, the bucket's pages,
-            # the temperature, the key; with state, the slot it is for
-            prompt, t0, pages, temp, key = rest[n:n + 5]
+            # the temperature, the key, the slot it is for, and the
+            # tokens on the device, whose row of the slot it writes
+            prompt, t0, pages, temp, key, slot, tokens = rest[n:]
             if n > pools:
                 caches, logits, counts, rows = model.paged_prefill(
                     params, rest[:pools], prompt, t0, pages)
                 caches = (*caches, *ops.write_slot_state(
-                    rest[pools:n], rest[n + 5], rows))
+                    rest[pools:n], slot, rows))
             else:
                 caches, logits, counts = model.paged_prefill(
                     params, rest[:n], prompt, t0, pages)
             first = ops.sample_first(logits, temp, key)
-            return (*caches, first) if counts is None \
-                else (*caches, first, counts)
+            out = (*caches, tokens.at[slot].set(first), first)
+            return out if counts is None else (*out, counts)
 
         return prefill
 
-    def _picked_prefill(self, pack):
+    def _picked_prefill(self):
         """The prefill of a model that picks inside its forward: greedy,
-        so the temperature and the key are not read; ``pack`` makes the
-        one array the host reads of what the model hands back between
-        the caches and the counts."""
+        so the temperature and the key are not read.  What the model
+        hands back between the caches and the counts is, leaf by leaf,
+        the slot's row of each array the prefill is handed
+        (``handed``); the host reads the same rows, stacked."""
         model, pick, n = self.model, self.ops.pick, len(self.cache.buffers())
 
         def prefill(params, *rest):
             prompt, t0, pages = rest[n:n + 3]
+            slot, *handed = rest[n + 5:]
             caches, *first, counts = model.paged_prefill(
                 params, rest[:n], prompt, t0, pages, pick=pick)
-            first = pack(*first)
-            return (*caches, first) if counts is None \
-                else (*caches, first, counts)
+            rows = jax.tree.leaves(first)
+            handed = [arr.at[slot].set(row.astype(arr.dtype))
+                      for arr, row in zip(handed, rows)]
+            out = (*caches, *handed,
+                   jnp.stack([row.astype(jnp.int32) for row in rows]))
+            return out if counts is None else (*out, counts)
 
         return prefill
 
@@ -273,11 +282,11 @@ class OneToken:
                 f"{type(self.model).__name__} {self.greedy_because}: "
                 f"temperature {temperature:g} is not served (give 0)")
 
-    def begin_prefill(self, slot: int, req, tracer, span_id) -> tuple:
+    def begin_prefill(self, req, tracer, span_id):
         """Inside an open ``serve.prefill``: what it says of the slot's
-        state; -> the prefill's arguments behind the engine's (the slot)."""
+        state."""
         if not self.cache.state:
-            return ()
+            return
         tracer.add_attrs(span_id, state_bytes=self.slot_state_bytes)
         if req.preempted:
             # its whole past is computed again: the state is rebuilt
@@ -285,28 +294,28 @@ class OneToken:
             tracer.add_attrs(span_id, rebuilt=1)
             self.state_rebuilds += 1
             self._rebuild_counter.inc()
-        return (np.int32(slot),)
 
-    def admit(self, slot: int, req, first, order: int):
-        """What the prefill's first result means -> the slot's
-        bookkeeping, the token to emit now (None: none yet)."""
-        tok = int(first)
-        return _Active(req, req.max_new_tokens - 1, tok, len(req.payload),
-                       order), tok
+    def admit(self, slot: int, req, order: int) -> _Active:
+        """At the prefill's dispatch -> the slot's bookkeeping, from the
+        prompt and the request alone: the prefill yields the first
+        token, so the steps owe one fewer."""
+        return _Active(req, req.max_new_tokens - 1, len(req.payload), order)
+
+    def prefilled(self, act: _Active, first):
+        """At the late read of the prefill's first result, before the
+        read of any step dispatched behind it: what the result means ->
+        the token to emit (None: none yet)."""
+        return int(first)
 
     def host_args(self, acts, key) -> tuple:
-        """The step's arguments behind the carry, as host arrays: a
-        slot admitted since the last step takes its input from them."""
+        """The step's arguments behind the carry, as host arrays: what
+        the host knows of a slot without reading anything back."""
         b = self.cache.max_slots
-        tokens, fresh = np.zeros((b,), np.int32), np.zeros((b,), bool)
         temps, active = np.zeros((b,), np.float32), np.zeros((b,), bool)
         for i, act in acts:
-            if act.first_token is not None:
-                fresh[i], tokens[i] = True, act.first_token
-                act.first_token = None
             temps[i] = act.req.temperature
             active[i] = True
-        return tokens, fresh, temps, active, key
+        return temps, active, key
 
     def ahead(self, act: _Active, length: int) -> int:
         """How far past its (lower-bound) ``length`` the slot's next
@@ -384,10 +393,12 @@ class Drafting(OneToken):
     owed, active, pick=)`` -> ``(caches, picked (B, 2), accepted (B,),
     next_draft (B,), counts)``.  What a step yielded is known on the
     device a step before the host reads it, so a slot's next token,
-    next draft, length and owed count are carried there; the host keeps
-    bounds and reconciles at the read."""
+    next draft, length and owed count are carried there (a prefill
+    writes a fresh slot's token and draft); the host keeps bounds and
+    reconciles at the read."""
 
     result_at = 4   # behind the token, the draft, the length, the owed count
+    handed = 2      # the token and the draft
     greedy_because = ("verifies its own drafts by exact match against "
                       "the greedy token")
 
@@ -408,13 +419,12 @@ class Drafting(OneToken):
         def step(params, *rest):
             # rest: the cache's buffers (donated), then tables, the
             # host's lengths, the four arrays the last step carried
-            # (token, draft, length, owed), the host's values of the
-            # four for the slots in fresh (admitted since that step),
-            # active
-            (tables, h_len, c_tok, c_draft, c_len, c_owed,
-             h_tok, h_draft, h_owed, fresh, active) = rest[n:]
-            tok = jnp.where(fresh, h_tok, c_tok)
-            draft = jnp.where(fresh, h_draft, c_draft)
+            # (token, draft, length, owed; a prefill since wrote its
+            # slot's token and draft), the host's owed counts: its
+            # lengths and those for the slots in fresh (admitted since
+            # that step), active
+            (tables, h_len, tok, draft, c_len, c_owed,
+             h_owed, fresh, active) = rest[n:]
             length = jnp.where(fresh, h_len, c_len)
             owed = jnp.where(fresh, h_owed, c_owed)
             # the host runs a slot while it MAY owe a token; the count
@@ -438,26 +448,20 @@ class Drafting(OneToken):
 
     def prefill(self):
         # the first token comes with the first draft
-        return self._picked_prefill(lambda first, draft:
-                                    jnp.stack([first, draft]))
+        return self._picked_prefill()
 
-    def admit(self, slot: int, req, first, order: int):
-        tok, draft = (int(t) for t in first)
-        return _Active(req, req.max_new_tokens - 1, tok, len(req.payload),
-                       order, first_draft=draft), tok
+    def prefilled(self, act: _Active, first):
+        return int(first[0])
 
     def host_args(self, acts, key) -> tuple:
         b = self.cache.max_slots
-        tokens, drafts = np.zeros((b,), np.int32), np.zeros((b,), np.int32)
         owed = np.zeros((b,), np.int32)
         fresh, active = np.zeros((b,), bool), np.zeros((b,), bool)
         for i, act in acts:
-            if act.first_token is not None:
-                fresh[i], tokens[i] = True, act.first_token
-                drafts[i], owed[i] = act.first_draft, act.left
-                act.first_token = act.first_draft = None
+            if act.fresh:
+                fresh[i], owed[i], act.fresh = True, act.left, False
             active[i] = True
-        return tokens, drafts, owed, fresh, active
+        return owed, fresh, active
 
     def ahead(self, act: _Active, length: int) -> int:
         # a step writes a second row, and a step not yet read may have
@@ -523,13 +527,14 @@ class Block(OneToken):
     DEVICE (the block becomes the pending tail, whose final rows the
     slot's next forward writes; ``lengths + B``; a new block all
     masked) and says so in ``kind``.  The state is carried from step to
-    step untouched; the host keeps bounds and reconciles at the read.
+    step untouched (a prefill writes a fresh slot's block and mask
+    flags into it); the host keeps bounds and reconciles at the read.
     **Emission is by prefix**: a token goes to its request by the step
     after which it and every position before it are final, so a step
     yields 0 to ``B`` tokens a slot; ``ServeRequest.unmasked`` keeps
     every generated position's token and the pass that unmasked it."""
 
-    sure, result_at = 0, 6
+    sure, result_at, handed = 0, 6, 2   # handed: block tokens, mask flags
     greedy_because = ("unmasks a block's positions by the confidence of "
                       "the greedy token")
 
@@ -555,14 +560,12 @@ class Block(OneToken):
             # rest: the cache's buffers (donated), then tables, the
             # host's lengths, the six arrays the last step carried
             # (block tokens, mask flags, pass count, length, the
-            # tail's tokens, whether a tail is pending), the host's
-            # block for the slots in fresh (admitted since that
-            # step; their pass count is 0 and no tail is pending),
-            # active
-            (tables, h_len, c_tok, c_mask, c_pass, c_len, c_tail, c_pend,
-             h_tok, h_mask, fresh, active) = rest[n:]
-            tok = jnp.where(fresh[:, None], h_tok, c_tok)
-            mask = jnp.where(fresh[:, None], h_mask, c_mask)
+            # tail's tokens, whether a tail is pending; a prefill since
+            # wrote its slot's block and flags), the slots in fresh
+            # (admitted since that step: the host's length, pass count
+            # 0 and no tail pending), active
+            (tables, h_len, tok, mask, c_pass, c_len, c_tail, c_pend,
+             fresh, active) = rest[n:]
             done = jnp.where(fresh, 0, c_pass)
             length = jnp.where(fresh, h_len, c_len)
             pend = c_pend & ~fresh
@@ -593,32 +596,32 @@ class Block(OneToken):
     def prefill(self):
         # no token is picked: the prompt's whole blocks are cached, and
         # the first block's tokens and mask flags come back
-        return self._picked_prefill(lambda block: jnp.stack(
-            [block[0], block[1].astype(block[0].dtype)]))
+        return self._picked_prefill()
 
-    def admit(self, slot: int, req, first, order: int):
-        # the slot's length is its block's first position; every
-        # generated position so far is on record (a preemption folded
-        # them into the prompt)
+    def admit(self, slot: int, req, order: int) -> _Active:
+        # the slot's length is its block's first position, and the
+        # prefill yields no token
         b, t0 = self.block, len(req.payload)
         self.cache.lengths[slot] = t0 - t0 % b
-        act = _Active(req, req.max_new_tokens, -1, t0, order)
+        act = _Active(req, req.max_new_tokens, t0, order)
         act.last_pos = -(-(t0 + req.max_new_tokens) // b) * b - 1
-        act.block = _Block(first[0], first[1], origin=t0 - len(req.unmasked))
-        return act, None
+        return act
+
+    def prefilled(self, act: _Active, first):
+        # the host's view of the first block, which the read of the
+        # step behind the prefill needs; every generated position so
+        # far is on record (a preemption folded them into the prompt)
+        req = act.req
+        act.block = _Block(first[0], first[1],
+                           origin=len(req.payload) - len(req.unmasked))
+        return None
 
     def host_args(self, acts, key) -> tuple:
         b = self.cache.max_slots
-        tokens = np.zeros((b, self.block), np.int32)
-        masked = np.zeros((b, self.block), bool)
         fresh, active = np.zeros((b,), bool), np.zeros((b,), bool)
         for i, act in acts:
-            if act.first_token is not None:
-                fresh[i] = True
-                tokens[i], masked[i] = act.block.tokens, act.block.masked
-                act.first_token = None
-            active[i] = True
-        return tokens, masked, fresh, active
+            fresh[i], act.fresh, active[i] = act.fresh, False, True
+        return fresh, active
 
     def ahead(self, act: _Active, length: int) -> int:
         # a step writes its block's rows, and every step not yet read

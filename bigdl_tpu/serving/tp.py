@@ -71,14 +71,12 @@ def build_tp_decode_step(model, *, tp: int, wire=None, page_size: int,
                          max_batch: int, positions: int):
     """The engine's decode step, sharded ``tp`` ways on the first
     ``tp`` local devices.  Same signature as the single-host step:
-    ``step(params, kp, vp, tables, lengths, prev, tokens, fresh,
-    temps, active, key) -> (kp, vp, next_tokens)`` with replicated
-    params/cache accepted (GSPMD reshards on first call); a slot's
-    input is ``prev`` (the last step's tokens, still on the devices)
-    unless ``fresh`` says the host's.  The attention body sees the
-    LOCAL head shard."""
+    ``step(params, kp, vp, tables, lengths, tokens, temps, active,
+    key) -> (kp, vp, next_tokens)`` with replicated params/cache
+    accepted (GSPMD reshards on first call); a slot's input is its row
+    of ``tokens`` (the last step's, or a prefill's since, still on the
+    devices).  The attention body sees the LOCAL head shard."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
     from bigdl_tpu.optim.distri_optimizer import _shard_map
@@ -128,9 +126,7 @@ def build_tp_decode_step(model, *, tp: int, wire=None, page_size: int,
                   P(), P()),
         out_specs=(cache_spec, cache_spec, P()))
 
-    def step(params, kp, vp, tables, lengths, prev, tokens, fresh,
-             temps, active, key):
-        tokens = jnp.where(fresh, tokens, prev)
+    def step(params, kp, vp, tables, lengths, tokens, temps, active, key):
         return mapped(params, kp, vp, tables, lengths, tokens, temps,
                       active, jax.random.key_data(key))
 

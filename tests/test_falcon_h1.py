@@ -604,13 +604,13 @@ def test_step_programs_carry_the_scopes_and_no_guard_of_the_state(roomy):
     z = jnp.zeros((3,), jnp.int32)
     no = jnp.zeros((3,), bool)
     step = eng._step_fn.lower(
-        eng.params, *eng.cache.buffers(), tables, lengths, z, z, no,
+        eng.params, *eng.cache.buffers(), tables, lengths, z,
         jnp.zeros((3,), jnp.float32), no,
         jax.random.key(0)).as_text(debug_info=True)
     pre = eng._prefill_fn(8).lower(
         eng.params, *eng.cache.buffers(), jnp.zeros((1, 8), jnp.int32), 5,
         jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
-        np.int32(1)).as_text(debug_info=True)
+        np.int32(1), z).as_text(debug_info=True)
     for scope in ("ssm.proj", "ssm.conv", "ssm.scan", "gqa.attn", "ffn",
                   "kv_write", "dense", "sample"):
         assert f"/{scope}/" in step, scope

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import late_read_cases
+
 from benchmarks.reference import joyai_llm_flash as ref
 from bigdl_tpu import obs
 from bigdl_tpu.models.joyai_flash import JoyAIFlash, build_joyai_flash
@@ -550,12 +552,12 @@ def test_step_programs_carry_the_scopes_and_the_prediction_layers():
     z = jnp.zeros((2,), jnp.int32)
     no = jnp.zeros((2,), bool)
     step = eng._step_fn.lower(
-        eng.params, eng.cache.kp, tables, lengths, z, z, z, z, z, z, z,
+        eng.params, eng.cache.kp, tables, lengths, z, z, z, z, z,
         no, no).as_text(debug_info=True)
     pre = eng._prefill_fn(8).lower(
         eng.params, eng.cache.kp, jnp.zeros((1, 8), jnp.int32), 5,
-        jnp.zeros((2,), jnp.int32), 0.0,
-        jax.random.key(1)).as_text(debug_info=True)
+        jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
+        np.int32(1), z, z).as_text(debug_info=True)
     for text in (step, pre):
         for scope in ("mla.proj", "kv_write", "mla.attn", "ffn",
                       "moe.route", "moe.experts", "dense", "sample"):
@@ -585,3 +587,20 @@ def test_int8_and_tp_are_refused_with_a_reason(kw, what):
     with pytest.raises(ValueError, match="JoyAIFlash does not offer "
                        + what):
         LMEngine(model, params=params, **kw)
+
+
+# ------------------------------------------------ a prefill is read late
+# (PR 45) ``tests/late_read_cases.py``'s cases under this file's kind
+# of step: ``Drafting``
+@pytest.fixture(scope="module")
+def late():
+    with jax.default_matmul_precision("highest"):
+        model, params, _ = make((4, 12), seed=3)
+        return late_read_cases.prepare(
+            lambda **kw: LMEngine(model, params=params, page_size=4, **kw),
+            [[int(t) for t in tokens_of(n, 40 + n)] for n in (5, 7, 3, 6)])
+
+
+@pytest.mark.parametrize("case", sorted(late_read_cases.ALL_CASES))
+def test_a_prefill_read_late(late, case):
+    late_read_cases.ALL_CASES[case](*late)

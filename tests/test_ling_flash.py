@@ -772,10 +772,17 @@ def test_spans_say_the_state_the_routing_and_the_groups_share(
                        key=lambda s: s["wall_time"])
         prefills = [s for s in spans if s["name"] == S.SPAN_STEP_PREFILL]
         assert len(prefills) == 2
-        for s, n in zip(sorted(prefills, key=lambda s: s["wall_time"]),
-                        (5, 7)):
+        # a prefill's counts come back with its first token, read late:
+        # they ride on its ``serve.read``, in the prefills' order
+        reads = [s for s in spans if s["name"] == S.SPAN_STEP_READ
+                 and s["attrs"]["program"] == "prefill"]
+        for s, r, n in zip(sorted(prefills, key=lambda s: s["wall_time"]),
+                           sorted(reads, key=lambda s: s["wall_time"]),
+                           (5, 7)):
             a = s["attrs"]
             assert a["state_bytes"] == STATE_BYTES and "rebuilt" not in a
+            assert r["attrs"]["request"] == a["request"]
+            a = r["attrs"]
             assert a["moe_held"] + a["moe_absent"] == 3 * 4 * n
             assert 0.0 <= a["moe_group_hit_share"] <= 1.0
         # a step's numbers ride on the span of the step that read them:
@@ -799,13 +806,13 @@ def test_step_programs_carry_the_scopes_and_no_guard_of_the_state(roomy):
     z = jnp.zeros((3,), jnp.int32)
     no = jnp.zeros((3,), bool)
     step = eng._step_fn.lower(
-        eng.params, *eng.cache.buffers(), tables, lengths, z, z, no,
+        eng.params, *eng.cache.buffers(), tables, lengths, z,
         jnp.zeros((3,), jnp.float32), no,
         jax.random.key(0)).as_text(debug_info=True)
     pre = eng._prefill_fn(8).lower(
         eng.params, *eng.cache.buffers(), jnp.zeros((1, 8), jnp.int32), 5,
         jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
-        np.int32(1)).as_text(debug_info=True)
+        np.int32(1), z).as_text(debug_info=True)
     shared = ("kda.proj", "kda.conv", "mla.proj", "mla.attn", "ffn",
               "moe.route", "moe.experts", "kv_write", "dense", "sample")
     for scope in shared + ("kda.state",):

@@ -478,7 +478,12 @@ def test_spans_carry_the_routing_counts_and_the_registry_counts_them(
         assert [e["attrs"]["reason"] for e in settles] == ["idle"]
         routed = [(s["attrs"], t["attrs"]["active"])
                   for s, t in zip(steps[1:] + settles, steps)]
-        routed += [(s["attrs"], s["attrs"]["prompt_len"])
+        # ... and a prefill's with its first token, read late: on its
+        # ``serve.read``
+        reads = {s["attrs"]["request"]: s["attrs"] for s in spans
+                 if s["name"] == S.SPAN_STEP_READ
+                 and s["attrs"]["program"] == "prefill"}
+        routed += [(reads[s["attrs"]["request"]], s["attrs"]["prompt_len"])
                    for s in prefills]
         total = {k: 0 for k in ("held", "zero", "absent")}
         for a, tokens in routed:
@@ -516,13 +521,13 @@ def test_step_programs_carry_the_new_scopes():
     z = jnp.zeros((2,), jnp.int32)
     no = jnp.zeros((2,), bool)
     step = eng._step_fn.lower(
-        eng.params, eng.cache.kp, tables, lengths, z, z, no,
+        eng.params, eng.cache.kp, tables, lengths, z,
         jnp.zeros((2,), jnp.float32), no,
         jax.random.key(0)).as_text(debug_info=True)
     pre = eng._prefill_fn(8).lower(
         eng.params, eng.cache.kp, jnp.zeros((1, 8), jnp.int32), 5,
-        jnp.zeros((2,), jnp.int32), 0.0,
-        jax.random.key(1)).as_text(debug_info=True)
+        jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
+        np.int32(1), z).as_text(debug_info=True)
     for scope in ("mla.proj", "kv_write", "mla.attn", "ffn", "moe.route",
                   "moe.experts", "moe.zero", "dense", "sample"):
         assert f"/{scope}/" in step, scope

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import late_read_cases
+
 from benchmarks.reference import sdar_30b_a3b_chat as ref
 from bigdl_tpu import obs
 from bigdl_tpu.models.sdar_moe import (FINISHED, REFINED, SDARMoE,
@@ -1172,7 +1174,7 @@ def test_step_programs_carry_the_scopes():
         text = eng._step_fn.lower(
             eng.params, eng.cache.kp, eng.cache.vp,
             jnp.zeros((b, 4), jnp.int32), ints, wide, wide.astype(bool),
-            ints, ints, wide, flags, wide, wide.astype(bool), flags, flags
+            ints, ints, wide, flags, flags, flags
         ).as_text(debug_info=True)
         for scope in ("gqa.attn", "unmask", "moe.route", "moe.experts",
                       "kv_write", "dense"):
@@ -1218,7 +1220,7 @@ def test_a_one_token_model_s_step_holds_no_kernel(dtype, monkeypatch):
         text = _lowered_for_a_chip(eng._step_fn, (
             jax.tree.map(_like, eng.params), _like(eng.cache.kp),
             _like(eng.cache.vp), jax.ShapeDtypeStruct((b, 4), jnp.int32),
-            ints, ints, ints, flags,
+            ints, ints,
             jax.ShapeDtypeStruct((b,), jnp.float32), flags,
             _like(jax.random.key(0))), monkeypatch)
     finally:
@@ -1254,8 +1256,8 @@ def test_the_block_step_holds_one_kernel_a_layer_and_gathers_no_pool(
         text = _lowered_for_a_chip(eng._step_fn, (
             jax.tree.map(_like, eng.params), *bufs,
             jax.ShapeDtypeStruct((b, 4), jnp.int32), ints, wide,
-            wide_flags, ints, ints, wide, flags, wide, wide_flags, flags,
-            flags), monkeypatch)
+            wide_flags, ints, ints, wide, flags, flags, flags),
+            monkeypatch)
     finally:
         eng.close()
     # a model's attentions share one traced program: one private
@@ -1278,3 +1280,21 @@ def test_the_block_step_holds_one_kernel_a_layer_and_gathers_no_pool(
     assert not re.findall(r"stablehlo\.dot_general[^\n]*tensor<\d+x\d+x%dx"
                           % VOCAB, text)
     assert not re.findall(r"tensor<%dx%dx" % (b * 2 * B, VOCAB), text)
+
+
+# ------------------------------------------------ a prefill is read late
+# (PR 45) ``tests/late_read_cases.py``'s cases under this file's kind
+# of step: ``Block``, whose prefill
+# yields no token (so no case about a first token)
+@pytest.fixture(scope="module")
+def late():
+    with jax.default_matmul_precision("highest"):
+        model, params, _ = make(7)
+        return late_read_cases.prepare(
+            lambda **kw: LMEngine(model, params=params, page_size=4, **kw),
+            [[int(t) for t in tokens_of(n, 40 + n)] for n in (5, 7, 3, 6)])
+
+
+@pytest.mark.parametrize("case", sorted(late_read_cases.CASES))
+def test_a_prefill_read_late(late, case):
+    late_read_cases.CASES[case](*late)

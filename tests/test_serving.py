@@ -9,6 +9,8 @@ batch, and across a page-exhaustion preemption."""
 import numpy as np
 import pytest
 
+import late_read_cases
+
 
 def _model(max_len=64):
     from bigdl_tpu.common import RandomGenerator
@@ -618,6 +620,64 @@ class TestOneStepInFlight:
         eng.close()
         assert len(req.tokens) == 2 and eng._inflight is None
         assert eng.stats()["settles"]["close"] == 1
+
+
+# ------------------------------------------- a prefill is read late
+class TestPrefillReadLate:
+    """The cases of ``tests/late_read_cases.py`` under ``OneToken``, and
+    what only a host that counts pages by hand can set up."""
+
+    PROMPTS = [list(np.random.RandomState(n).randint(0, 48, (n,)))
+               for n in (5, 7, 3, 6)]
+
+    @pytest.fixture(scope="class")
+    def late(self, lm_model):
+        from bigdl_tpu.serving import LMEngine
+
+        return late_read_cases.prepare(
+            lambda **kw: LMEngine(lm_model, page_size=4, **kw),
+            self.PROMPTS)
+
+    @pytest.fixture(scope="class")
+    def factory(self, late):
+        return late[0]
+
+    def test_alone_is_generate(self, lm_model, lm_params, late):
+        for prompt, tokens in zip(*late[1:]):
+            assert prompt + tokens == _ref(lm_model, lm_params, prompt,
+                                           len(tokens))
+
+    @pytest.mark.parametrize("case", sorted(late_read_cases.ALL_CASES))
+    def test_case(self, late, case):
+        late_read_cases.ALL_CASES[case](*late)
+
+    def test_a_preemption_in_the_cycle_of_an_admission_folds_the_first_token(
+            self, factory, lm_model, lm_params):
+        """Four pages: A holds two and B's prompt of eight fills the
+        other two, so B's first step finds no page for its row in the
+        very cycle that admitted B.  The settle before the preemption
+        reads B's prefill, and the fold finds B's first token."""
+        eng = factory(num_pages=5)
+        pa, pb = [3, 7, 11, 2, 9, 1], [5, 1, 4, 8, 8, 2, 6, 1]
+        a = eng.submit(pa, 10)
+        assert eng.pump() and eng.cache.free_pages() == 2
+        b = eng.submit(pb, 6)
+        assert eng.pump()
+        assert b.preempted == 1 and list(eng._stash) == [b]
+        assert len(b.tokens) == 1 and b.payload == pb + b.tokens
+        assert b.max_new_tokens == 5
+        st = eng.stats()
+        assert st["admitted"] == 2 and st["prefills_read_late"] == 1
+        assert st["settles"]["preempt"] == 1
+        eng.run_until_idle(120)
+        eng.close()
+        for prompt, req, n in ((pa, a, 10), (pb, b, 6)):
+            assert req.done and req.error is None
+            assert prompt + [int(t) for t in req.tokens] == \
+                _ref(lm_model, lm_params, prompt, n)
+        st = eng.stats()
+        # every admission but the one the settle read
+        assert st["prefills_read_late"] == st["admitted"] - 1 == 2
 
 
 # ----------------------------------------------- the used-page bucket

@@ -286,14 +286,14 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
     key = like(jax.random.key(0))
     programs = {"step": eng._step_fn.lower(
         weights, kp, vp, spec((b, 32), jnp.int32), spec((b,), jnp.int32),
-        spec((b,), jnp.int32), spec((b,), jnp.int32),
-        spec((b,), jnp.bool_), spec((b,), jnp.float32),
+        spec((b,), jnp.int32), spec((b,), jnp.float32),
         spec((b,), jnp.bool_), key)}
     for bucket in (128, 256):
         programs[f"prefill{bucket}"] = eng._prefill_fn(bucket).lower(
             weights, kp, vp, spec((1, bucket), jnp.int32),
             spec((), jnp.int32), spec((bucket // page,), jnp.int32),
-            spec((), jnp.float32), key)
+            spec((), jnp.float32), key, spec((), jnp.int32),
+            spec((b,), jnp.int32))
     eng.close()
 
     dims = ",".join(str(n) for n in eng.cache.kp.shape)
@@ -431,12 +431,12 @@ def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     programs = {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, spec((b, 128), jnp.int32), spec((b,), jnp.int32),
-            spec((b,), jnp.int32), spec((b,), jnp.int32),
-            spec((b,), jnp.bool_), spec((b,), jnp.float32),
+            spec((b,), jnp.int32), spec((b,), jnp.float32),
             spec((b,), jnp.bool_), key),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, spec((1, 256), jnp.int32), spec((), jnp.int32),
-            spec((256 // page,), jnp.int32), spec((), jnp.float32), key)}
+            spec((256 // page,), jnp.int32), spec((), jnp.float32), key,
+            spec((), jnp.int32), spec((b,), jnp.int32))}
     buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
     for name, lowered in programs.items():
         # the expert layer's grouped products are the Pallas kernel (two
@@ -551,10 +551,11 @@ def test_draft_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     programs = {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, spec((slots, 128), jnp.int32), ints,
-            ints, ints, ints, ints, ints, ints, ints, flags, flags),
+            ints, ints, ints, ints, ints, flags, flags),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, spec((1, 256), jnp.int32), spec((), jnp.int32),
-            spec((256 // page,), jnp.int32), spec((), jnp.float32), key)}
+            spec((256 // page,), jnp.int32), spec((), jnp.float32), key,
+            spec((), jnp.int32), ints, ints)}
     buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
     for name, lowered in programs.items():
         assert lowered.as_text().count("tpu_custom_call") >= 2, name
@@ -617,12 +618,12 @@ def test_block_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     programs = {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, buf, spec((slots, 128), jnp.int32), ints,
-            wide, wide_flags, ints, ints, wide, flags, wide, wide_flags,
-            flags, flags),
+            wide, wide_flags, ints, ints, wide, flags, flags, flags),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, buf, spec((1, 256), jnp.int32),
             spec((), jnp.int32), spec((256 // page,), jnp.int32),
-            spec((), jnp.float32), key)}
+            spec((), jnp.float32), key, spec((), jnp.int32), wide,
+            wide_flags)}
     buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
     for name, lowered in programs.items():
         text = lowered.as_text()
@@ -729,11 +730,11 @@ def test_state_engine_programs_work_on_the_cache_as_it_lies(one_chip,
     programs = {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, buf, *state, spec((slots, 128), jnp.int32), ints,
-            ints, ints, flags, spec((slots,), jnp.float32), flags, key),
+            ints, spec((slots,), jnp.float32), flags, key),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, buf, *state, spec((1, 256), jnp.int32),
             spec((), jnp.int32), spec((256 // page,), jnp.int32),
-            spec((), jnp.float32), key, spec((), jnp.int32))}
+            spec((), jnp.float32), key, spec((), jnp.int32), ints)}
     buffer_bytes = 2 * functools.reduce(lambda a, n: a * n, buf.shape)
     for name, lowered in programs.items():
         text = lowered.as_text()
@@ -825,11 +826,11 @@ def _hybrid_programs(one_chip, layers, slots=128):
     return {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, buf, *state, spec((slots, 128), jnp.int32), ints,
-            ints, ints, flags, spec((slots,), jnp.float32), flags, key),
+            ints, spec((slots,), jnp.float32), flags, key),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, buf, *state, spec((1, 256), jnp.int32),
             spec((), jnp.int32), spec((256 // page,), jnp.int32),
-            spec((), jnp.float32), key, spec((), jnp.int32))}, buf, state
+            spec((), jnp.float32), key, spec((), jnp.int32), ints)}, buf, state
 
 
 @pytest.mark.slow
@@ -948,11 +949,11 @@ def test_kda_engine_programs_work_on_cache_and_state_as_they_lie(
     programs = {
         "step": LMEngine._build_step(eng).lower(
             weights, buf, *state, spec((slots, 128), jnp.int32), ints, ints,
-            ints, flags, spec((slots,), jnp.float32), flags, key),
+            spec((slots,), jnp.float32), flags, key),
         "prefill256": LMEngine._prefill_fn(eng, 256).lower(
             weights, buf, *state, spec((1, 256), jnp.int32),
             spec((), jnp.int32), spec((256 // page,), jnp.int32),
-            spec((), jnp.float32), key, spec((), jnp.int32))}
+            spec((), jnp.float32), key, spec((), jnp.int32), ints)}
     for name, lowered in programs.items():
         text = lowered.as_text()
         if name == "step":
@@ -998,15 +999,20 @@ def test_kda_engine_programs_work_on_cache_and_state_as_they_lie(
 # ``keeps_inactive``), with the hashes PR 41's tree gives.  PR 44 meant
 # to change NONE of the six (``nn/experts.py`` and ``nn/latent.py``
 # gained options whose defaults trace the programs they traced) and
-# pinned the seventh, Ling-3.0-flash, with its own tree's.
+# pinned the seventh, Ling-3.0-flash, with its own tree's.  PR 45 meant
+# to change ALL fourteen, and their arguments: every prefill takes the
+# slot and what of the carry it writes the slot's row of, and every
+# step takes a fresh slot's input from there and no longer from the
+# host; the models' own mathematics is as it was (the served tokens
+# equal the parent's: ``tests/test_serving.py``).
 LOWERED = {
-    "tiny_gpt": ("ecfcedf2071ee787", "46c449c19d770f24"),
-    "tiny_longcat": ("8915adb05bc5a3eb", "1d9aa96cdb0e528a"),
-    "tiny_joyai": ("1f52c73cb02fe133", "cc5edd3f0a3bdc24"),
-    "tiny_sdar": ("6dd9578b515609a5", "97b140ade177e8e2"),
-    "tiny_zaya": ("792116b44675ebac", "35e5c200d9a20b14"),
-    "tiny_falcon_h1": ("dd5e4933b5bc20de", "f0c7c89cc405a2ea"),
-    "tiny_ling": ("52a7d173fcc09d24", "8a1ca5abc25ad51b"),
+    "tiny_gpt": ("10925615571aa732", "defaf580cc907f61"),
+    "tiny_longcat": ("fd1e74384430ef10", "cbd0b59cc7e05e3c"),
+    "tiny_joyai": ("8c80e5206f2620cb", "cd0c2e5e5fdb97fe"),
+    "tiny_sdar": ("852913276eb1dc96", "5e27a46a9de4314b"),
+    "tiny_zaya": ("95eeaa57c6ba2a7b", "eefa0a1999f540d7"),
+    "tiny_falcon_h1": ("43c0f50921a88202", "9fbc61e0ba1b9d4d"),
+    "tiny_ling": ("f5cc2d97ad984648", "2361fdbd91f49a64"),
 }
 
 
@@ -1064,12 +1070,12 @@ def test_the_other_serving_models_programs_lower_unchanged(
             *eng._carry, *host).as_text()
     else:
         bucket = 2 * eng.page_size
-        extra = (np.int32(1),) if eng.cache.state else ()
+        # the slot, and what of the carry a prefill writes its row of
         text = eng._prefill_fn(bucket).lower(
             eng.params, *eng.cache.buffers(),
             jnp.zeros((1, bucket), jnp.int32), 5,
             jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
-            *extra).as_text()
+            np.int32(1), *eng._carry[:eng._kind.handed]).as_text()
     assert _sha(text) == LOWERED[name][program == "prefill"], (name, program)
 
 
@@ -1080,7 +1086,8 @@ def test_the_other_serving_models_programs_lower_unchanged(
 # every kind, and a request served to its end moves the tallies of its
 # engine's kind and no other's.
 STATS_KEYS = [
-    "requests", "tokens", "steps", "steps_ahead", "greedy_step_share",
+    "requests", "tokens", "steps", "steps_ahead", "admitted",
+    "prefills_read_late", "greedy_step_share",
     "tokens_per_step", "drafts_verified", "drafts_accepted",
     "draft_accept_share", "block_passes", "block_tails", "block_commits",
     "positions_unmasked", "tokens_per_forward", "tail_share", "settles",

@@ -508,10 +508,12 @@ class TestEngineSpans:
 
     def test_children_lie_inside_their_parent_in_order(self, traced,
                                                        lm_model):
-        """``serve.decode_step`` and ``serve.prefill`` are split into
-        dispatch, wait and read: children of the parent, in that order,
-        disjoint, with the cycle's ``step`` (a ``serve.decode_step``
-        has none: joined through the ``serve.prep`` before it)."""
+        """``serve.decode_step`` is split into dispatch, wait and read:
+        children of the parent, in that order, disjoint, with the
+        cycle's ``step`` (a ``serve.decode_step`` has none: joined
+        through the ``serve.prep`` before it).  ``serve.prefill`` holds
+        its dispatch alone: a prefill's wait and read are no one's
+        children, like a settled step's."""
         from bigdl_tpu.serving import spans as S
 
         eng, _ = _serve(lm_model, PROMPTS, 6)
@@ -522,10 +524,14 @@ class TestEngineSpans:
                 kids.setdefault(s["parent"], []).append(s)
         parents = [s for s in spans if s["name"] in (
             S.SPAN_STEP_DECODE, S.SPAN_STEP_PREFILL)]
-        # a settled step's wait and read are no one's children
+        # a settled step's wait and read are no one's children, nor a
+        # prefill's: they lie inside no span of the cycle at all
+        st = eng.stats()
+        loose = kids.pop(None)
+        assert len(loose) == 2 * (sum(st["settles"].values())
+                                  + st["admitted"])
         assert sum(len(v) for v in kids.values()) == \
-            sum(len(kids.get(p["id"], ())) for p in parents) \
-            + 2 * sum(eng.stats()["settles"].values())
+            sum(len(kids.get(p["id"], ())) for p in parents)
         step_of = None
         for s in spans:
             if s["name"] == S.SPAN_STEP_PREP:
@@ -535,10 +541,10 @@ class TestEngineSpans:
             mine = kids[s["id"]]
             prefill = s["name"] == S.SPAN_STEP_PREFILL
             names = [k["name"] for k in mine]
-            # the first step after an idle engine reads nothing
-            assert names == list(CHILDREN) or (
-                not prefill and not s["attrs"]["ahead"]
-                and names == [S.SPAN_STEP_DISPATCH])
+            # a prefill is dispatched and no more; the first step after
+            # an idle engine reads nothing
+            assert names == [S.SPAN_STEP_DISPATCH] if prefill or \
+                not s["attrs"]["ahead"] else names == list(CHILDREN)
             end = s["wall_time"]
             for k in mine:
                 assert k["tid"] == s["tid"]
@@ -586,24 +592,70 @@ class TestEngineSpans:
         assert order == [S.SPAN_STEP_WAIT, S.SPAN_STEP_READ] * st["steps"]
 
     def test_one_dispatch_wait_and_read_a_prefill(self, traced, lm_model):
+        """A prefill is dispatched inside its ``serve.prefill`` and
+        waited for and read once, late: after the cycle's
+        ``serve.emit``, behind the dispatch of the cycle's step, before
+        the next cycle waits for that step; ``late`` says so, and is 0
+        where a settle read it with no step dispatched behind it."""
         from bigdl_tpu.serving import spans as S
 
         st, recs = _two_busy_stretches(lm_model, traced)
         spans = [r for r in recs if r["kind"] == "span"]
         prefills = _named(spans, S.SPAN_STEP_PREFILL)
-        assert len(prefills) == 5 + st["preemptions"]
+        assert len(prefills) == st["admitted"] == 5 + st["preemptions"]
         of_prefill = [s for s in spans if s["name"] in CHILDREN
                       and s["attrs"]["program"] == "prefill"]
         for p in prefills:
             mine = [s for s in of_prefill if s["parent"] == p["id"]]
-            assert [s["name"] for s in mine] == list(CHILDREN)
+            assert [s["name"] for s in mine] == [S.SPAN_STEP_DISPATCH]
             assert mine[0]["attrs"]["dry"] in (0, 1)
         assert len(of_prefill) == 3 * len(prefills)
+        # in the order of their dispatch: the reads say whose they are
+        reads = _named(of_prefill, S.SPAN_STEP_READ)
+        waits = _named(of_prefill, S.SPAN_STEP_WAIT)
+        assert [r["attrs"]["request"] for r in reads] == \
+            [p["attrs"]["request"] for p in prefills]
+        of_step = [s for s in spans if s["name"] in CHILDREN
+                   and s["attrs"]["program"] == "step"]
+        emits = _named(spans, S.SPAN_STEP_EMIT)
+        inside = [s for s in spans if s["name"] in (
+            S.SPAN_STEP_DECODE, S.SPAN_STEP_PREP, S.SPAN_STEP_EMIT,
+            S.SPAN_ADMISSION, S.SPAN_STEP_PREFILL)]
+        late = 0
+        for p, w, r in zip(prefills, waits, reads):
+            k = p["attrs"]["step"]
+            assert w["attrs"]["step"] == r["attrs"]["step"] == k
+            assert p["wall_time"] + p["dur_s"] <= w["wall_time"] + 1e-6
+            assert w["wall_time"] + w["dur_s"] <= r["wall_time"] + 1e-6
+            # outside every span of the cycle
+            assert w["parent"] is None and r["parent"] is None
+            assert not any(
+                s["wall_time"] < w["wall_time"] + 1e-7
+                < s["wall_time"] + s["dur_s"] for s in inside)
+            behind = [d for d in _named(of_step, S.SPAN_STEP_DISPATCH)
+                      if d["attrs"]["step"] == k
+                      and d["wall_time"] < w["wall_time"]]
+            assert r["attrs"]["late"] == len(behind)
+            if not behind:
+                continue
+            late += 1
+            # ... after the emit of the cycle that dispatched step k
+            emit, = [e for e in emits if e["attrs"]["step"] == k
+                     and e["wall_time"] > behind[0]["wall_time"]][:1]
+            assert emit["wall_time"] + emit["dur_s"] <= \
+                w["wall_time"] + 1e-6
+            # ... and before anything waits for step k: the next
+            # cycle, or a settle
+            of_k = [s for s in _named(of_step, S.SPAN_STEP_WAIT)
+                    if s["wall_time"] > emit["wall_time"]][0]
+            assert r["wall_time"] + r["dur_s"] <= of_k["wall_time"] + 1e-6
+        assert late == st["prefills_read_late"] >= 5
 
     def test_dry_on_the_first_step_and_after_every_settle(self, traced,
                                                           lm_model):
-        """With no step in flight nothing the host launched is still
-        running: ``dry`` is 1 wherever ``ahead`` is 0."""
+        """With no step in flight and no prefill unread nothing the
+        host launched is still running: ``dry`` is 1 wherever ``ahead``
+        is 0, but behind a prefill dispatched in the same cycle."""
         from bigdl_tpu.serving import spans as S
 
         st, recs = _two_busy_stretches(lm_model, traced)
@@ -614,19 +666,35 @@ class TestEngineSpans:
         assert all(d["attrs"]["dry"] in (0, 1) for d in dispatches)
         first = [d for d in dispatches
                  if not steps[d["parent"]]["attrs"]["ahead"]]
-        assert len(first) == sum(st["settles"].values()) \
-            and all(d["attrs"]["dry"] == 1 for d in first)
+        assert len(first) == sum(st["settles"].values())
+        unread = {s["attrs"]["step"] for s in spans
+                  if s["name"] == S.SPAN_STEP_READ
+                  and s["attrs"].get("late")}
+        alone = [d for d in first if d["attrs"]["step"] not in unread]
+        assert alone and all(d["attrs"]["dry"] == 1 for d in alone)
 
     @pytest.mark.parametrize("ready", [False, True])
     def test_dry_is_what_the_result_in_flight_says(self, traced, lm_model,
                                                    ready):
-        """``dry`` is the device buffer's own ``is_ready()``, asked once
-        a dispatch and before it."""
+        """``dry`` is the device buffers' own ``is_ready()``, asked
+        before a dispatch: of the step in flight and of every prefill
+        not yet read, so a step dispatched behind an unread, unfinished
+        prefill does not say the chip was dry."""
         from bigdl_tpu.serving import LMEngine, spans as S
 
         eng = LMEngine(lm_model, max_batch=2, page_size=4, num_pages=33)
         for p in PROMPTS[:2]:
             eng.submit(p, 8)
+        admit, prefilled = eng._admit, []
+
+        def admit_and_stub(**kw):
+            n = admit(**kw)
+            for rec in eng._unread:
+                prefilled.append(_Result(rec.result, ready))
+                rec.result = prefilled[-1]
+            return n
+
+        eng._admit = admit_and_stub
         stubs = []
         while eng.pump():
             if eng._inflight is not None:
@@ -637,8 +705,10 @@ class TestEngineSpans:
                                         S.SPAN_STEP_DISPATCH)
                       if s["attrs"]["program"] == "step"]
         assert len(dispatches) == len(stubs) == eng.stats()["steps"] > 4
+        # the first step has no step before it and two prefills
+        assert len(prefilled) == 2 and prefilled[0].asked >= 1
         assert [d["attrs"]["dry"] for d in dispatches] == \
-            [1] + [int(ready)] * (len(stubs) - 1)
+            [int(ready)] * len(stubs)
         # the last step's result is settled, never dispatched upon
         assert [r.asked for r in stubs] == [1] * (len(stubs) - 1) + [0]
 
@@ -733,12 +803,13 @@ class TestEngineSpans:
         no = jnp.zeros((2,), bool)
         step_txt = eng._step_fn.lower(
             eng.params, eng.cache.kp, eng.cache.vp, tables, lengths, z,
-            z, no, jnp.zeros((2,), jnp.float32), no,
+            jnp.zeros((2,), jnp.float32), no,
             jax.random.key(0)).as_text(debug_info=True)
         pre_txt = eng._prefill_fn(8).lower(
             eng.params, eng.cache.kp, eng.cache.vp,
             jnp.zeros((1, 8), jnp.int32), 5, jnp.zeros((2,), jnp.int32),
-            0.0, jax.random.key(0)).as_text(debug_info=True)
+            0.0, jax.random.key(0), np.int32(0),
+            z).as_text(debug_info=True)
         for scope in ("kv_write", "attn", "dense", "sample"):
             assert f"/{scope}/" in step_txt, scope
             assert f"/{scope}/" in pre_txt, scope

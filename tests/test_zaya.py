@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import late_read_cases
+
 from benchmarks.reference import zaya1_8b as ref
 from bigdl_tpu import obs
 from bigdl_tpu.models.zaya import Zaya, build_zaya
@@ -643,9 +645,14 @@ def test_spans_and_stats_say_the_state_and_the_routing(roomy, tmp_path,
                        key=lambda s: s["wall_time"])
         prefills = [s for s in spans if s["name"] == S.SPAN_STEP_PREFILL]
         assert len(prefills) == 2
-        for s in prefills:
+        # a prefill's counts come back with its first token, read late:
+        # they ride on its ``serve.read``, in the prefills' order
+        reads = [s for s in spans if s["name"] == S.SPAN_STEP_READ
+                 and s["attrs"]["program"] == "prefill"]
+        for s, r in zip(prefills, reads):
             assert s["attrs"]["state_bytes"] == STATE_BYTES
-            assert s["attrs"]["moe_held"] == 2 * s["attrs"]["prompt_len"]
+            assert r["attrs"]["request"] == s["attrs"]["request"]
+            assert r["attrs"]["moe_held"] == 2 * s["attrs"]["prompt_len"]
         # a step's counts ride on the span of the step that read them
         a = steps[1]["attrs"]
         assert a["moe_held"] == 2 * 2 and a["moe_absent"] == 0
@@ -666,13 +673,13 @@ def test_step_programs_carry_the_new_scopes():
     z = jnp.zeros((2,), jnp.int32)
     no = jnp.zeros((2,), bool)
     step = eng._step_fn.lower(
-        eng.params, *eng.cache.buffers(), tables, lengths, z, z, no,
+        eng.params, *eng.cache.buffers(), tables, lengths, z,
         jnp.zeros((2,), jnp.float32), no,
         jax.random.key(0)).as_text(debug_info=True)
     pre = eng._prefill_fn(8).lower(
         eng.params, *eng.cache.buffers(), jnp.zeros((1, 8), jnp.int32), 5,
         jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
-        np.int32(1)).as_text(debug_info=True)
+        np.int32(1), z).as_text(debug_info=True)
     for scope in ("cca.mix", "cca.attn", "kv_write", "moe.route",
                   "moe.experts", "dense", "sample"):
         assert f"/{scope}/" in step, scope
@@ -708,3 +715,20 @@ def test_a_model_without_state_spec_carries_none():
     req = eng.submit([1, 2, 3], 4)
     eng.run_until_idle(timeout_s=120)
     assert len(req.tokens) == 4
+
+
+# ------------------------------------------------ a prefill is read late
+# (PR 45) ``tests/late_read_cases.py``'s cases under this file's kind
+# of step: ``OneToken`` with state a slot carries
+@pytest.fixture(scope="module")
+def late():
+    with jax.default_matmul_precision("highest"):
+        model, params, _ = make(21)
+        return late_read_cases.prepare(
+            lambda **kw: LMEngine(model, params=params, page_size=4, **kw),
+            [[int(t) for t in tokens_of(n, 40 + n)] for n in (5, 7, 3, 6)])
+
+
+@pytest.mark.parametrize("case", sorted(late_read_cases.ALL_CASES))
+def test_a_prefill_read_late(late, case):
+    late_read_cases.ALL_CASES[case](*late)
