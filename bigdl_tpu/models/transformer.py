@@ -56,6 +56,37 @@ class TokenEmbedding(AbstractModule):
         return jnp.take(params["weight"], input.astype(jnp.int32), axis=0)
 
 
+#: lanes of one row of the TPU's vector tile
+_LANES = 128
+
+
+def row_table(weight):
+    """``weight`` (rows, width) in the form a gather of its ROWS takes
+    it where it lies: the width padded with zeros to whole lane tiles.
+
+    The TPU's compiler lays a matrix in memory in the order that pads
+    its tiles least.  A width of whole lane tiles keeps a row's values
+    together and a gather reads the rows it picks; GPT-2 XL's
+    ``(50257, 1600)`` (12.5 tiles wide) is laid ids-along-the-lanes,
+    and a program that gathers 12 rows of it first copies the whole
+    160 MB table into row order, in every call (0.49 of a 6.83 ms
+    decode step; PERF.md section 6, PR 46).  Whether a table needs
+    this follows from its width alone: one that has whole tiles is
+    handed back as it is."""
+    import jax.numpy as jnp
+
+    pad = -weight.shape[1] % _LANES
+    return jnp.pad(weight, ((0, 0), (0, pad))) if pad else weight
+
+
+def take_rows(table, ids, width: int):
+    """Rows ``ids`` of a table, :func:`row_table`'s or the matrix
+    itself, at the matrix's ``width``: bit for bit its rows."""
+    import jax.numpy as jnp
+
+    return jnp.take(table, ids, axis=0)[..., :width]
+
+
 class TransformerLM(_Composite):
     """Decoder-only causal LM over (batch, seq) int tokens -> logits
     (batch, seq, vocab)."""
@@ -225,10 +256,25 @@ class TransformerLM(_Composite):
                 "dtype": params["wte"]["weight"].dtype,
                 "heads": n_head, "head_dim": self.dim // n_head}
 
+    def serving_tables(self, params) -> dict:
+        """What the engine's programs take IN PLACE of parts of
+        ``params``, made once when the engine takes its weights and
+        again at a swap: the two embeddings as :func:`row_table` lays
+        them, each under its place in the tree; nothing for one that
+        gathers as it lies."""
+        made = {}
+        for name in ("wte", "wpe"):
+            weight = params[name]["weight"]
+            table = row_table(weight)
+            if table is not weight:
+                made[name] = {"weight": table}
+        return made
+
     def paged_prefill(self, params, caches, prompt, t0, pages):
         """One prompt, ``prompt`` (1, bucket) zero-padded past ``t0`` —
         causal attention keeps the real prefix exact — into the pages
-        ``pages``; ``(caches, logits (1, vocab) at t0 - 1, None)``."""
+        ``pages``; ``(caches, logits (1, vocab) at t0 - 1, None)``.
+        The token rows come as in :func:`paged_decode_logits`."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -238,8 +284,8 @@ class TransformerLM(_Composite):
         kp, vp = caches
         c = self._children
         bucket = prompt.shape[1]
-        x = jnp.take(params["wte"]["weight"], prompt, axis=0)
-        x = x + params["wpe"]["weight"][:bucket][None]
+        x = take_rows(params["wte"]["weight"], prompt, self.dim)
+        x = x + params["wpe"]["weight"][:bucket, :self.dim][None]
         for i in range(self.n_layer):
             # the block's prefill names its own attn and dense parts
             x, k, v = c[f"h{i}"].prefill_rows(params[f"h{i}"], x)
@@ -307,6 +353,15 @@ def paged_decode_logits(children, n_layer, page_size, params, qparams,
     ``TransformerBlock.decode_step`` exactly in the float path so paged
     decode bit-matches ``generate()`` at temperature 0.
 
+    The slots' token rows are gathered from ``params["wte"]["weight"]``
+    (and their positions' from ``wpe``), which is the embedding itself
+    or, behind the engine, the table ``TransformerLM.serving_tables``
+    made of it once (:func:`row_table`: the width padded to whole lane
+    tiles where it has none, so that the compiled gather reads the rows
+    it picks and not, after a copy of the whole table into row order,
+    the copy; the pad is cut off the gathered rows); the rows handed
+    to the first layer are the embedding's either way, bit for bit.
+
     The attention body is ``ops.decode_attention.paged_decode_attention``
     (the body of a cache of per-head K/V rows); ``tables`` may be the
     engine's used-page prefix bucket rather than the full table width
@@ -333,8 +388,9 @@ def paged_decode_logits(children, n_layer, page_size, params, qparams,
             return int8_matmul(x, qw[0], qw[1], impl="auto")
         return jnp.matmul(x, w.T)
 
-    x = jnp.take(params["wte"]["weight"], tokens, axis=0)[:, None, :]
-    x = x + jnp.take(params["wpe"]["weight"], lengths, axis=0)[:, None, :]
+    dim = children["wte"].dim
+    x = take_rows(params["wte"]["weight"], tokens, dim)[:, None, :]
+    x = x + take_rows(params["wpe"]["weight"], lengths, dim)[:, None, :]
     for i in range(n_layer):
         block = children[f"h{i}"]
         p = params[f"h{i}"]

@@ -66,7 +66,13 @@ block refined in place (``models/sdar_moe.py``).  Sampling, buckets,
 donation, the page tables, spans and ``stats()`` are the engine's; the
 layers' internals are the model's.  ``int8=True`` needs the model's
 ``quantize_for_decode``, ``tp > 1`` its ``tp_decode_step``; a model
-without them is refused with a ``ValueError`` that says so.
+without them is refused with a ``ValueError`` that says so.  A model
+may also offer ``serving_tables(params)``: parts of the tree its
+programs take in another form than the caller's
+(``models/transformer.py``: the embeddings with their width padded to
+whole lane tiles, so that the gather of a step's rows copies no table),
+made once here and again at a ``swap_weights``; ``weights()`` is the
+tree the programs are handed, ``params`` stays the caller's.
 
 Telemetry closes the serving loop: ``bigdl_request_latency_seconds
 {engine,kind=ttft|per_token|e2e}`` histograms, token/request counters,
@@ -289,6 +295,9 @@ class LMEngine:
             self.params = jax.tree.map(
                 jnp.asarray, self.params,
                 is_leaf=lambda x: x is None or hasattr(x, "shape"))
+        # what the model's programs take in place of parts of the tree
+        # (a table re-laid once for their gather; a swap renews it)
+        self._tables = self._serving_tables(self.params)
         self._prefill_fns: dict = {}
         self._tracer = obs.NULL_TRACER  # pump() looks it up each cycle
         from bigdl_tpu.obs import prof as _obs_prof
@@ -397,6 +406,20 @@ class LMEngine:
             total += float(leaf.size) * item
         return total
 
+    def _serving_tables(self, params) -> dict:
+        made = getattr(self.model, "serving_tables", None)
+        return made(params) if made is not None else {}
+
+    def weights(self):
+        """The tree ``jit_step`` and ``jit_prefill`` are handed:
+        ``params`` with what the model prepared for serving
+        (``serving_tables``) in its place.  Merged at each dispatch (a
+        dict of the tree's top level) and not kept: a kept tree would
+        hold the caller's weights alive after ``params`` lets go."""
+        if not self._tables:
+            return self.params
+        return {**self.params, **self._tables}
+
     # ------------------------------------------------------------ hot swap
     def swap_weights(self, params, *, version: str,
                      manifest_sha: Optional[str] = None) -> None:
@@ -434,10 +457,12 @@ class LMEngine:
                 is_leaf=lambda x: x is None or hasattr(x, "shape"))
         qparams = (self.model.quantize_for_decode(params)
                    if self.int8 else None)
+        tables = self._serving_tables(params)
         with self._lock:
             self._settle("swap")
             self.params = params
             self._qparams = qparams
+            self._tables = tables
             if self.int8:
                 self._step_fn = self._build_step()
             self._weight_bytes = self._decode_weight_bytes()
@@ -591,7 +616,7 @@ class LMEngine:
                 # behind the engine's arguments, the slot and the arrays
                 # of the carry the prefill writes the slot's row of
                 out = self._prefill_fn(bucket)(
-                    self.params, *self.cache.buffers(),
+                    self.weights(), *self.cache.buffers(),
                     jnp.asarray(prompt), t0, jnp.asarray(page_arg),
                     float(req.temperature), sub, np.int32(slot),
                     *self._carry[:handed])
@@ -855,7 +880,7 @@ class LMEngine:
                 host = [jnp.asarray(a) if isinstance(a, np.ndarray) else a
                         for a in host]
                 out = self._step_fn(
-                    self.params, *self.cache.buffers(), tables, lengths,
+                    self.weights(), *self.cache.buffers(), tables, lengths,
                     *self._carry, *host)
                 self.cache.set_buffers(out[:n])
                 k = len(self._carry)

@@ -281,7 +281,7 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
         return spec(a.shape, a.dtype)
 
     b, page = c["max_batch"], c["page_size"]
-    weights = jax.tree.map(like, eng.params)
+    weights = jax.tree.map(like, eng.weights())
     kp, vp = like(eng.cache.kp), like(eng.cache.vp)
     key = like(jax.random.key(0))
     programs = {"step": eng._step_fn.lower(
@@ -322,6 +322,141 @@ def test_engine_programs_work_on_the_cache_as_it_lies(one_chip):
               f"{temp / 1e6:.1f} MB, one cache buffer "
               f"{buffer_bytes / 1e6:.1f} MB")
 
+
+
+def _relayout_probe():
+    """``scripts/step_relayout_probe.py`` as a module (a script: loaded
+    by its path)."""
+    spec = importlib.util.spec_from_file_location(
+        "step_relayout_probe",
+        os.path.join(REPO, "scripts", "step_relayout_probe.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_the_relayout_probe_tells_a_copy_from_a_row_read():
+    """The probe's reading of a compiled program, on a recorded one:
+    of five operations that are handed 4 MB or more, the copy of a
+    whole table into row order is listed with both layouts, and the
+    gather that reads 12 rows of it, the head's product, the cache's
+    scatter in place and a kernel are counted apart."""
+    text = """HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (p0: bf16[1024,2048], p1: s32[12]) -> bf16[12,2048] {
+  %p0 = bf16[1024,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = s32[12]{0:T(128)} parameter(1)
+  ROOT %gather.1 = bf16[12,2048]{1,0:T(8,128)(2,1)} gather(%p0, %p1), offset_dims={1}
+}
+
+%fused_computation.2 (p0: bf16[12,2048], p1: bf16[1024,2048]) -> bf16[12,1024] {
+  %p0 = bf16[12,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[1024,2048]{0,1:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution.1 = bf16[12,1024]{1,0:T(8,128)(2,1)} convolution(%p0, %p1), dim_labels=bf_oi->bf
+}
+
+%fused_computation.3 (p0: bf16[2,512,16,256], p1: bf16[12,256]) -> bf16[2,512,16,256] {
+  %p0 = bf16[2,512,16,256]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[12,256]{1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %scatter.1 = bf16[2,512,16,256]{3,2,1,0:T(8,128)(2,1)} scatter(%p0, %p1, %p1), to_apply=%add
+}
+
+ENTRY %main (wte: bf16[1024,2048], head: bf16[1024,2048], kp: bf16[2,512,16,256], ids: s32[12]) -> bf16[12,1024] {
+  %wte = bf16[1024,2048]{0,1:T(8,128)(2,1)} parameter(0), metadata={op_name="params['wte']['weight']"}
+  %head = bf16[1024,2048]{0,1:T(8,128)(2,1)} parameter(1)
+  %kp = bf16[2,512,16,256]{3,2,1,0:T(8,128)(2,1)} parameter(2)
+  %ids = s32[12]{0:T(128)} parameter(3)
+  %copy.199 = bf16[1024,2048]{1,0:T(8,128)(2,1)} copy(%wte), metadata={op_name="params['wte']['weight']"}
+  %fusion.1 = bf16[12,2048]{1,0:T(8,128)(2,1)} fusion(%copy.199, %ids), kind=kCustom, calls=%fused_computation.1, metadata={op_name="jit(step)/jit(_take)/gather"}
+  %fusion.3 = bf16[2,512,16,256]{3,2,1,0:T(8,128)(2,1)} fusion(%kp, %fusion.1), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(step)/kv_write/scatter"}
+  %custom-call.1 = bf16[12,2048]{1,0:T(8,128)(2,1)} custom-call(%fusion.1, %fusion.3), custom_call_target="tpu_custom_call"
+  ROOT %fusion.2 = bf16[12,1024]{1,0:T(8,128)(2,1)} fusion(%custom-call.1, %head), kind=kOutput, calls=%fused_computation.2, metadata={op_name="jit(step)/dense/dot_general"}
+}
+"""
+    table_sized = _relayout_probe().table_sized
+    found = table_sized(text, 1024 * 2048 * 2)
+    assert {k: v for k, v in found.items() if k != "listed"} == {
+        "products": 1, "in_place": 1, "kernels": 1, "row_reads": 1}
+    assert found["listed"] == [{
+        "op": "copy.199", "kind": "copy",
+        "result": ["bf16[1024,2048]{1,0:T(8,128)(2,1)}"],
+        "operands": ["bf16[1024,2048]{0,1:T(8,128)(2,1)}"],
+        "op_name": "params['wte']['weight']"}]
+    # a table twice the size: nothing here touches one
+    assert table_sized(text, 1024 * 2048 * 4 + 1) == {
+        "listed": [], "products": 0, "in_place": 0, "kernels": 0,
+        "row_reads": 0}
+
+
+@pytest.mark.slow
+def test_engine_programs_read_of_the_embedding_the_rows_they_gather(
+        one_chip):
+    """GPT-2 XL's decode step and a prefill at the cell's widths and
+    engine size (two layers, the whole ``(50257, 1600)`` embedding),
+    from shapes alone, compiled for the described v5e: handed the tree
+    the engine hands them (``TransformerLM.serving_tables``: 1600
+    values a row padded to 13 lane tiles) no operation but the head's
+    product and the gather touches a table's worth of memory; handed
+    the caller's tree, both programs first copy the table, which lies
+    ids-along-the-lanes, into row order (``copy.199``: 0.49 of the
+    step's 6.83 ms, ledger PR 45)."""
+    import functools
+
+    from benchmarks.drivers import serve
+    from benchmarks.reference import gpt2_xl as ref
+    from bigdl_tpu.models.transformer import build_transformer_lm
+    from bigdl_tpu.serving.engine import LMEngine
+
+    sh, dt = one_chip, jnp.bfloat16
+    sizes = dict(n_layer=2, dim=1600, n_head=25, max_len=1024, vocab=50257,
+                 mlp_ratio=4, init_std=0.02)
+    b, page, pages = 12, 16, 481
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    def like(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    shapes = jax.eval_shape(functools.partial(ref.init_params, 1, sizes, dt))
+    with serve.modules_without_weights():
+        probe = build_transformer_lm(
+            sizes["vocab"], dim=sizes["dim"], n_head=sizes["n_head"],
+            n_layer=sizes["n_layer"], max_len=sizes["max_len"])
+    tables = jax.eval_shape(probe.serving_tables, shapes)
+    assert {k: v["weight"].shape for k, v in tables.items()} == {
+        "wte": (50257, 1664), "wpe": (1024, 1664)}
+    caller = like(shapes)
+    served = {**caller, **like(tables)}
+    buf = spec((sizes["n_layer"], pages, page, sizes["dim"]), dt)
+    eng = _engine_of_shapes(probe, caller, page, sizes["max_len"],
+                            (buf, buf))
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((b,), jnp.int32)
+
+    def programs(weights):
+        return {
+            "step": LMEngine._build_step(eng).lower(
+                weights, buf, buf, spec((b, 32), jnp.int32), ints, ints,
+                spec((b,), jnp.float32), spec((b,), jnp.bool_), key),
+            "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+                weights, buf, buf, spec((1, 256), jnp.int32),
+                spec((), jnp.int32), spec((256 // page,), jnp.int32),
+                spec((), jnp.float32), key, spec((), jnp.int32), ints)}
+
+    table_sized = _relayout_probe().table_sized
+    table_bytes = 50257 * 1600 * 2
+    for name, lowered in programs(served).items():
+        found = table_sized(lowered.compile().as_text(), table_bytes)
+        print(f"served tree, {name}: {found}")
+        assert found["listed"] == [], (name, found)
+        assert found["products"] == 1 and found["row_reads"] == 1, found
+    for name, lowered in programs(caller).items():
+        found = table_sized(lowered.compile().as_text(), table_bytes)
+        print(f"caller's tree, {name}: {found}")
+        assert [(r["kind"], r["operands"][0][:18], r["result"][0][:18])
+                for r in found["listed"]] == [
+            ("copy", "bf16[50257,1600]{0", "bf16[50257,1600]{1")], found
 
 
 def _engine_of_shapes(probe, weights, page, max_len, pools, state=()):
@@ -1004,9 +1139,13 @@ def test_kda_engine_programs_work_on_cache_and_state_as_they_lie(
 # slot and what of the carry it writes the slot's row of, and every
 # step takes a fresh slot's input from there and no longer from the
 # host; the models' own mathematics is as it was (the served tokens
-# equal the parent's: ``tests/test_serving.py``).
+# equal the parent's: ``tests/test_serving.py``).  PR 46 meant to change
+# ONE model's two: ``tiny_gpt``'s programs are handed the embeddings
+# with their 32 values a row padded to a lane tile
+# (``TransformerLM.serving_tables``) and cut the pad off the gathered
+# rows; the six others are handed ``params`` itself and kept PR 45's.
 LOWERED = {
-    "tiny_gpt": ("10925615571aa732", "defaf580cc907f61"),
+    "tiny_gpt": ("c0f5691a07849545", "ee822d4e8e31c000"),
     "tiny_longcat": ("fd1e74384430ef10", "cbd0b59cc7e05e3c"),
     "tiny_joyai": ("8c80e5206f2620cb", "cd0c2e5e5fdb97fe"),
     "tiny_sdar": ("852913276eb1dc96", "5e27a46a9de4314b"),
@@ -1066,13 +1205,13 @@ def test_the_other_serving_models_programs_lower_unchanged(
         # no slot runs: the kind's own host arrays, all zeros
         host = eng._kind.host_args((), jax.random.key(0))
         text = eng._step_fn.lower(
-            eng.params, *eng.cache.buffers(), tables, lengths,
+            eng.weights(), *eng.cache.buffers(), tables, lengths,
             *eng._carry, *host).as_text()
     else:
         bucket = 2 * eng.page_size
         # the slot, and what of the carry a prefill writes its row of
         text = eng._prefill_fn(bucket).lower(
-            eng.params, *eng.cache.buffers(),
+            eng.weights(), *eng.cache.buffers(),
             jnp.zeros((1, bucket), jnp.int32), 5,
             jnp.zeros((2,), jnp.int32), 0.0, jax.random.key(1),
             np.int32(1), *eng._carry[:eng._kind.handed]).as_text()

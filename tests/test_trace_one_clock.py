@@ -802,11 +802,11 @@ class TestEngineSpans:
         z = jnp.zeros((2,), jnp.int32)
         no = jnp.zeros((2,), bool)
         step_txt = eng._step_fn.lower(
-            eng.params, eng.cache.kp, eng.cache.vp, tables, lengths, z,
+            eng.weights(), eng.cache.kp, eng.cache.vp, tables, lengths, z,
             jnp.zeros((2,), jnp.float32), no,
             jax.random.key(0)).as_text(debug_info=True)
         pre_txt = eng._prefill_fn(8).lower(
-            eng.params, eng.cache.kp, eng.cache.vp,
+            eng.weights(), eng.cache.kp, eng.cache.vp,
             jnp.zeros((1, 8), jnp.int32), 5, jnp.zeros((2,), jnp.int32),
             0.0, jax.random.key(0), np.int32(0),
             z).as_text(debug_info=True)
