@@ -10,9 +10,12 @@ not re-exported here: ``joyai_flash`` (a step that verifies its own
 draft), ``sdar_moe`` (generation by blocks), ``zaya`` (attention in a
 compressed latent, a bounded-past slot state), ``falcon_h1`` (a
 state-space mixer beside grouped attention, a slot state that sums over
-the whole past) and ``ling_flash`` (a delta-rule linear attention with a
+the whole past), ``ling_flash`` (a delta-rule linear attention with a
 decay a channel in five layers of six, latent attention in the sixth,
-experts chosen by groups: layers that differ in what a slot keeps).
+experts chosen by groups: layers that differ in what a slot keeps) and
+``olmo_hybrid`` (a gated delta rule, one decay a head on 96 x 192
+tiles, in three layers of four, full attention of 30 heads in the
+fourth, the reordered norm).
 """
 
 from bigdl_tpu.models.lenet import build_lenet5

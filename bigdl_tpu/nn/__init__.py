@@ -46,7 +46,7 @@ from bigdl_tpu.nn.latent import (
 )
 from bigdl_tpu.nn.experts import DroplessExperts
 from bigdl_tpu.nn.ssm import Mamba2Mixer
-from bigdl_tpu.nn.delta import DeltaMixer
+from bigdl_tpu.nn.delta import DeltaMixer, GatedDeltaMixer
 from bigdl_tpu.nn.attention import (
     LayerNorm,
     MultiHeadAttention,
@@ -172,7 +172,7 @@ __all__ = (
         "PositionalEmbedding",
         "RMSNorm", "GatedMLP", "LatentAttention", "DroplessExperts",
         "Mamba2Mixer",
-        "DeltaMixer",
+        "DeltaMixer", "GatedDeltaMixer",
         "SpatialConvolutionBatchNorm", "fuse_conv_bn",
     ]
     + list(_layers_all)

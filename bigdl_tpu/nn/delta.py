@@ -1,6 +1,9 @@
-"""A **delta-rule linear attention with a decay a channel** (KDA) — the
-mixer of the hybrid decoders that run it in most layers and a full
-attention in the others (``models/ling_flash.py``).
+"""**Delta-rule linear attention** — the mixer of the hybrid decoders
+that run it in most layers and a full attention in the others: with a
+decay a CHANNEL (KDA, :class:`DeltaMixer`, ``models/ling_flash.py``) and
+with a decay a HEAD (the gated delta rule, :class:`GatedDeltaMixer`,
+``models/olmo_hybrid.py``; what differs is listed at the end, and
+nothing else does).
 
 With ``n`` the normalised stream (``dim`` wide), ``H`` heads of ``d_k``
 key and ``d_v`` value channels and ``K`` the convolution's kernel::
@@ -70,7 +73,33 @@ engine's buffer of 6 layers x 256 slots x 32 heads is 3.2 GB, past the
 ``jax.named_scope`` names: ``kda.proj`` (the two projections),
 ``kda.conv`` (the convolution and its rows), ``kda.state`` (a step's
 gates, the kernel, the norm and the output gate), ``kda.scan`` (the
-same for a prompt, in chunks).
+same for a prompt, in chunks); ``gdn.*`` under a decay a head.
+
+**The gated delta rule** (:class:`GatedDeltaMixer`; Yang, Kautz,
+Hatamizadeh, arXiv:2412.06464) is the recurrence above with ``g`` ONE
+number a head, and four things read otherwise::
+
+    [q ; k ; v ; z ; a ; b] = W_in n          H d_k, H d_k, H d_v, H d_v, H, H
+    g_h = -exp(a_log_h) softplus(a_h + dt_bias_h)    a head, unbounded
+    beta_h = beta_max sigmoid(b_h)            beta_max 2: I - beta k k^T has
+                                              the eigenvalue 1 - beta in
+                                              (-1, 1) along k
+    y = W_o [rms(o_h) * gain * silu(z_h)]_h   a norm a HEAD over its d_v
+                                              values, one gain of d_v
+
+and ``d_k != d_v`` (96 x 192).  Both forms of the recurrence are the
+ones above: :meth:`DeltaMixer.step` and :meth:`DeltaMixer.scan` run
+unchanged, over four hooks.  The gates (``_gates``: ``g`` comes back
+``(..., H, 1)`` and broadcasts wherever the scan's algebra has a decay a
+channel).  The chunk's pair matrices (``_pairs``): a scalar factors over
+a whole chunk, ``A = (K K^T) * exp(G_t - G_i)`` with every exponent of
+the kept triangle <= 0, so **no sub-blocks** and no cap.  The state's
+layout and kernel (``state_shapes``, ``_advance``, ``_kept``): a slot
+keeps ``S`` as ``(d_k, H d_v)``, all heads side by side along the lanes,
+because a ``d_v`` of 192 is 1.5 lane tiles and a ``(.., 96, 192)`` array
+is stored a third larger than it is (``ops/delta_state.py``
+``head_decay_update``, the kernel ``gdn_state_update``).  The norm and
+the output gate (``_finish``).
 """
 
 from __future__ import annotations
@@ -104,6 +133,8 @@ class DeltaMixer(AbstractModule):
     """The mixer of the module docstring."""
 
     param_names = ("w_in", "conv_w", "dt_bias", "a_log", "norm", "w_out")
+    #: the prefix of the ``jax.named_scope`` names
+    scope = "kda"
 
     def __init__(self, dim: int, heads: int, key_dim: int, value_dim: int,
                  d_conv: int = 4, lower_bound: float = -5.0,
@@ -236,30 +267,45 @@ class DeltaMixer(AbstractModule):
         import jax
         import jax.numpy as jnp
 
-        from bigdl_tpu.ops.delta_state import state_update
-
-        with jax.named_scope("kda.proj"):
+        with jax.named_scope(self.scope + ".proj"):
             qkv, f, z, b = self.project(params, n)
-        with jax.named_scope("kda.conv"):
+        with jax.named_scope(self.scope + ".conv"):
             kept = rows[layer]
             window = jnp.concatenate(
                 [kept, qkv[:, None].astype(kept.dtype)], axis=1)
             q, k, v = self._convolved(params, window)
             rows = rows.at[layer].set(
                 jnp.where(active[:, None, None], window[:, 1:], kept))
-        with jax.named_scope("kda.state"):
+        with jax.named_scope(self.scope + ".state"):
             g, beta = self._gates(params, f, b, active)
-            per = self.heads // self.state_parts
-            done = [state_update(part, layer, *(
-                a[:, j * per:(j + 1) * per]
-                for a in (jnp.exp(g), k, q, v, beta)))
-                for j, part in enumerate(states)]
-            states = tuple(part for part, _ in done)
-            o = jnp.concatenate([o for _, o in done], axis=1)
-            y = self._finish(params, o.reshape(n.shape[0], self.d_value),
-                             z, n.dtype)
-        with jax.named_scope("kda.proj"):
+            states, o = self._advance(states, layer, g, k, q, v, beta)
+            y = self._finish(params, o, z, n.dtype)
+        with jax.named_scope(self.scope + ".proj"):
             return jnp.matmul(y, params["w_out"].T), states, rows
+
+    def _advance(self, states, layer, g, k, q, v, beta):
+        """The slots' stacked state one token on at ``layer``, by the
+        kernel of this state's layout -> ``(states', o (S, H d_v))``, the
+        read from the NEW state."""
+        import jax.numpy as jnp
+
+        from bigdl_tpu.ops.delta_state import state_update
+
+        per = self.heads // self.state_parts
+        done = [state_update(part, layer, *(
+            a[:, j * per:(j + 1) * per]
+            for a in (jnp.exp(g), k, q, v, beta)))
+            for j, part in enumerate(states)]
+        o = jnp.concatenate([o for _, o in done], axis=1)
+        return tuple(part for part, _ in done), \
+            o.reshape(k.shape[0], self.d_value)
+
+    def _kept(self, state):
+        """A prompt's final ``S`` ``(H, d_k, d_v)`` as a slot keeps it
+        (:meth:`state_shapes`, without the convolution's rows)."""
+        import jax.numpy as jnp
+
+        return tuple(jnp.split(state, self.state_parts))
 
     # ---------------------------------------------------------- a prompt
     def _pairs(self, q, k, g_sum):
@@ -300,18 +346,19 @@ class DeltaMixer(AbstractModule):
         n = jnp.pad(n, ((0, -real % c), (0, 0)))
         t = n.shape[0]
         nc = t // c
-        with jax.named_scope("kda.proj"):
+        with jax.named_scope(self.scope + ".proj"):
             qkv, f, z, b = self.project(params, n)
-        with jax.named_scope("kda.conv"):
+        with jax.named_scope(self.scope + ".conv"):
             padded = jnp.concatenate(
                 [jnp.zeros((kk - 1, self.conv_dim), qkv.dtype), qkv])
             window = jnp.stack([padded[j:j + t] for j in range(kk)], axis=1)
             q, k, v = self._convolved(params, window)
             # padded[t0 .. t0 + K - 2] are the rows t0 - K + 1 .. t0 - 1
             rows = _f32(lax.dynamic_slice_in_dim(padded, t0, kk - 1))
-        with jax.named_scope("kda.scan"):
+        with jax.named_scope(self.scope + ".scan"):
             g, beta = self._gates(params, f, b, jnp.arange(t) < t0)
-            q, k, g = (a.reshape(nc, c, h, dk) for a in (q, k, g))
+            q, k = (a.reshape(nc, c, h, dk) for a in (q, k))
+            g = g.reshape(nc, c, h, -1)
             v, beta = v.reshape(nc, c, h, dv), beta.reshape(nc, c, h)
             g_sum = jnp.cumsum(g, axis=1)                       # inclusive
             lower = jnp.tril(jnp.ones((c, c), bool))
@@ -341,9 +388,9 @@ class DeltaMixer(AbstractModule):
                 carry, jnp.zeros((h, dk, dv), jnp.float32),
                 (u, wk, q_in, bq, k_end, whole))
             y = self._finish(params, o.reshape(t, self.d_value), z, n.dtype)
-        with jax.named_scope("kda.proj"):
+        with jax.named_scope(self.scope + ".proj"):
             return jnp.matmul(y, params["w_out"].T)[:real], \
-                tuple(jnp.split(state, self.state_parts)), rows
+                self._kept(state), rows
 
     def update_output_pure(self, params, input, *, training=False, rng=None):
         """``input`` (batch, T, dim) -> (batch, T, dim), every sequence
@@ -358,4 +405,116 @@ class DeltaMixer(AbstractModule):
                 f"x {self.value_dim})")
 
 
-__all__ = ["DeltaMixer"]
+class GatedDeltaMixer(DeltaMixer):
+    """The gated delta rule of the module docstring's last part: a decay
+    a head, ``beta`` up to ``beta_max``, a norm a head under ``silu(z)``,
+    ``S`` kept ``(d_k, H d_v)``.  No bound on ``g`` and no sub-blocks."""
+
+    scope = "gdn"
+
+    def __init__(self, dim: int, heads: int, key_dim: int, value_dim: int,
+                 d_conv: int = 4, beta_max: float = 2.0, chunk: int = 64,
+                 eps: float = 1e-6, init: bool = True):
+        super().__init__(dim, heads, key_dim, value_dim, d_conv=d_conv,
+                         lower_bound=0.0, norm_groups=heads, chunk=chunk,
+                         sub=chunk, eps=eps, init=False)
+        self._config = dict(
+            dim=dim, heads=heads, key_dim=key_dim, value_dim=value_dim,
+            d_conv=d_conv, beta_max=beta_max, chunk=chunk, eps=eps)
+        self.beta_max = float(beta_max)
+        #: the zones of ``W_in``'s outputs: q, k, v, z, a, b
+        self.zones = (self.d_key, self.d_key, self.d_value, self.d_value,
+                      heads, heads)
+        if init:
+            self.reset()
+
+    def reset(self):
+        import jax.numpy as jnp
+
+        self.w_in = _draw((sum(self.zones), self.dim))
+        self.conv_w = _draw((self.d_conv, self.conv_dim), 0.3)
+        # decays around 0.97 a step: softplus(-3.5) = 0.03
+        self.dt_bias = jnp.full((self.heads,), -3.5, jnp.float32)
+        self.a_log = jnp.zeros((self.heads,), jnp.float32)
+        self.norm = jnp.ones((self.value_dim,), jnp.float32)
+        self.w_out = _draw((self.dim, self.d_value))
+        return self
+
+    def state_shapes(self) -> tuple:
+        """``S`` with every head's ``d_v`` values side by side along the
+        lanes (``ops/delta_state.py``, second half), and the
+        convolution's last ``K - 1`` rows of ``[q ; k ; v]``."""
+        return ((self.key_dim, self.d_value),
+                (self.d_conv - 1, self.conv_dim))
+
+    def project(self, params, n):
+        """As :meth:`DeltaMixer.project`, the decay's input ``a`` (...,
+        H) where that has ``f``: ``[q ; k ; v]``, ``a``, ``z``, ``b``."""
+        import jax.numpy as jnp
+
+        p = jnp.matmul(n, params["w_in"].T)
+        at = np.cumsum((self.conv_dim,) + self.zones[3:])
+        return p[..., :at[0]], p[..., at[1]:at[2]], p[..., at[0]:at[1]], \
+            p[..., at[2]:]
+
+    def _gates(self, params, a, b, live):
+        """``g`` (..., H, 1) <= 0 and ``beta`` (..., H) in (0,
+        ``beta_max``), float32; both 0 where ``live`` is false."""
+        import jax
+        import jax.numpy as jnp
+
+        g = -jnp.exp(_f32(params["a_log"])) * jax.nn.softplus(
+            _f32(a) + _f32(params["dt_bias"]))
+        beta = self.beta_max * jax.nn.sigmoid(_f32(b))
+        return (jnp.where(live[..., None], g, 0.0)[..., None],
+                jnp.where(live[..., None], beta, 0.0))
+
+    def _finish(self, params, o, z, dtype):
+        import jax
+        import jax.numpy as jnp
+
+        lead = o.shape[:-1]
+        o = o.reshape(*lead, self.heads, self.value_dim)
+        o = o * jax.lax.rsqrt(
+            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + self.eps) \
+            * _f32(params["norm"])
+        return (o.reshape(*lead, self.d_value)
+                * jax.nn.silu(_f32(z))).astype(dtype)
+
+    def _advance(self, states, layer, g, k, q, v, beta):
+        import jax.numpy as jnp
+
+        from bigdl_tpu.ops.delta_state import head_decay_update
+
+        (s,) = states
+        s, o = head_decay_update(
+            s, layer, jnp.exp(g[..., 0]), k, q,
+            v.reshape(v.shape[0], self.d_value), beta)
+        return (s,), o
+
+    def _kept(self, state):
+        import jax.numpy as jnp
+
+        return (jnp.swapaxes(state, 0, 1).reshape(self.key_dim,
+                                                  self.d_value),)
+
+    def _pairs(self, q, k, g_sum):
+        """``P[t, i] = (x_t . k_i) exp(G_t - G_i)`` for ``x = k`` and ``x
+        = q``: ``g_sum`` (chunks, c, H, 1), one running sum a head, so the
+        factor is one plane a head over the whole chunk, 0 above the
+        diagonal (the exponent is masked BEFORE it is raised)."""
+        import jax.numpy as jnp
+
+        c = k.shape[1]
+        gs = jnp.moveaxis(g_sum[..., 0], 2, 1)              # (nc, H, c)
+        kept = jnp.tril(jnp.ones((c, c), bool))
+        factor = jnp.exp(jnp.where(
+            kept, gs[..., :, None] - gs[..., None, :], -jnp.inf))
+        return tuple(_mm("nthd,nihd->nhti", x, k) * factor for x in (k, q))
+
+    def __repr__(self):
+        return (f"GatedDeltaMixer({self.dim} -> {self.heads} x "
+                f"{self.key_dim} x {self.value_dim})")
+
+
+__all__ = ["DeltaMixer", "GatedDeltaMixer"]

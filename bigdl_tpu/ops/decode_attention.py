@@ -17,7 +17,18 @@ between them:
     sequence of ``TransformerBlock.decode_step``, so paged decode
     matches ``generate()`` token for token at temperature 0.  A
     per-head kernel measured 6x slower than this at those widths (25
-    tiny dots a page; PERF.md section 6, PRs 25 and 28);
+    tiny dots a page; PERF.md section 6, PRs 25 and 28).  **The
+    gather is a copy**, ``(B, maxp x P, H_kv x Dh)`` for K and again
+    for V, and it grows with the pool: 20 MB at GPT-2 XL's cell, 4 GB
+    each at 256 slots of 30 heads of 128 lanes over 2048 positions
+    (``models/olmo_hybrid.py``), beside 13 GB of arguments.  So where
+    a layer's pool is over :data:`_GATHER_POOL_BYTES` and a row is
+    whole lane tiles, one query row a key head goes through the page
+    stream as well (:func:`_single_kernel`: the same stream, mask and
+    online softmax as the kernel below; the two products are ONE
+    matrix product each, the queries laid block-diagonally as
+    :func:`_head_scores` lays them, because ``H_kv`` products of one
+    row are what measured 6x slower);
   - *query rows that share a key head* (``S x H > H_kv``: grouped
     heads and/or several positions a slot; ``models/sdar_moe.py``, 32
     query heads over 4 key heads of 128 lanes, a block of 4 positions a
@@ -109,7 +120,10 @@ def decode_hbm_bytes(b: int, h: int, d: int, page_size: int,
     bound, since the gauge does not know the slots' lengths: the kernel
     copies each slot's pages up to its length, rounded up to a group of
     ``_COPIES_A_TRIP`` pages (:func:`stream_rows_copied` counts them
-    for given lengths)."""
+    for given lengths).  The gauge is not told the pool: one query row
+    a key head is counted as the gather even over a pool that
+    :func:`_streams` sends through the kernel (three times too much
+    there; the spans' ``attn_rows_copied`` is the count to read)."""
     k = maxp * page_size
     rows = h if kv_heads is None else kv_heads
     pages = 2.0 * b * maxp * page_size * rows * d * kv_itemsize  # K + V
@@ -209,7 +223,10 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
       the op sequence of ``TransformerBlock.decode_step`` (scores,
       ``-inf`` mask, softmax, weighted sum, in the same dtypes) on the
       token-major cache, so the temperature-0 token-match contract vs
-      ``generate()`` holds;
+      ``generate()`` holds; unless the pool is one the gather cannot
+      be asked to copy (:func:`_streams`: a layer's pool over
+      :data:`_GATHER_POOL_BYTES`, rows of whole lane tiles), which goes
+      the second way;
     * ``S x H > H_kv`` (rows that share a key head): the Pallas kernel
       of :func:`_grouped_program` — each slot's pages of K and of V are
       copied from ``[layer, page]`` where they lie, a block at a time,
@@ -229,7 +246,7 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
     if scale is None:
         scale = d ** -0.5
     shape = q.shape
-    if math.prod(shape[1:-1]) > kp.shape[-1] // d:   # query rows a slot
+    if math.prod(shape[1:-1]) > kp.shape[-1] // d or _streams(kp):
         from bigdl_tpu.ops._pallas import resolve_interpret
 
         stacked = layer is not None
@@ -260,6 +277,23 @@ def paged_decode_attention(q, kp, vp, tables, lengths, *,
 # --------------------------------------------------------------------------
 
 
+# One query row a key head: a layer's K pool (and its V pool) over this
+# many bytes is streamed, not gathered.  The gathered copy of a full
+# table is the pool's size again under the engine's default pool (a
+# slot's longest context for every slot): 25 MB for GPT-2 XL's cell (481
+# pages), 4 GB for 256 slots of 7,680-byte rows.
+_GATHER_POOL_BYTES = 256 << 20
+
+
+def _streams(pool) -> bool:
+    """Whether one query row a key head over ``pool`` (a layer's
+    ``(num_pages, P, row)`` or the stacked ``(layers, ...)``) goes
+    through the page stream: by the pool's shape alone."""
+    pages, page, row = pool.shape[-3:]
+    return row % 128 == 0 and \
+        pages * page * row * pool.dtype.itemsize > _GATHER_POOL_BYTES
+
+
 # A block of pages is what one buffer of a kernel's ring holds, about
 # this many bytes; the copies of the ring's other blocks are in flight
 # while one is contracted.  Chosen on the chip (PERF.md section 6,
@@ -284,8 +318,13 @@ def _block_pages(page_size: int, row_width: int, itemsize: int,
     256) the scores pass 256 KB and a key head's share of them, which
     is what the grouped kernel folds at a time, does not, while blocks
     of 256 positions cost that step 0.4 of its attention's 4.15 ms
-    (chip run, PR 41; PR 31 found the same tenth)."""
-    by_bytes = _BLOCK_BYTES // (page_size * row_width * itemsize)
+    (chip run, PR 41; PR 31 found the same tenth).  A page so wide that
+    ``_BLOCK_BYTES`` hold less than one trip of copies (16 rows of
+    7,680 B: 5 pages) takes a whole trip where twice those bytes hold
+    it."""
+    page_bytes = page_size * row_width * itemsize
+    by_bytes = max(_BLOCK_BYTES // page_bytes,
+                   min(_COPIES_A_TRIP, 2 * _BLOCK_BYTES // page_bytes))
     positions = min(1024, max(512, (64 * 1024) // max(1, head_rows)))
     bp = max(1, min(by_bytes, positions // page_size))
     return bp if bp < _COPIES_A_TRIP else bp - bp % _COPIES_A_TRIP
@@ -490,6 +529,54 @@ def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int,
     return kernel
 
 
+def _single_kernel(bp: int, page: int, maxp: int, hkv: int, d: int):
+    """:func:`_grouped_kernel` for ONE query row a key head and one
+    length a slot.  Head by head that is ``hkv`` products of one row a
+    block, each of which loads its head's lanes of the block into the
+    matrix unit for a single row; here the ``hkv`` queries are laid
+    block-diagonally, ``(hkv, hkv x d)`` with query ``j`` in the lanes
+    of its head and exact zeros elsewhere, and a block is ONE ``(hkv,
+    row) x (row, positions)`` product for the scores and ONE ``(hkv,
+    positions) x (positions, row)`` for the mix, of which head ``j``
+    keeps its own ``d`` lanes (the same products and the same float32
+    sums as a dot a head: :func:`_head_scores`, :func:`_head_mix`).
+    The stream, the mask and the fold are the grouped kernel's."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    rows_blk, row = bp * page, hkv * d
+
+    def kernel(tables, need, lens, layer, q_ref, kpool, vpool, o_ref,
+               kbuf, vbuf, ksems, vsems, ring):
+        nblk, next_block = _page_stream(
+            tables, need, layer, ring,
+            ((kpool, kbuf, ksems), (vpool, vbuf, vsems)), bp, maxp)
+        length = lens[pl.program_id(0)]
+        head = lax.broadcasted_iota(jnp.int32, (hkv, row), 0) * d
+        lane = lax.broadcasted_iota(jnp.int32, (hkv, row), 1)
+        own = (lane >= head) & (lane < head + d)
+        qs = q_ref[0]                                  # (hkv, d)
+        qmat = jnp.where(own, jnp.concatenate([qs] * hkv, axis=1),
+                         jnp.zeros((), qs.dtype))
+
+        def block(i, carry):
+            half = next_block(i)
+            s = lax.dot_general(qmat, kbuf[half].reshape(rows_blk, row),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            live = i * rows_blk + lax.broadcasted_iota(
+                jnp.int32, (hkv, rows_blk), 1) <= length
+            return _fold(carry, jnp.where(live, s, -jnp.inf),
+                         vbuf[half].reshape(rows_blk, row), slice(None))
+
+        _, l, acc = lax.fori_loop(0, nblk, block, _fold_start(hkv, row))
+        out = jnp.where(own, acc / jnp.maximum(l, 1e-30), 0.0)
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+    return kernel
+
+
 @functools.lru_cache(maxsize=None)
 def _grouped_program(scale: float, interpret: bool):
     """The jitted call of the grouped kernel for one ``scale``, the
@@ -534,6 +621,30 @@ def _grouped_program(scale: float, interpret: bool):
         need = jnp.clip(lens // p + 1, 1, maxp)
         buffers = pltpu.VMEM((_BUFFERS, bp, p, row), kpool.dtype)
         sems = pltpu.SemaphoreType.DMA((_BUFFERS,))
+        scratch = [buffers, buffers, sems, sems, pltpu.SMEM((4,), jnp.int32)]
+        if r == 1 and not per_row:
+            # one query row a key head: queries (B, H_kv, Dh) in, the
+            # heads' mixes side by side (B, 1, H_kv x Dh) out
+            return pl.pallas_call(
+                _single_kernel(bp, p, maxp, hkv, d),
+                out_shape=jax.ShapeDtypeStruct((b, 1, row), q.dtype),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=4,
+                    grid=(b,),
+                    in_specs=[
+                        pl.BlockSpec((1, hkv, d), lambda i, *_: (i, 0, 0)),
+                        pl.BlockSpec(memory_space=pl.ANY),
+                        pl.BlockSpec(memory_space=pl.ANY),
+                    ],
+                    out_specs=pl.BlockSpec((1, 1, row),
+                                           lambda i, *_: (i, 0, 0)),
+                    scratch_shapes=scratch),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",)),
+                interpret=interpret,
+                name="single_decode_attention",
+            )(tables.reshape(-1).astype(jnp.int32), need, *scalars,
+              qs.reshape(b, hkv, d), kpool, vpool).reshape(q.shape)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(scalars),
             grid=(b,),
@@ -545,8 +656,7 @@ def _grouped_program(scale: float, interpret: bool):
             ],
             out_specs=pl.BlockSpec((1, hkv, r, d),
                                    lambda i, *_: (i, 0, 0, 0)),
-            scratch_shapes=[buffers, buffers, sems, sems,
-                            pltpu.SMEM((4,), jnp.int32)])
+            scratch_shapes=scratch)
         out = pl.pallas_call(
             _grouped_kernel(bp, p, maxp, hkv, d, per_row),
             out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),

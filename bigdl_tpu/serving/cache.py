@@ -57,7 +57,10 @@ before it is written**: a matrix a head whose transition is not diagonal
 decayed state along its key, writes a rank-1 correction, reads again
 along its query; kept only by the layers that mix so, 6 of that model's
 7, while the seventh keeps pages and no state: ``state_spec``'s
-``layers`` and ``cache_spec``'s count different layers).  None is a
+``layers`` and ``cache_spec``'s count different layers;
+``models/olmo_hybrid.py``: the same rule under a decay a head, 30 heads
+of 96 x 192 kept ``(96, 5760)``, 2.21 MB a slot and layer, in 3 layers
+of 4).  None is a
 function of the token alone, so no page holds it.  It is one array a declared shape,
 ``(layers, max_slots, *shape)``, indexed by SLOT (not by page: a slot
 has exactly one, whatever its length), handed to the step and the
@@ -81,7 +84,12 @@ shape is one buffer, and a buffer may not pass 2 GiB**
 two arrays of 16 heads serves right ones (``PERF.md`` section 6, PR 44;
 Falcon-H1's 2.0 GiB is the largest that is known to work), so a model
 whose state is larger declares it in parts and the constructor refuses
-a larger buffer.
+a larger buffer.  **What counts is what the device holds**
+(:func:`state_buffer_bytes`): lanes in tiles of 128 and rows in sublane
+groups, so 3 layers x 256 slots x 30 heads of 96 x 192 float32 are 1.58
+GiB of values and 2.11 GiB as ``(.., 30, 96, 192)``, refused, while the
+same values as ``(.., 96, 30 x 192)`` (``models/olmo_hybrid.py``: 45
+whole lane tiles a row) are 1.58 GiB on the device too.
 **Nothing is snapshotted**: a preempted request's second prefill
 rebuilds its state from its tokens.  That is exact for a bounded past,
 and for the whole past it is the prefill's scan over prompt + generated
@@ -112,16 +120,25 @@ STATE_BUFFER_BYTES = 1 << 31
 
 def state_buffer_bytes(layers: int, slots: int, shape, itemsize: int) -> int:
     """Bytes of the ``(layers, slots, *shape)`` buffer of one declared
-    shape; a ``ValueError`` over :data:`STATE_BUFFER_BYTES`."""
-    nbytes = int(layers) * int(slots) * int(np.prod(shape, dtype=np.int64)) \
-        * int(itemsize)
+    shape AS THE DEVICE LAYS IT OUT: the minor dimension in whole tiles
+    of 128 lanes and the one before it in whole sublane groups (8 rows
+    of 4 bytes, 16 of 2), so a float32 ``(.., 96, 192)`` counts 256
+    lanes a row, a third more than its values; a ``ValueError`` over
+    :data:`STATE_BUFFER_BYTES`, which says both counts."""
+    dims = (int(layers), int(slots)) + tuple(int(n) for n in shape)
+    rows = 8 * max(1, 4 // int(itemsize))
+    laid = dims[:-2] + (-(-dims[-2] // rows) * rows, -(-dims[-1] // 128) * 128)
+    nbytes = int(np.prod(laid, dtype=np.int64)) * int(itemsize)
     if nbytes > STATE_BUFFER_BYTES:
+        values = int(np.prod(dims, dtype=np.int64)) * int(itemsize)
         raise ValueError(
             f"a slot-state buffer of {layers} layers x {slots} slots x "
-            f"{tuple(shape)} is {nbytes / 2 ** 30:.2f} GiB: over 2 GiB the "
-            "served tokens came out wrong on the chip (PERF.md section 6, "
-            "PR 44); the model declares such a state in parts (state_spec's "
-            "shapes: one buffer a shape)")
+            f"{tuple(shape)} is {nbytes / 2 ** 30:.2f} GiB as the device "
+            f"lays it out ({values / 2 ** 30:.2f} GiB of values; lanes in "
+            "tiles of 128, rows in sublane groups): over 2 GiB the served "
+            "tokens came out wrong on the chip (PERF.md section 6, PR 44); "
+            "the model declares such a state in a shape that is dense in "
+            "lanes, or in parts (state_spec's shapes: one buffer a shape)")
     return nbytes
 
 

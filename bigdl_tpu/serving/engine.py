@@ -60,7 +60,8 @@ and never asks the name of (``serving/steps.py``, one class a kind,
 each with its contract): no further declaration, ``OneToken``
 (``models/transformer.py``, ``models/longcat_flash.py``); ``state_spec``,
 the same with state a slot carries beside its pages (``models/zaya.py``,
-``models/falcon_h1.py``, ``models/ling_flash.py``); ``draft_spec``, ``Drafting``, one or two
+``models/falcon_h1.py``, ``models/ling_flash.py``,
+``models/olmo_hybrid.py``); ``draft_spec``, ``Drafting``, one or two
 tokens a slot (``models/joyai_flash.py``); ``block_spec``, ``Block``, a
 block refined in place (``models/sdar_moe.py``).  Sampling, buckets,
 donation, the page tables, spans and ``stats()`` are the engine's; the

@@ -147,7 +147,8 @@ class OneToken:
 
     **With state a slot carries that is not keys and values**
     (``state_spec(params)`` -> ``{"layers", "shapes", "dtype"}``:
-    ``models/zaya.py``, ``models/falcon_h1.py``, ``models/ling_flash.py``;
+    ``models/zaya.py``, ``models/falcon_h1.py``, ``models/ling_flash.py``,
+    ``models/olmo_hybrid.py``;
     ``serving/cache.py`` keeps it beside the pages and tells its life,
     its cost and why a preemption snapshots nothing; ``layers`` counts
     the layers that KEEP state and ``cache_spec``'s the layers that keep

@@ -17,6 +17,11 @@ The load-bearing contracts:
   answer for 1 and 4 positions a slot, 2 and 8 query heads a key head,
   float32 and bfloat16 pools, over several blocks of pages a slot and
   at their edges;
+* one query row a key head over a pool too large to gather (by the
+  pool's shape alone) goes through the page stream too, by a body that
+  lays the queries block-diagonally: it gives the oracle's answer and
+  the gather's at 6 and 30 heads of 128 lanes, and a pool of GPT-2 XL's
+  size, or rows that are no whole lane tiles, stay the gather;
 * ``lengths`` of rank 2 gives every position of a slot a length of its
   own (a position that attends nothing comes out zero); one length a
   slot builds the program it built before (operations counted on the
@@ -588,6 +593,131 @@ class TestThePathIsChosenByTheShapes:
         assert "stablehlo.gather" not in text
 
 
+    @staticmethod
+    def _one_row(monkeypatch, b, heads, d, pages, page=16):
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+        S = jax.ShapeDtypeStruct
+        pool = S(pool_shape(pages, page, heads, d), jnp.bfloat16)
+        return jax.jit(
+            lambda q, kp, vp, t, l: paged_decode_attention(
+                q, kp, vp, t, l, page_size=page)).trace(
+            S((b, heads, d), jnp.bfloat16), pool, pool,
+            S((b, 32), jnp.int32), S((b,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def test_one_query_row_a_key_head_streams_a_pool_too_large_to_gather(
+            self, monkeypatch):
+        """A layer's pool over ``_GATHER_POOL_BYTES`` (the gathered copy
+        of a full table is the pool's size again): 30 heads of 128 in
+        32769 pages of 16 are 4 GB; the same heads in 2048 pages (252
+        MB) are the gather, as are GPT-2 XL's 481 pages of 25 x 64 and,
+        whatever the pool's size, rows that are no whole lane tiles."""
+        big = self._one_row(monkeypatch, 256, 30, 128, 32769)
+        assert big.count("tpu_custom_call") == 1
+        assert 'kernel_name = "single_decode_attention"' in big
+        assert "stablehlo.gather" not in big
+        for b, heads, d, pages in [(256, 30, 128, 2048), (12, 25, 64, 481),
+                                   (256, 25, 64, 65536)]:
+            text = self._one_row(monkeypatch, b, heads, d, pages)
+            assert "tpu_custom_call" not in text, (heads, d, pages)
+            assert text.count("#stablehlo.gather<") == 2
+        assert D._GATHER_POOL_BYTES == 256 << 20
+
+
+class TestSingleRowStreamParity:
+    """ONE query row a key head through the page stream
+    (``_single_kernel``): reached here by taking the pool's size out of
+    the choice.  Rows of ``hkv`` x 128 lanes in pages of 16."""
+    P, MAXP = 16, 40
+
+    @pytest.fixture(autouse=True)
+    def _stream_everything(self, monkeypatch):
+        monkeypatch.setattr(D, "_GATHER_POOL_BYTES", 0)
+
+    def _lengths(self, hkv, dtype):
+        bp = D._block_pages(self.P, hkv * 128, jnp.dtype(dtype).itemsize,
+                            hkv)
+        assert 5 <= bp < self.MAXP      # 30 heads in float32: 5 pages
+        edge = bp * self.P
+        return [0, self.MAXP * self.P - 1, edge - 1, edge, 3 * self.P - 1,
+                edge + self.P + 3, None]
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layout", ["pool", "stacked"])
+    @pytest.mark.parametrize("hkv", [6, 30])
+    def test_the_streamed_and_the_gathered_body_agree(self, hkv, layout,
+                                                      dtype, monkeypatch):
+        """Against the float64 oracle, and against the gather body over
+        the same arguments (6 heads, and the served 30: neither a power
+        of two): both masks, both softmaxes, the heads' own lanes."""
+        q, kp, vp, tbl, lens = _grouped_state(
+            self._lengths(hkv, dtype), 1, 1, hkv=hkv, p=self.P,
+            maxp=self.MAXP, dtype=dtype, seed=hkv)
+        want = _grouped_reference(q, kp, vp, tbl, lens, hkv)
+        kw = {}
+        if layout == "stacked":     # read in place at layer 1 of 3
+            kp, vp, kw = _stacked(kp, 1), _stacked(vp, 1), {"layer": 1}
+        assert D._streams(kp)
+        got = paged_decode_attention(q[:, 0], kp, vp, tbl, lens,
+                                     page_size=self.P, **kw)
+        assert got.dtype == q.dtype and got.shape == q[:, 0].shape
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64).reshape(want.shape), want, atol=tol)
+        monkeypatch.setattr(D, "_GATHER_POOL_BYTES", 1 << 40)
+        assert not D._streams(kp)
+        gathered = paged_decode_attention(q[:, 0], kp, vp, tbl, lens,
+                                          page_size=self.P, **kw)
+        live = np.asarray(lens) >= 0
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64)[live],
+            np.asarray(gathered, np.float64)[live], atol=2 * tol)
+        # (B, 1, H, Dh) is the same call
+        monkeypatch.setattr(D, "_GATHER_POOL_BYTES", 0)
+        np.testing.assert_array_equal(
+            np.asarray(paged_decode_attention(
+                q, kp, vp, tbl, lens, page_size=self.P, **kw))[:, 0],
+            np.asarray(got))
+
+    def test_trash_page_and_unnamed_pages_never_reach_an_output(self):
+        q, kp, vp, tbl, lens = _grouped_state(
+            [5, 200, None, 17 * 16 - 1], 1, 1, hkv=6, p=self.P,
+            maxp=self.MAXP, seed=2)
+        clean = paged_decode_attention(q[:, 0], kp, vp, tbl, lens,
+                                       page_size=self.P)
+        named = np.unique(np.asarray(tbl))
+        free = np.setdiff1d(np.arange(kp.shape[0]), named)
+        dirty = paged_decode_attention(
+            q[:, 0], kp.at[0].set(1e30).at[free].set(np.nan),
+            vp.at[0].set(1e30).at[free].set(np.nan), tbl, lens,
+            page_size=self.P)
+        live = np.asarray(lens) > 0
+        np.testing.assert_array_equal(np.asarray(dirty)[live],
+                                      np.asarray(clean)[live])
+
+    def test_a_table_cut_to_its_bucket_changes_no_bit(self):
+        lengths = [None, self.P - 1, self.P, 2 * self.P - 1]
+        q, kp, vp, tbl, lens = _grouped_state(
+            lengths, 1, 1, hkv=6, p=self.P, maxp=self.MAXP, seed=4)
+        full, cut = (paged_decode_attention(q[:, 0], kp, vp, t, lens,
+                                            page_size=self.P)
+                     for t in (tbl, tbl[:, :2]))
+        np.testing.assert_array_equal(np.asarray(cut), np.asarray(full))
+
+    def test_a_wide_page_takes_a_whole_trip_of_copies(self):
+        """Pages of 16 rows of 30 x 128 bfloat16 lanes are 122,880 B:
+        640 KB hold 5 of them, and a block is 8 (one trip of the issue
+        loop, 128 positions); a page twice as wide still fits a trip
+        into twice those bytes, one four times as wide takes the 2 pages
+        that do."""
+        assert D._block_pages(16, 3840, 2, 30) == 8
+        assert D._block_pages(16, 3840, 4, 30) == 5
+        assert D._block_pages(16, 5120, 2, 40) == 8
+        assert D._block_pages(16, 15360, 2, 120) == 2
+
+
 class TestLatentDecodeParity:
     SCALE, VW = 0.3, 16
 
@@ -899,6 +1029,9 @@ _STREAM_CELLS = {
     # block (PR 41), and still 32 pages a block
     "sdar_moe_block_gen": (512, 256, 32, 1.275),
     "zaya1_cca_long_gen": (256, 8, 64, 1.58),
+    # one query row a key head through the stream (PR 48): a block is
+    # one trip of 8 pages, so whole blocks ARE the groups
+    "olmo_hybrid_gdn_long_gen": (3840, 30, 8, 1.07),
 }
 
 

@@ -251,6 +251,8 @@ def _layer_cases():
         (N.Mamba2Mixer(6, 4, 2, 4, 2, chunk=2, in_multiplier=0.5,
                        zone_multipliers=(0.5, 1.0, 2.0, 1.0, 0.5)), seq),
         (N.DeltaMixer(6, 2, 4, 4, chunk=4, sub=2), seq),
+        (N.GatedDeltaMixer(6, 3, 4, 8, chunk=4), seq),
+        (N.GatedDeltaMixer(6, 2, 8, 4, beta_max=1.0, chunk=2), seq),
         (N.LatentAttention(6, 2, None, 4, 2, 2, 2, kv_scale=1.0,
                            head_gate=True), seq),
     ]

@@ -512,6 +512,23 @@ def _whole_cache_ops(compiled, buf) -> dict:
     return ops
 
 
+def _laid_out_bytes(compiled, buf) -> int:
+    """Bytes of a parameter of ``buf``'s shape as the compiled program
+    lays it out: its tiling ``T(rows, lanes)`` rounds the two minor
+    dimensions up."""
+    import math
+    import re
+
+    dims = ",".join(str(n) for n in buf.shape)
+    m = re.search(r"f32\[%s\]\{[\d,]+:T\((\d+),(\d+)\)" % dims,
+                  compiled.as_text())
+    rows, lanes = int(m.group(1)), int(m.group(2))
+    shape = list(buf.shape)
+    shape[-2] = -(-shape[-2] // rows) * rows
+    shape[-1] = -(-shape[-1] // lanes) * lanes
+    return 4 * math.prod(shape)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("row_align", [128, 1])
 def test_latent_engine_programs_work_on_the_cache_as_it_lies(one_chip,
@@ -1113,6 +1130,155 @@ def test_kda_engine_programs_work_on_cache_and_state_as_they_lie(
         assert mem.temp_size_in_bytes < 400e6, (name, mem.temp_size_in_bytes)
 
 
+# (benchmarks/configs/olmo_hybrid_7b.json): 256 slots, three linear
+# layers' stacked float32 state, 30 heads of 96 x 192 kept (96, 5760)
+GDN = dict(layers=3, slots=256, heads=30, dk=96, dv=192)
+
+
+def test_gdn_state_update_is_one_kernel_in_place_at_the_engine_shape():
+    """The gated delta rule's decode step at the cell's shape: ONE Mosaic
+    call, 8 slots of 2 heads (3 whole lane tiles) a grid step, and the
+    stacked state, its heads along the lanes, its input AND its output
+    (operand 1, behind the prefetched layer)."""
+    from bigdl_tpu.ops import delta_state
+
+    c = GDN
+    f32 = jnp.float32
+    row = ((c["slots"], c["heads"], c["dk"]), f32)
+    gate = ((c["slots"], c["heads"]), f32)
+    shapes = (((c["layers"], c["slots"], c["dk"], c["heads"] * c["dv"]),
+               f32), ((1,), jnp.int32), gate, row, row,
+              ((c["slots"], c["heads"] * c["dv"]), f32), gate)
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    lowered = delta_state._lane_program(False).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert lowered.count("tpu_custom_call") == 1
+    assert 'kernel_name = "gdn_state_update"' in lowered
+    assert "output_operand_aliases" in lowered
+    assert delta_state._lane_block(c["slots"], c["heads"], c["dk"],
+                                   c["dv"]) == (8, 2)
+    # what the first kernel's layout cannot offer at this shape
+    with pytest.raises(ValueError, match="sublane"):
+        delta_state._heads_a_block(c["heads"], c["dk"] * c["dv"] * 4)
+
+
+def test_one_query_row_a_key_head_streams_at_the_engine_shape(monkeypatch):
+    """Olmo-Hybrid's full attention at the cell's size: 30 query heads
+    over 30 key heads of 128 lanes, 256 slots, a pool of 32769 pages of
+    16 rows of 7,680 B (4 GB a buffer): ONE Mosaic call and no gather,
+    chosen by the pool's shape alone; the same call over GPT-2 XL's pool
+    (481 pages of 25 heads of 64) is the gather and no kernel."""
+    from bigdl_tpu.ops import decode_attention as da
+
+    def text(slots, heads, d, pages):
+        bf = jnp.bfloat16
+        pool = ((1, pages, 16, heads * d), bf)
+        shapes = (((slots, heads, d), bf), pool, pool,
+                  ((slots, 32), jnp.int32), ((slots,), jnp.int32))
+        args = [jax.ShapeDtypeStruct(s, t) for s, t in shapes]
+        return jax.jit(lambda q, kp, vp, tb, ln: da.paged_decode_attention(
+            q, kp, vp, tb, ln, page_size=16, layer=0)).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "some_new_chip")
+    lowered = text(256, 30, 128, 1 + 256 * 128)
+    assert lowered.count("tpu_custom_call") == 1
+    assert 'kernel_name = "single_decode_attention"' in lowered
+    assert "stablehlo.gather" not in lowered
+    # a block is one whole trip of copies: 8 pages of 122,880 B
+    assert da._block_pages(16, 3840, 2, 30) == 8
+    small = text(12, 25, 64, 481)
+    assert "tpu_custom_call" not in small and "stablehlo.gather" in small
+
+
+@pytest.mark.slow
+def test_gdn_engine_programs_work_on_cache_and_state_as_they_lie(
+        one_chip, monkeypatch):
+    """Olmo-Hybrid-7B's decode step and a prefill of 256 at the published
+    widths and the cell's size (4 layers, 256 slots, 32769 pages of ONE
+    cached layer in two buffers, three layers of float32 state) compiled
+    for the described v5e with the pools AND the state donated: ONE
+    ``gdn_state_update`` program called once a linear layer and one
+    ``single_decode_attention``, NO COPY of the slots' ``S`` (one array
+    of 1.70 GB, exactly its values' bytes: no padded lane) and under 1
+    GB of temporaries."""
+    import functools
+    import json
+
+    from benchmarks.reference import olmo_hybrid_7b as ref
+    from bigdl_tpu.models.olmo_hybrid import build_olmo_hybrid
+    from bigdl_tpu.serving.engine import LMEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "olmo_hybrid_7b.json"), encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    dt, slots, page, max_len = jnp.bfloat16, 256, 16, 2048
+    pages = 1 + slots * (max_len // page)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(functools.partial(
+        ref.init_params, 1, ref.sizes_of(cfg), dt))
+    weights = jax.tree.map(lambda a: spec(a.shape, a.dtype), shapes)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+    assert 3.20e9 < held < 3.21e9
+    probe = build_olmo_hybrid(cfg, params=weights)
+    cs, ss = probe.cache_spec(weights), probe.state_spec(weights)
+    assert (cs["layers"], cs["row_width"], cs["buffers"], cs["heads"],
+            cs["kv_heads"], cs["attn_query_rows"]) == (1, 3840, 2, 30, 30,
+                                                       30)
+    assert ss["layers"] == 3 and ss["keeps_inactive"]
+    assert ss["shapes"] == ((96, 5760), (3, 11520))
+    buf = spec((1, pages, page, 3840), dt)
+    state = tuple(spec((3, slots) + shp, ss["dtype"])
+                  for shp in ss["shapes"])
+    eng = _engine_of_shapes(probe, weights, page, max_len, (buf, buf),
+                            state=state)
+    key = spec((), jax.random.key(0).dtype)
+    ints = spec((slots,), jnp.int32)
+    flags = spec((slots,), jnp.bool_)
+    programs = {
+        "step": LMEngine._build_step(eng).lower(
+            weights, buf, buf, *state, spec((slots, 128), jnp.int32), ints,
+            ints, spec((slots,), jnp.float32), flags, key),
+        "prefill256": LMEngine._prefill_fn(eng, 256).lower(
+            weights, buf, buf, *state, spec((1, 256), jnp.int32),
+            spec((), jnp.int32), spec((256 // page,), jnp.int32),
+            spec((), jnp.float32), key, spec((), jnp.int32), ints)}
+    s_bytes = 3 * slots * 96 * 5760 * 4
+    for name, lowered in programs.items():
+        text = lowered.as_text()
+        if name == "step":
+            assert _kernel_calls(text, "gdn_state_update") == 3
+            assert _kernel_calls(text, "single_decode_attention") == 1
+            assert "stablehlo.gather" not in text.split(
+                'kernel_name = "single_decode_attention"')[0].rsplit(
+                "func.func private", 1)[-1]
+        else:
+            assert "gdn_state_update" not in text
+        compiled = lowered.compile()
+        ops = _whole_cache_ops(compiled, buf)
+        kept = _whole_cache_ops(compiled, state[0])
+        mem = compiled.memory_analysis()
+        laid = _laid_out_bytes(compiled, state[0])
+        print(f"gdn {name}: whole-cache instructions {ops}, whole-state "
+              f"instructions {kept}, arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e6:.1f} MB, S as laid out "
+              f"{laid} B against {s_bytes} B of values")
+        # a pool of ONE cached layer: its layer axis goes by a bitcast
+        assert set(ops) <= {"parameter", "scatter", "scatter fusion",
+                            "bitcast"}, (name, ops)
+        assert "copy" not in kept and "copy-start" not in kept, (name, kept)
+        # the compiler's layout of S: tiles of (8, 128) on (96, 5760),
+        # nothing padded
+        assert laid == s_bytes < 1 << 31
+        assert 13.0e9 < mem.argument_size_in_bytes < 13.3e9
+        assert mem.temp_size_in_bytes < 1e9, (name, mem.temp_size_in_bytes)
+
+
 # ---------------------------------------------------------------------------
 # The serving programs of the models that were there before a model
 # whose state sums over the whole past: PR 39 gave the engine a branch
@@ -1144,6 +1310,10 @@ def test_kda_engine_programs_work_on_cache_and_state_as_they_lie(
 # with their 32 values a row padded to a lane tile
 # (``TransformerLM.serving_tables``) and cut the pad off the gathered
 # rows; the six others are handed ``params`` itself and kept PR 45's.
+# PR 48 meant to change NONE of the seven (``nn/delta.py``'s step and
+# scan run over hooks that trace, for KDA, the operations they traced;
+# one query row a key head over GPT-2 XL's pool is still the gather) and
+# pinned the eighth, Olmo-Hybrid, with its own tree's.
 LOWERED = {
     "tiny_gpt": ("c0f5691a07849545", "ee822d4e8e31c000"),
     "tiny_longcat": ("fd1e74384430ef10", "cbd0b59cc7e05e3c"),
@@ -1152,6 +1322,7 @@ LOWERED = {
     "tiny_zaya": ("95eeaa57c6ba2a7b", "eefa0a1999f540d7"),
     "tiny_falcon_h1": ("43c0f50921a88202", "9fbc61e0ba1b9d4d"),
     "tiny_ling": ("f5cc2d97ad984648", "2361fdbd91f49a64"),
+    "tiny_olmo_hybrid": ("b07a3e7b0950adaf", "95a61bc245efff41"),
 }
 
 
@@ -1245,6 +1416,7 @@ KINDS = {
     "tiny_zaya": ("OneToken", True, set()),
     "tiny_falcon_h1": ("OneToken", True, set()),
     "tiny_ling": ("OneToken", True, set()),
+    "tiny_olmo_hybrid": ("OneToken", True, set()),
     "tiny_joyai": ("Drafting", False, {"drafts_verified",
                                        "draft_accept_share"}),
     "tiny_sdar": ("Block", False, {"block_passes", "block_tails",
