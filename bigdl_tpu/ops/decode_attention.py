@@ -59,12 +59,32 @@ masked.  Over the long-generation mixes' lengths that is 1.07 rows
 copied for every row a slot holds, where whole blocks copied 1.275 (32
 pages a block) and 1.58 (ZAYA1's 64); :func:`stream_rows_copied` is
 the count, and the engine puts it on ``serve.decode_step``.
+**With how many copies** (PR 49): a group whose table entries name
+neighbouring pages (``first, first + 1, ...`` for the pages the slot
+holds of it, and ``first + 8`` inside the pool) is ONE copy of 8 pages,
+64-160 KB of a layer's pool as they lie; any other group is a copy a
+page.  The scalar core that starts a block's copies runs in the same
+instruction stream as the block's products, so a kernel's time is its
+products PLUS the issue of its descriptors, and at a descriptor a page
+that issue was as long as the products (cells 4-6) or twice the bytes'
+transfer (cell 7's 8 KB pages).  Which groups are runs is decided
+from the table alone, in the program around the kernel
+(:func:`_run_starts`: the same comparisons cost the kernel's scalar
+core 7 descriptors' time a group); ``serving/cache.py``'s allocator
+makes runs the usual case.  Of
+a slot's last group a run's copy brings along the pages BEHIND the
+slot's, as they lie (another slot's rows, or the zeros the pool starts
+with): finite and masked like everything else past the length, so any
+table gives the bits a copy a page gives.  :func:`stream_copies` is the
+count (``attn_copies`` on the span).
 
 Mask contract (every path, pinned by tests): position ``pos <=
 length`` attends, everything else is ``-inf`` before the softmax — so
 page 0 (the reserved trash page unallocated table entries point at)
 can hold arbitrary finite garbage and never contributes a bit to any
-output.
+output, and neither can any other page of the pool (the kernels may
+read the 7 pages behind a slot's last one: the pool holds finite rows
+everywhere, which zeros and written rows are).
 
 The engine slices each step's page tables to the used-page bucket
 (:func:`used_page_bucket`): the pow2 count of pages covering
@@ -154,6 +174,34 @@ def stream_rows_copied(lengths, page_size: int, maxp: int, row_width: int,
     whole, last = np.divmod(need - 1, bp)    # last block: ``last + 1`` pages
     pages = whole * bp + _groups(last + 1, trip) * trip
     return int(pages.sum()) * page_size
+
+
+def stream_copies(tables, lengths, page_size: int, num_pages: int,
+                  row_width: int, itemsize: int, query_rows: int) -> int:
+    """Copy descriptors that ONE call of a kernel's page stream starts a
+    pool for slots that attend ``pos <= lengths`` over the table rows
+    ``tables`` (one a slot, as the step was handed them), by the
+    kernel's own arithmetic (:func:`_run_starts`): a slot
+    copies the groups of ``_COPIES_A_TRIP`` pages that hold its
+    ``length // P + 1`` pages; a group whose held entries name
+    neighbouring pages, with a whole group's pages from the first
+    inside the pool of ``num_pages``, is ONE descriptor, any other one
+    a page.  :func:`stream_rows_copied` over the page and over this is
+    the pages a descriptor: 1 under a scattered table, the trip under a
+    table of runs (the engine puts it on ``serve.decode_step``,
+    ``attn_copies``)."""
+    import numpy as np
+
+    tables = np.asarray(tables, np.int32)
+    bp = _block_pages(page_size, row_width, itemsize, query_rows)
+    trip = min(_COPIES_A_TRIP, bp)
+    need = np.clip(np.asarray(lengths, np.int64) // page_size + 1, 1,
+                   tables.shape[1])
+    groups = _groups(tables.shape[1], trip)
+    starts = _run_starts(tables, need, bp, num_pages, np) \
+        .reshape(len(tables), groups)
+    copied = np.arange(groups) * trip < need[:, None]
+    return int(np.where(starts >= 0, 1, trip)[copied].sum())
 
 
 # --------------------------------------------------------------------------
@@ -337,13 +385,49 @@ def _groups(pages, trip: int):
     return (pages + trip - 1) // trip
 
 
-def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
-    """What both kernels do about their pages, inside the kernel body
+def _run_starts(tables, need, bp: int, num_pages: int, xp=None):
+    """Which groups of a kernel's stream are RUNS, decided from the
+    table alone in the program around the kernel (a few vector
+    operations over ``(B, maxp)``, where the kernel's scalar core would
+    spend about 130 ns a group on the same comparisons: as long as 7 of
+    the 8 descriptors they save; chip run, PR 49): for every group of
+    ``min(_COPIES_A_TRIP, bp)`` table entries, flattened ``(B x
+    groups,)`` int32, the page its ONE copy starts from where the
+    entries the slot holds of it (``need``) name neighbouring pages
+    ``first, first + 1, ...`` and a whole group's pages from ``first``
+    lie inside the pool of ``num_pages``; -1 where the group is copied
+    a page at a time.  ``xp`` is ``jax.numpy``, or ``numpy`` where
+    :func:`stream_copies` counts the same on the host."""
+    if xp is None:
+        import jax.numpy as xp
+
+    b, maxp = tables.shape
+    trip = min(_COPIES_A_TRIP, bp)
+    groups = _groups(maxp, trip)
+    ent = tables.astype(xp.int32)
+    if groups * trip > maxp:
+        # past the table's width: its last entry again, as the kernel
+        # reads
+        ent = xp.pad(ent, ((0, 0), (0, groups * trip - maxp)), mode="edge")
+    ent = ent.reshape(b, groups, trip)
+    first = ent[:, :, 0]
+    at = xp.arange(groups, dtype=xp.int32) * trip - need.astype(
+        xp.int32)[:, None]            # entry ``j`` is held where at + j < 0
+    run = first + trip <= num_pages
+    for j in range(1, trip):
+        run = run & ((ent[:, :, j] == first + j) | (at + j >= 0))
+    return xp.where(run, first, -1).reshape(-1)
+
+
+def _page_stream(tables, need, starts, layer, ring, streams, bp: int,
+                 maxp: int):
+    """What the kernels do about their pages, inside the kernel body
     (one grid step a slot): the blocks of ``bp`` pages of all slots, in
     order, are one stream through a ring of buffers: all but one
     block's copies are in flight while one is contracted, across the
     slots' edges.  ``tables`` (flattened, ``maxp`` a slot), ``need``
-    (the pages a slot must read) and ``layer`` are scalar prefetch;
+    (the pages a slot must read), ``starts`` and ``layer`` are scalar
+    prefetch;
     ``streams`` is one ``(pool, buffers, semaphores)`` a pool read: the
     pool in HBM, read at ``[layer, page]``, a ring of buffers ``(n,
     bp, P, row)`` and a DMA semaphore a buffer; ``ring`` four SMEM
@@ -359,6 +443,19 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
     every position past the slot's length, so they meet probability
     exactly 0 and add exactly 0: a block contracted whole gives the
     bits it would give with the whole block copied.
+
+    **A group that is a run is ONE copy**: ``starts`` (scalar prefetch
+    too; :func:`_run_starts`, from the table alone) holds for every
+    group of a slot's table the page its copy starts from, where the
+    entries the slot holds of it are ``first, first + 1, ...`` and
+    ``first + _COPIES_A_TRIP`` pages lie inside the pool: the scalar
+    core reads that one number and starts one descriptor of
+    ``_COPIES_A_TRIP`` pages a pool from ``[layer, first]``; under a -1
+    one a page from the table's entries, as before PR 49.  Either way
+    the group's bytes are the same and so is the wait (a DMA semaphore
+    counts bytes).  Behind the pages a slot holds of its last group a
+    run's copy reads the pool as it lies, where a copy a page reads
+    what the table names there (page 0): finite and masked both.
 
     Returns ``(blocks, next_block)``: the blocks of this grid step's
     slot, and a function of the slot's block index that puts one more
@@ -391,18 +488,34 @@ def _page_stream(tables, need, layer, ring, streams, bp: int, maxp: int):
             half = ring[2] % nbuf
 
             def group(g, c):
-                for j in range(unroll):
-                    j += g * unroll
-                    # past the slot's last page, in its last group:
-                    # what the table names there (page 0, finite by
-                    # the cache's contract; past the table's width,
-                    # its last entry again)
-                    pg = tables[slot * maxp
-                                + jnp.minimum(blk * bp + j, maxp - 1)]
+                j0 = g * unroll
+                first = starts[slot * _groups(maxp, unroll)
+                               + blk * (bp // unroll) + g]
+
+                def run():
                     for pool, buf, sems in streams:
-                        pltpu.make_async_copy(pool.at[lyr, pg],
-                                              buf.at[half, j],
-                                              sems.at[half]).start()
+                        pltpu.make_async_copy(
+                            pool.at[lyr, pl.ds(first, unroll)],
+                            buf.at[half, pl.ds(j0, unroll)],
+                            sems.at[half]).start()
+
+                def pages():
+                    for j in range(unroll):
+                        # past the slot's last page, in its last
+                        # group: what the table names there (page 0,
+                        # finite by the cache's contract; past the
+                        # table's width, its last entry again)
+                        pg = tables[slot * maxp + jnp.minimum(
+                            blk * bp + j0 + j, maxp - 1)]
+                        for pool, buf, sems in streams:
+                            pltpu.make_async_copy(pool.at[lyr, pg],
+                                                  buf.at[half, j0 + j],
+                                                  sems.at[half]).start()
+
+                if unroll > streams[0][0].shape[1]:
+                    pages()     # a pool smaller than a run holds none
+                else:
+                    lax.cond(first >= 0, run, pages)
                 return c
 
             lax.fori_loop(0, groups_of(slot, blk), group, 0)
@@ -489,7 +602,7 @@ def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int,
 
     rows_blk = bp * page
 
-    def kernel(tables, need, *refs):
+    def kernel(tables, need, starts, *refs):
         if per_row:
             (layer, q_ref, lens, kpool, vpool, o_ref,
              kbuf, vbuf, ksems, vsems, ring) = refs
@@ -497,7 +610,7 @@ def _grouped_kernel(bp: int, page: int, maxp: int, hkv: int, d: int,
             (lens, layer, q_ref, kpool, vpool, o_ref,
              kbuf, vbuf, ksems, vsems, ring) = refs
         nblk, next_block = _page_stream(
-            tables, need, layer, ring,
+            tables, need, starts, layer, ring,
             ((kpool, kbuf, ksems), (vpool, vbuf, vsems)), bp, maxp)
         # a length a query row, (R, 1) beside the queries; or the slot's
         # one, a prefetched scalar
@@ -547,10 +660,10 @@ def _single_kernel(bp: int, page: int, maxp: int, hkv: int, d: int):
 
     rows_blk, row = bp * page, hkv * d
 
-    def kernel(tables, need, lens, layer, q_ref, kpool, vpool, o_ref,
-               kbuf, vbuf, ksems, vsems, ring):
+    def kernel(tables, need, starts, lens, layer, q_ref, kpool, vpool,
+               o_ref, kbuf, vbuf, ksems, vsems, ring):
         nblk, next_block = _page_stream(
-            tables, need, layer, ring,
+            tables, need, starts, layer, ring,
             ((kpool, kbuf, ksems), (vpool, vbuf, vsems)), bp, maxp)
         length = lens[pl.program_id(0)]
         head = lax.broadcasted_iota(jnp.int32, (hkv, row), 0) * d
@@ -619,6 +732,8 @@ def _grouped_program(scale: float, interpret: bool):
         else:
             scalars, rows, row_specs = (lens, layer), [], []
         need = jnp.clip(lens // p + 1, 1, maxp)
+        flat = (tables.reshape(-1).astype(jnp.int32), need,
+                _run_starts(tables, need, bp, kpool.shape[1]))
         buffers = pltpu.VMEM((_BUFFERS, bp, p, row), kpool.dtype)
         sems = pltpu.SemaphoreType.DMA((_BUFFERS,))
         scratch = [buffers, buffers, sems, sems, pltpu.SMEM((4,), jnp.int32)]
@@ -629,7 +744,7 @@ def _grouped_program(scale: float, interpret: bool):
                 _single_kernel(bp, p, maxp, hkv, d),
                 out_shape=jax.ShapeDtypeStruct((b, 1, row), q.dtype),
                 grid_spec=pltpu.PrefetchScalarGridSpec(
-                    num_scalar_prefetch=4,
+                    num_scalar_prefetch=5,
                     grid=(b,),
                     in_specs=[
                         pl.BlockSpec((1, hkv, d), lambda i, *_: (i, 0, 0)),
@@ -643,10 +758,10 @@ def _grouped_program(scale: float, interpret: bool):
                     dimension_semantics=("arbitrary",)),
                 interpret=interpret,
                 name="single_decode_attention",
-            )(tables.reshape(-1).astype(jnp.int32), need, *scalars,
-              qs.reshape(b, hkv, d), kpool, vpool).reshape(q.shape)
+            )(*flat, *scalars, qs.reshape(b, hkv, d), kpool,
+              vpool).reshape(q.shape)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 + len(scalars),
+            num_scalar_prefetch=3 + len(scalars),
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, hkv, r, d), lambda i, *_: (i, 0, 0, 0)),
@@ -665,8 +780,7 @@ def _grouped_program(scale: float, interpret: bool):
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="grouped_decode_attention",
-        )(tables.reshape(-1).astype(jnp.int32), need, *scalars, qs, *rows,
-          kpool, vpool)
+        )(*flat, *scalars, qs, *rows, kpool, vpool)
         return out.reshape(b, hkv, s, h // hkv, d).transpose(0, 2, 1, 3, 4) \
             .reshape(q.shape)
 
@@ -688,9 +802,9 @@ def _latent_kernel(bp: int, page: int, maxp: int, vw: int):
 
     rows_blk = bp * page
 
-    def kernel(tables, need, layer, q_ref, len_ref, pool, o_ref,
+    def kernel(tables, need, starts, layer, q_ref, len_ref, pool, o_ref,
                buf, sems, ring):
-        nblk, next_block = _page_stream(tables, need, layer, ring,
+        nblk, next_block = _page_stream(tables, need, starts, layer, ring,
                                         ((pool, buf, sems),), bp, maxp)
         qs = q_ref[0]                                  # (H, R)
         lens = len_ref[0]                              # (H, 1)
@@ -733,8 +847,9 @@ def latent_decode_attention(q, pages, tables, lengths, *, scale: float,
     ``layer`` are scalar prefetch; the pool stays in HBM and a slot's
     pages are copied from ``[layer, page]`` into fast memory a block at
     a time, up to the slot's OWN length (the ``length // P + 1`` pages
-    of its longest query, rounded up to a group of 8: of the last
-    group's pages past the slot's the table names page 0, finite by the
+    of its longest query, rounded up to a group of 8, a group of
+    neighbouring pages in ONE copy: the last group's pages past the
+    slot's are page 0 or the slot's pages' neighbours, finite by the
     cache's contract, and masked; :func:`_page_stream`) — not the
     table's width, and with no gathered copy in HBM — the next blocks'
     copies (at a slot's end: the next slot's first) in flight while
@@ -782,7 +897,7 @@ def _latent_program(scale: float, vw: int, interpret: bool):
         # pages a slot must read: up to its longest query's position
         need = jnp.clip(jnp.max(lens, axis=1) // p + 1, 1, maxp)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, h, r), lambda i, *_: (i, 0, 0)),
@@ -803,11 +918,13 @@ def _latent_program(scale: float, vw: int, interpret: bool):
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="latent_decode_attention",
-        )(tables.reshape(-1).astype(jnp.int32), need, layer, qs,
+        )(tables.reshape(-1).astype(jnp.int32), need,
+          _run_starts(tables, need, bp, pool.shape[1]), layer, qs,
           lens[:, :, None], pool)
 
     return jax.jit(call)
 
 
 __all__ = ["paged_decode_attention", "latent_decode_attention",
-           "used_page_bucket", "decode_hbm_bytes", "stream_rows_copied"]
+           "used_page_bucket", "decode_hbm_bytes", "stream_copies",
+           "stream_rows_copied"]

@@ -7,7 +7,7 @@ keeps its columns hot until the slowest batchmate drains.  Serving
 needs the vLLM-style alternative: K/V live in fixed-size **pages**
 (``page_size`` token rows of ``kv_heads * Dh``), each request owns only
 the pages its tokens actually fill (a per-slot **page table**), pages
-return to a free list the moment a request completes, and a new
+return to the pool the moment a request completes, and a new
 request is admitted into the freed slot at the next step boundary.
 
 Layout (one array per K and V, all layers stacked so the decode step
@@ -98,12 +98,31 @@ prefix: the state a step-by-step run would hold, to rounding
 never cut into chunks (``LMEngine._bucket`` goes to ``max_len``); a
 prefill in chunks would need the state at a chunk's start kept.
 
-The allocator is plain host Python — a free list and per-slot page
-lists.  Decode grows a slot one page at a time as its length crosses a
-page boundary; exhaustion is surfaced to the engine, which preempts the
-youngest request (its pages return to the pool, the request re-queues
-with its generated prefix as prompt) — the standard paged-attention
-answer to overcommit.
+The allocator is plain host Python and numpy — which pages are free,
+how many, how many of each **run** of ``PAGE_RUN`` = 8 pages (pages 1-8,
+9-16, ...), and per-slot page lists.  Pages that are neighbours in the
+pool are one contiguous piece of a layer's HBM, and the decode kernels'
+page stream copies a group of 8 table entries that name neighbours with
+ONE descriptor where it starts one a page for any other (``ops/
+decode_attention.py`` ``_page_stream``: the descriptors, not the bytes,
+bound those kernels; PERF.md section 6, PR 49).  So a slot is handed
+its pages in runs, **by preference and never by reservation**: a prompt
+takes wholly free runs, lowest first, and its last pages from the head
+of one more; decode grows a slot one page at a time as its length
+crosses a page boundary, by the page behind its last while its table's
+group of 8 is open, else the head of the lowest wholly free run, else
+the lowest free page.  What comes out is that table entries ``8k .. 8k
++ 7`` of a slot name neighbouring pages: always under the engine's
+default pool (every slot's longest context: a wholly free run then
+exists whenever a slot starts a group), as far as the pool allows under
+a tighter one.  **How many pages are free, who is admitted, when
+``grow`` fails are what a free list gives for the same calls**: no page
+is held back for a slot that has not asked for it, a tight pool loses
+runs and never capacity (``tests/test_paged_cache_runs.py`` holds the
+two side by side).  Exhaustion is surfaced to the engine, which
+preempts the youngest request (its pages return to the pool, the
+request re-queues with its generated prefix as prompt) — the standard
+paged-attention answer to overcommit.
 """
 
 from __future__ import annotations
@@ -112,6 +131,9 @@ from typing import List, Optional
 
 import numpy as np
 from bigdl_tpu.obs import names
+# the pages of a run: what the decode kernels' stream copies with one
+# descriptor, defined there
+from bigdl_tpu.ops.decode_attention import _COPIES_A_TRIP as PAGE_RUN
 
 
 #: the largest buffer of slot state the cache builds (module docstring)
@@ -198,7 +220,15 @@ class PagedKVCache:
         self.page_tables = np.zeros(
             (self.max_slots, self.max_pages_per_slot), np.int32)
         self.lengths = np.zeros((self.max_slots,), np.int32)
-        self._free: List[int] = list(range(1, self.num_pages))
+        # the allocator's books (module docstring): which pages are
+        # free, how many, and how many of each run of PAGE_RUN pages
+        # (run r: pages 1 + r x PAGE_RUN and the PAGE_RUN - 1 behind
+        # it; the pool's last pages, fewer than a run, are in none)
+        self._is_free = np.ones((self.num_pages,), bool)
+        self._is_free[0] = False
+        self._n_free = self.num_pages - 1
+        self._run_free = np.full(((self.num_pages - 1) // PAGE_RUN,),
+                                 PAGE_RUN, np.int64)
         self._slot_pages: List[List[int]] = [[] for _ in
                                              range(self.max_slots)]
         from bigdl_tpu import obs
@@ -212,44 +242,71 @@ class PagedKVCache:
         return max(1, -(-int(n_tokens) // self.page_size))
 
     def free_pages(self) -> int:
-        return len(self._free)
+        return self._n_free
 
     def pages_in_use(self) -> int:
-        return (self.num_pages - 1) - len(self._free)
+        return (self.num_pages - 1) - self._n_free
 
     def can_admit(self, n_tokens: int) -> bool:
-        return len(self._free) >= self.pages_for(n_tokens)
+        return self._n_free >= self.pages_for(n_tokens)
+
+    def _mark(self, pages, free: bool):
+        """``pages`` leave the pool, or come back to it."""
+        pages = np.asarray(pages, np.int64)
+        self._is_free[pages] = free
+        runs = (pages - 1) // PAGE_RUN
+        step = 1 if free else -1
+        np.add.at(self._run_free, runs[runs < len(self._run_free)], step)
+        self._n_free += step * len(pages)
+        self._pages_gauge.set(float(self.pages_in_use()))
 
     def alloc(self, slot: int, n_tokens: int) -> List[int]:
         """Give ``slot`` enough pages for ``n_tokens``; returns the page
         ids (raises on exhaustion — the engine checks ``can_admit``
-        first and preempts on decode-time growth failure)."""
+        first and preempts on decode-time growth failure).  Wholly free
+        runs, lowest first, the last pages from the head of one more;
+        where those run out, the lowest free pages."""
         need = self.pages_for(n_tokens)
-        if len(self._free) < need:
+        if self._n_free < need:
             raise RuntimeError(
                 f"KV cache exhausted: need {need} pages, "
-                f"{len(self._free)} free")
-        pages = [self._free.pop() for _ in range(need)]
+                f"{self._n_free} free")
+        whole = np.flatnonzero(self._run_free == PAGE_RUN)
+        taken = (1 + whole[:-(-need // PAGE_RUN), None] * PAGE_RUN
+                 + np.arange(PAGE_RUN)).ravel()[:need]
+        self._mark(taken, False)
+        if len(taken) < need:
+            rest = np.flatnonzero(self._is_free)[:need - len(taken)]
+            self._mark(rest, False)
+            taken = np.concatenate([taken, rest])
+        pages = [int(pg) for pg in taken]
         self._slot_pages[slot] = pages
         row = np.zeros((self.max_pages_per_slot,), np.int32)
         row[:need] = pages
         self.page_tables[slot] = row
         self.lengths[slot] = 0
-        self._pages_gauge.set(float(self.pages_in_use()))
         return pages
 
     def grow(self, slot: int) -> bool:
         """One more page for ``slot`` (its length is about to cross a
-        page boundary).  False on exhaustion — the engine preempts."""
-        if not self._free:
+        page boundary).  False on exhaustion — the engine preempts.
+        The page behind the slot's last, while the table's group of
+        PAGE_RUN entries it falls in is open and that page is free;
+        else the head of the lowest wholly free run; else the lowest
+        free page."""
+        if not self._n_free:
             return False
         pages = self._slot_pages[slot]
         if len(pages) >= self.max_pages_per_slot:
             return False
-        page = self._free.pop()
+        page = pages[-1] + 1 if len(pages) % PAGE_RUN else self.num_pages
+        if page >= self.num_pages or not self._is_free[page]:
+            whole = np.flatnonzero(self._run_free == PAGE_RUN)
+            page = 1 + int(whole[0]) * PAGE_RUN if len(whole) \
+                else int(np.argmax(self._is_free))
+        self._mark([page], False)
         pages.append(page)
         self.page_tables[slot, len(pages) - 1] = page
-        self._pages_gauge.set(float(self.pages_in_use()))
         return True
 
     def needs_growth(self, slot: int, ahead: int = 0) -> bool:
@@ -262,11 +319,22 @@ class PagedKVCache:
     def release(self, slot: int):
         """Request finished (or preempted): pages back to the pool, the
         table row points at the trash page again."""
-        self._free.extend(self._slot_pages[slot])
+        self._mark(self._slot_pages[slot], True)
         self._slot_pages[slot] = []
         self.page_tables[slot] = 0
         self.lengths[slot] = 0
-        self._pages_gauge.set(float(self.pages_in_use()))
+
+    def withhold(self, n: int) -> List[int]:
+        """Take the pool's ``n`` highest free pages out of it for no
+        slot (how a test makes a built engine's pool tight); returns
+        them for :meth:`hand_back`."""
+        pages = np.flatnonzero(self._is_free)[self._n_free - int(n):]
+        self._mark(pages, False)
+        return [int(pg) for pg in pages]
+
+    def hand_back(self, pages: List[int]):
+        """Withheld ``pages`` are free again."""
+        self._mark(pages, True)
 
     def slot_pages(self, slot: int) -> List[int]:
         return list(self._slot_pages[slot])

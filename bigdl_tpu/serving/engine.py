@@ -51,7 +51,8 @@ cached layers, the width of a token's row, one buffer or two, the
 longest context, and, where its decode attention is a page-walking
 kernel of ``ops/decode_attention.py``, the query rows a slot it hands
 that kernel (``attn_query_rows``: every ``serve.decode_step`` then says
-what the kernel's stream copied, ``attn_rows_copied``) — and
+what the kernel's stream copied, ``attn_rows_copied``, and with how
+many copy descriptors, ``attn_copies``) — and
 ``paged_prefill`` / ``paged_decode`` over the cache's buffers, with the
 signature of its **kind of step**.  What a kind carries from step to
 step, hands a fresh slot, reads back, how far ahead it names pages and
@@ -163,13 +164,16 @@ class _InFlight:
     model's routing counts or None; ``entries``, the [(slot, _Active)]
     it ran for; ``context_rows``, the rows of context it reads a slot it
     ran for (a one-token model's: the others' come back with the
-    step)."""
+    step); ``tables``, the host's copy of the page tables it was handed
+    (under a page-walking attention kernel, whose copies are counted at
+    the read; else None)."""
 
-    __slots__ = ("result", "counts", "entries", "context_rows")
+    __slots__ = ("result", "counts", "entries", "context_rows", "tables")
 
-    def __init__(self, result, counts, entries, context_rows):
+    def __init__(self, result, counts, entries, context_rows, tables):
         self.result, self.counts = result, counts
         self.entries, self.context_rows = entries, context_rows
+        self.tables = tables
 
 
 class _Prefilled:
@@ -860,6 +864,9 @@ class LMEngine:
                                       self.cache.max_pages_per_slot)
             self._last_bucket = bucket
             tables, lengths = self.cache.device_tables(pages=bucket)
+            # (the tables grow while the step is in flight)
+            host_tables = np.array(self.cache.page_tables[:, :bucket]) \
+                if self._kind.query_rows else None
             # running slots that sample: 0 means the step's pick takes
             # its greedy arm (``sample_step``)
             sampling = sum(act.req.temperature > 0.0 for _, act in acts)
@@ -906,7 +913,7 @@ class LMEngine:
                     act.unread += 1
                 self._inflight = _InFlight(
                     result[0], result[1] if len(result) > 1 else None,
-                    acts, context)
+                    acts, context, host_tables)
             if prev is not None:
                 read = self._read(prev, tracer, step)
                 # what the step just read routed and yielded, and the

@@ -148,6 +148,16 @@ Three families:
                         in groups of 8 pages); over ``context_tokens``
                         it is what the stream reads for every row it
                         must
+  ``attn_copies``       beside ``attn_rows_copied``: the copy
+                        descriptors that call starts a pool for those
+                        rows, over the page tables the step was handed
+                        (``stream_copies``: ONE for a group of 8 table
+                        entries that name neighbouring pages, as
+                        ``serving/cache.py`` hands them out, else one a
+                        page); the rows over the page size over this is
+                        the pages a descriptor, 8 under tables of runs
+                        and 1 under scattered ones
+                        (``stats()["attn_pages_a_copy"]``)
   ====================  ================================================
 
   The registry has the same counts as

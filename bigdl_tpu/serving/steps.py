@@ -173,6 +173,7 @@ class OneToken:
     sure, positions, result_at, greedy_because, handed = 1, 1, 0, None, 1
     # tallies (``stats``): 0 under the kinds that do not count one
     verified = accepted = passes = tails = unmasked = state_rebuilds = 0
+    pages_copied = copies = 0
 
     def __init__(self, model, spec: dict, cache, page_size, eos_id,
                  ops: DeviceOps, guarded: bool = False):
@@ -324,7 +325,8 @@ class OneToken:
         return 0
 
     def read(self, rec, res, slots, note_routing) -> _StepRead:
-        attrs = self._shared_attrs(rec, rec.context_rows, note_routing)
+        attrs = self._shared_attrs(rec, [i for i, _ in rec.entries],
+                                   rec.context_rows, note_routing)
         if self.cache.state:
             # what the step read and wrote of the slots' state: in and
             # out, for the slots that ran
@@ -332,23 +334,32 @@ class OneToken:
                                     * self.slot_state_bytes)
         return _StepRead(res[:, None], None, attrs)
 
-    def _shared_attrs(self, rec, context, note_routing) -> dict:
+    def _shared_attrs(self, rec, ran, context, note_routing) -> dict:
         """An expert model's routing counts and, beside them or a
         slot's state, ``context_tokens``: the sum of ``context`` (the
-        rows a slot the step ran for had to read, its own included),
-        and under a page-walking decode attention kernel
-        ``attn_rows_copied``: the rows one call copies a pool."""
+        rows a slot of ``ran``, those the step ran for, had to read,
+        its own included), and under a page-walking decode attention
+        kernel ``attn_rows_copied`` and ``attn_copies``: the rows one
+        call copies a pool, and the descriptors it starts for them
+        over the tables the step was handed."""
         attrs = {} if rec.counts is None else note_routing(rec.counts)
         if rec.counts is None and not self.cache.state:
             return attrs
         attrs["context_tokens"] = sum(context)
         if self.query_rows:
-            from bigdl_tpu.ops.decode_attention import stream_rows_copied
+            from bigdl_tpu.ops.decode_attention import (stream_copies,
+                                                        stream_rows_copied)
 
-            attrs["attn_rows_copied"] = stream_rows_copied(
-                np.asarray(context, np.int64) - 1, self.page_size,
-                self.cache.max_pages_per_slot, self.cache.row_width,
-                self.cache.dtype.itemsize, self.query_rows)
+            lengths = np.asarray(context, np.int64) - 1
+            shapes = (self.cache.row_width, self.cache.dtype.itemsize,
+                      self.query_rows)
+            rows = stream_rows_copied(lengths, self.page_size,
+                                      rec.tables.shape[1], *shapes)
+            copies = stream_copies(rec.tables[ran], lengths, self.page_size,
+                                   self.cache.num_pages, *shapes)
+            attrs["attn_rows_copied"], attrs["attn_copies"] = rows, copies
+            self.pages_copied += rows // self.page_size
+            self.copies += copies
         return attrs
 
     def yielded(self, slot: int, act: _Active, read: _StepRead) -> tuple:
@@ -378,7 +389,11 @@ class OneToken:
             "block_passes": passes, "block_tails": self.tails,
             "block_commits": 0, "positions_unmasked": self.unmasked,
             "tokens_per_forward": step_tokens / passes if passes else None,
-            "tail_share": self.tails / passes if passes else None}
+            "tail_share": self.tails / passes if passes else None,
+            # a page-walking attention kernel's: the pages its stream
+            # copied a descriptor (8 over tables of runs, 1 scattered)
+            "attn_pages_a_copy": (self.pages_copied / self.copies
+                                  if self.copies else None)}
 
 
 class Drafting(OneToken):
@@ -474,11 +489,12 @@ class Drafting(OneToken):
         # yields (0 where the slot owed nothing), the draft it verified
         # and the slot's length before the step
         first, second, emitted, draft, length = res.T
-        drafts, context = {}, []
+        drafts, ran, context = {}, [], []
         accepted = tokens = 0
         for slot, act in rec.entries:
             if slots[slot] is not act or not emitted[slot]:
                 continue    # completed since, or owed nothing there
+            ran.append(slot)
             # a draft counts as verified where the slot owed the token
             # it drafts (the device's owed count is the host's ``left``
             # once every earlier step is emitted, as here)
@@ -495,7 +511,7 @@ class Drafting(OneToken):
         self.accepted += accepted
         self._counter.labels(outcome="accepted").inc(accepted)
         self._counter.labels(outcome="rejected").inc(len(drafts) - accepted)
-        attrs.update(self._shared_attrs(rec, context, note_routing))
+        attrs.update(self._shared_attrs(rec, ran, context, note_routing))
         return _StepRead(np.stack([first, second], axis=1), emitted, attrs,
                          drafts=drafts)
 
@@ -638,11 +654,12 @@ class Block(OneToken):
         toks, after = res[:, :b], res[:, b:2 * b].astype(bool)
         length, kind, done, tail = res[:, 2 * b:].T    # BLOCK_RESULT
         emitted = np.zeros((self.cache.max_slots,), np.int32)
-        blocks, context = {}, []
+        blocks, ran, context = {}, [], []
         passes = tails = unmasked = left = 0
         for slot, act in rec.entries:
             if slots[slot] is not act or not kind[slot]:
                 continue    # completed since: a wasted block
+            ran.append(slot)
             blocks[slot] = (after[slot], int(kind[slot]), int(done[slot]))
             context.append(int(length[slot]) + b)
             passes += 1
@@ -668,7 +685,7 @@ class Block(OneToken):
         attrs = dict(block_passes=passes, block_tails=tails,
                      block_commits=0, positions_unmasked=unmasked,
                      tokens_emitted=int(emitted.sum()))
-        attrs.update(self._shared_attrs(rec, context, note_routing))
+        attrs.update(self._shared_attrs(rec, ran, context, note_routing))
         return _StepRead(toks, emitted, attrs, blocks=blocks)
 
     def yielded(self, slot: int, act: _Active, read: _StepRead) -> tuple:

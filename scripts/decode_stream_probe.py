@@ -11,13 +11,17 @@ For each cell (slots, the pool's row, the query rows a slot, pages of
 16, a table of 128 pages) it draws every slot's length the way the
 cell's closed-loop mix leaves them in a steady step (a pair of the
 mix's multiset, a position inside its answer), hands every slot the
-pages its length reaches (permuted, page 0 past them) and times the
-kernel as the model calls it, over ``--layers`` layers of a stacked
+pages its length reaches (page 0 past them; ``--layout``: in ``runs``
+of 8 neighbouring pages, as the cache's allocator hands them out since
+PR 49 and the stream copies with one descriptor, or ``scattered`` a
+page at a time, as a free list left them before; both by default) and
+times the kernel as the model calls it, over ``--layers`` layers of a stacked
 pool in one jitted call, ``--inner`` calls in flight: the median and
 the least of ``--calls`` readings, per kernel call.  Beside the time:
 the rows the stream copies over the rows the slots hold
-(``stream_rows_copied``) and the held rows' bytes over the time as a
-share of the chip's memory rate.
+(``stream_rows_copied``), the pages a copy descriptor
+(``stream_copies``) and the held rows' bytes over the time as a share
+of the chip's memory rate.
 
 ``--granules`` times the stream at other groups of pages a trip of its
 copy loop (``0``: a block whole, as before PR 38); ``--without copies``
@@ -35,6 +39,7 @@ from __future__ import annotations
 
 import argparse
 import inspect
+import itertools
 import json
 import os
 import statistics
@@ -78,18 +83,27 @@ def steady_lengths(rng, mix, slots, cap):
     return out
 
 
-def page_tables(rng, lengths, pool_pages):
-    """The pages each slot's length reaches, drawn without order from
-    the pool; page 0 past them."""
+def page_tables(rng, lengths, pool_pages, layout, run):
+    """The pages each slot's length reaches, page 0 past them.
+    ``scattered``: drawn a page at a time without order from the pool
+    (what a free list popped a page at a time leaves after a churn);
+    ``runs``: every group of ``run`` table entries names ``run``
+    neighbouring pages, the runs drawn without order (what
+    ``serving/cache.py`` hands out since PR 49: a slot's last run is
+    its own whole, only its head is in the table)."""
     import numpy as np
 
     tables = np.zeros((len(lengths), MAXP), np.int32)
-    free = rng.permutation(np.arange(1, pool_pages))
+    if layout == "scattered":
+        free = rng.permutation(np.arange(1, pool_pages))
+    else:
+        heads = 1 + run * rng.permutation((pool_pages - 1) // run)
+        free = (heads[:, None] + np.arange(run)).ravel()
     at = 0
     for i, ln in enumerate(lengths):
         n = ln // PAGE + 1
         tables[i, :n] = free[at:at + n]
-        at += n
+        at += n if layout == "scattered" else -(-n // run) * run
     return tables
 
 
@@ -117,7 +131,9 @@ def without_copies(D):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    def stream(tables, need, layer, ring, streams, bp, maxp):
+    def stream(tables, need, *rest):
+        # (before PR 49 the stream was handed no ``starts``)
+        ring, streams, bp, maxp = rest[-4:]
         b = pl.program_id(0)
 
         @pl.when(b == 0)
@@ -149,11 +165,14 @@ def without_products(D):
         zero = jnp.zeros(bufs[0].shape[-2:], jnp.float32)
         return lax.fori_loop(0, nblk, block, zero)[0, 0]
 
-    def grouped(bp, page, maxp, hkv, d):
-        def kernel(tables, need, lens, layer, q_ref, kpool, vpool, o_ref,
-                   kbuf, vbuf, ksems, vsems, ring):
+    # the scalar operands before the queries: tables, need, (since
+    # PR 49) starts, then the lengths (grouped) and the layer
+    def grouped(bp, page, maxp, hkv, d, per_row=False):
+        def kernel(*refs):
+            (*scalars, _lens, layer, q_ref, kpool, vpool, o_ref,
+             kbuf, vbuf, ksems, vsems, ring) = refs
             nblk, next_block = D._page_stream(
-                tables, need, layer, ring,
+                *scalars, layer, ring,
                 ((kpool, kbuf, ksems), (vpool, vbuf, vsems)), bp, maxp)
             o_ref[...] = jnp.full(
                 o_ref.shape, first_pages(nblk, next_block, (kbuf, vbuf)),
@@ -162,10 +181,11 @@ def without_products(D):
         return kernel
 
     def latent(bp, page, maxp, vw):
-        def kernel(tables, need, layer, q_ref, len_ref, pool, o_ref, buf,
-                   sems, ring):
+        def kernel(*refs):
+            *scalars, layer, q_ref, len_ref, pool, o_ref, buf, sems, ring \
+                = refs
             nblk, next_block = D._page_stream(
-                tables, need, layer, ring, ((pool, buf, sems),), bp, maxp)
+                *scalars, layer, ring, ((pool, buf, sems),), bp, maxp)
             o_ref[...] = jnp.full(
                 o_ref.shape, first_pages(nblk, next_block, (buf,)),
                 o_ref.dtype)
@@ -183,6 +203,9 @@ def main(argv=None):
                     help="comma list of pages a trip; 0 = a block whole; "
                     "default: the tree's own")
     ap.add_argument("--without", choices=("copies", "products"))
+    ap.add_argument("--layout", default="runs,scattered",
+                    help="comma list of runs, scattered: how a slot's "
+                    "pages lie in the pool")
     ap.add_argument("--tree", default=ROOT,
                     help="the checkout whose kernels are timed")
     ap.add_argument("--layers", type=int, default=4)
@@ -231,7 +254,6 @@ def main(argv=None):
             lengths = steady_lengths(rng, json.load(f), slots,
                                      MAXP * PAGE - 1 - per)
         pool_pages = 1 + slots * MAXP
-        tables = jnp.asarray(page_tables(rng, lengths, pool_pages))
         lens = jnp.asarray(lengths, jnp.int32)
         query_rows = heads * per
         key = jax.random.PRNGKey(a.seed)
@@ -265,11 +287,14 @@ def main(argv=None):
         held = int((reach + 1).sum())
         held_bytes = held * row * 2 * len(pools)
 
-        for granule in granules:
+        for layout, granule in itertools.product(a.layout.split(","),
+                                                 granules):
             D._COPIES_A_TRIP = granule or 1 << 20
             for prog in (D._grouped_program, D._latent_program):
                 prog.cache_clear()
             bp = D._block_pages(PAGE, row, 2, query_rows)
+            tables = page_tables(rng, reach, pool_pages, layout,
+                                 min(trip, bp))
             count = getattr(D, "stream_rows_copied", None)
             if a.without == "copies":
                 copied = None
@@ -278,15 +303,21 @@ def main(argv=None):
             else:   # a tree from before PR 38: every block whole
                 copied = int((-(-(reach // PAGE + 1) // bp) * bp * PAGE)
                              .sum())
+            # (a tree from before PR 49 starts a descriptor a page)
+            count = getattr(D, "stream_copies", None)
+            copies = count and copied and count(
+                tables, reach, PAGE, pool_pages, row, 2, query_rows)
             times = [t / layers for t in time_calls(
-                program, (q, top, tables, *pools), inner, calls)]
+                program, (q, top, jnp.asarray(tables), *pools), inner,
+                calls)]
             med, least = statistics.median(times), min(times)
             rec = dict(cell=name, kernel=kind, slots=slots, row=row,
                        query_rows=query_rows, block_pages=bp,
-                       granule=min(granule or bp, bp),
+                       granule=min(granule or bp, bp), layout=layout,
                        without=a.without, rows_held=held,
                        rows_copied=copied,
                        copied_over_held=copied and copied / held,
+                       pages_a_copy=copies and copied / PAGE / copies,
                        ms_a_call=med, ms_least=least,
                        memory_rate_share=rate and held_bytes
                        / (med * 1e-3) / rate)

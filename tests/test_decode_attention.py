@@ -107,6 +107,42 @@ def _tables(lengths, p, maxp, pool, rs):
     return jnp.asarray(tbl), jnp.asarray(lens)
 
 
+def _stream_walk(tbl, lengths, p, bp, num_pages):
+    """What the kernels' stream does for slots that attend ``pos <=
+    lengths`` over the table ``tbl``, walked the slow way: ``(pages,
+    copies)``, the set of pages it copies and the descriptors it
+    starts a pool.  A group of ``trip`` entries whose held ones name
+    neighbouring pages, a whole group's pages from the first inside
+    the pool, is one copy of those ``trip`` pages as they lie; any
+    other group a copy an entry (past the table's width: its last
+    entry again)."""
+    trip = min(D._COPIES_A_TRIP, bp)
+    tbl = np.asarray(tbl)
+    maxp = tbl.shape[1]
+    pages, copies = set(), 0
+    for row, ln in zip(tbl, np.asarray(lengths)):
+        need = min(max(int(ln), 0) // p + 1, maxp)
+        for at in range(0, need, trip):
+            ent = [int(row[min(at + j, maxp - 1)]) for j in range(trip)]
+            held = min(need - at, trip)
+            if ent[0] + trip <= num_pages and all(
+                    ent[j] == ent[0] + j for j in range(held)):
+                pages.update(range(ent[0], ent[0] + trip))
+                copies += 1
+            else:
+                pages.update(ent)
+                copies += trip
+    return pages, copies
+
+
+def _never_copied(tbl, lengths, p, bp, num_pages):
+    """A mask over the pool: the pages the stream copies for no slot."""
+    pages, _ = _stream_walk(tbl, lengths, p, bp, num_pages)
+    out = np.ones(num_pages, bool)
+    out[sorted(pages)] = False
+    return out
+
+
 def _state(lengths=LENGTHS["ragged"], h=4, d=16, p=P, maxp=MAXP, seed=0):
     """Random paged K/V state under ``lengths``."""
     rs = np.random.RandomState(seed)
@@ -355,14 +391,18 @@ class TestGroupedDecodeParity:
 
     def test_pages_no_table_names_are_never_read(self):
         """NaNs in every page no table entry names change no bit: the
-        kernel copies the pages a slot's table names and nothing
-        else."""
+        kernel copies the pages a slot's table names and nothing else
+        (but the neighbours behind the pages a slot holds of its last
+        group where those are a run, PR 49: one copy of 8 pages as they
+        lie, masked; ``_stream_walk`` says which)."""
         lengths = [0, 35 * self.P, 17 * self.P + 3, 5]
         q, kp, vp, tbl, lens = _grouped_state(lengths, 4, 2, seed=9)
         clean = np.asarray(paged_decode_attention(
             q, kp, vp, tbl, lens, page_size=self.P))
         named = np.zeros(kp.shape[0], bool)
         named[np.asarray(tbl).ravel()] = True
+        bp = D._block_pages(self.P, kp.shape[-1], 4, 2 * 4 * 2)
+        named |= ~_never_copied(tbl, lens, self.P, bp, kp.shape[0])
         assert (~named).sum() > 50
         poison = lambda pool: jnp.where(named[:, None, None], pool, jnp.nan)
         got = np.asarray(paged_decode_attention(
@@ -469,9 +509,12 @@ class TestALengthAQueryPosition:
         q, kp, vp, tbl, per = self._state(dtype, seed=4)
         clean = np.asarray(paged_decode_attention(
             q, kp, vp, tbl, per, page_size=self.P))
-        named = np.zeros(kp.shape[0], bool)
-        named[np.asarray(tbl).ravel()] = True
-        spare = int(np.flatnonzero(~named)[0])
+        # a page the stream copies for no slot (not even as a last
+        # group's neighbour, PR 49)
+        bp = D._block_pages(self.P, kp.shape[-1], kp.dtype.itemsize,
+                            q.shape[1] * q.shape[2])
+        spare = int(np.flatnonzero(_never_copied(
+            tbl, np.asarray(per).max(axis=1), self.P, bp, kp.shape[0]))[0])
         poisoned = np.array(tbl)
         for i, longest in enumerate(np.asarray(per).max(axis=1)):
             need = max(int(longest), 0) // self.P + 1
@@ -483,12 +526,16 @@ class TestALengthAQueryPosition:
         np.testing.assert_array_equal(_bits(got), _bits(clean))
 
     #: the operations of the rank-1 call's program at the shapes below,
-    #: counted on the commit before lengths could have a rank (6a5aff6)
+    #: counted on the commit before lengths could have a rank (6a5aff6),
+    #: and what the copy of a run of pages has added since (PR 49: 91
+    #: operations, 66 of them ``_run_starts``' around the kernel; in
+    #: the kernel a group's start read from its scalar operand, the
+    #: choice between one copy of 8 pages a pool and 8 of one)
     PARENT_OPS = {
-        "float32": 342, "bfloat16": 348, "dot_general": 4, "exp": 4,
-        "dma_start": 16, "dma_wait": 2, "get": 25, "swap": 12, "while": 4,
-        "cond": 2, "select_n": 16, "le": 1, "broadcast_in_dim": 17,
-        "pallas_call": 1}
+        "float32": 342 + 91, "bfloat16": 348 + 91, "dot_general": 4,
+        "exp": 4, "dma_start": 16 + 2, "dma_wait": 2, "get": 25 + 1,
+        "swap": 12, "while": 4, "cond": 2 + 1, "select_n": 16 + 1,
+        "le": 1 + 1, "broadcast_in_dim": 17 + 3, "pallas_call": 1}
 
     @staticmethod
     def _eqns(jaxpr):
@@ -533,10 +580,10 @@ class TestALengthAQueryPosition:
         assert sum(one.values()) == self.PARENT_OPS[dtype]
         for name, n in self.PARENT_OPS.items():
             assert one.get(name, n) == n, name
-        # tables, need, lengths, layer; the queries; the two pools
-        assert operands == 7
+        # tables, need, starts, lengths, layer; the queries; the pools
+        assert operands == 8
         two, operands = count((b, s))
-        assert operands == 7    # ... the lengths beside the queries
+        assert operands == 8    # ... the lengths beside the queries
         for name in ("dot_general", "exp", "dma_start", "dma_wait", "while",
                      "cond", "swap", "pallas_call"):
             assert two[name] == one[name], name
@@ -687,8 +734,11 @@ class TestSingleRowStreamParity:
             maxp=self.MAXP, seed=2)
         clean = paged_decode_attention(q[:, 0], kp, vp, tbl, lens,
                                        page_size=self.P)
-        named = np.unique(np.asarray(tbl))
-        free = np.setdiff1d(np.arange(kp.shape[0]), named)
+        bp = D._block_pages(self.P, kp.shape[-1], 4, 6)
+        free = np.flatnonzero(_never_copied(tbl, lens, self.P, bp,
+                                            kp.shape[0]))
+        free = np.setdiff1d(free, np.asarray(tbl))
+        assert len(free) > 50
         dirty = paged_decode_attention(
             q[:, 0], kp.at[0].set(1e30).at[free].set(np.nan),
             vp.at[0].set(1e30).at[free].set(np.nan), tbl, lens,
@@ -813,6 +863,9 @@ class TestLatentDecodeParity:
             q, pages, tbl, lens, scale=self.SCALE, value_width=512))
         named = np.zeros(pages.shape[0], bool)
         named[np.asarray(tbl).ravel()] = True
+        # (and the neighbours a last group's one copy brings along)
+        bp = D._block_pages(P, self.WIDE["r"], 4, self.WIDE["h"])
+        named |= ~_never_copied(tbl, lens, P, bp, pages.shape[0])
         assert (~named).sum() > 100
         poisoned = jnp.where(named[:, None, None], pages, jnp.nan)
         got = np.asarray(latent_decode_attention(
@@ -825,7 +878,8 @@ class TestLatentDecodeParity:
 # --------------------------------------------------------------------------
 
 
-def _whole_block_page_stream(tables, need, layer, ring, streams, bp, maxp):
+def _whole_block_page_stream(tables, need, _starts, layer, ring, streams,
+                             bp, maxp):
     """The kernels' page stream as it was before it followed the
     slots' lengths (PR 31 to PR 37): every block is copied whole, page
     0 standing in past a slot's pages, and awaited with one wait a
@@ -990,9 +1044,9 @@ class TestThePageStreamFollowsTheLength:
         run, tbl, pools = _stream_case(body, 1, 2, dtype, lengths=lengths)
         clean = run(tbl, *pools)
         assert np.isfinite(clean.astype(np.float32)).all()
-        named = np.zeros(pools[0].shape[0], bool)
-        named[np.asarray(tbl).ravel()] = True
-        spare = int(np.flatnonzero(~named)[0])
+        spare = int(np.flatnonzero(_never_copied(
+            tbl, [ln or 0 for ln in lengths], p, bp,
+            pools[0].shape[0]))[0])
         poisoned = np.array(tbl)
         for i, ln in enumerate(lengths):
             need = (ln or 0) // p + 1
@@ -1074,6 +1128,210 @@ def test_stream_rows_copied_counts_what_the_kernel_copies(cell):
     assert stream_rows_copied([2000, 5], p, 16, row, 2, query_rows) \
         == (16 + 8) * p
 
+
+
+# --------------------------------------------------------------------------
+# the page stream: a run of neighbouring pages is one copy (PR 49)
+# --------------------------------------------------------------------------
+
+# body -> page, table width, the pool's row and the query rows a slot
+# (what ``_block_pages`` is asked), float32
+_RUN_BODIES = {"grouped": (32, 40, 256, 4), "single": (16, 40, 768, 6),
+               "latent": (P, 80, 640, 4)}
+
+
+def _run_lengths(body):
+    """Length 0, a full slot, a block's last row, past it inside a
+    page, a short slot, a released one, and LAST a slot whose last
+    group holds 6 pages."""
+    p, maxp, row, rows = _RUN_BODIES[body]
+    bp = D._block_pages(p, row, 4, rows)
+    assert bp % 8 == 0 and bp < maxp
+    return [0, maxp * p - 1, bp * p - 1, bp * p + p + 3, 3 * p - 1, None,
+            21 * p + 5]
+
+
+def _run_state(body, lengths, seed=5):
+    """``(call, tables, lens, pools, reference)`` of one call of
+    ``body``'s kernel under ``_tables``' scattered table: ``call(tables,
+    *pools)`` -> the output, ``reference(tables, *pools)`` -> the
+    float64 oracle's."""
+    p, maxp, row, rows = _RUN_BODIES[body]
+    if body == "latent":
+        c = TestLatentDecodeParity
+        q, pages, tbl, lens = _latent_state(lengths, seed=seed, **c.WIDE)
+
+        def call(tbl, pages):
+            return np.asarray(latent_decode_attention(
+                q, pages, tbl, lens, scale=c.SCALE, value_width=512))
+
+        def reference(tbl, pages):
+            return _latent_reference(q, pages, tbl, lens, c.SCALE, 512)
+
+        return call, tbl, lens, (pages,), reference
+    hkv, s, g = (2, 1, 2) if body == "grouped" else (6, 1, 1)
+    q, kp, vp, tbl, lens = _grouped_state(lengths, s, g, hkv=hkv, p=p,
+                                          maxp=maxp, seed=seed)
+
+    def call(tbl, kp, vp):
+        return np.asarray(paged_decode_attention(q, kp, vp, tbl, lens,
+                                                 page_size=p))
+
+    def reference(tbl, kp, vp):
+        return _grouped_reference(q, kp, vp, tbl, lens, hkv)
+
+    return call, tbl, lens, (kp, vp), reference
+
+
+def _relaid(tbl, lengths, p, pools, layout):
+    """The SAME rows under another table: ``(tables, pools)`` with the
+    pages the slots hold moved to where ``layout`` puts them (what the
+    pools held there before stays wherever nothing lands: finite).
+    ``runs``: slot ``i``'s pages are neighbours from ``1 + i x maxp``
+    on, so every group of 8 entries is a run.  ``half``: so, but every
+    odd slot's first run is broken in its middle (entries 3 and 4
+    change places) and the last slot's last group lies at the pool's
+    very end, where a copy of 8 pages from its first would pass the
+    pool's last page."""
+    tbl = np.asarray(tbl)
+    b, maxp = tbl.shape
+    n = pools[0].shape[0]
+    new = np.zeros_like(tbl)
+    needs = [0 if ln is None else ln // p + 1 for ln in lengths]
+    for i, need in enumerate(needs):
+        new[i, :need] = 1 + i * maxp + np.arange(need)
+    if layout == "half":
+        for i in range(1, b, 2):
+            if needs[i] >= 8:
+                new[i, [3, 4]] = new[i, [4, 3]]
+        last = needs[-1] % 8
+        assert 1 <= last <= 7
+        new[-1, needs[-1] - last:needs[-1]] = n - last + np.arange(last)
+    held = new > 0
+    assert len(np.unique(new[held])) == held.sum() == (tbl > 0).sum()
+    return jnp.asarray(new), tuple(
+        pool.at[new[held]].set(pool[tbl[held]]) for pool in pools)
+
+
+@pytest.fixture
+def _single_streams(monkeypatch):
+    """One query row a key head goes through the stream whatever the
+    pool's size (``TestSingleRowStreamParity``)."""
+    monkeypatch.setattr(D, "_GATHER_POOL_BYTES", 0)
+
+
+@pytest.mark.usefixtures("_single_streams")
+class TestARunOfPagesIsOneCopy:
+    """Each of the three kernels over the SAME rows laid scattered, in
+    runs, and half and half: live rows reach the same buffer positions
+    by one copy of 8 pages or by 8 of one, and what a run's copy brings
+    along behind a slot's last pages is masked."""
+
+    @pytest.mark.parametrize("layout", ["runs", "half"])
+    @pytest.mark.parametrize("body", sorted(_RUN_BODIES))
+    def test_the_output_is_the_scattered_tables_to_the_last_bit(self, body,
+                                                                layout):
+        lengths = _run_lengths(body)
+        p, _, row, rows = _RUN_BODIES[body]
+        call, tbl, lens, pools, reference = _run_state(body, lengths)
+        want = call(tbl, *pools)
+        new, moved = _relaid(tbl, lengths, p, pools, layout)
+        got = call(new, *moved)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        live = np.asarray([ln is not None for ln in lengths])
+        np.testing.assert_allclose(
+            got.astype(np.float64).reshape(
+                reference(new, *moved).shape)[live],
+            reference(new, *moved)[live], atol=2e-5)
+        # the stream did take the other arm: the descriptors it starts
+        n, bp = pools[0].shape[0], D._block_pages(p, row, 4, rows)
+        _, scattered = _stream_walk(tbl, lens, p, bp, n)
+        _, laid = _stream_walk(new, lens, p, bp, n)
+        groups = sum(-(-(int(ln) // p + 1) // 8) for ln in np.asarray(lens))
+        if layout == "runs":
+            assert laid == groups
+        else:
+            # two broken runs and the group at the pool's end
+            assert laid == groups + 3 * 7
+        assert scattered > 6 * groups
+        for t, count in ((tbl, scattered), (new, laid)):
+            assert D.stream_copies(np.asarray(t), np.asarray(lens), p, n,
+                                   row, 4, rows) == count
+
+    @pytest.mark.parametrize("bucket", [1, 2, 4, 8])
+    @pytest.mark.parametrize("body", sorted(_RUN_BODIES))
+    def test_a_table_of_runs_cut_to_its_bucket_changes_no_bit(self, body,
+                                                              bucket):
+        """A table narrower than a group: the copy of 8 pages from a
+        slot's first still lies inside the pool, and what it brings
+        past the table's width is masked."""
+        p = _RUN_BODIES[body][0]
+        lengths = [0, bucket * p - 1, None, (bucket - 1) * p + 2,
+                   bucket * p // 2]
+        call, tbl, _, pools, _ = _run_state(body, lengths, seed=6)
+        want = call(tbl, *pools)
+        new, moved = _relaid(tbl, lengths, p, pools, "runs")
+        np.testing.assert_array_equal(_bits(call(new[:, :bucket], *moved)),
+                                      _bits(want))
+        np.testing.assert_array_equal(_bits(call(tbl[:, :bucket], *pools)),
+                                      _bits(want))
+
+
+def test_a_pool_smaller_than_a_run_is_copied_a_page_at_a_time():
+    """5 pages under groups of 8: no copy of a run fits the pool (the
+    kernel builds none), and the oracle's answer comes out."""
+    q, pages, tbl, lens = _latent_state([3 * P - 1], maxp=4, seed=8)
+    assert pages.shape[0] == 5 < D._COPIES_A_TRIP
+    tbl = jnp.asarray([[1, 2, 3, 0]], jnp.int32)
+    got = latent_decode_attention(q, pages, tbl, lens, scale=0.3,
+                                  value_width=16)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float64),
+        _latent_reference(q, pages, tbl, lens, 0.3, 16), atol=2e-5)
+    assert D.stream_copies(np.asarray(tbl), np.asarray(lens), P, 5, 24, 4,
+                           4) == 8
+
+
+@pytest.mark.parametrize("cell", sorted(_STREAM_CELLS))
+def test_stream_copies_counts_a_descriptor_a_run(cell):
+    """``stream_copies`` at the cells' shapes against the slow walk,
+    over a table of runs, a scattered one and one whose every other
+    group is broken: with ``stream_rows_copied`` it gives 8, about 1
+    and 16 / 9 pages a descriptor."""
+    row, query_rows, _, _ = _STREAM_CELLS[cell]
+    p, maxp, slots = 16, 128, 24
+    rs = np.random.RandomState(7)
+    lengths = rs.randint(128, 2047, size=slots)
+    need = lengths // p + 1
+    pool = 1 + slots * maxp
+    bp = D._block_pages(p, row, 2, query_rows)
+    runs = np.zeros((slots, maxp), np.int64)
+    scattered = np.zeros_like(runs)
+    free = rs.permutation(np.arange(1, pool))
+    for i, n in enumerate(need):
+        runs[i, :n] = 1 + i * maxp + np.arange(n)
+        scattered[i, :n], free = free[:n], free[n:]
+    broken = runs.copy()
+    broken[:, 2::16], broken[:, 3::16] = runs[:, 3::16], runs[:, 2::16]
+    pages = stream_rows_copied(lengths, p, maxp, row, 2, query_rows) // p
+    want = {}
+    for name, tbl in (("runs", runs), ("scattered", scattered),
+                      ("broken", broken)):
+        got = D.stream_copies(tbl, lengths, p, pool, row, 2, query_rows)
+        assert got == _stream_walk(tbl, lengths, p, bp, pool)[1], name
+        want[name] = pages / got
+    assert want["runs"] == 8.0
+    # (a last group of one page is a run of one)
+    assert 1.0 <= want["scattered"] < 1.03
+    assert abs(want["broken"] - 16 / 9) < 0.1
+    # no slot ran (a step all of whose slots completed since): nothing
+    assert D.stream_copies(runs[:0], lengths[:0], p, pool, row, 2,
+                           query_rows) == 0
+    # a run that would pass the pool's last page is copied a page at a
+    # time: the same table over a pool that ends inside its last run
+    last = int(runs.max())
+    assert D.stream_copies(runs, lengths, p, last + 1, row, 2, query_rows) \
+        > pages // 8
 
 
 @pytest.mark.parametrize("body", ["paged", "grouped", "latent"])

@@ -536,11 +536,11 @@ def test_a_preempted_request_is_rebuilt_within_the_tolerance(roomy):
     eng, params, sizes = roomy
     want = [list(r.tokens) for r in _serve(eng, PROMPTS, 10)]
     before = eng.stats()
-    spare, eng.cache._free = eng.cache._free[9:], eng.cache._free[:9]
+    spare = eng.cache.withhold(eng.cache.free_pages() - 9)
     try:
         reqs = _serve(eng, PROMPTS, 10)
     finally:
-        eng.cache._free += spare
+        eng.cache.hand_back(spare)
     st = eng.stats()
     preempted = st["preemptions"] - before["preemptions"]
     assert preempted >= 1

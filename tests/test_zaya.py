@@ -522,11 +522,11 @@ def test_a_preempted_request_resumes_to_the_same_tokens(roomy):
     before = eng.stats()["preemptions"]
     # the same engine (its programs are compiled) with most of its free
     # pages taken away
-    spare, eng.cache._free = eng.cache._free[8:], eng.cache._free[:8]
+    spare = eng.cache.withhold(eng.cache.free_pages() - 8)
     try:
         reqs = _serve(eng, PROMPTS, 8)
     finally:
-        eng.cache._free += spare
+        eng.cache.hand_back(spare)
     assert eng.stats()["preemptions"] > before
     assert [list(r.tokens) for r in reqs] == want
     assert float(_gaps(params, sizes, PROMPTS, reqs).max()) <= GAP_LIMIT
@@ -663,6 +663,43 @@ def test_spans_and_stats_say_the_state_and_the_routing(roomy, tmp_path,
         assert a["attn_rows_copied"] == 2 * 8 * 4
     finally:
         obs.reset()
+
+
+def test_a_kernel_model_s_steps_count_a_copy_a_run(tmp_path, monkeypatch):
+    """ZAYA1's tiny engine (grouped attention through the page stream)
+    on its default pool: every ``serve.decode_step`` says how many copy
+    descriptors the kernel started a pool, one a group of 8 pages of
+    each slot that ran, and ``stats()`` the pages a descriptor."""
+    from bigdl_tpu.serving import spans as S
+    from bigdl_tpu.serving.cache import PAGE_RUN
+
+    model, params, _ = make(3)
+    eng = LMEngine(model, params=params, max_batch=2, page_size=4)
+    monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path / "trace"))
+    obs.reset()
+    try:
+        reqs = [eng.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3][:n], 34)
+                for n in (10, 7)]
+        eng.run_until_idle(300)
+        assert all(r.error is None and len(r.tokens) == 34 for r in reqs)
+        tracer = obs.get_tracer()
+        tracer.flush()
+        with open(tracer.jsonl_path, encoding="utf-8") as fh:
+            recs = [json.loads(line) for line in fh]
+        steps = [r["attrs"] for r in recs if r["kind"] == "span"
+                 and r["name"] == S.SPAN_STEP_DECODE
+                 and "attn_copies" in r["attrs"]]
+        assert len(steps) >= 30
+        # contexts pass 8 pages of 4: a second group, a second copy
+        assert max(a["attn_copies"] for a in steps) == 4
+        for a in steps:
+            pages = a["attn_rows_copied"] // 4
+            assert pages % PAGE_RUN == 0
+            assert a["attn_copies"] == pages // PAGE_RUN
+        assert eng.stats()["attn_pages_a_copy"] == PAGE_RUN
+    finally:
+        obs.reset()
+        eng.close()
 
 
 def test_step_programs_carry_the_new_scopes():
