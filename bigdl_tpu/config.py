@@ -78,11 +78,6 @@ class ObsConfig:
     metrics_dir: Optional[str] = None
     # step-time / dispatch-time reservoir capacity [BIGDL_OBS_RESERVOIR]
     reservoir_size: int = 4096
-    # slow-step anomaly detector: a step slower than
-    # median * slow_step_factor emits a structured `slow_step` trace
-    # event with its child-span breakdown; <= 0 disables
-    # [BIGDL_SLOW_STEP_FACTOR]
-    slow_step_factor: float = 3.0
     # flight recorder: how many recent span/event records the tracer
     # retains in memory for postmortem bundles [BIGDL_FLIGHT_SPANS]
     flight_spans: int = 512
@@ -217,7 +212,6 @@ class ObsConfig:
             trace_dir=_env_str("BIGDL_TRACE_DIR", None),
             metrics_dir=_env_str("BIGDL_METRICS_DIR", None),
             reservoir_size=_env_int("BIGDL_OBS_RESERVOIR", 4096),
-            slow_step_factor=_env_float("BIGDL_SLOW_STEP_FACTOR", 3.0),
             flight_spans=_env_int("BIGDL_FLIGHT_SPANS", 512),
             regress_tolerance=_env_float("BIGDL_REGRESS_TOLERANCE", 1.5),
             health_every=_env_int("BIGDL_HEALTH_EVERY", 0),

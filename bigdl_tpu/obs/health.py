@@ -28,7 +28,7 @@ On top of the raw stats:
   offending layer(s); the driver emits a ``health.nonfinite_layers``
   trace event carrying the first offender + the full list, and bumps
   ``bigdl_nonfinite_layers_total{layer}``;
-* a **numerics anomaly detector** mirroring the slow-step detector: a
+* a **numerics anomaly detector**: a
   loss or global-grad-norm observation above ``rolling median *
   BIGDL_HEALTH_SPIKE_FACTOR`` emits a ``health.anomaly`` trace event
   and bumps ``bigdl_numerics_anomalies_total{kind}``.
@@ -280,9 +280,8 @@ class HealthMonitor:
 
     def _spike(self, kind: str, window: collections.deque, step: int,
                value: float):
-        """Rolling-median spike detector (mirrors the slow-step
-        detector: 8-observation warmup, factor from config, structured
-        event + counter)."""
+        """Rolling-median spike detector (8-observation warmup,
+        factor from config, structured event + counter)."""
         if self.spike_factor <= 0 or value is None \
                 or not np.isfinite(value):
             return

@@ -152,9 +152,6 @@ CHECKPOINT_WRITE_FAILURES_TOTAL = _m(
 PREEMPTIONS_TOTAL = _m(
     "bigdl_preemptions_total", "counter",
     doc="SIGTERM/SIGINT preemptions handled by the elastic exit path")
-SLOW_STEPS_TOTAL = _m(
-    "bigdl_slow_steps_total", "counter",
-    doc="Steps slower than median * BIGDL_SLOW_STEP_FACTOR")
 NONFINITE_SKIPS_TOTAL = _m(
     "bigdl_nonfinite_skips_total", "counter",
     doc="Weight updates skipped by the non-finite step guard")
@@ -551,6 +548,14 @@ PROF_STACKS = _m(
     "bigdl_prof_stacks", "gauge", policy="max",
     doc="Distinct collapsed stacks held in the profiler's bounded "
         "fold table (overflow folds into the 'other' stack)")
+STALLS_TOTAL = _m(
+    "bigdl_stalls_total", "counter", ("loop", "cause"), 16,
+    "Pauses of a minded loop (the stall watch, obs/prof.py: span "
+    "boundaries further apart than the loop's limit under a recording "
+    "tracer), by loop and cause", policy="sum")
+STALLED_SECONDS_TOTAL = _m(
+    "bigdl_stalled_seconds_total", "counter", ("loop",), 4,
+    "Seconds a minded loop stood still in pauses", policy="sum")
 BUNDLE_WRITES_TOTAL = _m(
     "bigdl_bundle_writes_total", "counter", ("trigger",), 6,
     "Debug bundles written, by trigger (alert / supervisor / http / "

@@ -10,7 +10,9 @@ snapshots when present) and renders what a postmortem asks first:
 * collective wire bytes by op/dtype, per-step footprint and the
   int8-vs-f32 savings ratio;
 * resilience events (retries, non-finite skips, checkpoint failures);
-* slow-step anomalies and the slowest spans per host;
+* stalls (the stall watch's ``obs.stall`` spans, obs/prof.py): how
+  often and for how long a minded loop stood still, by cause, and the
+  longest with where they stood; and the slowest spans per host;
 * training health (obs/health.py): per-layer grad norm / param norm /
   update ratio gauges, non-finite layer attributions, numerics
   anomalies;
@@ -96,6 +98,19 @@ def _metric_samples(snaps: List[dict], name: str) -> list:
     return out
 
 
+def _stalls_section(stalls: list) -> dict:
+    """The run's ``obs.stall`` spans by ``"<loop> <cause>"`` as
+    ``[count, seconds]``, and the eight longest with their attributes."""
+    by_cause: dict = {}
+    for s in stalls:
+        e = by_cause.setdefault(f"{s.get('loop')} {s.get('cause')}",
+                                [0, 0.0])
+        e[0] += 1
+        e[1] += s["dur_s"]
+    return {"by_cause": by_cause,
+            "longest": sorted(stalls, key=lambda s: -s["dur_s"])[:8]}
+
+
 def build_report(trace_dir: str, metrics_dir: Optional[str] = None,
                  bundle_dir: Optional[str] = None) -> dict:
     shards = read_shards(trace_dir)
@@ -103,7 +118,7 @@ def build_report(trace_dir: str, metrics_dir: Optional[str] = None,
 
     hosts: dict = {}
     resilience: dict = {}
-    slow_steps: list = []
+    stalls: list = []
     ckpt_async_writes = 0
     ckpt_snapshots = 0
     compile_events: list = []
@@ -144,6 +159,9 @@ def build_report(trace_dir: str, metrics_dir: Optional[str] = None,
                     ckpt_async_writes += 1
                 elif name == "checkpoint.snapshot":
                     ckpt_snapshots += 1
+                if name == "obs.stall":
+                    stalls.append(dict(attrs, host=sh.host,
+                                       dur_s=round(dur, 6)))
                 if name.endswith(".compile"):
                     compile_events.append(
                         {"host": sh.host, "name": name,
@@ -151,10 +169,6 @@ def build_report(trace_dir: str, metrics_dir: Optional[str] = None,
             else:
                 if name.startswith("resilience."):
                     resilience[name] = resilience.get(name, 0) + 1
-                elif name == "slow_step":
-                    a = dict(rec.get("attrs") or {})
-                    a["host"] = sh.host
-                    slow_steps.append(a)
                 elif name == "health.nonfinite_layers":
                     a = dict(rec.get("attrs") or {})
                     a["host"] = sh.host
@@ -608,7 +622,7 @@ def build_report(trace_dir: str, metrics_dir: Optional[str] = None,
         "wire_savings_ratio": max(savings) if savings else None,
         "wire_savings_by_path": savings_by_path,
         "resilience_events": resilience,
-        "slow_steps": slow_steps,
+        "stalls": _stalls_section(stalls),
         "alerts": alerts,
         "serving": serving,
         "reqtrace": reqtrace,
@@ -680,15 +694,19 @@ def render_text(rep: dict) -> str:
     for name, n in sorted(rep["resilience_events"].items()):
         lines.append(f"  {name}: {n}")
     lines.append("")
-    lines.append("-- slow steps --")
-    if not rep["slow_steps"]:
+    lines.append("-- stalls (a minded loop stood still) --")
+    st = rep["stalls"]
+    if not st["by_cause"]:
         lines.append("  (none)")
-    for s in rep["slow_steps"][:8]:
+    for key, (n, secs) in sorted(st["by_cause"].items()):
+        lines.append(f"  {key}: {n} stall(s), {secs:.3f}s")
+    for s in st["longest"]:
         lines.append(
-            f"  host{s.get('host')} step {s.get('step')}: "
-            f"{float(s.get('dur_s', 0)) * 1000:.1f}ms "
-            f"(median {float(s.get('median_s', 0)) * 1000:.1f}ms, "
-            f"breakdown {s.get('breakdown')})")
+            f"  host{s.get('host')} {s.get('loop')} "
+            f"{s['dur_s'] * 1000:.1f}ms in {s.get('phase') or '(no span)'}"
+            f" step {s.get('step')}: {s.get('cause')}, "
+            f"{s.get('loop_state', '?')} in {s.get('frame') or '?'}, "
+            f"busiest {s.get('busiest') or '?'}")
     lines.append("")
     lines.append("-- alerts --")
     al = rep.get("alerts") or {}

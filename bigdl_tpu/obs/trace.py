@@ -2,7 +2,7 @@
 
 The reference's only timeline attribution was the driver-side phase
 averages in «bigdl»/optim/Metrics.scala; averages cannot answer "where
-did *this* slow step spend its time".  This tracer gives the training
+did *this* step spend its time".  This tracer gives the training
 stack nested wall-clock spans:
 
 * contextvar-based nesting — spans opened inside a span become its
@@ -61,6 +61,35 @@ name               kind   covers
 ``validation``, ``checkpoint``, ``build_train_step``  live, as named
 =================  =====  ==================================================
 
+**The stall watch's names** (``obs/prof.py``, which gives every
+attribute and the rule of ``cause``; both spans are retroactive and lie
+on the WATCH thread's line, ``tid=`` naming the loop's, so a reader of
+the loop's line meets neither):
+
+===================  =====  ================================================
+name                 kind   covers
+===================  =====  ================================================
+``obs.host``         retro  one second of a watched loop (``loop=``): the
+                            watch's ticks and how late it woke, the loop
+                            thread's CPU and run-queue wait, the process's
+                            CPU, involuntary switches, collector time
+``obs.stall``        retro  from a watched loop's last span boundary to
+                            the next, where they lie further apart than
+                            the loop's limit: where the loop's thread
+                            stood and ``cause=``
+``obs.stall.sample`` event  every Python thread's stack with its live
+                            span while a stall lasts (``stall=`` joins it
+                            to the span), at most 40 a stall
+``obs.stall.threads`` event the process's native threads over a stall:
+                            ``comm``, state, CPU and run-queue ms
+===================  =====  ================================================
+
+**The heartbeat** is the span log's own: a recording tracer's ``span()``
+reads the clock at enter and exit anyway, and for a thread the watch
+minds (``_BEATS``, one ``dict.get`` for any other) it keeps that instant
+and the open span's id in the thread's :class:`Beat`, and writes down,
+itself, any two boundaries further apart than the limit.
+
 **Events of the kernels' call sites** (instant, made while a program is
 traced, so once a compile and never in a step): ``kernel.fallback``
 (``ops/conv_bn.py``: ``site=`` and the shapes that fell back to XLA) and
@@ -105,6 +134,38 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 # thread reads racily (a sample landing inside a push/pop window lands
 # in the adjacent phase — one sample of noise, by design).
 _PHASES: dict = {}
+
+
+class Beat:
+    """The heartbeat of one thread a stall watch minds (``obs/prof.py``):
+    ``t`` the ``perf_counter`` instant of its newest span boundary and
+    ``sid`` the span open at it (None between spans).  Two boundaries
+    further apart than ``limit`` are a gap, and the thread itself writes
+    it down in ``gaps`` as ``(start, end, span id, the names of the
+    spans open during it, their step)``: exact, whatever the watch's
+    own thread was doing meanwhile."""
+
+    __slots__ = ("t", "sid", "limit", "gaps")
+
+    def __init__(self, limit: float):
+        self.t = time.perf_counter()
+        self.sid = None
+        self.limit = float(limit)
+        self.gaps: collections.deque = collections.deque(maxlen=256)
+
+    def mark(self, t: float, sid, stack, open_spans: dict):
+        """A boundary at ``t`` that leaves span ``sid`` open; ``stack``
+        holds the names of the spans that were open until it."""
+        if t - self.t > self.limit:
+            step = (open_spans.get(self.sid) or {}).get("step")
+            self.gaps.append((self.t, t, self.sid, tuple(stack), step))
+        self.t = t
+        self.sid = sid
+
+
+# thread ident -> Beat, for the threads a stall watch minds (it adds and
+# drops them; ``Tracer.span`` only looks)
+_BEATS: dict = {}
 
 
 def current_phase(ident: int):
@@ -221,8 +282,7 @@ class Tracer:
         self._tids: dict = {}
         self._closed = False
         # flight recorder: the last `ring_size` structured records stay
-        # in memory for postmortem bundles (obs/regress.py) and the
-        # slow-step detector's child-span breakdown
+        # in memory for postmortem bundles (obs/regress.py)
         self._recent: collections.deque = collections.deque(
             maxlen=max(1, int(ring_size)))
         # one wall-clock anchor + perf_counter timeline: Chrome wants a
@@ -292,12 +352,20 @@ class Tracer:
         else:
             ann = self._annotation(name, id=sid)
         t0 = time.perf_counter()
+        beat = _BEATS.get(ident)
+        if beat is not None:
+            # (the time since the last boundary passed in the parent)
+            beat.mark(t0, sid, _PHASES[ident][:-1], self._open)
         ann.__enter__()
         try:
             yield sid
         finally:
             ann.__exit__(None, None, None)
-            dur = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            dur = t1 - t0
+            beat = _BEATS.get(ident)
+            if beat is not None:
+                beat.mark(t1, parent, _PHASES.get(ident, ()), self._open)
             _CURRENT.reset(token)
             _pop_phase(ident)
             del self._open[sid]

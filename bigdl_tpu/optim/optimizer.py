@@ -450,48 +450,6 @@ class BaseOptimizer:
         divisibility."""
         return inp, tgt
 
-    def _detect_slow_step(self, n, dt, tracer, runtime):
-        """Slow-step anomaly detector: a step slower than
-        ``median * BIGDL_SLOW_STEP_FACTOR`` (default 3x) emits a
-        structured ``slow_step`` trace event carrying the step's
-        child-span breakdown (data_wait / batch_prep / device_put /
-        step_dispatch durations out of the tracer's flight-recorder
-        ring), so outliers self-diagnose instead of vanishing into the
-        p99.  Only runs when the runtime profile is live (obs on); the
-        median window is the step-time reservoir, which already holds
-        this step."""
-        from bigdl_tpu.config import config
-
-        factor = config.obs.slow_step_factor
-        if factor <= 0:
-            return
-        res = runtime.step_times
-        if res.count < 8:
-            return  # warmup: compiles dominate, the median is noise
-        med = res.percentiles((0.5,))[0.5]
-        if med is None or med <= 0 or dt <= med * factor:
-            return
-        breakdown = {}
-        for rec in tracer.recent():
-            if rec.get("kind") != "span" or rec.get("name") in (
-                    "iteration", "computing"):
-                continue
-            if (rec.get("attrs") or {}).get("step") == n:
-                breakdown[rec["name"]] = round(
-                    breakdown.get(rec["name"], 0.0)
-                    + float(rec.get("dur_s", 0.0)), 6)
-        log.warning(
-            "slow step %d: %.4fs vs median %.4fs (> %gx) — breakdown %s",
-            n, dt, med, factor, breakdown or "unavailable (tracing off)")
-        tracer.event("slow_step", step=n, dur_s=round(dt, 6),
-                     median_s=round(med, 6), factor=factor,
-                     breakdown=breakdown)
-        from bigdl_tpu import obs
-
-        obs.get_registry().counter(
-            names.SLOW_STEPS_TOTAL,
-            "Steps exceeding median * BIGDL_SLOW_STEP_FACTOR").inc()
-
     def _params_tree(self, pvar):
         """Device-resident training params -> the model's params pytree.
         Local training already holds the tree; DistriOptimizer overrides
@@ -777,10 +735,22 @@ class LocalOptimizer(BaseOptimizer):
             wall_start = time.time()
             records_total = 0
             stop = False
-            return self._optimize_loop(
-                model, pvar, mod_state, opt, opt_state, train_step,
-                base_key, wall_start, records_total, stop, profiler,
-            )
+            # under a recording tracer the stall watch (obs/prof.py)
+            # minds this thread for as long as the loop: its spans'
+            # boundaries are the heartbeat, and a validation or a
+            # checkpoint is no stall
+            minded = _obs_prof.get_watch().add(
+                "train", quiet=("validation", "checkpoint",
+                                "build_train_step")) \
+                if tracer.enabled else None
+            try:
+                return self._optimize_loop(
+                    model, pvar, mod_state, opt, opt_state, train_step,
+                    base_key, wall_start, records_total, stop, profiler,
+                )
+            finally:
+                if minded is not None:
+                    minded.drop()
         finally:
             # an exception mid-epoch must not leak an active trace — the
             # DistriOptimizer retry path would otherwise hit "profiler
@@ -899,7 +869,6 @@ class LocalOptimizer(BaseOptimizer):
                 # resolves one iteration after its dispatch
                 runtime.record_step(dt)
                 tracer.complete("computing", t0, dt, step=n)
-                self._detect_slow_step(n, dt, tracer, runtime)
             # goodput: one productive-step interval (re-tagged rework
             # by the ledger when n is under the resume high-water mark)
             ledger.record("step", t0, dt, step=n)
@@ -1088,8 +1057,9 @@ class LocalOptimizer(BaseOptimizer):
                 # shared no-op object when observability is off
                 tracer.complete("data_wait", t_wait, dt_wait, step=n)
                 ledger.record("data_wait", t_wait, dt_wait, step=n)
-                # child spans carry the step too: the slow-step detector
-                # and the merged cross-host timeline both key on it
+                # child spans carry the step too: the stall watch's
+                # ``obs.stall`` and the merged cross-host timeline both
+                # key on it
                 with tracer.span("iteration", step=n):
                     if batch is not None:
                         # double-buffered: prepared + transferred while

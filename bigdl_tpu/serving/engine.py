@@ -305,6 +305,8 @@ class LMEngine:
         self._tables = self._serving_tables(self.params)
         self._prefill_fns: dict = {}
         self._tracer = obs.NULL_TRACER  # pump() looks it up each cycle
+        # the stall watch's handle while this loop is minded (``_mind``)
+        self._minded = None
         from bigdl_tpu.obs import prof as _obs_prof
 
         # continuous profiler: starts with the engine when
@@ -968,10 +970,7 @@ class LMEngine:
         buffers without blocking, before the host ships its arrays, and
         only under a recording tracer."""
         if tracer.enabled:
-            launched = [rec for rec in (self._inflight, *self._unread)
-                        if rec is not None]
-            tracer.add_attrs(dispatch_id, dry=int(all(
-                bool(rec.result.is_ready()) for rec in launched)))
+            tracer.add_attrs(dispatch_id, dry=int(self._chip_ready()))
 
     def _read(self, rec: _InFlight, tracer, step: int) -> steps._StepRead:
         """Wait for a dispatched step's tokens (``serve.wait``: the one
@@ -1049,8 +1048,38 @@ class LMEngine:
             # the prefills a settle has not read lie behind a step this
             # cycle dispatched: no pump ends with one unread
             self._read_prefills(self._tracer, late=True)
-            return stepped or self._inflight is not None \
+            work = stepped or self._inflight is not None \
                 or bool(self._stash) or self.queue.depth() > 0
+            if self._tracer.enabled:
+                self._mind(work)
+            return work
+
+    def _mind(self, work: bool):
+        """Under a recording tracer the stall watch (``obs/prof.py``)
+        minds the thread that pumps, from the first cycle with work
+        until a cycle finds none (an idle engine is no stall): the
+        cycle's span boundaries are its heartbeat, and a pause between
+        two of them becomes an ``obs.stall`` span that says where every
+        thread stood and whether the chip was waiting."""
+        minded = self._minded
+        if minded is not None and not (
+                work and minded.ident == threading.get_ident()
+                and minded.watch.tracer is self._tracer):
+            minded.drop()
+            minded = self._minded = None
+        if work and minded is None:
+            from bigdl_tpu.obs import prof
+
+            self._minded = prof.get_watch().add(
+                "serve", probe=self._chip_ready)
+
+    def _chip_ready(self) -> bool:
+        """The stall watch's probe, asked from its thread without
+        blocking: whether nothing launched is still running (the
+        question ``dry=`` asks at a dispatch)."""
+        return all(bool(rec.result.is_ready())
+                   for rec in (self._inflight, *self._unread)
+                   if rec is not None)
 
     def run_until_idle(self, timeout_s: float = 60.0):
         """Drive synchronously until queue + slots drain (tests/smokes)."""
@@ -1096,6 +1125,9 @@ class LMEngine:
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        if self._minded is not None:
+            self._minded.drop()
+            self._minded = None
         with self._lock:
             self._settle("close")
         self.queue.close()

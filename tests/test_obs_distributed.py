@@ -1,6 +1,6 @@
 """Distributed-observability specs (ISSUE 3): trace-shard merging with
 clock alignment, collective-traffic accounting, the run-report CLI, the
-perf-regression gate + flight recorder, the slow-step detector, and the
+perf-regression gate + flight recorder, and the
 one-lock-per-scrape histogram parity.
 
 The acceptance gates live here: a 2-host (simulated, CPU) traced run
@@ -27,7 +27,6 @@ from bigdl_tpu.engine import Engine
 from bigdl_tpu.nn import ClassNLLCriterion, Linear, LogSoftMax, ReLU, Sequential
 from bigdl_tpu.obs import aggregate, collectives as C, regress, report
 from bigdl_tpu.obs.metrics import MetricsRegistry
-from bigdl_tpu.obs.runtime import RuntimeStats
 from bigdl_tpu.obs.trace import Tracer
 from bigdl_tpu.optim import DistriOptimizer, LocalOptimizer, SGD, Trigger
 from bigdl_tpu.resilience import reset_injector
@@ -40,8 +39,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def _fresh_obs(monkeypatch):
     for var in ("BIGDL_OBS", "BIGDL_TRACE_DIR", "BIGDL_METRICS_DIR",
-                "BIGDL_FAULT_PLAN", "BIGDL_SLOW_STEP_FACTOR",
-                "BIGDL_REGRESS_TOLERANCE", "BIGDL_PROCESS_ID"):
+                "BIGDL_FAULT_PLAN", "BIGDL_REGRESS_TOLERANCE",
+                "BIGDL_PROCESS_ID"):
         monkeypatch.delenv(var, raising=False)
     reset_injector()
     obs.reset()
@@ -487,88 +486,6 @@ class TestRegressionGate:
         verdict = regress.gate(res, traj)
         res["extras"]["regression"] = verdict
         assert res["extras"]["regression"]["status"] == "violation"
-
-
-# ------------------------------------------------------ slow-step detector
-class TestSlowStepDetector:
-    def _opt(self):
-        x, y = _toy(n=64)
-        return LocalOptimizer(_model(), (x, y), ClassNLLCriterion(),
-                              batch_size=32)
-
-    def test_unit_emits_event_with_breakdown(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path))
-        obs.reset()
-        opt = self._opt()
-        tracer = obs.get_tracer()
-        runtime = RuntimeStats()
-        for _ in range(10):
-            runtime.step_times.add(0.01)
-        with tracer.span("iteration", step=11):
-            with tracer.span("device_put", step=11):
-                pass
-            with tracer.span("step_dispatch", step=11):
-                pass
-        runtime.step_times.add(0.05)
-        opt._detect_slow_step(11, 0.05, tracer, runtime)
-        tracer.flush()
-        recs = [r for r in tracer.recent() if r["name"] == "slow_step"]
-        assert len(recs) == 1
-        a = recs[0]["attrs"]
-        assert a["step"] == 11 and a["factor"] == 3.0
-        assert a["dur_s"] == pytest.approx(0.05)
-        assert a["median_s"] == pytest.approx(0.01)
-        assert set(a["breakdown"]) == {"device_put", "step_dispatch"}
-        fam = obs.get_registry().counter("bigdl_slow_steps_total")
-        assert fam.labels().value == 1
-
-    def test_fast_step_and_warmup_do_not_fire(self, monkeypatch,
-                                              tmp_path):
-        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path))
-        obs.reset()
-        opt = self._opt()
-        tracer = obs.get_tracer()
-        runtime = RuntimeStats()
-        runtime.step_times.add(0.01)
-        opt._detect_slow_step(1, 10.0, tracer, runtime)  # warmup: <8 obs
-        for _ in range(10):
-            runtime.step_times.add(0.01)
-        opt._detect_slow_step(12, 0.02, tracer, runtime)  # only 2x median
-        assert not [r for r in tracer.recent()
-                    if r["name"] == "slow_step"]
-
-    def test_factor_zero_disables(self, monkeypatch, tmp_path):
-        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path))
-        monkeypatch.setenv("BIGDL_SLOW_STEP_FACTOR", "0")
-        obs.reset()
-        opt = self._opt()
-        tracer = obs.get_tracer()
-        runtime = RuntimeStats()
-        for _ in range(20):
-            runtime.step_times.add(0.01)
-        opt._detect_slow_step(21, 99.0, tracer, runtime)
-        assert not [r for r in tracer.recent()
-                    if r["name"] == "slow_step"]
-
-    def test_integration_traced_run_self_diagnoses(self, tmp_path,
-                                                   monkeypatch):
-        """A traced run with an absurdly low factor flags steady-state
-        steps and each slow_step event carries the span breakdown."""
-        monkeypatch.setenv("BIGDL_TRACE_DIR", str(tmp_path))
-        monkeypatch.setenv("BIGDL_SLOW_STEP_FACTOR", "1e-6")
-        obs.reset()
-        x, y = _toy(n=480)
-        opt = LocalOptimizer(_model(), (x, y), ClassNLLCriterion(),
-                             batch_size=32)
-        opt.set_optim_method(SGD(learningrate=0.1))
-        opt.set_end_when(Trigger.max_epoch(1))
-        opt.optimize()
-        events = [r for r in obs.get_tracer().recent()
-                  if r["name"] == "slow_step"]
-        # fires from the step where the reservoir holds 8 obs: 8..15
-        assert len(events) == 8
-        for r in events:
-            assert "step_dispatch" in r["attrs"]["breakdown"]
 
 
 # --------------------------------------- one-lock-per-scrape histograms

@@ -372,13 +372,16 @@ def _two_busy_stretches(lm_model, tracer):
 
 class _Result:
     """Stands in for a step's result on the device: says ``ready`` when
-    asked, counts the askings, reads as the array it holds."""
+    asked, counts the askings of the thread that made it (the engine's;
+    the stall watch's probe asks from its own thread while a first
+    compile holds the loop), reads as the array it holds."""
 
     def __init__(self, arr, ready):
         self.arr, self.ready, self.asked = arr, ready, 0
+        self._asker = threading.get_ident()
 
     def is_ready(self):
-        self.asked += 1
+        self.asked += threading.get_ident() == self._asker
         return self.ready
 
     def __array__(self, dtype=None, copy=None):
