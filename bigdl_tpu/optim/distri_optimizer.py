@@ -35,6 +35,9 @@ multi-host DCN+ICI mesh — XLA picks the collective implementation.
 
 from __future__ import annotations
 
+import logging
+import math
+
 import numpy as np
 
 from bigdl_tpu.optim.optimizer import BaseOptimizer, LocalOptimizer
@@ -74,6 +77,113 @@ def int8_blockwise_reduce_scatter(g, axis, n, block):
     out, _ = wire.reduce_scatter(
         g, axis, n, wire.WireSpec("int8", block=block))
     return out
+
+
+#: a TPU tile is 128 lanes wide: a leaf with fewer elements than that
+#: behind its second dimension cannot be read out of the flat vector by
+#: a reshape without padding every one of those tails to a whole tile
+_LANES = 128
+
+
+def leaf_is_relaid(shape) -> bool:
+    """Which route a leaf takes between the flat vector and its own
+    shape, from the shape alone: rank 3 or more with 2 to 127 elements
+    behind the second dimension (a convolution kernel ``(out, in, kh,
+    kw)``: 2-D, volumetric, grouped, depthwise, a 7 x 7 stem) goes by a
+    transposition, everything else (vectors, ``Linear``'s matrices,
+    embeddings, 1 x 1 kernels, a tail of 128 or more) by the plain
+    reshape.  See ``DistriOptimizer._init_params``."""
+    return len(shape) >= 3 and 2 <= math.prod(shape[2:]) < _LANES
+
+
+def unpack_leaf(seg, shape):
+    """A leaf's slice of the flat vector (``leaf.reshape(-1)`` order) as
+    the leaf.  A relaid leaf is read as the matrix ``(out * in, taps)``
+    it is in memory, transposed once to taps-first, and handed on behind
+    a logical ``transpose`` to ``(out, in) + taps``, which the compiler
+    folds into the layout its convolution reads (taps outermost, ``in``
+    along the lanes)."""
+    shape = tuple(int(d) for d in shape)
+    if not leaf_is_relaid(shape):
+        return seg.reshape(shape)
+    jnp = _jnp()
+    r = len(shape)
+    taps_first = seg.reshape(shape[0] * shape[1], -1).T.reshape(
+        shape[2:] + shape[:2])
+    return jnp.transpose(taps_first, (r - 2, r - 1) + tuple(range(r - 2)))
+
+
+def pack_leaf(leaf):
+    """``unpack_leaf``'s inverse: the leaf as its slice of the flat
+    vector, the same elements in the same order as ``leaf.reshape(-1)``.
+    A relaid leaf is brought taps-first by a logical ``transpose`` (its
+    gradient comes out of the convolution that way), read as the matrix
+    ``(taps, out * in)`` and transposed once."""
+    shape = leaf.shape
+    if not leaf_is_relaid(shape):
+        return leaf.reshape(-1)
+    jnp = _jnp()
+    r = len(shape)
+    taps_first = jnp.transpose(leaf, tuple(range(2, r)) + (0, 1))
+    return taps_first.reshape(-1, shape[0] * shape[1]).T.reshape(-1)
+
+
+class FlatLayout:
+    """The flat ZeRO-1 vector's layout: ``concatenate(leaf.reshape(-1))``
+    in ``jax.tree.leaves`` order, in the leaves' common dtype
+    (``jax.flatten_util.ravel_pytree``'s, element for element), with an
+    unpack and a pack that choose each leaf's route by
+    ``leaf_is_relaid``."""
+
+    def __init__(self, tree):
+        import jax
+
+        jnp = _jnp()
+        leaves, self.treedef = jax.tree.flatten(tree)
+        self.shapes = [tuple(int(d) for d in np.shape(x)) for x in leaves]
+        self.dtypes = [jnp.result_type(x) for x in leaves]
+        self.sizes = [int(np.prod(s, dtype=np.int64)) for s in self.shapes]
+        self.offsets = [int(o) for o in
+                        np.cumsum([0] + self.sizes, dtype=np.int64)]
+        self.dtype = jnp.result_type(*self.dtypes) if leaves \
+            else jnp.dtype(jnp.float32)
+
+    @property
+    def elems(self) -> int:
+        return self.offsets[-1]
+
+    def said(self) -> dict:
+        """How often the transposition engages (the tracer's event
+        ``distri.unpack``)."""
+        relaid = [z for s, z in zip(self.shapes, self.sizes)
+                  if leaf_is_relaid(s)]
+        return dict(leaves=len(self.shapes), relaid_leaves=len(relaid),
+                    elems=self.elems, relaid_elems=int(sum(relaid)))
+
+    def unpack(self, flat):
+        """Flat vector (padding allowed behind it) -> the tree."""
+        import jax
+
+        leaves = []
+        for shape, dtype, off, size in zip(self.shapes, self.dtypes,
+                                           self.offsets, self.sizes):
+            leaf = unpack_leaf(jax.lax.slice_in_dim(flat, off, off + size),
+                               shape)
+            leaves.append(leaf if flat.dtype == dtype else leaf.astype(dtype))
+        return jax.tree.unflatten(self.treedef, leaves)
+
+    def pack(self, tree, dtype=None):
+        """The tree -> flat vector, each leaf cast to ``dtype`` (default:
+        the layout's) BEFORE it is moved: a cast commutes with moving
+        elements, and a narrower leaf is half the bytes to move."""
+        import jax
+
+        jnp = _jnp()
+        dtype = self.dtype if dtype is None else dtype
+        leaves = jax.tree.leaves(tree)
+        if not leaves:
+            return jnp.zeros((0,), dtype)
+        return jnp.concatenate([pack_leaf(x.astype(dtype)) for x in leaves])
 
 
 class DistriOptimizer(LocalOptimizer):
@@ -168,12 +278,36 @@ class DistriOptimizer(LocalOptimizer):
     # ------------------------------------------------------------ sharding
     def _init_params(self):
         """The ZeRO-1 data plane works on the flat parameter vector (the
-        reference's AllReduceParameter flat layout); keep the unravel
-        closure for write-back."""
-        from jax.flatten_util import ravel_pytree
+        reference's AllReduceParameter flat layout):
+        ``concatenate(leaf.reshape(-1))`` in ``jax.tree.leaves`` order,
+        which is what ``velocity``, ``wire_ef``, ``_topology()``,
+        ``elastic.ensure_shard_layout``, the health boundaries and the
+        frozen intervals all index.
 
-        flat, unravel = ravel_pytree(self.model.params())
-        self._unravel = unravel
+        The unpack and the pack between that vector and the leaves are
+        the optimizer's own (``FlatLayout``), and a leaf's route follows
+        from its shape alone (``leaf_is_relaid``).  The chip keeps a
+        convolution kernel ``(out, in, kh, kw)`` in the layout
+        ``{1,0,3,2}``: taps outermost, ``in`` along the 128 lanes.  The
+        flat order has the TAP varying fastest, so a plain ``reshape`` of
+        a slice makes the compiler build an array whose 3 x 3 tail is
+        padded to a whole tile (268 MB for one 4.7 MB bfloat16 512 x 512
+        x 3 x 3 kernel) and copy THAT into the convolution's layout, and
+        the same on the gradient's way back: 14 ms of ResNet-50's 65 ms
+        step on four chips (PERF.md, PR 51).  So a leaf of rank 3 or more
+        with 2 to 127 elements behind its second dimension is cut out as
+        the matrix ``(out * in, taps)`` and transposed ONCE, at the
+        memory rate; every other leaf (a vector, a matrix, an embedding,
+        a 1 x 1 kernel, a tail of 128 or more, which fills its lanes)
+        takes the plain reshape and costs what it cost."""
+        import jax
+
+        self._layout = layout = FlatLayout(self.model.params())
+        # one program each for the reads outside the step (validation,
+        # histograms, write-back) and for this pack, not an eager
+        # dispatch a leaf
+        self._unpack = jax.jit(layout.unpack)
+        flat = jax.jit(layout.pack)(self.model.params())
         # static shape metadata for the collective byte footprint —
         # host-side ints, no device read
         self._flat_elems = int(flat.size)
@@ -181,9 +315,9 @@ class DistriOptimizer(LocalOptimizer):
         return flat
 
     def _params_tree(self, pvar):
-        # unravel on device: the flat ZeRO vector -> params pytree with
-        # no host round-trip (the unravel closure is a pure jax fn)
-        return self._unravel(pvar)
+        # unpack on device: the flat ZeRO vector -> params pytree with
+        # no host round-trip
+        return self._unpack(pvar)
 
     def _topology(self):
         """Checkpoint topology tag: the flat ZeRO-1 layout plus the
@@ -212,12 +346,12 @@ class DistriOptimizer(LocalOptimizer):
         return topo
 
     def _write_back(self, pvar, mod_state):
-        # unravel allocates fresh arrays; mod_state is copied so the model
-        # never aliases buffers the donated step will delete
+        # the unpack allocates fresh arrays; mod_state is copied so the
+        # model never aliases buffers the donated step will delete
         import jax
 
         jnp = _jnp()
-        self.model.set_params(self._unravel(pvar))
+        self.model.set_params(self._unpack(pvar))
         self.model.set_state(
             jax.tree.map(lambda a: jnp.array(a, copy=True), mod_state)
         )
@@ -434,6 +568,15 @@ class DistriOptimizer(LocalOptimizer):
         # (valid count) on top of this; the standard step's budget is
         # the per-step account
         self._collective_footprint = self._collective_byte_footprint()
+        # how often the unpack's transposition engages, said once a
+        # program (as ops/grouped_matmul.py says its tiles)
+        from bigdl_tpu import obs
+
+        said = self._layout.said()
+        logging.getLogger("bigdl_tpu.optim").debug("distri.unpack %s", said)
+        tracer = obs.get_tracer()
+        if tracer.enabled:
+            tracer.event("distri.unpack", **said)
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -543,19 +686,22 @@ class DistriOptimizer(LocalOptimizer):
                 m = m * (1.0 - ((idx >= s) & (idx < e)).astype(dtype))
             return m
 
+        # where the very next thing the flat gradient meets is the cast
+        # to the wire, the leaves are cast first and packed in that
+        # dtype: the same bits on the wire, half the bytes to move
+        pack_dtype = wire if not staged_ring else None
+        grads = self._value_and_flat_grad
+
         def sharded_step(flat_p, opt_st, mstate, rng, inp, tgt, mask=None):
             # named_scopes carry the reference's Metrics phase names into
             # profiler traces / HLO metadata (SURVEY.md §5 Tracing)
-            with jax.named_scope("computing"):
-                # ---- local replica compute (per-core fwd/bwd) -----------
-                args = (flat_p, mstate, rng, inp, tgt) + (
-                    (mask,) if masked else ())
-                (_, (loss_aux, new_mstate)), grad = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(*args)
-                if frozen_intervals is not None:
-                    grad = grad * _keep_mask(0, grad.shape[0], grad.dtype)
+            rest = (mstate, rng, inp, tgt) + ((mask,) if masked else ())
+            (loss_aux, new_mstate), grad = grads(loss_fn, flat_p, rest,
+                                                 pack_dtype)
             with jax.named_scope("put_gradient"):
+                if frozen_intervals is not None:
+                    # 0 / 1 commute with the cast the pack already made
+                    grad = grad * _keep_mask(0, grad.shape[0], grad.dtype)
                 # ---- putGradients + aggregateGradientPartition ----------
                 # one exchange per overlap bucket, emitted last-layer-
                 # first: the ravel layout is first-layer-first and the
@@ -594,8 +740,6 @@ class DistriOptimizer(LocalOptimizer):
                             else jnp.concatenate(
                                 [e.reshape(-1) for e in ef_pieces])
                 else:
-                    if wire is not None and wire != g.dtype:
-                        g = g.astype(wire)
                     for b in reversed(range(len(buckets))):
                         s, z = buckets[b]
                         pieces[b] = jax.lax.psum_scatter(
@@ -792,13 +936,11 @@ class DistriOptimizer(LocalOptimizer):
         count)."""
         model, criterion = self.model, self.criterion
         local_bs = self.batch_size // self.n_shards
-        unravel = self._unravel
 
-        def forward(flat_p, mstate, rng, inp):
+        def forward(p, mstate, rng, inp):
             import jax
 
             jnp = _jnp()
-            p = unravel(flat_p)
             pc, inpc = self._cast_for_compute(p, inp)
             out, new_mstate = model.apply(pc, mstate, inpc, training=True,
                                           rng=rng)
@@ -809,14 +951,14 @@ class DistriOptimizer(LocalOptimizer):
                 else a,
                 out,
             )
-            return p, out, new_mstate
+            return out, new_mstate
 
         if masked:
-            def loss_fn(flat_p, mstate, rng, inp, tgt, mask):
+            def loss_fn(p, mstate, rng, inp, tgt, mask):
                 import jax
 
                 jnp = _jnp()
-                p, out, new_mstate = forward(flat_p, mstate, rng, inp)
+                out, new_mstate = forward(p, mstate, rng, inp)
                 single = lambda t: jax.tree.map(lambda a: a[None], t)
                 per = jax.vmap(
                     lambda o, t: criterion.loss(single(o), single(t))
@@ -827,8 +969,8 @@ class DistriOptimizer(LocalOptimizer):
 
             return loss_fn
 
-        def loss_fn(flat_p, mstate, rng, inp, tgt):
-            p, out, new_mstate = forward(flat_p, mstate, rng, inp)
+        def loss_fn(p, mstate, rng, inp, tgt):
+            out, new_mstate = forward(p, mstate, rng, inp)
             per_mean = criterion.loss(out, tgt)
             # un-average: total local loss; grads then sum over samples, and
             # the sharded step divides by the global batch afterwards
@@ -843,6 +985,27 @@ class DistriOptimizer(LocalOptimizer):
 
         return loss_fn
 
+    def _value_and_flat_grad(self, loss_fn, flat_p, rest, pack_dtype):
+        """``loss_fn``'s aux and its gradient as a flat vector in
+        ``pack_dtype`` (None: the flat vector's own), inside the sharded
+        step.  The leaves are cut out of ``flat_p`` OUTSIDE the
+        differentiated function and the gradient is taken with respect
+        to the TREE, then packed by the inverse route: differentiating
+        through the unpack instead lets the compiler rebuild every saved
+        copy as one pass over the whole flat vector
+        (scripts/ravel_layout_probe.py, form C against form D)."""
+        import jax
+
+        layout = self._layout
+        with jax.named_scope("get_weights"):
+            p = layout.unpack(flat_p)
+        with jax.named_scope("computing"):
+            # ---- local replica compute (per-core fwd/bwd) ---------------
+            (_, aux), gtree = jax.value_and_grad(loss_fn, has_aux=True)(
+                p, *rest)
+        with jax.named_scope("put_gradient"):
+            return aux, layout.pack(gtree, pack_dtype)
+
     def _prepare_batch(self, inp, tgt):
         """The P(data) input sharding needs the batch divisible by the
         mesh; PAD the remainder by repeating the last sample and mark
@@ -850,8 +1013,6 @@ class DistriOptimizer(LocalOptimizer):
         variant folds into the loss/gradient mean (the reference's
         SampleToMiniBatch padding — SURVEY.md §2.1 "Dataset core";
         VERDICT r3 weak #7).  Nothing is ever trimmed or dropped."""
-        import logging
-
         bs = np.asarray(inp).shape[0]
         # per-process datasets yield LOCAL slices: divisibility is
         # against this process's device count, not the global mesh
@@ -909,7 +1070,6 @@ class DistriOptimizer(LocalOptimizer):
         # the FIRST attempt with zero checkpoint reloads; transient ones
         # (XLA/OSError/injected faults/non-finite escalation) back off
         # exponentially and reload the newest INTACT checkpoint.
-        import logging
         import time
 
         from bigdl_tpu import obs
